@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Loss-parity harness: every entry-point equivalent must train the toy
-problem to matching loss (BASELINE.md: "all four entry points reach
+problem to matching loss ("all four entry points reach
 matching loss" — the reference's cross-backend eyeball comparison,
 SURVEY.md §4.2, as an automated report).
 

@@ -63,10 +63,7 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count={devices}")
 import jax
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", {devices})
-except AttributeError:
-    pass  # older jax: the XLA_FLAGS pin above did the job
+jax.config.update("jax_num_cpu_devices", {devices})
 import sys
 sys.path.insert(0, {repo!r})
 sys.argv = ["plan_bench"]
@@ -190,7 +187,6 @@ def _collective_bandwidth() -> "float | None":
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from tpudist.parallel.overlap import compat_shard_map
     from tpudist.runtime.mesh import data_parallel_mesh
 
     n = jax.device_count()
@@ -199,9 +195,9 @@ def _collective_bandwidth() -> "float | None":
     mesh = data_parallel_mesh()
     m = 1 << 18  # 1 MiB of f32 per shard
     x = jnp.ones((n, m), jnp.float32)
-    f = jax.jit(compat_shard_map(
+    f = jax.jit(jax.shard_map(
         lambda v: jax.lax.psum(v, "data"), mesh=mesh,
-        in_specs=P("data"), out_specs=P()))
+        in_specs=P("data"), out_specs=P(), check_vma=False))
     jax.block_until_ready(f(x))  # compile
     iters = 20
     t0 = time.perf_counter()

@@ -3,7 +3,7 @@
 
 `bench.py` (TPUDIST_BENCH_PROFILE=dir) and the demos (``--profile_dir``)
 capture TensorBoard-style profiles; this tool turns the Chrome-trace
-export (``**/*.trace.json.gz``) into the table BASELINE.md wants next to
+export (``**/*.trace.json.gz``) into the table PERF.md wants next to
 an MFU number: top ops by device self-time, grouped, with percentages —
 the "where did the non-matmul time go" evidence (VERDICT r2 weak #2).
 

@@ -34,8 +34,14 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-# Public v5e spec: 197 bf16 TFLOP/s, 819 GB/s HBM BW, 16 GiB HBM.
-HBM_BYTES_PER_S = 8.19e11
+from tpudist.utils.flops import (HBM_BYTES_PER_S as _HBM_BY_KIND,  # noqa: E402
+                                 PEAK_BF16_FLOPS)
+
+# This analysis is OF one chip, the v5e (public spec: 197 bf16 TFLOP/s,
+# 819 GB/s HBM BW, 16 GiB HBM); its peaks come from the one table keyed
+# by device kind (tpudist/utils/flops.py), not from literals here.
+DEVICE_KIND = "TPU v5 lite"
+HBM_BYTES_PER_S = _HBM_BY_KIND[DEVICE_KIND]
 HBM_CAPACITY = 16 * 2 ** 30
 
 GEOM = dict(seq_len=2048, d_model=1024, n_layers=8, d_ff=4096, vocab=256)
@@ -233,9 +239,9 @@ def main(argv=None) -> int:
                     help="artifact path (default ROOFLINE_r{NN}.json at "
                          "the repo root, round auto-detected)")
     args = ap.parse_args(argv)
-    from tpudist.utils.flops import PEAK_BF16_FLOPS, transformer_train_flops
+    from tpudist.utils.flops import transformer_train_flops
 
-    peak = PEAK_BF16_FLOPS["TPU v5 lite"]
+    peak = PEAK_BF16_FLOPS[DEVICE_KIND]
     n_params = param_count(**GEOM)
     rows = []
     for tag, batch, remat in RUNGS:

@@ -100,29 +100,18 @@ def _pct(vals, q):
 
 
 def _ensure_devices(n: int) -> None:
-    """Emulate ``n`` CPU devices when the backend is not yet up (the
-    comm_audit trick) — standalone ``--mesh``/``--disagg`` runs need
-    them; under pytest the conftest's 8-device mesh is already live."""
+    """A CPU virtual mesh of at least ``n`` devices for a standalone run
+    (this script works on the CPU by design and never touches a chip).
+    Embedded in a process whose backend is already up (pytest: the
+    conftest's 8-device mesh) the device count cannot change any more,
+    and the embedder's devices stand."""
     import jax
 
+    jax.config.update("jax_platforms", "cpu")
     try:
-        from jax._src import xla_bridge as _xb
-
-        backend_up = _xb.backends_are_initialized()
-    except Exception:
-        backend_up = True
-    if not backend_up:
-        jax.config.update("jax_platforms", "cpu")
-        try:
-            jax.config.update("jax_num_cpu_devices", max(n, 2))
-        except AttributeError:
-            import os
-
-            flags = os.environ.get("XLA_FLAGS", "")
-            if "xla_force_host_platform_device_count" not in flags:
-                os.environ["XLA_FLAGS"] = (
-                    flags + f" --xla_force_host_platform_device_count"
-                            f"={max(n, 2)}").strip()
+        jax.config.update("jax_num_cpu_devices", max(n, 2))
+    except RuntimeError:
+        pass  # backends already initialized
 
 
 def _server_decode_stats(server) -> dict:

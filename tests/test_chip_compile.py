@@ -1,0 +1,208 @@
+"""Ask the v5e's compiler, from a sandbox without the chip, whether it
+accepts what ``chip_smoke.py`` will run there: every Pallas kernel at the
+real widths and pool size, and the whole d1024 train step.
+
+Interpret-mode tests cannot see what Mosaic refuses (block shapes that
+break the (8, 128) rule, SMEM/VMEM overflow at a real pool size, a bf16
+matmul accumulator) — five serving kernels passed every interpret test
+and none compiled.  A compile here is a rehearsal: nothing runs, so it
+says nothing about results or speed (``chip_smoke.py`` checks numerics on
+the chip).
+
+The topology is described inside a module-scoped fixture, never at
+import or collection time: only one process may hold the TPU library,
+and under pytest-xdist every worker imports every test file.  All the
+compiles live in this one file, in the test's own process, with the
+persistent compilation cache off (a cache entry compiled for a described
+chip cannot be read back without one).
+"""
+
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+import chip_smoke  # noqa: E402
+
+W = chip_smoke.FULL
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, args, sharding):
+    """Lower + compile ``fn`` for the described chip from shapes alone."""
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        args)
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def test_topology_is_the_v5e(topo):
+    """The peaks table and the tuned-constants file are keyed by this
+    very device kind — an unknown kind is an error on the chip."""
+    from tpudist.utils.flops import chip_hbm_bytes_per_s, chip_peak_flops
+    from tpudist.utils.tuning import tuned_file_path
+
+    dev = topo.devices[0]
+    assert dev.platform == "tpu" and len(topo.devices) == 4
+    assert chip_peak_flops(dev) == 197e12
+    assert chip_hbm_bytes_per_s(dev) == 8.19e11
+    assert tuned_file_path(dev.device_kind).is_file()
+
+
+# (batch, heads, seq, dh, block_q, block_k): transformer.py's routing —
+# 512/512 from seq 1024, 512/1024 from seq 8192 — and the tiles the
+# tracked tpudist/tuned/TPU_v5_lite.json selects on this chip (1024/1024)
+FLASH = {
+    "seq2048-dh128-512x512": (8, 8, 2048, 128, 512, 512),
+    "seq2048-dh128-1024x1024": (8, 8, 2048, 128, 1024, 1024),
+    "seq8192-dh64-512x1024": (4, 4, 8192, 64, 512, 1024),
+}
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+@pytest.mark.parametrize("tiling", sorted(FLASH))
+def test_flash_attention_compiles(one_chip, tiling, grad):
+    from tpudist.ops import flash_attention
+
+    b, h, s, dh, bq, bk = FLASH[tiling]
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, bq, bk, False).astype(
+            jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else loss
+    qkv = (jax.ShapeDtypeStruct((b, h, s, dh), jnp.bfloat16),) * 3
+    text = _compile(fn, qkv, one_chip).as_text()
+    # forward kernel; + dq and dk/dv kernels under grad
+    assert text.count("tpu_custom_call") == (3 if grad else 1)
+
+
+KERNEL_FAMILIES = ("paged_attention", "paged_prefill", "fused_sample",
+                   "fused_residual", "fused_rope_qkv", "lora_delta",
+                   "fused_mlp")
+
+
+@pytest.mark.parametrize("family", KERNEL_FAMILIES)
+def test_serving_kernel_compiles_at_real_size(one_chip, family):
+    """The very cases ``chip_smoke.py`` checks numerically on the chip —
+    32 slots, 8 layers x 1024 blocks x 8 kv heads x dh 128, bf16 and int8
+    pools, vocab 256 and 32768 — compiled from their shapes."""
+    cases = [c for c in chip_smoke.kernel_cases(W)
+             if c.name.split("/")[0] == family]
+    assert cases, family
+    for case in cases:
+        args = jax.eval_shape(
+            lambda: case.make(np.random.default_rng(0)))
+        compiled = _compile(
+            lambda *a: case.fn(*a, interpret=False), args, one_chip)
+        assert "tpu_custom_call" in compiled.as_text(), case.name
+
+
+def _abstract_lm(w, tx):
+    """``(module, ModelState of ShapeDtypeStructs)`` for ``w`` — nothing
+    is materialized (there is no device to hold it)."""
+    from tpudist.models import create_transformer
+    from tpudist.train import init_lm_state
+
+    made = {}
+
+    def init():
+        made["module"], params = create_transformer(
+            jax.random.PRNGKey(0), seq_len=w.seq, **w.model_kwargs())
+        return init_lm_state(params, tx)
+
+    return made, jax.eval_shape(init)
+
+
+def test_d1024_train_step_compiles_with_the_flash_kernel(topo, monkeypatch):
+    """The whole train step of ``chip_smoke.py``'s first phase, from
+    ``jax.eval_shape`` shapes.  The model picks its attention by asking
+    JAX for the platform, so the test (not a new option of the program)
+    answers with the described devices."""
+    import optax
+
+    from tpudist.runtime.mesh import MeshConfig, make_mesh
+    from tpudist.train import make_lm_train_step, token_sharding
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    mesh = make_mesh(MeshConfig(data=1), devices=topo.devices[:1])
+    repl = NamedSharding(mesh, PartitionSpec())
+    tx = optax.adam(1e-3)
+    made, abstract = _abstract_lm(W, tx)
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+        abstract)
+    tokens = jax.ShapeDtypeStruct((W.batch, W.seq), jnp.int32,
+                                  sharding=token_sharding(mesh))
+    compiled = make_lm_train_step(made["module"].apply, tx, mesh).lower(
+        state, tokens).compile()
+    # one forward + two backward kernels per layer, nothing gave way to
+    # the blockwise XLA formulation
+    assert compiled.as_text().count("tpu_custom_call") == 3 * W.n_layers
+    # params + Adam moments + activations fit the chip's 16 GB of HBM
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 15e9
+
+
+def test_four_chip_fsdp_step_keeps_the_flash_kernel(topo, monkeypatch):
+    """Mosaic kernels cannot be partitioned automatically: inside a
+    multi-chip jit the flash kernel must arrive wrapped per shard
+    (``transformer._per_shard`` under the step builder's ambient mesh) —
+    code that has only seen a CPU virtual mesh takes the XLA attention
+    there and never meets the refusal.  Two layers: the point is the
+    partitioning, not the size."""
+    import dataclasses
+
+    import optax
+
+    from tpudist.parallel import fsdp_sharding
+    from tpudist.runtime.mesh import MeshConfig, make_mesh
+    from tpudist.train import make_lm_train_step, token_sharding
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    w = dataclasses.replace(W, n_layers=2)
+    mesh = make_mesh(MeshConfig(data=4), devices=topo.devices)
+    tx = optax.adam(1e-3)
+    made, abstract = _abstract_lm(w, tx)
+    sharding = fsdp_sharding(mesh, abstract)
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        abstract, sharding)
+    tokens = jax.ShapeDtypeStruct((w.batch, w.seq), jnp.int32,
+                                  sharding=token_sharding(mesh))
+    text = make_lm_train_step(
+        made["module"].apply, tx, mesh, state_sharding=sharding).lower(
+            state, tokens).compile().as_text()
+    assert text.count("tpu_custom_call") == 3 * w.n_layers
+    assert "all-gather" in text and "reduce-scatter" in text

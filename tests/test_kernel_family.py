@@ -24,8 +24,9 @@ closeness.
 
 Marker policy (``pallas``): everything here runs through the Pallas
 INTERPRETER on CPU — tier-1 coverage of the exact walk/merge/quantize
-code.  Native-lowering twins (``TestKernelFamilyNative``) are
-slow-lane (tests/conftest.py) and skip off-TPU.
+code.  The native lowering is covered where it can be: compiled for the
+v5e at the real pool size in tests/test_chip_compile.py, and run against
+the references on the chip by chip_smoke.py.
 """
 
 import jax
@@ -453,35 +454,3 @@ class TestKernelFamilyEngine:
         with pytest.raises(ValueError, match="LORA_KERNEL"):
             SlotEngine(module, params, num_slots=2, paged=True,
                        kv_block=4, adapters=True, lora_kernel=True)
-
-
-class TestKernelFamilyNative:
-    """Native Mosaic lowering — slow-lane (tests/conftest.py) and
-    TPU-only: the rung a hardware round runs via ``pytest -m pallas``."""
-
-    @pytest.mark.skipif(jax.devices()[0].platform != "tpu",
-                        reason="native Mosaic lowering requires a TPU")
-    def test_native_prefill_matches_reference(self):
-        args = _prefill_case(S=4, nh=4, n_kv=2, dh=128, L=2, nb=24,
-                             bs=16, M=6, P=16, quant=True, seed=0)
-        _check_prefill(args, True, layer=0)
-
-    @pytest.mark.skipif(jax.devices()[0].platform != "tpu",
-                        reason="native Mosaic lowering requires a TPU")
-    def test_native_sample_and_linear_match(self):
-        r = np.random.default_rng(1)
-        logits = jnp.asarray(r.normal(size=(4, 256)), jnp.float32)
-        temps = jnp.asarray([0.0, 0.5, 1.0, 1.5], jnp.float32)
-        out = fused_sample_prep(logits, temps, top_k=8, interpret=False)
-        ref = fused_sample_reference(logits, temps, top_k=8)
-        for a, b in zip(out, ref):
-            np.testing.assert_array_equal(a, b)
-        h = jnp.asarray(r.normal(size=(4, 8, 256)), jnp.float32)
-        w = jnp.asarray(r.normal(size=(256, 512)) * 0.05, jnp.float32)
-        offs = jnp.asarray([0, 3, 11, 40], jnp.int32)
-        out = fused_rope_qkv(h, w, offs, n_heads=2, n_kv=1, dh=128,
-                             interpret=False)
-        ref = fused_rope_qkv_reference(h, w, offs, n_heads=2, n_kv=1,
-                                       dh=128)
-        for a, b in zip(out, ref):
-            np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)

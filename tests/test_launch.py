@@ -254,6 +254,49 @@ class TestTpurun:
                          "--coordinator", "h:1", "--elastic",
                          "--", "python", "x.py"])
 
+    @pytest.mark.parametrize("env,refused", [
+        ({}, True),                                   # every worker, every chip
+        ({"TPU_VISIBLE_CHIPS": "0"}, False),          # caller bound the chips
+        ({"JAX_PLATFORMS": "cpu"}, False),            # workers stay off the TPU
+        ({"JAX_PLATFORMS": "tpu,cpu"}, True),
+    ], ids=["unbound", "tpu-visible", "cpu-pinned", "tpu-listed"])
+    def test_nprocs_on_tpu_host_needs_chip_binding(self, tmp_path,
+                                                   monkeypatch, env,
+                                                   refused):
+        """One process for each chip: N>1 workers on a (faked) TPU host
+        are refused before anything is spawned, unless bound."""
+        _clean_env(monkeypatch)
+        for var in list(os.environ):
+            if var == "JAX_PLATFORMS" or (
+                    var.startswith("TPU_") and "VISIBLE" in var):
+                monkeypatch.delenv(var)
+        monkeypatch.setenv("TPU_NAME", "fake-v5e")
+        for k, v in env.items():
+            monkeypatch.setenv(k, v)
+        marker = tmp_path / "ran"
+        worker = _write_worker(tmp_path, f"""
+            import os
+            open({str(marker)!r} + os.environ["TPUDIST_PROCESS_ID"], "w")
+        """)
+        argv = ["--nprocs", "2", "--tmpdir", str(tmp_path / "scratch"),
+                "--", sys.executable, str(worker)]
+        if refused:
+            with pytest.raises(SystemExit, match="chip binding"):
+                tpurun_main(argv)
+            assert not list(tmp_path.glob("ran*"))
+        else:
+            assert tpurun_main(argv) == 0
+            assert len(list(tmp_path.glob("ran*"))) == 2
+        # one worker per host is always fine
+        assert tpurun_main(["--nprocs", "1"] + argv[2:]) == 0
+
+    def test_agent_import_initializes_no_backend(self):
+        """The agent must stay off the chip its workers need."""
+        code = ("import tpudist.launch.run, jax._src.xla_bridge as xb; "
+                "assert not xb.backends_are_initialized()")
+        subprocess.run([sys.executable, "-c", code], check=True,
+                       cwd=Path(__file__).resolve().parent.parent)
+
     def test_cmd_must_start_with_python(self, tmp_path):
         # torchrun_launcher.sh:23-25 parity.
         with pytest.raises(SystemExit):

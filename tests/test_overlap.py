@@ -29,7 +29,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpudist.parallel import (
     ag_matmul,
-    compat_shard_map,
     init_mlp_params,
     make_tp_mlp,
     matmul_rs,
@@ -51,8 +50,8 @@ def model_mesh(devices):
 
 
 def _sharded(body, mesh, in_specs, out_specs):
-    return jax.jit(compat_shard_map(
-        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
+    return jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
 
 
 class TestPrimitives:
@@ -169,11 +168,11 @@ class TestPrimitives:
                 out = matmul_rs(h, w2l, axis_name=AXIS_MODEL, mode=mode)
                 return jax.lax.psum(jnp.sum(out * out), AXIS_MODEL)
 
-            inner = compat_shard_map(
+            inner = jax.shard_map(
                 body, mesh=model_mesh,
                 in_specs=(P(AXIS_MODEL, None), P(None, AXIS_MODEL),
                           P(AXIS_MODEL, None)),
-                out_specs=P())
+                out_specs=P(), check_vma=False)
             return inner(xx, w1_, w2_)
 
         def dense_loss(xx, w1_, w2_):

@@ -18,8 +18,9 @@ STREAMS are byte-identical (tests pin equality, not closeness).
 
 Marker policy (``pallas``): everything here runs the kernel through the
 Pallas INTERPRETER on CPU — tier-1 coverage of the exact walk/mask/
-dequant code.  Native-lowering cases (``TestPagedAttentionNative``) are
-additionally slow-lane (tests/conftest.py) and skip off-TPU.
+dequant code.  The native lowering is compiled for the v5e at the real
+pool size in tests/test_chip_compile.py and run against the reference on
+the chip by chip_smoke.py.
 """
 
 import jax
@@ -323,19 +324,3 @@ class TestKernelServer:
         kv = report["serving"]["kv"]
         assert kv["attn_kernel"] == "paged"
         assert kv["read_bytes_per_token"] > 0
-
-
-class TestPagedAttentionNative:
-    """Native Mosaic lowering (no interpreter) — the on-chip half.
-    Slow-lane (tests/conftest.py) and TPU-only: the container's CPU
-    backend cannot lower Mosaic, so this is the rung a hardware round
-    runs via ``pytest -m pallas``."""
-
-    @pytest.mark.skipif(jax.devices()[0].platform != "tpu",
-                        reason="native Mosaic lowering requires a TPU")
-    def test_native_matches_reference(self):
-        args = _case(S=4, nh=4, n_kv=2, s=4, dh=128, L=2, nb=9, bs=16,
-                     M=4, quant=True, seed=0)
-        out = paged_attention(*args, layer=0, interpret=False)
-        ref = paged_attention_reference(*args, layer=0)
-        np.testing.assert_allclose(out, ref, **TOL["int8"])

@@ -11,7 +11,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tpudist.parallel import (
     MoEStats,
     attention_reference,
-    compat_shard_map,
     init_mlp_params,
     make_moe,
     make_pipeline,
@@ -336,8 +335,8 @@ class TestPipeline:
         mesh = Mesh(np.array(jax.devices()[:4]), ("stage",))
         with pytest.raises(ValueError, match="collective"):
             jax.eval_shape(
-                compat_shard_map(run, mesh=mesh,
-                                 in_specs=P(), out_specs=P()),
+                jax.shard_map(run, mesh=mesh,
+                                 in_specs=P(), out_specs=P(), check_vma=False),
                 args)
 
     def test_head_collective_free_loss_passes(self):
@@ -750,7 +749,7 @@ class TestZigzagRing:
         mesh = Mesh(np.asarray(devices[:4]), (AXIS_SEQ,))
         q = jax.random.normal(jax.random.PRNGKey(0), (1, 1, 12, 8))
         with pytest.raises(ValueError, match="even"):
-            compat_shard_map(
+            jax.shard_map(
                 lambda a, b, c: ring_attention_shard_zigzag(a, b, c),
                 mesh=mesh,
                 in_specs=(P(None, None, AXIS_SEQ, None),) * 3,

@@ -14,7 +14,6 @@ import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpudist.parallel.overlap import compat_shard_map
 from tpudist.parallel.pipeline_interleaved import (
     deinterleave_block_params,
     interleave_block_params,
@@ -118,11 +117,10 @@ class TestShardParity:
                 Wb, ow, xm, am, stage_fn=stage_fn, loss_fn=loss_fn,
                 schedule=sched, axis_name="stage")
 
-        loss_sum, cg, og, dx = jax.jit(compat_shard_map(
+        loss_sum, cg, og, dx = jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(P("stage"), P(), P(), P()),
-            out_specs=(P(), P("stage"), P(), P()),
-        ))(interleave_block_params(Ws, D), out_w, xs, aux)
+            out_specs=(P(), P("stage"), P(), P()), check_vma=False))(interleave_block_params(Ws, D), out_w, xs, aux)
 
         np.testing.assert_allclose(float(loss_sum), float(ref_l), rtol=1e-5)
         np.testing.assert_allclose(

@@ -1,5 +1,7 @@
 """Bootstrap contract tests — the rank-derivation matrix of SURVEY.md §3.1-3.3."""
 
+from pathlib import Path
+
 import pytest
 
 from tpudist.runtime.bootstrap import (
@@ -164,51 +166,69 @@ def test_hybrid_mesh_forced_granules_layout(devices):
 
 
 class TestCompilationCache:
-    """Persistent XLA compilation cache wiring (wedge-retry mitigation)."""
+    """The cache rule: JAX's own variable places the cache from outside
+    and then the program sets no directory; otherwise ONE fixed path
+    inside the checkout."""
 
-    def test_enables_and_creates_dir(self, tmp_path, monkeypatch):
+    @pytest.fixture()
+    def cache_config(self, monkeypatch):
+        """jax.config survives monkeypatch: restore it, so no later
+        test's compiles land in a cache dir."""
         import jax
 
-        from tpudist.runtime import enable_compilation_cache
-
+        monkeypatch.delenv("TPUDIST_COMPILATION_CACHE", raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         old = jax.config.jax_compilation_cache_dir
-        target = tmp_path / "xla-cache"
-        monkeypatch.setenv("TPUDIST_COMPILATION_CACHE", str(target))
-        try:
-            got = enable_compilation_cache()
-            assert got == str(target)
-            assert target.is_dir()
-            assert jax.config.jax_compilation_cache_dir == str(target)
-        finally:
-            # jax.config survives monkeypatch; a deleted tmp cache dir
-            # must not leak into later tests' compiles
-            jax.config.update("jax_compilation_cache_dir", old)
+        floor = jax.config.jax_persistent_cache_min_compile_time_secs
+        yield jax.config
+        jax.config.update("jax_compilation_cache_dir", old)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
 
-    def test_off_switch(self, monkeypatch):
+    def test_variable_set_means_no_directory_set_in_code(
+            self, cache_config, monkeypatch, tmp_path):
+        import tpudist.runtime.bootstrap as bootstrap
+
+        cache_config.update("jax_compilation_cache_dir", "sentinel")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "out"))
+        monkeypatch.setattr(bootstrap, "_INITIALIZED_CTX", None)
+        bootstrap.initialize()
+        # untouched by initialize(), and nothing was created for it
+        assert cache_config.jax_compilation_cache_dir == "sentinel"
+        assert not (tmp_path / "out").exists()
         from tpudist.runtime import enable_compilation_cache
 
+        assert enable_compilation_cache() == str(tmp_path / "out")
+
+    def test_unset_means_the_fixed_path_inside_the_checkout(
+            self, cache_config, monkeypatch):
+        from tpudist.runtime import compilation_cache as cc
+
+        repo = Path(__file__).resolve().parent.parent
+        assert cc.DEFAULT_CACHE_DIR == repo / ".jax_cache"
+        # never the home directory, a temp name, a pid or the time
+        monkeypatch.setenv("HOME", "/nonexistent-home")
+        monkeypatch.setenv("TMPDIR", "/nonexistent-tmp")
+        first = cc.enable_compilation_cache()
+        assert first == str(repo / ".jax_cache") == \
+            cache_config.jax_compilation_cache_dir
+        assert Path(first).is_dir()
+        assert cc.enable_compilation_cache() == first  # two calls, one path
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+
+    def test_off_switch(self, cache_config, monkeypatch):
+        from tpudist.runtime import enable_compilation_cache
+
+        cache_config.update("jax_compilation_cache_dir", "sentinel")
         monkeypatch.setenv("TPUDIST_COMPILATION_CACHE", "off")
         assert enable_compilation_cache() is None
+        assert cache_config.jax_compilation_cache_dir == "sentinel"
 
-    def test_explicit_path_wins(self, tmp_path, monkeypatch):
-        import jax
+    def test_unwritable_location_raises_instead_of_running_uncached(
+            self, cache_config, monkeypatch, tmp_path):
+        from tpudist.runtime import compilation_cache as cc
 
-        from tpudist.runtime import enable_compilation_cache
-
-        old = jax.config.jax_compilation_cache_dir
-        monkeypatch.delenv("TPUDIST_COMPILATION_CACHE", raising=False)
-        try:
-            got = enable_compilation_cache(str(tmp_path / "explicit"))
-            assert got == str(tmp_path / "explicit")
-        finally:
-            jax.config.update("jax_compilation_cache_dir", old)
-
-    def test_cpu_platform_not_cached_by_default(self, monkeypatch):
-        """Default-on is for accelerator platforms only: XLA:CPU AOT
-        entries are cpu-feature-sensitive (SIGILL risk) and CPU compiles
-        are cheap; an explicit env dir still opts in."""
-        from tpudist.runtime import enable_compilation_cache
-
-        monkeypatch.delenv("TPUDIST_COMPILATION_CACHE", raising=False)
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        assert enable_compilation_cache() is None
+        blocker = tmp_path / "file"
+        blocker.write_text("")
+        monkeypatch.setattr(cc, "DEFAULT_CACHE_DIR", blocker / ".jax_cache")
+        with pytest.raises(OSError):
+            cc.enable_compilation_cache()

@@ -22,6 +22,7 @@ dependency.
 from __future__ import annotations
 
 import ctypes
+import functools
 import hashlib
 import os
 import subprocess
@@ -37,14 +38,14 @@ from tpudist.data.sharding import epoch_indices
 _SRC = Path(__file__).parent / "native" / "gather.cpp"
 _LIB: Optional[ctypes.CDLL] = None
 _LIB_TRIED = False
+_LIB_ERROR = ""  # why the build/load failed — shown once by make_loader
 
 
 def _cache_dir() -> Path:
-    base = os.environ.get("TPUDIST_CACHE", os.path.join(
-        os.environ.get("XDG_CACHE_HOME", os.path.expanduser("~/.cache")),
-        "tpudist",
-    ))
-    p = Path(base)
+    """Where the built library lives: ``TPUDIST_CACHE`` if set, else a
+    ``build/`` directory beside the source (git-ignored) — the program
+    writes nothing around its checkout."""
+    p = Path(os.environ.get("TPUDIST_CACHE") or _SRC.parent / "build")
     p.mkdir(parents=True, exist_ok=True)
     return p
 
@@ -66,7 +67,9 @@ def _build_library() -> Optional[Path]:
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
             os.replace(tmp_out, out)  # atomic: concurrent builders are safe
-        except (OSError, subprocess.SubprocessError):
+        except (OSError, subprocess.SubprocessError) as e:
+            global _LIB_ERROR
+            _LIB_ERROR = f"{' '.join(cmd[:2])} ...: {e}"
             return None
     return out
 
@@ -82,7 +85,9 @@ def load_library() -> Optional[ctypes.CDLL]:
         return None
     try:
         lib = ctypes.CDLL(str(path))
-    except OSError:
+    except OSError as e:
+        global _LIB_ERROR
+        _LIB_ERROR = f"loading {path}: {e}"
         return None
     lib.tg_create.restype = ctypes.c_void_p
     lib.tg_create.argtypes = [ctypes.c_int]
@@ -225,11 +230,23 @@ class PrefetchingLoader(ShardedLoader):
 def make_loader(dataset, batch_size, plan, *, num_workers: int = 0,
                 prefetch_depth: int = 4) -> ShardedLoader:
     """Loader factory honoring the reference's ``--num_workers`` semantics:
-    0 → synchronous; >0 → native prefetching pool when buildable, with a
-    silent fallback to synchronous otherwise (the flag is a performance
-    hint, never a correctness requirement)."""
+    0 → synchronous; >0 → native prefetching pool when buildable, else
+    the synchronous loader (the flag is a performance hint, never a
+    correctness requirement) — said once on the rank-0 log, with the
+    reason."""
     if num_workers > 0 and native_available():
         return PrefetchingLoader(dataset, batch_size, plan,
                                  num_workers=num_workers,
                                  prefetch_depth=prefetch_depth)
+    if num_workers > 0:
+        _warn_no_native(num_workers)
     return ShardedLoader(dataset, batch_size, plan)
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_no_native(num_workers: int) -> None:
+    from tpudist.runtime.rank_logging import rank_print
+
+    rank_print(f"[tpudist.data] --num_workers {num_workers}: the native "
+               f"prefetch loader is unavailable ({_LIB_ERROR}); batches are "
+               "assembled synchronously")

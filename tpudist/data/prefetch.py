@@ -2,7 +2,7 @@
 
 The training loops call ``jax.device_put(next(loader), sharding)``
 synchronously: the accelerator idles through the host-side batch
-assembly AND the PCIe/tunnel transfer of every batch.  The torch side
+assembly AND the PCIe transfer of every batch.  The torch side
 hides this with pinned-memory DataLoader workers; the JAX-native
 equivalent is simpler — ``device_put`` is asynchronous (it returns
 before the transfer completes, like every dispatch), so it suffices to

@@ -220,6 +220,34 @@ def _terminate(procs: List[subprocess.Popen], grace_s: float = 10.0) -> None:
                 p.wait()
 
 
+def _require_chip_binding(nprocs: int, env) -> None:
+    """One process for each chip: refuse ``--nprocs N>1`` on a TPU host
+    unless the caller bound chips per worker.
+
+    A chip belongs to one process at a time, and a JAX process claims
+    every chip it can see — N unbound workers would all claim all of
+    them, and all but one fail or hang.  The standard shape is ONE
+    process per host that sees all local chips (what
+    distributed_dispatcher/tpu_pod_run launch).  Bound means a
+    ``TPU_*VISIBLE*`` variable in the environment (the caller's wrapper
+    sets it per worker), or workers pinned off the TPU by
+    ``JAX_PLATFORMS`` (CPU multi-process runs on a TPU host).
+    """
+    on_tpu_host = os.path.exists("/dev/accel0") or env.get("TPU_NAME")
+    if nprocs <= 1 or not on_tpu_host:
+        return
+    bound = any(k.startswith("TPU_") and "VISIBLE" in k for k in env)
+    platforms = env.get("JAX_PLATFORMS", "").lower()
+    if bound or (platforms and "tpu" not in platforms.split(",")):
+        return
+    raise SystemExit(
+        f"tpurun: --nprocs {nprocs} on a TPU host without per-worker chip "
+        "binding: every worker would claim every chip, and a chip belongs "
+        "to one process. Run 1 process per host (it sees all local chips), "
+        "bind chips per worker (TPU_VISIBLE_* in the environment), or pin "
+        "the workers to CPU with JAX_PLATFORMS=cpu — see launch/README.md")
+
+
 def _run_attempt(cmd: List[str], args, coordinator: str, world: int,
                  run_id: str, restart_count: int, error_template: str,
                  tmpdir: str, telemetry_dir: Optional[str] = None,
@@ -233,20 +261,6 @@ def _run_attempt(cmd: List[str], args, coordinator: str, world: int,
     procs: List[subprocess.Popen] = []
     _preempt_state["procs"] = procs
     base_env = dict(os.environ)
-    if nprocs > 1 and (
-        os.path.exists("/dev/accel0") or base_env.get("TPU_NAME")
-    ) and not any(k.startswith("TPU_") and "VISIBLE" in k for k in base_env):
-        # The standard JAX shape on TPU hosts is ONE process per host that
-        # sees all local chips (what distributed_dispatcher/tpu_pod_run
-        # launch); N workers would all try to claim every chip.  Honor the
-        # request (the operator may have set per-chip topology envs another
-        # way) but say so.
-        print(
-            f"[tpurun] warning: {nprocs} workers on a TPU host without "
-            "per-process chip binding (TPU_VISIBLE_* env); TPU jobs normally "
-            "run 1 process/host — see launch/README.md",
-            file=sys.stderr,
-        )
     for i in range(nprocs):
         rank = args.node_rank * nprocs + i
         env = _worker_env(base_env, coordinator=coordinator, world=world,
@@ -305,6 +319,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             "renumbering is node-local; multi-node elasticity needs a "
             "cross-agent rendezvous)")
 
+    _require_chip_binding(args.nprocs, os.environ)
     world = args.nnodes * args.nprocs
     standalone = args.standalone or (args.nnodes == 1 and args.coordinator is None)
     if standalone:

@@ -35,6 +35,34 @@ from tpudist.parallel.ring_attention import attention_reference
 AttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
 
 
+def _per_shard(kernel, q, k, v):
+    """Run a Pallas attention ``kernel`` on each device's own batch rows.
+
+    Mosaic kernels cannot be partitioned automatically: inside a jit over
+    several chips (every multi-chip DP / FSDP / ZeRO train step) a bare
+    ``pallas_call`` is refused with "wrap the call in a shard_map".  The
+    model does not know the mesh, so the step builders
+    (``tpudist.train.lm``) trace under it as JAX's ambient mesh, and this
+    wraps the kernel in a ``shard_map`` over its ``data`` axis — attention
+    rows are independent per batch element.  Heads are not split: under
+    tensor parallelism every ``model`` shard computes all heads.  One
+    device, no ambient mesh, or already inside a ``shard_map`` body (ring
+    attention, the pipeline schedules): the kernel runs as it is.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from tpudist.runtime.mesh import AXIS_DATA
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return kernel(q, k, v)
+    data = (AXIS_DATA if AXIS_DATA in mesh.axis_names
+            and q.shape[0] % mesh.shape[AXIS_DATA] == 0 else None)
+    spec = P(data)
+    return jax.shard_map(kernel, in_specs=(spec, spec, spec), out_specs=spec,
+                         check_vma=False)(q, k, v)
+
+
 def make_length_aware_attention(window: Optional[int] = None):
     """Build the platform/length-aware single-device causal attention:
     dense XLA for short sequences (lowest dispatch overhead), the Pallas
@@ -76,7 +104,9 @@ def make_length_aware_attention(window: Optional[int] = None):
         if use_flash:
             from tpudist.ops import flash_attention
 
-            return flash_attention(q, k, v, True, bq, bk, False, window)
+            return _per_shard(
+                lambda q, k, v: flash_attention(q, k, v, True, bq, bk, False,
+                                                window), q, k, v)
         if not blocks_fit:
             return attention_reference(q, k, v, causal=True, window=window)
         from tpudist.ops import blockwise_attention
@@ -97,6 +127,17 @@ def make_length_aware_attention(window: Optional[int] = None):
 _default_attention = make_length_aware_attention()
 
 
+def rope_angles(offset, seq: int, half: int, base: float) -> jax.Array:
+    """f32 rotary angles ``[(b,) seq, half]`` for positions ``offset +
+    [0, seq)`` — the one place the angle math lives (``rope_rotate`` and
+    the fused RoPE+QKV kernel's tables both call it, so they cannot
+    drift)."""
+    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    off = jnp.asarray(offset, jnp.float32)
+    positions = off[..., None] + jnp.arange(seq, dtype=jnp.float32)
+    return positions[..., None] * freqs
+
+
 def rope_rotate(x: jax.Array, base: float = 10000.0, offset=0) -> jax.Array:
     """Rotary position embedding over ``[batch, heads, seq, head_dim]``.
 
@@ -109,11 +150,8 @@ def rope_rotate(x: jax.Array, base: float = 10000.0, offset=0) -> jax.Array:
     KV-cache decode path rotates tokens at their absolute position.
     """
     half = x.shape[-1] // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    off = jnp.asarray(offset, jnp.float32)
-    positions = off[..., None] + jnp.arange(x.shape[-2], dtype=jnp.float32)
-    angles = positions[..., None] * freqs            # [(b,) s, half]
-    if off.ndim:
+    angles = rope_angles(offset, x.shape[-2], half, base)
+    if angles.ndim == 3:
         # per-batch offsets: broadcast over the heads axis
         angles = angles[:, None]                     # [b, 1, s, half]
     sin, cos = jnp.sin(angles), jnp.cos(angles)

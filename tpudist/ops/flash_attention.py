@@ -34,11 +34,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Renamed across pallas versions (TPUCompilerParams -> CompilerParams) —
-# same shim as the other kernel families in this package.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 from tpudist.parallel.ring_attention import (
     _block_update,
     _causal_mask,
@@ -360,7 +355,7 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
             pltpu.VMEM((bq, 1), jnp.float32),   # l (running normalizer)
             pltpu.VMEM((bq, d), jnp.float32),   # acc (unnormalized out)
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # bh and q rows are independent; only the KV sweep accumulates.
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
@@ -627,7 +622,7 @@ def _flash_backward(q, k, v, do, lse, delta, *, causal, block_q, block_k,
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -681,7 +676,7 @@ def _flash_backward(q, k, v, do, lse, delta, *, causal, block_q, block_k,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(

@@ -8,8 +8,9 @@ Dense matmul, three slices, three reshape/transposes, then
 ``rope_rotate``'s trig tower — each round-tripping the ``[S, T, d]``
 activations through HBM.  Here the weight tile stays resident in VMEM
 across the slot loop, the per-slot VECTOR offsets (PR 11's paged
-cursors) ride in as a scalar-prefetch operand, and the rotation applies
-in-registers right after the matmul, bit-matching
+cursors) become per-slot rotary tables built in-graph
+(:func:`rope_tables` — the trig tower is ``[S, T, dh]``, tiny), and the
+rotation applies in-registers right after the matmul, bit-matching
 ``transformer.rope_rotate`` (same f32 angle/trig math, same half-split
 layout).  The optional ``extra`` operand is the LoRA delta, applied
 pre-rotation under its ``on`` mask — exactly where ``Block._ad``
@@ -32,55 +33,56 @@ this at multi-GB weights.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+
+def rope_tables(offsets: jax.Array, T: int, dh: int, base: float):
+    """Full-width rotary tables ``(cos, sin) [S, T, dh]`` f32 for the
+    kernel's roll form of the half-split rotation:
+    ``x * [cos, cos] + roll(x, dh/2) * [-sin, sin]`` is, term for term,
+    ``transformer.rope_rotate``'s ``[x1*cos - x2*sin, x1*sin + x2*cos]``
+    (negating a factor negates the product exactly).  The angles are
+    ``rope_rotate``'s own (``transformer.rope_angles``), per-slot
+    absolute offsets."""
+    from tpudist.models.transformer import rope_angles
+
+    angles = rope_angles(offsets, T, dh // 2, base)    # [S, T, half]
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    return (jnp.concatenate([cos, cos], axis=-1),
+            jnp.concatenate([-sin, sin], axis=-1))
 
 
-def _rope_qkv_kernel(off_ref, on_ref, h_ref, w_ref, e_ref,
+def _rope_qkv_kernel(on_ref, h_ref, w_ref, e_ref, cos_ref, sin_ref,
                      q_ref, k_ref, v_ref, *, n_heads: int, n_kv: int,
-                     dh: int, base: float, rope: bool, has_extra: bool):
-    """One slot: matmul -> (+ masked LoRA delta) -> split -> rotate."""
+                     dh: int, rope: bool, has_extra: bool):
+    """One slot: matmul -> (+ masked LoRA delta) -> per-head split ->
+    rotate.  Heads are static lane slices of the projection (no
+    in-kernel reshape/transpose)."""
     s = pl.program_id(0)
     hm = h_ref[0]                                      # [T, d]
-    qkv = jnp.dot(hm, w_ref[...])                      # [T, d + 2*kv_dim]
+    # f32 accumulator (the MXU's; Mosaic refuses a bf16 one), rounded to
+    # the compute dtype like the unfused Dense
+    qkv = jnp.dot(hm, w_ref[...],
+                  preferred_element_type=jnp.float32).astype(hm.dtype)
     if has_extra:
         qkv = jnp.where(on_ref[s] != 0, qkv + e_ref[0], qkv)
-    T = hm.shape[0]
-
-    def heads(t, n):                                   # [T, n*dh] -> [n, T, dh]
-        return t.reshape(T, n, dh).transpose(1, 0, 2)
-
-    qh = heads(qkv[:, : n_heads * dh], n_heads)
-    kh = heads(qkv[:, n_heads * dh: (n_heads + n_kv) * dh], n_kv)
-    vh = heads(qkv[:, (n_heads + n_kv) * dh:], n_kv)
     if rope:
-        # mirror transformer.rope_rotate bit-for-bit: f32 angles from
-        # the slot's absolute offset, GPT-NeoX half-split rotation
-        half = dh // 2
-        freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-        positions = off_ref[s].astype(jnp.float32) + jnp.arange(
-            T, dtype=jnp.float32)
-        angles = positions[:, None] * freqs[None]      # [T, half]
-        sin, cos = jnp.sin(angles), jnp.cos(angles)
+        cos, sin = cos_ref[0], sin_ref[0]              # [T, dh] f32
 
-        def rot(x):                                    # [n, T, dh]
-            x1 = x[..., :half].astype(jnp.float32)
-            x2 = x[..., half:].astype(jnp.float32)
-            return jnp.concatenate(
-                [x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                axis=-1).astype(x.dtype)
+    def emit(o_ref, first, n, rotate):
+        for i in range(n):
+            x = qkv[:, (first + i) * dh: (first + i + 1) * dh]
+            if rotate:
+                x = x.astype(jnp.float32)
+                x = x * cos + pltpu.roll(x, dh // 2, 1) * sin
+            o_ref[0, i] = x.astype(o_ref.dtype)
 
-        qh, kh = rot(qh), rot(kh)
-    q_ref[0] = qh.astype(q_ref.dtype)
-    k_ref[0] = kh.astype(k_ref.dtype)
-    v_ref[0] = vh.astype(v_ref.dtype)
+    emit(q_ref, 0, n_heads, rope)
+    emit(k_ref, n_heads, n_kv, rope)
+    emit(v_ref, n_heads + n_kv, n_kv, False)
 
 
 def fused_rope_qkv(
@@ -122,34 +124,39 @@ def fused_rope_qkv(
     has_extra = extra is not None
     if on is None:
         on = jnp.ones((S,), jnp.int32)
-    scalars = (offsets.astype(jnp.int32), on.astype(jnp.int32))
 
     def hidx(s, *_):
         return (s, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, T, d), hidx),
-        pl.BlockSpec((d, dtot), lambda s, *_: (0, 0)),
+        # one resident copy: the index never moves, so a second
+        # pipeline buffer would only double the largest VMEM tenant
+        pl.BlockSpec((d, dtot), lambda s, *_: (0, 0),
+                     pipeline_mode=pl.Buffered(1)),
     ]
-    operands = scalars + (h, w)
+    operands = [on.astype(jnp.int32), h, w]
     if has_extra:
         in_specs.append(pl.BlockSpec((1, T, dtot), hidx))
-        operands = operands + (extra,)
+        operands.append(extra)
+    if rope:
+        in_specs += [pl.BlockSpec((1, T, dh), hidx)] * 2
+        operands += rope_tables(offsets, T, dh, base)
 
-    def kernel(*refs):
-        off_ref, on_ref = refs[0], refs[1]
-        h_ref, w_ref = refs[2], refs[3]
-        e_ref = refs[4] if has_extra else None
-        outs = refs[5:] if has_extra else refs[4:]
-        _rope_qkv_kernel(off_ref, on_ref, h_ref, w_ref, e_ref, *outs,
-                         n_heads=n_heads, n_kv=n_kv, dh=dh, base=base,
+    def kernel(on_ref, h_ref, w_ref, *refs):
+        refs = list(refs)
+        e_ref = refs.pop(0) if has_extra else None
+        cos_ref, sin_ref = (refs.pop(0), refs.pop(0)) if rope else (None,
+                                                                    None)
+        _rope_qkv_kernel(on_ref, h_ref, w_ref, e_ref, cos_ref, sin_ref,
+                         *refs, n_heads=n_heads, n_kv=n_kv, dh=dh,
                          rope=rope, has_extra=has_extra)
 
     def oidx(s, *_):
         return (s, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(S,),
         in_specs=in_specs,
         out_specs=[
@@ -166,8 +173,12 @@ def fused_rope_qkv(
             jax.ShapeDtypeStruct((S, n_kv, T, dh), h.dtype),
             jax.ShapeDtypeStruct((S, n_kv, T, dh), h.dtype),
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            # the whole weight is one VMEM tenant (12 MiB in f32 at
+            # d1024): past the 16 MiB default scope, well inside the
+            # v5e's 128 MiB
+            vmem_limit_bytes=64 * 1024 * 1024,
         ),
         cost_estimate=pl.CostEstimate(
             flops=int(2 * S * T * d * dtot),
@@ -208,7 +219,11 @@ def _lora_kernel(ids_ref, x_ref, a_ref, b_ref, o_ref):
     x = x_ref[0]                                       # [T, din]
     a = a_ref[0, 0].astype(x.dtype)                    # [din, r]
     bm = b_ref[0, 0].astype(x.dtype)                   # [r, dout]
-    o_ref[0] = jnp.dot(jnp.dot(x, a), bm).astype(o_ref.dtype)
+    # f32 accumulators, each product rounded to the compute dtype like
+    # the unfused ``(x @ a) @ b``
+    xa = jnp.dot(x, a, preferred_element_type=jnp.float32).astype(x.dtype)
+    o_ref[0] = jnp.dot(xa, bm,
+                       preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
 def lora_delta(
@@ -255,7 +270,7 @@ def lora_delta(
         _lora_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, T, d_out), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         cost_estimate=pl.CostEstimate(

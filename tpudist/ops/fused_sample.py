@@ -28,52 +28,58 @@ distribution (``max(p_target - p_draft, 0)``, log with the 1e-30 floor,
 in-graph — the kernel only replaces elementwise dispatches, so the
 accept/reject decisions are bit-identical.
 
+The sort-based filters (top-k / top-p) are NOT in the kernel — Mosaic
+lowers neither ``sort`` nor ``top_k`` — they run in-graph on the
+kernel's ``scaled`` output (:func:`_filter`, shared with the reference,
+so the op order and the results are the unfused tail's).  Rows ride as
+``(1, V)`` blocks of ``[S, 1, V]`` views: a second-last block dim of 1
+must be the whole axis under Mosaic's (8, 128) block rule.
+
 ``interpret=True`` (any non-TPU backend) is the tier-1 CPU path.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 
 def _sample_kernel(temps_ref, gidx_ref, gstate_ref, lg_ref, ga_ref,
-                   masked_ref, scaled_ref, greedy_ref, *,
-                   top_k: int, top_p: float, grammar: bool):
-    """One slot: grammar mask -> greedy argmax -> temp scale -> filters."""
+                   masked_ref, scaled_ref, greedy_ref, *, grammar: bool):
+    """One slot: grammar mask -> greedy argmax -> temperature scale."""
     s = pl.program_id(0)
-    lg = lg_ref[0].astype(jnp.float32)                 # [V]
+    lg = lg_ref[0].astype(jnp.float32)                 # [1, V]
     if grammar:
-        allow = ga_ref[0, 0]                           # [V] bool
+        allow = ga_ref[0, 0]                           # [1, V] bool
         lg = jnp.where(allow, lg, jnp.finfo(jnp.float32).min)
     masked_ref[0] = lg
-    greedy_ref[0] = jnp.argmax(lg).astype(jnp.int32)
-    sc = lg / jnp.maximum(temps_ref[s], 1e-6)
+    greedy_ref[0] = jnp.argmax(lg, axis=-1, keepdims=True).astype(jnp.int32)
+    scaled_ref[0] = lg / jnp.maximum(temps_ref[s], 1e-6)
+
+
+def _filter(sc: jax.Array, top_k: int, top_p: float) -> jax.Array:
+    """Static top-k / top-p filters over scaled logits ``[S, V]``,
+    value-space semantics matching ``generate.sample_logits``."""
+    S, V = sc.shape
     neg = jnp.finfo(sc.dtype).min
-    if top_k > 0 and top_k < lg.shape[0]:
-        # value-space kth-largest cutoff — same semantics as
-        # generate.sample_logits (ties at the threshold all survive)
-        kth = jax.lax.top_k(sc, top_k)[0][-1:]
+    if top_k > 0 and top_k < V:
+        # kth-largest cutoff (ties at the threshold all survive)
+        kth = jax.lax.top_k(sc, top_k)[0][..., -1:]
         sc = jnp.where(sc < kth, neg, sc)
     if 0.0 < top_p < 1.0:
-        # nucleus in value space: smallest prefix of the sorted probs
-        # reaching top_p, the top token force-kept — mirroring
-        # generate.sample_logits's shifted-cumsum form
-        srt = jnp.sort(sc)[::-1]
-        cum = jnp.cumsum(jax.nn.softmax(srt))
-        keep = jnp.concatenate([jnp.zeros((1,), cum.dtype),
-                                cum[:-1]]) < top_p
-        keep = keep.at[0].set(True)
-        cutoff = jnp.min(jnp.where(keep, srt, jnp.inf))
+        # nucleus: smallest prefix of the sorted probs reaching top_p,
+        # the top token force-kept — the shifted-cumsum form
+        srt = jnp.sort(sc, axis=-1)[..., ::-1]
+        cum = jnp.cumsum(jax.nn.softmax(srt, axis=-1), axis=-1)
+        keep = jnp.concatenate(
+            [jnp.zeros((S, 1), cum.dtype), cum[..., :-1]], axis=-1) < top_p
+        keep = keep.at[..., 0].set(True)
+        cutoff = jnp.min(jnp.where(keep, srt, jnp.inf), axis=-1,
+                         keepdims=True)
         sc = jnp.where(sc < cutoff, neg, sc)
-    scaled_ref[0] = sc
+    return sc
 
 
 def fused_sample_prep(
@@ -97,8 +103,7 @@ def fused_sample_prep(
       point at the sentinel all-True program), or all ``None`` for no
       grammar;
     - ``top_k`` (0 = off) / ``top_p`` (0.0 = off) — static filters
-      applied to the scaled logits, value-space semantics matching
-      ``generate.sample_logits``.
+      applied in-graph to the kernel's scaled logits (:func:`_filter`).
 
     Returns ``(masked [S, V] f32, scaled [S, V] f32, greedy [S] i32)``:
     ``masked`` is the constrain-masked logits (feed to top-logprobs /
@@ -109,24 +114,28 @@ def fused_sample_prep(
     S, V = logits.shape
     grammar = gallow is not None
     temps = temps.astype(jnp.float32)
+
+    def row(s, *_):
+        return (s, 0, 0)
+
     if grammar:
         G1, n_states, _ = gallow.shape
 
         def ga_index(s, t, gi, gs):
             return (jnp.minimum(gi[s], G1 - 1),
-                    jnp.minimum(gs[s], n_states - 1), 0)
+                    jnp.minimum(gs[s], n_states - 1), 0, 0)
 
         scalars = (temps, gidx.astype(jnp.int32), gstate.astype(jnp.int32))
         in_specs = [
-            pl.BlockSpec((1, V), lambda s, *_: (s, 0)),
-            pl.BlockSpec((1, 1, V), ga_index),
+            pl.BlockSpec((1, 1, V), row),
+            pl.BlockSpec((1, 1, 1, V), ga_index),
         ]
-        operands = scalars + (logits, gallow)
+        operands = scalars + (logits[:, None], gallow[:, :, None])
     else:
         zero = jnp.zeros((S,), jnp.int32)
         scalars = (temps, zero, zero)
-        in_specs = [pl.BlockSpec((1, V), lambda s, *_: (s, 0))]
-        operands = scalars + (logits,)
+        in_specs = [pl.BlockSpec((1, 1, V), row)]
+        operands = scalars + (logits[:, None],)
 
     def kernel(*refs):
         if grammar:
@@ -137,32 +146,33 @@ def fused_sample_prep(
             ga_ref = None
             outs = refs[4:7]
         _sample_kernel(t_ref, gi_ref, gs_ref, lg_ref, ga_ref, *outs,
-                       top_k=top_k, top_p=top_p, grammar=grammar)
+                       grammar=grammar)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, V), lambda s, *_: (s, 0)),
-            pl.BlockSpec((1, V), lambda s, *_: (s, 0)),
-            pl.BlockSpec((1,), lambda s, *_: (s,)),
+            pl.BlockSpec((1, 1, V), row),
+            pl.BlockSpec((1, 1, V), row),
+            pl.BlockSpec((1, 1, 1), row),
         ],
     )
     masked, scaled, greedy = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((S, V), jnp.float32),
-            jax.ShapeDtypeStruct((S, V), jnp.float32),
-            jax.ShapeDtypeStruct((S,), jnp.int32),
+            jax.ShapeDtypeStruct((S, 1, V), jnp.float32),
+            jax.ShapeDtypeStruct((S, 1, V), jnp.float32),
+            jax.ShapeDtypeStruct((S, 1, 1), jnp.int32),
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
     )(*operands)
-    return masked, scaled, greedy
+    return (masked[:, 0], _filter(scaled[:, 0], top_k, top_p),
+            greedy[:, 0, 0])
 
 
 def fused_sample_reference(
@@ -185,30 +195,17 @@ def fused_sample_reference(
         lg = jnp.where(allow, lg, jnp.finfo(jnp.float32).min)
     greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
     sc = lg / jnp.maximum(temps.astype(jnp.float32), 1e-6)[:, None]
-    neg = jnp.finfo(sc.dtype).min
-    if top_k > 0 and top_k < V:
-        kth = jax.lax.top_k(sc, top_k)[0][..., -1:]
-        sc = jnp.where(sc < kth, neg, sc)
-    if 0.0 < top_p < 1.0:
-        srt = jnp.sort(sc, axis=-1)[..., ::-1]
-        cum = jnp.cumsum(jax.nn.softmax(srt, axis=-1), axis=-1)
-        keep = jnp.concatenate(
-            [jnp.zeros((S, 1), cum.dtype), cum[..., :-1]], axis=-1) < top_p
-        keep = keep.at[..., 0].set(True)
-        cutoff = jnp.min(jnp.where(keep, srt, jnp.inf), axis=-1,
-                         keepdims=True)
-        sc = jnp.where(sc < cutoff, neg, sc)
-    return lg, sc, greedy
+    return lg, _filter(sc, top_k, top_p), greedy
 
 
 def _residual_kernel(temps_ref, lt_ref, ld_ref, pt_ref, pd_ref, lr_ref):
     """One (slot, draft position): softmax pair + residual logits."""
     s = pl.program_id(0)
     temp = jnp.maximum(temps_ref[s], 1e-6)
-    lt = lt_ref[0, 0].astype(jnp.float32) / temp       # [V]
+    lt = lt_ref[0, 0].astype(jnp.float32) / temp       # [1, V]
     ld = ld_ref[0, 0].astype(jnp.float32) / temp
-    pt = jax.nn.softmax(lt)
-    pd = jax.nn.softmax(ld)
+    pt = jax.nn.softmax(lt, axis=-1)
+    pd = jax.nn.softmax(ld, axis=-1)
     pt_ref[0, 0] = pt
     pd_ref[0, 0] = pd
     res = jnp.maximum(pt - pd, 0.0)
@@ -236,34 +233,26 @@ def fused_residual_prep(
     temps = temps.astype(jnp.float32)
 
     def index(s, j, *_):
-        return (s, j, 0)
+        return (s, j, 0, 0)
 
+    # [S, k, 1, V] views: the (1, V) row block's dims are whole-array
+    spec = pl.BlockSpec((1, 1, 1, V), index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(S, k),
-        in_specs=[
-            pl.BlockSpec((1, 1, V), index),
-            pl.BlockSpec((1, 1, V), index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, V), index),
-            pl.BlockSpec((1, 1, V), index),
-            pl.BlockSpec((1, 1, V), index),
-        ],
+        in_specs=[spec, spec],
+        out_specs=[spec, spec, spec],
     )
-    pt, pd, lr = pl.pallas_call(
-        functools.partial(_residual_kernel),
+    outs = pl.pallas_call(
+        _residual_kernel,
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((S, k, V), jnp.float32),
-            jax.ShapeDtypeStruct((S, k, V), jnp.float32),
-            jax.ShapeDtypeStruct((S, k, V), jnp.float32),
-        ),
-        compiler_params=_CompilerParams(
+        out_shape=(jax.ShapeDtypeStruct((S, k, 1, V), jnp.float32),) * 3,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(temps, lt, ld)
+    )(temps, lt[:, :, None], ld[:, :, None])
+    pt, pd, lr = (o[:, :, 0] for o in outs)
     return pt, pd, lr
 
 

@@ -61,17 +61,33 @@ from jax.experimental.pallas import tpu as pltpu
 
 _MASK_VALUE = -1e30
 
-# jax 0.4.x names the compiler-params struct TPUCompilerParams; newer
-# releases renamed it CompilerParams.  The kernel must import under both
-# (tier-1 runs whatever the container bakes in).
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
+
+def walk_scales(scale: jax.Array, layer: int, table: jax.Array) -> jax.Array:
+    """One layer's dequant scales gathered along each slot's block table:
+    ``[L, num_blocks, n_kv]`` -> ``[S, n_kv, 1, M]`` f32.
+
+    The kernels read them as a VMEM row per (slot, kv head), so their
+    footprint follows the TABLE (slots x blocks-per-slot), not the pool:
+    the whole ``[L, num_blocks, n_kv]`` array as a scalar-prefetch
+    operand pads its last axis to 128 words and overflows the v5e's
+    1 MiB of SMEM at a pool of 1024 blocks.  The singleton axis makes
+    the ``(1, M)`` block's last two dims whole-array dims (Mosaic's
+    block rule).  Sentinel ids clamp, like the walk's own index map.
+    """
+    rows = jnp.minimum(table, scale.shape[1] - 1)
+    g = scale[layer][rows].astype(jnp.float32)         # [S, M, n_kv]
+    return jnp.swapaxes(g, 1, 2)[:, :, None, :]
 
 
-def _kernel(table_ref, pos_ref, fill_ref, sk_ref, sv_ref,
-            q_ref, pk_ref, pv_ref, wk_ref, wv_ref, o_ref,
-            m_ref, l_ref, acc_ref, *, layer: int, block_size: int,
-            s: int, quantized: bool, scale: float, window):
+def scale_at(row: jax.Array, j) -> jax.Array:
+    """Lane ``j`` of a ``[1, M]`` scale row as a scalar (exact: one live
+    term plus zeros) — a dynamic lane index Mosaic can lower."""
+    lane = lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    return jnp.sum(jnp.where(lane == j, row, 0.0))
+
+
+def _kernel(*refs, block_size: int, s: int, quantized: bool,
+            scale: float, window):
     """One (slot, kv_head, walk_step) grid step.
 
     Walk steps ``j < live(slot)`` consume pool block ``table[slot, j]``
@@ -81,8 +97,11 @@ def _kernel(table_ref, pos_ref, fill_ref, sk_ref, sv_ref,
     (``live <= j < M``) skip compute and, because their index map
     repeats the last live block, their DMA too.
     """
+    table_ref, pos_ref, fill_ref, q_ref, pk_ref, pv_ref = refs[:6]
+    sk_ref, sv_ref = refs[6:8] if quantized else (None, None)
+    wk_ref, wv_ref, o_ref, m_ref, l_ref, acc_ref = refs[-6:]
+    del table_ref                     # consumed by the index maps only
     b = pl.program_id(0)
-    h = pl.program_id(1)
     j = pl.program_id(2)
     nsteps = pl.num_programs(2)
 
@@ -117,12 +136,10 @@ def _kernel(table_ref, pos_ref, fill_ref, sk_ref, sv_ref,
         v = pv_ref[0, 0, 0]
         if quantized:
             # in-register dequant, bit-matching the gather path's
-            # ``int8.astype(compute) * scale.astype(compute)``.  j < live
-            # here, so table_ref[b, j] is a mapped id (clamp is belt
-            # only, mirroring the index map's).
-            bid = jnp.minimum(table_ref[b, j], sk_ref.shape[1] - 1)
-            k = k.astype(q.dtype) * sk_ref[layer, bid, h].astype(q.dtype)
-            v = v.astype(q.dtype) * sv_ref[layer, bid, h].astype(q.dtype)
+            # ``int8.astype(compute) * scale.astype(compute)``; lane j
+            # of the slot's gathered scale row is block table[b, j]'s
+            k = k.astype(q.dtype) * scale_at(sk_ref[0, 0], j).astype(q.dtype)
+            v = v.astype(q.dtype) * scale_at(sv_ref[0, 0], j).astype(q.dtype)
         st = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         R, bs = st.shape
         kpos = j * block_size + lax.broadcasted_iota(jnp.int32, (R, bs), 1)
@@ -185,7 +202,9 @@ def paged_attention(
       index, consumed by the index map so no per-layer slice (and no
       pool copy) is ever materialized;
     - ``scale_k``/``scale_v [L, num_blocks, n_kv]`` f32 dequant scales
-      (scalar-prefetched; ignored unless the pool is int8);
+      (gathered along the table into a VMEM row per slot — see
+      :func:`walk_scales`; not passed to the kernel at all unless the
+      pool is int8);
     - ``table [S, M]`` int32 — per-slot physical block ids (sentinel
       ``num_blocks`` = unmapped; only entries below a slot's live count
       are ever dereferenced, and the walk clamps defensively);
@@ -229,16 +248,22 @@ def paged_attention(
     def pool_index(b, h, j, *refs):
         return (layer, phys(b, j, *refs), h, 0, 0)
 
+    in_specs = [
+        pl.BlockSpec((1, 1, R, dh), q_index),
+        pl.BlockSpec((1, 1, 1, bs, dh), pool_index),
+        pl.BlockSpec((1, 1, 1, bs, dh), pool_index),
+    ]
+    operands = [table, pos0, fill, q4, pool_k, pool_v]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, 1, 1, M), q_index)] * 2
+        operands += [walk_scales(scale_k, layer, table),
+                     walk_scales(scale_v, layer, table)]
+    in_specs += [pl.BlockSpec((1, 1, W, dh), q_index)] * 2
+    operands += [wk, wv]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=3,
         grid=(S, n_kv, M + 1),
-        in_specs=[
-            pl.BlockSpec((1, 1, R, dh), q_index),
-            pl.BlockSpec((1, 1, 1, bs, dh), pool_index),
-            pl.BlockSpec((1, 1, 1, bs, dh), pool_index),
-            pl.BlockSpec((1, 1, W, dh), q_index),
-            pl.BlockSpec((1, 1, W, dh), q_index),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, R, dh), q_index),
         scratch_shapes=[
             pltpu.VMEM((R, 1), jnp.float32),   # m (running row max)
@@ -247,7 +272,7 @@ def paged_attention(
         ],
     )
     kernel = functools.partial(
-        _kernel, layer=layer, block_size=bs, s=s, quantized=quantized,
+        _kernel, block_size=bs, s=s, quantized=quantized,
         scale=dh ** -0.5, window=window)
     # Upper-bound cost for the XLA scheduler: a full walk touches every
     # table entry plus the window (live-KV elision only shrinks it).
@@ -256,7 +281,7 @@ def paged_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, n_kv, R, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -267,7 +292,7 @@ def paged_attention(
                  + q4.size) * q.dtype.itemsize),
         ),
         interpret=interpret,
-    )(table, pos0, fill, scale_k, scale_v, q4, pool_k, pool_v, wk, wv)
+    )(*operands)
     return out.reshape(S, nh, s, dh)
 
 

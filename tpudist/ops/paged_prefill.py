@@ -36,9 +36,10 @@ polluted by another lane's garbage — slightly better int8 numerics than
 the gather path's dense round-trip, same masking contract.
 
 ``interpret=True`` (any non-TPU backend) is the tier-1 CPU path.  The
-scale outputs use rank-3 ``(1, 1, 1)`` blocks, fine under the
-interpreter; native lowering keeps them in VMEM (revisit as SMEM
-outputs if a real-TPU run objects).
+dequant scales ride in, and the fresh scales ride out, as one VMEM row
+per (slot, kv head) — ``[S, n_kv, 1, M]`` / ``[S, n_kv, 1, Mw]``, see
+:func:`tpudist.ops.paged_attention.walk_scales` — and only for an int8
+pool: a float pool passes and emits no scales at all.
 """
 
 from __future__ import annotations
@@ -51,18 +52,13 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudist.ops.paged_attention import scale_at, walk_scales
+
 _MASK_VALUE = -1e30
 
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
 
-
-def _kernel(table_ref, wtable_ref, pos_ref, clen_ref, sk_ref, sv_ref,
-            q_ref, kn_ref, vn_ref, pk_ref, pv_ref,
-            o_ref, ok_ref, ov_ref, osk_ref, osv_ref,
-            m_ref, l_ref, acc_ref, *, layer: int, block_size: int,
-            chunk: int, n_prefix: int, quantized: bool, scale: float,
-            window):
+def _kernel(*refs, block_size: int, chunk: int, n_prefix: int,
+            quantized: bool, scale: float, window):
     """One (slot, kv_head, step) grid step.
 
     Steps ``j < live(slot)`` walk the prefix out of the pool;
@@ -72,8 +68,16 @@ def _kernel(table_ref, wtable_ref, pos_ref, clen_ref, sk_ref, sv_ref,
     re-aimed by the shared index map), overlays the chunk's K/V, and
     emits the requantized block + scale.
     """
+    pos_ref, clen_ref, q_ref, kn_ref, vn_ref, pk_ref, pv_ref = refs[2:9]
+    if quantized:
+        # walk scales [1, 1, 1, M], touched blocks' scales [1, 1, 1, Mw]
+        sk_ref, sv_ref, swk_ref, swv_ref = refs[9:13]
+        o_ref, ok_ref, ov_ref, osk_ref, osv_ref = refs[13:18]
+    else:
+        sk_ref = sv_ref = swk_ref = swv_ref = osk_ref = osv_ref = None
+        o_ref, ok_ref, ov_ref = refs[9:12]
+    m_ref, l_ref, acc_ref = refs[-3:]
     b = pl.program_id(0)
-    h = pl.program_id(1)
     j = pl.program_id(2)
     bs = block_size
     P = chunk
@@ -111,9 +115,8 @@ def _kernel(table_ref, wtable_ref, pos_ref, clen_ref, sk_ref, sv_ref,
         k = pk_ref[0, 0, 0]                   # [bs, dh] storage dtype
         v = pv_ref[0, 0, 0]
         if quantized:
-            bid = jnp.minimum(table_ref[b, j], sk_ref.shape[1] - 1)
-            k = k.astype(q.dtype) * sk_ref[layer, bid, h].astype(q.dtype)
-            v = v.astype(q.dtype) * sv_ref[layer, bid, h].astype(q.dtype)
+            k = k.astype(q.dtype) * scale_at(sk_ref[0, 0], j).astype(q.dtype)
+            v = v.astype(q.dtype) * scale_at(sv_ref[0, 0], j).astype(q.dtype)
         st = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         R, _ = st.shape
         kpos = j * bs + lax.broadcasted_iota(jnp.int32, (R, bs), 1)
@@ -150,7 +153,6 @@ def _kernel(table_ref, wtable_ref, pos_ref, clen_ref, sk_ref, sv_ref,
         # outside [pos0, pos0 + clen) keep the ORIGINAL block contents
         # (chunked prefill's partial first block; untouched tail).
         w = j - (M + 1)
-        bid = jnp.minimum(wtable_ref[b, w], sk_ref.shape[1] - 1)
         blk0 = (lax.div(pos0, bs) + w) * bs
         kpos = blk0 + lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
         in_new = (kpos >= pos0) & (kpos < pos0 + cl)
@@ -164,8 +166,8 @@ def _kernel(table_ref, wtable_ref, pos_ref, clen_ref, sk_ref, sv_ref,
             orig = pool_ref[0, 0, 0]          # [bs, dh] storage dtype
             cdtype = chunk_ref.dtype
             if quantized:
-                orig = orig.astype(cdtype) * sc_ref[layer, bid, h].astype(
-                    cdtype)
+                orig = orig.astype(cdtype) * scale_at(
+                    sc_ref[0, 0], w).astype(cdtype)
             else:
                 orig = orig.astype(cdtype)
             new = jnp.dot(selm, chunk_ref[0, 0].astype(jnp.float32),
@@ -177,13 +179,17 @@ def _kernel(table_ref, wtable_ref, pos_ref, clen_ref, sk_ref, sv_ref,
                 sc = jnp.where(amax > 0, amax / 127.0, 1.0)
                 oq_ref[0, 0, 0] = jnp.clip(
                     jnp.round(v32 / sc), -127, 127).astype(oq_ref.dtype)
-                osc_ref[0, 0, 0] = sc
+                # the (slot, head)'s scale row stays resident across the
+                # write steps; step w fills lane w (every lane is
+                # written — all Mw steps always run)
+                row = osc_ref[0, 0]
+                lane = lax.broadcasted_iota(jnp.int32, row.shape, 1)
+                osc_ref[0, 0] = jnp.where(lane == w, sc, row)
             else:
                 oq_ref[0, 0, 0] = merged.astype(oq_ref.dtype)
-                osc_ref[0, 0, 0] = 1.0
 
-        emit(kn_ref, pk_ref, sk_ref, ok_ref, osk_ref)
-        emit(vn_ref, pv_ref, sv_ref, ov_ref, osv_ref)
+        emit(kn_ref, pk_ref, swk_ref, ok_ref, osk_ref)
+        emit(vn_ref, pv_ref, swv_ref, ov_ref, osv_ref)
 
 
 def paged_prefill_attention(
@@ -254,26 +260,39 @@ def paged_prefill_attention(
     def wblock_index(b, h, j, *_):
         return (b, jnp.clip(j - (M + 1), 0, Mw - 1), h, 0, 0)
 
-    def wscale_index(b, h, j, *_):
-        return (b, jnp.clip(j - (M + 1), 0, Mw - 1), h)
-
+    storage = pool_k.dtype
+    in_specs = [
+        pl.BlockSpec((1, 1, R, dh), chunk_index),   # q4
+        pl.BlockSpec((1, 1, P, dh), chunk_index),   # k_new
+        pl.BlockSpec((1, 1, P, dh), chunk_index),   # v_new
+        pl.BlockSpec((1, 1, 1, bs, dh), pool_index),
+        pl.BlockSpec((1, 1, 1, bs, dh), pool_index),
+    ]
+    operands = [table, wtable, pos0, clen, q4, k_new, v_new, pool_k, pool_v]
+    out_specs = [
+        pl.BlockSpec((1, 1, R, dh), chunk_index),
+        pl.BlockSpec((1, 1, 1, bs, dh), wblock_index),
+        pl.BlockSpec((1, 1, 1, bs, dh), wblock_index),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((S, n_kv, R, dh), q.dtype),
+        jax.ShapeDtypeStruct((S, Mw, n_kv, bs, dh), storage),
+        jax.ShapeDtypeStruct((S, Mw, n_kv, bs, dh), storage),
+    ]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, 1, 1, M), chunk_index)] * 2
+        in_specs += [pl.BlockSpec((1, 1, 1, Mw), chunk_index)] * 2
+        operands += [walk_scales(scale_k, layer, table),
+                     walk_scales(scale_v, layer, table),
+                     walk_scales(scale_k, layer, wtable),
+                     walk_scales(scale_v, layer, wtable)]
+        out_specs += [pl.BlockSpec((1, 1, 1, Mw), chunk_index)] * 2
+        out_shape += [jax.ShapeDtypeStruct((S, n_kv, 1, Mw), jnp.float32)] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=4,
         grid=(S, n_kv, M + 1 + Mw),
-        in_specs=[
-            pl.BlockSpec((1, 1, R, dh), chunk_index),   # q4
-            pl.BlockSpec((1, 1, P, dh), chunk_index),   # k_new
-            pl.BlockSpec((1, 1, P, dh), chunk_index),   # v_new
-            pl.BlockSpec((1, 1, 1, bs, dh), pool_index),
-            pl.BlockSpec((1, 1, 1, bs, dh), pool_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, R, dh), chunk_index),
-            pl.BlockSpec((1, 1, 1, bs, dh), wblock_index),
-            pl.BlockSpec((1, 1, 1, bs, dh), wblock_index),
-            pl.BlockSpec((1, 1, 1), wscale_index),
-            pl.BlockSpec((1, 1, 1), wscale_index),
-        ],
+        in_specs=in_specs,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((R, 1), jnp.float32),   # m (running row max)
             pltpu.VMEM((R, 1), jnp.float32),   # l (running normalizer)
@@ -281,21 +300,14 @@ def paged_prefill_attention(
         ],
     )
     kernel = functools.partial(
-        _kernel, layer=layer, block_size=bs, chunk=P, n_prefix=M,
+        _kernel, block_size=bs, chunk=P, n_prefix=M,
         quantized=quantized, scale=dh ** -0.5, window=window)
     work = S * n_kv * R * (M * bs + P)
-    storage = pool_k.dtype
-    o, qk, qv, sk, sv = pl.pallas_call(
+    outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((S, n_kv, R, dh), q.dtype),
-            jax.ShapeDtypeStruct((S, Mw, n_kv, bs, dh), storage),
-            jax.ShapeDtypeStruct((S, Mw, n_kv, bs, dh), storage),
-            jax.ShapeDtypeStruct((S, Mw, n_kv), jnp.float32),
-            jax.ShapeDtypeStruct((S, Mw, n_kv), jnp.float32),
-        ),
-        compiler_params=_CompilerParams(
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
@@ -307,8 +319,13 @@ def paged_prefill_attention(
                  + 2 * S * Mw * n_kv * bs * dh) * q.dtype.itemsize),
         ),
         interpret=interpret,
-    )(table, wtable, pos0, clen, scale_k, scale_v,
-      q4, k_new, v_new, pool_k, pool_v)
+    )(*operands)
+    o, qk, qv = outs[:3]
+    if quantized:
+        # [S, n_kv, 1, Mw] rows -> the commit's [S, Mw, n_kv]
+        sk, sv = (jnp.swapaxes(x[:, :, 0, :], 1, 2) for x in outs[3:])
+    else:
+        sk = sv = jnp.ones((S, Mw, n_kv), jnp.float32)
     return o.reshape(S, nh, P, dh), qk, qv, sk, sv
 
 

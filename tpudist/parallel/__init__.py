@@ -42,7 +42,6 @@ from tpudist.parallel.overlap import (  # noqa: F401
     OVERLAP_MODES,
     OVERLAP_SCOPE,
     ag_matmul,
-    compat_shard_map,
     matmul_rs,
     overlap_mode,
 )

@@ -142,8 +142,7 @@ def overlap_fsdp_mlp(
 
     ``activation`` defaults to the Block's ``gelu``.
     """
-    from tpudist.parallel.overlap import (ag_matmul, compat_shard_map,
-                                          overlap_mode)
+    from tpudist.parallel.overlap import ag_matmul, overlap_mode
 
     mode = overlap_mode(overlap)
     if mode == "off":
@@ -163,12 +162,11 @@ def overlap_fsdp_mlp(
         return y.reshape(b_loc, s, d).astype(x.dtype)
 
     param_specs = {"wi": P(None, axis_name), "wo": P(axis_name, None)}
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, P(axis_name, None, None)),
-        out_specs=P(axis_name, None, None),
-    )
+        out_specs=P(axis_name, None, None), check_vma=False)
 
     def mlp_fn(params, x):
         return sharded(params, x)

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpudist.parallel.overlap import (compat_axis_size,
-                                     compat_shard_map)
 from tpudist.runtime.mesh import AXIS_MODEL
 
 # ExpertFn: (expert_params, tokens [slots, d]) -> [slots, d]
@@ -109,7 +107,7 @@ def moe_shard(
     (n_experts == axis size); ``k`` routes each token to its top-k experts
     (capacity scales with k so the fair share per expert is unchanged).
     """
-    n_experts = compat_axis_size(axis_name)
+    n_experts = lax.axis_size(axis_name)
     tokens = x.shape[0]
     capacity = int(capacity_factor * k * tokens / n_experts + 0.5)
 
@@ -169,10 +167,9 @@ def make_moe(
         return out, stats
 
     param_specs = {"router": P(), "experts": P(axis_name)}
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, P(batch_axis, None)),
-        out_specs=(P(batch_axis, None), MoEStats(P(), P(), P())),
-    )
+        out_specs=(P(batch_axis, None), MoEStats(P(), P(), P())), check_vma=False)
     return jax.jit(sharded)

@@ -102,44 +102,6 @@ def overlap_mode(override: str | None = None) -> str:
     return "off"
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` on jax >= 0.9 (``check_vma``), falling back to
-    ``jax.experimental.shard_map`` (``check_rep``) on the older API —
-    the overlap layer stays importable and TESTABLE on both, unlike the
-    rep-check kwarg soup it papers over.  ``check_vma`` maps onto
-    ``check_rep`` on the fallback — which stays ``False`` regardless:
-    the old rep-checker has no ``pcast`` escape hatch, so bodies whose
-    carries legitimately become varying (ppermute rotations) cannot be
-    typed under it.  ``check_vma`` is honored only where the new vma
-    checker exists."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def compat_pcast(x, axes, *, to):
-    """``lax.pcast`` where the vma type system exists; identity on the
-    older API, whose shard_map (run with ``check_rep=False`` — see
-    :func:`compat_shard_map`) has no varying-axes types to cast
-    between."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to=to)
-    return x
-
-
-def compat_axis_size(axis_name: str) -> int:
-    """``lax.axis_size`` where the API has it, else the ``psum(1)``
-    fold (static Python int either way — callers unroll chains with
-    it, so it must never be a tracer)."""
-    if hasattr(lax, "axis_size"):
-        return int(lax.axis_size(axis_name))
-    return int(lax.psum(1, axis_name))
-
-
 def _ring_perm(n: int, shift: int):
     """source_target pairs moving every shard ``shift`` ranks around the
     ring (shift=+1: rank r's shard lands on rank r+1)."""
@@ -385,7 +347,6 @@ __all__ = [
     "OVERLAP_MODES",
     "OVERLAP_SCOPE",
     "ag_matmul",
-    "compat_shard_map",
     "matmul_rs",
     "overlap_mode",
 ]

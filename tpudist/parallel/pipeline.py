@@ -32,8 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpudist.parallel.overlap import (compat_axis_size,
-                                     compat_shard_map)
 from tpudist.runtime.mesh import AXIS_STAGE
 
 # StageFn: (stage_params, activation [micro_batch, d]) -> activation
@@ -153,7 +151,7 @@ def pipeline_shard(
         # the tick *boundaries* the scan already carries — the memory
         # property a hand-scheduled 1F1B buys, obtained compiler-side.
         stage_fn = jax.checkpoint(stage_fn)
-    n_stages = compat_axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     my_stage = lax.axis_index(axis_name)
     num_micro = x_microbatches.shape[0]
     micro_shape = x_microbatches.shape[1:]
@@ -264,7 +262,7 @@ def pipeline_1f1b_shard(
     contribution, psum-replicated).
     """
     p = jax.tree.map(lambda a: a[0], stage_params)
-    n_stages = compat_axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     my_stage = lax.axis_index(axis_name)
     last = n_stages - 1
     num_micro = x_microbatches.shape[0]
@@ -393,12 +391,11 @@ def make_pipeline(
         # Leading stage axis on the output; slicing the last block makes
         # XLA move one stage's data (a broadcast from the final stage)
         # instead of all-reducing zeros from every other stage.
-        out = compat_shard_map(
+        out = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(axis_name), P()),
-            out_specs=P(axis_name),
-        )(stage_params, xm)
+            out_specs=P(axis_name), check_vma=False)(stage_params, xm)
         out = out[-1]
         return out.reshape((num_micro * micro,) + out.shape[2:])
 

@@ -57,7 +57,6 @@ import numpy as np
 from jax import lax
 
 from tpudist.parallel.pipeline import head_grad_branches
-from tpudist.parallel.overlap import compat_axis_size
 from tpudist.runtime.mesh import AXIS_STAGE
 
 _INF = 10**9
@@ -337,9 +336,9 @@ def pipeline_interleaved_shard(
     """
     D = schedule.n_dev
     V = schedule.n_chunks
-    if compat_axis_size(axis_name) != D:
+    if lax.axis_size(axis_name) != D:
         raise ValueError(f"schedule built for {D} devices, axis "
-                         f"{axis_name!r} has {compat_axis_size(axis_name)}")
+                         f"{axis_name!r} has {lax.axis_size(axis_name)}")
     my = lax.axis_index(axis_name)
     num_micro = schedule.n_micro
     if x_microbatches.shape[0] != num_micro:

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpudist.parallel.overlap import compat_shard_map
 from tpudist.parallel.pipeline import pipeline_1f1b_shard, pipeline_shard
 from tpudist.runtime.mesh import AXIS_DATA, AXIS_STAGE
 
@@ -233,12 +232,11 @@ def make_pp_lm_apply(
                 sp, xmb, stage_fn=stage_fn, axis_name=axis_name, remat=remat
             )[None]
 
-        out = compat_shard_map(
+        out = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(P(axis_name), data_in_spec),
-            out_specs=out_spec,
-        )(pp_params["blocks"], xm)
+            out_specs=out_spec, check_vma=False)(pp_params["blocks"], xm)
         # Last stage's block only — one stage's data moves, not a psum of
         # the whole [n_stages, ...] stack.
         x = out[-1].reshape(b, s, d)
@@ -343,12 +341,11 @@ def make_pp_lm_train_step(
                 loss_fn=micro_loss, axis_name=axis_name, data_axis=data_axis,
             )
 
-    sharded_body = compat_shard_map(
+    sharded_body = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name), P(), data_in_spec, data_in_spec),
-        out_specs=(P(), P(axis_name), P(), data_in_spec),
-    )
+        out_specs=(P(), P(axis_name), P(), data_in_spec), check_vma=False)
 
     def step(state: ModelState, tokens):
         pp_params = state.params

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpudist.parallel.overlap import (compat_axis_size,
-                                     compat_pcast, compat_shard_map)
 from tpudist.runtime.mesh import AXIS_SEQ
 
 # Finite stand-in for -inf: keeps exp() NaN-free when a whole row is masked
@@ -131,7 +129,7 @@ def ring_attention_shard(
     drops from O(shard²) to O(shard·inner_block), which is what lets very
     long shards (many thousands of tokens per chip) train.
     """
-    axis_size = compat_axis_size(axis_name)
+    axis_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     scale = q.shape[-1] ** -0.5
     block = q.shape[-2]
@@ -146,11 +144,11 @@ def ring_attention_shard(
     # pcast-to-varying: the carries join a scan whose outputs vary over the
     # seq axis (they mix in the sharded q/k/v), so the initial values must
     # carry the same varying-manual-axes type.
-    m = compat_pcast(jnp.full(q.shape[:-1], _MASK_VALUE, jnp.float32),
+    m = lax.pcast(jnp.full(q.shape[:-1], _MASK_VALUE, jnp.float32),
                   (axis_name,), to="varying")
-    l = compat_pcast(jnp.zeros(q.shape[:-1], jnp.float32),
+    l = lax.pcast(jnp.zeros(q.shape[:-1], jnp.float32),
                   (axis_name,), to="varying")
-    o = compat_pcast(jnp.zeros(q.shape, jnp.float32), (axis_name,), to="varying")
+    o = lax.pcast(jnp.zeros(q.shape, jnp.float32), (axis_name,), to="varying")
     q_off = my_idx * block
 
     if window is not None:
@@ -276,7 +274,7 @@ def ring_attention_shard_flash(
             q, k, v, axis_name=axis_name, causal=causal, window=window
         )
 
-    axis_size = compat_axis_size(axis_name)
+    axis_size = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
 
     # Hop 0 is this device's own (diagonal) KV shard: causal kernel
@@ -378,7 +376,7 @@ def make_ring_attention(
             ring_attention_shard, axis_name=axis_name, causal=causal,
             inner_block=inner_block, window=window,
         )
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         lambda q, k, v: body(q, k, v),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -459,7 +457,7 @@ def ring_attention_shard_zigzag(
     sliding windows not supported — the window's early-exit already
     rebalances the contiguous ring.
     """
-    axis_size = compat_axis_size(axis_name)
+    axis_size = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     scale = q.shape[-1] ** -0.5
     shard = q.shape[-2]
@@ -475,11 +473,11 @@ def ring_attention_shard_zigzag(
 
     def fresh(qb):
         return (
-            compat_pcast(jnp.full(qb.shape[:-1], _MASK_VALUE, jnp.float32),
+            lax.pcast(jnp.full(qb.shape[:-1], _MASK_VALUE, jnp.float32),
                       (axis_name,), to="varying"),
-            compat_pcast(jnp.zeros(qb.shape[:-1], jnp.float32),
+            lax.pcast(jnp.zeros(qb.shape[:-1], jnp.float32),
                       (axis_name,), to="varying"),
-            compat_pcast(jnp.zeros(qb.shape, jnp.float32),
+            lax.pcast(jnp.zeros(qb.shape, jnp.float32),
                       (axis_name,), to="varying"),
         )
 
@@ -552,7 +550,7 @@ def make_zigzag_ring_attention(
     the loss — a per-position mean — needs no unpermute.
     """
     spec = P(batch_axis, None, axis_name, None)
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         functools.partial(ring_attention_shard_zigzag, axis_name=axis_name),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -133,7 +133,7 @@ def make_tp_mlp(
     model axis carries the batch pipeline) and a batch divisible by the
     axis size.
     """
-    from tpudist.parallel.overlap import compat_shard_map, overlap_mode
+    from tpudist.parallel.overlap import overlap_mode
 
     mode = overlap_mode(overlap)
     param_specs = {
@@ -149,21 +149,19 @@ def make_tp_mlp(
                 "axis; batch_axis must be None")
         body = functools.partial(tp_mlp_overlap_shard, axis_name=axis_name,
                                  activation=activation, mode=mode)
-        sharded = compat_shard_map(
+        sharded = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(param_specs, P(axis_name, None)),
-            out_specs=P(axis_name, None),
-        )
+            out_specs=P(axis_name, None), check_vma=False)
         return jax.jit(sharded)
     body = functools.partial(tp_mlp_shard, axis_name=axis_name,
                              activation=activation)
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, P(batch_axis, None)),
-        out_specs=P(batch_axis, None),
-    )
+        out_specs=P(batch_axis, None), check_vma=False)
     return jax.jit(sharded)
 
 

@@ -1,7 +1,7 @@
 """Hang watchdog: convert a wedged step into a fast, diagnosable restart.
 
 A hung collective (one rank dead in a way the coordination service hasn't
-noticed, a deadlocked host callback, a wedged device tunnel) leaves every
+noticed, a deadlocked host callback, a stuck device runtime) leaves every
 process alive but advancing nothing — the worst failure mode on a managed
 allocation, because ``tpurun``'s restart loop only reacts to *exits* and
 the scheduler only reclaims the job at its own (hour-scale) timeout.

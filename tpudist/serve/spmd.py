@@ -290,7 +290,7 @@ def serve_overlap_mlp_fn(mesh, *, axis_name: str = AXIS_MODEL,
     """
     from jax.sharding import PartitionSpec as P
 
-    from tpudist.parallel.overlap import ag_matmul, compat_shard_map
+    from tpudist.parallel.overlap import ag_matmul
 
     if mode not in ("ring", "bidir"):
         return None
@@ -308,10 +308,10 @@ def serve_overlap_mlp_fn(mesh, *, axis_name: str = AXIS_MODEL,
         return y.reshape(b, s, d).astype(x.dtype)
 
     param_specs = {"wi": P(None, axis_name), "wo": P(None, axis_name)}
-    sharded = compat_shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_specs, P(None, None, None)),
-        out_specs=P(None, None, None))
+        out_specs=P(None, None, None), check_vma=False)
 
     def mlp_fn(params, x):
         return sharded(params, x)

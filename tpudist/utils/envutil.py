@@ -287,7 +287,7 @@ ENV_VARS = {
     "TPUDIST_OVERLAP":
         "collective-matmul overlap mode: off|ring|bidir (default off)",
     # caches / tuned constants
-    "TPUDIST_COMPILATION_CACHE": "persistent XLA compile cache dir (off = disable)",
+    "TPUDIST_COMPILATION_CACHE": "off = disable the persistent XLA compile cache (placed by JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache)",
     "TPUDIST_CACHE": "native data-loader build cache base dir",
     "TPUDIST_TUNED_FILE": "measured tuned-constants JSON path override",
     "TPUDIST_SYNC_EVERY": "train-loop scan window / metric sync cadence",
