@@ -145,34 +145,61 @@ def _abstract_lm(w, tx):
     return made, jax.eval_shape(init)
 
 
-def test_d1024_train_step_compiles_with_the_flash_kernel(topo, monkeypatch):
-    """The whole train step of ``chip_smoke.py``'s first phase, from
-    ``jax.eval_shape`` shapes.  The model picks its attention by asking
-    JAX for the platform, so the test (not a new option of the program)
-    answers with the described devices."""
+@pytest.fixture(scope="module")
+def d1024_step(topo):
+    """The whole train step of ``chip_smoke.py``'s first phase, compiled
+    once from ``jax.eval_shape`` shapes.  The model picks its attention by
+    asking JAX for the platform, so the test (not a new option of the
+    program) answers with the described devices."""
     import optax
 
     from tpudist.runtime.mesh import MeshConfig, make_mesh
     from tpudist.train import make_lm_train_step, token_sharding
 
-    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
-    mesh = make_mesh(MeshConfig(data=1), devices=topo.devices[:1])
-    repl = NamedSharding(mesh, PartitionSpec())
-    tx = optax.adam(1e-3)
-    made, abstract = _abstract_lm(W, tx)
-    state = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
-        abstract)
-    tokens = jax.ShapeDtypeStruct((W.batch, W.seq), jnp.int32,
-                                  sharding=token_sharding(mesh))
-    compiled = make_lm_train_step(made["module"].apply, tx, mesh).lower(
-        state, tokens).compile()
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+        mesh = make_mesh(MeshConfig(data=1), devices=topo.devices[:1])
+        repl = NamedSharding(mesh, PartitionSpec())
+        tx = optax.adam(1e-3)
+        made, abstract = _abstract_lm(W, tx)
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+            abstract)
+        tokens = jax.ShapeDtypeStruct((W.batch, W.seq), jnp.int32,
+                                      sharding=token_sharding(mesh))
+        return make_lm_train_step(made["module"].apply, tx, mesh).lower(
+            state, tokens).compile()
+
+
+def test_d1024_train_step_compiles_with_the_flash_kernel(d1024_step):
     # one forward + two backward kernels per layer, nothing gave way to
     # the blockwise XLA formulation
-    assert compiled.as_text().count("tpu_custom_call") == 3 * W.n_layers
+    assert d1024_step.as_text().count("tpu_custom_call") == 3 * W.n_layers
     # params + Adam moments + activations fit the chip's 16 GB of HBM
-    mem = compiled.memory_analysis()
+    mem = d1024_step.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 15e9
+
+
+def _kernels_named(text: str) -> list:
+    """The program's name of every Mosaic custom call of a compiled text,
+    read where a device trace carries it: the call's ``kernel_metadata``
+    (``pallas_call(metadata=)``), whatever the instruction is called."""
+    import re
+
+    return re.findall(
+        r'custom_call_target="tpu_custom_call"[^\n]*'
+        r'kernel_metadata=\{\s*"kernel"\s*:\s*"([^"]+)"', text)
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"])
+def test_each_flash_kernel_is_named_once_a_layer(d1024_step, kernel):
+    from tpudist.telemetry import names
+
+    assert kernel in names.FLASH_KERNELS
+    named = _kernels_named(d1024_step.as_text())
+    assert len(named) == 3 * W.n_layers   # no custom call goes unnamed
+    assert named.count(kernel) == W.n_layers
 
 
 def test_four_chip_fsdp_step_keeps_the_flash_kernel(topo, monkeypatch):
@@ -206,3 +233,7 @@ def test_four_chip_fsdp_step_keeps_the_flash_kernel(topo, monkeypatch):
             state, tokens).compile().as_text()
     assert text.count("tpu_custom_call") == 3 * w.n_layers
     assert "all-gather" in text and "reduce-scatter" in text
+    # under the per-shard wrapper the instructions are ``shard_map.<n>``;
+    # the kernel's own name still rides in its metadata
+    assert sorted(_kernels_named(text)) == sorted(
+        ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"] * w.n_layers)

@@ -1,0 +1,346 @@
+"""The one vocabulary of names (``tpudist/telemetry/names.py``) where the
+program writes it: scopes in the lowered LM step, spans on the profiler's
+clock, the step helper's arrival-to-arrival ``step`` spans, and the compile
+listener's spans and events.  (The kernel names in a compiled TPU step are
+checked in ``test_chip_compile.py``, the one file that describes a chip.)"""
+
+import re
+import subprocess
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from tpudist import telemetry
+from tpudist.telemetry import names
+from tpudist.train.loop import StepSpans
+
+
+# ---------------------------------------------------------------------------
+# (a) scopes in the lowered step
+
+
+@pytest.fixture(scope="module")
+def step_op_names():
+    """Every ``op_name`` of a tiny ``make_lm_train_step`` lowered on CPU."""
+    from tpudist.models import create_transformer
+    from tpudist.runtime.mesh import MeshConfig, make_mesh
+    from tpudist.train import init_lm_state, make_lm_train_step
+
+    module, params = create_transformer(
+        jax.random.PRNGKey(0), seq_len=16, vocab=64, d_model=32, n_layers=2,
+        n_heads=2, d_ff=64)
+    tx = optax.adam(1e-3)
+    mesh = make_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    step = make_lm_train_step(module.apply, tx, mesh, accum_steps=2)
+    lowered = step.lower(init_lm_state(params, tx),
+                         jnp.zeros((4, 16), jnp.int32))
+    # (inside the accumulation scan's body the names start afresh, without
+    # the ``jit(step)/`` prefix, so every location string is kept)
+    return set(re.findall(r'loc\("([^"]+)"',
+                          lowered.as_text(debug_info=True)))
+
+
+def _under(scope: str, op_name: str) -> bool:
+    return re.search(rf"(^|[/(]){scope}([/)]|$)", op_name) is not None
+
+
+@pytest.mark.parametrize("scope", names.SCOPES)
+def test_the_lowered_step_carries_every_scope(step_op_names, scope):
+    assert [n for n in step_op_names if _under(scope, n)], scope
+
+
+def test_a_backward_op_carries_the_transpose_mark_and_its_sublayer(
+        step_op_names):
+    for scope in (names.ATTN, names.MLP, names.EMBED, names.HEAD,
+                  names.LOSS):
+        assert [n for n in step_op_names
+                if names.BACKWARD_MARK in n and _under(scope, n)], scope
+    # the optimizer is not differentiated: no transposed op under it
+    assert not [n for n in step_op_names
+                if names.BACKWARD_MARK in n and _under(names.OPTIMIZER, n)]
+
+
+def test_scopes_do_not_change_what_the_step_computes():
+    """Scopes are metadata: the same state and tokens give the same loss
+    and update with ``jax.named_scope`` turned into a no-op."""
+    import contextlib
+    from unittest import mock
+
+    from tpudist.models import create_transformer
+    from tpudist.runtime.mesh import MeshConfig, make_mesh
+    from tpudist.train import init_lm_state, make_lm_train_step
+
+    module, params = create_transformer(
+        jax.random.PRNGKey(1), seq_len=8, vocab=32, d_model=16, n_layers=1,
+        n_heads=2, d_ff=32)
+    tx = optax.adam(1e-2)
+    mesh = make_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    tokens = jnp.arange(16, dtype=jnp.int32).reshape(2, 8) % 32
+
+    def one_step():
+        step = make_lm_train_step(module.apply, tx, mesh, donate_state=False)
+        return step(init_lm_state(params, tx), tokens)
+
+    state_a, loss_a = one_step()
+    with mock.patch.object(jax, "named_scope",
+                           lambda name: contextlib.nullcontext()):
+        state_b, loss_b = one_step()
+    assert float(loss_a) == float(loss_b)
+    for a, b in zip(jax.tree.leaves(state_a), jax.tree.leaves(state_b)):
+        assert jnp.array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
+# (c) spans on the profiler's clock; the package without jax
+
+
+def test_a_span_shows_on_the_host_rows_of_a_profiler_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    telemetry.start(tmp_path / "tele", rank=0, generation=0)
+    try:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path / "trace"),
+                                 profiler_options=options)
+        try:
+            with telemetry.span("x_marks_the_span"):
+                with telemetry.span(names.DISPATCH):
+                    jnp.ones((8, 8)).sum().block_until_ready()
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        telemetry.finish(write_report=False)
+    (path,) = (tmp_path / "trace").glob("plugins/profile/*/*.xplane.pb")
+    host = [p for p in ProfileData.from_file(str(path)).planes
+            if p.name == "/host:CPU"]
+    events = {e.name: e for line in host[0].lines for e in line.events
+              if e.name in ("x_marks_the_span", names.DISPATCH)}
+    assert set(events) == {"x_marks_the_span", names.DISPATCH}
+    outer, inner = events["x_marks_the_span"], events[names.DISPATCH]
+    assert outer.start_ns <= inner.start_ns
+    assert (inner.start_ns + inner.duration_ns
+            <= outer.start_ns + outer.duration_ns)
+
+
+@pytest.mark.parametrize("module", ["tpudist.telemetry",
+                                    "tpudist.telemetry.names"])
+def test_the_telemetry_package_still_does_not_import_jax(module):
+    """Importing it does not, and neither does a span's look for the
+    profiler's annotation: without jax it is the shared no-op."""
+    code = (f"import sys, {module}; "
+            "from tpudist.telemetry import spans; "
+            "assert spans._trace_annotation('x') is spans._NULL_SPAN; "
+            "assert 'jax' not in sys.modules, 'jax was imported'")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+# ---------------------------------------------------------------------------
+# (d) the step helper
+
+
+class FakeDevice:
+    """Runs one step at a time, ``step_s`` each; a result is ready when its
+    step has finished, and waiting for it is counted."""
+
+    def __init__(self, step_s: float):
+        self.step_s, self.free_at, self.waits = step_s, 0.0, 0
+
+    def step(self, state, _batch):
+        self.free_at = max(self.free_at, time.monotonic()) + self.step_s
+        return state + 1, FakeResult(self, self.free_at)
+
+
+class FakeResult:
+    def __init__(self, device, ready_at):
+        self.device, self.ready_at = device, ready_at
+
+    def block_until_ready(self):
+        self.device.waits += 1
+        time.sleep(max(0.0, self.ready_at - time.monotonic()))
+        return self
+
+
+def _spans(session, name):
+    return [r for r in session.ring
+            if r["kind"] == "span" and r["name"] == name]
+
+
+def test_step_spans_sum_to_the_loops_wall_time_and_dispatch_does_not(
+        tmp_path):
+    session = telemetry.start(tmp_path, rank=0, generation=0)
+    try:
+        device, state, n = FakeDevice(0.02), 0, 25
+        t0 = time.monotonic()
+        with StepSpans(session) as steps:
+            for i in range(n):
+                with session.span(names.DATA_WAIT):
+                    time.sleep(0.001)
+                state, result = steps.run(i, device.step, state, None,
+                                          steps=1)
+        wall = time.monotonic() - t0
+        assert state == n and isinstance(result, FakeResult)
+        step, compile_ = _spans(session, names.STEP), _spans(session, names.COMPILE)
+        assert len(compile_) == 1 and len(step) == n - 1
+        assert all(s["steps"] == 1 for s in step)
+        total = sum(s["dur"] for s in step + compile_)
+        assert abs(total - wall) <= 0.05 * wall, (total, wall)
+        # every step but the drained last one took one device step
+        assert sorted(s["dur"] for s in step)[len(step) // 2] \
+            == pytest.approx(0.02, rel=0.25)
+        dispatch = _spans(session, names.DISPATCH)
+        assert len(dispatch) == n
+        assert sum(s["dur"] for s in dispatch) < 0.2 * wall
+        # host work while a step is in flight is the step's child: detail
+        # for the goodput sum, not a second copy of the wall-clock
+        waits = _spans(session, names.DATA_WAIT)
+        assert "parent" not in waits[0]            # nothing in flight yet
+        assert {w.get("parent") for w in waits[2:]} == {"step"}
+        assert {d.get("parent") for d in dispatch[2:]} == {"step"}
+        # ... and nothing is left on the thread's span stack
+        with session.span("after"):
+            pass
+        assert "parent" not in _spans(session, "after")[0]
+    finally:
+        telemetry.finish(write_report=False)
+
+
+def test_step_spans_disarmed_add_no_sync():
+    device, state = FakeDevice(0.005), 0
+    with StepSpans(None) as steps:
+        for i in range(5):
+            state, _ = steps.run(i, device.step, state, None)
+    assert state == 5 and device.waits == 0
+
+
+def test_step_spans_unwind_when_the_loop_raises(tmp_path):
+    session = telemetry.start(tmp_path, rank=0, generation=0)
+    try:
+        device = FakeDevice(0.001)
+        with pytest.raises(RuntimeError, match="boom"):
+            with StepSpans(session) as steps:
+                for i in range(3):
+                    steps.run(i, device.step, 0, None)
+                raise RuntimeError("boom")
+        with session.span("after"):
+            pass
+        assert "parent" not in _spans(session, "after")[0]
+    finally:
+        telemetry.finish(write_report=False)
+
+
+def test_goodput_moves_a_steps_data_wait_out_of_step(tmp_path):
+    """The aggregator's half of the contract: a ``data_wait`` that lies in
+    a ``step`` span is the same wall-clock under its own heading."""
+    from tpudist.telemetry.aggregate import aggregate_run
+
+    session = telemetry.start(tmp_path, rank=0, generation=0)
+    device, state = FakeDevice(0.01), 0
+    with StepSpans(session) as steps:
+        for i in range(10):
+            with session.span(names.DATA_WAIT):
+                time.sleep(0.004)
+            state, _ = steps.run(i, device.step, state, None)
+    telemetry.finish(write_report=False)
+    report = aggregate_run(tmp_path)
+    goodput = {k: v["s"] for k, v in report["goodput"].items()}
+    assert goodput["data"] == pytest.approx(10 * 0.004, rel=0.5)
+    assert sum(goodput.values()) == pytest.approx(report["wall_clock_s"],
+                                                  rel=0.05)
+    assert report["per_rank"][0]["overlap_s"] == 0.0
+
+
+# ---------------------------------------------------------------------------
+# (e) the compile listener
+
+
+@pytest.fixture()
+def cache_in(tmp_path, monkeypatch):
+    """JAX's persistent cache on, in a fresh directory, with no floor, for
+    one test; the listeners registered as ``initialize()`` registers them."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from tpudist.runtime import compilation_cache
+
+    monkeypatch.setenv("TPUDIST_COMPILATION_CACHE", "on")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    keys = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    cc.reset_cache()
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path / "cache"))
+    jax.config.update("jax_enable_compilation_cache", True)
+    assert compilation_cache.enable_compilation_cache() == str(
+        tmp_path / "cache")
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    yield compilation_cache
+    for k, v in was.items():
+        jax.config.update(k, v)
+    cc.reset_cache()
+
+
+def test_the_compile_listener_records_spans_a_miss_and_then_a_hit(
+        tmp_path, cache_in):
+    session = telemetry.start(tmp_path / "tele", rank=0, generation=0)
+    try:
+        def fresh():
+            # a new function object: the jit cache misses, the HLO is the same
+            def poly(x):
+                for k in range(40):
+                    x = jnp.sin(x) * (k + 1.5) + jnp.tanh(x @ x)
+                return x.sum()
+            return jax.jit(poly)
+
+        import numpy as np
+
+        # (a host array: making a device array would compile a program too)
+        x = np.full((64, 64), 0.3751, np.float32)
+        before = cache_in.event_counts()
+        fresh()(x).block_until_ready()
+        mid = cache_in.event_counts()
+        assert mid[names.COMPILE_CACHE_MISS] \
+            == before[names.COMPILE_CACHE_MISS] + 1
+        assert mid[names.COMPILE_CACHE_HIT] == before[names.COMPILE_CACHE_HIT]
+        fresh()(x).block_until_ready()   # the second, process-local lookup
+        after = cache_in.event_counts()
+        assert after[names.COMPILE_CACHE_HIT] \
+            == before[names.COMPILE_CACHE_HIT] + 1
+        assert after[names.COMPILE_CACHE_MISS] == mid[names.COMPILE_CACHE_MISS]
+        recorded = [r["name"] for r in session.ring]
+        assert recorded.count(names.COMPILE_CACHE_MISS) == 1
+        assert recorded.count(names.COMPILE_CACHE_HIT) == 1
+        for name in (names.XLA_TRACE, names.XLA_LOWER,
+                     names.XLA_BACKEND_COMPILE):
+            spans = _spans(session, name)
+            assert spans, name
+            # detail for the goodput sum, started ``dur`` before it was told
+            assert all(s["parent"] == names.XLA_PARENT for s in spans)
+            assert all(s["dur"] >= 1e-3 for s in spans)
+    finally:
+        telemetry.finish(write_report=False)
+
+
+def test_initialize_records_one_init_span_in_a_single_process(
+        tmp_path, monkeypatch):
+    from tpudist.runtime import bootstrap
+
+    for var in ("RANK", "WORLD_SIZE", "SLURM_PROCID", "SLURM_NTASKS",
+                "TPUDIST_PROCESS_ID", "TPUDIST_NUM_PROCESSES",
+                "OMPI_COMM_WORLD_RANK", "OMPI_COMM_WORLD_SIZE"):
+        monkeypatch.delenv(var, raising=False)
+    monkeypatch.setattr(bootstrap, "_INITIALIZED_CTX", None)
+    session = telemetry.start(tmp_path, rank=0, generation=0)
+    try:
+        ctx = bootstrap.initialize()
+        assert not ctx.is_distributed
+        (init,) = _spans(session, names.INIT)
+        assert init["world"] == 1 and "parent" not in init
+    finally:
+        telemetry.finish(write_report=False)
+        monkeypatch.setattr(bootstrap, "_INITIALIZED_CTX", None)

@@ -25,7 +25,9 @@ from typing import Iterator, Optional
 
 import numpy as np
 
+from tpudist import telemetry
 from tpudist.data.sharding import ShardPlan, epoch_indices
+from tpudist.telemetry import names
 
 
 def open_token_stream(path: str | Path, dtype: Optional[str] = None) -> np.ndarray:
@@ -98,7 +100,10 @@ def lm_batches(
         while True:
             idx = epoch_indices(plan, epoch)
             for i in range(0, len(idx) - batch_size + 1, batch_size):
-                yield windows.gather(idx[i : i + batch_size])
+                # the program's own share of a loop's ``data_wait``
+                with telemetry.span(names.LM_BATCH):
+                    batch = windows.gather(idx[i : i + batch_size])
+                yield batch
             epoch += 1
 
     return gen()
@@ -177,10 +182,11 @@ class PrefetchingTokenBatches:
             for _ in range(self._depth):
                 submit()
             while True:
-                job, _sel, slot = inflight.popleft()
-                self._pool.wait(job)
-                out = slot.astype(np.int32)  # fresh copy per yield
-                submit()
+                with telemetry.span(names.LM_BATCH):
+                    job, _sel, slot = inflight.popleft()
+                    self._pool.wait(job)
+                    out = slot.astype(np.int32)  # fresh copy per yield
+                    submit()
                 yield out
         finally:
             # abandoned stream: drain before the slot buffers can be freed

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from tpudist.parallel.ring_attention import attention_reference
+from tpudist.telemetry import names
 
 AttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
 
@@ -388,121 +389,128 @@ class Block(nn.Module):
             m = on.reshape(on.shape + (1,) * (y.ndim - on.ndim))
             return jnp.where(m, y + delta, y)
 
-        # LayerNorm statistics in f32 for stability; projections compute in
-        # ``dtype`` (flax casts inputs + the f32 master params at apply).
-        h = nn.LayerNorm(use_bias=False, dtype=jnp.float32)(x)
-        use_fused_qkv = self.fused_rope and not self.is_initializing()
-        if use_fused_qkv and not (
-                self.decode
-                and self.decode_kernel in ("paged", "paged_prefill")):
-            raise ValueError(
-                "fused_rope fuses the QKV projection with the per-slot "
-                "rope offsets of the paged decode/prefill arms — set "
-                "decode_kernel='paged'/'paged_prefill' (training and the "
-                "dense decode path keep the unfused projection)")
-        rotated = False
-        if use_fused_qkv:
-            from tpudist.ops.fused_linear import fused_rope_qkv
-            # same qkv/kernel param as the Dense twin (_Kernel seam)
-            w = _Kernel((self.d_model, self.d_model + 2 * kv_dim),
-                        name="qkv")()
-            if self.decode_kernel == "paged":
-                offs = self.get_variable("cache", "idx")  # absolute cursors
-            else:
-                offs = self.get_variable("pool", "pos0")  # chunk starts
-            extra = on = None
-            if ad is not None:
-                extra = _delta(h, "a_qkv", "b_qkv")
-                on = jnp.asarray(ad["on"]).astype(jnp.int32)
-            q, k, v = fused_rope_qkv(
-                h.astype(self.dtype), w.astype(self.dtype),
-                offs.astype(jnp.int32), extra, on,
-                n_heads=self.n_heads, n_kv=n_kv, dh=dh, rope=self.rope,
-                interpret=jax.devices()[0].platform != "tpu")
-            rotated = True
-        else:
-            qkv = nn.Dense(self.d_model + 2 * kv_dim, use_bias=False,
-                           name="qkv", dtype=self.dtype)(h)
-            qkv = _ad(qkv, h, "a_qkv", "b_qkv")
-            q = qkv[..., : self.d_model]
-            k = qkv[..., self.d_model : self.d_model + kv_dim]
-            v = qkv[..., self.d_model + kv_dim :]
-
-            def heads(t, n):  # [b, s, n·dh] -> [b, n, s, dh]
-                b, s, _ = t.shape
-                return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
-
-            q = heads(q, self.n_heads)
-            k = heads(k, n_kv)
-            v = heads(v, n_kv)
-        if self.decode:
-            if self.decode_kernel == "paged":
-                attn = self._decode_attention_paged(q, k, v,
-                                                    rotated=rotated)
-            elif self.decode_kernel == "paged_prefill":
-                attn = self._prefill_attention_paged(q, k, v,
-                                                     rotated=rotated)
-            elif self.decode_kernel is not None:
+        # the two sublayers, each under its scope (tpudist.telemetry.names):
+        # pre-LN through the residual add, so the transposes and copies
+        # round the attention kernel read as ``attn`` in a device trace
+        with jax.named_scope(names.ATTN):
+            # LayerNorm statistics in f32 for stability; projections compute in
+            # ``dtype`` (flax casts inputs + the f32 master params at apply).
+            h = nn.LayerNorm(use_bias=False, dtype=jnp.float32)(x)
+            use_fused_qkv = self.fused_rope and not self.is_initializing()
+            if use_fused_qkv and not (
+                    self.decode
+                    and self.decode_kernel in ("paged", "paged_prefill")):
                 raise ValueError(
-                    f"unknown decode_kernel {self.decode_kernel!r} "
-                    "(None = dense cached softmax, 'paged' = the Pallas "
-                    "paged-attention decode kernel, 'paged_prefill' = "
-                    "the Pallas paged-prefill kernel)")
+                    "fused_rope fuses the QKV projection with the per-slot "
+                    "rope offsets of the paged decode/prefill arms — set "
+                    "decode_kernel='paged'/'paged_prefill' (training and the "
+                    "dense decode path keep the unfused projection)")
+            rotated = False
+            if use_fused_qkv:
+                from tpudist.ops.fused_linear import fused_rope_qkv
+                # same qkv/kernel param as the Dense twin (_Kernel seam)
+                w = _Kernel((self.d_model, self.d_model + 2 * kv_dim),
+                            name="qkv")()
+                if self.decode_kernel == "paged":
+                    # absolute cursors
+                    offs = self.get_variable("cache", "idx")
+                else:
+                    offs = self.get_variable("pool", "pos0")  # chunk starts
+                extra = on = None
+                if ad is not None:
+                    extra = _delta(h, "a_qkv", "b_qkv")
+                    on = jnp.asarray(ad["on"]).astype(jnp.int32)
+                q, k, v = fused_rope_qkv(
+                    h.astype(self.dtype), w.astype(self.dtype),
+                    offs.astype(jnp.int32), extra, on,
+                    n_heads=self.n_heads, n_kv=n_kv, dh=dh, rope=self.rope,
+                    interpret=jax.devices()[0].platform != "tpu")
+                rotated = True
             else:
-                attn = self._decode_attention(q, k, v)
-        else:
-            if self.sliding_window is not None and getattr(
-                    self.attention_fn, "window", None) != self.sliding_window:
-                # sliding_window alone only masks the decode cache; a
-                # non-windowed attention_fn would train full-causal and
-                # decode windowed.  TransformerLM/pipeline_lm thread a
-                # matching windowed fn — raw Block users must too (fns
-                # built by make_length_aware_attention / make_ring_attention
-                # carry a ``window`` tag).
-                raise ValueError(
-                    "Block.sliding_window is set but attention_fn is not "
-                    "tagged with a matching window — inject an attention_fn "
-                    "built with the same window (e.g. "
-                    "make_length_aware_attention(window)), or tag a custom "
-                    "fn with .window")
-            if self.rope:
-                q, k = rope_rotate(q), rope_rotate(k)
-            if n_kv != self.n_heads and not getattr(
-                    self.attention_fn, "supports_gqa", False):
-                group = self.n_heads // n_kv
-                k = jnp.repeat(k, group, axis=1)
-                v = jnp.repeat(v, group, axis=1)
-            attn = self.attention_fn(q, k, v)
-        b, nh, s, _ = attn.shape
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, s, self.d_model)
-        x = x + nn.Dense(self.d_model, use_bias=False, name="proj",
-                         dtype=self.dtype)(attn)
+                qkv = nn.Dense(self.d_model + 2 * kv_dim, use_bias=False,
+                               name="qkv", dtype=self.dtype)(h)
+                qkv = _ad(qkv, h, "a_qkv", "b_qkv")
+                q = qkv[..., : self.d_model]
+                k = qkv[..., self.d_model : self.d_model + kv_dim]
+                v = qkv[..., self.d_model + kv_dim :]
 
-        h = nn.LayerNorm(use_bias=False, dtype=jnp.float32)(x)
-        if self.n_experts > 0:
+                def heads(t, n):  # [b, s, n·dh] -> [b, n, s, dh]
+                    b, s, _ = t.shape
+                    return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
+
+                q = heads(q, self.n_heads)
+                k = heads(k, n_kv)
+                v = heads(v, n_kv)
+            if self.decode:
+                if self.decode_kernel == "paged":
+                    attn = self._decode_attention_paged(q, k, v,
+                                                        rotated=rotated)
+                elif self.decode_kernel == "paged_prefill":
+                    attn = self._prefill_attention_paged(q, k, v,
+                                                         rotated=rotated)
+                elif self.decode_kernel is not None:
+                    raise ValueError(
+                        f"unknown decode_kernel {self.decode_kernel!r} "
+                        "(None = dense cached softmax, 'paged' = the Pallas "
+                        "paged-attention decode kernel, 'paged_prefill' = "
+                        "the Pallas paged-prefill kernel)")
+                else:
+                    attn = self._decode_attention(q, k, v)
+            else:
+                fn_window = getattr(self.attention_fn, "window", None)
+                if (self.sliding_window is not None
+                        and fn_window != self.sliding_window):
+                    # sliding_window alone only masks the decode cache; a
+                    # non-windowed attention_fn would train full-causal and
+                    # decode windowed.  TransformerLM/pipeline_lm thread a
+                    # matching windowed fn — raw Block users must too (fns
+                    # built by make_length_aware_attention /
+                    # make_ring_attention carry a ``window`` tag).
+                    raise ValueError(
+                        "Block.sliding_window is set but attention_fn is not "
+                        "tagged with a matching window — inject an "
+                        "attention_fn built with the same window (e.g. "
+                        "make_length_aware_attention(window)), or tag a "
+                        "custom fn with .window")
+                if self.rope:
+                    q, k = rope_rotate(q), rope_rotate(k)
+                if n_kv != self.n_heads and not getattr(
+                        self.attention_fn, "supports_gqa", False):
+                    group = self.n_heads // n_kv
+                    k = jnp.repeat(k, group, axis=1)
+                    v = jnp.repeat(v, group, axis=1)
+                attn = self.attention_fn(q, k, v)
+            b, nh, s, _ = attn.shape
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, self.d_model)
+            x = x + nn.Dense(self.d_model, use_bias=False, name="proj",
+                             dtype=self.dtype)(attn)
+
+        with jax.named_scope(names.MLP):
+            h = nn.LayerNorm(use_bias=False, dtype=jnp.float32)(x)
+            if self.n_experts > 0:
+                if self.mlp_fn is not None:
+                    raise ValueError(
+                        "mlp_fn replaces the dense FFN; it cannot compose "
+                        "with the MoE FFN (n_experts > 0)")
+                return x + MoEFFN(self.d_model, self.d_ff, self.n_experts,
+                                  self.moe_fn, dtype=self.dtype, name="moe")(h)
             if self.mlp_fn is not None:
-                raise ValueError(
-                    "mlp_fn replaces the dense FFN; it cannot compose "
-                    "with the MoE FFN (n_experts > 0)")
-            return x + MoEFFN(self.d_model, self.d_ff, self.n_experts,
-                              self.moe_fn, dtype=self.dtype, name="moe")(h)
-        if self.mlp_fn is not None:
-            wi = _Kernel((self.d_model, self.d_ff), name="wi")()
-            wo = _Kernel((self.d_ff, self.d_model), name="wo")()
-            # Same mixed-precision contract as the Dense twins: f32
-            # master kernels cast to the compute dtype at apply.
-            y = self.mlp_fn(
-                {"wi": wi.astype(self.dtype), "wo": wo.astype(self.dtype)},
-                h.astype(self.dtype))
-            return x + y
-        hin = h
-        h = nn.Dense(self.d_ff, use_bias=False, name="wi",
-                     dtype=self.dtype)(hin)
-        h = _ad(h, hin, "a_wi", "b_wi")
-        h = nn.gelu(h)
-        y = nn.Dense(self.d_model, use_bias=False, name="wo",
-                     dtype=self.dtype)(h)
-        return x + _ad(y, h, "a_wo", "b_wo")
+                wi = _Kernel((self.d_model, self.d_ff), name="wi")()
+                wo = _Kernel((self.d_ff, self.d_model), name="wo")()
+                # Same mixed-precision contract as the Dense twins: f32
+                # master kernels cast to the compute dtype at apply.
+                y = self.mlp_fn(
+                    {"wi": wi.astype(self.dtype), "wo": wo.astype(self.dtype)},
+                    h.astype(self.dtype))
+                return x + y
+            hin = h
+            h = nn.Dense(self.d_ff, use_bias=False, name="wi",
+                         dtype=self.dtype)(hin)
+            h = _ad(h, hin, "a_wi", "b_wi")
+            h = nn.gelu(h)
+            y = nn.Dense(self.d_model, use_bias=False, name="wo",
+                         dtype=self.dtype)(h)
+            return x + _ad(y, h, "a_wo", "b_wo")
 
     def _decode_attention(self, q, k, v):
         """Cached attention over a decode WINDOW of ``s >= 1`` tokens:
@@ -777,25 +785,26 @@ class TransformerLM(nn.Module):
             make_length_aware_attention(self.sliding_window)
             if self.sliding_window is not None else _default_attention)
         seq = tokens.shape[1]
-        x = nn.Embed(self.vocab, self.d_model, name="tok_embed",
-                     dtype=self.dtype)(tokens)
-        if not self.rope:
-            if self.decode:
-                pi = self.variable("cache", "pos",
-                                   lambda: jnp.zeros((), jnp.int32))
-                if pi.value.ndim:
-                    # slot-batched paged-kernel decode: every lane sits
-                    # at its own cursor, so positions are [batch, seq]
-                    positions = (pi.value[:, None]
-                                 + jnp.arange(seq, dtype=jnp.int32)[None])
-                else:
-                    positions = pi.value + jnp.arange(seq, dtype=jnp.int32)
-                pi.value = pi.value + seq
-            elif positions is None:
-                positions = jnp.arange(seq, dtype=jnp.int32)
-            pos = nn.Embed(self.max_len, self.d_model, name="pos_embed",
-                           dtype=self.dtype)(positions)
-            x = x + (pos if pos.ndim == 3 else pos[None])
+        with jax.named_scope(names.EMBED):
+            x = nn.Embed(self.vocab, self.d_model, name="tok_embed",
+                         dtype=self.dtype)(tokens)
+            if not self.rope:
+                if self.decode:
+                    pi = self.variable("cache", "pos",
+                                       lambda: jnp.zeros((), jnp.int32))
+                    if pi.value.ndim:
+                        # slot-batched paged-kernel decode: every lane sits
+                        # at its own cursor, so positions are [batch, seq]
+                        positions = (pi.value[:, None]
+                                     + jnp.arange(seq, dtype=jnp.int32)[None])
+                    else:
+                        positions = pi.value + jnp.arange(seq, dtype=jnp.int32)
+                    pi.value = pi.value + seq
+                elif positions is None:
+                    positions = jnp.arange(seq, dtype=jnp.int32)
+                pos = nn.Embed(self.max_len, self.d_model, name="pos_embed",
+                               dtype=self.dtype)(positions)
+                x = x + (pos if pos.ndim == 3 else pos[None])
         block_cls = Block
         if self.remat and not self.decode:
             # static_argnums: nothing — Block takes only the activation.
@@ -825,9 +834,10 @@ class TransformerLM(nn.Module):
                 lora_kernel=self.lora_kernel,
                 name=f"block_{i}",
             )(x)
-        x = nn.LayerNorm(use_bias=False, dtype=jnp.float32)(x)
-        return nn.Dense(self.vocab, use_bias=False, name="head",
-                        dtype=self.dtype)(x)
+        with jax.named_scope(names.HEAD):
+            x = nn.LayerNorm(use_bias=False, dtype=jnp.float32)(x)
+            return nn.Dense(self.vocab, use_bias=False, name="head",
+                            dtype=self.dtype)(x)
 
 
 def transformer_tp_sharding(mesh, tree, *, axis_name: str = "model"):

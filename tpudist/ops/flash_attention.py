@@ -39,6 +39,7 @@ from tpudist.parallel.ring_attention import (
     _causal_mask,
     attention_reference,
 )
+from tpudist.telemetry import names
 
 _MASK_VALUE = -1e30
 
@@ -360,6 +361,7 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=cost,
+        **names.kernel(names.FLASH_FWD),
         interpret=interpret,
     )(qr, kr, vr)
     return out.reshape(batch, heads, seq_q, d), lse.reshape(batch, heads, seq_q)
@@ -629,6 +631,7 @@ def _flash_backward(q, k, v, do, lse, delta, *, causal, block_q, block_k,
             flops=int(6 * work * d), transcendentals=int(work),
             bytes_accessed=in_bytes + int(qr.size * q.dtype.itemsize),
         ),
+        **names.kernel(names.FLASH_BWD_DQ),
         interpret=interpret,
     )(qr, kr, vr, dor, lser, deltar)
 
@@ -683,6 +686,7 @@ def _flash_backward(q, k, v, do, lse, delta, *, causal, block_q, block_k,
             flops=int(8 * work * d), transcendentals=int(work),
             bytes_accessed=in_bytes + int(2 * kr.size * k.dtype.itemsize),
         ),
+        **names.kernel(names.FLASH_BWD_DKV),
         interpret=interpret,
     )(qr, kr, vr, dor, lser, deltar)
 

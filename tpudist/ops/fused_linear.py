@@ -38,6 +38,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudist.telemetry import names
+
 
 def rope_tables(offsets: jax.Array, T: int, dh: int, base: float):
     """Full-width rotary tables ``(cos, sin) [S, T, dh]`` f32 for the
@@ -186,6 +188,7 @@ def fused_rope_qkv(
             bytes_accessed=int((h.size + S * w.size + 3 * S * T * dtot)
                                * h.dtype.itemsize),
         ),
+        **names.kernel(names.FUSED_ROPE_QKV),
         interpret=interpret,
     )(*operands)
     return q, k, v
@@ -280,6 +283,7 @@ def lora_delta(
                 (x.size + S * (d_in * r + r * d_out) + S * T * d_out)
                 * x.dtype.itemsize),
         ),
+        **names.kernel(names.LORA_DELTA),
         interpret=interpret,
     )(ids.astype(jnp.int32), x, pool_a, pool_b)
     return out

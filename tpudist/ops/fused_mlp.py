@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudist.telemetry import names
+
 LANE = 128
 NEGATIVE_SLOPE = 0.01  # torch.nn.LeakyReLU default, toy_model_and_data.py:14
 
@@ -95,6 +97,7 @@ def fused_mlp(
         ],
         out_specs=pl.BlockSpec((bb, LANE), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
+        **names.kernel(names.FUSED_MLP),
         interpret=interpret,
     )(xp, *padded_params)
     return out[:, :d_out]

@@ -45,6 +45,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudist.telemetry import names
+
 
 def _sample_kernel(temps_ref, gidx_ref, gstate_ref, lg_ref, ga_ref,
                    masked_ref, scaled_ref, greedy_ref, *, grammar: bool):
@@ -169,6 +171,7 @@ def fused_sample_prep(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
+        **names.kernel(names.FUSED_SAMPLE),
         interpret=interpret,
     )(*operands)
     return (masked[:, 0], _filter(scaled[:, 0], top_k, top_p),
@@ -250,6 +253,7 @@ def fused_residual_prep(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
+        **names.kernel(names.FUSED_RESIDUAL),
         interpret=interpret,
     )(temps, lt[:, :, None], ld[:, :, None])
     pt, pd, lr = (o[:, :, 0] for o in outs)

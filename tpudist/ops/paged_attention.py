@@ -59,6 +59,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudist.telemetry import names
+
 _MASK_VALUE = -1e30
 
 
@@ -291,6 +293,7 @@ def paged_attention(
                 (q4.size + 2 * S * n_kv * M * bs * dh + wk.size + wv.size
                  + q4.size) * q.dtype.itemsize),
         ),
+        **names.kernel(names.PAGED_ATTENTION),
         interpret=interpret,
     )(*operands)
     return out.reshape(S, nh, s, dh)

@@ -53,6 +53,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpudist.ops.paged_attention import scale_at, walk_scales
+from tpudist.telemetry import names
 
 _MASK_VALUE = -1e30
 
@@ -318,6 +319,7 @@ def paged_prefill_attention(
                  + k_new.size + v_new.size + q4.size
                  + 2 * S * Mw * n_kv * bs * dh) * q.dtype.itemsize),
         ),
+        **names.kernel(names.PAGED_PREFILL),
         interpret=interpret,
     )(*operands)
     o, qk, qv = outs[:3]

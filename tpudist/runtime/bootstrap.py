@@ -291,6 +291,11 @@ def initialize(
     ``tpurun`` often races the coordinator's own restart): ``init_retries``
     retries (default ``TPUDIST_INIT_RETRIES`` or 3) starting at
     ``init_backoff_s`` (default ``TPUDIST_INIT_BACKOFF_S`` or 1.0s).
+
+    The whole bring-up is one ``init`` telemetry span, in a single
+    process too: the coordination service and then the accelerator
+    client (``jax.local_devices()``, which costs nothing if the client
+    is already up).
     """
     global _INITIALIZED_CTX
     if _INITIALIZED_CTX is not None:
@@ -308,41 +313,48 @@ def initialize(
     # the training loop records into.
     from tpudist import telemetry
     from tpudist.runtime import faults
+    from tpudist.telemetry import names
 
     faults.arm_from_env()
     telemetry.ensure_started()
-    if ctx.is_distributed:
-        import jax
+    import jax
 
-        from tpudist.utils.envutil import env_float
+    # one ``init`` span for the whole bring-up, single process or many:
+    # the coordination service (retried) and then the accelerator client,
+    # which ``jax.local_devices()`` brings up if nothing has yet
+    with telemetry.span(names.INIT, world=ctx.num_processes,
+                        source=ctx.launch_source):
+        if ctx.is_distributed:
+            from tpudist.utils.envutil import env_float
 
-        if init_retries is None:
-            init_retries = max(0, int(env_float("TPUDIST_INIT_RETRIES", 3)))
-        if init_backoff_s is None:
-            init_backoff_s = env_float("TPUDIST_INIT_BACKOFF_S", 1.0)
+            if init_retries is None:
+                init_retries = max(
+                    0, int(env_float("TPUDIST_INIT_RETRIES", 3)))
+            if init_backoff_s is None:
+                init_backoff_s = env_float("TPUDIST_INIT_BACKOFF_S", 1.0)
 
-        def _attempt(attempt: int) -> None:
-            faults.inject_init(attempt)
-            if attempt > 0:
-                # A failed connect leaves jax's global distributed state
-                # half-initialized (State.initialize sets .client BEFORE
-                # connect()), so a bare retry would raise 'should only be
-                # called once' forever.  shutdown() clears it and is a
-                # documented no-op when nothing is running.
-                jax.distributed.shutdown()
-            jax.distributed.initialize(
-                coordinator_address=ctx.coordinator_address,
-                num_processes=ctx.num_processes,
-                process_id=ctx.process_id,
-                initialization_timeout=initialization_timeout_s,
-            )
+            def _attempt(attempt: int) -> None:
+                faults.inject_init(attempt)
+                if attempt > 0:
+                    # A failed connect leaves jax's global distributed
+                    # state half-initialized (State.initialize sets
+                    # .client BEFORE connect()), so a bare retry would
+                    # raise 'should only be called once' forever.
+                    # shutdown() clears it and is a documented no-op when
+                    # nothing is running.
+                    jax.distributed.shutdown()
+                jax.distributed.initialize(
+                    coordinator_address=ctx.coordinator_address,
+                    num_processes=ctx.num_processes,
+                    process_id=ctx.process_id,
+                    initialization_timeout=initialization_timeout_s,
+                )
 
-        with telemetry.span("init", world=ctx.num_processes,
-                            source=ctx.launch_source):
             _retry_with_backoff(
                 _attempt, retries=init_retries, backoff_s=init_backoff_s,
                 what=f"jax.distributed.initialize({ctx.coordinator_address})",
             )
+        jax.local_devices()
     _INITIALIZED_CTX = ctx
     return ctx
 

@@ -14,7 +14,12 @@ Attribution rules (the math the tests pin down):
 
 - Only TOP-LEVEL spans (no ``parent``) enter the goodput sum — a
   ``host_collective`` nested inside ``metric_flush`` is detail, not a
-  second copy of the same wall-clock.
+  second copy of the same wall-clock.  One exception: the training
+  loops' ``step`` span runs from the arrival of one step's result to the
+  next (``tpudist.train.loop.StepSpans``), so the loop's ``data_wait`` /
+  ``ckpt_*`` / ``host_collective`` spans lie inside it; a direct child of
+  ``step`` that belongs to another component is moved out of ``step``
+  into its own, so a loader-bound run still reads as ``data``.
 - Per rank: ``wall = last record end − first record start`` across all
   generations; ``lost_restart = Σ gaps`` between one generation's last
   record and the next generation's first (the time a killed process's
@@ -38,11 +43,10 @@ from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
 #: span name → goodput component; unmapped top-level spans land in "other".
-#: ``metric_flush`` counts as step time on purpose: a jitted step's span
-#: brackets only the async dispatch, and the device compute it ran ahead
-#: of surfaces in the next blocking loss fetch — attributing that wait to
-#: anything but step would make the headline step%% read near-zero on
-#: compute-bound runs.
+#: ``metric_flush`` (the blocking loss fetch) counts as step time: it waits
+#: for device compute.  In the training loops it now lies inside a ``step``
+#: span (arrival to arrival) and is detail; top-level, as other callers
+#: record it, it is the only sign of the device work it waited for.
 COMPONENT_OF = {
     "step": "step",
     "metric_flush": "step",
@@ -170,9 +174,21 @@ def _rank_breakdown(rank_recs: List[dict]) -> dict:
     comp["lost_restart"] = lost
     comp["resize"] = resize
     for r in rank_recs:
-        if r.get("kind") != "span" or "parent" in r:
-            continue  # nested spans are detail, not additional wall-clock
-        comp[COMPONENT_OF.get(r["name"], "other")] += float(r.get("dur", 0.0))
+        if r.get("kind") != "span":
+            continue
+        own = COMPONENT_OF.get(r["name"], "other")
+        dur = float(r.get("dur", 0.0))
+        if "parent" not in r:
+            comp[own] += dur
+        elif r["parent"] == "step" and own not in ("step", "other"):
+            # the loops' step span runs from one result's arrival to the
+            # next, so the loop's own data/ckpt/comm spans lie INSIDE it:
+            # each is carved out of the step it sits in — the same
+            # wall-clock under its own heading, not a second copy.  Any
+            # other nested span is detail.
+            comp[own] += dur
+            comp["step"] -= dur
+    comp["step"] = max(0.0, comp["step"])  # a killed process: children, no step
     busy = sum(comp[c] for c in COMPONENTS
                if c not in ("idle", "resize", "lost_restart"))
     idle = wall - busy - lost - resize

@@ -1,0 +1,89 @@
+"""The one vocabulary of names the program writes into a trace and its
+telemetry stream, and every reader of either looks for.
+
+Plain string constants, no jax import: the program (``tpudist.ops``,
+``tpudist.models``, ``tpudist.train``, ``tpudist.data``,
+``tpudist.runtime``) and the readers (``cellbench/readers``, the
+aggregator) both import this module, so a name is spelled once.
+
+Three kinds of name, three places they show:
+
+- **kernel names** — ``pallas_call(name=..., metadata={"kernel": ...})``:
+  the Mosaic custom call's event text in a device trace carries
+  ``kernel_metadata={"kernel": "<name>"}`` whatever a ``shard_map`` round
+  the call does to the instruction's own name;
+- **scopes** — ``jax.named_scope``: a component of the ``op_name`` of
+  every HLO instruction traced inside it (``jit(step)/loss/...``,
+  ``.../transpose(jvp(attn))/...`` for its backward), which the profiler
+  keeps with the device operation;
+- **spans and events** — ``tpudist.telemetry``: records of the JSONL
+  stream and the session's ring, and (spans) ``TraceAnnotation``s on the
+  host rows of a profiler trace.
+"""
+
+# -- kernel names (tpudist/ops) ----------------------------------------------
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+FLASH_KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
+PAGED_ATTENTION = "paged_attention"
+PAGED_PREFILL = "paged_prefill"
+FUSED_ROPE_QKV = "fused_rope_qkv"
+LORA_DELTA = "lora_delta"
+FUSED_SAMPLE = "fused_sample"
+FUSED_RESIDUAL = "fused_residual"
+FUSED_MLP = "fused_mlp"
+#: the key of ``pallas_call``'s ``metadata`` dict that holds the name
+KERNEL_KEY = "kernel"
+
+# -- scopes (jax.named_scope) -------------------------------------------------
+# tpudist/models/transformer.py: the sublayers of the forward pass; JAX adds
+# ``jvp(...)`` / ``transpose(jvp(...))`` round them for the backward pass
+EMBED = "embed"
+ATTN = "attn"
+MLP = "mlp"
+HEAD = "head"
+# tpudist/train/lm.py: the phases of the jitted step
+LOSS = "loss"
+OPTIMIZER = "optimizer"
+GRAD_ACCUM = "grad_accum"
+SCOPES = (EMBED, ATTN, MLP, HEAD, LOSS, OPTIMIZER, GRAD_ACCUM)
+#: what JAX itself writes round the scopes of a transposed (backward) op
+BACKWARD_MARK = "transpose("
+
+# -- spans (tpudist.telemetry.span / record_span) -----------------------------
+STEP = "step"            # one arrival of a step's result to the next
+COMPILE = "compile"      # the first step: enqueue to its result, compile in
+DISPATCH = "dispatch"    # child of step: the enqueue of the next step
+DATA_WAIT = "data_wait"  # the loop's blocking next() on its loader
+LM_BATCH = "lm_batch"    # tpudist/data/lm.py: producing one token batch
+INIT = "init"            # tpudist/runtime/bootstrap.py: initialize()
+# tpudist/runtime/compilation_cache.py, from JAX's own duration events
+XLA_TRACE = "xla_trace"
+XLA_LOWER = "xla_lower"
+XLA_BACKEND_COMPILE = "xla_backend_compile"
+#: ``jax.monitoring`` duration event -> span name
+XLA_DURATION_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": XLA_TRACE,
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": XLA_LOWER,
+    "/jax/core/compile/backend_compile_duration": XLA_BACKEND_COMPILE,
+}
+#: the ``parent`` every ``xla_*`` span carries, so that none enters a goodput sum
+XLA_PARENT = "xla"
+#: the ``StepTraceAnnotation`` of the training loops
+STEP_ANNOTATION = "train"
+
+# -- events ------------------------------------------------------------------
+COMPILE_CACHE_HIT = "compile_cache_hit"
+COMPILE_CACHE_MISS = "compile_cache_miss"
+#: ``jax.monitoring`` event -> event name
+XLA_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": COMPILE_CACHE_HIT,
+    "/jax/compilation_cache/cache_misses": COMPILE_CACHE_MISS,
+}
+
+
+def kernel(name: str) -> dict:
+    """The two keyword arguments that name a ``pallas_call``:
+    ``pl.pallas_call(..., **names.kernel(names.FLASH_FWD))``."""
+    return {"name": name, "metadata": {KERNEL_KEY: name}}

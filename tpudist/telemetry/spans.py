@@ -1,7 +1,8 @@
 """Per-step span tracing: the recording half of the telemetry subsystem.
 
 A *span* is a named wall-clock interval (``step``, ``compile``,
-``data_wait``, ``ckpt_save``, ``host_collective``, ``init``); an *event*
+``data_wait``, ``ckpt_save``, ``host_collective``, ``init``, ``lm_batch``;
+the names live in :mod:`tpudist.telemetry.names`); an *event*
 is a zero-duration tagged marker (``fault_injected``, ``watchdog_stall``,
 ``retry``).  Each process records into
 
@@ -24,9 +25,18 @@ processes/generations merge on one axis; durations are measured with
 ``time.monotonic`` and mapped onto the wall axis through one clock-pair
 read at session start (span math never mixes clock reads).
 
+On the profiler's clock too: the bracket form (``span``) runs its body
+inside a ``jax.profiler.TraceAnnotation`` of the same name, so while a
+``jax.profiler`` trace is being taken every span of the program shows on
+the host rows of that trace, beside the device's operations and on one
+clock with them (an idle gap of the device can be put down to a span).
+The explicit-stamp form (``record_span``) cannot bracket and shows in the
+stream only.  Names live in :mod:`tpudist.telemetry.names`.
+
 Hot-path cost: disarmed (``TPUDIST_TELEMETRY=0`` or no session) every
 site pays one module-attribute load + ``None`` check; armed, a span is
-two ``monotonic()`` reads, a small dict, and one buffered ``write``.
+two ``monotonic()`` reads, a ``TraceAnnotation`` (inert while no trace
+is taken), a small dict, and one buffered ``write``.
 Telemetry must never take a job down: I/O errors drop records — but no
 longer SILENTLY: stream write failures, and ring evictions when the
 session is RING-ONLY (the stream never opened, so an evicted record
@@ -52,6 +62,7 @@ import collections
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 import warnings
@@ -66,6 +77,16 @@ DEFAULT_RING = 4096
 
 #: Keys every record carries; tags may not override them.
 RESERVED_KEYS = ("kind", "name", "t", "dur", "rank", "gen", "parent")
+
+
+def _trace_annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)`` if jax is ALREADY imported
+    (this package stays importable, and usable, without it); inert while
+    no profiler trace is being taken."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NULL_SPAN
+    return jax.profiler.TraceAnnotation(name)
 
 
 def enabled_from_env() -> bool:
@@ -206,17 +227,33 @@ class TelemetrySession:
         self._emit(rec)
 
     @contextlib.contextmanager
-    def span(self, name: str, **tags):
-        """Nested-aware span bracket: while the body runs, inner spans
-        record this one as their ``parent`` (per-thread stack, so the
-        prefetch thread's spans never claim a trainer-thread parent)."""
+    def scope(self, name: str):
+        """A parent scope without a record of its own: while the body
+        runs, spans of this thread record ``name`` as their ``parent``
+        (per-thread stack, so the prefetch thread's spans never claim a
+        trainer-thread parent), and the body is a
+        ``jax.profiler.TraceAnnotation(name)`` — the same name, on the
+        profiler's clock, on the host rows of any trace being taken.
+        :meth:`span` is a scope plus one record; the training loops' step
+        helper (``tpudist.train.loop.StepSpans``) holds one open from the
+        arrival of one step's result to the next."""
         st = self._stack()
-        t0 = time.monotonic()
         st.append(name)
         try:
-            yield self
+            with _trace_annotation(name):
+                yield self
         finally:
             st.pop()
+
+    @contextlib.contextmanager
+    def span(self, name: str, **tags):
+        """Nested-aware span bracket: a :meth:`scope` whose duration is
+        recorded when the body ends."""
+        t0 = time.monotonic()
+        try:
+            with self.scope(name):
+                yield self
+        finally:
             self.record_span(name, t0, time.monotonic() - t0, tags or None)
 
     def _emit(self, rec: dict) -> None:

@@ -24,6 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpudist.models.transformer import lm_loss
 from tpudist.runtime.mesh import AXIS_DATA, AXIS_SEQ
+from tpudist.telemetry import names
 from tpudist.train.step import ModelState
 
 
@@ -36,6 +37,24 @@ def token_sharding(mesh: Mesh) -> NamedSharding:
 
 def init_lm_state(params, tx: optax.GradientTransformation) -> ModelState:
     return ModelState(params=params, opt_state=tx.init(params))
+
+
+def _scoped_loss(loss_fn: Callable, logits, tokens):
+    """``loss_fn`` under the ``loss`` scope: the name every operation of the
+    vocabulary-wide loss (and, as ``transpose(jvp(loss))``, of its gradient)
+    carries in a device trace.  The model names its own sublayers
+    (``embed``/``attn``/``mlp``/``head``, ``tpudist.models.transformer``)."""
+    with jax.named_scope(names.LOSS):
+        return loss_fn(logits, tokens)
+
+
+def _scoped_update(tx: optax.GradientTransformation, state: ModelState,
+                   grads) -> ModelState:
+    """The optimizer update under the ``optimizer`` scope."""
+    with jax.named_scope(names.OPTIMIZER):
+        updates, new_opt = tx.update(grads, state.opt_state, state.params)
+        new_params = optax.apply_updates(state.params, updates)
+    return ModelState(params=new_params, opt_state=new_opt)
 
 
 def _under_mesh(fn: Callable, mesh: Mesh) -> Callable:
@@ -78,7 +97,7 @@ def _make_lm_train_step_compressed(
         # Local mean over this shard's rows; equal shards (the sharded
         # batch contract) make pmean-of-means the exact global mean.
         loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(apply_fn(p, toks), toks))(params)
+            lambda p: _scoped_loss(loss_fn, apply_fn(p, toks), toks))(params)
         narrow = jax.tree.map(
             lambda g: lax.pmean(g.astype(reduce_dtype), AXIS_DATA), grads)
         grads = jax.tree.map(lambda g: g.astype(jnp.float32), narrow)
@@ -92,9 +111,7 @@ def _make_lm_train_step_compressed(
 
     def step(state: ModelState, tokens):
         loss, grads = sharded_grad(state.params, tokens)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        return ModelState(params=new_params, opt_state=new_opt), loss
+        return _scoped_update(tx, state, grads), loss
 
     return jax.jit(
         step,
@@ -146,6 +163,12 @@ def make_lm_train_step(
 
     ``apply_fn(params, tokens) -> logits`` is the TransformerLM apply with
     whatever attention op the caller injected (ring for multi-chip).
+
+    The step names its phases for a device trace (``jax.named_scope``,
+    names in :mod:`tpudist.telemetry.names`): ``loss``, ``optimizer`` and,
+    under ``accum_steps``, ``grad_accum``; the model names its sublayers,
+    and JAX marks the backward pass itself (``transpose(jvp(...))``).
+    Metadata only: the compiled step is the same program.
 
     ``state_sharding`` (a pytree of ``NamedSharding`` matching the
     ``ModelState``, e.g. from
@@ -230,7 +253,7 @@ def make_lm_train_step(
                 logits, mut = apply_fn(p, toks, mutable=["intermediates"])
                 # flax omits the collection entirely when nothing was sown
                 collected = _collect_aux(mut.get("intermediates", {}))
-                lm = loss_fn(logits, toks)
+                lm = _scoped_loss(loss_fn, logits, toks)
                 total = lm
                 if moe_balance_weight > 0.0 and "moe_balance_loss" in collected:
                     total = total + moe_balance_weight * collected[
@@ -244,7 +267,7 @@ def make_lm_train_step(
             return out, grads
 
         def loss_of(p):
-            return loss_fn(apply_fn(p, toks), toks)
+            return _scoped_loss(loss_fn, apply_fn(p, toks), toks)
 
         loss, grads = jax.value_and_grad(loss_of)(params)
         return (loss, {}), grads
@@ -266,16 +289,15 @@ def make_lm_train_step(
 
             def body(acc, chunk):
                 out = grad_of(state.params, chunk)
-                return jax.tree.map(jnp.add, acc, out), None
+                with jax.named_scope(names.GRAD_ACCUM):
+                    return jax.tree.map(jnp.add, acc, out), None
 
             ((loss, collected), grads), _ = lax.scan(body, acc0, chunks)
             scale = 1.0 / accum_steps
             loss = loss * scale
             collected = jax.tree.map(lambda a: a * scale, collected)
             grads = jax.tree.map(lambda g: g * scale, grads)
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        new_state = ModelState(params=new_params, opt_state=new_opt)
+        new_state = _scoped_update(tx, state, grads)
         if aux:
             return new_state, loss, collected
         return new_state, loss
@@ -363,11 +385,9 @@ def make_scanned_lm_train_step(
     def chunk(state: ModelState, tokens_chunk):
         def body(st, toks):
             loss, grads = jax.value_and_grad(
-                lambda p: loss_fn(apply_fn(p, toks), toks))(st.params)
-            updates, new_opt = tx.update(grads, st.opt_state, st.params)
-            new = ModelState(params=optax.apply_updates(st.params, updates),
-                             opt_state=new_opt)
-            return new, loss
+                lambda p: _scoped_loss(loss_fn, apply_fn(p, toks), toks)
+            )(st.params)
+            return _scoped_update(tx, st, grads), loss
 
         return _lax.scan(body, state, tokens_chunk)
 

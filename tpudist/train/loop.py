@@ -26,6 +26,7 @@ import jax
 from tpudist import telemetry
 from tpudist.comm.collectives import MetricBackend, barrier
 from tpudist.data.loader import ShardedLoader, shard_batch
+from tpudist.telemetry import names
 from tpudist.train.step import ModelState, batch_sharding
 from tpudist.utils.metrics import MetricsLogger
 
@@ -171,6 +172,96 @@ def _data_wait_iter(source, tele):
         except StopIteration:
             return
         yield item
+
+
+class StepSpans:
+    """Times a loop's steps with one step in flight, as the loops run.
+
+    A jitted step returns when it is ENQUEUED; timing the call times the
+    enqueue (2 ms of a 255 ms step on a v5e).  A step's time is the gap
+    between the arrivals of consecutive results, so::
+
+        with StepSpans(tele) as steps:
+            for i, batch in ...:
+                state, loss = steps.run(i, step_fn, state, batch)
+
+    dispatches step ``i`` and only then waits for the result of step
+    ``i-1`` (the device always has the next step queued, exactly the
+    pipelining the loops had).  What it records, armed:
+
+    - ``step``: from one arrival to the next (from the enqueue when
+      nothing was in flight); the first is ``compile`` and ends at its own
+      arrival, so it covers the XLA compile.  Extra ``tags`` (the scanned
+      loop's ``steps=k``) ride on it.  A run's ``step`` spans therefore
+      sum to the loop's wall time, which is what the goodput table reads.
+    - ``dispatch``: child of ``step``, the enqueue alone, inside a
+      ``jax.profiler.StepTraceAnnotation("train", step_num=i)`` so a
+      profiler trace groups the device's work by step.
+    - whatever else the loop's thread records while a step is in flight
+      (``data_wait``, ``metric_flush``, ``ckpt_save``) gets ``step`` as
+      its parent: host work hidden behind the device, not more wall-clock.
+
+    ``fn`` returns ``(state, *results)``; the wait is on the results, never
+    the state, which the next dispatch may donate.  Disarmed
+    (``tele is None``) ``run`` is the bare call: no wait, no record."""
+
+    def __init__(self, tele):
+        self._tele = tele
+        self._scope = None     # entered ``step`` scope of the step in flight
+        self._results = None   # ... and what its arrival is read from
+        self._edge = 0.0       # monotonic start of that span
+        self._tags = None
+        self._first = True
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, *_):
+        if exc_type is None:
+            self.drain()
+        elif self._scope is not None:
+            # unwind only: the results may be poisoned
+            self._scope.__exit__(None, None, None)
+            self._scope = self._results = None
+
+    def _open(self, tags) -> None:
+        self._scope = self._tele.scope(names.STEP)
+        self._scope.__enter__()
+        self._edge = time.monotonic()
+        self._tags = tags or None
+
+    def _close(self, results) -> None:
+        jax.block_until_ready(results)
+        now = time.monotonic()
+        self._scope.__exit__(None, None, None)
+        self._scope = None
+        self._tele.record_span("compile" if self._first else "step",
+                               self._edge, now - self._edge, self._tags)
+        self._first = False
+
+    def run(self, i: int, fn: Callable, *args, **tags):
+        tele = self._tele
+        if tele is None:
+            return fn(*args)
+        if self._scope is None:   # nothing in flight: starts with the enqueue
+            self._open(tags)
+        with jax.profiler.StepTraceAnnotation(names.STEP_ANNOTATION,
+                                              step_num=i), \
+                tele.span(names.DISPATCH):
+            out = fn(*args)
+        in_flight, self._results = self._results, out[1:]
+        if self._first:
+            self.drain()          # the compile span ends at its own arrival
+        elif in_flight is not None:
+            self._close(in_flight)
+            self._open(tags)
+        return out
+
+    def drain(self) -> None:
+        """Wait for the step in flight, if any, and close its span."""
+        if self._scope is not None:
+            self._close(self._results)
+            self._results = None
 
 
 def _make_pbar(config: TrainLoopConfig, initial: int = 0):
@@ -329,54 +420,44 @@ def _dispatch_training(states, step_fn, loader, mesh, logger, config,
 
     deferred = _DeferredMetrics(logger, config) if logger is not None else None
     tele = telemetry.active()
-    first_step = True  # first dispatch pays XLA compile → its own span
     last_losses = None
     preempted = False
-    while iteration < config.total_iterations and not preempted:
-        loader.set_epoch(epoch)
-        iteration += skip_in_epoch
-        skip, skip_in_epoch = skip_in_epoch, 0
-        for x, y in _data_wait_iter(loader.iter_from(skip), tele):
-            if iteration >= config.total_iterations:
-                break
-            faults.inject_step(iteration)  # chaos: kill/sigterm@step
-            bs = x.shape[0]
-            gx, gy = shard_batch((x, y), sharding)
-            if tele is not None:
-                _t0 = time.monotonic()
-            states, losses = step_fn(states, gx, gy)
-            if tele is not None:
-                if first_step:
-                    # Block on the first result so the span measures the
-                    # compile, not just the async dispatch.
-                    jax.block_until_ready(losses)
-                tele.record_span("compile" if first_step else "step",
-                                 _t0, time.monotonic() - _t0)
-            first_step = False
-            if wd is not None:
-                # Pet AFTER the step: the first pet must land past the XLA
-                # compile so the watchdog's first-deadline slack covers it.
-                wd.pet()
-            last_losses = losses
-            if deferred is not None and iteration % config.log_every == 0:
-                deferred.add(iteration, bs, losses)
-            iteration += 1
-            if ckpt is not None:
-                ckpt.maybe_save(
-                    iteration, states, {"iteration": iteration, "epoch": epoch}
-                )
+    with StepSpans(tele) as steps:
+        while iteration < config.total_iterations and not preempted:
+            loader.set_epoch(epoch)
+            iteration += skip_in_epoch
+            skip, skip_in_epoch = skip_in_epoch, 0
+            for x, y in _data_wait_iter(loader.iter_from(skip), tele):
+                if iteration >= config.total_iterations:
+                    break
+                faults.inject_step(iteration)  # chaos: kill/sigterm@step
+                bs = x.shape[0]
+                gx, gy = shard_batch((x, y), sharding)
+                states, losses = steps.run(iteration, step_fn, states, gx, gy)
                 if wd is not None:
-                    wd.pet()  # a save making I/O progress is not a hang
-            if (config.preempt_save and ckpt is not None
-                    and iteration < config.total_iterations
-                    and iteration % max(1, config.sync_every) == 0
-                    and _preemption_check()):
-                preempted = True
-                break
-            if pbar is not None:
-                pbar.update(1)
-        if not preempted:  # the preempted break leaves epoch mid-flight
-            epoch += 1
+                    # Pet AFTER the step: the first pet must land past the XLA
+                    # compile so the watchdog's first-deadline slack covers it.
+                    wd.pet()
+                last_losses = losses
+                if deferred is not None and iteration % config.log_every == 0:
+                    deferred.add(iteration, bs, losses)
+                iteration += 1
+                if ckpt is not None:
+                    ckpt.maybe_save(
+                        iteration, states, {"iteration": iteration, "epoch": epoch}
+                    )
+                    if wd is not None:
+                        wd.pet()  # a save making I/O progress is not a hang
+                if (config.preempt_save and ckpt is not None
+                        and iteration < config.total_iterations
+                        and iteration % max(1, config.sync_every) == 0
+                        and _preemption_check()):
+                    preempted = True
+                    break
+                if pbar is not None:
+                    pbar.update(1)
+            if not preempted:  # the preempted break leaves epoch mid-flight
+                epoch += 1
 
     if pbar is not None:
         pbar.close()
@@ -441,68 +522,59 @@ def _run_scanned(
     from tpudist.runtime import faults
 
     tele = telemetry.active()
-    first_window = True  # first dispatch pays XLA compile → its own span
     preempted = False
-    while iteration < total:
-        faults.inject_step(iteration)  # chaos: kill/sigterm at window edges
-        # window length: sync cadence, save cadence, and budget boundaries
-        k = min(max(1, config.sync_every), total - iteration)
-        if save_every > 0:
-            to_save = save_every - (iteration % save_every)
-            k = min(k, to_save)
-        if tele is not None:
-            _t0 = time.monotonic()
-        idx_rows = []
-        while len(idx_rows) < k:
-            if gen is None:
-                gen = global_batches(epoch)
-                for _ in range(batch_in_epoch):
-                    next(gen)
-                batch_in_epoch = 0
-            for row in gen:
-                idx_rows.append(row)
-                if len(idx_rows) == k:
-                    break
-            else:
-                gen = None
-                epoch += 1
-        if tele is not None:
+    with StepSpans(tele) as steps:
+        while iteration < total:
+            faults.inject_step(iteration)  # chaos: kill/sigterm at window edges
+            # window length: sync cadence, save cadence, and budget boundaries
+            k = min(max(1, config.sync_every), total - iteration)
+            if save_every > 0:
+                to_save = save_every - (iteration % save_every)
+                k = min(k, to_save)
+            idx_rows = []
             # host-side index/window assembly = the scanned path's data stall
-            tele.record_span("data_wait", _t0, time.monotonic() - _t0)
-            _t0 = time.monotonic()
-        idx = jax.device_put(np.stack(idx_rows).astype(np.int32), repl)
-        states, losses = chunk_step_fn(states, x_all, y_all, idx)
-        if tele is not None:
-            if first_window:
-                jax.block_until_ready(losses)  # span covers the compile
-            tele.record_span("compile" if first_window else "step",
-                             _t0, time.monotonic() - _t0,
-                             {"steps": len(idx_rows)})
-        first_window = False
-        if wd is not None:
-            # Pet AFTER the window: the first pet must land past the XLA
-            # compile so the watchdog's first-deadline slack covers it.
-            wd.pet()
-        last_losses = losses
-        if logger is not None:
-            pending_losses.append((iteration, losses))
-            if len(pending_losses) * k >= config.sync_every:
-                _flush_scanned(pending_losses, logger, config)
-                pending_losses = []
-        iteration += len(idx_rows)
-        if ckpt is not None:
-            ckpt.maybe_save(iteration, states, {"iteration": iteration, "epoch": epoch})
+            with telemetry.span(names.DATA_WAIT):
+                while len(idx_rows) < k:
+                    if gen is None:
+                        gen = global_batches(epoch)
+                        for _ in range(batch_in_epoch):
+                            next(gen)
+                        batch_in_epoch = 0
+                    for row in gen:
+                        idx_rows.append(row)
+                        if len(idx_rows) == k:
+                            break
+                    else:
+                        gen = None
+                        epoch += 1
+            idx = jax.device_put(np.stack(idx_rows).astype(np.int32), repl)
+            # the unit of this loop's ``step`` span is a window of k steps
+            states, losses = steps.run(iteration, chunk_step_fn, states,
+                                       x_all, y_all, idx, steps=len(idx_rows))
             if wd is not None:
-                wd.pet()  # a save making I/O progress is not a hang
-        if pbar is not None:
-            pbar.update(len(idx_rows))
-        # Window edges are the natural (all-process-agreed) preemption
-        # boundaries of the scanned path.  A signal during the FINAL
-        # window is not a preemption — the run completed.
-        if (config.preempt_save and ckpt is not None
-                and iteration < total and _preemption_check()):
-            preempted = True
-            break
+                # Pet AFTER the window: the first pet must land past the XLA
+                # compile so the watchdog's first-deadline slack covers it.
+                wd.pet()
+            last_losses = losses
+            if logger is not None:
+                pending_losses.append((iteration, losses))
+                if len(pending_losses) * k >= config.sync_every:
+                    _flush_scanned(pending_losses, logger, config)
+                    pending_losses = []
+            iteration += len(idx_rows)
+            if ckpt is not None:
+                ckpt.maybe_save(iteration, states, {"iteration": iteration, "epoch": epoch})
+                if wd is not None:
+                    wd.pet()  # a save making I/O progress is not a hang
+            if pbar is not None:
+                pbar.update(len(idx_rows))
+            # Window edges are the natural (all-process-agreed) preemption
+            # boundaries of the scanned path.  A signal during the FINAL
+            # window is not a preemption — the run completed.
+            if (config.preempt_save and ckpt is not None
+                    and iteration < total and _preemption_check()):
+                preempted = True
+                break
 
     if pbar is not None:
         pbar.close()

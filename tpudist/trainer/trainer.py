@@ -387,13 +387,12 @@ class Trainer:
         ordering are the SHARED helpers in :mod:`tpudist.train.loop`
         (``preemption_scope`` / ``finalize_run``) — one copy of that
         contract for every loop in the framework."""
-        import time
-
         import numpy as np
 
         from tpudist import telemetry
         from tpudist.train import token_sharding
         from tpudist.train.loop import (
+            StepSpans,
             TrainLoopConfig,
             _data_wait_iter,
             _make_pbar,
@@ -418,7 +417,6 @@ class Trainer:
 
         statusz.ensure_started()
         tele = telemetry.active()
-        first_step = True  # first dispatch pays XLA compile → its own span
 
         ts = token_sharding(mesh)
         batches = len(loader) if hasattr(loader, "__len__") else None
@@ -447,53 +445,44 @@ class Trainer:
         # must run with the SIGTERM handler still installed, or a second
         # signal during the grace window kills the process mid-save.
         with preemption_scope(ckpt is not None):
-            while iteration < self.max_steps and not preempted:
-                if hasattr(loader, "set_epoch"):
-                    loader.set_epoch(epoch)
-                it = iter(loader)
-                for _ in range(skip):
-                    next(it, None)
-                skip = 0
-                advanced = False
-                for tokens in _data_wait_iter(it, tele):
-                    advanced = True
-                    if iteration >= self.max_steps:
-                        break
-                    if tele is not None:
-                        _t0 = time.monotonic()
-                    state, loss = step(
-                        state, jax.device_put(
-                            np.asarray(tokens, dtype=np.int32), ts))
-                    if tele is not None:
-                        if first_step:
-                            # Block on the first result so the span
-                            # measures the compile, not the dispatch.
-                            jax.block_until_ready(loss)
-                        tele.record_span("compile" if first_step else "step",
-                                         _t0, time.monotonic() - _t0)
-                    first_step = False
-                    iteration += 1
-                    # The compiled LM step already reduces the loss over
-                    # the GLOBAL batch, so there is no per-rank value for
-                    # a host-fabric (metric_backend) reduction to merge —
-                    # rank-0 logging of the step loss is the whole story.
-                    if logger is not None and \
-                            iteration % max(1, self.log_every) == 0:
-                        logger.log({"loss/lm": float(loss)}, commit=True)
-                    if pbar is not None:
-                        pbar.update(1)
-                    if ckpt is not None:
-                        ckpt.maybe_save(iteration, state,
-                                        {"iteration": iteration,
-                                         "epoch": epoch})
-                        if (iteration < self.max_steps
-                                and _preemption_check()):
-                            preempted = True
+            with StepSpans(tele) as steps:
+                while iteration < self.max_steps and not preempted:
+                    if hasattr(loader, "set_epoch"):
+                        loader.set_epoch(epoch)
+                    it = iter(loader)
+                    for _ in range(skip):
+                        next(it, None)
+                    skip = 0
+                    advanced = False
+                    for tokens in _data_wait_iter(it, tele):
+                        advanced = True
+                        if iteration >= self.max_steps:
                             break
-                if not advanced:
-                    raise ValueError("LM loader yielded no batches")
-                if not preempted:
-                    epoch += 1
+                        state, loss = steps.run(
+                            iteration, step, state, jax.device_put(
+                                np.asarray(tokens, dtype=np.int32), ts))
+                        iteration += 1
+                        # The compiled LM step already reduces the loss over
+                        # the GLOBAL batch, so there is no per-rank value for
+                        # a host-fabric (metric_backend) reduction to merge —
+                        # rank-0 logging of the step loss is the whole story.
+                        if logger is not None and \
+                                iteration % max(1, self.log_every) == 0:
+                            logger.log({"loss/lm": float(loss)}, commit=True)
+                        if pbar is not None:
+                            pbar.update(1)
+                        if ckpt is not None:
+                            ckpt.maybe_save(iteration, state,
+                                            {"iteration": iteration,
+                                             "epoch": epoch})
+                            if (iteration < self.max_steps
+                                    and _preemption_check()):
+                                preempted = True
+                                break
+                    if not advanced:
+                        raise ValueError("LM loader yielded no batches")
+                    if not preempted:
+                        epoch += 1
             if pbar is not None:
                 pbar.close()
             finalize_run(state, iteration=iteration, epoch=epoch,
