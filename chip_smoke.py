@@ -541,7 +541,8 @@ def phase_kernels(w: Widths, *, seed: int, interpret: bool = False,
     import jax.numpy as jnp
     import numpy as np
 
-    from tpudist.ops import flash_attention
+    from tpudist.models.transformer import merge_heads
+    from tpudist.ops import flash_attention, flash_attention_packed
     from tpudist.parallel import attention_reference
 
     report = {}
@@ -584,6 +585,29 @@ def phase_kernels(w: Widths, *, seed: int, interpret: bool = False,
         say("kernels", case=f"flash/bf16/dh{w.dh}/tiles{bq}x{bk}/fwd+bwd",
             wall_s=time.perf_counter() - t0, max_rel_err=max(errs))
         report[f"flash_{bq}x{bk}"] = max(errs)
+
+        # the packed entry (what Block's training arm calls at dh % 128 ==
+        # 0) runs the same kernel bodies over the same tiles: the same bits
+        def loss_packed(qkv, bq=bq, bk=bk):
+            return (flash_attention_packed(qkv, w.n_heads, w.n_heads, True,
+                                           bq, bk, interpret)
+                    .astype(jnp.float32) ** 2).sum()
+
+        t0 = time.perf_counter()
+        loss, cotangent = jax.jit(jax.value_and_grad(loss_packed))(
+            jnp.concatenate([merge_heads(a) for a in (q, k, v)], axis=-1))
+        # (the loss sums o in another order; its cotangent 2·o, and dq, dk,
+        # dv behind it, are element for element the head-major ones)
+        same = bool(jnp.array_equal(
+            cotangent,
+            jnp.concatenate([merge_heads(g) for g in got[1]], axis=-1)))
+        if not same or abs(float(loss) / float(got[0]) - 1) > 1e-5:
+            raise AssertionError(f"packed flash tiles {bq}/{bk} differs from "
+                                 "the head-major entry on the same values")
+        say("kernels", case=f"flash_packed/bf16/dh{w.dh}/tiles{bq}x{bk}"
+            "/fwd+bwd", wall_s=time.perf_counter() - t0,
+            equals_head_major=same)
+        report[f"flash_packed_{bq}x{bk}"] = 0.0
     for case in kernel_cases(w):
         args = case.make(np.random.default_rng(seed))
         t0 = time.perf_counter()

@@ -108,6 +108,25 @@ def test_flash_attention_compiles(one_chip, tiling, grad):
     assert text.count("tpu_custom_call") == (3 if grad else 1)
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_packed_flash_attention_compiles_at_the_cells_shape(one_chip, grad):
+    """The packed entry's blocks — ``(1, 1024, 128)`` column blocks of a
+    ``[4, 2048, 36·128]`` array — at cell ``cgpt590m-train-1chip``'s shape
+    and the tuned tiles: what Mosaic's block rule has to accept."""
+    from tpudist.ops import flash_attention_packed
+
+    b, s, h, dh = 4, 2048, 12, 128
+
+    def loss(qkv):
+        return flash_attention_packed(qkv, h, h, True, 1024, 1024,
+                                      False).astype(jnp.float32).sum()
+
+    qkv = jax.ShapeDtypeStruct((b, s, 3 * h * dh), jnp.bfloat16)
+    text = _compile(jax.grad(loss) if grad else loss, (qkv,),
+                    one_chip).as_text()
+    assert text.count("tpu_custom_call") == (3 if grad else 1)
+
+
 KERNEL_FAMILIES = ("paged_attention", "paged_prefill", "fused_sample",
                    "fused_residual", "fused_rope_qkv", "lora_delta",
                    "fused_mlp")
@@ -178,6 +197,57 @@ def test_d1024_train_step_compiles_with_the_flash_kernel(d1024_step):
     # params + Adam moments + activations fit the chip's 16 GB of HBM
     mem = d1024_step.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 15e9
+
+
+def _entry_ops(text: str):
+    """``(opcode, shape, op_name)`` of every instruction the compiled
+    module runs as an operation of its own: everything outside the
+    computations a fusion or a reduction calls."""
+    import re
+
+    called = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", text))
+    comp = None
+    for line in text.split("\n"):
+        header = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if header:
+            comp = header.group(1)
+            continue
+        found = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (.*?)\s([a-z][\w\-]*)\(",
+                         line)
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if found and op_name and comp not in called:
+            yield found.group(2), found.group(1), op_name.group(1)
+
+
+def test_d1024_step_moves_no_activation_round_the_attention(d1024_step):
+    """Under scope ``attn`` nothing of ``b·s·d`` elements or more is
+    transposed, copied or concatenated as an operation of its own: the
+    flash kernels read the projection's output where it lies and the
+    projection's backward reads dq, dk, dv where the kernels left them
+    (the join is fused into its matmuls).  The parent's step had eleven
+    such copies a layer.  The small f32 stats of ``delta`` may move."""
+    import math
+    import re
+
+    from tpudist.telemetry import names
+
+    moved = []
+    for opcode, shape, op_name in _entry_ops(d1024_step.as_text()):
+        if (opcode in ("copy", "transpose", "concatenate")
+                and f"/{names.ATTN}/" in op_name):
+            sizes = [math.prod(int(n) for n in dims.split(",") if n)
+                     for dims in re.findall(r"\[([\d,]*)\]", shape)]
+            if max(sizes, default=0) >= W.batch * W.seq * W.d_model:
+                moved.append((opcode, shape, op_name))
+    assert not moved, moved
+
+
+def test_d1024_step_needs_no_more_temporaries_than_head_major(d1024_step):
+    # With head-major operands (q, k, v, o transposed and a seq-major copy
+    # of o, all kept for the backward) this step compiled to 3,178,668,032
+    # bytes of temporaries (PR 27's parent); packed it keeps the projection's
+    # output and one o a layer: 2,908,942,336.
+    assert d1024_step.memory_analysis().temp_size_in_bytes <= 3_178_668_032
 
 
 def _kernels_named(text: str) -> list:

@@ -2,12 +2,20 @@
 checked against the dense XLA references — the pattern SURVEY.md §4
 prescribes for doing better than the reference's zero-test strategy."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpudist.ops import flash_attention, fused_mlp, mlp_reference, pad_params
+from tpudist.ops import (
+    flash_attention,
+    flash_attention_packed,
+    fused_mlp,
+    mlp_reference,
+    pad_params,
+)
 from tpudist.parallel import attention_reference
 
 
@@ -190,6 +198,75 @@ class TestFlashAttention:
                 np.asarray(a, np.float32), np.asarray(b, np.float32),
                 atol=0.15, rtol=0.15,
             )
+
+
+# (heads, kv heads, head_dim, seq, block_q, block_k, causal, window, dtype)
+PACKED = {
+    "mha": (4, 4, 32, 128, 64, 64, True, None, jnp.float32),
+    "gqa": (4, 2, 32, 128, 64, 64, True, None, jnp.float32),
+    "mqa-window": (4, 1, 32, 128, 32, 64, True, 48, jnp.float32),
+    "non-causal": (2, 2, 32, 128, 64, 32, False, None, jnp.float32),
+    "bq-below-seq-dh128-bf16": (4, 2, 128, 256, 64, 128, True, None,
+                                jnp.bfloat16),
+}
+
+
+class TestFlashAttentionPacked:
+    """The packed entry reads q, k, v out of one ``[b, s, (h + 2·kv)·dh]``
+    array and writes ``[b, s, h·dh]``: the same kernel bodies over the
+    same tiles in the same order as the head-major entry, so the same
+    bits, forward and backward."""
+
+    @pytest.mark.parametrize("case", sorted(PACKED))
+    def test_packed_equals_head_major_bit_for_bit(self, case):
+        from tpudist.models.transformer import merge_heads, split_heads
+
+        h, kv, d, seq, bq, bk, causal, window, dtype = PACKED[case]
+        qkv = jax.random.normal(jax.random.PRNGKey(0),
+                                (2, seq, (h + 2 * kv) * d), dtype)
+        w = jax.random.normal(jax.random.PRNGKey(1), (2, seq, h * d),
+                              jnp.float32)
+
+        def packed(qkv):
+            return flash_attention_packed(qkv, h, kv, causal, bq, bk, True,
+                                          window)
+
+        def head_major(qkv, attention=flash_attention):
+            q, k, v = split_heads(qkv, h, kv)
+            if attention is attention_reference:
+                group = h // kv
+                return merge_heads(attention_reference(
+                    q, jnp.repeat(k, group, 1), jnp.repeat(v, group, 1),
+                    causal=causal, window=window))
+            return merge_heads(attention(q, k, v, causal, bq, bk, True,
+                                         window))
+
+        def cotangent(fn):
+            return jax.grad(lambda x: jnp.sum(
+                fn(x).astype(jnp.float32) * w))(qkv)
+
+        out, g = packed(qkv), cotangent(packed)
+        assert out.shape == (2, seq, h * d) and g.shape == qkv.shape
+        assert g.dtype == qkv.dtype
+        np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                      np.asarray(head_major(qkv), np.float32))
+        np.testing.assert_array_equal(
+            np.asarray(g, np.float32),
+            np.asarray(cotangent(head_major), np.float32))
+        # and against the dense reference, at the flash tests' tolerances
+        tol = (dict(atol=0.15, rtol=0.15) if dtype == jnp.bfloat16
+               else dict(atol=5e-5, rtol=5e-5))
+        ref = functools.partial(head_major, attention=attention_reference)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref(qkv), np.float32), **tol)
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(cotangent(ref), np.float32),
+                                   **tol)
+
+    def test_a_width_that_holds_no_whole_heads_raises(self):
+        with pytest.raises(ValueError, match="does not hold"):
+            flash_attention_packed(jnp.zeros((1, 64, 100)), 4, 2, True, 64,
+                                   64, True)
 
 
 class TestFusedMLP:

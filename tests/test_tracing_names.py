@@ -344,3 +344,57 @@ def test_initialize_records_one_init_span_in_a_single_process(
     finally:
         telemetry.finish(write_report=False)
         monkeypatch.setattr(bootstrap, "_INITIALIZED_CTX", None)
+
+
+# ---------------------------------------------------------------------------
+# (f) the attention's operand layout, said once a call site when it is traced
+
+
+def test_attn_layout_is_spelled_once_in_the_vocabulary():
+    from pathlib import Path
+
+    import tpudist
+
+    package = Path(tpudist.__file__).resolve().parent
+    spelled = [p.relative_to(package).as_posix()
+               for p in sorted(package.rglob("*.py"))
+               if f'"{names.ATTN_LAYOUT}"' in p.read_text()]
+    assert spelled == ["telemetry/names.py"]
+
+
+def test_attn_layout_fires_once_a_call_site_at_trace_time_and_not_a_step(
+        tmp_path):
+    from tpudist.models import create_transformer
+    from tpudist.runtime.mesh import MeshConfig, make_mesh
+    from tpudist.train import init_lm_state, make_lm_train_step
+
+    layers = 3
+    module, params = create_transformer(
+        jax.random.PRNGKey(2), seq_len=8, vocab=32, d_model=16,
+        n_layers=layers, n_heads=2, d_ff=32)
+    tx = optax.adam(1e-2)
+    mesh = make_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    tokens = jnp.arange(16, dtype=jnp.int32).reshape(2, 8) % 32
+    state = init_lm_state(params, tx)
+    session = telemetry.start(tmp_path / "tele", rank=0, generation=0)
+    try:
+        def events():
+            return [r for r in session.ring if r["name"] == names.ATTN_LAYOUT]
+
+        step = make_lm_train_step(module.apply, tx, mesh, donate_state=False)
+        state, _ = step(state, tokens)       # traced here: one a layer
+        assert len(events()) == layers
+        # a CPU, and 8 tokens: the reason names the first test that failed
+        assert all(r["kind"] == "event" and r["layout"] == names.HEAD_MAJOR
+                   and r["reason"] == names.WHY_SEQ for r in events())
+        # (the state a step returns is laid out on the mesh, the seeded one
+        # was not: jit traces once more for it, and that trace speaks too)
+        state, _ = step(state, tokens)
+        traced = len(events())
+        assert traced in (layers, 2 * layers)
+        for _ in range(3):                   # the compiled step says nothing
+            state, loss = step(state, tokens)
+        jax.block_until_ready(loss)
+        assert len(events()) == traced
+    finally:
+        telemetry.finish(write_report=False)

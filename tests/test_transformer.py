@@ -1069,3 +1069,132 @@ class TestRematPolicies:
         bad = mod.clone(remat=True, remat_policy="everything")
         with pytest.raises(ValueError, match="remat_policy"):
             bad.apply(params, _tokens(batch=1, seq=16))
+
+
+class _AsTpu:
+    """The first CPU device, answering ``platform`` as a TPU would: what
+    ``make_length_aware_attention`` asks before it picks a route."""
+
+    platform = "tpu"
+
+    def __init__(self, device):
+        self._device = device
+
+    def __getattr__(self, name):
+        return getattr(self._device, name)
+
+
+@pytest.fixture
+def as_tpu(monkeypatch):
+    """Steer the attention closure the way a v5e would — from the test, not
+    through an option of the program: the platform reads ``tpu``, the flash
+    kernels start at seq 128 with 64-wide tiles, and the kernels the closure
+    then picks run in the Pallas interpreter."""
+    import tpudist.ops as ops
+
+    def interpreted(kernel):
+        def run(*args):
+            return kernel(*args[:6], True, *args[7:])   # args[6]: interpret
+        return run
+
+    real = jax.devices()
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a, **k: [_AsTpu(real[0])] + real[1:])
+    monkeypatch.setenv("TPUDIST_FLASH_MIN_SEQ", "128")
+    monkeypatch.setenv("TPUDIST_FLASH_BLOCK_Q", "64")
+    monkeypatch.setenv("TPUDIST_FLASH_BLOCK_K", "64")
+    monkeypatch.setattr(ops, "flash_attention_packed",
+                        interpreted(ops.flash_attention_packed))
+    monkeypatch.setattr(ops, "flash_attention",
+                        interpreted(ops.flash_attention))
+
+
+@pytest.fixture
+def layouts(tmp_path):
+    """The ``attn_layout`` events recorded while the test runs, as
+    ``(layout, reason)`` pairs."""
+    from tpudist import telemetry
+    from tpudist.telemetry import names
+
+    session = telemetry.start(tmp_path / "tele", rank=0, generation=0)
+    try:
+        yield lambda: [(r["layout"], r.get("reason")) for r in session.ring
+                       if r["name"] == names.ATTN_LAYOUT]
+    finally:
+        telemetry.finish(write_report=False)
+
+
+def _untagged(attention_fn):
+    """The same attention as an injected ``attention_fn`` with no packed
+    route: ``Block`` keeps its head-major path for it."""
+    def fn(q, k, v):
+        return attention_fn(q, k, v)
+    fn.supports_gqa = True
+    return fn
+
+
+class TestPackedAttentionRoute:
+    """``Block`` hands the default attention the fused projection's own
+    ``[b, s, 3·d]`` output; the closure takes the packed flash kernels where
+    it can see that they fit (TPU, long enough, ``dh % 128 == 0``) and the
+    head-major route everywhere else, and says which in ``attn_layout``."""
+
+    def _loss_and_grads(self, seq, attention_fn=None, **overrides):
+        from tpudist.models.transformer import _default_attention
+
+        cfg = dict(vocab=32, d_model=256, n_layers=2, n_heads=2, d_ff=256,
+                   max_len=seq) | overrides
+        module, params = create_transformer(
+            jax.random.PRNGKey(0), seq_len=seq,
+            attention_fn=attention_fn and _untagged(_default_attention),
+            **cfg)
+        tokens = _tokens(batch=2, seq=seq)
+        return jax.value_and_grad(
+            lambda p: lm_loss(module.apply(p, tokens), tokens))(params)
+
+    @pytest.mark.parametrize("kv_heads", [None, 1], ids=["mha", "gqa"])
+    def test_packed_route_equals_head_major_at_dh_128(self, as_tpu, layouts,
+                                                      kv_heads):
+        loss_p, grads_p = self._loss_and_grads(128, n_kv_heads=kv_heads)
+        # init (always the default attention), then the differentiated
+        # trace: one event a layer each
+        assert layouts() == [("packed", None)] * 4
+        loss_h, grads_h = self._loss_and_grads(128, attention_fn="untagged",
+                                               n_kv_heads=kv_heads)
+        assert layouts()[4:] == ([("packed", None)] * 2
+                                 + [("head_major", "custom_fn")] * 2)
+        np.testing.assert_allclose(loss_p, loss_h, rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(grads_p), jax.tree.leaves(grads_h)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+    @pytest.mark.parametrize("reason", ["dh", "seq", "platform"])
+    def test_other_shapes_and_platforms_stay_head_major(
+            self, reason, request, layouts, monkeypatch):
+        if reason == "platform":     # long enough, tiles fit, but a CPU
+            monkeypatch.setenv("TPUDIST_FLASH_MIN_SEQ", "128")
+            monkeypatch.setenv("TPUDIST_FLASH_BLOCK_Q", "64")
+            monkeypatch.setenv("TPUDIST_FLASH_BLOCK_K", "64")
+        else:
+            request.getfixturevalue("as_tpu")
+        seq = 64 if reason == "seq" else 128
+        heads = 4 if reason == "dh" else 2            # dh 64 / dh 128
+        loss, _ = self._loss_and_grads(seq, n_heads=heads)
+        assert np.isfinite(loss)
+        assert layouts() == [("head_major", reason)] * 4
+
+    @pytest.mark.parametrize("kv_heads", [None, 2], ids=["mha", "gqa"])
+    def test_rope_on_the_packed_view_equals_rope_head_major(self, kv_heads):
+        """``Block`` rotates q's and k's heads on the ``[b, s, h, dh]`` view
+        of the packed tensor; the head-major arm rotates ``[b, h, s, dh]``:
+        the same angles on the same numbers."""
+        from tpudist.models.transformer import _default_attention
+
+        cfg = dict(CFG, rope=True, n_kv_heads=kv_heads)
+        tokens = _tokens()
+        packed_mod, params = create_transformer(jax.random.PRNGKey(0),
+                                                seq_len=64, **cfg)
+        head_mod, _ = create_transformer(
+            jax.random.PRNGKey(0), seq_len=64,
+            attention_fn=_untagged(_default_attention), **cfg)
+        np.testing.assert_array_equal(packed_mod.apply(params, tokens),
+                                      head_mod.apply(params, tokens))

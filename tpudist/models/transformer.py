@@ -7,7 +7,9 @@ Pallas attention kernel (``tpudist.ops``) a real consumer, designed
 TPU-first:
 
 - **pluggable attention op**: the block calls an injected
-  ``attention_fn(q, k, v) -> out`` over ``[batch, heads, seq, head_dim]``.
+  ``attention_fn(q, k, v) -> out`` over ``[batch, heads, seq, head_dim]``
+  (or, where the fn carries a ``packed`` route as the default does, hands
+  it the fused projection's ``[batch, seq, 3·d]`` output as it lies).
   Three interchangeable implementations ship: the dense XLA reference
   (:func:`tpudist.parallel.attention_reference`), the Pallas flash kernel
   (:func:`tpudist.ops.flash_attention`), and ring attention over a
@@ -30,13 +32,14 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tpudist import telemetry
 from tpudist.parallel.ring_attention import attention_reference
 from tpudist.telemetry import names
 
 AttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
 
 
-def _per_shard(kernel, q, k, v):
+def _per_shard(kernel, *operands):
     """Run a Pallas attention ``kernel`` on each device's own batch rows.
 
     Mosaic kernels cannot be partitioned automatically: inside a jit over
@@ -45,10 +48,12 @@ def _per_shard(kernel, q, k, v):
     model does not know the mesh, so the step builders
     (``tpudist.train.lm``) trace under it as JAX's ambient mesh, and this
     wraps the kernel in a ``shard_map`` over its ``data`` axis — attention
-    rows are independent per batch element.  Heads are not split: under
-    tensor parallelism every ``model`` shard computes all heads.  One
-    device, no ambient mesh, or already inside a ``shard_map`` body (ring
-    attention, the pipeline schedules): the kernel runs as it is.
+    rows are independent per batch element, which is the leading axis of
+    every operand (``q, k, v`` head-major, or the one packed ``qkv``) and
+    of the result.  Heads are not split: under tensor parallelism every
+    ``model`` shard computes all heads.  One device, no ambient mesh, or
+    already inside a ``shard_map`` body (ring attention, the pipeline
+    schedules): the kernel runs as it is.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -56,12 +61,35 @@ def _per_shard(kernel, q, k, v):
 
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.size == 1 or mesh.manual_axes:
-        return kernel(q, k, v)
+        return kernel(*operands)
     data = (AXIS_DATA if AXIS_DATA in mesh.axis_names
-            and q.shape[0] % mesh.shape[AXIS_DATA] == 0 else None)
+            and operands[0].shape[0] % mesh.shape[AXIS_DATA] == 0 else None)
     spec = P(data)
-    return jax.shard_map(kernel, in_specs=(spec, spec, spec), out_specs=spec,
-                         check_vma=False)(q, k, v)
+    return jax.shard_map(kernel, in_specs=(spec,) * len(operands),
+                         out_specs=spec, check_vma=False)(*operands)
+
+
+def split_heads(qkv: jax.Array, n_heads: int, n_kv: int):
+    """The fused projection's ``[b, s, (n_heads + 2·n_kv)·dh]`` output cut
+    into head-major ``q [b, n_heads, s, dh]`` and ``k, v [b, n_kv, s,
+    dh]`` — what every attention but the packed flash route takes."""
+    b, s, cols = qkv.shape
+    dh = cols // (n_heads + 2 * n_kv)
+
+    def heads(t, n):  # [b, s, n·dh] -> [b, n, s, dh]
+        return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
+
+    d, kv_dim = n_heads * dh, n_kv * dh
+    return (heads(qkv[..., :d], n_heads),
+            heads(qkv[..., d : d + kv_dim], n_kv),
+            heads(qkv[..., d + kv_dim :], n_kv))
+
+
+def merge_heads(attn: jax.Array) -> jax.Array:
+    """``[b, h, s, dh]`` back to the ``[b, s, h·dh]`` the output projection
+    reads."""
+    b, h, s, dh = attn.shape
+    return attn.transpose(0, 2, 1, 3).reshape(b, s, h * dh)
 
 
 def make_length_aware_attention(window: Optional[int] = None):
@@ -78,8 +106,19 @@ def make_length_aware_attention(window: Optional[int] = None):
     The result accepts grouped-query K/V (fewer heads than q): the flash
     kernels consume it natively — KV tiles are fetched once per group,
     never materialized at full head count; the non-kernel paths broadcast.
+
+    Two operand layouts.  ``attend(q, k, v)`` is head-major, ``[b, h, s,
+    dh]``.  ``attend.packed(qkv, n_heads, n_kv)`` takes the fused
+    projection's own ``[b, s, (n_heads + 2·n_kv)·dh]`` output and returns
+    ``[b, s, n_heads·dh]``: ``Block`` calls it when an attention_fn carries
+    the tag.  The rule that picks is what the code can observe, the same
+    one that picks the flash kernels, plus ``dh % 128 == 0``; one
+    ``attn_layout`` event a traced call site says what it chose.
     """
-    def attend(q, k, v):
+    def route(seq: int):
+        """``(why_not, block_q, block_k)``: the tiles for this length and
+        why the flash kernels do not take it here (``names.WHY_SEQ`` /
+        ``WHY_PLATFORM``), ``None`` when they do."""
         from tpudist.utils.tuning import tuned
 
         # Measured-on-v5e defaults, re-tunable per platform generation
@@ -89,14 +128,21 @@ def make_length_aware_attention(window: Optional[int] = None):
         # Wider KV tiles amortize the per-tile grid overhead once the KV
         # sweep is long (8192: 6.8 vs 8.7 ms fwd+bwd — flash_sweep).
         bk_long = tuned("flash_block_k_long")
-        seq = q.shape[2]
         bk = (bk_long if seq >= tuned("flash_long_seq")
               and seq % bk_long == 0 else tuned("flash_block_k"))
         # BOTH tile sizes must divide seq (the kernel's contract) — with
         # independently overridable knobs a bad combination routes to the
         # fallbacks instead of crashing at trace time.
-        blocks_fit = seq >= min_seq and seq % bq == 0 and seq % bk == 0
-        use_flash = blocks_fit and jax.devices()[0].platform == "tpu"
+        why_not = None
+        if not (seq >= min_seq and seq % bq == 0 and seq % bk == 0):
+            why_not = names.WHY_SEQ
+        elif jax.devices()[0].platform != "tpu":
+            why_not = names.WHY_PLATFORM
+        return why_not, bq, bk
+
+    def attend(q, k, v):
+        why_not, bq, bk = route(q.shape[2])
+        use_flash = why_not is None
         if not use_flash and k.shape[1] != q.shape[1]:
             # only the flash kernels consume grouped K/V natively
             group = q.shape[1] // k.shape[1]
@@ -108,12 +154,34 @@ def make_length_aware_attention(window: Optional[int] = None):
             return _per_shard(
                 lambda q, k, v: flash_attention(q, k, v, True, bq, bk, False,
                                                 window), q, k, v)
-        if not blocks_fit:
+        if why_not == names.WHY_SEQ:
             return attention_reference(q, k, v, causal=True, window=window)
         from tpudist.ops import blockwise_attention
 
         return blockwise_attention(q, k, v, causal=True, block_k=bk,
                                    window=window)
+
+    def attend_packed(qkv, n_heads: int, n_kv: int):
+        """The same attention over the fused projection's own ``[b, s,
+        (n_heads + 2·n_kv)·dh]`` output, giving the ``[b, s, n_heads·dh]``
+        the output projection reads.  Where the flash kernels run and one
+        head is a whole number of 128-lane tiles (``dh % 128 == 0``) they
+        index that layout themselves and nothing is re-laid out round
+        them; everywhere else: split, :func:`attend`, merge."""
+        dh = qkv.shape[-1] // (n_heads + 2 * n_kv)
+        why_not, bq, bk = route(qkv.shape[1])
+        if why_not is None and dh % 128:
+            why_not = names.WHY_DH
+        if why_not is not None:
+            telemetry.event(names.ATTN_LAYOUT, layout=names.HEAD_MAJOR,
+                            reason=why_not)
+            return merge_heads(attend(*split_heads(qkv, n_heads, n_kv)))
+        from tpudist.ops import flash_attention_packed
+
+        telemetry.event(names.ATTN_LAYOUT, layout=names.PACKED)
+        return _per_shard(
+            lambda qkv: flash_attention_packed(qkv, n_heads, n_kv, True, bq,
+                                               bk, False, window), qkv)
 
     # Block consults this tag before broadcasting K/V to full head count —
     # this path handles grouped-query inputs itself (see above).
@@ -122,6 +190,9 @@ def make_length_aware_attention(window: Optional[int] = None):
     # sliding_window field (decode-cache masking alone is not windowed
     # training — the mismatch must be loud, not silent).
     attend.window = window
+    # Block hands an attention_fn that carries this tag the projection's
+    # packed output instead of head-major q, k, v.
+    attend.packed = attend_packed
     return attend
 
 
@@ -139,8 +210,11 @@ def rope_angles(offset, seq: int, half: int, base: float) -> jax.Array:
     return positions[..., None] * freqs
 
 
-def rope_rotate(x: jax.Array, base: float = 10000.0, offset=0) -> jax.Array:
-    """Rotary position embedding over ``[batch, heads, seq, head_dim]``.
+def rope_rotate(x: jax.Array, base: float = 10000.0, offset=0,
+                seq_axis: int = 2) -> jax.Array:
+    """Rotary position embedding over ``[batch, heads, seq, head_dim]``, or
+    with ``seq_axis=1`` over the ``[batch, seq, heads, head_dim]`` view of
+    a projection's output.
 
     Angles are computed in f32 (precision-sensitive at long context) on the
     GLOBAL sequence axis — callers apply it before any seq sharding, so
@@ -151,8 +225,10 @@ def rope_rotate(x: jax.Array, base: float = 10000.0, offset=0) -> jax.Array:
     KV-cache decode path rotates tokens at their absolute position.
     """
     half = x.shape[-1] // 2
-    angles = rope_angles(offset, x.shape[-2], half, base)
-    if angles.ndim == 3:
+    angles = rope_angles(offset, x.shape[seq_axis], half, base)
+    if seq_axis == 1:
+        angles = angles[..., None, :]                # [(b,) s, 1, half]
+    elif angles.ndim == 3:
         # per-batch offsets: broadcast over the heads axis
         angles = angles[:, None]                     # [b, 1, s, half]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
@@ -162,6 +238,18 @@ def rope_rotate(x: jax.Array, base: float = 10000.0, offset=0) -> jax.Array:
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
     ).astype(x.dtype)
+
+
+def rope_rotate_packed(qkv: jax.Array, n_rotated: int, dh: int) -> jax.Array:
+    """:func:`rope_rotate` on a fused projection's ``[b, s, (h + 2·kv)·dh]``
+    output as it lies: q's and k's heads are its leading ``n_rotated``
+    column blocks, rotated on the ``[b, s, heads, dh]`` view (the sequence
+    is axis 1 there); v's pass through."""
+    b, s, _ = qkv.shape
+    by_head = qkv.reshape(b, s, -1, dh)
+    return jnp.concatenate(
+        [rope_rotate(by_head[:, :, :n_rotated], seq_axis=1),
+         by_head[:, :, n_rotated:]], axis=2).reshape(qkv.shape)
 
 
 def moe_expert_fn(params, tokens):
@@ -406,6 +494,16 @@ class Block(nn.Module):
                     "decode_kernel='paged'/'paged_prefill' (training and the "
                     "dense decode path keep the unfused projection)")
             rotated = False
+            # The training arm hands an attention_fn that carries a
+            # ``packed`` route (the default: make_length_aware_attention)
+            # the projection's own [b, s, (n_heads + 2·n_kv)·dh] output and
+            # takes [b, s, d] back: on TPU at dh % 128 == 0 the flash
+            # kernels index that layout and nothing is transposed, sliced
+            # or copied round them.  Decode, and an injected attention_fn
+            # without the tag (ring attention, a user's own), get head-major
+            # q, k, v [b, h, s, dh] as ever.
+            packed = None if self.decode else getattr(
+                self.attention_fn, "packed", None)
             if use_fused_qkv:
                 from tpudist.ops.fused_linear import fused_rope_qkv
                 # same qkv/kernel param as the Dense twin (_Kernel seam)
@@ -430,17 +528,8 @@ class Block(nn.Module):
                 qkv = nn.Dense(self.d_model + 2 * kv_dim, use_bias=False,
                                name="qkv", dtype=self.dtype)(h)
                 qkv = _ad(qkv, h, "a_qkv", "b_qkv")
-                q = qkv[..., : self.d_model]
-                k = qkv[..., self.d_model : self.d_model + kv_dim]
-                v = qkv[..., self.d_model + kv_dim :]
-
-                def heads(t, n):  # [b, s, n·dh] -> [b, n, s, dh]
-                    b, s, _ = t.shape
-                    return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
-
-                q = heads(q, self.n_heads)
-                k = heads(k, n_kv)
-                v = heads(v, n_kv)
+                if packed is None:
+                    q, k, v = split_heads(qkv, self.n_heads, n_kv)
             if self.decode:
                 if self.decode_kernel == "paged":
                     attn = self._decode_attention_paged(q, k, v,
@@ -456,6 +545,7 @@ class Block(nn.Module):
                         "the Pallas paged-prefill kernel)")
                 else:
                     attn = self._decode_attention(q, k, v)
+                attn = merge_heads(attn)
             else:
                 fn_window = getattr(self.attention_fn, "window", None)
                 if (self.sliding_window is not None
@@ -472,16 +562,22 @@ class Block(nn.Module):
                         "attention_fn built with the same window (e.g. "
                         "make_length_aware_attention(window)), or tag a "
                         "custom fn with .window")
-                if self.rope:
-                    q, k = rope_rotate(q), rope_rotate(k)
-                if n_kv != self.n_heads and not getattr(
-                        self.attention_fn, "supports_gqa", False):
-                    group = self.n_heads // n_kv
-                    k = jnp.repeat(k, group, axis=1)
-                    v = jnp.repeat(v, group, axis=1)
-                attn = self.attention_fn(q, k, v)
-            b, nh, s, _ = attn.shape
-            attn = attn.transpose(0, 2, 1, 3).reshape(b, s, self.d_model)
+                if packed is not None:
+                    if self.rope:
+                        qkv = rope_rotate_packed(qkv, self.n_heads + n_kv, dh)
+                    attn = packed(qkv, self.n_heads, n_kv)
+                else:
+                    telemetry.event(names.ATTN_LAYOUT,
+                                    layout=names.HEAD_MAJOR,
+                                    reason=names.WHY_CUSTOM_FN)
+                    if self.rope:
+                        q, k = rope_rotate(q), rope_rotate(k)
+                    if n_kv != self.n_heads and not getattr(
+                            self.attention_fn, "supports_gqa", False):
+                        group = self.n_heads // n_kv
+                        k = jnp.repeat(k, group, axis=1)
+                        v = jnp.repeat(v, group, axis=1)
+                    attn = merge_heads(self.attention_fn(q, k, v))
             x = x + nn.Dense(self.d_model, use_bias=False, name="proj",
                              dtype=self.dtype)(attn)
 

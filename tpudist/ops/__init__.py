@@ -25,6 +25,7 @@ as fallbacks and in correctness tests (interpret mode on CPU).
 from tpudist.ops.flash_attention import (  # noqa: F401
     blockwise_attention,
     flash_attention,
+    flash_attention_packed,
     flash_attention_with_lse,
 )
 from tpudist.ops.paged_attention import (  # noqa: F401

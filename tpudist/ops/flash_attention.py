@@ -20,6 +20,33 @@ same way the forward does.  Fwd and bwd match ``attention_reference``
 numerically (see tests).  ``blockwise_attention`` (plain-XLA scan with the
 same online-softmax math) remains as the kernel-free fallback path.
 
+Two operand layouts, one set of kernel bodies.  The three ``pallas_call``s
+are built once (``_flash_forward``, ``_flash_backward``) over a
+:class:`_Layout` that says where one head's ``[tile, d]`` block lives in
+each array; the kernels only ever see that block.
+
+- *head-major* — :func:`flash_attention`, :func:`flash_attention_with_lse`:
+  ``q [b, h, s, d]``, ``k, v [b, h_kv, s, d]``, seen as ``[b·h, s, d]``.
+  K/V are arrays of their own, which is what ring attention rotates
+  between chips; the pipeline schedules and any injected
+  ``attention_fn`` use it too.
+- *packed* — :func:`flash_attention_packed`: a fused q/k/v projection's own
+  ``[b, s, (h + 2·h_kv)·d]`` output in, ``[b, s, h·d]`` out (what the
+  output projection reads), ``do`` in that layout and ONE cotangent back.
+  A head is a ``d``-wide column block, so with ``d`` a multiple of the
+  128 lanes a ``(1, tile, d)`` block at ``(batch, tile, head)`` is a legal
+  Mosaic block and nothing is transposed, sliced or copied round the
+  kernels.  dq, dk, dv leave the kernels as three ``[b, s, ·]`` arrays
+  and are joined by a ``concatenate`` that XLA fuses into the
+  projection's backward matmuls (no device op of its own in the compiled
+  step; the other way, one ``[b, s, 3·d]`` buffer passed from the dk/dv
+  call to the dq call through ``input_output_aliases``, cannot hold all
+  three: dk and dv are two outputs of one call and an output has one
+  ``BlockSpec``, so one of them would still be copied in).
+
+``tpudist.models.transformer.make_length_aware_attention`` picks the
+layout from what it can observe (platform, length, ``d % 128``).
+
 No reference counterpart (the reference has no attention and ships no
 kernels of its own — SURVEY.md §0, §5.7); this is TPU-native capability.
 """
@@ -27,6 +54,7 @@ kernels of its own — SURVEY.md §0, §5.7); this is TPU-native capability.
 from __future__ import annotations
 
 import functools
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -251,12 +279,26 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         )
 
 
+def _split(row, n: int):
+    """``(row // n, row % n)`` of a grid index, which is never negative: one
+    truncating divide.  Index maps run on the scalar core at every grid
+    step, and ``//`` and ``%`` each trace into a divide of their own plus
+    sign fix-ups; ``n == 1`` costs nothing."""
+    if n == 1:
+        return row, 0
+    major = lax.div(row, n)
+    return major, row - major * n
+
+
 def _kv_row_map(heads: int, kv_heads: int):
     """Map a batch-major q-head grid row to its KV head's row (GQA)."""
     group = heads // kv_heads
+    if group == 1:
+        return lambda b: b
 
     def kv_row(b):
-        return (b // heads) * kv_heads + (b % heads) // group
+        batch, head = _split(b, heads)
+        return batch * kv_heads + lax.div(head, group)
 
     return kv_row
 
@@ -276,19 +318,80 @@ def _gqa_shape_check(q, k, v) -> int:
     return kv_heads
 
 
-def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
-                   out_f32=False, window=None):
-    lo, hi = _normalize_band(causal, window)
+def _head_tile(n: int, first: int = 0):
+    """Where one head's ``[tile, d]`` block lives: the index map ``(row,
+    tile) -> block index`` of ``(1, tile, d)`` blocks for grid row ``row``
+    (batch-major over ``n`` heads a batch element) in an array ``[batch,
+    seq, (... the n heads from column block first ...)·d]``.  ``n=1`` is
+    the head-major ``[batch·heads, seq, d]`` view: block ``(row, tile,
+    0)``."""
+    def at(row, tile):
+        batch, head = _split(row, n)
+        return (batch, tile, first + head)
+
+    return at
+
+
+class _Layout(NamedTuple):
+    """The one thing the two entries differ in: the shape of the problem
+    and where a head's tile lives in each array the three calls read and
+    write (see :func:`_head_tile`).  ``o`` also places ``do`` and ``dq``,
+    ``dkv`` places ``dk`` and ``dv``; the stats (``lse``, ``delta``) are
+    ``[batch·heads, 1, seq_q]`` in both."""
+
+    batch: int
+    heads: int
+    kv_heads: int
+    seq_q: int
+    seq_k: int
+    d: int
+    q: Callable
+    k: Callable
+    v: Callable
+    o: Callable
+    dkv: Callable
+    o_shape: tuple
+    dkv_shape: tuple
+
+
+def _head_major_layout(q, k, v) -> _Layout:
+    """``[b, h, s, d]`` operands, each seen as ``[b·h, s, d]``."""
     batch, heads, seq_q, d = q.shape
     kv_heads = _gqa_shape_check(q, k, v)
     seq_k = k.shape[2]
-    bq = min(block_q, seq_q)
-    bk = min(block_k, seq_k)
-    if seq_q % bq or seq_k % bk:
+    at = _head_tile(1)
+    return _Layout(batch, heads, kv_heads, seq_q, seq_k, d, at, at, at, at,
+                   at, (batch * heads, seq_q, d), (batch * kv_heads, seq_k, d))
+
+
+def _packed_layout(qkv, heads: int, kv_heads: int) -> _Layout:
+    """One ``[b, s, (heads + 2·kv_heads)·d]`` array — a fused q/k/v
+    projection's own output — holding q's heads, then k's, then v's, one
+    ``d``-wide column block a head; ``o`` (and ``do``, ``dq``) are ``[b,
+    s, heads·d]``, ``dk`` / ``dv`` ``[b, s, kv_heads·d]``."""
+    batch, seq, cols = qkv.shape
+    if heads % kv_heads or cols % (heads + 2 * kv_heads):
         raise ValueError(
-            f"block sizes ({bq}, {bk}) must divide seq lengths ({seq_q}, {seq_k})"
+            f"packed qkv {qkv.shape} does not hold {heads} q heads and "
+            f"2 x {kv_heads} kv heads (kv heads must divide q heads)")
+    d = cols // (heads + 2 * kv_heads)
+    return _Layout(
+        batch, heads, kv_heads, seq, seq, d,
+        _head_tile(heads), _head_tile(kv_heads, heads),
+        _head_tile(kv_heads, heads + kv_heads), _head_tile(heads),
+        _head_tile(kv_heads),
+        (batch, seq, heads * d), (batch, seq, kv_heads * d))
+
+
+def _blocks(lay: _Layout, block_q: int, block_k: int, interpret: bool):
+    bq = min(block_q, lay.seq_q)
+    bk = min(block_k, lay.seq_k)
+    if lay.seq_q % bq or lay.seq_k % bk:
+        raise ValueError(
+            f"block sizes ({bq}, {bk}) must divide seq lengths "
+            f"({lay.seq_q}, {lay.seq_k})"
         )
-    if bq < seq_q and bq % 128 and not interpret:
+    if bq < lay.seq_q and bq % 128 and not interpret:
         # The (bh, 1, seq_q) stats layout puts the Q block on the LANE dim
         # of the lse/delta blocks, so a partial block must be a lane-tile
         # multiple on TPU.  Catch it here with a clear message instead of
@@ -297,21 +400,49 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
         raise ValueError(
             f"block_q ({bq}) must be a multiple of 128 (or the full seq_q)"
         )
+    return bq, bk
+
+
+def _kv_innermost_specs(lay: _Layout, bq: int, bk: int, lo, hi):
+    """``(q_tile, kv_tile, row_spec)`` for the ``(batch·heads, q tile, kv
+    tile)`` grids of the forward and dq calls: ``q_tile(at)`` /
+    ``kv_tile(at)`` make the ``(1, tile, d)`` spec of an array whose head
+    tiles lie where ``at`` says (dead KV tiles re-mapped, see
+    :func:`_band_kv_index`); ``row_spec`` is the stats' ``(1, 1, bq)``."""
+    kv_row = _kv_row_map(lay.heads, lay.kv_heads)
+    band_j = _band_kv_index(bq, bk, lo, hi, lay.seq_k // bk)
+
+    def q_tile(at):
+        return pl.BlockSpec((1, bq, lay.d), lambda b, i, j: at(b, i),
+                            memory_space=pltpu.VMEM)
+
+    def kv_tile(at):
+        return pl.BlockSpec(
+            (1, bk, lay.d),
+            lambda b, i, j: at(kv_row(b), band_j(b, i, j)[1]),
+            memory_space=pltpu.VMEM)
+
+    row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i),
+                            memory_space=pltpu.VMEM)
+    return q_tile, kv_tile, row_spec
+
+
+def _flash_forward(q, k, v, lay: _Layout, *, causal, block_q, block_k,
+                   interpret, out_f32=False, window=None):
+    """The forward call over operands laid out as ``lay`` says (the packed
+    entry passes one array three times): ``(o, lse)`` with ``o`` of
+    ``lay.o_shape`` and ``lse`` ``[batch·heads, 1, seq_q]``."""
+    lo, hi = _normalize_band(causal, window)
+    seq_q, seq_k, d = lay.seq_q, lay.seq_k, lay.d
+    bq, bk = _blocks(lay, block_q, block_k, interpret)
     scale = d ** -0.5
-    bh = batch * heads
-    qr = q.reshape(bh, seq_q, d)
-    kr = k.reshape(batch * kv_heads, seq_k, d)
-    vr = v.reshape(batch * kv_heads, seq_k, d)
+    bh = lay.batch * lay.heads
+    bh_kv = lay.batch * lay.kv_heads
 
     kernel = functools.partial(
         _flash_kernel, block_q=bq, block_k=bk, lo=lo, hi=hi, scale=scale,
     )
-
-    kv_row = _kv_row_map(heads, kv_heads)
-    band_j = _band_kv_index(bq, bk, lo, hi, seq_k // bk)
-
-    def kv_index(b, i, j):
-        return (kv_row(b), band_j(b, i, j)[1], 0)
+    q_tile, kv_tile, row_spec = _kv_innermost_specs(lay, bq, bk, lo, hi)
 
     # Whole-kernel cost for the XLA scheduler (matmul mult-add = 2 FLOPs;
     # exp per score entry; causal does half the score work).
@@ -319,13 +450,13 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
     cost = pl.CostEstimate(
         flops=int(4 * work * d),
         transcendentals=int(work),
-        bytes_accessed=int(qr.size + kr.size + vr.size + qr.size)
+        bytes_accessed=int(2 * bh * seq_q * d + 2 * bh_kv * seq_k * d)
         * q.dtype.itemsize,
     )
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct((bh, seq_q, d),
+            jax.ShapeDtypeStruct(lay.o_shape,
                                  jnp.float32 if out_f32 else q.dtype),
             # Stats with seq on the LANE dim.  A trailing singleton
             # ((bh, seq_q, 1)) looks harmless but the T(8,128) HBM layout
@@ -339,18 +470,8 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
         grid=(bh, seq_q // bq, seq_k // bk),
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), kv_index, memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, bk, d), kv_index, memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i),
-                         memory_space=pltpu.VMEM),
-        ],
+        in_specs=[q_tile(lay.q), kv_tile(lay.k), kv_tile(lay.v)],
+        out_specs=[q_tile(lay.o), row_spec],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),   # m (running row max)
             pltpu.VMEM((bq, 1), jnp.float32),   # l (running normalizer)
@@ -363,7 +484,17 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, interpret,
         cost_estimate=cost,
         **names.kernel(names.FLASH_FWD),
         interpret=interpret,
-    )(qr, kr, vr)
+    )(q, k, v)
+
+
+def _head_major_forward(q, k, v, *, causal, block_q, block_k, interpret,
+                        out_f32, window):
+    lay = _head_major_layout(q, k, v)
+    batch, heads, seq_q, d = q.shape
+    out, lse = _flash_forward(
+        q.reshape(lay.o_shape), k.reshape(lay.dkv_shape),
+        v.reshape(lay.dkv_shape), lay, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=interpret, out_f32=out_f32, window=window)
     return out.reshape(batch, heads, seq_q, d), lse.reshape(batch, heads, seq_q)
 
 
@@ -393,7 +524,7 @@ def flash_attention_with_lse(
     dtype — partial-merging callers keep full precision across merges
     (the in-kernel accumulator is f32 either way, so this is free).
     """
-    return _flash_forward(
+    return _head_major_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret, out_f32=out_f32, window=window,
     )
@@ -575,106 +706,97 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc_ref[:].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, do, lse, delta, *, causal, block_q, block_k,
-                    interpret, window=None):
+def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
+                    block_q, block_k, interpret, window=None):
+    """The two backward calls over operands laid out as ``lay`` says:
+    ``(dq, dk, dv)`` with ``dq`` of ``lay.o_shape``, ``dk`` / ``dv`` of
+    ``lay.dkv_shape``; ``lse`` / ``delta`` are ``[batch·heads, 1, seq_q]``."""
     lo, hi = _normalize_band(causal, window)
-    batch, heads, seq_q, d = q.shape
-    kv_heads = _gqa_shape_check(q, k, v)
+    heads, kv_heads = lay.heads, lay.kv_heads
+    seq_q, seq_k, d = lay.seq_q, lay.seq_k, lay.d
     group = heads // kv_heads
-    seq_k = k.shape[2]
-    bq = min(block_q, seq_q)
-    bk = min(block_k, seq_k)
+    bq, bk = _blocks(lay, block_q, block_k, interpret)
     scale = d ** -0.5
-    bh = batch * heads
-    bh_kv = batch * kv_heads
-    qr = q.reshape(bh, seq_q, d)
-    kr = k.reshape(bh_kv, seq_k, d)
-    vr = v.reshape(bh_kv, seq_k, d)
-    dor = do.reshape(bh, seq_q, d).astype(q.dtype)
-    lser = lse.reshape(bh, 1, seq_q)
-    deltar = delta.reshape(bh, 1, seq_q)
+    bh = lay.batch * heads
+    bh_kv = lay.batch * kv_heads
+    do = do.astype(q.dtype)
     nq = seq_q // bq
     nkv = seq_k // bk
 
-    kv_row = _kv_row_map(heads, kv_heads)
-
     work = bh * _band_live_pairs(seq_q, seq_k, lo, hi)
-    in_bytes = int(
-        (qr.size + kr.size + vr.size + dor.size) * q.dtype.itemsize
-        + (lser.size + deltar.size) * 4
-    )
+    q_bytes = bh * seq_q * d * q.dtype.itemsize
+    kv_bytes = bh_kv * seq_k * d * q.dtype.itemsize
+    in_bytes = int(2 * q_bytes + 2 * kv_bytes + 2 * bh * seq_q * 4)
 
-    def q_row_index(b, i, j):
-        return (b, i, 0)
-
-    q_spec = pl.BlockSpec((1, bq, d), q_row_index, memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i),
-                            memory_space=pltpu.VMEM)
-    band_j = _band_kv_index(bq, bk, lo, hi, nkv)
-
-    def kv_index(b, i, j):
-        return (kv_row(b), band_j(b, i, j)[1], 0)
-    kv_spec = pl.BlockSpec((1, bk, d), kv_index, memory_space=pltpu.VMEM)
+    q_tile, kv_tile, row_spec = _kv_innermost_specs(lay, bq, bk, lo, hi)
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
                           lo=lo, hi=hi, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((bh, seq_q, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(lay.o_shape, q.dtype),
         grid=(bh, nq, nkv),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
+        in_specs=[q_tile(lay.q), kv_tile(lay.k), kv_tile(lay.v),
+                  q_tile(lay.o), row_spec, row_spec],
+        out_specs=q_tile(lay.o),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
             flops=int(6 * work * d), transcendentals=int(work),
-            bytes_accessed=in_bytes + int(qr.size * q.dtype.itemsize),
+            bytes_accessed=in_bytes + int(q_bytes),
         ),
         **names.kernel(names.FLASH_BWD_DQ),
         interpret=interpret,
-    )(qr, kr, vr, dor, lser, deltar)
+    )(q, k, v, do, lse, delta)
 
     # dk/dv sweep (group x Q tiles) innermost per KV head; causal dead Q
     # tiles (fully above the diagonal) re-map to the KV row's first live
     # tile of the same group head so their DMA is elided, mirroring the
     # forward trick on the transposed schedule.
-    def q_row(b, g):
-        # KV grid row (batch-major over kv heads) + group member -> q row
-        return (b // kv_heads) * heads + (b % kv_heads) * group + g
-
     def q_index(b, j, gi):
-        qi = gi % nq
+        # KV grid row (batch-major over kv heads) + group member -> q row
+        if group == 1:
+            row, qi = b, gi
+        else:
+            g, qi = _split(gi, nq)
+            batch, kv_head = _split(b, kv_heads)
+            row = batch * heads + kv_head * group + g
         if lo is not None:
             # band's lower edge: q < k + lo tiles are dead
             qi = jnp.maximum(qi, (j * bk + lo) // bq)
         if hi is not None:
             # band's upper edge: q tiles past k + hi are dead too
             qi = jnp.minimum(qi, ((j + 1) * bk - 1 + hi - 1) // bq)
-        return (q_row(b, gi // nq), jnp.clip(qi, 0, nq - 1), 0)
+        return row, jnp.clip(qi, 0, nq - 1)
 
-    q_spec_t = pl.BlockSpec((1, bq, d), q_index, memory_space=pltpu.VMEM)
+    def q_tile_t(at):
+        return pl.BlockSpec((1, bq, d),
+                            lambda b, j, gi: at(*q_index(b, j, gi)),
+                            memory_space=pltpu.VMEM)
 
     def row_index_t(b, j, gi):
-        r, qi, _ = q_index(b, j, gi)
+        r, qi = q_index(b, j, gi)
         return (r, 0, qi)
 
     row_spec_t = pl.BlockSpec((1, 1, bq), row_index_t,
                               memory_space=pltpu.VMEM)
-    kv_spec_t = pl.BlockSpec((1, bk, d), lambda b, j, gi: (b, j, 0),
-                             memory_space=pltpu.VMEM)
+
+    def kv_tile_t(at):
+        return pl.BlockSpec((1, bk, d), lambda b, j, gi: at(b, j),
+                            memory_space=pltpu.VMEM)
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=bq, block_k=bk,
                           lo=lo, hi=hi, scale=scale, n_q_tiles=nq),
         out_shape=[
-            jax.ShapeDtypeStruct((bh_kv, seq_k, d), k.dtype),
-            jax.ShapeDtypeStruct((bh_kv, seq_k, d), v.dtype),
+            jax.ShapeDtypeStruct(lay.dkv_shape, k.dtype),
+            jax.ShapeDtypeStruct(lay.dkv_shape, v.dtype),
         ],
         grid=(bh_kv, nkv, nq * group),
-        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t, row_spec_t,
-                  row_spec_t],
-        out_specs=[kv_spec_t, kv_spec_t],
+        in_specs=[q_tile_t(lay.q), kv_tile_t(lay.k), kv_tile_t(lay.v),
+                  q_tile_t(lay.o), row_spec_t, row_spec_t],
+        out_specs=[kv_tile_t(lay.dkv), kv_tile_t(lay.dkv)],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
@@ -684,19 +806,16 @@ def _flash_backward(q, k, v, do, lse, delta, *, causal, block_q, block_k,
         ),
         cost_estimate=pl.CostEstimate(
             flops=int(8 * work * d), transcendentals=int(work),
-            bytes_accessed=in_bytes + int(2 * kr.size * k.dtype.itemsize),
+            bytes_accessed=in_bytes + int(2 * kv_bytes),
         ),
         **names.kernel(names.FLASH_BWD_DKV),
         interpret=interpret,
-    )(qr, kr, vr, dor, lser, deltar)
-
-    shape_q = (batch, heads, seq_q, d)
-    shape_k = (batch, kv_heads, seq_k, d)
-    return (dq.reshape(shape_q), dk.reshape(shape_k), dv.reshape(shape_k))
+    )(q, k, v, do, lse, delta)
+    return dq, dk, dv
 
 
 def _fwd(q, k, v, causal, block_q, block_k, interpret, out_f32, window):
-    out, lse = _flash_forward(
+    out, lse = _head_major_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
         interpret=interpret, out_f32=out_f32, window=window,
     )
@@ -713,10 +832,67 @@ def _bwd(causal, block_q, block_k, interpret, out_f32, window, residuals, g):
     delta = jnp.sum(
         out.astype(jnp.float32) * g_out.astype(jnp.float32), axis=-1
     ) - g_lse.astype(jnp.float32)
-    return _flash_backward(
-        q, k, v, g_out, lse, delta, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret, window=window,
+    lay = _head_major_layout(q, k, v)
+    stats = (lay.batch * lay.heads, 1, lay.seq_q)
+    dq, dk, dv = _flash_backward(
+        q.reshape(lay.o_shape), k.reshape(lay.dkv_shape),
+        v.reshape(lay.dkv_shape), g_out.reshape(lay.o_shape),
+        lse.reshape(stats), delta.reshape(stats), lay, causal=causal,
+        block_q=block_q, block_k=block_k, interpret=interpret, window=window,
     )
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
 
 flash_attention_with_lse.defvjp(_fwd, _bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
+def flash_attention_packed(
+    qkv: jax.Array,
+    n_heads: int,
+    n_kv: int,
+    causal: bool = False,
+    block_q: int = 128,
+    block_k: int = 128,
+    interpret: bool = False,
+    window: int | None = None,
+) -> jax.Array:
+    """Flash attention straight over a fused projection's output: ``qkv``
+    is ``[batch, seq, (n_heads + 2·n_kv)·head_dim]`` (q's heads, then k's,
+    then v's), the result ``[batch, seq, n_heads·head_dim]`` — what the
+    output projection reads.  The same three kernels as
+    :func:`flash_attention`, with the same tiles in the same order (so
+    the same bits); only their index maps differ, so no transpose, slice
+    or copy stands between the projections and the kernels, forward or
+    backward.  On TPU ``head_dim`` must be a multiple of 128: one head is
+    then a whole number of lane tiles of the last dimension."""
+    return _packed_fwd(qkv, n_heads, n_kv, causal, block_q, block_k,
+                       interpret, window)[0]
+
+
+def _packed_fwd(qkv, n_heads, n_kv, causal, block_q, block_k, interpret,
+                window):
+    out, lse = _flash_forward(
+        qkv, qkv, qkv, _packed_layout(qkv, n_heads, n_kv), causal=causal,
+        block_q=block_q, block_k=block_k, interpret=interpret, window=window)
+    return out, (qkv, out, lse)
+
+
+def _packed_bwd(n_heads, n_kv, causal, block_q, block_k, interpret, window,
+                residuals, g):
+    qkv, out, lse = residuals
+    lay = _packed_layout(qkv, n_heads, n_kv)
+    # delta a head at a time: each is a lane-aligned column block of o and
+    # do reduced over its own d lanes, which lands [b, s] with s minor, the
+    # stats layout, so neither the operands nor the result are transposed
+    d = lay.d
+    prod = out.astype(jnp.float32) * g.astype(jnp.float32)
+    delta = jnp.stack([jnp.sum(prod[..., h * d:(h + 1) * d], axis=-1)
+                       for h in range(n_heads)], axis=1).reshape(lse.shape)
+    dq, dk, dv = _flash_backward(
+        qkv, qkv, qkv, g, lse, delta, lay, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=interpret, window=window)
+    return (jnp.concatenate([dq, dk, dv], axis=-1),)
+
+
+flash_attention_packed.defvjp(_packed_fwd, _packed_bwd)

@@ -76,6 +76,18 @@ STEP_ANNOTATION = "train"
 # -- events ------------------------------------------------------------------
 COMPILE_CACHE_HIT = "compile_cache_hit"
 COMPILE_CACHE_MISS = "compile_cache_miss"
+# tpudist/models/transformer.py, once a trace of each attention call site of
+# a training ``Block``: which operand layout the attention took, as
+# ``layout=`` PACKED (the flash kernels index the fused projection's own
+# [b, s, 3·d] output) or HEAD_MAJOR ([b, h, s, dh] operands, re-laid out
+# round the attention) with the ``reason=`` it was not packed
+ATTN_LAYOUT = "attn_layout"
+PACKED = "packed"
+HEAD_MAJOR = "head_major"
+WHY_DH = "dh"                # head_dim is not a multiple of 128 lanes
+WHY_SEQ = "seq"              # too short for the flash kernels, or no tile fits
+WHY_PLATFORM = "platform"    # not a TPU
+WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 #: ``jax.monitoring`` event -> event name
 XLA_CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": COMPILE_CACHE_HIT,
