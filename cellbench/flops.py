@@ -44,24 +44,46 @@ def lm_train_flops_per_token(*, seq: int, **shape) -> float:
     return lm_train_flops(batch=1, seq=seq, **shape) / seq
 
 
-def flash_train_flops(*, batch: int, seq: int, d_model: int,
-                      n_layers: int) -> float:
-    """FLOPs the attention ALGORITHM needs in one training step, all layers:
-    forward (QK^T, PV) once and backward (dV, dP, dQ, dK) twice that.  The
-    flash backward recomputes the scores in both of its kernels; that is the
-    kernels' choice and is not counted, so a kernel that recomputes less
-    scores higher."""
-    return 3.0 * n_layers * attention_forward_flops(
-        batch=batch, seq=seq, d_model=d_model)
+#: the names attention's three kernels carry in a trace (the ``kernel`` of a
+#: Mosaic custom call's ``kernel_metadata``), spelled once for the yardstick:
+#: ``trace_reduce`` and the readers take them from here, and
+#: ``tests/test_scope_readers.py`` holds them against the program's
+#: (``tpudist/telemetry/names.py``).  A program that renames a kernel does
+#: not thereby change what the benchmark takes for attention.
+FLASH_FWD = "flash_fwd"
+FLASH_BWD_DQ = "flash_bwd_dq"
+FLASH_BWD_DKV = "flash_bwd_dkv"
+FLASH_KERNELS = (FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV)
 
 
-def flash_train_bytes(*, batch: int, seq: int, d_model: int, n_layers: int,
-                      itemsize: int = 2) -> float:
-    """Bytes the attention algorithm has to move in one training step, all
-    layers, if every tensor crossed HBM once: forward reads q, k, v and
-    writes o; backward reads q, k, v, o, do and writes dq, dk, dv."""
-    tensor = batch * seq * d_model * itemsize
-    return n_layers * (4 + 8) * float(tensor)
+def flash_kernel_work(*, batch: int, seq: int, d_model: int, n_layers: int,
+                      itemsize: int = 2) -> dict:
+    """``kernel name -> (FLOPs, bytes)`` the attention ALGORITHM needs in one
+    training step, all layers, split over the three kernels it runs as.
+
+    Each kernel owns two of the six matmuls, ``4 * batch * pairs * d_model``
+    a layer: forward QK^T and PV, the dq kernel dP and dQ, the dk/dv kernel
+    dV and dK.  The flash backward recomputes the scores in both of its
+    kernels; that is the kernels' choice and is not counted, so a kernel
+    that recomputes less scores higher.  The three sum to forward once and
+    backward twice that: ``3 * n_layers * attention_forward_flops``.
+
+    The bytes are those of the algorithm if every tensor crossed HBM once,
+    12 tensors of ``batch * seq * d_model`` a layer: forward reads q, k, v
+    and writes o (4); backward reads q, k, v, o, do and writes dq, dk, dv
+    (8).  Of the backward's 8, each kernel is given its own output (dq: 1,
+    dk and dv: 2) and half of the five reads the two share, so the three
+    sum to the 12.  That the split into two kernels reads them twice is the
+    kernels' choice, like the recomputed scores: a backward kernel taken
+    alone has to move 5 (dq) and 6 (dk/dv) tensors, so where the memory
+    bound applies (a small head_dim, a short sequence) its share reads lower
+    than its own traffic would give, and never higher."""
+    f = n_layers * attention_forward_flops(batch=batch, seq=seq,
+                                           d_model=d_model)
+    tensor = n_layers * float(batch * seq * d_model * itemsize)
+    return {FLASH_FWD: (f, 4.0 * tensor),
+            FLASH_BWD_DQ: (f, 3.5 * tensor),
+            FLASH_BWD_DKV: (f, 4.5 * tensor)}
 
 
 def roofline_seconds(flops: float, bytes_: float, peak: dict) -> tuple:

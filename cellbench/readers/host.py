@@ -1,9 +1,10 @@
 """Per-layer readers that need no trace: host-clock spans of the runner's
-loop, program counters, and the rate the window measured."""
+loop, program counters, and the rate the window measured.  A reader that
+finds nothing to read returns ``None``."""
 
 from __future__ import annotations
 
-from cellbench import flops
+from cellbench import archs
 
 
 def _ms_per_step(r, span: str):
@@ -31,13 +32,12 @@ def peak_hbm_gib(r):
 
 
 def mfu_pct(r):
-    """Model FLOPs per token (causal attention, recomputation not counted)
+    """Model FLOPs per token (the architecture's own count, by the
+    conventions of ``flops.py``: causal attention, recomputation not counted)
     x tokens/s/chip of the steps outside the capture, over the chip's peak."""
     rate = r.counters.get("tokens_per_s_per_chip")
     if not rate:
         return None
-    c = r.config
-    per_token = flops.lm_train_flops_per_token(
-        seq=r.counters["seq_len"], d_model=c["n_embd"],
-        n_layers=c["n_layer"], d_ff=c["n_inner"], vocab=c["vocab_size"])
+    per_token = archs.load(r.config).train_flops_per_token(
+        r.config, r.counters["seq_len"])
     return 100.0 * per_token * rate / r.peak["bf16_flops_per_s"]

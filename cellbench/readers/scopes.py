@@ -1,7 +1,7 @@
 """Per-layer readers over the names the PROGRAM writes onto its device work:
-kernel names (``pallas_call(name=, metadata=)``) and ``jax.named_scope``
-scopes, both from ``tpudist/telemetry/names.py`` (imported, not spelled
-again here).
+kernel names (``pallas_call(name=, metadata=)``; attention's are looked for
+by the yardstick's copy, ``flops.FLASH_KERNELS``) and ``jax.named_scope``
+scopes (``tpudist/telemetry/names.py``, imported, not spelled again here).
 
 ``Reading.reds`` keeps instruction short names only and ``trace_reduce.load``
 drops event stats, so this module reads the traced run's ``.xplane.pb``
@@ -14,7 +14,8 @@ PR 25, which printed the stat keys of the live TPU trace):
 
 - a kernel's name: the custom call's own event text, which carries
   ``frontend_attributes={kernel_metadata={"kernel":"<name>"}}`` verbatim
-  whatever a ``shard_map`` round the call does to the instruction's name;
+  whatever a ``shard_map`` round the call does to the instruction's name
+  (``trace_reduce.kernel_of``);
 - an operation's scope: its ``tf_op`` stat, the ``op_name`` the compiler
   kept for the instruction (``jit(step)/transpose(jvp(TransformerLM))/
   block_3/attn/qkv/dot_general:``; for a fusion, the one XLA gave the
@@ -36,7 +37,7 @@ import re
 from pathlib import Path
 from typing import NamedTuple, Optional
 
-from cellbench import trace_reduce
+from cellbench import flops, trace_reduce
 from cellbench.trace_reduce import Event
 
 try:
@@ -46,7 +47,6 @@ except ImportError:   # a program without the vocabulary: nothing to read
 
 #: the stat of an operation's event metadata that carries its scope
 SCOPE_STAT = "tf_op"
-KERNEL_NAME = re.compile(r'kernel_metadata=\{\s*"kernel"\s*:\s*"([^"]+)"')
 
 
 class Op(NamedTuple):
@@ -160,14 +160,6 @@ def _under(*scope_names: str):
     return re.compile(rf"(^|[/(])({alternatives})([/)]|$)")
 
 
-def kernel_of(e: Event) -> Optional[str]:
-    """The program's name of a Mosaic custom call, None for anything else."""
-    if not trace_reduce.is_flash_call(e):
-        return None
-    m = KERNEL_NAME.search(e.name)
-    return m.group(1) if m else None
-
-
 def phase_of(scope: str, kernel: Optional[str]) -> Optional[str]:
     """``fwd`` / ``bwd`` / ``optimizer`` for an operation that carries one of
     the program's scopes or a kernel name, None for one that carries none."""
@@ -181,7 +173,7 @@ def phase_of(scope: str, kernel: Optional[str]) -> Optional[str]:
 
 
 def _op(e: Event, scope: str) -> Op:
-    kernel = kernel_of(e)
+    kernel = trace_reduce.kernel_of(e)
     return Op(e, scope, kernel, phase_of(scope, kernel))
 
 
@@ -234,15 +226,15 @@ def _kernel_ms(r, kernel: str):
 
 
 def flash_fwd_ms_per_step(r):
-    return _kernel_ms(r, names.FLASH_FWD) if names else None
+    return _kernel_ms(r, flops.FLASH_FWD)
 
 
 def flash_bwd_dq_ms_per_step(r):
-    return _kernel_ms(r, names.FLASH_BWD_DQ) if names else None
+    return _kernel_ms(r, flops.FLASH_BWD_DQ)
 
 
 def flash_bwd_dkv_ms_per_step(r):
-    return _kernel_ms(r, names.FLASH_BWD_DKV) if names else None
+    return _kernel_ms(r, flops.FLASH_BWD_DKV)
 
 
 def fwd_ms_per_step(r):

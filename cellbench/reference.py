@@ -1,12 +1,14 @@
-"""Plain reference for the GPT-2-shaped decoder LM cells: seeded weights,
-forward pass, next-token loss, gradients and Adam, in float32 ``jax.numpy``.
+"""Plain reference for the decoder-LM training cells, the part that is free
+of architecture: the seed, matmuls at a stated precision, the next-token
+loss, Adam, the per-tensor norms and projections, and the loop that follows
+a training job's first steps in blocks of rows.  What only an architecture
+knows (its weights, their init, the forward pass and so the loss and
+gradients of one block of rows) is the configuration's module under
+``archs/`` (``archs.load(config)``), passed in as ``arch``.
 
-Written from the published GPT-2 equations (Radford et al. 2019; the
-Cerebras-GPT ``config.json`` keys) with the departures each configuration
-file lists under ``as_run`` (no biases, untied head, tanh GELU, LayerNorm
-epsilon).  It imports nothing of the program and takes nothing the
-program made: the benchmark makes the weights here, from the seed, and
-hands the program a copy.
+It imports nothing of the program and takes nothing the program made: the
+benchmark makes the weights here, from the seed, and hands the program a
+copy.
 
 Precision is one argument, ``mode``:
 
@@ -17,16 +19,11 @@ Precision is one argument, ``mode``:
   precision below the one the configuration states, the step that would
   tempt a later PR.  A configuration that states another precision brings
   its control with it.
-
-Layers are stacked on a leading axis and run under ``lax.scan`` with each
-block rematerialised, so a 24-layer model compiles as one block and the
-reference's activations stay small beside its 16 bytes a parameter.
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -36,8 +33,6 @@ from jax import lax
 MODES = ("f32", "fp8")
 CONTROL = "fp8"
 ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
-#: per-layer tensors, stacked on axis 0
-STACKED = ("ln1", "qkv", "proj", "ln2", "wi", "wo")
 
 
 def split_seed(seed: int):
@@ -54,72 +49,13 @@ def seed_key(seed_words) -> jax.Array:
     return jax.random.fold_in(jax.random.PRNGKey(seed_words[0]), seed_words[1])
 
 
-def dims(config: dict) -> dict:
-    """The sizes of a GPT-2-style ``config.json``, under short names."""
-    d = config["n_embd"]
-    return dict(vocab=config["vocab_size"], seq=config["n_positions"], d=d,
-                layers=config["n_layer"], heads=config["n_head"],
-                dh=d // config["n_head"], ff=config["n_inner"])
-
-
-def weight_shapes(config: dict) -> dict:
-    """name -> shape.  Per-layer tensors are stacked on axis 0."""
-    m = dims(config)
-    L, d, f = m["layers"], m["d"], m["ff"]
-    return {
-        "tok_embed": (m["vocab"], d), "pos_embed": (m["seq"], d),
-        "ln1": (L, d), "qkv": (L, d, 3 * d), "proj": (L, d, d),
-        "ln2": (L, d), "wi": (L, d, f), "wo": (L, f, d),
-        "ln_f": (d,), "head": (d, m["vocab"]),
-    }
-
-
-def init_weights(config: dict, seed_words) -> dict:
-    """GPT-2's published init from the seed (:func:`split_seed` words):
-    normal(0, initializer_range) for embeddings and matrices, the two
-    residual projections scaled by 1/sqrt(2 * n_layer), LayerNorm scales 1.
-    Trace it under ``jit`` with ``out_shardings`` to make the weights on the
-    device, laid out.  Stacked tensors are drawn layer by layer, so that a
-    program that wants single layers never holds the stack."""
-    std = config["initializer_range"]
-    resid = 1.0 / math.sqrt(2 * config["n_layer"])
-    key = seed_key(seed_words)
-    out = {}
-    for i, (name, shape) in enumerate(sorted(weight_shapes(config).items())):
-        if name.startswith("ln"):
-            out[name] = jnp.ones(shape, jnp.float32)
-            continue
-        scale = std * (resid if name in ("proj", "wo") else 1.0)
-        k = jax.random.fold_in(key, i)
-        if name in STACKED:
-            out[name] = jnp.stack([
-                scale * jax.random.normal(jax.random.fold_in(k, l),
-                                          shape[1:], jnp.float32)
-                for l in range(shape[0])])
-        else:
-            out[name] = scale * jax.random.normal(k, shape, jnp.float32)
-    return out
-
-
-def leaf_names(config: dict) -> list:
-    """One name per tensor as a model holds them: ``tok_embed``,
-    ``block_3.qkv``, ...  The order of :func:`leaf_norms`."""
-    names = []
-    for name, shape in sorted(weight_shapes(config).items()):
-        if name in STACKED:
-            names += [f"block_{i}.{name}" for i in range(shape[0])]
-        else:
-            names.append(name)
-    return names
-
-
-def leaf_norms(config: dict, tree: dict) -> jax.Array:
+def leaf_norms(arch, config: dict, tree: dict) -> jax.Array:
     """L2 norm of every tensor of a stacked tree, ordered as
-    :func:`leaf_names` (a stacked entry gives one norm per layer)."""
+    ``arch.leaf_names`` (a stacked entry gives one norm per layer)."""
     parts = []
-    for name in sorted(weight_shapes(config)):
+    for name in sorted(arch.weight_shapes(config)):
         x = tree[name].astype(jnp.float32)
-        if name in STACKED:
+        if name in arch.STACKED:
             parts.append(jnp.sqrt(jnp.sum(
                 x * x, axis=tuple(range(1, x.ndim)))))
         else:
@@ -158,12 +94,12 @@ def sign_projections(x: jax.Array) -> jax.Array:
     return jnp.stack(out)
 
 
-def leaf_projections(config: dict, tree: dict) -> jax.Array:
-    """``[tensors, PROJECTIONS]`` of a stacked tree, in :func:`leaf_names`
+def leaf_projections(arch, config: dict, tree: dict) -> jax.Array:
+    """``[tensors, PROJECTIONS]`` of a stacked tree, in ``arch.leaf_names``
     order."""
     parts = []
-    for name in sorted(weight_shapes(config)):
-        if name in STACKED:
+    for name in sorted(arch.weight_shapes(config)):
+        if name in arch.STACKED:
             parts.append(jax.vmap(sign_projections)(tree[name]))
         else:
             parts.append(sign_projections(tree[name])[None])
@@ -188,7 +124,8 @@ def _dot(a, b):
     return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
 
 
-def _t(x):
+def t_last(x):
+    """``x`` with its last two axes swapped."""
     return jnp.swapaxes(x, -1, -2)
 
 
@@ -207,12 +144,12 @@ def _matmul_fwd(a, b, mode):
 def _matmul_bwd(mode, res, g):
     a, b = res
     ra, rb, rg = _round(a, mode), _round(b, mode), _round(g, mode)
-    da = _dot(rg, _t(rb))
+    da = _dot(rg, t_last(rb))
     if b.ndim == 2:
-        db = _dot(_t(ra.reshape(-1, ra.shape[-1])),
+        db = _dot(t_last(ra.reshape(-1, ra.shape[-1])),
                   rg.reshape(-1, rg.shape[-1]))
     else:
-        db = _dot(_t(ra), rg)
+        db = _dot(t_last(ra), rg)
     return da, db
 
 
@@ -220,57 +157,7 @@ matmul.defvjp(_matmul_fwd, _matmul_bwd)
 
 
 # ---------------------------------------------------------------------------
-# the model
-
-
-def _layernorm(x, scale, eps):
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    return (x - mean) * lax.rsqrt(var + eps) * scale
-
-
-def _gelu(x, kind):
-    if kind == "gelu_tanh":
-        return 0.5 * x * (1.0 + jnp.tanh(
-            math.sqrt(2.0 / math.pi) * (x + 0.044715 * x ** 3)))
-    if kind == "gelu":
-        return 0.5 * x * (1.0 + lax.erf(x / math.sqrt(2.0)))
-    raise ValueError(f"unknown activation {kind!r}")
-
-
-def _block(x, w, *, m, eps, act, mode):
-    b, s, d = x.shape
-    h = _layernorm(x, w["ln1"], eps)
-    qkv = matmul(h, w["qkv"], mode)
-
-    def heads(t):   # [b, s, d] -> [b, heads, s, dh]
-        return t.reshape(b, s, m["heads"], m["dh"]).transpose(0, 2, 1, 3)
-
-    q, k, v = (heads(t) for t in jnp.split(qkv, 3, axis=-1))
-    scores = matmul(q, _t(k), mode) / math.sqrt(m["dh"])
-    causal = jnp.tril(jnp.ones((s, s), bool))
-    scores = jnp.where(causal, scores, -jnp.inf)
-    attn = matmul(jax.nn.softmax(scores, axis=-1), v, mode)
-    attn = attn.transpose(0, 2, 1, 3).reshape(b, s, d)
-    x = x + matmul(attn, w["proj"], mode)
-    h = _layernorm(x, w["ln2"], eps)
-    h = _gelu(matmul(h, w["wi"], mode), act)
-    return x + matmul(h, w["wo"], mode)
-
-
-def forward(config: dict, weights: dict, tokens: jax.Array,
-            mode: str = "f32") -> jax.Array:
-    """``tokens [batch, seq] int`` -> logits ``[batch, seq, vocab]`` f32."""
-    m = dims(config)
-    run = config["as_run"]
-    eps, act = run["layer_norm_epsilon"], run["activation"]
-    x = weights["tok_embed"][tokens] + weights["pos_embed"][:tokens.shape[1]]
-    stacked = {k: weights[k] for k in STACKED}
-    block = jax.checkpoint(functools.partial(
-        _block, m=m, eps=eps, act=act, mode=mode))
-    x, _ = lax.scan(lambda x, w: (block(x, w), None), x, stacked)
-    x = _layernorm(x, weights["ln_f"], eps)
-    return matmul(x, weights["head"], mode)
+# loss, optimizer and the loop over a job's first steps
 
 
 def lm_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
@@ -278,12 +165,6 @@ def lm_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     logp = jax.nn.log_softmax(logits[:, :-1], axis=-1)
     picked = jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
     return -jnp.mean(picked)
-
-
-def loss_and_grads(config: dict, weights: dict, tokens: jax.Array,
-                   mode: str = "f32"):
-    return jax.value_and_grad(
-        lambda w: lm_loss(forward(config, w, tokens, mode), tokens))(weights)
 
 
 def adam_update(weights, grads, mu, nu, step, lr):
@@ -302,7 +183,7 @@ def adam_update(weights, grads, mu, nu, step, lr):
     return pick(0), pick(1), pick(2)
 
 
-def train_readings(config: dict, seed: int, batches: list, *, lr: float,
+def train_readings(arch, config: dict, seed: int, batches: list, *, lr: float,
                    rows_per_block: int, mode: str = "f32",
                    weight_sharding=None, token_sharding=None) -> dict:
     """Follow ``len(batches)`` Adam steps from the seeded weights and return
@@ -316,19 +197,19 @@ def train_readings(config: dict, seed: int, batches: list, *, lr: float,
     the weights, of ``jax.sharding.Sharding``) lays the reference's state
     over several devices with XLA's own partitioner.
     """
-    make = jax.jit(functools.partial(init_weights, config),
+    make = jax.jit(functools.partial(arch.init_weights, config),
                    out_shardings=weight_sharding)
-    grad_block = jax.jit(functools.partial(loss_and_grads, config),
+    grad_block = jax.jit(functools.partial(arch.loss_and_grads, config),
                          static_argnames="mode",
                          out_shardings=(None, weight_sharding))
     add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b), donate_argnums=0)
     scale = jax.jit(lambda t, s: jax.tree.map(lambda x: x * s, t),
                     donate_argnums=0)
     update = jax.jit(adam_update, donate_argnums=(0, 2, 3))
-    norms = jax.jit(lambda t: (leaf_norms(config, t),
-                               leaf_projections(config, t)))
+    norms = jax.jit(lambda t: (leaf_norms(arch, config, t),
+                               leaf_projections(arch, config, t)))
     delta = jax.jit(lambda a, s: norms(jax.tree.map(
-        jnp.subtract, a, init_weights(config, s))))
+        jnp.subtract, a, arch.init_weights(config, s))))
     # zeros depend on no input, so without a layout of their own the
     # partitioner replicates them: two whole copies of the model a chip
     zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t),

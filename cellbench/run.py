@@ -75,13 +75,23 @@ class Reading(NamedTuple):
 
 
 def layer_metrics(reading: Reading) -> dict:
-    """Every metric file of ``layer_metrics/`` whose reader finds something
-    to read in this run: ``name -> {"value", "unit"}``."""
+    """Every metric file of ``layer_metrics/`` that is for this cell (its
+    ``cells`` is absent or names it) and whose reader finds something to
+    read in this run: ``name -> {"value", "unit"}``.  A reader returns
+    ``None`` where there is nothing to read; one that raises ends the run."""
     out = {}
     for path in sorted((HERE / "layer_metrics").glob("*.json")):
         spec = load_json(path)
+        if reading.cell["name"] not in spec.get("cells",
+                                                [reading.cell["name"]]):
+            continue
         module, fn = spec["reader"].split(":")
-        value = getattr(importlib.import_module(module), fn)(reading)
+        try:
+            value = getattr(importlib.import_module(module), fn)(reading)
+        except Exception as e:
+            raise SystemExit(
+                f"per-layer metric {spec['name']!r} ({path.name}): its reader "
+                f"{spec['reader']} raised {type(e).__name__}: {e}") from e
         if value is not None:
             out[spec["name"]] = {"value": float(value), "unit": spec["unit"]}
     return out

@@ -2,12 +2,17 @@
 starts it, timed for a window.
 
 The call sequence is ``chip_smoke.py``'s train phase (``initialize -> mesh
--> TransformerLM -> init_lm_state -> make_lm_train_step``) with three
-differences a real size forces: the weights are the benchmark's own, made on
-the device from the seed (``reference.init_weights``) and handed to the
+-> module -> init_lm_state -> make_lm_train_step``) with three differences
+a real size forces: the weights are the benchmark's own, made on the device
+from the seed (the architecture's ``init_weights``) and handed to the
 program; the state is born already laid out (``jit`` with ``out_shardings``),
 because a 1.4 B-parameter Adam state cannot exist on one chip first; and the
 batches come from the program's loader over a seeded corpus file.
+
+Nothing here knows an architecture: the module the step is built round, the
+benchmark's weights in its parameter tree and back, and the reference's
+forward pass are the configuration's module under ``archs/``
+(``archs.load(config)``, by its ``model_type``).
 
 One ``Job`` holds the compiled step; set-up drives THAT object from the
 seeded state through the first ``check.steps`` steps (through the window's
@@ -31,9 +36,8 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec
 
-from cellbench import checks, corpus, reference
+from cellbench import archs, checks, corpus, reference
 from tpudist.data.lm import make_lm_loader
-from tpudist.models.transformer import TransformerLM
 from tpudist.parallel import fsdp_sharding
 from tpudist.runtime import initialize
 from tpudist.runtime.mesh import MeshConfig, make_mesh
@@ -52,60 +56,15 @@ def say(tag: str, **fields) -> None:
           flush=True)
 
 
-# ---------------------------------------------------------------------------
-# the benchmark's weights in the program's tree, and back
-
-
-def program_tree(weights: dict) -> dict:
-    """The reference's stacked weights as ``TransformerLM``'s parameters."""
-    params = {
-        "tok_embed": {"embedding": weights["tok_embed"]},
-        "pos_embed": {"embedding": weights["pos_embed"]},
-        "LayerNorm_0": {"scale": weights["ln_f"]},
-        "head": {"kernel": weights["head"]},
-    }
-    for i in range(weights["qkv"].shape[0]):
-        params[f"block_{i}"] = {
-            "LayerNorm_0": {"scale": weights["ln1"][i]},
-            "LayerNorm_1": {"scale": weights["ln2"][i]},
-            "qkv": {"kernel": weights["qkv"][i]},
-            "proj": {"kernel": weights["proj"][i]},
-            "wi": {"kernel": weights["wi"][i]},
-            "wo": {"kernel": weights["wo"][i]},
-        }
-    return {"params": params}
-
-
-def named_leaves(config: dict, params: dict) -> list:
-    """The tensors of a program tree in ``reference.leaf_names`` order."""
-    p = params["params"]
-    top = {"tok_embed": p["tok_embed"]["embedding"],
-           "pos_embed": p["pos_embed"]["embedding"],
-           "ln_f": p["LayerNorm_0"]["scale"], "head": p["head"]["kernel"]}
-    inner = {"ln1": ("LayerNorm_0", "scale"), "ln2": ("LayerNorm_1", "scale"),
-             "qkv": ("qkv", "kernel"), "proj": ("proj", "kernel"),
-             "wi": ("wi", "kernel"), "wo": ("wo", "kernel")}
-    out = []
-    for name in reference.leaf_names(config):
-        if name in top:
-            out.append(top[name])
-        else:
-            block, kind = name.split(".")
-            a, b = inner[kind]
-            out.append(p[block][a][b])
-    return out
-
-
-def norm_vector(config: dict, params: dict) -> jax.Array:
-    """Per-tensor L2 norms of a program tree."""
+def norm_vector(leaves: list) -> jax.Array:
+    """Per-tensor L2 norms of a program tree's ``named_leaves``."""
     return jnp.stack([jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
-                      for x in named_leaves(config, params)])
+                      for x in leaves])
 
 
-def projection_matrix(config: dict, params: dict) -> jax.Array:
-    """Per-tensor ``reference.sign_projections`` of a program tree."""
-    return jnp.stack([reference.sign_projections(x)
-                      for x in named_leaves(config, params)])
+def projection_matrix(leaves: list) -> jax.Array:
+    """Per-tensor ``reference.sign_projections`` of the same."""
+    return jnp.stack([reference.sign_projections(x) for x in leaves])
 
 
 # ---------------------------------------------------------------------------
@@ -117,8 +76,9 @@ class Job:
 
     def __init__(self, cell: dict, config: dict, devices):
         job = cell["job"]
-        m = reference.dims(config)
-        self.config, self.job = config, job
+        arch = archs.load(config)
+        m = arch.dims(config)
+        self.config, self.job, self.arch = config, job, arch
         self.chips = len(devices)
         self.seq = job["seq_len"]
         self.batch = job["per_chip_batch"] * self.chips
@@ -127,13 +87,7 @@ class Job:
                              f"{m['seq']} positions")
         initialize()
         self.mesh = make_mesh(MeshConfig(data=self.chips), devices=devices)
-        run = config["as_run"]
-        self.module = TransformerLM(
-            vocab=m["vocab"], d_model=m["d"], n_layers=m["layers"],
-            n_heads=m["heads"], d_ff=m["ff"], max_len=m["seq"],
-            dtype=jnp.dtype(run["compute_dtype"]),
-            remat=job["remat"] is not None,
-            remat_policy=job["remat"] or "nothing")
+        self.module = arch.build_module(config, job)
         self.lr = job["optimizer"]["learning_rate"]
         if job["optimizer"]["name"] != "adam":
             raise ValueError("the reference follows Adam only")
@@ -141,7 +95,8 @@ class Job:
 
         def make_state(seed_words):
             return init_lm_state(
-                program_tree(reference.init_weights(config, seed_words)),
+                arch.program_tree(config,
+                                  arch.init_weights(config, seed_words)),
                 self.tx)
 
         abstract = jax.eval_shape(make_state, reference.split_seed(0))
@@ -184,18 +139,22 @@ class Job:
                 raise AssertionError(f"the compiled step holds no {c}")
         say("job", step_loaded_s=loaded_s,
             as_text_and_checks_done_s=time.perf_counter() - t0)
-        self._norms = jax.jit(lambda tree: (
-            norm_vector(config, tree), projection_matrix(config, tree)))
+
+        def norms(tree):
+            leaves = arch.named_leaves(config, tree)
+            return norm_vector(leaves), projection_matrix(leaves)
+
+        self._norms = jax.jit(norms)
         self._delta = jax.jit(lambda params, words: self._norms(
-            jax.tree.map(jnp.subtract, params, program_tree(
-                reference.init_weights(config, words)))))
+            jax.tree.map(jnp.subtract, params, arch.program_tree(
+                config, arch.init_weights(config, words)))))
 
     def feed(self, batch: np.ndarray) -> jax.Array:
         return jax.device_put(batch, self.tok_sharding)
 
     def loader(self, seed: int, directory: Path):
         """The program's LM loader over this seed's corpus file."""
-        m = reference.dims(self.config)
+        m = self.arch.dims(self.config)
         path = corpus.write_corpus(
             Path(directory) / "corpus.bin", self.job["corpus"],
             vocab=m["vocab"], seq_len=self.seq, seed=seed)
@@ -250,10 +209,10 @@ class Job:
                 return NamedSharding(self.mesh, PartitionSpec(*spec))
 
             w_sh = {k: lay(s) for k, s in
-                    reference.weight_shapes(self.config).items()}
+                    self.arch.weight_shapes(self.config).items()}
             t_sh = NamedSharding(self.mesh, PartitionSpec(axis))
         return reference.train_readings(
-            self.config, seed, batches, lr=self.lr,
+            self.arch, self.config, seed, batches, lr=self.lr,
             rows_per_block=self.job["reference_rows_per_block"] * self.chips,
             mode=mode, weight_sharding=w_sh, token_sharding=t_sh)
 
@@ -430,4 +389,4 @@ def run(*, cell: dict, config: dict, seed: int, seconds: float, trace: bool,
                   "tokens_per_s_per_chip": tokens_per_s_per_chip,
                   "memory_peak_bytes": memory_peak},
         spans=w["spans"], host_span_names=HOST_SPANS, trace_dir=trace_dir,
-        trace_hints={"vocab": config["vocab_size"]})
+        trace_hints={"vocab": job.arch.dims(config)["vocab"]})

@@ -7,6 +7,8 @@ from pathlib import Path
 
 import pytest
 
+from cellbench import archs
+
 HERE = Path(__file__).resolve().parents[1]
 REPO = HERE.parent
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -34,6 +36,15 @@ def test_every_cell_has_its_files(manifest):
         assert data["source"] == c["source"] and len(c["source"]) <= 200
         assert data["reduced"] == c["reduced"]
         assert any(w["config"] == c["name"] for w in manifest["workloads"])
+        # its architecture is the one file its own model_type names
+        assert (HERE / "archs" / f"{data['model_type']}.py").is_file()
+        arch = archs.load(data)
+        for fn in ("dims", "weight_shapes", "init_weights", "leaf_names",
+                   "loss_and_grads", "build_module", "program_tree",
+                   "named_leaves", "train_flops_per_token", "kernel_work"):
+            assert callable(getattr(arch, fn)), (data["model_type"], fn)
+        assert {"vocab", "seq", "layers"} <= set(arch.dims(data))
+        assert set(arch.STACKED) <= set(arch.weight_shapes(data))
 
 
 def test_names_and_units_hold_only_permitted_characters(manifest):

@@ -1,68 +1,72 @@
-"""The plain reference against the system at the tiny configuration, and
-the control: the comparison that decides ``correct`` has to be shown to
-fail when the arithmetic drops a precision."""
+"""The plain reference against the system at the tiny configurations (the
+GPT-2-shaped one and the architecture added as files alone), and the
+control: the comparison that decides ``correct`` has to be shown to fail
+when the arithmetic drops a precision."""
 
-import json
-from pathlib import Path
+import copy
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cellbench import checks, reference
+from cellbench import archs, checks, reference
 from cellbench.runners import train_lm
-
-DATA = Path(__file__).resolve().parent / "data"
-
-
-def load(name):
-    cell = json.loads((DATA / f"{name}.json").read_text())
-    cell["name"] = name
-    return cell, json.loads((DATA / "tiny-gpt.json").read_text())
+from cellbench.tests.conftest import FILES_ALONE_CELLS, load_cell
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    return load("tiny-train-1dev")
+@pytest.fixture(scope="module", params=["tiny-train-1dev"] + FILES_ALONE_CELLS)
+def tiny(request):
+    return load_cell(request.param)
 
 
 def batches(config, seed, n=3, rows=4):
-    m = reference.dims(config)
+    m = archs.load(config).dims(config)
     rng = np.random.default_rng(seed)
     return [rng.integers(0, m["vocab"], (rows, m["seq"]), dtype=np.int32)
             for _ in range(n)]
 
 
 def test_system_in_float32_equals_the_reference(tiny):
-    """Loss and every gradient tensor of ``TransformerLM.apply`` + ``lm_loss``
-    (the forward and autodiff the step uses) within 1e-5 relative."""
-    from tpudist.models.transformer import TransformerLM, lm_loss
+    """Loss and every gradient tensor of the architecture's module +
+    ``lm_loss`` (the forward and autodiff the step uses) within 1e-5
+    relative."""
+    from tpudist.models.transformer import lm_loss
 
-    _, config = tiny
-    m = reference.dims(config)
-    module = TransformerLM(vocab=m["vocab"], d_model=m["d"],
-                           n_layers=m["layers"], n_heads=m["heads"],
-                           d_ff=m["ff"], max_len=m["seq"], dtype=jnp.float32)
-    weights = reference.init_weights(config, reference.split_seed(3))
+    cell, config = tiny
+    arch = archs.load(config)
+    config = copy.deepcopy(config)
+    config["as_run"]["compute_dtype"] = "float32"
+    module = arch.build_module(config, cell["job"])
+    weights = arch.init_weights(config, reference.split_seed(3))
     tokens = jnp.asarray(batches(config, 3, n=1)[0])
     with jax.default_matmul_precision("highest"):
         loss, grads = jax.value_and_grad(lambda p: lm_loss(
-            module.apply(p, tokens), tokens))(train_lm.program_tree(weights))
-    ref_loss, ref_grads = reference.loss_and_grads(config, weights, tokens)
+            module.apply(p, tokens), tokens))(
+                arch.program_tree(config, weights))
+    ref_loss, ref_grads = arch.loss_and_grads(config, weights, tokens)
     assert abs(float(loss) - float(ref_loss)) <= 1e-5 * float(ref_loss)
-    got = train_lm.program_tree(ref_grads)
+    got = arch.program_tree(config, ref_grads)
+    assert jax.tree.structure(grads) == jax.tree.structure(got)
     for (path, a), b in zip(jax.tree_util.tree_flatten_with_path(grads)[0],
                             jax.tree.leaves(got)):
         err = float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
         assert err <= 1e-5, (jax.tree_util.keystr(path), err)
+    # the tree and back: named_leaves is program_tree's inverse
+    leaves = arch.named_leaves(config, arch.program_tree(config, weights))
+    assert len(leaves) == len(arch.leaf_names(config))
+    norms = reference.leaf_norms(arch, config, weights)
+    np.testing.assert_allclose(
+        [float(jnp.linalg.norm(x)) for x in leaves], norms, rtol=1e-6)
 
 
 def test_large_seeds_are_data_not_programs(tiny):
     _, config = tiny
-    a = reference.init_weights(config, reference.split_seed(2 ** 31 + 5))
-    b = reference.init_weights(config, reference.split_seed(5))
-    assert not np.allclose(a["head"], b["head"])
+    arch = archs.load(config)
+    a = arch.init_weights(config, reference.split_seed(2 ** 31 + 5))
+    b = arch.init_weights(config, reference.split_seed(5))
+    head = max(a, key=lambda k: a[k].size)
+    assert not np.allclose(a[head], b[head])
     with pytest.raises(ValueError):
         reference.split_seed(-1)
 
@@ -91,9 +95,12 @@ def test_bf16_program_passes_and_the_8_bit_control_fails(tiny, seed):
 
 def test_reference_row_blocks_do_not_change_the_readings(tiny):
     _, config = tiny
+    arch = archs.load(config)
     first = batches(config, 9, n=2)
-    a = reference.train_readings(config, 9, first, lr=1e-3, rows_per_block=4)
-    b = reference.train_readings(config, 9, first, lr=1e-3, rows_per_block=1)
+    a = reference.train_readings(arch, config, 9, first, lr=1e-3,
+                                 rows_per_block=4)
+    b = reference.train_readings(arch, config, 9, first, lr=1e-3,
+                                 rows_per_block=1)
     np.testing.assert_allclose(a["losses"], b["losses"], rtol=1e-5)
     np.testing.assert_allclose(a["grad_norms"], b["grad_norms"], rtol=1e-4)
     np.testing.assert_allclose(a["update_norms"], b["update_norms"],
@@ -108,3 +115,10 @@ def test_worst_leaf_gap_is_held_against_the_median_tensor():
     gap, i = checks.worst_leaf_gap(np.zeros(4), ref)   # an unchanged state
     assert gap == 1.0
 
+
+
+def test_an_unknown_model_type_names_the_file_to_add():
+    with pytest.raises(LookupError, match=r"cellbench/archs/mamba9\.py"):
+        archs.load({"name": "x", "model_type": "mamba9"})
+    with pytest.raises(LookupError, match="no model_type"):
+        archs.load({"name": "x"})

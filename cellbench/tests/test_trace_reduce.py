@@ -23,7 +23,15 @@ def test_union_subtract_and_gaps():
 
 MATMUL = "%fusion.1 = bf16[8,8]{1,0} fusion(bf16[8,8]{1,0} %p), kind=kOutput, calls=%c"
 FLASH = ('%block_0.3 = (bf16[4,8,8]{2,1,0}) custom-call(bf16[4,8,8]{2,1,0} %q), '
-         'custom_call_target="tpu_custom_call"')
+         'custom_call_target="tpu_custom_call", frontend_attributes='
+         '{kernel_metadata={"kernel":"' + tr.FLASH_KERNELS[0] + '"}}')
+#: a Mosaic custom call of another kernel than attention's, and one unnamed
+EXPERTS = ('%experts.7 = (bf16[8,8]{1,0}) custom-call(bf16[8,8]{1,0} %x), '
+           'custom_call_target="tpu_custom_call", frontend_attributes='
+           '{kernel_metadata={"kernel":"grouped_matmul"}}')
+UNNAMED = ('%shard_map.4 = (bf16[8,8]{1,0}) custom-call(bf16[8,8]{1,0} %x), '
+           'custom_call_target="tpu_custom_call", frontend_attributes='
+           '{kernel_metadata={}}')
 GATHER = "%all-gather-start.3 = (f32[2,8]{1,0}, f32[8,8]{1,0}) all-gather-start(f32[2,8]{1,0} %w)"
 GATHER_DONE = "%all-gather-done.3 = f32[8,8]{1,0} all-gather-done((f32[2,8]{1,0}, f32[8,8]{1,0}) %all-gather-start.3)"
 ADAM = "%fusion.9 = f32[8]{0} fusion(f32[8]{0} %state_opt_state_0__mu__x), kind=kLoop, calls=%d"
@@ -55,11 +63,37 @@ def test_whole_steps_busy_idle_and_exposed_collectives():
     assert len(red.gaps) == 2
     assert red.collective_ns == 80 and red.collective_exposed_ns == 30
     assert red.custom_call_ns == 30
+    assert red.kernel_ns == {tr.FLASH_KERNELS[0]: 30}
     assert red.group_ns == {"collectives": 30, "flash custom calls": 30,
-                            "matmul fusions": 80, "optimizer update": 10,
-                            "loss/logits": 10, "other": 0}
+                            "other custom calls": 0, "matmul fusions": 80,
+                            "optimizer update": 10, "loss/logits": 10,
+                            "other": 0}
     assert red.op_ns["fusion.1"] == 80 and red.op_ns["block_0.3"] == 30
     assert tr.group_of(Event(LOGITS, 0, 1)) == "other"   # no vocab given
+
+
+def test_a_custom_call_is_attentions_only_by_its_name():
+    """A kernel that is not attention's goes under its own name and one
+    without a name under ``other custom calls``; neither is counted as a
+    flash kernel, both are custom calls."""
+    lines = synthetic_lines()
+    lines[tr.OPS_LINE] = [
+        Event(EXPERTS if e.name == MATMUL else
+              UNNAMED if e.name == LOGITS else e.name, e.start, e.end)
+        for e in lines[tr.OPS_LINE]]
+    red = tr.reduce_device(lines, vocab=512)
+    assert red.kernel_ns == {tr.FLASH_KERNELS[0]: 30, "grouped_matmul": 80}
+    assert red.custom_call_ns == 30 + 80 + 10
+    assert red.group_ns["flash custom calls"] == 30
+    assert red.group_ns["grouped_matmul"] == 80
+    assert red.group_ns["other custom calls"] == 10
+    assert red.group_ns["matmul fusions"] == red.group_ns["loss/logits"] == 0
+    assert [tr.kernel_of(Event(n, 0, 1))
+            for n in (FLASH, EXPERTS, UNNAMED, MATMUL)] == [
+                tr.FLASH_KERNELS[0], "grouped_matmul", None, None]
+    rows = dict(tr.breakdown({0: red}, tr.Trace({}, {}), ())["device_ops"])
+    assert rows["[grouped_matmul]"] == 80e-9
+    assert rows["[flash custom calls]"] == 30e-9
 
 
 def test_a_single_run_of_the_program_gives_no_whole_step():
@@ -98,6 +132,7 @@ def test_recorded_chip_trace_gives_fixed_numbers(recorded):
             w["collective_exposed_ns"], rel=1e-9)
         assert red.custom_call_ns == pytest.approx(w["custom_call_ns"],
                                                    rel=1e-9)
+        assert red.kernel_ns == w["kernel_ns"]   # from before the names
         assert 0 < red.busy_ns <= red.window_ns
         assert red.collective_exposed_ns <= red.collective_ns
         for group, ns in w["group_ns"].items():

@@ -20,6 +20,8 @@ import re
 from pathlib import Path
 from typing import Iterable, NamedTuple, Optional, Sequence
 
+from cellbench.flops import FLASH_KERNELS
+
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 HOST_PLANE = "/host:CPU"
 #: the lines of a chip's plane that are read: one event per run of a compiled
@@ -27,15 +29,23 @@ HOST_PLANE = "/host:CPU"
 #: text); one per asynchronous operation in flight (copies, collectives)
 MODULES_LINE, OPS_LINE, ASYNC_LINE = "XLA Modules", "XLA Ops", "Async XLA Ops"
 
-#: op groups of the breakdown, in the order an operation is tried against them
-GROUPS = ("collectives", "flash custom calls", "matmul fusions",
-          "optimizer update", "loss/logits", "other")
+#: op groups of the breakdown, in the order an operation is tried against
+#: them; a Mosaic custom call that carries another kernel's name than
+#: attention's makes a group of that name
+GROUPS = ("collectives", "flash custom calls", "other custom calls",
+          "matmul fusions", "optimizer update", "loss/logits", "other")
 _COLL = (r"(all-gather|all-reduce|reduce-scatter|collective-permute|"
          r"all-to-all|async-collective)(-start|-done)?")
 #: by the instruction's own name, or by its opcode where XLA renamed it
 COLLECTIVE_NAME = re.compile(rf"^{_COLL}(\.[0-9]+)?$")
 COLLECTIVE_OPCODE = re.compile(rf" {_COLL}\(")
-FLASH_CALL = 'custom_call_target="tpu_custom_call"'
+CUSTOM_CALL = 'custom_call_target="tpu_custom_call"'
+#: a Mosaic custom call's event text carries the name its ``pallas_call`` was
+#: given (``frontend_attributes={kernel_metadata={"kernel":"<name>"}}``)
+#: whatever a ``shard_map`` round the call does to the instruction's name
+KERNEL_NAME = re.compile(r'kernel_metadata=\{\s*"kernel"\s*:\s*"([^"]+)"')
+#: the breakdown group of a Mosaic custom call that carries no name
+UNNAMED_CALLS = "other custom calls"
 
 
 class Event(NamedTuple):
@@ -150,13 +160,25 @@ def is_collective(e: Event) -> bool:
                 or COLLECTIVE_OPCODE.search(e.name))
 
 
-def is_flash_call(e: Event) -> bool:
-    return FLASH_CALL in e.name
+def is_custom_call(e: Event) -> bool:
+    return CUSTOM_CALL in e.name
+
+
+def kernel_of(e: Event) -> Optional[str]:
+    """The program's name of a Mosaic custom call; None for a call that
+    carries none and for anything else."""
+    if not is_custom_call(e):
+        return None
+    m = KERNEL_NAME.search(e.name)
+    return m.group(1) if m else None
 
 
 def group_of(e: Event, vocab: Optional[int] = None) -> str:
     """The breakdown group of one device operation, from its HLO text.
 
+    A Mosaic custom call goes by the name the program gave its kernel:
+    attention's under ``flash custom calls``, another kernel under its own
+    name, one without a name under ``other custom calls``.
     ``matmul fusions`` are the output fusions (``kind=kOutput``: a
     convolution at the root, on this compiler often with the Adam update of
     the weight it differentiates fused behind it) and bare dots;
@@ -165,8 +187,11 @@ def group_of(e: Event, vocab: Optional[int] = None) -> str:
     name = e.name
     if is_collective(e):
         return "collectives"
-    if is_flash_call(e):
-        return "flash custom calls"
+    if is_custom_call(e):
+        kernel = kernel_of(e)
+        if kernel in FLASH_KERNELS:
+            return "flash custom calls"
+        return kernel or UNNAMED_CALLS
     if "kind=kOutput" in name or " convolution(" in name or " dot(" in name:
         return "matmul fusions"
     if "opt_state" in name:
@@ -202,7 +227,12 @@ class DeviceReduction(NamedTuple):
     op_ns: dict          # op name -> summed durations
     collective_ns: float        # time with a collective running or in flight
     collective_exposed_ns: float   # ... and no other operation on the core
-    custom_call_ns: float
+    kernel_ns: dict             # kernel name -> its custom calls' durations
+
+    @property
+    def custom_call_ns(self) -> float:
+        """Every Mosaic custom call, named or not."""
+        return sum(self.kernel_ns.values()) + self.group_ns[UNNAMED_CALLS]
 
 
 def reduce_device(lines: dict,
@@ -221,9 +251,14 @@ def reduce_device(lines: dict,
     gaps = sorted(idle_gaps(busy, lo, hi), key=lambda g: g[0] - g[1])
     group_ns = dict.fromkeys(GROUPS, 0.0)
     op_ns: dict = collections.defaultdict(float)
+    kernel_ns: dict = collections.defaultdict(float)
     for e in ops:
-        group_ns[group_of(e, vocab)] += e.dur
+        group = group_of(e, vocab)
+        group_ns[group] = group_ns.get(group, 0.0) + e.dur
         op_ns[e.short] += e.dur
+        kernel = kernel_of(e)
+        if kernel:
+            kernel_ns[kernel] += e.dur
     in_flight = [e for e in clip(lines.get(ASYNC_LINE, []), lo, hi)
                  if is_collective(e)]
     coll = [e for e in ops if is_collective(e)]
@@ -234,7 +269,7 @@ def reduce_device(lines: dict,
         collective_ns=total(spans(coll) + spans(in_flight)),
         collective_exposed_ns=total(subtract(
             spans(coll) + spans(in_flight), spans(compute))),
-        custom_call_ns=sum(e.dur for e in ops if is_flash_call(e)))
+        kernel_ns=dict(kernel_ns))
 
 
 def reduce_trace(trace: Trace, vocab: Optional[int] = None) -> dict:
