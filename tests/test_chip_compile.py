@@ -88,6 +88,10 @@ FLASH = {
     "seq2048-dh128-1024x1024": (8, 8, 2048, 128, 1024, 1024),
     "seq8192-dh64-512x1024": (4, 4, 8192, 64, 512, 1024),
 }
+# (batch, q heads, kv heads, seq, dh, block_q, block_k): the gated attention
+# of cell qwen3next-train-ep16share-8k, 8 query heads a kv head at dh 256,
+# with the tiles the tuned file selects at 8,192 positions
+FLASH_GQA_256 = (2, 16, 2, 8192, 256, 1024, 1024)
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
@@ -106,6 +110,42 @@ def test_flash_attention_compiles(one_chip, tiling, grad):
     text = _compile(fn, qkv, one_chip).as_text()
     # forward kernel; + dq and dk/dv kernels under grad
     assert text.count("tpu_custom_call") == (3 if grad else 1)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_packed_flash_compiles_at_head_dim_256_grouped_8_to_1(one_chip, grad):
+    """``(1, 1024, 256)`` column blocks of a ``[2, 8192, 20 * 256]`` array:
+    twice the VMEM a head of 128 takes, never run before this cell."""
+    from tpudist.ops import flash_attention_packed
+
+    b, h, kv, s, dh, bq, bk = FLASH_GQA_256
+
+    def loss(qkv):
+        return flash_attention_packed(qkv, h, kv, True, bq, bk,
+                                      False).astype(jnp.float32).sum()
+
+    qkv = jax.ShapeDtypeStruct((b, s, (h + 2 * kv) * dh), jnp.bfloat16)
+    text = _compile(jax.grad(loss) if grad else loss, (qkv,),
+                    one_chip).as_text()
+    assert text.count("tpu_custom_call") == (3 if grad else 1)
+
+
+def test_chunked_delta_rule_compiles_at_the_cells_shape(one_chip):
+    """Plain XLA, forward and backward, 32 heads of 128 x 128 over 8,192
+    positions; what the backward keeps is the carried state a chunk."""
+    from tpudist.ops.gated_delta import chunked_gated_delta_rule
+
+    b, s, h, d = 2, 8192, 32, 128
+
+    def loss(q, k, v, g, beta):
+        return chunked_gated_delta_rule(q, k, v, g, beta).astype(
+            jnp.float32).sum()
+
+    wide = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+    gate = jax.ShapeDtypeStruct((b, s, h), jnp.float32)
+    mem = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                   (wide, wide, wide, gate, gate), one_chip).memory_analysis()
+    assert mem.temp_size_in_bytes < 4e9
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
@@ -307,3 +347,83 @@ def test_four_chip_fsdp_step_keeps_the_flash_kernel(topo, monkeypatch):
     # the kernel's own name still rides in its metadata
     assert sorted(_kernels_named(text)) == sorted(
         ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"] * w.n_layers)
+
+
+@pytest.fixture(scope="module")
+def hybrid_step(topo):
+    """The whole train step of cell ``qwen3next-train-ep16share-8k`` (one
+    chip's share of a 16-way expert-parallel deployment), built as the
+    benchmark's runner builds it: the architecture's module from the cell's
+    files, ``make_lm_train_step(module.apply, tx, mesh)``, compiled from
+    shapes.  ``memory_analysis()`` of THIS step is what fixes the cell's
+    rows and remat policy."""
+    import json
+
+    import optax
+
+    from cellbench import archs, reference
+    from tpudist.runtime.mesh import MeshConfig, make_mesh
+    from tpudist.train import (init_lm_state, make_lm_train_step,
+                               token_sharding)
+
+    root = Path(__file__).resolve().parent.parent / "cellbench"
+    cell = json.loads((root / "workloads"
+                       / "qwen3next-train-ep16share-8k.json").read_text())
+    config = json.loads(
+        (root / "configs" / f"{cell['config']}.json").read_text())
+    job, arch = cell["job"], archs.load(config)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+        mesh = make_mesh(MeshConfig(data=1), devices=topo.devices[:1])
+        repl = NamedSharding(mesh, PartitionSpec())
+        tx = optax.adam(job["optimizer"]["learning_rate"])
+        abstract = jax.eval_shape(
+            lambda words: init_lm_state(arch.program_tree(
+                config, arch.init_weights(config, words)), tx),
+            reference.split_seed(0))
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+            abstract)
+        tokens = jax.ShapeDtypeStruct(
+            (job["per_chip_batch"], job["seq_len"]), jnp.int32,
+            sharding=token_sharding(mesh))
+        step = make_lm_train_step(
+            arch.build_module(config, job).apply, tx, mesh,
+            accum_steps=job["accum_steps"]).lower(state, tokens).compile()
+    return step, job, arch.dims(config)
+
+
+def test_hybrid_cell_step_fills_one_chip_and_fits(hybrid_step):
+    step, job, m = hybrid_step
+    assert job["per_chip_batch"] == 2 and job["seq_len"] == 8192
+    mem = step.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    # 625,667,136 parameters x 12 bytes resident
+    assert 7.4e9 < mem.argument_size_in_bytes < 7.6e9
+    # the cell fills the chip (12 GB or more of the 16).  13.92 GiB: each
+    # layer's activation between mixer and experts is kept under remat, so
+    # the experts' backward pass (buffers of 81,920 rows, a block of 8,192
+    # tokens' picks) does not run while the mixer's recomputed forward is
+    # alive; the compiler allows 15.75 GiB
+    assert 12e9 < held < 14.5 * 2 ** 30
+
+
+def test_hybrid_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
+        hybrid_step):
+    """One full-attention layer in the four: the three flash kernels by
+    name (the forward twice, its layer being rematerialised), at head
+    width 256 over 2 kv heads.  The expert layers' products are the
+    compiler's grouped matmuls over the 32 experts HELD: no product over
+    512 experts, and the count the benchmark's runner holds the step to."""
+    import re
+
+    step, job, m = hybrid_step
+    text = step.as_text()
+    assert sorted(_kernels_named(text)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"]
+    assert re.search(r"ragged-dot", text)
+    assert text.count("tpu_custom_call") == (
+        job["custom_calls_per_layer"] * m["layers"])
+    assert not re.search(r"\[512,2048,512\]|\[512,512,2048\]", text)
+    assert re.search(r"\[32,2048,512\]", text)

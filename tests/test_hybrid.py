@@ -1,0 +1,472 @@
+"""The pattern decoder (``tpudist/models/hybrid.py``), its chunked
+delta-rule scan (``tpudist/ops/gated_delta.py``) and the dropless expert
+share (``tpudist/parallel/moe.py``), held to the plain float32 reference of
+the benchmark (``cellbench/archs/qwen3_next.py``) at tiny widths on the CPU:
+d 64, 2 key / 4 value heads of 16, attention 4 x 32 over 1 kv head, 16
+experts top-4 with 4 held, vocabulary 256, 4 layers in the 3:1 pattern.
+
+Tolerances, and why.  Float32 against float32 differs only by the order of
+sums (the chunked form against the recurrence; grouped products against
+masked dense ones): 1e-5 of a tensor's largest entry for values, 1e-4 of a
+gradient's norm.  A bf16 program rounds every matmul operand to 8 bits of
+mantissa (2^-9 relative), which reads 3e-3..8e-3 on the scan's outputs and
+2e-2..3e-2 on a gradient's direction: 100x over the float32 tolerances, so
+bf16 where float32 is stated fails them (a test shows it) and the bf16
+bounds sit 3x over what was read.
+"""
+
+import json
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from cellbench import reference
+from cellbench.archs import qwen3_next as arch
+from tpudist.models import hybrid
+from tpudist.models.transformer import lm_loss
+from tpudist.ops.gated_delta import (chunked_gated_delta_rule,
+                                     gated_delta_rule_reference)
+from tpudist.parallel import moe
+
+DATA = Path(__file__).resolve().parent.parent / "cellbench" / "tests" / "data"
+
+
+def tiny(dtype="float32", **as_run) -> dict:
+    config = json.loads((DATA / "tiny-hybrid.json").read_text())
+    config["as_run"].update(compute_dtype=dtype, **as_run)
+    return config
+
+
+@pytest.fixture(autouse=True)
+def highest_precision():
+    # the CPU multiplies float32 exactly; stated for the reader
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def rel(got, want) -> float:
+    got, want = (np.asarray(x, np.float64) for x in (got, want))
+    return float(np.linalg.norm(got - want) / (np.linalg.norm(want) + 1e-30))
+
+
+def worst(got, want) -> float:
+    got, want = (np.asarray(x, np.float64) for x in (got, want))
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+# ---------------------------------------------------------------------------
+# (a) the chunked scan against the per-position recurrence
+
+
+def scan_inputs(chunks: int, dtype, heads=3, dk=16, dv=16, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    shape = (2, chunks * 64, heads)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+    q = unit(jax.random.normal(ks[0], shape + (dk,))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], shape + (dk,)))
+    v = jax.random.normal(ks[2], shape + (dv,))
+    # per-position decays from exp(-1) = 0.37 down to exp(-0.001)
+    g = -jnp.exp(jax.random.uniform(ks[3], shape, minval=-7.0, maxval=0.0))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], shape))
+    return [x.astype(dtype) for x in (q, k, v)] + [g, beta]
+
+
+SCAN_BOUNDS = {jnp.float32: 1e-5, jnp.bfloat16: 2.5e-2}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("chunks", [1, 2, 5])
+def test_chunked_scan_gives_the_recurrences_values(chunks, dtype):
+    args = scan_inputs(chunks, dtype)
+    got = chunked_gated_delta_rule(*args)
+    assert got.dtype == dtype
+    assert worst(got.astype(jnp.float32),
+                 gated_delta_rule_reference(*args)) < SCAN_BOUNDS[dtype]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("chunks", [1, 2, 5])
+def test_chunked_scan_gives_the_recurrences_gradients(chunks, dtype):
+    args = scan_inputs(chunks, dtype, seed=1)
+
+    def through(fn):
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(
+            fn(*a).astype(jnp.float32))), argnums=(0, 1, 2, 3, 4))(*args)
+
+    for name, got, want in zip("q k v g beta".split(),
+                               through(chunked_gated_delta_rule),
+                               through(gated_delta_rule_reference)):
+        assert worst(got.astype(jnp.float32), want.astype(
+            jnp.float32)) < SCAN_BOUNDS[dtype], name
+
+
+def test_bf16_scan_fails_the_float32_bound():
+    args = scan_inputs(2, jnp.bfloat16)
+    got = chunked_gated_delta_rule(*args).astype(jnp.float32)
+    assert worst(got, gated_delta_rule_reference(*args)) > 100 * SCAN_BOUNDS[
+        jnp.float32]
+
+
+def test_strong_forgetting_does_not_overflow():
+    """``g`` of -30 a position: a cumulative sum of -1920 inside a chunk;
+    no decay is ever the ``exp`` of a positive number."""
+    q, k, v, g, beta = scan_inputs(2, jnp.float32)
+    g = jnp.full_like(g, -30.0)
+    got = chunked_gated_delta_rule(q, k, v, g, beta)
+    assert bool(jnp.all(jnp.isfinite(got)))
+    assert worst(got, gated_delta_rule_reference(q, k, v, g, beta)) < 1e-5
+
+
+def test_scan_refuses_a_ragged_last_chunk():
+    q, k, v, g, beta = scan_inputs(1, jnp.float32)
+    with pytest.raises(ValueError, match="whole number of chunks"):
+        chunked_gated_delta_rule(q[:, :50], k[:, :50], v[:, :50], g[:, :50],
+                                 beta[:, :50])
+
+
+# ---------------------------------------------------------------------------
+# (b) the decoder against the reference
+
+
+def program_and_reference(dtype="float32", seed=7, rows=2, seq=128):
+    config = tiny(dtype)
+    weights = arch.init_weights(config, reference.split_seed(seed))
+    tokens = jax.random.randint(jax.random.PRNGKey(seed), (rows, seq), 0,
+                                config["vocab_size"])
+    module = arch.build_module(config, {"remat": "nothing"})
+    return config, weights, tokens, module
+
+
+@pytest.fixture(scope="module")
+def f32_pair():
+    with jax.default_matmul_precision("highest"):
+        config, weights, tokens, module = program_and_reference()
+        params = arch.program_tree(config, weights)
+        loss, grads = jax.value_and_grad(
+            lambda p: lm_loss(module.apply(p, tokens), tokens))(params)
+        ref_loss, ref_grads = arch.loss_and_grads(config, weights, tokens)
+        return dict(config=config, weights=weights, tokens=tokens,
+                    module=module, params=params, loss=loss, grads=grads,
+                    ref_loss=ref_loss, ref_grads=ref_grads)
+
+
+def test_logits_match_the_reference(f32_pair):
+    p = f32_pair
+    got = p["module"].apply(p["params"], p["tokens"])
+    want = arch.forward(p["config"], p["weights"], p["tokens"])
+    assert worst(got, want) < 1e-5
+
+
+def test_loss_matches_the_reference(f32_pair):
+    assert abs(float(f32_pair["loss"]) - float(f32_pair["ref_loss"])) < 2e-6
+
+
+def test_remat_keeps_each_layers_activation_between_mixer_and_experts(
+        f32_pair):
+    """Under remat a layer's activation between its mixer and its experts
+    is named and kept (one a layer), so the experts' backward pass needs
+    nothing of the mixer's forward; the gradients above are this
+    program's."""
+    p = f32_pair
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda q: lm_loss(p["module"].apply(q, p["tokens"]), p["tokens"])))(
+            p["params"]))
+    assert "checkpoint" in text or "remat" in text
+    assert text.count(f"name={hybrid.MIXER_OUT}") >= (
+        p["config"]["num_hidden_layers"])
+
+
+@pytest.mark.parametrize(
+    "name", arch.leaf_names(json.loads((DATA / "tiny-hybrid.json")
+                                       .read_text())))
+def test_every_gradient_matches_the_reference(f32_pair, name):
+    p = f32_pair
+    names = arch.leaf_names(p["config"])
+    got = arch.named_leaves(p["config"], p["grads"])[names.index(name)]
+    assert rel(got, p["ref_grads"][name]) < 1e-4
+
+
+def test_the_program_in_bf16_fails_the_float32_tolerances():
+    config, weights, tokens, module = program_and_reference("bfloat16")
+    params = arch.program_tree(config, weights)
+    grads = jax.grad(lambda p: lm_loss(module.apply(p, tokens), tokens))(
+        params)
+    _, ref = arch.loss_and_grads(config, weights, tokens)
+    gaps = {n: rel(g, ref[n]) for n, g in zip(
+        arch.leaf_names(config), arch.named_leaves(config, grads))}
+    assert worst(module.apply(params, tokens).astype(jnp.float32),
+                 arch.forward(config, weights, tokens)) > 1e-3
+    assert min(gaps.values()) > 100 * 1e-4
+    # and yet it is the same mathematics: a matrix's gradient is within
+    # 3x of bf16's 3e-2; the routed tensors of the deeper layers read
+    # higher (a pick whose 4th and 5th scores tie goes to another expert)
+    dense = [n for n in gaps if "experts_" not in n and "router" not in n
+             and "A_log" not in n and "dt_bias" not in n]
+    assert max(gaps[n] for n in dense) < 0.1
+
+
+def run_steps(config, module, weights, batches, lr):
+    from tpudist.runtime.mesh import MeshConfig, make_mesh
+    from tpudist.train import init_lm_state, make_lm_train_step
+
+    tx = optax.adam(lr)
+    mesh = make_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    # the state shares the reference's weights: nothing is donated
+    step = make_lm_train_step(module.apply, tx, mesh, donate_state=False)
+    state = init_lm_state(arch.program_tree(config, weights), tx)
+    losses = []
+    for batch in batches:
+        state, loss = step(state, jnp.asarray(batch))
+        losses.append(float(loss))
+    return state, losses
+
+
+def test_three_adam_steps_follow_the_reference():
+    """``make_lm_train_step`` over the float32 program against the
+    reference's own Adam: losses to 1e-5, every tensor's change after three
+    steps to 2e-3 of its norm (Adam divides by the root of the second
+    moment, which magnifies the 1e-4 of a gradient where it is small)."""
+    config, weights, _, module = program_and_reference()
+    rng = np.random.default_rng(0)
+    batches = [rng.integers(0, 256, (2, 128), dtype=np.int32)
+               for _ in range(3)]
+    state, losses = run_steps(config, module, weights, batches, 2e-3)
+    ref = reference.train_readings(arch, config, 7, batches, lr=2e-3,
+                                   rows_per_block=2)
+    np.testing.assert_allclose(losses, ref["losses"], rtol=1e-5)
+    moved = jax.tree.map(jnp.subtract, state.params,
+                         arch.program_tree(config, weights))
+    norms = np.array([float(jnp.linalg.norm(x))
+                      for x in arch.named_leaves(config, moved)])
+    np.testing.assert_allclose(norms, ref["update_norms"], rtol=2e-3)
+
+
+def test_the_step_returns_the_assignments_a_held_expert_a_layer():
+    from tpudist.runtime.mesh import MeshConfig, make_mesh
+    from tpudist.train import init_lm_state, make_lm_train_step
+
+    config, weights, tokens, module = program_and_reference()
+    tx = optax.adam(1e-3)
+    mesh = make_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    step = make_lm_train_step(module.apply, tx, mesh, aux=True,
+                              donate_state=False)
+    _, _, aux = step(init_lm_state(arch.program_tree(config, weights), tx),
+                     tokens)
+    counts = np.asarray(aux["moe_expert_tokens"])
+    assert counts.shape == (4, 4)      # layers x held experts
+    # the reference, routing the first layer's expert input for itself
+    m = arch.dims(config)
+    w = arch._of_layer(weights, 0)
+    want = np.zeros(4, np.int64)
+    for row in tokens:
+        x = weights["embed"][row]
+        x = x + arch._linear_attention(
+            arch._norm(x, w["mixer_norm"], m["eps"]), w, m=m, mode="f32")
+        picks, _ = arch.route(arch._norm(x, w["experts_norm"], m["eps"]),
+                              w["router"], m=m)
+        want += [(np.asarray(picks) == m["first"] + e).sum()
+                 for e in range(4)]
+    np.testing.assert_array_equal(counts[0], want)
+
+
+# ---------------------------------------------------------------------------
+# (c) the shares add up to the uncut layer; (d) nothing is dropped
+
+
+def expert_layer(seed=3, tokens=96):
+    """An uncut reference layer of 16 experts and its weights."""
+    config = tiny(router_experts=16, first_expert=0)
+    config["num_experts"] = 16
+    m = arch.dims(config)
+    weights = arch.init_weights(config, reference.split_seed(seed))
+    w = {k: 10.0 * v for k, v in arch._of_layer(weights, 0).items()}
+    x = jax.random.normal(jax.random.PRNGKey(seed), (tokens, m["d"]))
+    return m, w, x
+
+
+def share_of(m, w, first, held, shared):
+    d, width = m["d"], m["width"]
+    cut = lambda name, a, b: w[name].reshape(16, a, b)[first:first + held]
+    params = {"router": w["router"], "experts": {
+        "gate": cut("experts_gate", d, width),
+        "up": cut("experts_up", d, width),
+        "down": cut("experts_down", width, d)}}
+    if shared:
+        params["shared"] = {k: w[f"shared_{k}"]
+                            for k in ("gate", "up", "down", "score")}
+    return params
+
+
+def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
+    m, w, x = expert_layer()
+    whole = arch._experts(x, w, m=m, mode="f32")
+    parts = [moe.expert_share(
+        share_of(m, w, first, 4, shared=first == 0), x, n_experts=16, held=4,
+        first_expert=first, k=m["top_k"])[0] for first in range(0, 16, 4)]
+    assert worst(sum(parts), whole) < 1e-5
+    # a share alone is the reference's share: the absent experts' part is
+    # left out in both, and the shared expert is every member's
+    alone = arch._experts(x, w, m=m, mode="f32", first=8, held=4)
+    got, counts = moe.expert_share(share_of(m, w, 8, 4, shared=True), x,
+                                   n_experts=16, held=4, first_expert=8,
+                                   k=m["top_k"])
+    assert worst(got, alone) < 1e-5
+    picks, _ = arch.route(x, w["router"], m=m)
+    np.testing.assert_array_equal(
+        counts, [(np.asarray(picks) == e).sum() for e in range(8, 12)])
+
+
+@pytest.mark.parametrize("block", [8192, 256])
+@pytest.mark.parametrize("rigged", ["one_expert", "all_held"])
+def test_rigged_imbalance_drops_nothing(rigged, block):
+    """1,024 tokens x top 4 = 4,096 assignments, all of them through the
+    layer's buffer in one block, and in four blocks of 256 tokens.
+    ``one_expert``: every token picks held expert 5 (1,024 rows there, the
+    most one expert can get); ``all_held``: every token's four picks are
+    the four held experts, the full bound.  There is no capacity and no
+    buffer to outgrow: the result and the gradients are those of the
+    masked dense reference."""
+    m, w, x = expert_layer(tokens=1024)
+    bias = np.zeros(16, np.float32)
+    bias[[5] if rigged == "one_expert" else [4, 5, 6, 7]] = 50.0
+    # a constant input feature that the router reads as a bias
+    x = x.at[:, 0].set(1.0)
+    w["router"] = w["router"].at[0].set(bias)
+    params = share_of(m, w, 4, 4, shared=False)
+
+    def program(params, x):
+        return moe.expert_share(params, x, n_experts=16, held=4,
+                                first_expert=4, k=4, block_tokens=block)
+
+    def ref(w, x):
+        return arch._experts(x, w, m=m, mode="f32", first=4, held=4,
+                             shared=False)
+
+    y, counts = program(params, x)
+    if rigged == "all_held":
+        np.testing.assert_array_equal(counts, [1024] * 4)
+    else:
+        assert int(counts[1]) == 1024
+    assert worst(y, ref(w, x)) < 1e-5
+    loss = lambda fn: lambda p, x: jnp.sum(jnp.sin(fn(p, x)))
+    gp, gx = jax.grad(lambda p, x: loss(lambda *a: program(*a)[0])(p, x),
+                      argnums=(0, 1))(params, x)
+    rw, rx = jax.grad(loss(ref), argnums=(0, 1))(w, x)
+    assert rel(gx, rx) < 1e-4
+    assert rel(gp["router"], rw["router"]) < 1e-4
+    for name in ("gate", "up", "down"):
+        full = rw[f"experts_{name}"].reshape(16, *gp["experts"][name]
+                                             .shape[1:])[4:8]
+        assert rel(gp["experts"][name], full) < 1e-4, name
+
+
+def test_rows_the_grouped_products_leave_undefined_reach_nothing(
+        monkeypatch):
+    """On the chip a grouped product's rows behind its last group, and the
+    same rows of its cotangents, hold whatever the buffer held (the CPU's
+    reference lowering zeroes them, so no other test here sees it).  With
+    those rows poisoned, forward and backward, the layer's result and
+    every gradient are still the masked dense reference's."""
+    real = jax.lax.ragged_dot
+
+    @jax.custom_vjp
+    def poison_cotangent(x, live):
+        return x
+
+    poison_cotangent.defvjp(
+        lambda x, live: (x, live),
+        lambda live, g: (jnp.where(live, g, jnp.nan), None))
+
+    def poisoned(lhs, rhs, group_sizes, **kw):
+        live = (jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes))[:, None]
+        out = real(poison_cotangent(lhs, live), rhs, group_sizes, **kw)
+        return jnp.where(live, out, jnp.nan)
+
+    monkeypatch.setattr(moe.lax, "ragged_dot", poisoned)
+    m, w, x = expert_layer(tokens=1024)
+    params = share_of(m, w, 4, 4, shared=False)
+
+    def program(params, x):
+        return moe.expert_share(params, x, n_experts=16, held=4,
+                                first_expert=4, k=4)[0]
+
+    def ref(w, x):
+        return arch._experts(x, w, m=m, mode="f32", first=4, held=4,
+                             shared=False)
+
+    assert worst(program(params, x), ref(w, x)) < 1e-5
+    loss = lambda fn: lambda p, x: jnp.sum(jnp.sin(fn(p, x)))
+    gp, gx = jax.grad(loss(program), argnums=(0, 1))(params, x)
+    rw, rx = jax.grad(loss(ref), argnums=(0, 1))(w, x)
+    assert rel(gx, rx) < 1e-4
+    assert rel(gp["router"], rw["router"]) < 1e-4
+    for name in ("gate", "up", "down"):
+        full = rw[f"experts_{name}"].reshape(
+            16, *gp["experts"][name].shape[1:])[4:8]
+        assert rel(gp["experts"][name], full) < 1e-4, name
+
+
+def test_the_capacity_arm_routes_by_the_same_router():
+    logits = jax.random.normal(jax.random.PRNGKey(0), (32, 8))
+    routing = moe.route(logits, n_experts=8, k=2, held=2, first_expert=4)
+    np.testing.assert_allclose(routing.weights.sum(-1), 1.0, rtol=1e-6)
+    held_here = (routing.expert_idx >= 4) & (routing.expert_idx < 6)
+    np.testing.assert_array_equal(routing.local < 2, held_here)
+    _, combine, _ = moe._topk_dispatch(logits, 8, capacity=32, k=2)
+    # the capacity arm's combine weights are the router's, expert by expert
+    by_expert = np.zeros((32, 8), np.float32)
+    np.put_along_axis(by_expert, np.asarray(routing.expert_idx),
+                      np.asarray(routing.weights), axis=1)
+    np.testing.assert_allclose(combine.sum(-1), by_expert, rtol=1e-6)
+    with pytest.raises(ValueError, match="not a run"):
+        moe.route(logits, n_experts=8, k=2, held=4, first_expert=6)
+
+
+# ---------------------------------------------------------------------------
+# (e) the flash kernels at head_dim 256, 8 query heads a kv head
+
+
+@pytest.fixture(scope="module")
+def gqa_256():
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (1, 8, 256, 256), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 1, 256, 256), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 1, 256, 256), jnp.float32)
+    return q, k, v
+
+
+def _blockwise(q, k, v):
+    from tpudist.ops import blockwise_attention
+
+    group = q.shape[1] // k.shape[1]
+    return blockwise_attention(q, jnp.repeat(k, group, axis=1),
+                               jnp.repeat(v, group, axis=1), causal=True,
+                               block_k=128)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_at_head_dim_256_grouped_8_to_1(gqa_256, grad):
+    from tpudist.ops import flash_attention
+
+    flash = lambda q, k, v: flash_attention(q, k, v, True, 128, 128, True)
+    if not grad:
+        assert worst(flash(*gqa_256), _blockwise(*gqa_256)) < 1e-5
+        return
+    through = lambda fn: jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
+                                  argnums=(0, 1, 2))(*gqa_256)
+    for got, want in zip(through(flash), through(_blockwise)):
+        assert worst(got, want) < 1e-4
+
+
+def test_packed_flash_at_head_dim_256_grouped_8_to_1(gqa_256):
+    from tpudist.ops import flash_attention_packed
+
+    q, k, v = gqa_256
+    flat = lambda t: t.transpose(0, 2, 1, 3).reshape(1, 256, -1)
+    qkv = jnp.concatenate([flat(q), flat(k), flat(v)], axis=-1)
+    got = flash_attention_packed(qkv, 8, 1, True, 128, 128, True)
+    assert worst(got, flat(_blockwise(q, k, v))) < 1e-5

@@ -40,8 +40,24 @@ def step_op_names():
                          jnp.zeros((4, 16), jnp.int32))
     # (inside the accumulation scan's body the names start afresh, without
     # the ``jit(step)/`` prefix, so every location string is kept)
-    return set(re.findall(r'loc\("([^"]+)"',
-                          lowered.as_text(debug_info=True)))
+    found = set(re.findall(r'loc\("([^"]+)"',
+                           lowered.as_text(debug_info=True)))
+    # the pattern decoder's step: the mixers' and the expert layer's scopes
+    from tpudist.models.hybrid import HybridLM, HybridSizes
+
+    hybrid = HybridLM(
+        vocab=64, layer_types=(names.LINEAR, names.FULL),
+        sizes=HybridSizes(d_model=32, n_heads=2, n_kv_heads=1, head_dim=16,
+                          rotary_dim=4, linear_key_heads=1,
+                          linear_value_heads=2, linear_key_dim=8,
+                          linear_value_dim=8, n_experts=4, held=2, top_k=2,
+                          expert_width=16, shared_width=16), remat=True)
+    tokens = jnp.zeros((2, 64), jnp.int32)
+    params = hybrid.init(jax.random.PRNGKey(0), tokens)
+    lowered = make_lm_train_step(hybrid.apply, tx, mesh).lower(
+        init_lm_state(params, tx), tokens)
+    return found | set(re.findall(r'loc\("([^"]+)"',
+                                  lowered.as_text(debug_info=True)))
 
 
 def _under(scope: str, op_name: str) -> bool:
@@ -56,7 +72,8 @@ def test_the_lowered_step_carries_every_scope(step_op_names, scope):
 def test_a_backward_op_carries_the_transpose_mark_and_its_sublayer(
         step_op_names):
     for scope in (names.ATTN, names.MLP, names.EMBED, names.HEAD,
-                  names.LOSS):
+                  names.LOSS, names.LINEAR_ATTN, names.DELTA_RULE,
+                  names.MOE, names.EXPERTS, names.SHARED_EXPERT):
         assert [n for n in step_op_names
                 if names.BACKWARD_MARK in n and _under(scope, n)], scope
     # the optimizer is not differentiated: no transposed op under it
@@ -398,3 +415,34 @@ def test_attn_layout_fires_once_a_call_site_at_trace_time_and_not_a_step(
         assert len(events()) == traced
     finally:
         telemetry.finish(write_report=False)
+
+
+def test_the_pattern_decoder_says_its_layout_once_a_trace(tmp_path):
+    """``mixer_layout`` (the layer kinds) once a trace of the decoder and
+    ``moe_layout`` once an expert layer, at trace time and not a step."""
+    from tpudist.models.hybrid import HybridLM, HybridSizes
+
+    hybrid = HybridLM(
+        vocab=64, layer_types=(names.LINEAR, names.FULL),
+        sizes=HybridSizes(d_model=32, n_heads=2, n_kv_heads=1, head_dim=16,
+                          rotary_dim=4, linear_key_heads=1,
+                          linear_value_heads=2, linear_key_dim=8,
+                          linear_value_dim=8, n_experts=4, held=2,
+                          first_expert=2, top_k=2, expert_width=16,
+                          shared_width=16))
+    tokens = jnp.zeros((2, 64), jnp.int32)
+    params = hybrid.init(jax.random.PRNGKey(0), tokens)
+    session = telemetry.start(tmp_path / "tele", rank=0, generation=0)
+    try:
+        apply = jax.jit(hybrid.apply)
+        for _ in range(3):
+            apply(params, tokens).block_until_ready()
+        events = [r for r in session.ring if r.get("kind") == "event"]
+    finally:
+        telemetry.finish(write_report=False)
+    mixers = [r for r in events if r["name"] == names.MIXER_LAYOUT]
+    experts = [r for r in events if r["name"] == names.MOE_LAYOUT]
+    assert len(mixers) == 1 and len(experts) == 2
+    assert mixers[0]["kinds"] == [names.LINEAR, names.FULL]
+    assert {(r["experts"], r["held"], r["first"], r["top_k"], r["dropless"])
+            for r in experts} == {(4, 2, 2, 2, True)}

@@ -1,0 +1,312 @@
+"""A decoder LM whose layers follow a pattern of mixers: gated delta-rule
+linear attention and gated softmax attention, each followed by a routed
+expert layer of which this device holds a share.
+
+What :mod:`tpudist.models.transformer`'s ``Block`` (pre-LN, LayerNorm,
+ungated GELU FFN) cannot say, by mechanism:
+
+- a **layer pattern**: ``layer_types`` names each layer's mixer
+  (``names.LINEAR`` / ``names.FULL``);
+- **zero-centred RMSNorm** (``x * rsqrt(mean(x^2) + eps) * (1 + w)``, in
+  float32, ``w`` starting at 0);
+- **gated softmax attention** (:class:`GatedAttention`): the query
+  projection also gives a sigmoid gate on the attention's output, queries
+  and keys are RMS-normed per head, rotary positions turn only the first
+  ``rotary_dim`` of a head's dims, grouped key/value heads, a head width
+  that is not ``d_model / n_heads``; the causal attention itself is
+  ``transformer``'s length-aware dispatch (the flash kernels where they
+  run);
+- **gated delta-rule linear attention** (:class:`GatedDeltaNet`): fused
+  projections laid out per key head, a short depthwise causal convolution
+  with SiLU over q, k, v, per-head decay and write strength, L2-normed
+  queries and keys, the chunked scan of :mod:`tpudist.ops.gated_delta`,
+  an RMS norm gated by ``silu(z)``;
+- **routed experts as a share** (:class:`ExpertShare`):
+  :func:`tpudist.parallel.moe.expert_share`, dropless, with a gated shared
+  expert.
+
+The embedding, the head, their names and scopes, the loss the step
+builders take (``lm_loss``), the remat policy names and the rotary angles
+are ``transformer``'s, imported.  Training only: no decode cache.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from tpudist import telemetry
+from tpudist.models.transformer import (_default_attention, remat_module,
+                                        rope_angles)
+from tpudist.ops.gated_delta import chunked_gated_delta_rule
+from tpudist.parallel.moe import expert_share
+from tpudist.telemetry import names
+
+
+# a layer's activation between its mixer and its experts: what remat keeps
+# besides the layer's input, whatever the policy
+MIXER_OUT = "mixer_out"
+
+
+def _rms(x, eps):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+
+
+class ZeroCentredRMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + eps) * (1 + scale)`` over the last axis, in
+    float32; ``scale`` starts at 0."""
+
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.zeros, (x.shape[-1],))
+        return _rms(x, self.eps) * (1.0 + scale)
+
+
+def _dense(features: int, name: str, dtype):
+    return nn.Dense(features, use_bias=False, name=name, dtype=dtype)
+
+
+def rotate_partial(x, rotary_dim: int, base: float):
+    """Rotary positions on the first ``rotary_dim`` dims of each head of
+    ``x [b, s, heads, dh]`` (half-split pairing ``(i, i + rotary_dim / 2)``,
+    angles in f32); the other dims pass through."""
+    half = rotary_dim // 2
+    angles = rope_angles(0, x.shape[1], half, base)[:, None, :]
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:rotary_dim].astype(jnp.float32)
+    return jnp.concatenate(
+        [(x1 * cos - x2 * sin).astype(x.dtype),
+         (x1 * sin + x2 * cos).astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridSizes:
+    """The sizes of a pattern decoder, apart from depth and vocabulary."""
+
+    d_model: int
+    # gated softmax attention
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    rotary_dim: int
+    rope_theta: float = 1e7
+    # gated delta-rule linear attention
+    linear_key_heads: int = 16
+    linear_value_heads: int = 32
+    linear_key_dim: int = 128
+    linear_value_dim: int = 128
+    linear_conv_width: int = 4
+    # routed experts: ``n_experts`` is the router's width, ``held`` of them
+    # (``first_expert`` on) live here
+    n_experts: int = 8
+    held: int = 8
+    first_expert: int = 0
+    top_k: int = 2
+    expert_width: int = 512
+    shared_width: int = 512
+    eps: float = 1e-6
+
+
+class GatedAttention(nn.Module):
+    """Causal softmax attention with normed, partly rotated q and k and a
+    sigmoid gate from the query projection on its output."""
+
+    sizes: HybridSizes
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        z = self.sizes
+        h, kv, dh = z.n_heads, z.n_kv_heads, z.head_dim
+        qg = _dense(h * 2 * dh, "q_proj", self.dtype)(x)
+        qg = qg.reshape(b, s, h, 2 * dh)
+        q, gate = qg[..., :dh], qg[..., dh:]
+        k = _dense(kv * dh, "k_proj", self.dtype)(x).reshape(b, s, kv, dh)
+        v = _dense(kv * dh, "v_proj", self.dtype)(x)
+        q = ZeroCentredRMSNorm(z.eps, name="q_norm")(q).astype(self.dtype)
+        k = ZeroCentredRMSNorm(z.eps, name="k_norm")(k).astype(self.dtype)
+        q = rotate_partial(q, z.rotary_dim, z.rope_theta)
+        k = rotate_partial(k, z.rotary_dim, z.rope_theta)
+        # q's heads, k's, v's side by side: the layout the dispatch's packed
+        # route (the flash kernels, at head_dim % 128 == 0) indexes itself
+        qkv = jnp.concatenate(
+            [q.reshape(b, s, h * dh), k.reshape(b, s, kv * dh), v], axis=-1)
+        attn = _default_attention.packed(qkv, h, kv)
+        attn = attn * jax.nn.sigmoid(
+            gate.reshape(b, s, h * dh).astype(jnp.float32)).astype(self.dtype)
+        return _dense(d, "o_proj", self.dtype)(attn)
+
+
+def causal_depthwise_conv(x, kernel):
+    """``y[t, c] = sum_j kernel[c, j] * x[t - (width - 1) + j, c]`` over
+    ``x [b, s, c]``, zeros before position 0, no bias.  Products and sum
+    in float32, the result in ``x``'s dtype: ``x`` is padded as it is, so
+    that the taps, the sum and whatever elementwise follows are one pass
+    over it."""
+    width = kernel.shape[-1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    s = x.shape[1]
+    return sum(padded[:, j:j + s].astype(jnp.float32) * kernel[:, j]
+               for j in range(width))
+
+
+class GatedDeltaNet(nn.Module):
+    """The gated delta-rule linear-attention mixer."""
+
+    sizes: HybridSizes
+    dtype: jnp.dtype
+    chunk: int = 64
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        z = self.sizes
+        nk, nv = z.linear_key_heads, z.linear_value_heads
+        dk, dv = z.linear_key_dim, z.linear_value_dim
+        r = nv // nk
+        # laid out per key head: q, k (dk each), then v, z (r * dv each)
+        qkvz = _dense(nk * (2 * dk + 2 * r * dv), "in_proj_qkvz",
+                      self.dtype)(x).reshape(b, s, nk, 2 * dk + 2 * r * dv)
+        ba = _dense(nk * 2 * r, "in_proj_ba", self.dtype)(x)
+        ba = ba.reshape(b, s, nk, 2 * r).astype(jnp.float32)
+        q, k, v, gate = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + r * dv],
+                                  axis=-1)
+        mixed = jnp.concatenate([q.reshape(b, s, nk * dk),
+                                 k.reshape(b, s, nk * dk),
+                                 v.reshape(b, s, nv * dv)], axis=-1)
+        kernel = self.param("conv", nn.initializers.lecun_normal(),
+                            (mixed.shape[-1], z.linear_conv_width))
+        mixed = jax.nn.silu(causal_depthwise_conv(mixed, kernel)).astype(
+            self.dtype)
+        q, k, v = jnp.split(mixed, [nk * dk, 2 * nk * dk], axis=-1)
+        a_log = self.param("A_log", nn.initializers.zeros, (nv,))
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (nv,))
+        beta = jax.nn.sigmoid(ba[..., :r].reshape(b, s, nv))
+        g = -jnp.exp(a_log) * jax.nn.softplus(
+            ba[..., r:].reshape(b, s, nv) + dt_bias)
+
+        def unit(t, scale=1.0):
+            """L2-normed per head, each key head serving r value heads."""
+            t = t.reshape(b, s, nk, dk).astype(jnp.float32)
+            t = t * (scale * jax.lax.rsqrt(
+                jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6))
+            return jnp.repeat(t.astype(self.dtype), r, axis=2)
+
+        o = chunked_gated_delta_rule(
+            unit(q, dk ** -0.5), unit(k), v.reshape(b, s, nv, dv), g, beta,
+            chunk=self.chunk)
+        norm = self.param("norm", nn.initializers.ones, (dv,))
+        o = norm * _rms(o, z.eps) * jax.nn.silu(
+            gate.reshape(b, s, nv, dv).astype(jnp.float32))
+        return _dense(d, "out_proj", self.dtype)(
+            o.reshape(b, s, nv * dv).astype(self.dtype))
+
+
+class ExpertShare(nn.Module):
+    """The routed expert layer as this device's share
+    (:func:`tpudist.parallel.moe.expert_share`), with its shared expert."""
+
+    sizes: HybridSizes
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        """``x [b, s, d]`` float32 (the norm's output): the router reads it
+        as it is, the experts its rounding to the compute dtype."""
+        b, s, d = x.shape
+        z = self.sizes
+        init = nn.initializers.lecun_normal()
+        w, sw, e = z.expert_width, z.shared_width, z.held
+        params = {
+            "router": self.param("router", init, (d, z.n_experts)),
+            "experts": {
+                "gate": self.param("gate", init, (e, d, w)),
+                "up": self.param("up", init, (e, d, w)),
+                "down": self.param("down", init, (e, w, d)),
+            },
+            "shared": {
+                "gate": self.param("shared_gate", init, (d, sw)),
+                "up": self.param("shared_up", init, (d, sw)),
+                "down": self.param("shared_down", init, (sw, d)),
+                "score": self.param("shared_score", init, (d, 1)),
+            },
+        }
+        rows = x.reshape(b * s, d)
+        y, counts = expert_share(
+            params, rows.astype(self.dtype), n_experts=z.n_experts, held=e,
+            first_expert=z.first_expert, k=z.top_k, router_input=rows)
+        # assignments per held expert: collected by train steps built with
+        # ``aux=True`` (make_lm_train_step), one row a layer
+        self.sow("intermediates", "moe_expert_tokens", counts)
+        return y.reshape(b, s, d)
+
+
+class HybridLayer(nn.Module):
+    """``h = x + Mixer(norm(x))``, ``y = h + Experts(norm(h))``."""
+
+    kind: str
+    sizes: HybridSizes
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        z = self.sizes
+        if self.kind == names.FULL:
+            scope, mixer = names.ATTN, GatedAttention(z, self.dtype,
+                                                      name="attn")
+        else:
+            scope, mixer = names.LINEAR_ATTN, GatedDeltaNet(
+                z, self.dtype, name="linear_attn")
+        with jax.named_scope(scope):
+            h = ZeroCentredRMSNorm(z.eps, name="mixer_norm")(x)
+            x = x + mixer(h.astype(self.dtype))
+        # kept under remat: the expert layer's backward pass then needs
+        # nothing of the mixer's, whose forward is recomputed after it
+        x = checkpoint_name(x, MIXER_OUT)
+        # the expert layer names its own scope (``moe``)
+        h = ZeroCentredRMSNorm(z.eps, name="experts_norm")(x)
+        return x + ExpertShare(z, self.dtype, name="experts")(h)
+
+
+class HybridLM(nn.Module):
+    """Causal LM: token embedding, ``len(layer_types)`` pattern layers, a
+    final zero-centred RMSNorm, an untied head."""
+
+    vocab: int
+    layer_types: tuple          # names.LINEAR / names.FULL, one a layer
+    sizes: HybridSizes
+    dtype: jnp.dtype = jnp.float32   # compute dtype; params stay f32 masters
+    remat: bool = False
+    # the names ``TransformerLM`` takes; every policy also keeps each
+    # layer's ``MIXER_OUT``
+    remat_policy: str = "nothing"
+
+    @nn.compact
+    def __call__(self, tokens: jax.Array) -> jax.Array:
+        """``tokens [batch, seq] int32`` -> logits ``[batch, seq, vocab]``."""
+        unknown = set(self.layer_types) - {names.LINEAR, names.FULL}
+        if unknown:
+            raise ValueError(f"layer_types holds {sorted(unknown)}; a layer "
+                             f"is {names.LINEAR!r} or {names.FULL!r}")
+        telemetry.event(names.MIXER_LAYOUT, kinds=list(self.layer_types))
+        with jax.named_scope(names.EMBED):
+            x = nn.Embed(self.vocab, self.sizes.d_model, name="tok_embed",
+                         dtype=self.dtype)(tokens)
+        layer_cls = (remat_module(HybridLayer, self.remat_policy,
+                                  keep=(MIXER_OUT,))
+                     if self.remat else HybridLayer)
+        for i, kind in enumerate(self.layer_types):
+            x = layer_cls(kind, self.sizes, self.dtype, name=f"layer_{i}")(x)
+        with jax.named_scope(names.HEAD):
+            x = ZeroCentredRMSNorm(self.sizes.eps, name="final_norm")(x)
+            return nn.Dense(self.vocab, use_bias=False, name="head",
+                            dtype=self.dtype)(x)

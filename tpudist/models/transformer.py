@@ -774,6 +774,31 @@ class Block(nn.Module):
         return o
 
 
+def remat_module(cls, policy: str, keep: tuple = ()):
+    """``cls`` rematerialised in the backward pass (``nn.remat``), keeping
+    what the named policy keeps (the names of ``TransformerLM.remat_policy``,
+    which every decoder of ``tpudist.models`` takes) and, besides, the
+    activations the module names ``keep``
+    (``jax.ad_checkpoint.checkpoint_name``).  The module takes only the
+    activation, so nothing is static."""
+    policies = {
+        "nothing": None,  # save only block boundaries
+        "dots": jax.checkpoint_policies.checkpoint_dots,
+        "dots_no_batch":
+            jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
+    }
+    if policy not in policies:
+        raise ValueError(
+            f"remat_policy must be one of {sorted(policies)}, "
+            f"got {policy!r}")
+    pol = policies[policy]
+    if keep:
+        named = jax.checkpoint_policies.save_only_these_names(*keep)
+        pol = named if pol is None else (
+            jax.checkpoint_policies.save_from_both_policies(pol, named))
+    return nn.remat(cls) if pol is None else nn.remat(cls, policy=pol)
+
+
 class TransformerLM(nn.Module):
     """Causal LM: token + learned position embeddings, N pre-LN blocks,
     tied-free output head."""
@@ -903,20 +928,7 @@ class TransformerLM(nn.Module):
                 x = x + (pos if pos.ndim == 3 else pos[None])
         block_cls = Block
         if self.remat and not self.decode:
-            # static_argnums: nothing — Block takes only the activation.
-            policies = {
-                "nothing": None,  # save only block boundaries
-                "dots": jax.checkpoint_policies.checkpoint_dots,
-                "dots_no_batch":
-                    jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-            }
-            if self.remat_policy not in policies:
-                raise ValueError(
-                    f"remat_policy must be one of {sorted(policies)}, "
-                    f"got {self.remat_policy!r}")
-            pol = policies[self.remat_policy]
-            block_cls = (nn.remat(Block) if pol is None
-                         else nn.remat(Block, policy=pol))
+            block_cls = remat_module(Block, self.remat_policy)
         for i in range(self.n_layers):
             x = block_cls(
                 self.d_model, self.n_heads, self.d_ff, attn,

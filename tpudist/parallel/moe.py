@@ -1,5 +1,21 @@
-"""Expert parallelism: top-k routed MoE (k=1 Switch, k>1 Mixtral/GShard)
-with ``all_to_all`` token exchange over the ``model`` (expert) mesh axis.
+"""Routed experts: ONE router (:func:`route`) and two ways a device
+computes its part.
+
+- :func:`moe_shard` / :func:`make_moe`, the **capacity arm**: top-k routed
+  MoE (k=1 Switch, k>1 Mixtral/GShard) with ``all_to_all`` token exchange
+  over the ``model`` (expert) mesh axis, one expert a device, overflow
+  dropped (design notes below);
+- :func:`expert_share`, the **dropless share**: a device that is told which
+  ``held`` of the router's ``n_experts`` experts it holds (``first_expert``
+  on) routes every token over all of them and computes the part of the
+  result its own experts give, whatever the load.  What the absent experts
+  would add is left out: the partial sum is what goes on when a chip runs
+  alone as one member of an expert-parallel group.  Nothing stands in for
+  the absent chips; on one chip the layer runs without its exchange, and
+  under an expert-parallel mesh the same body (:func:`_held_experts`) is what
+  sits between the two ``all_to_all``s :func:`moe_shard` has.
+
+The capacity arm:
 
 Absent from the reference (SURVEY.md §2.4: EP "not required for parity");
 provided as the TPU-native extension.  Design, TPU-first:
@@ -27,10 +43,49 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from tpudist import telemetry
 from tpudist.runtime.mesh import AXIS_MODEL
+from tpudist.telemetry import names
 
 # ExpertFn: (expert_params, tokens [slots, d]) -> [slots, d]
 ExpertFn = Callable[[dict, jax.Array], jax.Array]
+
+
+class Routing(NamedTuple):
+    """What :func:`route` decides for ``tokens`` rows."""
+
+    expert_idx: jax.Array  # [tokens, k] int32: the picks, among all experts
+    weights: jax.Array     # [tokens, k] f32: their combine weights
+    probs: jax.Array       # [tokens, n_experts] f32: the softmax
+    local: jax.Array       # [tokens, k] int32: pick - first_expert where the
+    #                        expert is held here, ``held`` where it is absent
+
+
+def route(logits: jax.Array, *, n_experts: int, k: int,
+          held: int | None = None, first_expert: int = 0) -> Routing:
+    """The one router of both arms: softmax over all ``n_experts`` in f32
+    whatever the compute dtype (ties and gate scales are
+    precision-sensitive), the top ``k``, and their weights: the raw top
+    probability at ``k=1`` (Switch), renormalised to sum 1 over the ``k``
+    at ``k>1`` (Mixtral/GShard, HF's ``norm_topk_prob``).  The width, the
+    picks and the renormalisation do not depend on which experts are held
+    here; ``held`` / ``first_expert`` only say which of the picks this
+    device computes (``local``)."""
+    if logits.shape[-1] != n_experts:
+        raise ValueError(f"the router gives {logits.shape[-1]} scores for "
+                         f"{n_experts} experts")
+    held = n_experts if held is None else held
+    if not (0 <= first_expert and 1 <= held
+            and first_expert + held <= n_experts):
+        raise ValueError(f"experts {first_expert}..{first_expert + held} are "
+                         f"not a run of the {n_experts} routed experts")
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, expert_idx = lax.top_k(probs, k)
+    if k > 1:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    local = expert_idx - first_expert
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    return Routing(expert_idx, weights, probs, local)
 
 
 class MoEStats(NamedTuple):
@@ -46,11 +101,8 @@ class MoEStats(NamedTuple):
 
 def _topk_dispatch(router_logits, n_experts, capacity, k=1):
     """Build the [tokens, experts, capacity] dispatch/combine tensors for
-    top-``k`` routing.  Routing probabilities are computed in f32 whatever
-    the compute dtype (argmax ties and gate scales are precision-sensitive).
-
-    ``k=1``: Switch semantics — the raw top probability gates the output.
-    ``k>1``: Mixtral/GShard semantics — the k gates renormalize to sum 1.
+    top-``k`` routing by :func:`route` (``k=1``: the raw top probability
+    gates the output; ``k>1``: the k gates renormalize to sum 1).
     Capacity queues fill in choice-major priority (every token's first
     choice is placed before any second choice), the standard GShard order.
 
@@ -60,10 +112,8 @@ def _topk_dispatch(router_logits, n_experts, capacity, k=1):
     perfect balance, differentiable through ``P_e``.
     """
     t = router_logits.shape[0]
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
-    gate_vals, expert_idx = lax.top_k(probs, k)  # [tokens, k]
-    if k > 1:
-        gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
+    expert_idx, gate_vals, probs, _ = route(router_logits,
+                                            n_experts=n_experts, k=k)
 
     # Choice-major flattening: [k·tokens] with all first choices leading.
     flat_idx = expert_idx.T.reshape(-1)
@@ -173,3 +223,163 @@ def make_moe(
         in_specs=(param_specs, P(batch_axis, None)),
         out_specs=(P(batch_axis, None), MoEStats(P(), P(), P())), check_vma=False)
     return jax.jit(sharded)
+
+
+# ---------------------------------------------------------------------------
+# the dropless share
+
+
+def gated_ffn(params: dict, rows: jax.Array, dot=jnp.matmul) -> jax.Array:
+    """A gated SiLU expert, ``down(silu(gate(x)) * up(x))``.  ``dot(a, w)``
+    is ``a @ w`` for one expert's weights, or the grouped product when
+    ``params`` carries a leading axis over the experts held."""
+    return dot(jax.nn.silu(dot(rows, params["gate"]))
+               * dot(rows, params["up"]), params["down"])
+
+
+#: tokens whose picks :func:`expert_share` takes through its buffers at a
+#: time: the buffers hold ``SHARE_BLOCK_TOKENS * k`` rows
+SHARE_BLOCK_TOKENS = 8192
+
+
+def _plan(local, held: int):
+    """For a block's picks ``local [t, k]`` (the held expert's number, or
+    ``held`` where the expert is absent): ``order [t * k]``, the
+    assignment in each buffer row, sorted by held expert with the absent
+    ones behind all held ones, and ``token``, its token; ``pos [t, k]``,
+    each assignment's row; ``counts [held]``, the rows of each expert."""
+    k = local.shape[1]
+    key = local.reshape(-1)
+    order = jnp.argsort(key).astype(jnp.int32)
+    pos = jnp.argsort(order).astype(jnp.int32).reshape(local.shape)
+    counts = jnp.sum(key[:, None] == jnp.arange(held)[None], axis=0,
+                     dtype=jnp.int32)
+    return order // k, order, pos, counts
+
+
+def _rows(a, index):
+    """``a[index]`` for an index known to lie inside ``a``."""
+    return a.at[index].get(mode="promise_in_bounds")
+
+
+def _grouped(expert_fn, counts):
+    return lambda experts, rows: expert_fn(
+        experts, rows, functools.partial(lax.ragged_dot, group_sizes=counts))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _held_experts(experts, x, weights, local, held, expert_fn):
+    """``y [t, d]`` f32: for every token of a block the weighted results of
+    its picks among the ``held`` experts here.
+
+    All ``t * k`` assignments go through a buffer of as many rows, sorted
+    by held expert: the tokens' rows are gathered into it, one grouped
+    product a projection computes the rows that arrived (the held
+    experts' runs, which lead), and every token gathers its ``k`` rows
+    back and adds up those of held experts.  So the gathers cost the same
+    whatever the router does, the products go with the rows that arrived,
+    nothing is dropped, and the layer has no buffer to outgrow.  (Gathers,
+    not scatter-adds: on the TPU a row scattered costs four of a row
+    gathered.)
+
+    The grouped products define only the rows of their groups: behind the
+    last arrival a row of their result, and of their cotangents, is
+    whatever the buffer held.  Those are absent experts' rows, and the
+    ``where`` over ``local < held`` leaves them out, forward and
+    backward."""
+    token, _, pos, counts = _plan(local, held)
+    with jax.named_scope(names.EXPERTS):
+        out = _grouped(expert_fn, counts)(experts, _rows(x, token))
+    picked = _rows(out, pos).astype(jnp.float32) * weights[..., None]
+    return jnp.sum(jnp.where((local < held)[..., None], picked, 0.0), axis=1)
+
+
+def _held_experts_fwd(experts, x, weights, local, held, expert_fn):
+    y = _held_experts(experts, x, weights, local, held, expert_fn)
+    return y, (experts, x, weights, local)
+
+
+def _held_experts_bwd(held, expert_fn, residuals, dy):
+    """The buffer again (the products are recomputed, nothing of a block
+    is kept but its inputs); each row takes its token's cotangent, each
+    token gathers its rows' back."""
+    experts, x, weights, local = residuals
+    token, order, pos, counts = _plan(local, held)
+    is_held = local < held
+    with jax.named_scope(names.EXPERTS):
+        out, pull = jax.vjp(_grouped(expert_fn, counts), experts,
+                            _rows(x, token))
+    # in the compute dtype, as the products take it
+    dy_rows = _rows(dy.astype(x.dtype), token).astype(jnp.float32)
+    d_weight_rows = jnp.sum(dy_rows * out.astype(jnp.float32), axis=-1)
+    d_out = dy_rows * _rows(weights.reshape(-1), order)[:, None]
+    with jax.named_scope(names.EXPERTS):
+        d_experts, d_rows = pull(d_out.astype(out.dtype))
+    d_x = jnp.sum(jnp.where(is_held[..., None],
+                            _rows(d_rows, pos).astype(jnp.float32), 0.0),
+                  axis=1)
+    d_weights = jnp.where(is_held, _rows(d_weight_rows, pos), 0.0)
+    return d_experts, d_x.astype(x.dtype), d_weights.astype(weights.dtype), None
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
+                 first_expert: int, k: int,
+                 expert_fn: ExpertFn = gated_ffn,
+                 router_input: jax.Array | None = None,
+                 block_tokens: int = SHARE_BLOCK_TOKENS):
+    """This device's part of one routed-expert layer, dropless:
+    ``(y [tokens, d] in x's dtype, assignments per held expert [held])``.
+
+    ``params = {"router": [d, n_experts] f32, "experts": a pytree with a
+    leading axis over the ``held`` experts held (``first_expert`` on), and
+    optionally "shared": one expert's weights with "score": [d, 1]}``;
+    ``x [tokens, d]`` in the compute dtype.  The router's logits are a
+    float32 product at the highest precision of ``router_input`` (``x``
+    before it was rounded to the compute dtype, where the caller has it),
+    its softmax, top ``k`` and renormalisation are :func:`route`'s, over
+    all ``n_experts``.  The experts compute in ``x``'s dtype.  ``shared``
+    is ``sigmoid(x . score) * E_shared(x)``, what every member of the
+    group computes alike.  The tokens are taken in equal blocks of at most
+    ``block_tokens``, one after another, so that the buffers hold
+    ``block_tokens * k`` rows and not ``tokens * k``; the result does not
+    depend on it.
+
+    Runs under the scope ``names.MOE``, the grouped products under
+    ``names.EXPERTS``, the shared expert under ``names.SHARED_EXPERT``.
+    Shard-local: inside a multi-device program call it under ``shard_map``.
+    """
+    tokens, d = x.shape
+    blocks = max(1, -(-tokens // block_tokens))
+    while tokens % blocks:
+        blocks += 1
+    telemetry.event(names.MOE_LAYOUT, experts=n_experts, held=held,
+                    first=first_expert, top_k=k, dropless=True,
+                    buffer_rows=tokens // blocks * k, blocks=blocks)
+    with jax.named_scope(names.MOE):
+        scored = x if router_input is None else router_input
+        logits = jnp.matmul(scored.astype(jnp.float32),
+                            params["router"].astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        routing = route(logits, n_experts=n_experts, k=k, held=held,
+                        first_expert=first_expert)
+        counts = jnp.sum(routing.local.reshape(-1)[:, None]
+                         == jnp.arange(held)[None], axis=0, dtype=jnp.int32)
+        experts = jax.tree.map(lambda w: w.astype(x.dtype),
+                               params["experts"])
+        y = lax.map(
+            lambda block: _held_experts(experts, *block, held, expert_fn),
+            jax.tree.map(lambda a: a.reshape(blocks, -1, *a.shape[1:]),
+                         (x, routing.weights, routing.local))
+        ).reshape(tokens, d)
+        if "shared" in params:
+            with jax.named_scope(names.SHARED_EXPERT):
+                shared = jax.tree.map(lambda w: w.astype(x.dtype),
+                                      params["shared"])
+                score = jax.nn.sigmoid(jnp.matmul(
+                    x, shared.pop("score"),
+                    preferred_element_type=jnp.float32))
+                y = y + score * expert_fn(shared, x).astype(jnp.float32)
+        return y.astype(x.dtype), counts

@@ -47,7 +47,20 @@ HEAD = "head"
 LOSS = "loss"
 OPTIMIZER = "optimizer"
 GRAD_ACCUM = "grad_accum"
-SCOPES = (EMBED, ATTN, MLP, HEAD, LOSS, OPTIMIZER, GRAD_ACCUM)
+# tpudist/models/hybrid.py: the mixers and the expert layer of a decoder
+# whose layers follow a pattern.  ``linear_attn`` is the whole linear-attention
+# mixer (norm, projections, convolution, gates, output projection) as ``attn``
+# is the whole softmax one; ``delta_rule`` (tpudist/ops/gated_delta.py) is the
+# recurrence alone, nested in it.  ``moe`` (tpudist/parallel/moe.py) runs from
+# the router to the combine; ``experts`` (the grouped products) and
+# ``shared_expert`` nest in it
+LINEAR_ATTN = "linear_attn"
+DELTA_RULE = "delta_rule"
+MOE = "moe"
+EXPERTS = "experts"
+SHARED_EXPERT = "shared_expert"
+SCOPES = (EMBED, ATTN, MLP, HEAD, LOSS, OPTIMIZER, GRAD_ACCUM, LINEAR_ATTN,
+          DELTA_RULE, MOE, EXPERTS, SHARED_EXPERT)
 #: what JAX itself writes round the scopes of a transposed (backward) op
 BACKWARD_MARK = "transpose("
 
@@ -88,6 +101,14 @@ WHY_DH = "dh"                # head_dim is not a multiple of 128 lanes
 WHY_SEQ = "seq"              # too short for the flash kernels, or no tile fits
 WHY_PLATFORM = "platform"    # not a TPU
 WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
+# tpudist/models/hybrid.py, once a trace of the decoder: ``kinds=`` the layer
+# kinds in order (LINEAR / FULL), and of each expert layer
+# (tpudist/parallel/moe.py): ``experts=`` the router's width, ``held=``,
+# ``first=``, ``top_k=``, ``dropless=``
+MIXER_LAYOUT = "mixer_layout"
+MOE_LAYOUT = "moe_layout"
+LINEAR = "linear_attention"
+FULL = "full_attention"
 #: ``jax.monitoring`` event -> event name
 XLA_CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": COMPILE_CACHE_HIT,

@@ -180,7 +180,9 @@ def make_lm_train_step(
     returns ``step(state, tokens) -> (state, loss, aux_dict)`` where
     ``aux_dict`` carries MoE routing stats averaged over layers
     (``moe_dropped_fraction`` scalar, ``moe_expert_load`` ``[n_experts]``,
-    ``moe_balance_loss`` scalar) — empty when the model sows nothing.
+    ``moe_balance_loss`` scalar; from a dropless share layer
+    ``moe_expert_tokens`` ``[layers, held]``, the assignments each held
+    expert got this step) — empty when the model sows nothing.
     Requires ``apply_fn`` to accept flax's ``mutable=`` kwarg (i.e. a
     ``Module.apply``).
 
@@ -241,10 +243,19 @@ def make_lm_train_step(
                          "moe_balance_loss"):
                 if name in keys:
                     by_name.setdefault(name, []).append(leaf)
-        return {
+        out = {
             name: jnp.mean(jnp.stack(vals), axis=0)
             for name, vals in by_name.items()
         }
+        # a dropless share layer (tpudist.models.hybrid) sows the
+        # assignments each held expert got: kept a row a layer, not averaged
+        tokens = [leaf for path, leaf in
+                  jax.tree_util.tree_flatten_with_path(inters)[0]
+                  if any(getattr(e, "key", None) == "moe_expert_tokens"
+                         for e in path)]
+        if tokens:
+            out["moe_expert_tokens"] = jnp.stack(tokens)
+        return out
 
     def grad_of(params, toks):
         """((lm_loss, collected), grads) for one microbatch."""
