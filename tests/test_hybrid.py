@@ -301,6 +301,21 @@ def share_of(m, w, first, held, shared):
     return params
 
 
+def assert_share_follows(program, ref, params, w, x):
+    """``program(params, x)`` and every gradient of it against the masked
+    dense ``ref(w, x)`` of held experts 4..7, float32 against float32."""
+    assert worst(program(params, x), ref(w, x)) < 1e-5
+    loss = lambda fn: lambda p, x: jnp.sum(jnp.sin(fn(p, x)))
+    gp, gx = jax.grad(loss(program), argnums=(0, 1))(params, x)
+    rw, rx = jax.grad(loss(ref), argnums=(0, 1))(w, x)
+    assert rel(gx, rx) < 1e-4
+    assert rel(gp["router"], rw["router"]) < 1e-4
+    for name in ("gate", "up", "down"):
+        full = rw[f"experts_{name}"].reshape(
+            16, *gp["experts"][name].shape[1:])[4:8]
+        assert rel(gp["experts"][name], full) < 1e-4, name
+
+
 def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
     m, w, x = expert_layer()
     whole = arch._experts(x, w, m=m, mode="f32")
@@ -346,22 +361,81 @@ def test_rigged_imbalance_drops_nothing(rigged, block):
         return arch._experts(x, w, m=m, mode="f32", first=4, held=4,
                              shared=False)
 
-    y, counts = program(params, x)
+    _, counts = program(params, x)
     if rigged == "all_held":
         np.testing.assert_array_equal(counts, [1024] * 4)
     else:
         assert int(counts[1]) == 1024
-    assert worst(y, ref(w, x)) < 1e-5
-    loss = lambda fn: lambda p, x: jnp.sum(jnp.sin(fn(p, x)))
-    gp, gx = jax.grad(lambda p, x: loss(lambda *a: program(*a)[0])(p, x),
-                      argnums=(0, 1))(params, x)
-    rw, rx = jax.grad(loss(ref), argnums=(0, 1))(w, x)
-    assert rel(gx, rx) < 1e-4
-    assert rel(gp["router"], rw["router"]) < 1e-4
-    for name in ("gate", "up", "down"):
-        full = rw[f"experts_{name}"].reshape(16, *gp["experts"][name]
-                                             .shape[1:])[4:8]
-        assert rel(gp["experts"][name], full) < 1e-4, name
+    assert_share_follows(lambda *a: program(*a)[0], ref, params, w, x)
+
+
+@pytest.mark.parametrize("block", [8192, 256])
+@pytest.mark.parametrize("k", [10, 1])
+def test_any_top_k_adds_up_each_tokens_held_rows(k, block):
+    """The router as seeded, so a token's picks are held and absent side by
+    side, at the benchmark cell's ``k = 10`` (which a ``[tokens, k, d]``
+    layout would pad to sixteen) and at ``k = 1``, in one block and in
+    four: the result and every gradient are the masked dense
+    reference's."""
+    m, w, x = expert_layer(tokens=1024)
+    params = share_of(m, w, 4, 4, shared=False)
+
+    def program(params, x):
+        return moe.expert_share(params, x, n_experts=16, held=4,
+                                first_expert=4, k=k, block_tokens=block)[0]
+
+    def ref(w, x):
+        y = arch._experts(x, w, m=dict(m, top_k=k), mode="f32", first=4,
+                          held=4, shared=False)
+        if k == 1:
+            # a lone pick is gated by its raw probability (``moe.route``);
+            # the reference renormalises it to 1
+            y = y * jnp.max(jax.nn.softmax(x @ w["router"]), axis=-1,
+                            keepdims=True)
+        return y
+
+    picks, _ = arch.route(x, w["router"], m=dict(m, top_k=k))
+    here = (np.asarray(picks) >= 4) & (np.asarray(picks) < 8)
+    assert here.any() and not here.all()
+    assert_share_follows(program, ref, params, w, x)
+
+
+def _avals(jaxpr):
+    """Every value of a jaxpr and of the jaxprs nested in its equations."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in (*eqn.invars, *eqn.outvars))
+        for param in eqn.params.values():
+            for inner in (param if isinstance(param, (tuple, list))
+                          else [param]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _avals(inner)
+
+
+def test_the_combine_builds_no_token_major_tensor():
+    """A block of ``_held_experts`` forward and backward: each token's ``k``
+    rows come back as ``k`` slabs ``[k, tokens, d]``; no ``[tokens, k, d]``
+    value in any dtype, whose ``k`` the TPU's ``(8, 128)`` tile would pad."""
+    t, k, d, width, held = 24, 10, 16, 8, 4
+    key = jax.random.split(jax.random.PRNGKey(0), 5)
+    experts = {"gate": jax.random.normal(key[0], (held, d, width)),
+               "up": jax.random.normal(key[1], (held, d, width)),
+               "down": jax.random.normal(key[2], (held, width, d))}
+    x = jax.random.normal(key[3], (t, d))
+    weights = jnp.full((t, k), 1.0 / k)
+    local = jax.random.randint(key[4], (t, k), 0, held + 1)
+
+    def block(experts, x, weights, dy):
+        y, pull = jax.vjp(
+            lambda *a: moe._held_experts(*a, local, held, moe.gated_ffn),
+            experts, x, weights)
+        return y, pull(dy)
+
+    shapes = {a.shape for a in _avals(
+        jax.make_jaxpr(block)(experts, x, weights, x).jaxpr)
+        if hasattr(a, "shape")}
+    assert (k, t, d) in shapes
+    assert (t, k, d) not in shapes
 
 
 def test_rows_the_grouped_products_leave_undefined_reach_nothing(
@@ -398,16 +472,7 @@ def test_rows_the_grouped_products_leave_undefined_reach_nothing(
         return arch._experts(x, w, m=m, mode="f32", first=4, held=4,
                              shared=False)
 
-    assert worst(program(params, x), ref(w, x)) < 1e-5
-    loss = lambda fn: lambda p, x: jnp.sum(jnp.sin(fn(p, x)))
-    gp, gx = jax.grad(loss(program), argnums=(0, 1))(params, x)
-    rw, rx = jax.grad(loss(ref), argnums=(0, 1))(w, x)
-    assert rel(gx, rx) < 1e-4
-    assert rel(gp["router"], rw["router"]) < 1e-4
-    for name in ("gate", "up", "down"):
-        full = rw[f"experts_{name}"].reshape(
-            16, *gp["experts"][name].shape[1:])[4:8]
-        assert rel(gp["experts"][name], full) < 1e-4, name
+    assert_share_follows(program, ref, params, w, x)
 
 
 def test_the_capacity_arm_routes_by_the_same_router():

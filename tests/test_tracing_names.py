@@ -81,6 +81,32 @@ def test_a_backward_op_carries_the_transpose_mark_and_its_sublayer(
                 if names.BACKWARD_MARK in n and _under(names.OPTIMIZER, n)]
 
 
+def test_the_combine_scope_nests_in_moe_forward_and_backward():
+    """Inside the share layer's loop over blocks the lowered names start
+    afresh, so the nesting is read off the compiled text, where the
+    profiler takes an operation's scope from: ``moe_combine`` under ``moe``
+    (it stays inside what ``moe`` reads), forward and backward."""
+    from tpudist.parallel import moe
+
+    key = jax.random.split(jax.random.PRNGKey(0), 5)
+    d, width, held = 16, 8, 2
+    params = {"router": jax.random.normal(key[0], (d, 4)), "experts": {
+        "gate": jax.random.normal(key[1], (held, d, width)),
+        "up": jax.random.normal(key[2], (held, d, width)),
+        "down": jax.random.normal(key[3], (held, width, d))}}
+    x = jax.random.normal(key[4], (32, d))
+    loss = lambda p, x: jnp.sum(jnp.sin(moe.expert_share(
+        p, x, n_experts=4, held=held, first_expert=1, k=2)[0]))
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    # the gathers: a constant the compiler hoists out of the loop loses
+    # the loop's names with it
+    gathers = [n for n in re.findall(r'op_name="([^"]+)"', text)
+               if _under(names.MOE_COMBINE, n) and n.endswith("/gather")]
+    assert gathers and all(_under(names.MOE, n) for n in gathers)
+    assert {names.BACKWARD_MARK in n for n in gathers} == {False, True}
+
+
 def test_scopes_do_not_change_what_the_step_computes():
     """Scopes are metadata: the same state and tokens give the same loss
     and update with ``jax.named_scope`` turned into a no-op."""
@@ -444,5 +470,6 @@ def test_the_pattern_decoder_says_its_layout_once_a_trace(tmp_path):
     experts = [r for r in events if r["name"] == names.MOE_LAYOUT]
     assert len(mixers) == 1 and len(experts) == 2
     assert mixers[0]["kinds"] == [names.LINEAR, names.FULL]
-    assert {(r["experts"], r["held"], r["first"], r["top_k"], r["dropless"])
-            for r in experts} == {(4, 2, 2, 2, True)}
+    assert {(r["experts"], r["held"], r["first"], r["top_k"], r["dropless"],
+             r["combine"]) for r in experts} == {
+                 (4, 2, 2, 2, True, names.PICK_MAJOR)}

@@ -262,6 +262,32 @@ def _rows(a, index):
     return a.at[index].get(mode="promise_in_bounds")
 
 
+def _combine(rows, pos, keep, weights=None):
+    """``[t, d]`` f32: for every token the sum of its ``k`` rows
+    ``rows[pos[t, j]]`` (times ``weights[t, j]``, the product in float32),
+    those of ``keep [t, k]`` alone.
+
+    Pick-major: one gather through ``pos`` transposed gives ``k`` slabs of
+    ``[t, d]``, and the slabs are added up one after another in float32.
+    The TPU tiles an array's two minor dimensions ``(8 or 16, 128)``: a
+    ``[t, k, d]`` gather at ``k = 10`` is laid out with ten padded to
+    sixteen and summed across padded sublanes, where ``[k, t, d]`` tiles
+    without padding and its sum is an elementwise add.  The adds are
+    spelled out because a ``sum`` over the leading axis makes XLA write the
+    whole gather out again in float32 first; so they fuse into one pass
+    that reads the slabs as gathered.  Under the scope
+    ``names.MOE_COMBINE``."""
+    with jax.named_scope(names.MOE_COMBINE):
+        picked = _rows(rows, pos.T)
+        total = 0.0
+        for j in range(pos.shape[1]):
+            slab = picked[j].astype(jnp.float32)
+            if weights is not None:
+                slab = slab * weights[:, j, None]
+            total = total + jnp.where(keep[:, j, None], slab, 0.0)
+        return total
+
+
 def _grouped(expert_fn, counts):
     return lambda experts, rows: expert_fn(
         experts, rows, functools.partial(lax.ragged_dot, group_sizes=counts))
@@ -276,11 +302,13 @@ def _held_experts(experts, x, weights, local, held, expert_fn):
     by held expert: the tokens' rows are gathered into it, one grouped
     product a projection computes the rows that arrived (the held
     experts' runs, which lead), and every token gathers its ``k`` rows
-    back and adds up those of held experts.  So the gathers cost the same
-    whatever the router does, the products go with the rows that arrived,
-    nothing is dropped, and the layer has no buffer to outgrow.  (Gathers,
-    not scatter-adds: on the TPU a row scattered costs four of a row
-    gathered.)
+    back and adds up those of held experts, pick-major (:func:`_combine`:
+    ``k`` slabs of ``[t, d]``, never a ``[t, k, d]`` tensor, whose ten in
+    second-minor place the ``(8, 128)`` tile would pay as sixteen).  So
+    the gathers cost the same whatever the router does, the products go
+    with the rows that arrived, nothing is dropped, and the layer has no
+    buffer to outgrow.  (Gathers, not scatter-adds: on the TPU a row
+    scattered costs four of a row gathered.)
 
     The grouped products define only the rows of their groups: behind the
     last arrival a row of their result, and of their cotangents, is
@@ -290,8 +318,7 @@ def _held_experts(experts, x, weights, local, held, expert_fn):
     token, _, pos, counts = _plan(local, held)
     with jax.named_scope(names.EXPERTS):
         out = _grouped(expert_fn, counts)(experts, _rows(x, token))
-    picked = _rows(out, pos).astype(jnp.float32) * weights[..., None]
-    return jnp.sum(jnp.where((local < held)[..., None], picked, 0.0), axis=1)
+    return _combine(out, pos, local < held, weights)
 
 
 def _held_experts_fwd(experts, x, weights, local, held, expert_fn):
@@ -302,7 +329,8 @@ def _held_experts_fwd(experts, x, weights, local, held, expert_fn):
 def _held_experts_bwd(held, expert_fn, residuals, dy):
     """The buffer again (the products are recomputed, nothing of a block
     is kept but its inputs); each row takes its token's cotangent, each
-    token gathers its rows' back."""
+    token gathers its rows' back and adds them up pick-major, as the
+    forward combine does and for the same tile."""
     experts, x, weights, local = residuals
     token, order, pos, counts = _plan(local, held)
     is_held = local < held
@@ -315,9 +343,7 @@ def _held_experts_bwd(held, expert_fn, residuals, dy):
     d_out = dy_rows * _rows(weights.reshape(-1), order)[:, None]
     with jax.named_scope(names.EXPERTS):
         d_experts, d_rows = pull(d_out.astype(out.dtype))
-    d_x = jnp.sum(jnp.where(is_held[..., None],
-                            _rows(d_rows, pos).astype(jnp.float32), 0.0),
-                  axis=1)
+    d_x = _combine(d_rows, pos, is_held)
     d_weights = jnp.where(is_held, _rows(d_weight_rows, pos), 0.0)
     return d_experts, d_x.astype(x.dtype), d_weights.astype(weights.dtype), None
 
@@ -348,7 +374,9 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
     depend on it.
 
     Runs under the scope ``names.MOE``, the grouped products under
-    ``names.EXPERTS``, the shared expert under ``names.SHARED_EXPERT``.
+    ``names.EXPERTS``, the combine (forward, and the tokens' input
+    gradients backward) under ``names.MOE_COMBINE``, the shared expert
+    under ``names.SHARED_EXPERT``.
     Shard-local: inside a multi-device program call it under ``shard_map``.
     """
     tokens, d = x.shape
@@ -357,7 +385,8 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
         blocks += 1
     telemetry.event(names.MOE_LAYOUT, experts=n_experts, held=held,
                     first=first_expert, top_k=k, dropless=True,
-                    buffer_rows=tokens // blocks * k, blocks=blocks)
+                    buffer_rows=tokens // blocks * k, blocks=blocks,
+                    combine=names.PICK_MAJOR)
     with jax.named_scope(names.MOE):
         scored = x if router_input is None else router_input
         logits = jnp.matmul(scored.astype(jnp.float32),

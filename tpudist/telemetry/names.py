@@ -52,15 +52,17 @@ GRAD_ACCUM = "grad_accum"
 # mixer (norm, projections, convolution, gates, output projection) as ``attn``
 # is the whole softmax one; ``delta_rule`` (tpudist/ops/gated_delta.py) is the
 # recurrence alone, nested in it.  ``moe`` (tpudist/parallel/moe.py) runs from
-# the router to the combine; ``experts`` (the grouped products) and
-# ``shared_expert`` nest in it
+# the router to the combine; ``experts`` (the grouped products),
+# ``moe_combine`` (each token's rows back out of the buffer and added up,
+# forward and backward) and ``shared_expert`` nest in it
 LINEAR_ATTN = "linear_attn"
 DELTA_RULE = "delta_rule"
 MOE = "moe"
 EXPERTS = "experts"
 SHARED_EXPERT = "shared_expert"
+MOE_COMBINE = "moe_combine"
 SCOPES = (EMBED, ATTN, MLP, HEAD, LOSS, OPTIMIZER, GRAD_ACCUM, LINEAR_ATTN,
-          DELTA_RULE, MOE, EXPERTS, SHARED_EXPERT)
+          DELTA_RULE, MOE, EXPERTS, SHARED_EXPERT, MOE_COMBINE)
 #: what JAX itself writes round the scopes of a transposed (backward) op
 BACKWARD_MARK = "transpose("
 
@@ -104,9 +106,12 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # tpudist/models/hybrid.py, once a trace of the decoder: ``kinds=`` the layer
 # kinds in order (LINEAR / FULL), and of each expert layer
 # (tpudist/parallel/moe.py): ``experts=`` the router's width, ``held=``,
-# ``first=``, ``top_k=``, ``dropless=``
+# ``first=``, ``top_k=``, ``dropless=``, ``buffer_rows=``, ``blocks=``,
+# ``combine=`` PICK_MAJOR (each token's ``k`` rows are added up as ``k``
+# slabs of [tokens, d])
 MIXER_LAYOUT = "mixer_layout"
 MOE_LAYOUT = "moe_layout"
+PICK_MAJOR = "pick_major"
 LINEAR = "linear_attention"
 FULL = "full_attention"
 #: ``jax.monitoring`` event -> event name
