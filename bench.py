@@ -512,9 +512,9 @@ def numerics_gate(interpret: bool = False, quick: bool = False) -> dict:
     libtpu would otherwise ship a plausible-looking number.  Assert the
     flash kernels (fwd + bwd; dense / sliding-window / GQA / both) against
     the XLA reference — at small shapes for mask/GQA semantics AND at the
-    PRODUCTION tile sizes the timed paths use (512-wide blocks at seq 1024,
-    1024-wide KV blocks at seq 8192 — ``make_length_aware_attention``'s
-    routing), since a miscompile can be specific to one tile layout.  A
+    tile sizes of ``tpudist.ops.attention``'s default row (512-wide blocks
+    at seq 1024, 1024-wide KV blocks at seq 8192), since a miscompile can
+    be specific to one tile layout.  A
     mismatch raises — main() turns that into a value-0 record and a NONZERO
     exit, so a bad kernel can never produce a recorded measurement.
 
@@ -527,8 +527,7 @@ def numerics_gate(interpret: bool = False, quick: bool = False) -> dict:
     """
     import jax.numpy as jnp
 
-    from tpudist.ops import flash_attention
-    from tpudist.parallel import attention_reference
+    from tpudist.ops import attention_reference, flash_attention
 
     h = 4
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(7), 3)

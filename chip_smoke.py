@@ -170,9 +170,12 @@ def phase_train(w: Widths, *, seed: int, steps: int = 10,
 # phase: the reference's own workload (examples/demo.py --dry_run)
 
 
-def phase_demo(*, iterations: int = 300, group: str = "chip_smoke_demo"):
+def phase_demo(*, seed: int, iterations: int = 300,
+               group: str = "chip_smoke_demo"):
     """``examples/demo.py --dry_run`` in this process; loss read back from
-    the metrics rows this very run committed."""
+    the metrics rows this very run committed.  The seed is passed on: left
+    to itself the demo draws one anew each run, and the halving below is
+    judged on one run."""
     import importlib.util
 
     rows_path = Path("runs") / group / "metrics.jsonl"
@@ -183,7 +186,7 @@ def phase_demo(*, iterations: int = 300, group: str = "chip_smoke_demo"):
     spec.loader.exec_module(demo)
     argv, sys.argv = sys.argv, [
         "demo.py", "--dry_run", "--total_iterations", str(iterations),
-        "--group", group]
+        "--group", group, "--seed", str(seed)]
     t0 = time.perf_counter()
     try:
         demo.main()
@@ -541,9 +544,9 @@ def phase_kernels(w: Widths, *, seed: int, interpret: bool = False,
     import jax.numpy as jnp
     import numpy as np
 
-    from tpudist.models.transformer import merge_heads
-    from tpudist.ops import flash_attention, flash_attention_packed
-    from tpudist.parallel import attention_reference
+    from tpudist.ops import (attention_reference, flash_attention,
+                             flash_attention_packed)
+    from tpudist.ops.attention import merge_heads
 
     report = {}
     if flash_gate:
@@ -822,7 +825,7 @@ def main(argv=None) -> int:
         phase_multichip(FULL, seed=args.seed)
     else:
         phase_train(FULL, seed=args.seed)
-        phase_demo()
+        phase_demo(seed=args.seed)
         phase_serve(FULL, seed=args.seed)
         phase_kernels(FULL, seed=args.seed)
     say("done", wall_s=time.perf_counter() - t_start)

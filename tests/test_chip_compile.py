@@ -68,21 +68,21 @@ def _compile(fn, args, sharding):
 
 
 def test_topology_is_the_v5e(topo):
-    """The peaks table and the tuned-constants file are keyed by this
+    """The peaks table and the attention tiles' table are keyed by this
     very device kind — an unknown kind is an error on the chip."""
+    from tpudist.ops.attention import TILES
     from tpudist.utils.flops import chip_hbm_bytes_per_s, chip_peak_flops
-    from tpudist.utils.tuning import tuned_file_path
 
     dev = topo.devices[0]
     assert dev.platform == "tpu" and len(topo.devices) == 4
     assert chip_peak_flops(dev) == 197e12
     assert chip_hbm_bytes_per_s(dev) == 8.19e11
-    assert tuned_file_path(dev.device_kind).is_file()
+    assert dev.device_kind in TILES
 
 
-# (batch, heads, seq, dh, block_q, block_k): transformer.py's routing —
-# 512/512 from seq 1024, 512/1024 from seq 8192 — and the tiles the
-# tracked tpudist/tuned/TPU_v5_lite.json selects on this chip (1024/1024)
+# (batch, heads, seq, dh, block_q, block_k): the default row of
+# tpudist.ops.attention.TILES — 512/512 from seq 1024, 512/1024 from seq
+# 8192 — and the tiles its row for this chip selects (1024/1024)
 FLASH = {
     "seq2048-dh128-512x512": (8, 8, 2048, 128, 512, 512),
     "seq2048-dh128-1024x1024": (8, 8, 2048, 128, 1024, 1024),
@@ -90,7 +90,7 @@ FLASH = {
 }
 # (batch, q heads, kv heads, seq, dh, block_q, block_k): the gated attention
 # of cell qwen3next-train-ep16share-8k, 8 query heads a kv head at dh 256,
-# with the tiles the tuned file selects at 8,192 positions
+# with the tiles the table's row for this chip selects at 8,192 positions
 FLASH_GQA_256 = (2, 16, 2, 8192, 256, 1024, 1024)
 
 
@@ -315,7 +315,7 @@ def test_each_flash_kernel_is_named_once_a_layer(d1024_step, kernel):
 def test_four_chip_fsdp_step_keeps_the_flash_kernel(topo, monkeypatch):
     """Mosaic kernels cannot be partitioned automatically: inside a
     multi-chip jit the flash kernel must arrive wrapped per shard
-    (``transformer._per_shard`` under the step builder's ambient mesh) —
+    (``ops.attention._per_shard`` under the step builder's ambient mesh) —
     code that has only seen a CPU virtual mesh takes the XLA attention
     there and never meets the refusal.  Two layers: the point is the
     partitioning, not the size."""

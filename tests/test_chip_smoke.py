@@ -89,7 +89,7 @@ class TestPhasesTiny:
 
     def test_demo(self, tmp_path, monkeypatch):
         monkeypatch.chdir(tmp_path)  # the demo logs under ./runs
-        curve = chip_smoke.phase_demo(iterations=300)
+        curve = chip_smoke.phase_demo(seed=0, iterations=300)
         assert set(curve) == {"model_X", "model_Y"}
 
     def test_serve(self):

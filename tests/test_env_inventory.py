@@ -14,7 +14,7 @@ PKG = REPO / "tpudist"
 DOCS = REPO / "docs" / "ARCHITECTURE.md"
 
 #: Matches full names (TPUDIST_WATCHDOG_S) and wildcard/prefix mentions
-#: (``TPUDIST_FLASH_*`` or the f-string ``TPUDIST_{key}`` construction,
+#: (``TPUDIST_SERVE_*`` or an f-string ``TPUDIST_{key}`` construction,
 #: which surface as a trailing-underscore token).
 _TOKEN = re.compile(r"TPUDIST_[A-Z0-9_]*")
 
@@ -26,7 +26,7 @@ def _scan_package():
             continue  # the registry itself must not self-satisfy the gate
         for tok in _TOKEN.findall(path.read_text()):
             if tok.endswith("_"):
-                prefixes.add(tok)  # wildcard mention: TPUDIST_FLASH_*
+                prefixes.add(tok)  # wildcard mention: TPUDIST_SERVE_*
             else:
                 names.add(tok)
     return names, prefixes
@@ -53,18 +53,12 @@ def test_no_stale_registry_entries():
     """Every registered name is actually consumed by the package — by
     literal token or through a wildcard construction site prefix."""
     names, prefixes = _scan_package()
-    # The bare ``TPUDIST_`` construction prefix (tuning.py's f-string)
-    # would make every entry pass; only count specific prefixes.
+    # A bare ``TPUDIST_`` construction prefix would make every entry pass;
+    # only count specific prefixes.
     specific = {p for p in prefixes if p != "TPUDIST_"}
     stale = sorted(
         v for v in envutil.ENV_VARS
         if v not in names and not any(v.startswith(p) for p in specific))
-    # Tuned-constant overrides resolve via the TPUDIST_<NAME> f-string in
-    # tuning.py — they are "referenced" through the tuned-key table.
-    from tpudist.utils import tuning
-
-    tuned_keys = {f"TPUDIST_{k}" for k in tuning._V5E_DEFAULTS}
-    stale = [v for v in stale if v not in tuned_keys]
     assert not stale, (
         f"ENV_VARS entries no longer referenced anywhere in the package "
         f"(remove them or wire them back up): {stale}")

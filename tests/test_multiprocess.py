@@ -176,7 +176,8 @@ RING_WORKER = """
 
     from tpudist.runtime import bootstrap
     from tpudist.comm import collectives
-    from tpudist.parallel import attention_reference, make_ring_attention
+    from tpudist.ops import attention_reference
+    from tpudist.parallel import make_ring_attention
     from tpudist.runtime.mesh import AXIS_SEQ
 
     ctx = bootstrap.initialize()

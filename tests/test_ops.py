@@ -10,13 +10,13 @@ import numpy as np
 import pytest
 
 from tpudist.ops import (
+    attention_reference,
     flash_attention,
     flash_attention_packed,
     fused_mlp,
     mlp_reference,
     pad_params,
 )
-from tpudist.parallel import attention_reference
 
 
 class TestFlashAttention:
@@ -219,7 +219,7 @@ class TestFlashAttentionPacked:
 
     @pytest.mark.parametrize("case", sorted(PACKED))
     def test_packed_equals_head_major_bit_for_bit(self, case):
-        from tpudist.models.transformer import merge_heads, split_heads
+        from tpudist.ops.attention import merge_heads, split_heads
 
         h, kv, d, seq, bq, bk, causal, window, dtype = PACKED[case]
         qkv = jax.random.normal(jax.random.PRNGKey(0),

@@ -8,9 +8,9 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from tpudist.ops import attention_reference
 from tpudist.parallel import (
     MoEStats,
-    attention_reference,
     init_mlp_params,
     make_moe,
     make_pipeline,
@@ -686,8 +686,7 @@ class TestZigzagRing:
 
     @pytest.mark.parametrize("n,S", [(4, 64), (8, 64), (2, 32)])
     def test_value_and_grad_parity_vs_dense(self, devices, n, S):
-        from tpudist.parallel import (attention_reference,
-                                      make_zigzag_ring_attention,
+        from tpudist.parallel import (make_zigzag_ring_attention,
                                       zigzag_indices)
         from tpudist.runtime.mesh import AXIS_SEQ
 
@@ -825,7 +824,6 @@ class TestZigzagRing:
         """Explicit positions are rejected under rope, decode, AND the
         default array-order attention (each silently wrong otherwise)."""
         from tpudist.models import create_transformer
-        from tpudist.parallel import attention_reference
 
         toks = jnp.zeros((1, 16), jnp.int32)
         pos = jnp.arange(16, dtype=jnp.int32)
@@ -879,7 +877,7 @@ class TestRingGQAWire:
     def test_gqa_value_and_grad_parity(self, devices):
         """Grouped K/V through the xla ring equals the repeated-KV dense
         reference — values and grads (the repeat happens post-hop)."""
-        from tpudist.parallel import attention_reference, make_ring_attention
+        from tpudist.parallel import make_ring_attention
         from tpudist.runtime.mesh import AXIS_SEQ
 
         n, B, H, HKV, S, D = 4, 2, 4, 2, 64, 16
@@ -908,7 +906,7 @@ class TestRingGQAWire:
     def test_gqa_composes_with_window_on_xla_ring(self, devices):
         """GQA + sliding window + xla ring in one body: post-hop repeat
         must not disturb the band masking or the early ring exit."""
-        from tpudist.parallel import attention_reference, make_ring_attention
+        from tpudist.parallel import make_ring_attention
         from tpudist.runtime.mesh import AXIS_SEQ
 
         n, B, H, HKV, S, D, W = 4, 2, 4, 2, 64, 16, 12

@@ -119,6 +119,16 @@ def test_scanned_fallback_on_partial_batches(dp_mesh):
     assert all(np.isfinite(v) for v in losses.values())
 
 
+def test_loop_config_resolves_sync_every(monkeypatch):
+    """The scan window is the loop config's own: 256, or
+    ``TPUDIST_SYNC_EVERY``, or what the caller passes."""
+    monkeypatch.delenv("TPUDIST_SYNC_EVERY", raising=False)
+    assert TrainLoopConfig().sync_every == 256
+    monkeypatch.setenv("TPUDIST_SYNC_EVERY", "32")
+    assert TrainLoopConfig().sync_every == 32
+    assert TrainLoopConfig(sync_every=8).sync_every == 8
+
+
 class TestScannedLMStep:
     """make_scanned_lm_train_step: K optimizer steps per dispatch, losses
     and final state bit-matching K plain steps."""

@@ -10,6 +10,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from tpudist.models import create_transformer, lm_loss
 from tpudist.ops import flash_attention
+from tpudist.ops.attention import Tiles
 from tpudist.parallel import make_ring_attention
 from tpudist.runtime.mesh import AXIS_DATA, AXIS_SEQ, AXIS_STAGE
 from tpudist.train import init_lm_state, make_lm_train_step, token_sharding
@@ -395,7 +396,7 @@ class TestRoPE:
         """RoPE scores depend on relative offsets: a sequence prefixed by
         padding produces the same causal attention pattern shifted — check
         via the model's shift property on a repeating input."""
-        from tpudist.models.transformer import rope_rotate
+        from tpudist.ops import rope_rotate
 
         q = jax.random.normal(jax.random.PRNGKey(0), (1, 2, 8, 16))
         k = jax.random.normal(jax.random.PRNGKey(1), (1, 2, 8, 16))
@@ -812,7 +813,7 @@ class TestBlockWindowGuard:
 
     def test_untagged_attention_fn_raises(self):
         from tpudist.models.transformer import Block
-        from tpudist.parallel import attention_reference
+        from tpudist.ops import attention_reference
 
         def untagged(q, k, v):
             return attention_reference(q, k, v, causal=True)
@@ -1071,11 +1072,17 @@ class TestRematPolicies:
             bad.apply(params, _tokens(batch=1, seq=16))
 
 
+# a row that lets a test's 128 positions reach the routes a chip's 2,048 do
+_SHORT_TILES = Tiles(min_seq=128, block_q=64, block_k=64, block_k_long=64,
+                     long_seq=8192)
+
+
 class _AsTpu:
-    """The first CPU device, answering ``platform`` as a TPU would: what
-    ``make_length_aware_attention`` asks before it picks a route."""
+    """The first CPU device, answering ``device_kind`` as a TPU would:
+    what ``make_length_aware_attention`` asks before it picks a route."""
 
     platform = "tpu"
+    device_kind = "TPU of the test"
 
     def __init__(self, device):
         self._device = device
@@ -1087,10 +1094,11 @@ class _AsTpu:
 @pytest.fixture
 def as_tpu(monkeypatch):
     """Steer the attention closure the way a v5e would — from the test, not
-    through an option of the program: the platform reads ``tpu``, the flash
-    kernels start at seq 128 with 64-wide tiles, and the kernels the closure
-    then picks run in the Pallas interpreter."""
-    import tpudist.ops as ops
+    through an option of the program: the device kind reads as a TPU's
+    with a row of its own in the table (the flash kernels from seq 128 with
+    64-wide tiles), and the kernels the closure then picks run in the Pallas
+    interpreter."""
+    from tpudist.ops import attention
 
     def interpreted(kernel):
         def run(*args):
@@ -1100,13 +1108,11 @@ def as_tpu(monkeypatch):
     real = jax.devices()
     monkeypatch.setattr(jax, "devices",
                         lambda *a, **k: [_AsTpu(real[0])] + real[1:])
-    monkeypatch.setenv("TPUDIST_FLASH_MIN_SEQ", "128")
-    monkeypatch.setenv("TPUDIST_FLASH_BLOCK_Q", "64")
-    monkeypatch.setenv("TPUDIST_FLASH_BLOCK_K", "64")
-    monkeypatch.setattr(ops, "flash_attention_packed",
-                        interpreted(ops.flash_attention_packed))
-    monkeypatch.setattr(ops, "flash_attention",
-                        interpreted(ops.flash_attention))
+    monkeypatch.setitem(attention.TILES, _AsTpu.device_kind, _SHORT_TILES)
+    monkeypatch.setattr(attention, "flash_attention_packed",
+                        interpreted(attention.flash_attention_packed))
+    monkeypatch.setattr(attention, "flash_attention",
+                        interpreted(attention.flash_attention))
 
 
 @pytest.fixture
@@ -1140,13 +1146,13 @@ class TestPackedAttentionRoute:
     head-major route everywhere else, and says which in ``attn_layout``."""
 
     def _loss_and_grads(self, seq, attention_fn=None, **overrides):
-        from tpudist.models.transformer import _default_attention
+        from tpudist.ops import default_attention
 
         cfg = dict(vocab=32, d_model=256, n_layers=2, n_heads=2, d_ff=256,
                    max_len=seq) | overrides
         module, params = create_transformer(
             jax.random.PRNGKey(0), seq_len=seq,
-            attention_fn=attention_fn and _untagged(_default_attention),
+            attention_fn=attention_fn and _untagged(default_attention),
             **cfg)
         tokens = _tokens(batch=2, seq=seq)
         return jax.value_and_grad(
@@ -1171,9 +1177,10 @@ class TestPackedAttentionRoute:
     def test_other_shapes_and_platforms_stay_head_major(
             self, reason, request, layouts, monkeypatch):
         if reason == "platform":     # long enough, tiles fit, but a CPU
-            monkeypatch.setenv("TPUDIST_FLASH_MIN_SEQ", "128")
-            monkeypatch.setenv("TPUDIST_FLASH_BLOCK_Q", "64")
-            monkeypatch.setenv("TPUDIST_FLASH_BLOCK_K", "64")
+            from tpudist.ops import attention
+
+            monkeypatch.setitem(attention.TILES,
+                                jax.devices()[0].device_kind, _SHORT_TILES)
         else:
             request.getfixturevalue("as_tpu")
         seq = 64 if reason == "seq" else 128
@@ -1187,7 +1194,7 @@ class TestPackedAttentionRoute:
         """``Block`` rotates q's and k's heads on the ``[b, s, h, dh]`` view
         of the packed tensor; the head-major arm rotates ``[b, h, s, dh]``:
         the same angles on the same numbers."""
-        from tpudist.models.transformer import _default_attention
+        from tpudist.ops import default_attention
 
         cfg = dict(CFG, rope=True, n_kv_heads=kv_heads)
         tokens = _tokens()
@@ -1195,6 +1202,6 @@ class TestPackedAttentionRoute:
                                                 seq_len=64, **cfg)
         head_mod, _ = create_transformer(
             jax.random.PRNGKey(0), seq_len=64,
-            attention_fn=_untagged(_default_attention), **cfg)
+            attention_fn=_untagged(default_attention), **cfg)
         np.testing.assert_array_equal(packed_mod.apply(params, tokens),
                                       head_mod.apply(params, tokens))

@@ -26,8 +26,9 @@ ungated GELU FFN) cannot say, by mechanism:
   expert.
 
 The embedding, the head, their names and scopes, the loss the step
-builders take (``lm_loss``), the remat policy names and the rotary angles
-are ``transformer``'s, imported.  Training only: no decode cache.
+builders take (``lm_loss``) and the remat policy names are
+``transformer``'s, imported; the attention dispatch and the rotary angles
+are ``tpudist.ops``'s.  Training only: no decode cache.
 """
 
 from __future__ import annotations
@@ -40,9 +41,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from tpudist import telemetry
-from tpudist.models.transformer import (_default_attention, remat_module,
-                                        rope_angles)
+from tpudist.models.transformer import remat_module
+from tpudist.ops.attention import default_attention
 from tpudist.ops.gated_delta import chunked_gated_delta_rule
+from tpudist.ops.rope import rope_angles
 from tpudist.parallel.moe import expert_share
 from tpudist.telemetry import names
 
@@ -140,7 +142,7 @@ class GatedAttention(nn.Module):
         # route (the flash kernels, at head_dim % 128 == 0) indexes itself
         qkv = jnp.concatenate(
             [q.reshape(b, s, h * dh), k.reshape(b, s, kv * dh), v], axis=-1)
-        attn = _default_attention.packed(qkv, h, kv)
+        attn = default_attention.packed(qkv, h, kv)
         attn = attn * jax.nn.sigmoid(
             gate.reshape(b, s, h * dh).astype(jnp.float32)).astype(self.dtype)
         return _dense(d, "o_proj", self.dtype)(attn)

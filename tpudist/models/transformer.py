@@ -11,7 +11,7 @@ TPU-first:
   (or, where the fn carries a ``packed`` route as the default does, hands
   it the fused projection's ``[batch, seq, 3·d]`` output as it lies).
   Three interchangeable implementations ship: the dense XLA reference
-  (:func:`tpudist.parallel.attention_reference`), the Pallas flash kernel
+  (:func:`tpudist.ops.attention_reference`), the Pallas flash kernel
   (:func:`tpudist.ops.flash_attention`), and ring attention over a
   ``seq``-sharded mesh (:func:`tpudist.parallel.make_ring_attention`) —
   all numerically identical (tests assert it), so single-chip and
@@ -33,223 +33,16 @@ import jax
 import jax.numpy as jnp
 
 from tpudist import telemetry
-from tpudist.parallel.ring_attention import attention_reference
+from tpudist.ops.attention import (
+    default_attention,
+    make_length_aware_attention,
+    merge_heads,
+    split_heads,
+)
+from tpudist.ops.rope import rope_rotate, rope_rotate_packed
 from tpudist.telemetry import names
 
 AttentionFn = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
-
-
-def _per_shard(kernel, *operands):
-    """Run a Pallas attention ``kernel`` on each device's own batch rows.
-
-    Mosaic kernels cannot be partitioned automatically: inside a jit over
-    several chips (every multi-chip DP / FSDP / ZeRO train step) a bare
-    ``pallas_call`` is refused with "wrap the call in a shard_map".  The
-    model does not know the mesh, so the step builders
-    (``tpudist.train.lm``) trace under it as JAX's ambient mesh, and this
-    wraps the kernel in a ``shard_map`` over its ``data`` axis — attention
-    rows are independent per batch element, which is the leading axis of
-    every operand (``q, k, v`` head-major, or the one packed ``qkv``) and
-    of the result.  Heads are not split: under tensor parallelism every
-    ``model`` shard computes all heads.  One device, no ambient mesh, or
-    already inside a ``shard_map`` body (ring attention, the pipeline
-    schedules): the kernel runs as it is.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    from tpudist.runtime.mesh import AXIS_DATA
-
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
-        return kernel(*operands)
-    data = (AXIS_DATA if AXIS_DATA in mesh.axis_names
-            and operands[0].shape[0] % mesh.shape[AXIS_DATA] == 0 else None)
-    spec = P(data)
-    return jax.shard_map(kernel, in_specs=(spec,) * len(operands),
-                         out_specs=spec, check_vma=False)(*operands)
-
-
-def split_heads(qkv: jax.Array, n_heads: int, n_kv: int):
-    """The fused projection's ``[b, s, (n_heads + 2·n_kv)·dh]`` output cut
-    into head-major ``q [b, n_heads, s, dh]`` and ``k, v [b, n_kv, s,
-    dh]`` — what every attention but the packed flash route takes."""
-    b, s, cols = qkv.shape
-    dh = cols // (n_heads + 2 * n_kv)
-
-    def heads(t, n):  # [b, s, n·dh] -> [b, n, s, dh]
-        return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
-
-    d, kv_dim = n_heads * dh, n_kv * dh
-    return (heads(qkv[..., :d], n_heads),
-            heads(qkv[..., d : d + kv_dim], n_kv),
-            heads(qkv[..., d + kv_dim :], n_kv))
-
-
-def merge_heads(attn: jax.Array) -> jax.Array:
-    """``[b, h, s, dh]`` back to the ``[b, s, h·dh]`` the output projection
-    reads."""
-    b, h, s, dh = attn.shape
-    return attn.transpose(0, 2, 1, 3).reshape(b, s, h * dh)
-
-
-def make_length_aware_attention(window: Optional[int] = None):
-    """Build the platform/length-aware single-device causal attention:
-    dense XLA for short sequences (lowest dispatch overhead), the Pallas
-    flash kernel on TPU / the blockwise XLA formulation elsewhere.
-    Crossover measured on-chip (benchmarks/flash_sweep.py): flash fwd+bwd
-    wins 3× at 1024 and 3.1× at 2048; dense wins below 1024.
-
-    ``window``: sliding-window (local) attention — the flash kernels mask
-    to the band and elide tiles outside it on both sides (compute scales
-    with window, not seq); the non-kernel paths mask the dense scores.
-
-    The result accepts grouped-query K/V (fewer heads than q): the flash
-    kernels consume it natively — KV tiles are fetched once per group,
-    never materialized at full head count; the non-kernel paths broadcast.
-
-    Two operand layouts.  ``attend(q, k, v)`` is head-major, ``[b, h, s,
-    dh]``.  ``attend.packed(qkv, n_heads, n_kv)`` takes the fused
-    projection's own ``[b, s, (n_heads + 2·n_kv)·dh]`` output and returns
-    ``[b, s, n_heads·dh]``: ``Block`` calls it when an attention_fn carries
-    the tag.  The rule that picks is what the code can observe, the same
-    one that picks the flash kernels, plus ``dh % 128 == 0``; one
-    ``attn_layout`` event a traced call site says what it chose.
-    """
-    def route(seq: int):
-        """``(why_not, block_q, block_k)``: the tiles for this length and
-        why the flash kernels do not take it here (``names.WHY_SEQ`` /
-        ``WHY_PLATFORM``), ``None`` when they do."""
-        from tpudist.utils.tuning import tuned
-
-        # Measured-on-v5e defaults, re-tunable per platform generation
-        # via TPUDIST_FLASH_* env vars (tpudist.utils.tuning).
-        min_seq = tuned("flash_min_seq")
-        bq = tuned("flash_block_q")
-        # Wider KV tiles amortize the per-tile grid overhead once the KV
-        # sweep is long (8192: 6.8 vs 8.7 ms fwd+bwd — flash_sweep).
-        bk_long = tuned("flash_block_k_long")
-        bk = (bk_long if seq >= tuned("flash_long_seq")
-              and seq % bk_long == 0 else tuned("flash_block_k"))
-        # BOTH tile sizes must divide seq (the kernel's contract) — with
-        # independently overridable knobs a bad combination routes to the
-        # fallbacks instead of crashing at trace time.
-        why_not = None
-        if not (seq >= min_seq and seq % bq == 0 and seq % bk == 0):
-            why_not = names.WHY_SEQ
-        elif jax.devices()[0].platform != "tpu":
-            why_not = names.WHY_PLATFORM
-        return why_not, bq, bk
-
-    def attend(q, k, v):
-        why_not, bq, bk = route(q.shape[2])
-        use_flash = why_not is None
-        if not use_flash and k.shape[1] != q.shape[1]:
-            # only the flash kernels consume grouped K/V natively
-            group = q.shape[1] // k.shape[1]
-            k = jnp.repeat(k, group, axis=1)
-            v = jnp.repeat(v, group, axis=1)
-        if use_flash:
-            from tpudist.ops import flash_attention
-
-            return _per_shard(
-                lambda q, k, v: flash_attention(q, k, v, True, bq, bk, False,
-                                                window), q, k, v)
-        if why_not == names.WHY_SEQ:
-            return attention_reference(q, k, v, causal=True, window=window)
-        from tpudist.ops import blockwise_attention
-
-        return blockwise_attention(q, k, v, causal=True, block_k=bk,
-                                   window=window)
-
-    def attend_packed(qkv, n_heads: int, n_kv: int):
-        """The same attention over the fused projection's own ``[b, s,
-        (n_heads + 2·n_kv)·dh]`` output, giving the ``[b, s, n_heads·dh]``
-        the output projection reads.  Where the flash kernels run and one
-        head is a whole number of 128-lane tiles (``dh % 128 == 0``) they
-        index that layout themselves and nothing is re-laid out round
-        them; everywhere else: split, :func:`attend`, merge."""
-        dh = qkv.shape[-1] // (n_heads + 2 * n_kv)
-        why_not, bq, bk = route(qkv.shape[1])
-        if why_not is None and dh % 128:
-            why_not = names.WHY_DH
-        if why_not is not None:
-            telemetry.event(names.ATTN_LAYOUT, layout=names.HEAD_MAJOR,
-                            reason=why_not)
-            return merge_heads(attend(*split_heads(qkv, n_heads, n_kv)))
-        from tpudist.ops import flash_attention_packed
-
-        telemetry.event(names.ATTN_LAYOUT, layout=names.PACKED)
-        return _per_shard(
-            lambda qkv: flash_attention_packed(qkv, n_heads, n_kv, True, bq,
-                                               bk, False, window), qkv)
-
-    # Block consults this tag before broadcasting K/V to full head count —
-    # this path handles grouped-query inputs itself (see above).
-    attend.supports_gqa = True
-    # Block's training-path guard checks this tag against its
-    # sliding_window field (decode-cache masking alone is not windowed
-    # training — the mismatch must be loud, not silent).
-    attend.window = window
-    # Block hands an attention_fn that carries this tag the projection's
-    # packed output instead of head-major q, k, v.
-    attend.packed = attend_packed
-    return attend
-
-
-_default_attention = make_length_aware_attention()
-
-
-def rope_angles(offset, seq: int, half: int, base: float) -> jax.Array:
-    """f32 rotary angles ``[(b,) seq, half]`` for positions ``offset +
-    [0, seq)`` — the one place the angle math lives (``rope_rotate`` and
-    the fused RoPE+QKV kernel's tables both call it, so they cannot
-    drift)."""
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    off = jnp.asarray(offset, jnp.float32)
-    positions = off[..., None] + jnp.arange(seq, dtype=jnp.float32)
-    return positions[..., None] * freqs
-
-
-def rope_rotate(x: jax.Array, base: float = 10000.0, offset=0,
-                seq_axis: int = 2) -> jax.Array:
-    """Rotary position embedding over ``[batch, heads, seq, head_dim]``, or
-    with ``seq_axis=1`` over the ``[batch, seq, heads, head_dim]`` view of
-    a projection's output.
-
-    Angles are computed in f32 (precision-sensitive at long context) on the
-    GLOBAL sequence axis — callers apply it before any seq sharding, so
-    ring-attention shards see correct absolute positions.  Half-split
-    rotation (GPT-NeoX convention).  ``offset`` (static or traced scalar,
-    or a ``[batch]`` vector for the slot-batched paged-kernel decode path
-    where every lane sits at its own cursor) shifts positions — the
-    KV-cache decode path rotates tokens at their absolute position.
-    """
-    half = x.shape[-1] // 2
-    angles = rope_angles(offset, x.shape[seq_axis], half, base)
-    if seq_axis == 1:
-        angles = angles[..., None, :]                # [(b,) s, 1, half]
-    elif angles.ndim == 3:
-        # per-batch offsets: broadcast over the heads axis
-        angles = angles[:, None]                     # [b, 1, s, half]
-    sin, cos = jnp.sin(angles), jnp.cos(angles)
-    # rotate in f32 (position precision at long context), cast back after
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1
-    ).astype(x.dtype)
-
-
-def rope_rotate_packed(qkv: jax.Array, n_rotated: int, dh: int) -> jax.Array:
-    """:func:`rope_rotate` on a fused projection's ``[b, s, (h + 2·kv)·dh]``
-    output as it lies: q's and k's heads are its leading ``n_rotated``
-    column blocks, rotated on the ``[b, s, heads, dh]`` view (the sequence
-    is axis 1 there); v's pass through."""
-    b, s, _ = qkv.shape
-    by_head = qkv.reshape(b, s, -1, dh)
-    return jnp.concatenate(
-        [rope_rotate(by_head[:, :, :n_rotated], seq_axis=1),
-         by_head[:, :, n_rotated:]], axis=2).reshape(qkv.shape)
 
 
 def moe_expert_fn(params, tokens):
@@ -904,7 +697,7 @@ class TransformerLM(nn.Module):
                     f"sliding_window must be >= 1, got {self.sliding_window}")
         attn = self.attention_fn or (
             make_length_aware_attention(self.sliding_window)
-            if self.sliding_window is not None else _default_attention)
+            if self.sliding_window is not None else default_attention)
         seq = tokens.shape[1]
         with jax.named_scope(names.EMBED):
             x = nn.Embed(self.vocab, self.d_model, name="tok_embed",

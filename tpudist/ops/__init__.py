@@ -1,9 +1,15 @@
 """Pallas TPU kernels for hot ops, with XLA reference implementations used
 as fallbacks and in correctness tests (interpret mode on CPU).
 
-- :mod:`flash_attention` — blockwise online-softmax attention; pairs with
-  ``tpudist.parallel.ring_attention`` (ring shards between chips, flash
-  blocks within a chip).
+- :mod:`attention` — which attention runs on one device, in which operand
+  layout, with which tiles: one table by device kind and the dispatch the
+  models take as their default ``attention_fn``.
+- :mod:`flash_attention` — blockwise online-softmax attention (Pallas
+  kernels and the blockwise XLA scan) and ``attention_reference``, the
+  dense ground truth; pairs with ``tpudist.parallel.ring_attention`` (ring
+  shards between chips, flash blocks within a chip).
+- :mod:`rope` — rotary angles and rotation, shared by the models and the
+  fused RoPE+QKV kernel.
 - :mod:`fused_mlp` — the toy workload's 5-layer MLP in one kernel, weights
   zero-padded to lane-aligned tiles, activations pinned in VMEM.
 - :mod:`paged_attention` — serving-decode attention that walks the paged
@@ -23,10 +29,20 @@ as fallbacks and in correctness tests (interpret mode on CPU).
 """
 
 from tpudist.ops.flash_attention import (  # noqa: F401
+    attention_reference,
     blockwise_attention,
     flash_attention,
     flash_attention_packed,
     flash_attention_with_lse,
+)
+from tpudist.ops.attention import (  # noqa: F401
+    default_attention,
+    make_length_aware_attention,
+)
+from tpudist.ops.rope import (  # noqa: F401
+    rope_angles,
+    rope_rotate,
+    rope_rotate_packed,
 )
 from tpudist.ops.paged_attention import (  # noqa: F401
     paged_attention,

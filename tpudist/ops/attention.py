@@ -1,0 +1,219 @@
+"""How causal attention runs on one device: which implementation, in
+which operand layout, with which tiles.
+
+Three implementations of the same math live in
+:mod:`tpudist.ops.flash_attention`: the dense XLA reference, the Pallas
+flash kernels, and the blockwise XLA scan.  This module is the one place
+that chooses among them, from what it can observe: the device kind, the
+sequence length and the head width.  The tiles come from ONE table by
+device kind (:data:`TILES`); the choice itself is :func:`route`, a pure
+function a test can call without a device.  The models take
+:data:`default_attention` (or a windowed instance from
+:func:`make_length_aware_attention`) as their ``attention_fn``; ring
+attention (:mod:`tpudist.parallel.ring_attention`) is the other
+``attention_fn`` and covers the sequence sharded between chips.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+
+from tpudist import telemetry
+from tpudist.ops.flash_attention import (
+    attention_reference,
+    blockwise_attention,
+    flash_attention,
+    flash_attention_packed,
+)
+from tpudist.telemetry import names
+
+
+class Tiles(NamedTuple):
+    """One device kind's row: where the flash kernels take over and the
+    tiles they run with."""
+
+    min_seq: int        # the flash kernels from this many positions
+    block_q: int
+    block_k: int
+    block_k_long: int   # the KV tile from ``long_seq`` positions, where it
+    long_seq: int       # divides the length
+
+
+# The v5e row is what the round-2 autotuner wrote for this kind (1024 x 1024
+# tiles, flash from 2,048 positions), timed at a toy shape (head width 128,
+# 8 heads, batch 2), over the default row's ``long_seq``.  Both benchmark
+# cells run with it (2,048 and 8,192 positions: 1024 x 1024).  It has not
+# been measured on the cells' own shapes: ROADMAP S5 does that, and where
+# tiles turn out to depend on the shape the key grows here.
+TILES = {
+    "TPU v5 lite": Tiles(2048, 1024, 1024, 1024, 8192),
+}
+# Every other kind: the values first chosen on a v5e in round 2, before the
+# autotuner.  The blockwise route off the TPU reads its ``block_k`` here.
+DEFAULT_TILES = Tiles(1024, 512, 512, 1024, 8192)
+
+FLASH, REFERENCE, BLOCKWISE = "flash", "reference", "blockwise"
+
+
+class Route(NamedTuple):
+    kernel: str              # FLASH, REFERENCE or BLOCKWISE
+    block_q: int
+    block_k: int
+    why_not: Optional[str]   # why not packed flash (names.WHY_*), or None
+
+    @property
+    def layout(self) -> str:
+        return names.PACKED if self.why_not is None else names.HEAD_MAJOR
+
+
+def route(device_kind: str, seq: int, dh: int) -> Route:
+    """What runs for ``seq`` positions at head width ``dh`` on a device of
+    this kind.
+
+    The flash kernels take a length from the row's ``min_seq`` that both
+    tiles divide (the kernels' contract), on a TPU: a kind whose name
+    begins ``TPU``, as every ``jax.Device.device_kind`` of one does.  A
+    length they do not take runs the dense reference (``names.WHY_SEQ``);
+    off the TPU a length they would take runs the blockwise scan over
+    ``block_k`` (``names.WHY_PLATFORM``).  The flash kernels read the
+    packed layout where one head is a whole number of 128-lane tiles;
+    otherwise (``names.WHY_DH``) they run head-major.
+    """
+    t = TILES.get(device_kind, DEFAULT_TILES)
+    bk = (t.block_k_long if seq >= t.long_seq and seq % t.block_k_long == 0
+          else t.block_k)
+    if not (seq >= t.min_seq and seq % t.block_q == 0 and seq % bk == 0):
+        return Route(REFERENCE, t.block_q, bk, names.WHY_SEQ)
+    if not device_kind.startswith("TPU"):
+        return Route(BLOCKWISE, t.block_q, bk, names.WHY_PLATFORM)
+    return Route(FLASH, t.block_q, bk, names.WHY_DH if dh % 128 else None)
+
+
+def _per_shard(kernel, *operands):
+    """Run a Pallas attention ``kernel`` on each device's own batch rows.
+
+    Mosaic kernels cannot be partitioned automatically: inside a jit over
+    several chips (every multi-chip DP / FSDP / ZeRO train step) a bare
+    ``pallas_call`` is refused with "wrap the call in a shard_map".  The
+    model does not know the mesh, so the step builders
+    (``tpudist.train.lm``) trace under it as JAX's ambient mesh, and this
+    wraps the kernel in a ``shard_map`` over its ``data`` axis — attention
+    rows are independent per batch element, which is the leading axis of
+    every operand (``q, k, v`` head-major, or the one packed ``qkv``) and
+    of the result.  Heads are not split: under tensor parallelism every
+    ``model`` shard computes all heads.  One device, no ambient mesh, or
+    already inside a ``shard_map`` body (ring attention, the pipeline
+    schedules): the kernel runs as it is.
+    """
+    from jax.sharding import PartitionSpec as P
+
+    from tpudist.runtime.mesh import AXIS_DATA
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return kernel(*operands)
+    data = (AXIS_DATA if AXIS_DATA in mesh.axis_names
+            and operands[0].shape[0] % mesh.shape[AXIS_DATA] == 0 else None)
+    spec = P(data)
+    return jax.shard_map(kernel, in_specs=(spec,) * len(operands),
+                         out_specs=spec, check_vma=False)(*operands)
+
+
+def split_heads(qkv: jax.Array, n_heads: int, n_kv: int):
+    """The fused projection's ``[b, s, (n_heads + 2·n_kv)·dh]`` output cut
+    into head-major ``q [b, n_heads, s, dh]`` and ``k, v [b, n_kv, s,
+    dh]`` — what every attention but the packed flash route takes."""
+    b, s, cols = qkv.shape
+    dh = cols // (n_heads + 2 * n_kv)
+
+    def heads(t, n):  # [b, s, n·dh] -> [b, n, s, dh]
+        return t.reshape(b, s, n, dh).transpose(0, 2, 1, 3)
+
+    d, kv_dim = n_heads * dh, n_kv * dh
+    return (heads(qkv[..., :d], n_heads),
+            heads(qkv[..., d : d + kv_dim], n_kv),
+            heads(qkv[..., d + kv_dim :], n_kv))
+
+
+def merge_heads(attn: jax.Array) -> jax.Array:
+    """``[b, h, s, dh]`` back to the ``[b, s, h·dh]`` the output projection
+    reads."""
+    b, h, s, dh = attn.shape
+    return attn.transpose(0, 2, 1, 3).reshape(b, s, h * dh)
+
+
+def make_length_aware_attention(window: Optional[int] = None):
+    """Build the single-device causal attention that :func:`route`
+    steers, reading the device kind from ``jax.devices()[0]`` at trace
+    time: the dense XLA reference for lengths the flash kernels do not
+    take, the Pallas flash kernels on a TPU, the blockwise XLA scan
+    elsewhere.
+
+    ``window``: sliding-window (local) attention — the flash kernels mask
+    to the band and elide tiles outside it on both sides (compute scales
+    with window, not seq); the non-kernel paths mask the dense scores.
+
+    The result accepts grouped-query K/V (fewer heads than q): the flash
+    kernels consume it natively — KV tiles are fetched once per group,
+    never materialized at full head count; the non-kernel paths broadcast.
+
+    Two operand layouts.  ``attend(q, k, v)`` is head-major, ``[b, h, s,
+    dh]``.  ``attend.packed(qkv, n_heads, n_kv)`` takes the fused
+    projection's own ``[b, s, (n_heads + 2·n_kv)·dh]`` output and returns
+    ``[b, s, n_heads·dh]``: ``Block`` calls it when an attention_fn carries
+    the tag.  One ``attn_layout`` event a traced call site says which
+    layout :func:`route` chose, and why where it is not the packed one.
+    """
+    def attend(q, k, v):
+        r = route(jax.devices()[0].device_kind, q.shape[2], q.shape[3])
+        if r.kernel == FLASH:
+            return _per_shard(
+                lambda q, k, v: flash_attention(
+                    q, k, v, True, r.block_q, r.block_k, False, window),
+                q, k, v)
+        if k.shape[1] != q.shape[1]:
+            # only the flash kernels consume grouped K/V natively
+            group = q.shape[1] // k.shape[1]
+            k = jnp.repeat(k, group, axis=1)
+            v = jnp.repeat(v, group, axis=1)
+        if r.kernel == REFERENCE:
+            return attention_reference(q, k, v, causal=True, window=window)
+        return blockwise_attention(q, k, v, causal=True, block_k=r.block_k,
+                                   window=window)
+
+    def attend_packed(qkv, n_heads: int, n_kv: int):
+        """The same attention over the fused projection's own ``[b, s,
+        (n_heads + 2·n_kv)·dh]`` output, giving the ``[b, s, n_heads·dh]``
+        the output projection reads.  Where the flash kernels run and one
+        head is a whole number of 128-lane tiles (``dh % 128 == 0``) they
+        index that layout themselves and nothing is re-laid out round
+        them; everywhere else: split, :func:`attend`, merge."""
+        dh = qkv.shape[-1] // (n_heads + 2 * n_kv)
+        r = route(jax.devices()[0].device_kind, qkv.shape[1], dh)
+        if r.why_not is not None:
+            telemetry.event(names.ATTN_LAYOUT, layout=r.layout,
+                            reason=r.why_not)
+            return merge_heads(attend(*split_heads(qkv, n_heads, n_kv)))
+        telemetry.event(names.ATTN_LAYOUT, layout=r.layout)
+        return _per_shard(
+            lambda qkv: flash_attention_packed(
+                qkv, n_heads, n_kv, True, r.block_q, r.block_k, False,
+                window), qkv)
+
+    # Block consults this tag before broadcasting K/V to full head count —
+    # this path handles grouped-query inputs itself (see above).
+    attend.supports_gqa = True
+    # Block's training-path guard checks this tag against its
+    # sliding_window field (decode-cache masking alone is not windowed
+    # training — the mismatch must be loud, not silent).
+    attend.window = window
+    # Block hands an attention_fn that carries this tag the projection's
+    # packed output instead of head-major q, k, v.
+    attend.packed = attend_packed
+    return attend
+
+
+default_attention = make_length_aware_attention()

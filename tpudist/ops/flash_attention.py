@@ -44,8 +44,13 @@ each array; the kernels only ever see that block.
   three: dk and dv are two outputs of one call and an output has one
   ``BlockSpec``, so one of them would still be copied in).
 
-``tpudist.models.transformer.make_length_aware_attention`` picks the
-layout from what it can observe (platform, length, ``d % 128``).
+``tpudist.ops.attention.make_length_aware_attention`` picks the layout
+and the tiles from what it can observe (device kind, length, ``d % 128``).
+
+The module also holds the plain-XLA side of the same math:
+:func:`attention_reference` (the dense ground truth every kernel is tested
+against) and the online-softmax block update that
+:func:`blockwise_attention` and ring attention's shard-local bodies share.
 
 No reference counterpart (the reference has no attention and ships no
 kernels of its own — SURVEY.md §0, §5.7); this is TPU-native capability.
@@ -62,14 +67,78 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from tpudist.parallel.ring_attention import (
-    _block_update,
-    _causal_mask,
-    attention_reference,
-)
 from tpudist.telemetry import names
 
+# Finite stand-in for -inf: keeps exp() NaN-free when a whole row is masked
+# (a fully-masked KV block contributes exp(NEG - m_finite) == 0).
 _MASK_VALUE = -1e30
+
+
+def attention_reference(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    *,
+    causal: bool = False,
+    window: int | None = None,
+) -> jax.Array:
+    """Plain softmax attention — the single-device ground truth.
+
+    Shapes: ``q, k, v: [batch, heads, seq, head_dim]``.  ``window``
+    (requires ``causal``) masks to the sliding band ``q − k < window``.
+    """
+    scale = q.shape[-1] ** -0.5
+    # Mixed-precision discipline (a no-op for f32 inputs): MXU operands in
+    # the input dtype, score accumulation + softmax in f32, output cast back.
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    if window is not None:
+        if not causal:
+            raise ValueError("window requires causal=True")
+        if window < 1:
+            raise ValueError(f"window must be >= 1, got {window}")
+    if causal:
+        q_len, k_len = scores.shape[-2], scores.shape[-1]
+        qi = lax.broadcasted_iota(jnp.int32, (q_len, k_len), 0)
+        kj = lax.broadcasted_iota(jnp.int32, (q_len, k_len), 1)
+        keep = qi >= kj
+        if window is not None:
+            keep &= qi - kj < window
+        scores = jnp.where(keep, scores, _MASK_VALUE)
+    w = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", w.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _block_update(q, k, v, m, l, o, *, scale, mask=None):
+    """One online-softmax accumulation step over a KV block.
+
+    ``m`` row-max, ``l`` normalizer sum, ``o`` unnormalized output — the
+    (m, l, o) running triple of blockwise/flash attention.  The carry is
+    f32 whatever the input dtype (mixed-precision discipline: MXU operands
+    in the input dtype, accumulation in f32).
+    """
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    if mask is not None:
+        s = jnp.where(mask, s, _MASK_VALUE)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+    correction = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new[..., None])
+    l_new = l * correction + jnp.sum(p, axis=-1)
+    o_new = o * correction[..., None] + jnp.einsum(
+        "bhqk,bhkd->bhqd", p.astype(v.dtype), v,
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, o_new
+
+
+def _causal_mask(q_off, k_off, bq: int, bk: int, window=None):
+    q_pos = q_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k_pos = k_off + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep &= q_pos - k_pos < window
+    return keep
 
 
 def _normalize_band(causal, window):
@@ -595,8 +664,8 @@ def blockwise_attention(
     vb = jnp.moveaxis(v.reshape(v.shape[0], v.shape[1], num_kv, bk, -1), 2, 0)
 
     # One shared implementation of the numerically-sensitive softmax-rescale
-    # math: ring_attention's _block_update/_causal_mask (so the flash
-    # backward can never drift from the ring forward).
+    # math: _block_update/_causal_mask above, which ring attention's forward
+    # steps through too (so this fallback can never drift from the ring).
     @jax.checkpoint
     def body(carry, blk):
         m, l, o = carry

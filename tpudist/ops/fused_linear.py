@@ -11,7 +11,7 @@ across the slot loop, the per-slot VECTOR offsets (PR 11's paged
 cursors) become per-slot rotary tables built in-graph
 (:func:`rope_tables` — the trig tower is ``[S, T, dh]``, tiny), and the
 rotation applies in-registers right after the matmul, bit-matching
-``transformer.rope_rotate`` (same f32 angle/trig math, same half-split
+``rope.rope_rotate`` (same f32 angle/trig math, same half-split
 layout).  The optional ``extra`` operand is the LoRA delta, applied
 pre-rotation under its ``on`` mask — exactly where ``Block._ad``
 applies it on the unfused path.
@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpudist.ops.rope import rope_angles, rope_rotate
 from tpudist.telemetry import names
 
 
@@ -45,12 +46,10 @@ def rope_tables(offsets: jax.Array, T: int, dh: int, base: float):
     """Full-width rotary tables ``(cos, sin) [S, T, dh]`` f32 for the
     kernel's roll form of the half-split rotation:
     ``x * [cos, cos] + roll(x, dh/2) * [-sin, sin]`` is, term for term,
-    ``transformer.rope_rotate``'s ``[x1*cos - x2*sin, x1*sin + x2*cos]``
+    ``rope.rope_rotate``'s ``[x1*cos - x2*sin, x1*sin + x2*cos]``
     (negating a factor negates the product exactly).  The angles are
-    ``rope_rotate``'s own (``transformer.rope_angles``), per-slot
-    absolute offsets."""
-    from tpudist.models.transformer import rope_angles
-
+    ``rope_rotate``'s own (``rope.rope_angles``), per-slot absolute
+    offsets."""
     angles = rope_angles(offsets, T, dh // 2, base)    # [S, T, half]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
     return (jnp.concatenate([cos, cos], axis=-1),
@@ -198,7 +197,6 @@ def fused_rope_qkv_reference(h, w, offsets, extra=None, on=None, *,
                              n_heads, n_kv, dh, base=10000.0, rope=True):
     """Plain-jnp twin: Dense matmul + `_ad` select + head split +
     `rope_rotate`, composed exactly as `Block.__call__` does."""
-    from tpudist.models.transformer import rope_rotate
     S, T, d = h.shape
     qkv = h @ w
     if extra is not None:

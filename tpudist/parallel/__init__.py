@@ -25,7 +25,6 @@ from tpudist.parallel.ring_attention import (  # noqa: F401
     make_zigzag_ring_attention,
     ring_attention_shard_zigzag,
     zigzag_indices,
-    attention_reference,
     make_ring_attention,
     ring_attention_shard,
 )

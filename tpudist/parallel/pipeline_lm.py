@@ -151,9 +151,9 @@ def _lm_pipeline_parts(module):
     """Shared sub-modules + stage fn for the pipelined TransformerLM:
     ``(embed_mod, head_mod, stage_fn)`` — one construction point so the
     GPipe apply and the 1F1B train step cannot drift."""
-    from tpudist.models.transformer import (
-        Block,
-        _default_attention,
+    from tpudist.models.transformer import Block
+    from tpudist.ops.attention import (
+        default_attention,
         make_length_aware_attention,
     )
 
@@ -163,7 +163,7 @@ def _lm_pipeline_parts(module):
     if module.sliding_window is not None:
         attn = make_length_aware_attention(module.sliding_window)
     else:
-        attn = module.attention_fn or _default_attention
+        attn = module.attention_fn or default_attention
     block_mod = Block(
         module.d_model, module.n_heads, module.d_ff, attn,
         n_experts=module.n_experts, moe_fn=module.moe_fn,

@@ -28,6 +28,7 @@ from tpudist.comm.collectives import MetricBackend, barrier
 from tpudist.data.loader import ShardedLoader, shard_batch
 from tpudist.telemetry import names
 from tpudist.train.step import ModelState, batch_sharding
+from tpudist.utils.envutil import env_int
 from tpudist.utils.metrics import MetricsLogger
 
 
@@ -46,8 +47,7 @@ class TrainLoopConfig:
     # 256 (vs the earlier 32): on a real v5e chip the toy step costs
     # ~41 µs inside a 512-long scan vs ~60 µs at window 32 (value-fetch-
     # synced timing) — longer windows amortize per-step overhead ~1.5x.
-    # None = resolve via tpudist.utils.tuning (TPUDIST_SYNC_EVERY env /
-    # per-device-kind table / the measured 256) at loop start.
+    # None = TPUDIST_SYNC_EVERY, else 256.
     sync_every: Optional[int] = None
     # Device-cached scan path: opt-out plus an HBM budget — the dataset is
     # replicated per device, so only datasets under this cap take the path.
@@ -80,9 +80,7 @@ class TrainLoopConfig:
 
     def __post_init__(self):
         if self.sync_every is None:
-            from tpudist.utils.tuning import tuned
-
-            self.sync_every = tuned("sync_every")
+            self.sync_every = env_int("TPUDIST_SYNC_EVERY", 256)
 
 
 def _preemption_check() -> bool:

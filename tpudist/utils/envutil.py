@@ -286,16 +286,10 @@ ENV_VARS = {
     # parallel execution strategy
     "TPUDIST_OVERLAP":
         "collective-matmul overlap mode: off|ring|bidir (default off)",
-    # caches / tuned constants
+    # caches, the train loop's window
     "TPUDIST_COMPILATION_CACHE": "off = disable the persistent XLA compile cache (placed by JAX_COMPILATION_CACHE_DIR, else <repo>/.jax_cache)",
     "TPUDIST_CACHE": "native data-loader build cache base dir",
-    "TPUDIST_TUNED_FILE": "measured tuned-constants JSON path override",
     "TPUDIST_SYNC_EVERY": "train-loop scan window / metric sync cadence",
-    "TPUDIST_FLASH_MIN_SEQ": "flash-attention routing crossover (seq len)",
-    "TPUDIST_FLASH_BLOCK_Q": "flash-attention query tile size",
-    "TPUDIST_FLASH_BLOCK_K": "flash-attention KV tile size",
-    "TPUDIST_FLASH_BLOCK_K_LONG": "flash-attention KV tile at long seq",
-    "TPUDIST_FLASH_LONG_SEQ": "seq length where the long KV tile kicks in",
     # sweep harness contract (launch/sweep.py)
     "TPUDIST_SWEEP_METRIC_FILE": "where a sweep trial writes its objective",
     "TPUDIST_SWEEP_RESULTS": "sweep results.jsonl path for the report CLI",
