@@ -13,7 +13,8 @@ import pytest
 
 from tpudist.ops import attention
 from tpudist.ops.attention import (BLOCKWISE, FLASH, REFERENCE, Route, Tiles,
-                                   route)
+                                   computed_over_live, route)
+from tpudist.ops.flash_attention import _diagonal_strips, diag_sub
 from tpudist.telemetry import names
 
 PKG = Path(__file__).resolve().parent.parent / "tpudist"
@@ -21,39 +22,44 @@ V5E = "TPU v5 lite"
 
 
 def test_the_table_holds_the_two_rows_the_cells_were_measured_with():
-    assert attention.TILES == {V5E: Tiles(2048, 1024, 1024, 1024, 8192)}
-    assert attention.DEFAULT_TILES == Tiles(1024, 512, 512, 1024, 8192)
+    assert attention.TILES == {V5E: Tiles(2048, 1024, 1024, 1024, 8192, 256)}
+    assert attention.DEFAULT_TILES == Tiles(1024, 512, 512, 1024, 8192, 256)
 
 
 # id: (device kind, seq, q heads, kv heads, dh, window) ->
-#     (kernel, layout, block_q, block_k, why_not)
+#     (kernel, layout, block_q, block_k, why_not[, what the event says of
+#      the diagonal tiles: diag_sub, computed_over_live])
 ROUTES = {
     # cell cgpt590m-train-1chip: 2,048 positions, 12 heads of 128
     "v5e-gpt2-cell": ((V5E, 2048, 12, 12, 128, None),
-                      (FLASH, names.PACKED, 1024, 1024, None)),
+                      (FLASH, names.PACKED, 1024, 1024, None, 256, 1.1245)),
     # cell qwen3next-train-ep16share-8k: 8,192 positions, 16/2 heads of 256
     "v5e-share-cell": ((V5E, 8192, 16, 2, 256, None),
-                       (FLASH, names.PACKED, 1024, 1024, None)),
+                       (FLASH, names.PACKED, 1024, 1024, None, 256, 1.0311)),
     "v5e-below-min-seq": ((V5E, 1024, 8, 8, 128, None),
                           (REFERENCE, names.HEAD_MAJOR, 1024, 1024,
                            names.WHY_SEQ)),
     "v5e-dh64": ((V5E, 2048, 8, 8, 64, None),
-                 (FLASH, names.HEAD_MAJOR, 1024, 1024, names.WHY_DH)),
+                 (FLASH, names.HEAD_MAJOR, 1024, 1024, names.WHY_DH, 256,
+                  1.1245)),
     "v5e-tiles-do-not-divide": ((V5E, 2560, 8, 8, 128, None),
                                 (REFERENCE, names.HEAD_MAJOR, 1024, 1024,
                                  names.WHY_SEQ)),
+    # a window's band-edge tiles are computed whole, and not counted
     "v5e-windowed": ((V5E, 4096, 8, 2, 128, 512),
-                     (FLASH, names.PACKED, 1024, 1024, None)),
+                     (FLASH, names.PACKED, 1024, 1024, None, 0, None)),
+    # unequal tiles: whole
     "default-row-long": (("TPU v6 lite", 8192, 8, 8, 128, None),
-                         (FLASH, names.PACKED, 512, 1024, None)),
+                         (FLASH, names.PACKED, 512, 1024, None, 0, 1.1249)),
     "default-row-4096": (("TPU v6 lite", 4096, 8, 8, 128, None),
-                         (FLASH, names.PACKED, 512, 512, None)),
+                         (FLASH, names.PACKED, 512, 512, None, 256, 1.0622)),
     # from long_seq, but the long tile does not divide: the short one
     "default-row-long-tile-does-not-divide": (
         ("TPU v6 lite", 8704, 8, 8, 128, None),
-        (FLASH, names.PACKED, 512, 512, None)),
+        (FLASH, names.PACKED, 512, 512, None, 256, 1.0293)),
     "unknown-kind-at-1024": (("TPU v99 imaginary", 1024, 4, 4, 128, None),
-                             (FLASH, names.PACKED, 512, 512, None)),
+                             (FLASH, names.PACKED, 512, 512, None, 256,
+                              1.2488)),
     "cpu-2048": (("cpu", 2048, 8, 8, 128, None),
                  (BLOCKWISE, names.HEAD_MAJOR, 512, 512,
                   names.WHY_PLATFORM)),
@@ -73,21 +79,26 @@ def test_route_and_dispatch(case, monkeypatch, tmp_path):
     in one ``attn_layout`` event.  Shapes only: nothing is computed."""
     from tpudist import telemetry
 
-    (kind, seq, h, kv, dh, window), (kernel, layout, bq, bk, why_not) = (
-        ROUTES[case])
+    (kind, seq, h, kv, dh, window), (kernel, layout, bq, bk, why_not,
+                                     *diagonal) = ROUTES[case]
     want = route(kind, seq, dh)
-    assert want == Route(kernel, bq, bk, why_not) and want.layout == layout
+    # the row's ``sub`` rides with the flash route alone
+    sub = attention.TILES.get(kind, attention.DEFAULT_TILES).sub
+    assert want == Route(kernel, bq, bk, why_not, sub * (kernel == FLASH))
+    assert want.layout == layout
 
     called = []   # (kernel, layout, kv heads seen, tiles it was given, window)
 
-    def flash(q, k, v, causal, bq, bk, interpret, win):
+    def flash(q, k, v, causal, bq, bk, interpret, win, sub):
         assert causal and not interpret
-        called.append((FLASH, names.HEAD_MAJOR, k.shape[1], (bq, bk), win))
+        called.append((FLASH, names.HEAD_MAJOR, k.shape[1], (bq, bk, sub),
+                       win))
         return q
 
-    def flash_packed(qkv, n_heads, n_kv, causal, bq, bk, interpret, win):
+    def flash_packed(qkv, n_heads, n_kv, causal, bq, bk, interpret, win,
+                     sub):
         assert causal and not interpret
-        called.append((FLASH, names.PACKED, n_kv, (bq, bk), win))
+        called.append((FLASH, names.PACKED, n_kv, (bq, bk, sub), win))
         return qkv[..., : n_heads * dh]
 
     def reference(q, k, v, *, causal, window):
@@ -115,22 +126,83 @@ def test_route_and_dispatch(case, monkeypatch, tmp_path):
         out = jax.eval_shape(
             lambda qkv: attend.packed(qkv, h, kv),
             jax.ShapeDtypeStruct((2, seq, (h + 2 * kv) * dh), jnp.bfloat16))
-        events = [(r["layout"], r.get("reason")) for r in session.ring
+        events = [(r["layout"], r.get("reason"), r.get("diag_sub"),
+                   r.get("computed_over_live")) for r in session.ring
                   if r["name"] == names.ATTN_LAYOUT]
     finally:
         telemetry.finish(write_report=False)
     assert out.shape == (2, seq, h * dh)
     # only the flash kernels take K/V at its own head count, and both tiles
-    given = {FLASH: (kv, (want.block_q, want.block_k)),
+    given = {FLASH: (kv, (want.block_q, want.block_k, want.sub)),
              BLOCKWISE: (h, (want.block_k,)), REFERENCE: (h, ())}[want.kernel]
     assert called == [(want.kernel, want.layout, *given, window)]
-    assert events == [(want.layout, want.why_not)]
+    # only where the flash kernels run does the event speak of their tiles
+    assert events == [(want.layout, want.why_not, *(diagonal or (None, None)))]
+
+
+# (seq, block_q, block_k, sub) -> computed score entries over live pairs
+COMPUTED = {
+    "gpt2-cell-whole": ((2048, 1024, 1024, 0), 1.50),
+    "gpt2-cell-by-256": ((2048, 1024, 1024, 256), 1.125),
+    "share-cell-whole": ((8192, 1024, 1024, 0), 1.125),
+    "share-cell-by-256": ((8192, 1024, 1024, 256), 1.031),
+    "one-tile-whole": ((1024, 1024, 1024, 0), 2.0),
+    "one-tile-by-128": ((1024, 1024, 1024, 128), 1.125),
+    "unequal-whole": ((2048, 512, 1024, 0), 1.50),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COMPUTED))
+def test_computed_over_live(case):
+    """The pure count the event quotes, at the cells' shapes, and the same
+    number counted entry by entry from the strips the kernels unroll."""
+    (seq, bq, bk, sub), want = COMPUTED[case]
+    got = computed_over_live(seq, bq, bk, sub)
+    assert got == pytest.approx(want, abs=2e-3)
+    computed = np.zeros((seq, seq), bool)
+    for i in range(seq // bq):
+        for j in range(seq // bk):
+            rows, cols = slice(i * bq, (i + 1) * bq), slice(j * bk, (j + 1) * bk)
+            if rows.stop - 1 < cols.start:
+                continue
+            if sub and rows.start < cols.stop - 1:
+                tile = np.zeros((bq, bk), bool)
+                for r, c in _diagonal_strips(bq, sub):
+                    assert not tile[r, c].any()
+                    tile[r, c] = True
+                assert np.tril(np.ones((bq, bk), bool))[~tile].sum() == 0
+                computed[rows, cols] = tile
+            else:
+                computed[rows, cols] = True
+    assert computed[np.tril_indices(seq)].all()   # nothing live is left out
+    assert got == computed.sum() / (seq * (seq + 1) / 2)
+
+
+# (block_q, block_k, lo, hi, sub) -> the squares a diagonal tile goes by
+DIAG_SUB = {
+    "plain-causal": ((1024, 1024, 0, None, 256), 256),
+    "plain-causal-by-128": ((512, 512, 0, None, 128), 128),
+    "window": ((1024, 1024, 0, 512, 256), 0),
+    "ring-hop-shifted-band": ((1024, 1024, None, -1024, 256), 0),
+    "band-with-both-edges": ((1024, 1024, 256, 2048, 256), 0),
+    "not-causal": ((1024, 1024, None, None, 256), 0),
+    "unequal-blocks": ((512, 1024, 0, None, 256), 0),
+    "sub-does-not-divide": ((384, 384, 0, None, 256), 0),
+    "sub-is-the-block": ((256, 256, 0, None, 256), 0),
+    "no-sub": ((1024, 1024, 0, None, 0), 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DIAG_SUB))
+def test_only_the_plain_causal_band_over_equal_blocks_is_cut(case):
+    args, want = DIAG_SUB[case]
+    assert diag_sub(*args) == want
 
 
 @pytest.mark.parametrize("row,kernel", [
-    (Tiles(128, 64, 64, 64, 8192), REFERENCE),   # below min_seq
-    (Tiles(32, 16, 32, 64, 8192), BLOCKWISE),    # long enough, tiles divide
-    (Tiles(32, 16, 48, 64, 8192), REFERENCE),    # block_k does not divide 64
+    (Tiles(128, 64, 64, 64, 8192, 16), REFERENCE),   # below min_seq
+    (Tiles(32, 16, 32, 64, 8192, 16), BLOCKWISE),    # long enough, tiles divide
+    (Tiles(32, 16, 48, 64, 8192, 16), REFERENCE),    # block_k does not divide 64
 ], ids=["short", "fits", "tile-does-not-divide"])
 def test_every_route_off_the_tpu_gives_the_reference_numbers(
         row, kernel, monkeypatch):

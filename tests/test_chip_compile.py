@@ -17,6 +17,7 @@ persistent compilation cache off (a cache entry compiled for a described
 chip cannot be read back without one).
 """
 
+import contextlib
 import sys
 from pathlib import Path
 
@@ -77,7 +78,7 @@ def test_topology_is_the_v5e(topo):
     assert dev.platform == "tpu" and len(topo.devices) == 4
     assert chip_peak_flops(dev) == 197e12
     assert chip_hbm_bytes_per_s(dev) == 8.19e11
-    assert dev.device_kind in TILES
+    assert TILES[dev.device_kind].sub == V5E_SUB
 
 
 # (batch, heads, seq, dh, block_q, block_k): the default row of
@@ -92,6 +93,22 @@ FLASH = {
 # of cell qwen3next-train-ep16share-8k, 8 query heads a kv head at dh 256,
 # with the tiles the table's row for this chip selects at 8,192 positions
 FLASH_GQA_256 = (2, 16, 2, 8192, 256, 1024, 1024)
+# the squares that row cuts a tile on the diagonal into
+V5E_SUB = 256
+
+
+@contextlib.contextmanager
+def _counting_cut_tiles():
+    """``(block, sub)`` of every kernel body traced with its diagonal tiles
+    cut into squares: one a kernel."""
+    import importlib
+
+    kernels = importlib.import_module("tpudist.ops.flash_attention")
+    cut, real = [], kernels._diagonal_strips
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(kernels, "_diagonal_strips",
+                      lambda *a: cut.append(a) or real(*a))
+        yield cut
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
@@ -121,13 +138,16 @@ def test_packed_flash_compiles_at_head_dim_256_grouped_8_to_1(one_chip, grad):
     b, h, kv, s, dh, bq, bk = FLASH_GQA_256
 
     def loss(qkv):
-        return flash_attention_packed(qkv, h, kv, True, bq, bk,
-                                      False).astype(jnp.float32).sum()
+        return flash_attention_packed(qkv, h, kv, True, bq, bk, False, None,
+                                      V5E_SUB).astype(jnp.float32).sum()
 
     qkv = jax.ShapeDtypeStruct((b, s, (h + 2 * kv) * dh), jnp.bfloat16)
-    text = _compile(jax.grad(loss) if grad else loss, (qkv,),
-                    one_chip).as_text()
+    with _counting_cut_tiles() as cut:
+        text = _compile(jax.grad(loss) if grad else loss, (qkv,),
+                        one_chip).as_text()
     assert text.count("tpu_custom_call") == (3 if grad else 1)
+    # Mosaic takes the strips' slices of the (1, 1024, 256) column blocks
+    assert cut == [(bq, V5E_SUB)] * (3 if grad else 1)
 
 
 def test_chunked_delta_rule_compiles_at_the_cells_shape(one_chip):
@@ -158,13 +178,16 @@ def test_packed_flash_attention_compiles_at_the_cells_shape(one_chip, grad):
     b, s, h, dh = 4, 2048, 12, 128
 
     def loss(qkv):
-        return flash_attention_packed(qkv, h, h, True, 1024, 1024,
-                                      False).astype(jnp.float32).sum()
+        return flash_attention_packed(qkv, h, h, True, 1024, 1024, False,
+                                      None, V5E_SUB).astype(jnp.float32).sum()
 
     qkv = jax.ShapeDtypeStruct((b, s, 3 * h * dh), jnp.bfloat16)
-    text = _compile(jax.grad(loss) if grad else loss, (qkv,),
-                    one_chip).as_text()
+    with _counting_cut_tiles() as cut:
+        text = _compile(jax.grad(loss) if grad else loss, (qkv,),
+                        one_chip).as_text()
     assert text.count("tpu_custom_call") == (3 if grad else 1)
+    # Mosaic takes the strips' slices of the (1, 1024, 128) column blocks
+    assert cut == [(1024, V5E_SUB)] * (3 if grad else 1)
 
 
 KERNEL_FAMILIES = ("paged_attention", "paged_prefill", "fused_sample",
@@ -349,14 +372,11 @@ def test_four_chip_fsdp_step_keeps_the_flash_kernel(topo, monkeypatch):
         ["flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"] * w.n_layers)
 
 
-@pytest.fixture(scope="module")
-def hybrid_step(topo):
-    """The whole train step of cell ``qwen3next-train-ep16share-8k`` (one
-    chip's share of a 16-way expert-parallel deployment), built as the
+def _cell_step(topo, name):
+    """``(compiled step, job, dims)`` of a benchmark cell, built as the
     benchmark's runner builds it: the architecture's module from the cell's
     files, ``make_lm_train_step(module.apply, tx, mesh)``, compiled from
-    shapes.  ``memory_analysis()`` of THIS step is what fixes the cell's
-    rows and remat policy."""
+    shapes."""
     import json
 
     import optax
@@ -367,8 +387,7 @@ def hybrid_step(topo):
                                token_sharding)
 
     root = Path(__file__).resolve().parent.parent / "cellbench"
-    cell = json.loads((root / "workloads"
-                       / "qwen3next-train-ep16share-8k.json").read_text())
+    cell = json.loads((root / "workloads" / f"{name}.json").read_text())
     config = json.loads(
         (root / "configs" / f"{cell['config']}.json").read_text())
     job, arch = cell["job"], archs.load(config)
@@ -391,6 +410,46 @@ def hybrid_step(topo):
             arch.build_module(config, job).apply, tx, mesh,
             accum_steps=job["accum_steps"]).lower(state, tokens).compile()
     return step, job, arch.dims(config)
+
+
+@pytest.fixture(scope="module")
+def hybrid_step(topo):
+    """The whole train step of cell ``qwen3next-train-ep16share-8k`` (one
+    chip's share of a 16-way expert-parallel deployment).
+    ``memory_analysis()`` of THIS step is what fixes the cell's rows and
+    remat policy."""
+    return _cell_step(topo, "qwen3next-train-ep16share-8k")
+
+
+@pytest.fixture(scope="module")
+def gpt2_cell_step(topo):
+    """The whole train step of cell ``cgpt590m-train-1chip``: 18 layers of
+    12 heads of 128 over 2,048 positions, 4 rows, no remat."""
+    return _cell_step(topo, "cgpt590m-train-1chip")
+
+
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv"])
+def test_gpt2_cell_step_names_each_flash_kernel_once_a_layer(gpt2_cell_step,
+                                                             kernel):
+    """The benchmark's runner ends the run unless the compiled step holds
+    EXACTLY the custom calls the cell's file states (54 = 3 a layer), and
+    its reader unless each carries a known name: cutting the diagonal
+    tiles adds no call and renames none."""
+    step, job, m = gpt2_cell_step
+    text = step.as_text()
+    assert text.count("tpu_custom_call") == (
+        job["custom_calls_per_layer"] * m["layers"]) == 54
+    assert _kernels_named(text).count(kernel) == m["layers"]
+
+
+def test_gpt2_cell_step_needs_no_more_memory_than_whole_tiles_did(
+        gpt2_cell_step):
+    # PR 32's tree (whole diagonal tiles): arguments 8,006,918,656 +
+    # temporaries 7,865,907,712 bytes; the kernels' scratch is VMEM
+    mem = gpt2_cell_step[0].memory_analysis()
+    assert mem.argument_size_in_bytes == 8_006_918_656
+    assert mem.temp_size_in_bytes <= 7_865_907_712
 
 
 def test_hybrid_cell_step_fills_one_chip_and_fits(hybrid_step):

@@ -3,6 +3,7 @@ checked against the dense XLA references — the pattern SURVEY.md §4
 prescribes for doing better than the reference's zero-test strategy."""
 
 import functools
+import importlib
 
 import jax
 import jax.numpy as jnp
@@ -13,10 +14,14 @@ from tpudist.ops import (
     attention_reference,
     flash_attention,
     flash_attention_packed,
+    flash_attention_with_lse,
     fused_mlp,
     mlp_reference,
     pad_params,
 )
+
+# the module: ``tpudist.ops.flash_attention`` names the function it exports
+kernels = importlib.import_module("tpudist.ops.flash_attention")
 
 
 class TestFlashAttention:
@@ -267,6 +272,118 @@ class TestFlashAttentionPacked:
         with pytest.raises(ValueError, match="does not hold"):
             flash_attention_packed(jnp.zeros((1, 64, 100)), 4, 2, True, 64,
                                    64, True)
+
+
+# (q heads, kv heads, head_dim, layout, tiles a side): 256-wide tiles cut
+# into 64-wide squares, the cells' two head shapes
+DIAGONAL = {
+    f"{name}-{layout}-{tiles}-tiles": (h, kv, d, layout, tiles)
+    for name, h, kv, d, layouts in [
+        ("mha-dh128", 2, 2, 128, ("head-major", "packed")),
+        ("gqa8-dh256", 8, 1, 256, ("packed",))]
+    for layout in layouts for tiles in (2, 4)
+}
+# (causal, block_q, block_k, window): what a tile on the diagonal is NOT cut
+# for, each a path some caller takes
+WHOLE = {
+    "window": (True, 128, 128, 96),
+    "window-wider-than-a-tile": (True, 64, 64, 100),
+    "ring-hop-shifted-band": (False, 128, 128, (None, 64)),
+    "band-with-both-edges": (False, 128, 128, (-32, 160)),
+    "unequal-blocks-wide-k": (True, 64, 128, None),
+    "unequal-blocks-wide-q": (True, 128, 64, None),
+    "not-causal": (False, 128, 128, None),
+    "sub-does-not-divide": (True, 80, 80, None),
+}
+
+
+class TestDiagonalTilesBySquares:
+    """A tile on the diagonal of the plain causal band goes by ``sub``-wide
+    squares, and the squares above the diagonal are never computed: the
+    same numbers as the dense reference, forward, ``lse`` and all three
+    gradients; every other band keeps the whole-tile branch, bit for bit."""
+
+    @pytest.mark.parametrize("case", sorted(DIAGONAL))
+    def test_values_lse_and_gradients_match_the_reference(self, case,
+                                                          monkeypatch):
+        from tpudist.ops.attention import merge_heads, split_heads
+
+        h, kv, d, layout, tiles = DIAGONAL[case]
+        block, sub, seq = 256, 64, 256 * tiles
+        qkv = jax.random.normal(jax.random.PRNGKey(tiles),
+                                (1, seq, (h + 2 * kv) * d), jnp.float32)
+        w = jax.random.normal(jax.random.PRNGKey(9), (1, seq, h * d))
+        cut = []   # one call a kernel traced: forward, dq, dk/dv
+        real = kernels._diagonal_strips
+        monkeypatch.setattr(
+            kernels, "_diagonal_strips",
+            lambda *a: cut.append(a) or real(*a))
+
+        def flash(qkv):
+            if layout == "packed":
+                return flash_attention_packed(qkv, h, kv, True, block, block,
+                                              True, None, sub)
+            return merge_heads(flash_attention(
+                *split_heads(qkv, h, kv), True, block, block, True, None,
+                sub))
+
+        def reference(qkv):
+            q, k, v = split_heads(qkv, h, kv)
+            return merge_heads(attention_reference(
+                q, jnp.repeat(k, h // kv, 1), jnp.repeat(v, h // kv, 1),
+                causal=True))
+
+        def cotangent(fn):
+            return jax.grad(lambda x: jnp.sum(fn(x) * w))(qkv)
+
+        np.testing.assert_allclose(flash(qkv), reference(qkv), atol=2e-5,
+                                   rtol=2e-5)
+        assert cut == [(block, sub)]
+        # the gradient holds dq, dk and dv side by side
+        np.testing.assert_allclose(cotangent(flash), cotangent(reference),
+                                   atol=1e-4, rtol=1e-4)
+        assert cut[1:] == [(block, sub)] * 3   # forward again, dq, dk/dv
+        q, k, v = split_heads(qkv, h, kv)
+        if layout == "packed":
+            lse = kernels._packed_fwd(qkv, h, kv, True, block, block, True,
+                                      None, sub)[1][2].reshape(1, h, seq)
+        else:
+            lse = flash_attention_with_lse(q, k, v, True, block, block, True,
+                                           False, None, sub)[1]
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q,
+                            jnp.repeat(k, h // kv, 1)) * d ** -0.5
+        scores = jnp.where(jnp.tril(jnp.ones((seq, seq), bool)), scores,
+                           -jnp.inf)
+        np.testing.assert_allclose(
+            lse, jax.scipy.special.logsumexp(scores, axis=-1), atol=2e-5,
+            rtol=2e-5)
+
+    @pytest.mark.parametrize("case", sorted(WHOLE))
+    def test_every_other_band_keeps_the_whole_tile_branch_bit_for_bit(
+            self, case, monkeypatch):
+        causal, bq, bk, window = WHOLE[case]
+        seq = 240 if bq == 80 else 256
+        ks = jax.random.split(jax.random.PRNGKey(3), 4)
+        q = jax.random.normal(ks[0], (1, 4, seq, 32), jnp.float32)
+        k, v = (jax.random.normal(key, (1, 2, seq, 32), jnp.float32)
+                for key in ks[1:3])
+        w = jax.random.normal(ks[3], q.shape)
+        monkeypatch.setattr(
+            kernels, "_diagonal_strips",
+            lambda *a: pytest.fail(f"{case} cut a tile: {a}"))
+
+        def run(sub):
+            def both(q, k, v):
+                out, lse = flash_attention_with_lse(
+                    q, k, v, causal, bq, bk, True, False, window, sub)
+                return jnp.sum(out * w) + jnp.sum(jnp.where(
+                    lse > -1e29, lse, 0.0)), (out, lse)
+            return jax.value_and_grad(both, argnums=(0, 1, 2),
+                                      has_aux=True)(q, k, v)
+
+        for got, want in zip(jax.tree.leaves(run(32)),
+                             jax.tree.leaves(run(0))):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 class TestFusedMLP:

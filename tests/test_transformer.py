@@ -1074,7 +1074,7 @@ class TestRematPolicies:
 
 # a row that lets a test's 128 positions reach the routes a chip's 2,048 do
 _SHORT_TILES = Tiles(min_seq=128, block_q=64, block_k=64, block_k_long=64,
-                     long_seq=8192)
+                     long_seq=8192, sub=32)
 
 
 class _AsTpu:

@@ -25,6 +25,7 @@ from tpudist import telemetry
 from tpudist.ops.flash_attention import (
     attention_reference,
     blockwise_attention,
+    diag_sub,
     flash_attention,
     flash_attention_packed,
 )
@@ -40,20 +41,29 @@ class Tiles(NamedTuple):
     block_k: int
     block_k_long: int   # the KV tile from ``long_seq`` positions, where it
     long_seq: int       # divides the length
+    sub: int            # a tile on the diagonal goes by squares this wide
 
 
 # The v5e row is what the round-2 autotuner wrote for this kind (1024 x 1024
 # tiles, flash from 2,048 positions), timed at a toy shape (head width 128,
 # 8 heads, batch 2), over the default row's ``long_seq``.  Both benchmark
-# cells run with it (2,048 and 8,192 positions: 1024 x 1024).  It has not
-# been measured on the cells' own shapes: ROADMAP S5 does that, and where
-# tiles turn out to depend on the shape the key grows here.
+# cells run with it (2,048 and 8,192 positions: 1024 x 1024).  A tile that
+# size on the causal diagonal is half dead; the kernels work it by strips
+# of ``sub`` q rows and leave out the ``sub``-wide squares above the
+# diagonal (10 of 16 at 256: ``flash_attention.diag_sub`` says when,
+# :func:`computed_over_live` what is left).  ``sub`` 256 is PR 33's, from
+# the three kernels timed on the chip at both cells' shapes against 128
+# and 512 (PERF.md section 6): a diagonal tile's 0.625 of the products
+# gives dq all of it back, dk/dv and the forward less (the forward's
+# strips each wait on their own row statistics; it alone would take 512).
+# The tiles themselves have not been swept on the cells' shapes; where
+# they turn out to depend on the shape the key grows here.
 TILES = {
-    "TPU v5 lite": Tiles(2048, 1024, 1024, 1024, 8192),
+    "TPU v5 lite": Tiles(2048, 1024, 1024, 1024, 8192, 256),
 }
 # Every other kind: the values first chosen on a v5e in round 2, before the
 # autotuner.  The blockwise route off the TPU reads its ``block_k`` here.
-DEFAULT_TILES = Tiles(1024, 512, 512, 1024, 8192)
+DEFAULT_TILES = Tiles(1024, 512, 512, 1024, 8192, 256)
 
 FLASH, REFERENCE, BLOCKWISE = "flash", "reference", "blockwise"
 
@@ -63,6 +73,7 @@ class Route(NamedTuple):
     block_q: int
     block_k: int
     why_not: Optional[str]   # why not packed flash (names.WHY_*), or None
+    sub: int = 0             # the row's, for the flash kernels' diagonal tiles
 
     @property
     def layout(self) -> str:
@@ -89,7 +100,30 @@ def route(device_kind: str, seq: int, dh: int) -> Route:
         return Route(REFERENCE, t.block_q, bk, names.WHY_SEQ)
     if not device_kind.startswith("TPU"):
         return Route(BLOCKWISE, t.block_q, bk, names.WHY_PLATFORM)
-    return Route(FLASH, t.block_q, bk, names.WHY_DH if dh % 128 else None)
+    return Route(FLASH, t.block_q, bk, names.WHY_DH if dh % 128 else None,
+                 t.sub)
+
+
+def computed_over_live(seq: int, block_q: int, block_k: int,
+                       sub: int = 0) -> float:
+    """Score entries the flash kernels compute over the ``seq·(seq+1)/2``
+    live pairs of plain causal attention: every tile the band touches
+    whole, but a tile on the diagonal, where the kernels work it by
+    ``sub``-wide squares (``sub`` as :func:`diag_sub` gives it, 0 for the
+    whole tile), only the squares on or under the diagonal.  1024 x 1024
+    tiles: 1.50 whole and 1.125 at ``sub`` 256 over 2,048 positions, 1.125
+    and 1.031 over 8,192."""
+    n = block_q // sub if sub else 0
+    computed = 0
+    for i in range(seq // block_q):
+        for j in range(seq // block_k):
+            if (i + 1) * block_q - 1 < j * block_k:
+                continue                                    # elided
+            if sub and i * block_q < (j + 1) * block_k - 1:
+                computed += n * (n + 1) // 2 * sub * sub    # on the diagonal
+            else:
+                computed += block_q * block_k
+    return computed / (seq * (seq + 1) / 2)
 
 
 def _per_shard(kernel, *operands):
@@ -172,7 +206,8 @@ def make_length_aware_attention(window: Optional[int] = None):
         if r.kernel == FLASH:
             return _per_shard(
                 lambda q, k, v: flash_attention(
-                    q, k, v, True, r.block_q, r.block_k, False, window),
+                    q, k, v, True, r.block_q, r.block_k, False, window,
+                    r.sub),
                 q, k, v)
         if k.shape[1] != q.shape[1]:
             # only the flash kernels consume grouped K/V natively
@@ -192,16 +227,26 @@ def make_length_aware_attention(window: Optional[int] = None):
         index that layout themselves and nothing is re-laid out round
         them; everywhere else: split, :func:`attend`, merge."""
         dh = qkv.shape[-1] // (n_heads + 2 * n_kv)
-        r = route(jax.devices()[0].device_kind, qkv.shape[1], dh)
+        seq = qkv.shape[1]
+        r = route(jax.devices()[0].device_kind, seq, dh)
+        said = dict(layout=r.layout)
         if r.why_not is not None:
-            telemetry.event(names.ATTN_LAYOUT, layout=r.layout,
-                            reason=r.why_not)
+            said["reason"] = r.why_not
+        if r.kernel == FLASH:
+            # how a tile on the diagonal is worked (0: whole), and what
+            # that makes the kernels compute; a window's band is not
+            # counted, its tiles are computed whole
+            said["diag_sub"] = diag_sub(r.block_q, r.block_k, 0, window, r.sub)
+            if window is None:
+                said["computed_over_live"] = round(computed_over_live(
+                    seq, r.block_q, r.block_k, said["diag_sub"]), 4)
+        telemetry.event(names.ATTN_LAYOUT, **said)
+        if r.why_not is not None:
             return merge_heads(attend(*split_heads(qkv, n_heads, n_kv)))
-        telemetry.event(names.ATTN_LAYOUT, layout=r.layout)
         return _per_shard(
             lambda qkv: flash_attention_packed(
                 qkv, n_heads, n_kv, True, r.block_q, r.block_k, False,
-                window), qkv)
+                window, r.sub), qkv)
 
     # Block consults this tag before broadcasting K/V to full head count —
     # this path handles grouped-query inputs itself (see above).

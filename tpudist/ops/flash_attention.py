@@ -44,8 +44,34 @@ each array; the kernels only ever see that block.
   three: dk and dv are two outputs of one call and an output has one
   ``BlockSpec``, so one of them would still be copied in).
 
-``tpudist.ops.attention.make_length_aware_attention`` picks the layout
-and the tiles from what it can observe (device kind, length, ``d % 128``).
+What a tile costs.  A tile above the causal diagonal is elided (no fetch,
+no compute), a tile under it is interior (no mask chain).  A tile ON the
+diagonal is half dead, and with 1024 x 1024 tiles over 2,048 positions two
+of a head's three computed tiles are such tiles.  Under the plain causal
+band with equal blocks (:func:`diag_sub`) all three bodies take it as
+``block/sub`` strips of ``sub`` q rows, statically unrolled, each strip
+ONE rectangle against the keys up to its own ``sub x sub`` square (dk/dv
+too: strip ``i`` adds into rows ``[0, (i+1)·sub)`` of ``dk_acc`` and
+``dv_acc``).  The squares above the diagonal are never computed and only
+the square on it passes through the mask (one triangle, the same in every
+strip): at ``block/sub`` 4, 10 of 16 squares computed and 4 masked where
+the whole tile computes and masks 16; 1.50 -> 1.125 times the live pairs
+at 2,048 positions, 1.125 -> 1.031 at 8,192
+(``ops.attention.computed_over_live``).  Each strip is one call of the
+same body that takes an interior tile whole, so there is one copy of each
+kernel's math.  Strips of q rows and not of keys, and one rectangle a
+strip and not its square and the rest apart: a strip of keys updates the
+row statistics (or reads ``lse`` and ``delta``) for every q row below it,
+2.5 times a tile's rows, and each further piece is a further round trip
+of ``m``, ``l``, ``acc`` through VMEM (PERF.md section 6, PR 33).  Every
+other band (a window, a ring hop's shifted band, unequal blocks) computes
+its band-edge tiles whole and masked.  ``sub`` is an argument like the
+blocks: ``ops/attention.py`` passes its table's, a caller that names none
+gets ``DIAG_SUB``.
+
+``tpudist.ops.attention.make_length_aware_attention`` picks the layout,
+the tiles and ``sub`` from what it can observe (device kind, length,
+``d % 128``).
 
 The module also holds the plain-XLA side of the same math:
 :func:`attention_reference` (the dense ground truth every kernel is tested
@@ -72,6 +98,10 @@ from tpudist.telemetry import names
 # Finite stand-in for -inf: keeps exp() NaN-free when a whole row is masked
 # (a fully-masked KV block contributes exp(NEG - m_finite) == 0).
 _MASK_VALUE = -1e30
+
+# Width of the squares a tile on the diagonal is worked by (`diag_sub`)
+# for a caller that names none; `ops/attention.py` passes its table's.
+DIAG_SUB = 256
 
 
 def attention_reference(
@@ -212,25 +242,78 @@ def _tile_interior(qi, kv, block_q: int, block_k: int, lo, hi):
     return inside
 
 
+_ALL = slice(None)
+
+
+def diag_sub(block_q: int, block_k: int, lo, hi, sub) -> int:
+    """The width of the squares a tile on the diagonal is worked by, or 0
+    where such a tile is computed whole and masked.  Decided by what the
+    call states: the plain causal band (``lo == 0``, no ``hi``) over
+    equal blocks is the one case where every band-edge tile has its corner
+    on the diagonal (``qi·block_q == kv·block_k``), so its live part is
+    the same static staircase of ``sub x sub`` squares in every such tile.
+    A window, a ring hop's shifted band, unequal blocks, or a ``sub`` that
+    does not cut the block into at least two keep the whole-tile branch."""
+    if (lo == 0 and hi is None and block_q == block_k and sub
+            and block_q % sub == 0 and block_q > sub):
+        return sub
+    return 0
+
+
+def _diagonal_strips(block: int, sub: int):
+    """The live part of a tile on the diagonal as ``block/sub`` strips of
+    ``sub`` q rows, each ONE rectangle ``(rows, cols)``: the strip against
+    the keys up to its own square.  Only that square (the strip's last
+    ``sub`` columns: the same triangle in every strip) is half dead, the
+    rest is interior; the squares above the diagonal appear in no strip:
+    at ``block/sub`` 4, 10 of 16 squares are computed and 4 of those pass
+    through the mask."""
+    return [(slice(i * sub, (i + 1) * sub), slice(0, (i + 1) * sub))
+            for i in range(block // sub)]
+
+
+def _mask_diagonal_square(s, keep):
+    """Mask the one square of a strip's scores ``s`` that lies on the
+    diagonal (its last columns, see :func:`_diagonal_strips`) to ``keep``,
+    the square's ``q >= k``; the rest of the strip passes untouched, so the
+    mask chain costs ``sub x sub`` a strip."""
+    sub = keep.shape[0]
+    square = jnp.where(keep, s[:, -sub:], _MASK_VALUE)
+    if s.shape[1] == sub:
+        return square
+    return jnp.concatenate([s[:, :-sub], square], axis=1)
+
+
 def _masked_tile_branches(live, qi, kv, block_q: int, block_k: int, lo, hi,
-                          tile):
-    """Run ``tile(mask=...)`` under the live predicate, splitting interior
-    tiles (mask elided) from band-edge tiles (mask applied).  Bandless
-    kernels keep the single unmasked branch."""
+                          update, sub: int = 0):
+    """Run ``update(rows, cols, mask)`` (one rectangle of the tile, ``mask``
+    a function of its scores or ``None``) under the live predicate: an
+    interior tile whole and unmasked; a band-edge tile whole and masked to
+    the band, or, where :func:`diag_sub` says it is a tile on the diagonal
+    (``sub``), strip by strip (:func:`_diagonal_strips`).  Bandless kernels
+    keep the single unmasked branch."""
     if lo is None and hi is None:
         @pl.when(live)
         def _():
-            tile(mask=False)
+            update(_ALL, _ALL, None)
         return
     interior = _tile_interior(qi, kv, block_q, block_k, lo, hi)
 
     @pl.when(live & interior)
     def _():
-        tile(mask=False)
+        update(_ALL, _ALL, None)
 
     @pl.when(live & jnp.logical_not(interior))
     def _():
-        tile(mask=True)
+        if not sub:
+            update(_ALL, _ALL, lambda s: _tile_band_mask(
+                s, qi, kv, block_q, block_k, lo, hi))
+            return
+        keep = (lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+                >= lax.broadcasted_iota(jnp.int32, (sub, sub), 1))
+        mask = functools.partial(_mask_diagonal_square, keep=keep)
+        for rows, cols in _diagonal_strips(block_q, sub):
+            update(rows, cols, mask)
 
 
 def _tile_band_mask(s, qi, kv, block_q: int, block_k: int, lo, hi):
@@ -282,7 +365,8 @@ def _band_kv_index(block_q: int, block_k: int, lo, hi, nkv: int):
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                  *, block_q: int, block_k: int, lo, hi, scale: float):
+                  *, block_q: int, block_k: int, lo, hi, sub: int,
+                  scale: float):
     """One (bh, q_block, kv_block) grid step.
 
     The grid's KV dimension is innermost (TPU grids run sequentially), so
@@ -301,29 +385,30 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
     # Tiles outside the band contribute nothing — skip.  Interior tiles
-    # (fully inside the band) additionally skip the mask chain.
-    def tile(mask: bool):
+    # (fully inside the band) additionally skip the mask chain, and a tile
+    # on the diagonal is taken strip by strip (`_masked_tile_branches`).
+    def update(rows, cols, mask):
         # MXU operands stay in the input dtype (bf16 runs at bf16 MXU
         # throughput); accumulation is always f32 via preferred_element_type.
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if mask:
-            s = _tile_band_mask(s, qi, kv, block_q, block_k, lo, hi)
-        m = m_ref[:, 0]
-        l = l_ref[:, 0]
+        if mask is not None:
+            s = mask(s)
+        m = m_ref[rows, 0]
+        l = l_ref[rows, 0]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[:, None])
         correction = jnp.exp(m - m_new)
-        m_ref[:, 0] = m_new
-        l_ref[:, 0] = l * correction + jnp.sum(p, axis=-1)
-        acc_ref[:] = acc_ref[:] * correction[:, None] + jnp.dot(
+        m_ref[rows, 0] = m_new
+        l_ref[rows, 0] = l * correction + jnp.sum(p, axis=-1)
+        acc_ref[rows] = acc_ref[rows] * correction[:, None] + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
 
     _masked_tile_branches(_tile_live(qi, kv, block_q, block_k, lo, hi),
-                          qi, kv, block_q, block_k, lo, hi, tile)
+                          qi, kv, block_q, block_k, lo, hi, update, sub)
 
     # Last KV block of this Q row: normalize and emit.  A row with no
     # live tile at all (possible under a shifted band — e.g. a ring hop
@@ -497,7 +582,7 @@ def _kv_innermost_specs(lay: _Layout, bq: int, bk: int, lo, hi):
 
 
 def _flash_forward(q, k, v, lay: _Layout, *, causal, block_q, block_k,
-                   interpret, out_f32=False, window=None):
+                   interpret, out_f32=False, window=None, sub=0):
     """The forward call over operands laid out as ``lay`` says (the packed
     entry passes one array three times): ``(o, lse)`` with ``o`` of
     ``lay.o_shape`` and ``lse`` ``[batch·heads, 1, seq_q]``."""
@@ -509,7 +594,8 @@ def _flash_forward(q, k, v, lay: _Layout, *, causal, block_q, block_k,
     bh_kv = lay.batch * lay.kv_heads
 
     kernel = functools.partial(
-        _flash_kernel, block_q=bq, block_k=bk, lo=lo, hi=hi, scale=scale,
+        _flash_kernel, block_q=bq, block_k=bk, lo=lo, hi=hi,
+        sub=diag_sub(bq, bk, lo, hi, sub), scale=scale,
     )
     q_tile, kv_tile, row_spec = _kv_innermost_specs(lay, bq, bk, lo, hi)
 
@@ -557,17 +643,18 @@ def _flash_forward(q, k, v, lay: _Layout, *, causal, block_q, block_k,
 
 
 def _head_major_forward(q, k, v, *, causal, block_q, block_k, interpret,
-                        out_f32, window):
+                        out_f32, window, sub):
     lay = _head_major_layout(q, k, v)
     batch, heads, seq_q, d = q.shape
     out, lse = _flash_forward(
         q.reshape(lay.o_shape), k.reshape(lay.dkv_shape),
         v.reshape(lay.dkv_shape), lay, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret, out_f32=out_f32, window=window)
+        block_k=block_k, interpret=interpret, out_f32=out_f32, window=window,
+        sub=sub)
     return out.reshape(batch, heads, seq_q, d), lse.reshape(batch, heads, seq_q)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention_with_lse(
     q: jax.Array,
     k: jax.Array,
@@ -578,6 +665,7 @@ def flash_attention_with_lse(
     interpret: bool = False,
     out_f32: bool = False,
     window: int | None = None,
+    sub: int = DIAG_SUB,
 ):
     """Flash attention that also returns the per-row logsumexp
     ``[batch, heads, seq_q]`` (f32, scaled-score domain).
@@ -595,7 +683,7 @@ def flash_attention_with_lse(
     """
     return _head_major_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, out_f32=out_f32, window=window,
+        interpret=interpret, out_f32=out_f32, window=window, sub=sub,
     )
 
 
@@ -608,6 +696,7 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool = False,
     window: int | None = None,
+    sub: int = DIAG_SUB,
 ) -> jax.Array:
     """Flash attention over ``[batch, heads, seq, head_dim]`` inputs.
 
@@ -618,7 +707,7 @@ def flash_attention(
     both sides — compute AND fetch cost scale with ``window``, not seq.
     """
     out, _ = flash_attention_with_lse(
-        q, k, v, causal, block_q, block_k, interpret, False, window
+        q, k, v, causal, block_q, block_k, interpret, False, window, sub
     )
     return out
 
@@ -685,7 +774,7 @@ def blockwise_attention(
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_acc_ref, *, block_q: int, block_k: int,
-                         lo, hi, scale: float):
+                         lo, hi, sub: int, scale: float):
     """dq: grid (bh, q_block, kv_block), KV innermost — dq for one Q tile
     accumulates in VMEM scratch across its KV sweep, mirroring the forward's
     schedule (and its causal dead-block elision)."""
@@ -697,31 +786,31 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
-    def tile(mask: bool):
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
+    def update(rows, cols, mask):
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
+        do = do_ref[0, rows]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if mask:
-            s = _tile_band_mask(s, qi, kv, block_q, block_k, lo, hi)
+        if mask is not None:
+            s = mask(s)
         # Softmax tile from the saved row logsumexp — no m/l recurrence.
         # Dead rows carry the _MASK_VALUE lse sentinel: exp(s − lse) would
         # be exp(0)=1 on their masked entries, so zero them explicitly.
-        row_lse = lse_ref[0, 0, :]
+        row_lse = lse_ref[0, 0, rows]
         # Dead-row mask as f32: a bool ([:, None]) minor-dim insert on the
         # lane-layout row vector is unsupported by Mosaic (i1 relayout);
         # the f32 multiply lowers cleanly and is numerically identical.
         live = (row_lse > _MASK_VALUE * 0.5).astype(jnp.float32)
         p = jnp.exp(s - row_lse[:, None]) * live[:, None]
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0, :][:, None]) * scale
-        dq_acc_ref[:] += jnp.dot(
+        ds = p * (dp - delta_ref[0, 0, rows][:, None]) * scale
+        dq_acc_ref[rows] += jnp.dot(
             ds.astype(k.dtype), k, preferred_element_type=jnp.float32
         )
 
     _masked_tile_branches(_tile_live(qi, kv, block_q, block_k, lo, hi),
-                          qi, kv, block_q, block_k, lo, hi, tile)
+                          qi, kv, block_q, block_k, lo, hi, update, sub)
 
     @pl.when(kv == _last_live_kv(qi, nkv, block_q, block_k, lo))
     def _():
@@ -730,7 +819,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *,
-                          block_q: int, block_k: int, lo, hi,
+                          block_q: int, block_k: int, lo, hi, sub: int,
                           scale: float, n_q_tiles: int):
     """dk/dv: grid (bh_kv, kv_block, group·q_block) with the (group member,
     Q tile) sweep innermost — dk/dv for one KV tile accumulate in VMEM
@@ -747,27 +836,27 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
 
-    def tile(mask: bool):
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
+    def update(rows, cols, mask):
+        q = q_ref[0, rows]
+        k = k_ref[0, cols]
+        v = v_ref[0, cols]
+        do = do_ref[0, rows]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if mask:
-            s = _tile_band_mask(s, qi, kv, block_q, block_k, lo, hi)
-        row_lse = lse_ref[0, 0, :]
+        if mask is not None:
+            s = mask(s)
+        row_lse = lse_ref[0, 0, rows]
         live = (row_lse > _MASK_VALUE * 0.5).astype(jnp.float32)  # see dq
         p = jnp.exp(s - row_lse[:, None]) * live[:, None]
         pt = p.astype(do.dtype).T
-        dv_acc_ref[:] += jnp.dot(pt, do, preferred_element_type=jnp.float32)
+        dv_acc_ref[cols] += jnp.dot(pt, do, preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0, :][:, None]) * scale
-        dk_acc_ref[:] += jnp.dot(
+        ds = p * (dp - delta_ref[0, 0, rows][:, None]) * scale
+        dk_acc_ref[cols] += jnp.dot(
             ds.astype(q.dtype).T, q, preferred_element_type=jnp.float32
         )
 
     _masked_tile_branches(_tile_live(qi, kv, block_q, block_k, lo, hi),
-                          qi, kv, block_q, block_k, lo, hi, tile)
+                          qi, kv, block_q, block_k, lo, hi, update, sub)
 
     @pl.when(gi == pl.num_programs(2) - 1)
     def _():
@@ -776,7 +865,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
-                    block_q, block_k, interpret, window=None):
+                    block_q, block_k, interpret, window=None, sub=0):
     """The two backward calls over operands laid out as ``lay`` says:
     ``(dq, dk, dv)`` with ``dq`` of ``lay.o_shape``, ``dk`` / ``dv`` of
     ``lay.dkv_shape``; ``lse`` / ``delta`` are ``[batch·heads, 1, seq_q]``."""
@@ -785,6 +874,7 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
     seq_q, seq_k, d = lay.seq_q, lay.seq_k, lay.d
     group = heads // kv_heads
     bq, bk = _blocks(lay, block_q, block_k, interpret)
+    sub = diag_sub(bq, bk, lo, hi, sub)
     scale = d ** -0.5
     bh = lay.batch * heads
     bh_kv = lay.batch * kv_heads
@@ -801,7 +891,7 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
-                          lo=lo, hi=hi, scale=scale),
+                          lo=lo, hi=hi, sub=sub, scale=scale),
         out_shape=jax.ShapeDtypeStruct(lay.o_shape, q.dtype),
         grid=(bh, nq, nkv),
         in_specs=[q_tile(lay.q), kv_tile(lay.k), kv_tile(lay.v),
@@ -857,7 +947,7 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=bq, block_k=bk,
-                          lo=lo, hi=hi, scale=scale, n_q_tiles=nq),
+                          lo=lo, hi=hi, sub=sub, scale=scale, n_q_tiles=nq),
         out_shape=[
             jax.ShapeDtypeStruct(lay.dkv_shape, k.dtype),
             jax.ShapeDtypeStruct(lay.dkv_shape, v.dtype),
@@ -883,15 +973,16 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
     return dq, dk, dv
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret, out_f32, window):
+def _fwd(q, k, v, causal, block_q, block_k, interpret, out_f32, window, sub):
     out, lse = _head_major_forward(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, out_f32=out_f32, window=window,
+        interpret=interpret, out_f32=out_f32, window=window, sub=sub,
     )
     return (out, lse), (q, k, v, out, lse)
 
 
-def _bwd(causal, block_q, block_k, interpret, out_f32, window, residuals, g):
+def _bwd(causal, block_q, block_k, interpret, out_f32, window, sub, residuals,
+         g):
     q, k, v, out, lse = residuals
     g_out, g_lse = g
     # delta_i = rowsum(dO_i · O_i): the dp→ds correction term, cheap
@@ -908,6 +999,7 @@ def _bwd(causal, block_q, block_k, interpret, out_f32, window, residuals, g):
         v.reshape(lay.dkv_shape), g_out.reshape(lay.o_shape),
         lse.reshape(stats), delta.reshape(stats), lay, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret, window=window,
+        sub=sub,
     )
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
@@ -915,7 +1007,7 @@ def _bwd(causal, block_q, block_k, interpret, out_f32, window, residuals, g):
 flash_attention_with_lse.defvjp(_fwd, _bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6, 7, 8))
 def flash_attention_packed(
     qkv: jax.Array,
     n_heads: int,
@@ -925,6 +1017,7 @@ def flash_attention_packed(
     block_k: int = 128,
     interpret: bool = False,
     window: int | None = None,
+    sub: int = DIAG_SUB,
 ) -> jax.Array:
     """Flash attention straight over a fused projection's output: ``qkv``
     is ``[batch, seq, (n_heads + 2·n_kv)·head_dim]`` (q's heads, then k's,
@@ -936,19 +1029,20 @@ def flash_attention_packed(
     backward.  On TPU ``head_dim`` must be a multiple of 128: one head is
     then a whole number of lane tiles of the last dimension."""
     return _packed_fwd(qkv, n_heads, n_kv, causal, block_q, block_k,
-                       interpret, window)[0]
+                       interpret, window, sub)[0]
 
 
 def _packed_fwd(qkv, n_heads, n_kv, causal, block_q, block_k, interpret,
-                window):
+                window, sub):
     out, lse = _flash_forward(
         qkv, qkv, qkv, _packed_layout(qkv, n_heads, n_kv), causal=causal,
-        block_q=block_q, block_k=block_k, interpret=interpret, window=window)
+        block_q=block_q, block_k=block_k, interpret=interpret, window=window,
+        sub=sub)
     return out, (qkv, out, lse)
 
 
 def _packed_bwd(n_heads, n_kv, causal, block_q, block_k, interpret, window,
-                residuals, g):
+                sub, residuals, g):
     qkv, out, lse = residuals
     lay = _packed_layout(qkv, n_heads, n_kv)
     # delta a head at a time: each is a lane-aligned column block of o and
@@ -960,7 +1054,7 @@ def _packed_bwd(n_heads, n_kv, causal, block_q, block_k, interpret, window,
                        for h in range(n_heads)], axis=1).reshape(lse.shape)
     dq, dk, dv = _flash_backward(
         qkv, qkv, qkv, g, lse, delta, lay, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret, window=window)
+        block_k=block_k, interpret=interpret, window=window, sub=sub)
     return (jnp.concatenate([dq, dk, dv], axis=-1),)
 
 

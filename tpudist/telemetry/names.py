@@ -95,7 +95,10 @@ COMPILE_CACHE_MISS = "compile_cache_miss"
 # a training ``Block``: which operand layout the attention took, as
 # ``layout=`` PACKED (the flash kernels index the fused projection's own
 # [b, s, 3·d] output) or HEAD_MAJOR ([b, h, s, dh] operands, re-laid out
-# round the attention) with the ``reason=`` it was not packed
+# round the attention) with the ``reason=`` it was not packed; where the
+# flash kernels run, ``diag_sub=`` the squares a tile on the causal diagonal
+# is worked by (0: whole and masked) and, without a window,
+# ``computed_over_live=`` score entries computed over live pairs
 ATTN_LAYOUT = "attn_layout"
 PACKED = "packed"
 HEAD_MAJOR = "head_major"
