@@ -168,6 +168,28 @@ def test_chunked_delta_rule_compiles_at_the_cells_shape(one_chip):
     assert mem.temp_size_in_bytes < 4e9
 
 
+def test_chunked_delta_rule_compiles_at_unequal_widths_beyond_beta_1(
+        one_chip):
+    """Cell ``olmohybrid-train-tp2share-8k``'s scan: 15 heads of key width
+    96 and value width 192 (neither a multiple of the 128 lanes) over one
+    row of 8,192 positions, the inverse by halves from 4-row blocks
+    (``beta_max`` 2), forward and backward."""
+    from tpudist.ops.gated_delta import chunked_gated_delta_rule
+
+    b, s, h, dk, dv = 1, 8192, 15, 96, 192
+
+    def loss(q, k, v, g, beta):
+        return chunked_gated_delta_rule(q, k, v, g, beta, beta_max=2.0).astype(
+            jnp.float32).sum()
+
+    key = jax.ShapeDtypeStruct((b, s, h, dk), jnp.bfloat16)
+    value = jax.ShapeDtypeStruct((b, s, h, dv), jnp.bfloat16)
+    gate = jax.ShapeDtypeStruct((b, s, h), jnp.float32)
+    mem = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                   (key, key, value, gate, gate), one_chip).memory_analysis()
+    assert mem.temp_size_in_bytes < 1e9
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
 def test_packed_flash_attention_compiles_at_the_cells_shape(one_chip, grad):
     """The packed entry's blocks — ``(1, 1024, 128)`` column blocks of a
@@ -486,3 +508,41 @@ def test_hybrid_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
         job["custom_calls_per_layer"] * m["layers"])
     assert not re.search(r"\[512,2048,512\]|\[512,512,2048\]", text)
     assert re.search(r"\[32,2048,512\]", text)
+
+
+@pytest.fixture(scope="module")
+def olmo_hybrid_step(topo):
+    """The whole train step of cell ``olmohybrid-train-tp2share-8k`` (one
+    chip's share of a 2-way head-parallel deployment): 1 row x 8,192."""
+    return _cell_step(topo, "olmohybrid-train-tp2share-8k")
+
+
+def test_olmo_hybrid_cell_step_fills_one_chip_and_fits(olmo_hybrid_step):
+    step, job, m = olmo_hybrid_step
+    assert job["per_chip_batch"] == 1 and job["seq_len"] == 8192
+    mem = step.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    # 766,241,946 parameters x 12 bytes resident
+    assert 9.1e9 < mem.argument_size_in_bytes < 9.3e9
+    # 12.50 GiB (temporaries 4.23 GB, the float32 gradient among them);
+    # the issue's ceiling is 15.0 GiB, the compiler allows 15.75
+    assert 12e9 < held < 15.0 * 2 ** 30
+
+
+def test_olmo_hybrid_cell_step_carries_exactly_the_three_flash_kernels(
+        olmo_hybrid_step):
+    """One full-attention layer in the four at 15 equal heads of 128: the
+    three flash kernels by name, the forward twice (its layer is
+    rematerialised), and no other custom call: the delta-rule scan and the
+    dense feed-forward are plain XLA."""
+    step, job, m = olmo_hybrid_step
+    text = step.as_text()
+    assert sorted(_kernels_named(text)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"]
+    assert text.count("tpu_custom_call") == 4 == (
+        job["custom_calls_per_layer"] * m["layers"])
+    assert "ragged-dot" not in text
+    assert job["collectives_in_step"] == []
+    assert "all-reduce" not in text and "all-gather" not in text
+

@@ -1,39 +1,62 @@
-"""A decoder LM whose layers follow a pattern of mixers: gated delta-rule
-linear attention and gated softmax attention, each followed by a routed
-expert layer of which this device holds a share.
+"""A decoder LM whose layers follow a pattern of mixers (gated delta-rule
+linear attention and causal softmax attention), each followed by a
+feed-forward arm: a routed expert layer of which this device holds a share,
+or a dense gated feed-forward.
 
 What :mod:`tpudist.models.transformer`'s ``Block`` (pre-LN, LayerNorm,
 ungated GELU FFN) cannot say, by mechanism:
 
 - a **layer pattern**: ``layer_types`` names each layer's mixer
   (``names.LINEAR`` / ``names.FULL``);
-- **zero-centred RMSNorm** (``x * rsqrt(mean(x^2) + eps) * (1 + w)``, in
-  float32, ``w`` starting at 0);
+- **RMS norms** in float32, zero-centred (``x * rsqrt(mean(x^2) + eps) *
+  (1 + w)``, ``w`` from 0) or plain (``... * w``, ``w`` from 1), **before**
+  each sublayer (``x + f(norm(x))``) or **after** it (``x + norm(f(x))``);
 - **gated softmax attention** (:class:`GatedAttention`): the query
   projection also gives a sigmoid gate on the attention's output, queries
   and keys are RMS-normed per head, rotary positions turn only the first
   ``rotary_dim`` of a head's dims, grouped key/value heads, a head width
-  that is not ``d_model / n_heads``; the causal attention itself is
-  ``transformer``'s length-aware dispatch (the flash kernels where they
-  run);
-- **gated delta-rule linear attention** (:class:`GatedDeltaNet`): fused
-  projections laid out per key head, a short depthwise causal convolution
-  with SiLU over q, k, v, per-head decay and write strength, L2-normed
-  queries and keys, the chunked scan of :mod:`tpudist.ops.gated_delta`,
-  an RMS norm gated by ``silu(z)``;
+  that is not ``d_model / n_heads``;
+- **softmax attention behind one norm over all heads**
+  (:class:`NormedAttention`): queries and keys RMS-normed with ONE statistic
+  a token over every head's dims together, no gate, no rotary positions;
+- **gated delta-rule linear attention** (:class:`GatedDeltaNet`):
+  projections fused per key head or separate, a short depthwise causal
+  convolution with SiLU over q, k, v, per-head decay, a write strength of
+  ``beta_scale * sigmoid``, L2-normed queries and keys, the chunked scan of
+  :mod:`tpudist.ops.gated_delta` (key and value widths may differ), an RMS
+  norm gated by ``silu(z)``;
 - **routed experts as a share** (:class:`ExpertShare`):
   :func:`tpudist.parallel.moe.expert_share`, dropless, with a gated shared
-  expert.
+  expert; or a **dense gated feed-forward** (:class:`GatedMLP`);
+- **a share of the heads**: the mixers are told how many heads of how many
+  they hold (``n_heads`` of ``n_heads_total``, ...), as the expert layer is
+  told its experts.  With ``heads_axis`` (a mapped axis over the members
+  that share a layer) the one norm statistic that runs over all heads and
+  the output projections' partial sums are reduced over it; without it a
+  member's partial output goes on as it is.
+
+Which arm a layer takes is data on :class:`HybridSizes`, filled in by
+whoever builds the module; nothing here knows a model.  The two
+architectures that run through it (``cellbench/archs``): ``qwen3_next``
+(norms zero-centred and before the sublayer, :class:`GatedAttention`, fused
+projections with ``nv / nk`` value heads a key head at 128 / 128, write
+strength in ``[0, 1]``, :class:`ExpertShare`) and ``olmo_hybrid`` (norms
+plain and after the sublayer, :class:`NormedAttention`, separate
+projections at ``dk`` 96 / ``dv`` 192, write strength in ``[0, 2]``,
+:class:`GatedMLP`, half of each mixer's heads held).
 
 The embedding, the head, their names and scopes, the loss the step
 builders take (``lm_loss``) and the remat policy names are
-``transformer``'s, imported; the attention dispatch and the rotary angles
-are ``tpudist.ops``'s.  Training only: no decode cache.
+``transformer``'s, imported; the causal attention itself is
+``tpudist.ops.attention``'s length-aware dispatch (the flash kernels where
+they run), the rotary angles ``tpudist.ops.rope``'s.  Training only: no
+decode cache.
 """
 
 from __future__ import annotations
 
 import dataclasses
+from typing import Optional
 
 import flax.linen as nn
 import jax
@@ -71,6 +94,31 @@ class ZeroCentredRMSNorm(nn.Module):
         return _rms(x, self.eps) * (1.0 + scale)
 
 
+class RMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + eps) * scale`` over the last axis, in float32;
+    ``scale`` starts at 1.  With ``axis`` the last axis is this member's
+    equal slice of a vector that the members of that mapped axis hold
+    between them: the mean square is taken over all of it (one ``psum`` of
+    a number a token) and ``scale`` is the slice's."""
+
+    eps: float = 1e-6
+    axis: Optional[str] = None
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        if self.axis is None:
+            return _rms(x, self.eps) * scale
+        x = x.astype(jnp.float32)
+        square = jax.lax.psum(jnp.sum(x * x, axis=-1, keepdims=True),
+                              self.axis)
+        dims = x.shape[-1] * jax.lax.psum(1, self.axis)
+        return x * jax.lax.rsqrt(square / dims + self.eps) * scale
+
+
+NORMS = {names.ZERO_CENTRED: ZeroCentredRMSNorm, names.PLAIN: RMSNorm}
+
+
 def _dense(features: int, name: str, dtype):
     return nn.Dense(features, use_bias=False, name=name, dtype=dtype)
 
@@ -91,21 +139,37 @@ def rotate_partial(x, rotary_dim: int, base: float):
 
 @dataclasses.dataclass(frozen=True)
 class HybridSizes:
-    """The sizes of a pattern decoder, apart from depth and vocabulary."""
+    """The sizes of a pattern decoder, apart from depth and vocabulary, and
+    which arm each part of a layer takes (``names.*``)."""
 
     d_model: int
-    # gated softmax attention
+    # softmax attention: heads HELD here, of ``n_heads_total`` query heads
+    # in all (None: all of them are here)
     n_heads: int
     n_kv_heads: int
     head_dim: int
-    rotary_dim: int
+    rotary_dim: int              # the gated attention's
     rope_theta: float = 1e7
-    # gated delta-rule linear attention
+    n_heads_total: Optional[int] = None
+    attention: str = names.GATED_ATTN        # or names.NORMED_ATTN
+    # gated delta-rule linear attention: heads HELD, of
+    # ``linear_value_heads_total`` value heads in all
     linear_key_heads: int = 16
     linear_value_heads: int = 32
     linear_key_dim: int = 128
     linear_value_dim: int = 128
     linear_conv_width: int = 4
+    linear_value_heads_total: Optional[int] = None
+    linear_projections: str = names.FUSED    # or names.SEPARATE
+    beta_scale: float = 1.0      # write strength = beta_scale * sigmoid(.)
+    # the mapped axis over the members that share a layer by heads, or None
+    heads_axis: Optional[str] = None
+    # norms: which, and on which side of the sublayer
+    norm: str = names.ZERO_CENTRED           # or names.PLAIN
+    norm_after: bool = False
+    # the feed-forward arm
+    feed_forward: str = names.EXPERT_SHARE   # or names.DENSE_FFN
+    ffn_width: int = 0           # the dense arm's
     # routed experts: ``n_experts`` is the router's width, ``held`` of them
     # (``first_expert`` on) live here
     n_experts: int = 8
@@ -148,6 +212,46 @@ class GatedAttention(nn.Module):
         return _dense(d, "o_proj", self.dtype)(attn)
 
 
+def _over_members(y, axis):
+    """An output projection's partial sums, added up over the members that
+    share the layer by heads."""
+    return y if axis is None else jax.lax.psum(y, axis)
+
+
+class NormedAttention(nn.Module):
+    """Causal softmax attention whose queries and keys are RMS-normed with
+    one statistic over ALL heads' dims together (``q_norm`` holds a weight
+    a dim of every head); no gate and no rotary positions (``rotary_dim``
+    is not read).  Holding ``n_heads`` of ``n_heads_total``, the statistic
+    runs over the held heads' dims unless ``heads_axis`` reduces it (and
+    ``o_proj``'s partial sums) over the members."""
+
+    sizes: HybridSizes
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        z = self.sizes
+        h, kv, dh = z.n_heads, z.n_kv_heads, z.head_dim
+
+        def normed(name, heads):
+            t = _dense(heads * dh, f"{name}_proj", self.dtype)(x)
+            return RMSNorm(z.eps, z.heads_axis,
+                           name=f"{name}_norm")(t).astype(self.dtype)
+
+        qkv = jnp.concatenate(
+            [normed("q", h), normed("k", kv),
+             _dense(kv * dh, "v_proj", self.dtype)(x)], axis=-1)
+        attn = default_attention.packed(qkv, h, kv)
+        return _over_members(_dense(d, "o_proj", self.dtype)(attn),
+                             z.heads_axis)
+
+
+ATTENTIONS = {names.GATED_ATTN: GatedAttention,
+              names.NORMED_ATTN: NormedAttention}
+
+
 def causal_depthwise_conv(x, kernel):
     """``y[t, c] = sum_j kernel[c, j] * x[t - (width - 1) + j, c]`` over
     ``x [b, s, c]``, zeros before position 0, no bias.  Products and sum
@@ -162,7 +266,9 @@ def causal_depthwise_conv(x, kernel):
 
 
 class GatedDeltaNet(nn.Module):
-    """The gated delta-rule linear-attention mixer."""
+    """The gated delta-rule linear-attention mixer, holding
+    ``linear_key_heads`` / ``linear_value_heads`` heads (each of its
+    projections the held heads' columns, ``out_proj`` their rows)."""
 
     sizes: HybridSizes
     dtype: jnp.dtype
@@ -175,13 +281,26 @@ class GatedDeltaNet(nn.Module):
         nk, nv = z.linear_key_heads, z.linear_value_heads
         dk, dv = z.linear_key_dim, z.linear_value_dim
         r = nv // nk
-        # laid out per key head: q, k (dk each), then v, z (r * dv each)
-        qkvz = _dense(nk * (2 * dk + 2 * r * dv), "in_proj_qkvz",
-                      self.dtype)(x).reshape(b, s, nk, 2 * dk + 2 * r * dv)
-        ba = _dense(nk * 2 * r, "in_proj_ba", self.dtype)(x)
-        ba = ba.reshape(b, s, nk, 2 * r).astype(jnp.float32)
-        q, k, v, gate = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + r * dv],
-                                  axis=-1)
+        if z.linear_projections == names.FUSED:
+            # laid out per key head: q, k (dk each), then v, z (r * dv each)
+            qkvz = _dense(nk * (2 * dk + 2 * r * dv), "in_proj_qkvz",
+                          self.dtype)(x).reshape(b, s, nk,
+                                                 2 * dk + 2 * r * dv)
+            ba = _dense(nk * 2 * r, "in_proj_ba", self.dtype)(x)
+            ba = ba.reshape(b, s, nk, 2 * r).astype(jnp.float32)
+            q, k, v, gate = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + r * dv],
+                                      axis=-1)
+        else:
+            # a projection each, key head ``j // r`` serving value head ``j``
+            q, k, v, gate = (
+                _dense(width, f"{name}_proj", self.dtype)(x)
+                for name, width in (("q", nk * dk), ("k", nk * dk),
+                                    ("v", nv * dv), ("g", nv * dv)))
+            # b and a side by side per key head, as the fused one has them
+            ba = jnp.concatenate(
+                [_dense(nv, f"{name}_proj", self.dtype)(x).reshape(
+                    b, s, nk, r) for name in "ba"],
+                axis=-1).astype(jnp.float32)
         mixed = jnp.concatenate([q.reshape(b, s, nk * dk),
                                  k.reshape(b, s, nk * dk),
                                  v.reshape(b, s, nv * dv)], axis=-1)
@@ -193,6 +312,13 @@ class GatedDeltaNet(nn.Module):
         a_log = self.param("A_log", nn.initializers.zeros, (nv,))
         dt_bias = self.param("dt_bias", nn.initializers.zeros, (nv,))
         beta = jax.nn.sigmoid(ba[..., :r].reshape(b, s, nv))
+        beyond_one = {}
+        if z.beta_scale != 1.0:
+            # the scan is told, for its inverse, that beta passes 1 (and
+            # only then: whatever stands in for the scan in a test of the
+            # other arm takes no such argument)
+            beta = z.beta_scale * beta
+            beyond_one = {"beta_max": z.beta_scale}
         g = -jnp.exp(a_log) * jax.nn.softplus(
             ba[..., r:].reshape(b, s, nv) + dt_bias)
 
@@ -205,12 +331,28 @@ class GatedDeltaNet(nn.Module):
 
         o = chunked_gated_delta_rule(
             unit(q, dk ** -0.5), unit(k), v.reshape(b, s, nv, dv), g, beta,
-            chunk=self.chunk)
+            chunk=self.chunk, **beyond_one)
         norm = self.param("norm", nn.initializers.ones, (dv,))
         o = norm * _rms(o, z.eps) * jax.nn.silu(
             gate.reshape(b, s, nv, dv).astype(jnp.float32))
-        return _dense(d, "out_proj", self.dtype)(
-            o.reshape(b, s, nv * dv).astype(self.dtype))
+        return _over_members(_dense(d, "out_proj", self.dtype)(
+            o.reshape(b, s, nv * dv).astype(self.dtype)), z.heads_axis)
+
+
+class GatedMLP(nn.Module):
+    """The dense feed-forward arm, ``down(silu(gate(x)) * up(x))`` without
+    biases, under scope ``names.MLP``."""
+
+    sizes: HybridSizes
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.sizes.ffn_width
+        with jax.named_scope(names.MLP):
+            h = (jax.nn.silu(_dense(w, "gate_proj", self.dtype)(x))
+                 * _dense(w, "up_proj", self.dtype)(x))
+            return _dense(x.shape[-1], "down_proj", self.dtype)(h)
 
 
 class ExpertShare(nn.Module):
@@ -253,7 +395,9 @@ class ExpertShare(nn.Module):
 
 
 class HybridLayer(nn.Module):
-    """``h = x + Mixer(norm(x))``, ``y = h + Experts(norm(h))``."""
+    """``h = x + Mixer(norm(x))``, ``y = h + FFN(norm(h))`` with the norms
+    before the sublayers, ``h = x + norm(Mixer(x))``, ``y = h + norm(FFN(h))``
+    with them after."""
 
     kind: str
     sizes: HybridSizes
@@ -263,25 +407,35 @@ class HybridLayer(nn.Module):
     def __call__(self, x):
         z = self.sizes
         if self.kind == names.FULL:
-            scope, mixer = names.ATTN, GatedAttention(z, self.dtype,
-                                                      name="attn")
+            scope, mixer = names.ATTN, ATTENTIONS[z.attention](
+                z, self.dtype, name="attn")
         else:
             scope, mixer = names.LINEAR_ATTN, GatedDeltaNet(
                 z, self.dtype, name="linear_attn")
+        # the expert layer names its own scope (``moe``), the dense one
+        # ``mlp``
+        ffn = (ExpertShare(z, self.dtype, name="experts")
+               if z.feed_forward == names.EXPERT_SHARE
+               else GatedMLP(z, self.dtype, name="mlp"))
+
+        def residual(x, sublayer, norm_name):
+            norm = NORMS[z.norm](z.eps, name=norm_name)
+            if z.norm_after:
+                return x + norm(sublayer(x)).astype(self.dtype)
+            return x + sublayer(norm(x))
+
         with jax.named_scope(scope):
-            h = ZeroCentredRMSNorm(z.eps, name="mixer_norm")(x)
-            x = x + mixer(h.astype(self.dtype))
-        # kept under remat: the expert layer's backward pass then needs
+            x = residual(x, lambda h: mixer(h.astype(self.dtype)),
+                         "mixer_norm")
+        # kept under remat: the feed-forward's backward pass then needs
         # nothing of the mixer's, whose forward is recomputed after it
         x = checkpoint_name(x, MIXER_OUT)
-        # the expert layer names its own scope (``moe``)
-        h = ZeroCentredRMSNorm(z.eps, name="experts_norm")(x)
-        return x + ExpertShare(z, self.dtype, name="experts")(h)
+        return residual(x, ffn, f"{ffn.name}_norm")
 
 
 class HybridLM(nn.Module):
     """Causal LM: token embedding, ``len(layer_types)`` pattern layers, a
-    final zero-centred RMSNorm, an untied head."""
+    final RMS norm of the layers' kind, an untied head."""
 
     vocab: int
     layer_types: tuple          # names.LINEAR / names.FULL, one a layer
@@ -299,7 +453,21 @@ class HybridLM(nn.Module):
         if unknown:
             raise ValueError(f"layer_types holds {sorted(unknown)}; a layer "
                              f"is {names.LINEAR!r} or {names.FULL!r}")
-        telemetry.event(names.MIXER_LAYOUT, kinds=list(self.layer_types))
+        z = self.sizes
+        telemetry.event(
+            names.MIXER_LAYOUT, kinds=list(self.layer_types),
+            attention=z.attention,
+            attn_heads=[z.n_heads, z.n_heads_total or z.n_heads],
+            attn_kv_heads=z.n_kv_heads, head_dim=z.head_dim,
+            linear_heads=[z.linear_value_heads, z.linear_value_heads_total
+                          or z.linear_value_heads],
+            linear_key_heads=z.linear_key_heads,
+            linear_key_dim=z.linear_key_dim,
+            linear_value_dim=z.linear_value_dim,
+            linear_projections=z.linear_projections,
+            beta_scale=z.beta_scale, heads_axis=z.heads_axis,
+            feed_forward=z.feed_forward, norm=z.norm,
+            norm_after=z.norm_after)
         with jax.named_scope(names.EMBED):
             x = nn.Embed(self.vocab, self.sizes.d_model, name="tok_embed",
                          dtype=self.dtype)(tokens)
@@ -307,8 +475,9 @@ class HybridLM(nn.Module):
                                   keep=(MIXER_OUT,))
                      if self.remat else HybridLayer)
         for i, kind in enumerate(self.layer_types):
-            x = layer_cls(kind, self.sizes, self.dtype, name=f"layer_{i}")(x)
+            x = layer_cls(kind, z, self.dtype,
+                          name=f"{names.PATTERN_LAYER}_{i}")(x)
         with jax.named_scope(names.HEAD):
-            x = ZeroCentredRMSNorm(self.sizes.eps, name="final_norm")(x)
+            x = NORMS[z.norm](z.eps, name="final_norm")(x)
             return nn.Dense(self.vocab, use_bias=False, name="head",
                             dtype=self.dtype)(x)

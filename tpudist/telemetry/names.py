@@ -47,8 +47,11 @@ HEAD = "head"
 LOSS = "loss"
 OPTIMIZER = "optimizer"
 GRAD_ACCUM = "grad_accum"
-# tpudist/models/hybrid.py: the mixers and the expert layer of a decoder
-# whose layers follow a pattern.  ``linear_attn`` is the whole linear-attention
+# tpudist/models/hybrid.py: the mixers and the feed-forward arm of a decoder
+# whose layers follow a pattern (each layer a module ``PATTERN_LAYER_<i>``, a
+# component of its ops' names; the dense feed-forward arm runs under ``mlp``
+# there as ``transformer``'s does under its blocks).  ``linear_attn`` is the
+# whole linear-attention
 # mixer (norm, projections, convolution, gates, output projection) as ``attn``
 # is the whole softmax one; ``delta_rule`` (tpudist/ops/gated_delta.py) is the
 # recurrence alone, nested in it.  ``moe`` (tpudist/parallel/moe.py) runs from
@@ -61,6 +64,7 @@ MOE = "moe"
 EXPERTS = "experts"
 SHARED_EXPERT = "shared_expert"
 MOE_COMBINE = "moe_combine"
+PATTERN_LAYER = "layer"
 SCOPES = (EMBED, ATTN, MLP, HEAD, LOSS, OPTIMIZER, GRAD_ACCUM, LINEAR_ATTN,
           DELTA_RULE, MOE, EXPERTS, SHARED_EXPERT, MOE_COMBINE)
 #: what JAX itself writes round the scopes of a transposed (backward) op
@@ -107,7 +111,16 @@ WHY_SEQ = "seq"              # too short for the flash kernels, or no tile fits
 WHY_PLATFORM = "platform"    # not a TPU
 WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # tpudist/models/hybrid.py, once a trace of the decoder: ``kinds=`` the layer
-# kinds in order (LINEAR / FULL), and of each expert layer
+# kinds in order (LINEAR / FULL); ``attention=`` GATED_ATTN / NORMED_ATTN with
+# ``attn_heads=`` [held, in all], ``attn_kv_heads=``, ``head_dim=``; of the
+# delta-rule mixers ``linear_heads=`` [value heads held, in all],
+# ``linear_key_heads=``, ``linear_key_dim=``, ``linear_value_dim=``,
+# ``linear_projections=`` FUSED / SEPARATE, ``beta_scale=`` (the write
+# strength is that times a sigmoid); ``heads_axis=`` the mapped axis the
+# members that share a layer by heads reduce over, or None;
+# ``feed_forward=`` EXPERT_SHARE / DENSE_FFN; ``norm=`` ZERO_CENTRED / PLAIN
+# and ``norm_after=`` whether it follows its sublayer.  And of each expert
+# layer
 # (tpudist/parallel/moe.py): ``experts=`` the router's width, ``held=``,
 # ``first=``, ``top_k=``, ``dropless=``, ``buffer_rows=``, ``blocks=``,
 # ``combine=`` PICK_MAJOR (each token's ``k`` rows are added up as ``k``
@@ -117,6 +130,14 @@ MOE_LAYOUT = "moe_layout"
 PICK_MAJOR = "pick_major"
 LINEAR = "linear_attention"
 FULL = "full_attention"
+GATED_ATTN = "gated"         # per-head q/k norms, an output gate, part rotary
+NORMED_ATTN = "normed"       # one q/k norm statistic over all heads, no gate
+FUSED = "fused_per_key_head"
+SEPARATE = "separate"
+ZERO_CENTRED = "zero_centred"
+PLAIN = "plain"
+EXPERT_SHARE = "expert_share"
+DENSE_FFN = "dense_gated"
 #: ``jax.monitoring`` event -> event name
 XLA_CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": COMPILE_CACHE_HIT,
