@@ -525,9 +525,30 @@ def test_olmo_hybrid_cell_step_fills_one_chip_and_fits(olmo_hybrid_step):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     # 766,241,946 parameters x 12 bytes resident
     assert 9.1e9 < mem.argument_size_in_bytes < 9.3e9
-    # 12.50 GiB (temporaries 4.23 GB, the float32 gradient among them);
-    # the issue's ceiling is 15.0 GiB, the compiler allows 15.75
+    # 13.29 GiB: temporaries 5,077,698,560 bytes, the float32 gradient
+    # among them.  PR 34's step, which computed each layer's feed-forward
+    # twice, held 12.50 GiB (temporaries 4,226,611,712); the outputs of
+    # the feed-forward's three products are now kept from forward to
+    # backward (423.6 MB a layer, 1.69 GB in all), of which 0.85 GB are
+    # still alive where the compiler's peak lies.  The issue's ceiling is
+    # 15.0 GiB, the compiler allows 15.75
     assert 12e9 < held < 15.0 * 2 ** 30
+    assert mem.temp_size_in_bytes >= 4_226_611_712 + 0.8e9
+
+
+def test_olmo_hybrid_cell_step_computes_each_feed_forward_product_once(
+        olmo_hybrid_step):
+    """Four layers x (3 forward + 6 backward) products with the
+    feed-forward's projections in their names, and not the 48 of a step
+    that rematerialises the three forward ones (the compiler writes a
+    product as a ``convolution``, alone or at the root of a fusion)."""
+    import re
+
+    step, job, m = olmo_hybrid_step
+    products = [line for line in step.as_text().splitlines()
+                if re.search(r" convolution\(", line) and re.search(
+                    r"/mlp/(gate|up|down)_proj/dot_general", line)]
+    assert len(products) == 4 * 9
 
 
 def test_olmo_hybrid_cell_step_carries_exactly_the_three_flash_kernels(

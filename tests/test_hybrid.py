@@ -31,6 +31,7 @@ from tpudist.models.transformer import lm_loss
 from tpudist.ops.gated_delta import (chunked_gated_delta_rule,
                                      gated_delta_rule_reference)
 from tpudist.parallel import moe
+from tpudist.telemetry.names import MIXER_OUT
 
 DATA = Path(__file__).resolve().parent.parent / "cellbench" / "tests" / "data"
 
@@ -176,7 +177,7 @@ def test_remat_keeps_each_layers_activation_between_mixer_and_experts(
         lambda q: lm_loss(p["module"].apply(q, p["tokens"]), p["tokens"])))(
             p["params"]))
     assert "checkpoint" in text or "remat" in text
-    assert text.count(f"name={hybrid.MIXER_OUT}") >= (
+    assert text.count(f"name={MIXER_OUT}") >= (
         p["config"]["num_hidden_layers"])
 
 

@@ -206,7 +206,92 @@ def test_remat_keeps_each_layers_activation_between_mixer_and_feed_forward(
         lambda q: lm_loss(p["module"].apply(q, p["tokens"]), p["tokens"])))(
             p["params"]))
     assert "checkpoint" in text or "remat" in text
-    assert text.count(f"name={hybrid.MIXER_OUT}") >= 4
+    assert text.count(f"name={names.MIXER_OUT}") >= 4
+
+
+def products(jaxpr) -> list:
+    """The operand shapes of every matrix product of a jaxpr, sub-jaxprs
+    (the rematerialised layers, the scans) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("dot_general", "ragged_dot",
+                                  "ragged_dot_general"):
+            found.append(tuple(v.aval.shape for v in eqn.invars))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += products(sub)
+    return found
+
+
+def bf16_program(arch_, config, policy):
+    """``(module, params, loss)`` of an architecture's tiny configuration
+    in bf16, rematerialised by ``policy``."""
+    config = json.loads(json.dumps(config))
+    config["as_run"]["compute_dtype"] = "bfloat16"
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0,
+                                config["vocab_size"])
+    module = arch_.build_module(config, {"remat": policy})
+    params = arch_.program_tree(config, arch_.init_weights(
+        config, reference.split_seed(7)))
+    return module, params, lambda p: lm_loss(module.apply(p, tokens), tokens)
+
+
+@pytest.mark.parametrize("policy", ["nothing", "dots_no_batch"])
+def test_a_rematerialised_layer_computes_its_feed_forward_once(policy,
+                                                               monkeypatch):
+    """Under remat a dense-arm layer keeps its feed-forward's three
+    products' outputs besides ``MIXER_OUT``, whatever the policy: the
+    gradient then holds 9 products with a feed-forward operand a layer (3
+    forward, 6 backward) where recomputing them makes it 12, the kept
+    residuals are those three tensors, and the gradients are the very bits
+    of the program that keeps ``MIXER_OUT`` alone."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    module, params, loss = bf16_program(arch, TINY, policy)
+    z, layers = module.sizes, TINY["num_hidden_layers"]
+    assert hybrid.remat_keeps(z) == (
+        names.MIXER_OUT, names.FFN_GATE, names.FFN_UP, names.FFN_OUT)
+
+    def of_the_ffn(jaxpr):
+        return sum(any(z.ffn_width in shape for shape in operands)
+                   for operands in products(jaxpr))
+
+    def ffn_residuals():
+        return sorted(aval.shape[-1] for aval, why in saved_residuals(
+            loss, params) if "GatedMLP" in why)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+    assert of_the_ffn(jaxpr.jaxpr) == 9 * layers
+    for name in hybrid.remat_keeps(z):
+        assert str(jaxpr).count(f"name={name}") >= layers
+    assert ffn_residuals() == sorted(
+        layers * [z.d_model, z.ffn_width, z.ffn_width])
+    grads = jax.jit(jax.grad(loss))(params)
+
+    monkeypatch.setattr(hybrid, "remat_keeps", lambda z: (names.MIXER_OUT,))
+    assert ffn_residuals() == []
+    # the policy that saves every product without a batch dimension saves
+    # these three already; ``nothing`` runs them again
+    assert of_the_ffn(jax.make_jaxpr(jax.grad(loss))(params).jaxpr) == (
+        12 if policy == "nothing" else 9) * layers
+    for got, want in zip(jax.tree.leaves(grads),
+                         jax.tree.leaves(jax.jit(jax.grad(loss))(params))):
+        assert got.dtype == want.dtype and bool(jnp.all(got == want))
+
+
+def test_the_expert_share_arm_keeps_what_it_kept():
+    """``MIXER_OUT`` alone: the count of products in its gradient is PR
+    34's (352 under ``nothing``, CPU)."""
+    from cellbench.archs import qwen3_next
+
+    module, params, loss = bf16_program(
+        qwen3_next, json.loads((DATA / "tiny-hybrid.json").read_text()),
+        "nothing")
+    assert module.sizes.feed_forward == names.EXPERT_SHARE
+    assert hybrid.remat_keeps(module.sizes) == (names.MIXER_OUT,)
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
+    assert len(products(jaxpr.jaxpr)) == 352
+    assert not any(f"name={name}" in str(jaxpr)
+                   for name in names.DENSE_FFN_KEEPS)
 
 
 @pytest.mark.parametrize("wrong", [

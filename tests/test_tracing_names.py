@@ -473,3 +473,36 @@ def test_the_pattern_decoder_says_its_layout_once_a_trace(tmp_path):
     assert {(r["experts"], r["held"], r["first"], r["top_k"], r["dropless"],
              r["combine"]) for r in experts} == {
                  (4, 2, 2, 2, True, names.PICK_MAJOR)}
+
+
+@pytest.mark.parametrize("feed_forward, remat, keeps, columns", [
+    (names.DENSE_FFN, True, [names.MIXER_OUT, names.FFN_GATE, names.FFN_UP,
+                             names.FFN_OUT], 32 + 2 * 48 + 32),
+    (names.EXPERT_SHARE, True, [names.MIXER_OUT], 32),
+    (names.DENSE_FFN, False, [], 0)],
+    ids=["dense_arm", "expert_share_arm", "no_remat"])
+def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
+        tmp_path, feed_forward, remat, keeps, columns):
+    """``remat_keeps``: the names kept besides a layer's input;
+    ``remat_kept_bytes_per_layer``: ``MIXER_OUT``'s ``tokens x d_model x
+    itemsize`` and, in the dense arm, ``tokens x (2 x ffn_width + d_model)
+    x itemsize`` more."""
+    from tpudist.models.hybrid import HybridLM, HybridSizes
+
+    hybrid = HybridLM(
+        vocab=64, layer_types=(names.LINEAR, names.FULL), dtype=jnp.bfloat16,
+        remat=remat, sizes=HybridSizes(
+            d_model=32, n_heads=2, n_kv_heads=1, head_dim=16, rotary_dim=4,
+            linear_key_heads=1, linear_value_heads=2, linear_key_dim=8,
+            linear_value_dim=8, feed_forward=feed_forward, ffn_width=48,
+            n_experts=4, held=2, top_k=2, expert_width=16, shared_width=16))
+    tokens = jnp.zeros((2, 64), jnp.int32)
+    session = telemetry.start(tmp_path / "tele", rank=0, generation=0)
+    try:
+        jax.eval_shape(hybrid.init, jax.random.PRNGKey(0), tokens)
+        said = [r for r in session.ring if r.get("name") == names.MIXER_LAYOUT]
+    finally:
+        telemetry.finish(write_report=False)
+    assert len(said) == 1
+    assert said[0]["remat_keeps"] == keeps
+    assert said[0]["remat_kept_bytes_per_layer"] == 2 * 64 * columns * 2

@@ -72,11 +72,6 @@ from tpudist.parallel.moe import expert_share
 from tpudist.telemetry import names
 
 
-# a layer's activation between its mixer and its experts: what remat keeps
-# besides the layer's input, whatever the policy
-MIXER_OUT = "mixer_out"
-
-
 def _rms(x, eps):
     x = x.astype(jnp.float32)
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
@@ -341,7 +336,11 @@ class GatedDeltaNet(nn.Module):
 
 class GatedMLP(nn.Module):
     """The dense feed-forward arm, ``down(silu(gate(x)) * up(x))`` without
-    biases, under scope ``names.MLP``."""
+    biases, under scope ``names.MLP``.  Its three products' outputs are
+    named (``names.DENSE_FFN_KEEPS``): a rematerialised layer keeps them
+    (:func:`remat_keeps`), ``tokens x (2 x ffn_width + d_model) x itemsize``
+    bytes, and its backward pass then recomputes only ``silu(gate) * up``,
+    one elementwise pass, and none of the products."""
 
     sizes: HybridSizes
     dtype: jnp.dtype
@@ -350,9 +349,13 @@ class GatedMLP(nn.Module):
     def __call__(self, x):
         w = self.sizes.ffn_width
         with jax.named_scope(names.MLP):
-            h = (jax.nn.silu(_dense(w, "gate_proj", self.dtype)(x))
-                 * _dense(w, "up_proj", self.dtype)(x))
-            return _dense(x.shape[-1], "down_proj", self.dtype)(h)
+            gate = checkpoint_name(_dense(w, "gate_proj", self.dtype)(x),
+                                   names.FFN_GATE)
+            up = checkpoint_name(_dense(w, "up_proj", self.dtype)(x),
+                                 names.FFN_UP)
+            return checkpoint_name(
+                _dense(x.shape[-1], "down_proj", self.dtype)(
+                    jax.nn.silu(gate) * up), names.FFN_OUT)
 
 
 class ExpertShare(nn.Module):
@@ -429,8 +432,33 @@ class HybridLayer(nn.Module):
                          "mixer_norm")
         # kept under remat: the feed-forward's backward pass then needs
         # nothing of the mixer's, whose forward is recomputed after it
-        x = checkpoint_name(x, MIXER_OUT)
+        x = checkpoint_name(x, names.MIXER_OUT)
         return residual(x, ffn, f"{ffn.name}_norm")
+
+
+def remat_keeps(sizes: HybridSizes) -> tuple:
+    """The names a rematerialised :class:`HybridLayer` keeps besides its
+    input, under every policy: ``names.MIXER_OUT`` (the layer's activation
+    between its mixer and its feed-forward arm, ``tokens x d_model x
+    itemsize`` bytes), and in the dense arm the outputs of the
+    feed-forward's three products (its input IS ``MIXER_OUT``), so that no
+    product of the feed-forward runs twice a step.  The expert-share arm
+    recomputes its feed-forward: the buffers of its dispatch are many times
+    a layer's activations."""
+    if sizes.feed_forward == names.DENSE_FFN:
+        return (names.MIXER_OUT,) + names.DENSE_FFN_KEEPS
+    return (names.MIXER_OUT,)
+
+
+def kept_bytes(keep: tuple, sizes: HybridSizes, tokens: int, dtype) -> int:
+    """What the activations named ``keep`` hold a layer from its forward to
+    its backward pass, over ``tokens`` positions in compute dtype
+    ``dtype``."""
+    columns = {names.MIXER_OUT: sizes.d_model,
+               names.FFN_GATE: sizes.ffn_width,
+               names.FFN_UP: sizes.ffn_width, names.FFN_OUT: sizes.d_model}
+    return tokens * jnp.dtype(dtype).itemsize * sum(
+        columns[name] for name in keep)
 
 
 class HybridLM(nn.Module):
@@ -442,8 +470,11 @@ class HybridLM(nn.Module):
     sizes: HybridSizes
     dtype: jnp.dtype = jnp.float32   # compute dtype; params stay f32 masters
     remat: bool = False
-    # the names ``TransformerLM`` takes; every policy also keeps each
-    # layer's ``MIXER_OUT``
+    # the names ``TransformerLM`` takes, for what the POLICY saves; besides,
+    # under every policy, a layer keeps what :func:`remat_keeps` names:
+    # ``mixer_out`` (``tokens x d_model x itemsize`` bytes a layer) and, with
+    # a dense feed-forward, its three products' outputs (``tokens x (2 x
+    # ffn_width + d_model) x itemsize`` more), so its forward runs once
     remat_policy: str = "nothing"
 
     @nn.compact
@@ -454,6 +485,7 @@ class HybridLM(nn.Module):
             raise ValueError(f"layer_types holds {sorted(unknown)}; a layer "
                              f"is {names.LINEAR!r} or {names.FULL!r}")
         z = self.sizes
+        keep = remat_keeps(z) if self.remat else ()
         telemetry.event(
             names.MIXER_LAYOUT, kinds=list(self.layer_types),
             attention=z.attention,
@@ -467,12 +499,13 @@ class HybridLM(nn.Module):
             linear_projections=z.linear_projections,
             beta_scale=z.beta_scale, heads_axis=z.heads_axis,
             feed_forward=z.feed_forward, norm=z.norm,
-            norm_after=z.norm_after)
+            norm_after=z.norm_after, remat_keeps=list(keep),
+            remat_kept_bytes_per_layer=kept_bytes(
+                keep, z, tokens.size, self.dtype))
         with jax.named_scope(names.EMBED):
             x = nn.Embed(self.vocab, self.sizes.d_model, name="tok_embed",
                          dtype=self.dtype)(tokens)
-        layer_cls = (remat_module(HybridLayer, self.remat_policy,
-                                  keep=(MIXER_OUT,))
+        layer_cls = (remat_module(HybridLayer, self.remat_policy, keep=keep)
                      if self.remat else HybridLayer)
         for i, kind in enumerate(self.layer_types):
             x = layer_cls(kind, z, self.dtype,

@@ -6,7 +6,7 @@ Plain string constants, no jax import: the program (``tpudist.ops``,
 ``tpudist.runtime``) and the readers (``cellbench/readers``, the
 aggregator) both import this module, so a name is spelled once.
 
-Three kinds of name, three places they show:
+Four kinds of name, four places they show:
 
 - **kernel names** — ``pallas_call(name=..., metadata={"kernel": ...})``:
   the Mosaic custom call's event text in a device trace carries
@@ -18,7 +18,10 @@ Three kinds of name, three places they show:
   keeps with the device operation;
 - **spans and events** — ``tpudist.telemetry``: records of the JSONL
   stream and the session's ring, and (spans) ``TraceAnnotation``s on the
-  host rows of a profiler trace.
+  host rows of a profiler trace;
+- **kept activations** — ``jax.ad_checkpoint.checkpoint_name``: what a
+  rematerialised layer keeps by name; they show in a gradient's jaxpr
+  (``name[name=...]``) and in the ``mixer_layout`` event's ``remat_keeps``.
 """
 
 # -- kernel names (tpudist/ops) ----------------------------------------------
@@ -70,6 +73,22 @@ SCOPES = (EMBED, ATTN, MLP, HEAD, LOSS, OPTIMIZER, GRAD_ACCUM, LINEAR_ATTN,
 #: what JAX itself writes round the scopes of a transposed (backward) op
 BACKWARD_MARK = "transpose("
 
+# -- activations kept under remat (jax.ad_checkpoint.checkpoint_name) --------
+# tpudist/models/hybrid.py: what a rematerialised pattern layer keeps besides
+# its input, whatever the remat policy.  ``mixer_out`` is the layer's
+# activation between its mixer and its feed-forward arm, ``[tokens, d_model]``
+# in the compute dtype (both arms; it is also the dense arm's input).  The
+# dense arm alone keeps the outputs of its three products as well:
+# ``gate_proj``'s and ``up_proj``'s (``[tokens, ffn_width]`` each) and
+# ``down_proj``'s (``[tokens, d_model]``, which the norm after the sublayer
+# needs), so nothing of the feed-forward's forward runs again in the backward
+# pass: ``tokens x (2 x ffn_width + d_model) x itemsize`` bytes a layer
+MIXER_OUT = "mixer_out"
+FFN_GATE = "ffn_gate"
+FFN_UP = "ffn_up"
+FFN_OUT = "ffn_out"
+DENSE_FFN_KEEPS = (FFN_GATE, FFN_UP, FFN_OUT)
+
 # -- spans (tpudist.telemetry.span / record_span) -----------------------------
 STEP = "step"            # one arrival of a step's result to the next
 COMPILE = "compile"      # the first step: enqueue to its result, compile in
@@ -119,7 +138,10 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # strength is that times a sigmoid); ``heads_axis=`` the mapped axis the
 # members that share a layer by heads reduce over, or None;
 # ``feed_forward=`` EXPERT_SHARE / DENSE_FFN; ``norm=`` ZERO_CENTRED / PLAIN
-# and ``norm_after=`` whether it follows its sublayer.  And of each expert
+# and ``norm_after=`` whether it follows its sublayer; ``remat_keeps=`` the
+# names a rematerialised layer keeps besides its input (MIXER_OUT, and
+# DENSE_FFN_KEEPS in the dense arm; ``[]`` without remat) and
+# ``remat_kept_bytes_per_layer=`` what they hold.  And of each expert
 # layer
 # (tpudist/parallel/moe.py): ``experts=`` the router's width, ``held=``,
 # ``first=``, ``top_k=``, ``dropless=``, ``buffer_rows=``, ``blocks=``,
