@@ -567,3 +567,52 @@ def test_olmo_hybrid_cell_step_carries_exactly_the_three_flash_kernels(
     assert job["collectives_in_step"] == []
     assert "all-reduce" not in text and "all-gather" not in text
 
+
+@pytest.fixture(scope="module")
+def nemotron_step(topo):
+    """The whole train step of cell ``nemotron3super-train-tp8ep64share-8k``
+    (one chip's share of a deployment in which 64 chips share each layer,
+    heads 8 ways and experts 64 ways): 1 row x 8,192."""
+    return _cell_step(topo, "nemotron3super-train-tp8ep64share-8k")
+
+
+def test_nemotron_cell_step_fills_one_chip_and_fits(nemotron_step):
+    step, job, m = nemotron_step
+    assert job["per_chip_batch"] == 1 and job["seq_len"] == 8192
+    assert job["remat"] == "nothing" and job["accum_steps"] == 1
+    mem = step.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    # 700,862,960 parameters (and 5 x 512 numbers of choice bias) x 12
+    # bytes resident
+    assert 8.40e9 < mem.argument_size_in_bytes < 8.42e9
+    # 11.81 GiB = 12.68 GB: temporaries 4,271,943,680 bytes, the float32
+    # gradient (2.80 GB) among them; an expert layer's backward pass runs
+    # its 180,224 assignments through buffers of 369 MB (1,024 wide) and
+    # 969 MB (2,688 wide).  The issue's ceiling is 15.0 GiB, the compiler
+    # allows 15.75; the fallbacks (the loss in blocks of rows, the share's
+    # blocks at 4,096 tokens) were not needed
+    assert 12e9 < held < 15.0 * 2 ** 30
+
+
+def test_nemotron_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
+        nemotron_step):
+    """One attention layer in the eleven at 4 query heads on 1 key/value
+    head of 128: the three flash kernels by name, the forward twice (its
+    layer is rematerialised).  The five expert layers' products are the
+    compiler's grouped matmuls over the 8 experts HELD at the latent
+    width: no product over 512 experts.  The state-space scan is plain
+    XLA.  No collective: one chip's share."""
+    import re
+
+    step, job, m = nemotron_step
+    text = step.as_text()
+    assert sorted(_kernels_named(text)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"]
+    assert text.count("tpu_custom_call") == 59 == (
+        job["custom_calls_per_layer"] * m["layers"])
+    assert re.search(r"ragged-dot", text)
+    assert re.search(r"\[8,1024,2688\]", text)
+    assert not re.search(r"\[512,1024,2688\]|\[512,2688,1024\]", text)
+    assert job["collectives_in_step"] == []
+    assert "all-reduce" not in text and "all-gather" not in text

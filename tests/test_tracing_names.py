@@ -4,6 +4,7 @@ clock, the step helper's arrival-to-arrival ``step`` spans, and the compile
 listener's spans and events.  (The kernel names in a compiled TPU step are
 checked in ``test_chip_compile.py``, the one file that describes a chip.)"""
 
+import dataclasses
 import re
 import subprocess
 import sys
@@ -56,6 +57,21 @@ def step_op_names():
     params = hybrid.init(jax.random.PRNGKey(0), tokens)
     lowered = make_lm_train_step(hybrid.apply, tx, mesh).lower(
         init_lm_state(params, tx), tokens)
+    found |= set(re.findall(r'loc\("([^"]+)"',
+                            lowered.as_text(debug_info=True)))
+    # layers of one sublayer: the state-space mixer's scopes and the latent
+    # projections round an expert layer's routed experts
+    hybrid = HybridLM(
+        vocab=64, layer_types=(names.STATE_SPACE, names.EXPERT_LAYER),
+        sizes=dataclasses.replace(
+            hybrid.sizes, one_sublayer=True, norm=names.PLAIN, ssm_heads=2,
+            ssm_head_dim=8, ssm_state=8, ssm_chunk=32,
+            scoring=names.SIGMOID_BIAS, routed_scale=2.5,
+            expert_fn=names.RELU2, latent_width=16, shared_scored=False),
+        remat=True)
+    params = hybrid.init(jax.random.PRNGKey(0), tokens)
+    lowered = make_lm_train_step(hybrid.apply, tx, mesh).lower(
+        init_lm_state(params, tx), tokens)
     return found | set(re.findall(r'loc\("([^"]+)"',
                                   lowered.as_text(debug_info=True)))
 
@@ -73,7 +89,8 @@ def test_a_backward_op_carries_the_transpose_mark_and_its_sublayer(
         step_op_names):
     for scope in (names.ATTN, names.MLP, names.EMBED, names.HEAD,
                   names.LOSS, names.LINEAR_ATTN, names.DELTA_RULE,
-                  names.MOE, names.EXPERTS, names.SHARED_EXPERT):
+                  names.MOE, names.EXPERTS, names.SHARED_EXPERT, names.SSM,
+                  names.SSD_SCAN, names.LATENT_PROJ):
         assert [n for n in step_op_names
                 if names.BACKWARD_MARK in n and _under(scope, n)], scope
     # the optimizer is not differentiated: no transposed op under it

@@ -1,13 +1,15 @@
 """A decoder LM whose layers follow a pattern of mixers (gated delta-rule
-linear attention and causal softmax attention), each followed by a
-feed-forward arm: a routed expert layer of which this device holds a share,
-or a dense gated feed-forward.
+linear attention, Mamba-2 state-space mixers and causal softmax attention)
+and a feed-forward arm: a routed expert layer of which this device holds a
+share, or a dense gated feed-forward.  A layer is a mixer followed by the
+feed-forward arm, or ONE sublayer (a mixer, or the feed-forward arm).
 
 What :mod:`tpudist.models.transformer`'s ``Block`` (pre-LN, LayerNorm,
 ungated GELU FFN) cannot say, by mechanism:
 
-- a **layer pattern**: ``layer_types`` names each layer's mixer
-  (``names.LINEAR`` / ``names.FULL``);
+- a **layer pattern**: ``layer_types`` names each layer's kind
+  (:data:`MIXERS`' keys; in a decoder of one-sublayer layers also
+  ``names.EXPERT_LAYER``, a layer that is the feed-forward arm);
 - **RMS norms** in float32, zero-centred (``x * rsqrt(mean(x^2) + eps) *
   (1 + w)``, ``w`` from 0) or plain (``... * w``, ``w`` from 1), **before**
   each sublayer (``x + f(norm(x))``) or **after** it (``x + norm(f(x))``);
@@ -19,15 +21,24 @@ ungated GELU FFN) cannot say, by mechanism:
 - **softmax attention behind one norm over all heads**
   (:class:`NormedAttention`): queries and keys RMS-normed with ONE statistic
   a token over every head's dims together, no gate, no rotary positions;
+- **plain grouped-query softmax attention** (:class:`GroupedAttention`): no
+  norm, no gate, no rotary positions;
 - **gated delta-rule linear attention** (:class:`GatedDeltaNet`):
   projections fused per key head or separate, a short depthwise causal
   convolution with SiLU over q, k, v, per-head decay, a write strength of
   ``beta_scale * sigmoid``, L2-normed queries and keys, the chunked scan of
   :mod:`tpudist.ops.gated_delta` (key and value widths may differ), an RMS
   norm gated by ``silu(z)``;
+- **a Mamba-2 state-space mixer** (:class:`Mamba2Mixer`): one input
+  projection into a gate, heads, groups of ``B`` and ``C`` and a step a
+  head, a depthwise causal convolution with bias and SiLU, the chunked scan
+  of :mod:`tpudist.ops.ssd`, an RMS norm of ``y * silu(z)`` a GROUP;
 - **routed experts as a share** (:class:`ExpertShare`):
-  :func:`tpudist.parallel.moe.expert_share`, dropless, with a gated shared
-  expert; or a **dense gated feed-forward** (:class:`GatedMLP`);
+  :func:`tpudist.parallel.moe.expert_share`, dropless; softmax or sigmoid +
+  choice-bias scoring with a scale, gated SiLU or squared-ReLU experts, at
+  the model's width or in a latent space behind two projections, with a
+  scored or plain shared expert; or a **dense gated feed-forward**
+  (:class:`GatedMLP`);
 - **a share of the heads**: the mixers are told how many heads of how many
   they hold (``n_heads`` of ``n_heads_total``, ...), as the expert layer is
   told its experts.  With ``heads_axis`` (a mapped axis over the members
@@ -36,14 +47,19 @@ ungated GELU FFN) cannot say, by mechanism:
   member's partial output goes on as it is.
 
 Which arm a layer takes is data on :class:`HybridSizes`, filled in by
-whoever builds the module; nothing here knows a model.  The two
+whoever builds the module; nothing here knows a model.  The three
 architectures that run through it (``cellbench/archs``): ``qwen3_next``
 (norms zero-centred and before the sublayer, :class:`GatedAttention`, fused
 projections with ``nv / nk`` value heads a key head at 128 / 128, write
-strength in ``[0, 1]``, :class:`ExpertShare`) and ``olmo_hybrid`` (norms
-plain and after the sublayer, :class:`NormedAttention`, separate
-projections at ``dk`` 96 / ``dv`` 192, write strength in ``[0, 2]``,
-:class:`GatedMLP`, half of each mixer's heads held).
+strength in ``[0, 1]``, :class:`ExpertShare` behind every mixer),
+``olmo_hybrid`` (norms plain and after the sublayer,
+:class:`NormedAttention`, separate projections at ``dk`` 96 / ``dv`` 192,
+write strength in ``[0, 2]``, :class:`GatedMLP`, half of each mixer's heads
+held) and ``nemotron_h`` (layers of one sublayer, norms plain and before
+it, :class:`Mamba2Mixer` holding one group of eight,
+:class:`GroupedAttention` at 4 : 1, :class:`ExpertShare` with sigmoid +
+bias scoring, a scale, squared-ReLU experts in a latent space and a plain
+shared expert).
 
 The embedding, the head, their names and scopes, the loss the step
 builders take (``lm_loss``) and the remat policy names are
@@ -68,7 +84,8 @@ from tpudist.models.transformer import remat_module
 from tpudist.ops.attention import default_attention
 from tpudist.ops.gated_delta import chunked_gated_delta_rule
 from tpudist.ops.rope import rope_angles
-from tpudist.parallel.moe import expert_share
+from tpudist.ops.ssd import ssd_scan
+from tpudist.parallel.moe import EXPERT_FNS, EXPERT_LEAVES, expert_share
 from tpudist.telemetry import names
 
 
@@ -146,7 +163,7 @@ class HybridSizes:
     rotary_dim: int              # the gated attention's
     rope_theta: float = 1e7
     n_heads_total: Optional[int] = None
-    attention: str = names.GATED_ATTN        # or names.NORMED_ATTN
+    attention: str = names.GATED_ATTN        # one of ATTENTIONS
     # gated delta-rule linear attention: heads HELD, of
     # ``linear_value_heads_total`` value heads in all
     linear_key_heads: int = 16
@@ -157,11 +174,24 @@ class HybridSizes:
     linear_value_heads_total: Optional[int] = None
     linear_projections: str = names.FUSED    # or names.SEPARATE
     beta_scale: float = 1.0      # write strength = beta_scale * sigmoid(.)
+    # state-space (Mamba-2) mixers: heads and groups HELD, of ``*_total`` in
+    # all; a group's ``B`` and ``C`` serve ``ssm_heads / ssm_groups`` heads
+    ssm_heads: int = 0
+    ssm_groups: int = 1
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_conv_width: int = 4
+    ssm_chunk: int = 128
+    ssm_heads_total: Optional[int] = None
+    ssm_groups_total: Optional[int] = None
     # the mapped axis over the members that share a layer by heads, or None
     heads_axis: Optional[str] = None
     # norms: which, and on which side of the sublayer
     norm: str = names.ZERO_CENTRED           # or names.PLAIN
     norm_after: bool = False
+    # a layer is a mixer AND the feed-forward arm, or (``one_sublayer``) ONE
+    # sublayer: a mixer, or the feed-forward arm (``names.EXPERT_LAYER``)
+    one_sublayer: bool = False
     # the feed-forward arm
     feed_forward: str = names.EXPERT_SHARE   # or names.DENSE_FFN
     ffn_width: int = 0           # the dense arm's
@@ -173,6 +203,19 @@ class HybridSizes:
     top_k: int = 2
     expert_width: int = 512
     shared_width: int = 512
+    # how the router scores (moe.SCORINGS) and what multiplies the picks'
+    # weights; whether its weight trains (the gradient through the scores
+    # into the tokens is computed either way)
+    scoring: str = names.SOFTMAX
+    routed_scale: float = 1.0
+    router_trained: bool = True
+    expert_fn: str = names.GATED_SILU        # one of moe.EXPERT_FNS
+    # the routed experts' width of input and output where it is not
+    # ``d_model``: two projections wrap them (router and shared expert read
+    # the tokens at ``d_model``)
+    latent_width: Optional[int] = None
+    # the shared expert is ``sigmoid(x . score) * E(x)``, or ``E(x)`` as it is
+    shared_scored: bool = True
     eps: float = 1e-6
 
 
@@ -243,8 +286,31 @@ class NormedAttention(nn.Module):
                              z.heads_axis)
 
 
+class GroupedAttention(nn.Module):
+    """Plain causal grouped-query softmax attention: no norm on queries or
+    keys, no gate, no rotary positions (``rotary_dim`` is not read).
+    Holding ``n_heads`` query heads with the ``n_kv_heads`` they read,
+    ``o_proj``'s partial sums are reduced over ``heads_axis`` where there
+    is one."""
+
+    sizes: HybridSizes
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        z = self.sizes
+        h, kv, dh = z.n_heads, z.n_kv_heads, z.head_dim
+        qkv = jnp.concatenate(
+            [_dense(heads * dh, f"{name}_proj", self.dtype)(x)
+             for name, heads in (("q", h), ("k", kv), ("v", kv))], axis=-1)
+        attn = default_attention.packed(qkv, h, kv)
+        return _over_members(_dense(x.shape[-1], "o_proj", self.dtype)(attn),
+                             z.heads_axis)
+
+
 ATTENTIONS = {names.GATED_ATTN: GatedAttention,
-              names.NORMED_ATTN: NormedAttention}
+              names.NORMED_ATTN: NormedAttention,
+              names.GROUPED_ATTN: GroupedAttention}
 
 
 def causal_depthwise_conv(x, kernel):
@@ -334,6 +400,53 @@ class GatedDeltaNet(nn.Module):
             o.reshape(b, s, nv * dv).astype(self.dtype)), z.heads_axis)
 
 
+class Mamba2Mixer(nn.Module):
+    """The Mamba-2 state-space mixer, holding ``ssm_heads`` heads of
+    ``ssm_groups`` groups: ``[z, xBC, dt] = in_proj(u)`` (the held heads'
+    and groups' columns: ``z`` and ``x`` a head's ``ssm_head_dim`` each,
+    ``B`` and ``C`` a group's ``ssm_state`` each, ``dt`` a number a head), a
+    depthwise causal convolution with bias and SiLU over ``xBC``,
+    ``dt = softplus(dt + dt_bias)``, the chunked scan of
+    :mod:`tpudist.ops.ssd`, an RMS norm of ``y * silu(z)`` whose mean
+    square runs over each GROUP's channels (so a member that holds whole
+    groups computes it alone), and ``out_proj`` (the held heads' rows; its
+    partial sums reduced over ``heads_axis`` where there is one)."""
+
+    sizes: HybridSizes
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        z = self.sizes
+        h, g, p, n = z.ssm_heads, z.ssm_groups, z.ssm_head_dim, z.ssm_state
+        inner, bc = h * p, g * n
+        gate, mixed, dt = jnp.split(
+            _dense(2 * inner + 2 * bc + h, "in_proj", self.dtype)(x),
+            [inner, 2 * inner + 2 * bc], axis=-1)
+        kernel = self.param("conv", nn.initializers.lecun_normal(),
+                            (inner + 2 * bc, z.ssm_conv_width))
+        bias = self.param("conv_bias", nn.initializers.zeros,
+                          (inner + 2 * bc,))
+        mixed = jax.nn.silu(causal_depthwise_conv(mixed, kernel)
+                            + bias).astype(self.dtype)
+        u, b_in, c_out = jnp.split(mixed, [inner, inner + bc], axis=-1)
+        a_log = self.param("A_log", nn.initializers.zeros, (h,))
+        skip = self.param("D", nn.initializers.ones, (h,))
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (h,))
+        y = ssd_scan(u.reshape(b, s, h, p),
+                     jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                     a_log, b_in.reshape(b, s, g, n),
+                     c_out.reshape(b, s, g, n), skip, chunk=z.ssm_chunk)
+        norm = self.param("norm", nn.initializers.ones, (inner,))
+        y = (y.reshape(b, s, inner).astype(jnp.float32)
+             * jax.nn.silu(gate.astype(jnp.float32)))
+        y = _rms(y.reshape(b, s, g, inner // g), z.eps).reshape(
+            b, s, inner) * norm
+        return _over_members(_dense(d, "out_proj", self.dtype)(
+            y.astype(self.dtype)), z.heads_axis)
+
+
 class GatedMLP(nn.Module):
     """The dense feed-forward arm, ``down(silu(gate(x)) * up(x))`` without
     biases, under scope ``names.MLP``.  Its three products' outputs are
@@ -360,7 +473,15 @@ class GatedMLP(nn.Module):
 
 class ExpertShare(nn.Module):
     """The routed expert layer as this device's share
-    (:func:`tpudist.parallel.moe.expert_share`), with its shared expert."""
+    (:func:`tpudist.parallel.moe.expert_share`), with its shared expert.
+    By ``sizes``: how the router scores (``scoring``, ``routed_scale``; a
+    ``choice_bias`` buffer where the scoring takes one: it steers the
+    choice alone, so its gradient is zero and Adam leaves it where it
+    was), what an expert computes (``expert_fn``), whether the routed
+    experts work in a latent space of ``latent_width`` behind two
+    projections (``latent_down`` before them, ``latent_up`` on their
+    partial sum; router and shared expert read the tokens at ``d_model``),
+    and whether the shared expert is scored."""
 
     sizes: HybridSizes
     dtype: jnp.dtype
@@ -372,35 +493,83 @@ class ExpertShare(nn.Module):
         b, s, d = x.shape
         z = self.sizes
         init = nn.initializers.lecun_normal()
-        w, sw, e = z.expert_width, z.shared_width, z.held
+        e, expert_fn = z.held, EXPERT_FNS[z.expert_fn]
+        if z.shared_scored and z.latent_width:
+            raise ValueError(
+                "a scored shared expert reads the rows the routed experts "
+                "read; behind latent projections those are latent_width wide")
+
+        def weights(prefix, rows, width, lead=()):
+            return {name: self.param(
+                prefix + name, init,
+                lead + ((width, rows) if name == "down" else (rows, width)))
+                for name in EXPERT_LEAVES[z.expert_fn]}
+
+        router = self.param("router", init, (d, z.n_experts))
         params = {
-            "router": self.param("router", init, (d, z.n_experts)),
-            "experts": {
-                "gate": self.param("gate", init, (e, d, w)),
-                "up": self.param("up", init, (e, d, w)),
-                "down": self.param("down", init, (e, w, d)),
-            },
-            "shared": {
-                "gate": self.param("shared_gate", init, (d, sw)),
-                "up": self.param("shared_up", init, (d, sw)),
-                "down": self.param("shared_down", init, (sw, d)),
-                "score": self.param("shared_score", init, (d, 1)),
-            },
+            "router": router if z.router_trained
+            else jax.lax.stop_gradient(router),
+            "experts": weights("", z.latent_width or d, z.expert_width, (e,)),
         }
+        shared = weights("shared_", d, z.shared_width)
+        if z.shared_scored:
+            params["shared"] = {
+                **shared, "score": self.param("shared_score", init, (d, 1))}
+        if z.scoring == names.SIGMOID_BIAS:
+            params["choice_bias"] = self.param(
+                "choice_bias", nn.initializers.zeros, (z.n_experts,))
         rows = x.reshape(b * s, d)
+        tokens = inside = rows.astype(self.dtype)
+        if z.latent_width:
+            with jax.named_scope(names.MOE), jax.named_scope(
+                    names.LATENT_PROJ):
+                inside = _dense(z.latent_width, "latent_down",
+                                self.dtype)(tokens)
         y, counts = expert_share(
-            params, rows.astype(self.dtype), n_experts=z.n_experts, held=e,
-            first_expert=z.first_expert, k=z.top_k, router_input=rows)
+            params, inside, n_experts=z.n_experts, held=e,
+            first_expert=z.first_expert, k=z.top_k, expert_fn=expert_fn,
+            router_input=rows, scoring=z.scoring, scale=z.routed_scale)
+        with jax.named_scope(names.MOE):
+            if z.latent_width:
+                with jax.named_scope(names.LATENT_PROJ):
+                    y = _dense(d, "latent_up", self.dtype)(y)
+            if not z.shared_scored:
+                with jax.named_scope(names.SHARED_EXPERT):
+                    y = y + expert_fn(jax.tree.map(
+                        lambda w: w.astype(self.dtype), shared), tokens)
         # assignments per held expert: collected by train steps built with
         # ``aux=True`` (make_lm_train_step), one row a layer
         self.sow("intermediates", "moe_expert_tokens", counts)
         return y.reshape(b, s, d)
 
 
+def _attention(sizes, dtype, name):
+    return ATTENTIONS[sizes.attention](sizes, dtype, name=name)
+
+
+#: layer kind -> (the scope its sublayer runs under, the sublayer's name in
+#: the parameter tree, what builds it): the mixers a layer can hold.  A
+#: ``names.EXPERT_LAYER`` (one-sublayer layers only) holds the feed-forward
+#: arm instead
+MIXERS = {
+    names.LINEAR: (names.LINEAR_ATTN, "linear_attn", GatedDeltaNet),
+    names.FULL: (names.ATTN, "attn", _attention),
+    names.STATE_SPACE: (names.SSM, "ssm", Mamba2Mixer),
+}
+
+
+def layer_kinds(sizes: HybridSizes) -> tuple:
+    """The kinds a layer of a decoder of these sizes can be."""
+    return tuple(MIXERS) + ((names.EXPERT_LAYER,) if sizes.one_sublayer
+                            else ())
+
+
 class HybridLayer(nn.Module):
     """``h = x + Mixer(norm(x))``, ``y = h + FFN(norm(h))`` with the norms
     before the sublayers, ``h = x + norm(Mixer(x))``, ``y = h + norm(FFN(h))``
-    with them after."""
+    with them after.  With ``sizes.one_sublayer`` a layer is the first of
+    the two lines alone, its sublayer a mixer or (``names.EXPERT_LAYER``)
+    the feed-forward arm."""
 
     kind: str
     sizes: HybridSizes
@@ -409,17 +578,6 @@ class HybridLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         z = self.sizes
-        if self.kind == names.FULL:
-            scope, mixer = names.ATTN, ATTENTIONS[z.attention](
-                z, self.dtype, name="attn")
-        else:
-            scope, mixer = names.LINEAR_ATTN, GatedDeltaNet(
-                z, self.dtype, name="linear_attn")
-        # the expert layer names its own scope (``moe``), the dense one
-        # ``mlp``
-        ffn = (ExpertShare(z, self.dtype, name="experts")
-               if z.feed_forward == names.EXPERT_SHARE
-               else GatedMLP(z, self.dtype, name="mlp"))
 
         def residual(x, sublayer, norm_name):
             norm = NORMS[z.norm](z.eps, name=norm_name)
@@ -427,13 +585,26 @@ class HybridLayer(nn.Module):
                 return x + norm(sublayer(x)).astype(self.dtype)
             return x + sublayer(norm(x))
 
+        def feed_forward(x):
+            # the expert layer names its own scope (``moe``), the dense one
+            # ``mlp``
+            ffn = (ExpertShare(z, self.dtype, name="experts")
+                   if z.feed_forward == names.EXPERT_SHARE
+                   else GatedMLP(z, self.dtype, name="mlp"))
+            return residual(x, ffn, f"{ffn.name}_norm")
+
+        if self.kind == names.EXPERT_LAYER:
+            return feed_forward(x)
+        scope, name, build = MIXERS[self.kind]
+        mixer = build(z, self.dtype, name=name)
         with jax.named_scope(scope):
             x = residual(x, lambda h: mixer(h.astype(self.dtype)),
                          "mixer_norm")
+        if z.one_sublayer:
+            return x
         # kept under remat: the feed-forward's backward pass then needs
         # nothing of the mixer's, whose forward is recomputed after it
-        x = checkpoint_name(x, names.MIXER_OUT)
-        return residual(x, ffn, f"{ffn.name}_norm")
+        return feed_forward(checkpoint_name(x, names.MIXER_OUT))
 
 
 def remat_keeps(sizes: HybridSizes) -> tuple:
@@ -444,7 +615,9 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
     feed-forward's three products (its input IS ``MIXER_OUT``), so that no
     product of the feed-forward runs twice a step.  The expert-share arm
     recomputes its feed-forward: the buffers of its dispatch are many times
-    a layer's activations."""
+    a layer's activations.  A layer of one sublayer keeps its input alone."""
+    if sizes.one_sublayer:
+        return ()
     if sizes.feed_forward == names.DENSE_FFN:
         return (names.MIXER_OUT,) + names.DENSE_FFN_KEEPS
     return (names.MIXER_OUT,)
@@ -462,11 +635,12 @@ def kept_bytes(keep: tuple, sizes: HybridSizes, tokens: int, dtype) -> int:
 
 
 class HybridLM(nn.Module):
-    """Causal LM: token embedding, ``len(layer_types)`` pattern layers, a
-    final RMS norm of the layers' kind, an untied head."""
+    """Causal LM: token embedding, ``len(layer_types)`` pattern layers (each
+    one of :func:`layer_kinds`), a final RMS norm of the layers' kind, an
+    untied head."""
 
     vocab: int
-    layer_types: tuple          # names.LINEAR / names.FULL, one a layer
+    layer_types: tuple          # one of :func:`layer_kinds` a layer
     sizes: HybridSizes
     dtype: jnp.dtype = jnp.float32   # compute dtype; params stay f32 masters
     remat: bool = False
@@ -480,15 +654,17 @@ class HybridLM(nn.Module):
     @nn.compact
     def __call__(self, tokens: jax.Array) -> jax.Array:
         """``tokens [batch, seq] int32`` -> logits ``[batch, seq, vocab]``."""
-        unknown = set(self.layer_types) - {names.LINEAR, names.FULL}
-        if unknown:
-            raise ValueError(f"layer_types holds {sorted(unknown)}; a layer "
-                             f"is {names.LINEAR!r} or {names.FULL!r}")
         z = self.sizes
+        unknown = set(self.layer_types) - set(layer_kinds(z))
+        if unknown:
+            raise ValueError(
+                f"layer_types holds {sorted(unknown)}; a layer "
+                f"(one_sublayer={z.one_sublayer}) is one of "
+                f"{list(layer_kinds(z))}")
         keep = remat_keeps(z) if self.remat else ()
         telemetry.event(
             names.MIXER_LAYOUT, kinds=list(self.layer_types),
-            attention=z.attention,
+            one_sublayer=z.one_sublayer, attention=z.attention,
             attn_heads=[z.n_heads, z.n_heads_total or z.n_heads],
             attn_kv_heads=z.n_kv_heads, head_dim=z.head_dim,
             linear_heads=[z.linear_value_heads, z.linear_value_heads_total
@@ -497,7 +673,11 @@ class HybridLM(nn.Module):
             linear_key_dim=z.linear_key_dim,
             linear_value_dim=z.linear_value_dim,
             linear_projections=z.linear_projections,
-            beta_scale=z.beta_scale, heads_axis=z.heads_axis,
+            beta_scale=z.beta_scale,
+            ssm_heads=[z.ssm_heads, z.ssm_heads_total or z.ssm_heads],
+            ssm_groups=[z.ssm_groups, z.ssm_groups_total or z.ssm_groups],
+            ssm_head_dim=z.ssm_head_dim, ssm_state=z.ssm_state,
+            ssm_chunk=z.ssm_chunk, heads_axis=z.heads_axis,
             feed_forward=z.feed_forward, norm=z.norm,
             norm_after=z.norm_after, remat_keeps=list(keep),
             remat_kept_bytes_per_layer=kept_bytes(

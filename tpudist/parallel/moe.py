@@ -56,33 +56,72 @@ class Routing(NamedTuple):
 
     expert_idx: jax.Array  # [tokens, k] int32: the picks, among all experts
     weights: jax.Array     # [tokens, k] f32: their combine weights
-    probs: jax.Array       # [tokens, n_experts] f32: the softmax
+    probs: jax.Array       # [tokens, n_experts] f32: the scores (the softmax,
+    #                        or the sigmoids)
     local: jax.Array       # [tokens, k] int32: pick - first_expert where the
     #                        expert is held here, ``held`` where it is absent
 
 
+def _softmax_scores(logits, k, choice_bias, scale):
+    """Softmax over all experts; the raw top probability at ``k=1``
+    (Switch), renormalised to sum 1 over the ``k`` at ``k>1``
+    (Mixtral/GShard, HF's ``norm_topk_prob``)."""
+    if choice_bias is not None or scale != 1.0:
+        raise ValueError(f"{names.SOFTMAX} scoring takes no choice bias and "
+                         f"no scale")
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, expert_idx = lax.top_k(probs, k)
+    if k > 1:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return expert_idx, weights, probs
+
+
+def _sigmoid_bias_scores(logits, k, choice_bias, scale):
+    """A sigmoid an expert (DeepSeek-V3's router, one group); the picks are
+    the ``k`` largest of ``score + choice_bias``, a buffer that steers the
+    choice alone and takes no gradient; the weights are the picks' UNbiased
+    scores over their sum (+ 1e-20), times ``scale``."""
+    scores = jax.nn.sigmoid(logits)
+    chosen = scores if choice_bias is None else scores + lax.stop_gradient(
+        choice_bias.astype(jnp.float32))
+    _, expert_idx = lax.top_k(chosen, k)
+    weights = jnp.take_along_axis(scores, expert_idx, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return expert_idx, weights * scale, scores
+
+
+#: scoring -> ``(logits f32, k, choice_bias, scale) -> (picks, weights,
+#: scores)``: the ways :func:`route` scores
+SCORINGS = {names.SOFTMAX: _softmax_scores,
+            names.SIGMOID_BIAS: _sigmoid_bias_scores}
+
+
 def route(logits: jax.Array, *, n_experts: int, k: int,
-          held: int | None = None, first_expert: int = 0) -> Routing:
-    """The one router of both arms: softmax over all ``n_experts`` in f32
+          held: int | None = None, first_expert: int = 0,
+          scoring: str = names.SOFTMAX, choice_bias: jax.Array | None = None,
+          scale: float = 1.0) -> Routing:
+    """The one router of both arms: scores over all ``n_experts`` in f32
     whatever the compute dtype (ties and gate scales are
-    precision-sensitive), the top ``k``, and their weights: the raw top
-    probability at ``k=1`` (Switch), renormalised to sum 1 over the ``k``
-    at ``k>1`` (Mixtral/GShard, HF's ``norm_topk_prob``).  The width, the
-    picks and the renormalisation do not depend on which experts are held
-    here; ``held`` / ``first_expert`` only say which of the picks this
-    device computes (``local``)."""
+    precision-sensitive), the top ``k``, and their weights, by ``scoring``
+    (:data:`SCORINGS`: softmax with the picks' probabilities renormalised,
+    or sigmoid scores picked by ``score + choice_bias [n_experts]`` and
+    weighted by the picks' own renormalised scores times ``scale``).  The
+    width, the picks and the renormalisation do not depend on which experts
+    are held here; ``held`` / ``first_expert`` only say which of the picks
+    this device computes (``local``)."""
     if logits.shape[-1] != n_experts:
         raise ValueError(f"the router gives {logits.shape[-1]} scores for "
                          f"{n_experts} experts")
+    if scoring not in SCORINGS:
+        raise ValueError(f"scoring is {scoring!r}; the router scores by one "
+                         f"of {sorted(SCORINGS)}")
     held = n_experts if held is None else held
     if not (0 <= first_expert and 1 <= held
             and first_expert + held <= n_experts):
         raise ValueError(f"experts {first_expert}..{first_expert + held} are "
                          f"not a run of the {n_experts} routed experts")
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, expert_idx = lax.top_k(probs, k)
-    if k > 1:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    expert_idx, weights, probs = SCORINGS[scoring](
+        logits.astype(jnp.float32), k, choice_bias, scale)
     local = expert_idx - first_expert
     local = jnp.where((local >= 0) & (local < held), local, held)
     return Routing(expert_idx, weights, probs, local)
@@ -237,6 +276,19 @@ def gated_ffn(params: dict, rows: jax.Array, dot=jnp.matmul) -> jax.Array:
                * dot(rows, params["up"]), params["down"])
 
 
+def relu2_ffn(params: dict, rows: jax.Array, dot=jnp.matmul) -> jax.Array:
+    """An ungated squared-ReLU expert, ``down(relu(up(x))^2)``; ``dot`` as
+    :func:`gated_ffn` takes it."""
+    return dot(jnp.square(jax.nn.relu(dot(rows, params["up"]))),
+               params["down"])
+
+
+#: what an expert computes, by name, and the projections it reads
+EXPERT_FNS = {names.GATED_SILU: gated_ffn, names.RELU2: relu2_ffn}
+EXPERT_LEAVES = {names.GATED_SILU: ("gate", "up", "down"),
+                 names.RELU2: ("up", "down")}
+
+
 #: tokens whose picks :func:`expert_share` takes through its buffers at a
 #: time: the buffers hold ``SHARE_BLOCK_TOKENS * k`` rows
 SHARE_BLOCK_TOKENS = 8192
@@ -355,18 +407,23 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
                  first_expert: int, k: int,
                  expert_fn: ExpertFn = gated_ffn,
                  router_input: jax.Array | None = None,
-                 block_tokens: int = SHARE_BLOCK_TOKENS):
+                 block_tokens: int = SHARE_BLOCK_TOKENS,
+                 scoring: str = names.SOFTMAX, scale: float = 1.0):
     """This device's part of one routed-expert layer, dropless:
     ``(y [tokens, d] in x's dtype, assignments per held expert [held])``.
 
-    ``params = {"router": [d, n_experts] f32, "experts": a pytree with a
-    leading axis over the ``held`` experts held (``first_expert`` on), and
-    optionally "shared": one expert's weights with "score": [d, 1]}``;
-    ``x [tokens, d]`` in the compute dtype.  The router's logits are a
-    float32 product at the highest precision of ``router_input`` (``x``
-    before it was rounded to the compute dtype, where the caller has it),
-    its softmax, top ``k`` and renormalisation are :func:`route`'s, over
-    all ``n_experts``.  The experts compute in ``x``'s dtype.  ``shared``
+    ``params = {"router": [d_router, n_experts] f32, "experts": a pytree
+    with a leading axis over the ``held`` experts held (``first_expert``
+    on), and optionally "choice_bias": [n_experts] (``scoring``
+    ``names.SIGMOID_BIAS``) and "shared": one expert's weights with
+    "score": [d, 1]}``; ``x [tokens, d]`` in the compute dtype.  The
+    router's logits are a float32 product at the highest precision of
+    ``router_input`` (``x`` before it was rounded to the compute dtype,
+    where the caller has it; or the tokens at their own width
+    ``d_router`` where ``x`` holds their projection into the experts'
+    latent space), its scores, top ``k``, renormalisation and ``scale``
+    are :func:`route`'s by ``scoring``, over all ``n_experts``.  The
+    experts compute in ``x``'s dtype.  ``shared``
     is ``sigmoid(x . score) * E_shared(x)``, what every member of the
     group computes alike.  The tokens are taken in equal blocks of at most
     ``block_tokens``, one after another, so that the buffers hold
@@ -386,14 +443,16 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
     telemetry.event(names.MOE_LAYOUT, experts=n_experts, held=held,
                     first=first_expert, top_k=k, dropless=True,
                     buffer_rows=tokens // blocks * k, blocks=blocks,
-                    combine=names.PICK_MAJOR)
+                    combine=names.PICK_MAJOR, scoring=scoring, scale=scale,
+                    width=d)
     with jax.named_scope(names.MOE):
         scored = x if router_input is None else router_input
         logits = jnp.matmul(scored.astype(jnp.float32),
                             params["router"].astype(jnp.float32),
                             precision=lax.Precision.HIGHEST)
         routing = route(logits, n_experts=n_experts, k=k, held=held,
-                        first_expert=first_expert)
+                        first_expert=first_expert, scoring=scoring,
+                        choice_bias=params.get("choice_bias"), scale=scale)
         counts = jnp.sum(routing.local.reshape(-1)[:, None]
                          == jnp.arange(held)[None], axis=0, dtype=jnp.int32)
         experts = jax.tree.map(lambda w: w.astype(x.dtype),

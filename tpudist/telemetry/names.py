@@ -68,8 +68,17 @@ EXPERTS = "experts"
 SHARED_EXPERT = "shared_expert"
 MOE_COMBINE = "moe_combine"
 PATTERN_LAYER = "layer"
+# ``ssm`` is the whole state-space mixer (norm, projection, convolution, the
+# scan, gated norm, output projection); ``ssd_scan`` (tpudist/ops/ssd.py) is
+# the chunked scan alone, nested in it.  ``latent_proj`` holds the two
+# projections that take an expert layer's tokens into its experts' latent
+# space and its partial sum back out, nested in ``moe``
+SSM = "ssm"
+SSD_SCAN = "ssd_scan"
+LATENT_PROJ = "latent_proj"
 SCOPES = (EMBED, ATTN, MLP, HEAD, LOSS, OPTIMIZER, GRAD_ACCUM, LINEAR_ATTN,
-          DELTA_RULE, MOE, EXPERTS, SHARED_EXPERT, MOE_COMBINE)
+          DELTA_RULE, MOE, EXPERTS, SHARED_EXPERT, MOE_COMBINE, SSM, SSD_SCAN,
+          LATENT_PROJ)
 #: what JAX itself writes round the scopes of a transposed (backward) op
 BACKWARD_MARK = "transpose("
 
@@ -130,8 +139,13 @@ WHY_SEQ = "seq"              # too short for the flash kernels, or no tile fits
 WHY_PLATFORM = "platform"    # not a TPU
 WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # tpudist/models/hybrid.py, once a trace of the decoder: ``kinds=`` the layer
-# kinds in order (LINEAR / FULL); ``attention=`` GATED_ATTN / NORMED_ATTN with
+# kinds in order (the keys of models/hybrid.py::MIXERS, or EXPERT_LAYER) and
+# ``one_sublayer=`` whether a layer is one sublayer (a mixer OR the
+# feed-forward arm) and not a mixer and its feed-forward; ``attention=``
+# GATED_ATTN / NORMED_ATTN / GROUPED_ATTN with
 # ``attn_heads=`` [held, in all], ``attn_kv_heads=``, ``head_dim=``; of the
+# state-space mixers ``ssm_heads=`` and ``ssm_groups=`` [held, in all],
+# ``ssm_head_dim=``, ``ssm_state=``, ``ssm_chunk=``; of the
 # delta-rule mixers ``linear_heads=`` [value heads held, in all],
 # ``linear_key_heads=``, ``linear_key_dim=``, ``linear_value_dim=``,
 # ``linear_projections=`` FUSED / SEPARATE, ``beta_scale=`` (the write
@@ -146,14 +160,25 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # (tpudist/parallel/moe.py): ``experts=`` the router's width, ``held=``,
 # ``first=``, ``top_k=``, ``dropless=``, ``buffer_rows=``, ``blocks=``,
 # ``combine=`` PICK_MAJOR (each token's ``k`` rows are added up as ``k``
-# slabs of [tokens, d])
+# slabs of [tokens, d]), ``scoring=`` SOFTMAX / SIGMOID_BIAS, ``scale=`` what
+# the picks' renormalised weights are multiplied by, ``width=`` the rows'
+# width (an expert layer's latent width where it has one)
 MIXER_LAYOUT = "mixer_layout"
 MOE_LAYOUT = "moe_layout"
 PICK_MAJOR = "pick_major"
 LINEAR = "linear_attention"
 FULL = "full_attention"
+STATE_SPACE = "state_space"  # a Mamba-2 mixer (tpudist/ops/ssd.py)
+EXPERT_LAYER = "expert_layer"  # one-sublayer layers only: the feed-forward arm
 GATED_ATTN = "gated"         # per-head q/k norms, an output gate, part rotary
 NORMED_ATTN = "normed"       # one q/k norm statistic over all heads, no gate
+GROUPED_ATTN = "grouped"     # plain grouped-query: no norm, gate or rotary
+# tpudist/parallel/moe.py: how a router scores (``route``) and what an expert
+# computes
+SOFTMAX = "softmax"          # softmax over all experts, top k renormalised
+SIGMOID_BIAS = "sigmoid_bias"  # sigmoid scores, picked by score + a bias
+GATED_SILU = "gated_silu"    # down(silu(gate(x)) * up(x))
+RELU2 = "relu2"              # down(relu(up(x))^2)
 FUSED = "fused_per_key_head"
 SEPARATE = "separate"
 ZERO_CENTRED = "zero_centred"
