@@ -504,10 +504,13 @@ def test_hybrid_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
     assert sorted(_kernels_named(text)) == [
         "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"]
     assert re.search(r"ragged-dot", text)
-    assert text.count("tpu_custom_call") == (
+    assert text.count("tpu_custom_call") == 64 == (
         job["custom_calls_per_layer"] * m["layers"])
     assert not re.search(r"\[512,2048,512\]|\[512,512,2048\]", text)
     assert re.search(r"\[32,2048,512\]", text)
+    # a 16th of the experts held: one buffer of the bound a block, no
+    # windows (``moe.share_windows``)
+    assert "[81920,2048]" in text and "[81920,512]" in text
 
 
 @pytest.fixture(scope="module")
@@ -586,13 +589,16 @@ def test_nemotron_cell_step_fills_one_chip_and_fits(nemotron_step):
     # 700,862,960 parameters (and 5 x 512 numbers of choice bias) x 12
     # bytes resident
     assert 8.40e9 < mem.argument_size_in_bytes < 8.42e9
-    # 11.81 GiB = 12.68 GB: temporaries 4,271,943,680 bytes, the float32
-    # gradient (2.80 GB) among them; an expert layer's backward pass runs
-    # its 180,224 assignments through buffers of 369 MB (1,024 wide) and
-    # 969 MB (2,688 wide).  The issue's ceiling is 15.0 GiB, the compiler
-    # allows 15.75; the fallbacks (the loss in blocks of rows, the share's
-    # blocks at 4,096 tokens) were not needed
-    assert 12e9 < held < 15.0 * 2 ** 30
+    # 10.59 GiB = 11.37 GB: temporaries 2,962,675,200 bytes, the float32
+    # gradient (2.80 GB) among them.  An expert layer takes what arrived
+    # through windows of 22,528 rows (46 MB at 1,024 wide, 121 MB at 2,688)
+    # and keeps its share's result (16.8 MB a layer) from forward to
+    # backward; PR 37's step, which ran all 180,224 assignments a layer
+    # through buffers of 369 MB and 969 MB, held 11.81 GiB (temporaries
+    # 4,271,943,680).  The issue's ceiling is 15.0 GiB, the compiler allows
+    # 15.75
+    assert 11.2e9 < held < 15.0 * 2 ** 30
+    assert mem.temp_size_in_bytes <= 4_271_943_680 - 1.2e9
 
 
 def test_nemotron_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
@@ -601,8 +607,13 @@ def test_nemotron_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
     head of 128: the three flash kernels by name, the forward twice (its
     layer is rematerialised).  The five expert layers' products are the
     compiler's grouped matmuls over the 8 experts HELD at the latent
-    width: no product over 512 experts.  The state-space scan is plain
-    XLA.  No collective: one chip's share."""
+    width: no product over 512 experts.  They run over windows of 22,528
+    rows (eight even shares of the 180,224 assignments a layer), inside
+    two loops a layer, forward and backward (2 + 1 and 6 + 2 calls): the
+    rematerialised forward's loop is dead, because the layer keeps the
+    share's result, so the step counts the 59 calls the benchmark's
+    runner holds it to.  The state-space scan is plain XLA.  No
+    collective: one chip's share."""
     import re
 
     step, job, m = nemotron_step
@@ -614,5 +625,7 @@ def test_nemotron_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
     assert re.search(r"ragged-dot", text)
     assert re.search(r"\[8,1024,2688\]", text)
     assert not re.search(r"\[512,1024,2688\]|\[512,2688,1024\]", text)
+    assert "[22528,2688]" in text and "[22528,1024]" in text
+    assert "[180224,2688]" not in text and "[180224,1024]" not in text
     assert job["collectives_in_step"] == []
     assert "all-reduce" not in text and "all-gather" not in text

@@ -31,6 +31,7 @@ from tpudist.models.transformer import lm_loss
 from tpudist.ops.gated_delta import (chunked_gated_delta_rule,
                                      gated_delta_rule_reference)
 from tpudist.parallel import moe
+from tpudist.telemetry import names
 from tpudist.telemetry.names import MIXER_OUT
 
 DATA = Path(__file__).resolve().parent.parent / "cellbench" / "tests" / "data"
@@ -327,9 +328,9 @@ def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
     # a share alone is the reference's share: the absent experts' part is
     # left out in both, and the shared expert is every member's
     alone = arch._experts(x, w, m=m, mode="f32", first=8, held=4)
-    got, counts = moe.expert_share(share_of(m, w, 8, 4, shared=True), x,
-                                   n_experts=16, held=4, first_expert=8,
-                                   k=m["top_k"])
+    got, counts, _ = moe.expert_share(share_of(m, w, 8, 4, shared=True), x,
+                                      n_experts=16, held=4, first_expert=8,
+                                      k=m["top_k"])
     assert worst(got, alone) < 1e-5
     picks, _ = arch.route(x, w["router"], m=m)
     np.testing.assert_array_equal(
@@ -362,7 +363,7 @@ def test_rigged_imbalance_drops_nothing(rigged, block):
         return arch._experts(x, w, m=m, mode="f32", first=4, held=4,
                              shared=False)
 
-    _, counts = program(params, x)
+    _, counts, _ = program(params, x)
     if rigged == "all_held":
         np.testing.assert_array_equal(counts, [1024] * 4)
     else:
@@ -439,13 +440,9 @@ def test_the_combine_builds_no_token_major_tensor():
     assert (t, k, d) not in shapes
 
 
-def test_rows_the_grouped_products_leave_undefined_reach_nothing(
-        monkeypatch):
-    """On the chip a grouped product's rows behind its last group, and the
-    same rows of its cotangents, hold whatever the buffer held (the CPU's
-    reference lowering zeroes them, so no other test here sees it).  With
-    those rows poisoned, forward and backward, the layer's result and
-    every gradient are still the masked dense reference's."""
+def _poison_the_rows_behind_the_last_group(monkeypatch):
+    """``lax.ragged_dot`` as the chip leaves it: the rows behind its last
+    group are NaN in its result and in its cotangents."""
     real = jax.lax.ragged_dot
 
     @jax.custom_vjp
@@ -462,6 +459,16 @@ def test_rows_the_grouped_products_leave_undefined_reach_nothing(
         return jnp.where(live, out, jnp.nan)
 
     monkeypatch.setattr(moe.lax, "ragged_dot", poisoned)
+
+
+def test_rows_the_grouped_products_leave_undefined_reach_nothing(
+        monkeypatch):
+    """On the chip a grouped product's rows behind its last group, and the
+    same rows of its cotangents, hold whatever the buffer held (the CPU's
+    reference lowering zeroes them, so no other test here sees it).  With
+    those rows poisoned, forward and backward, the layer's result and
+    every gradient are still the masked dense reference's."""
+    _poison_the_rows_behind_the_last_group(monkeypatch)
     m, w, x = expert_layer(tokens=1024)
     params = share_of(m, w, 4, 4, shared=False)
 
@@ -474,6 +481,136 @@ def test_rows_the_grouped_products_leave_undefined_reach_nothing(
                              shared=False)
 
     assert_share_follows(program, ref, params, w, x)
+
+
+#: a block of 64 tokens x top 6 = 384 assignments at 2 held experts, taken
+#: through windows of 32 rows (100 in ``ragged_bound``): load -> (the
+#: block's picks from a generator, the windows the arrivals fill)
+def _even(rng):
+    # a quarter of a window arrives in the mean
+    return np.where(rng.random((64, 6)) < 0.02, rng.integers(0, 2, (64, 6)),
+                    2), 1
+
+
+def _straddle(rng):
+    # 33 arrivals, one row past the first window's edge; the second
+    # expert's run of 13 lies over the edge
+    picks = np.full(384, 2)
+    picks[rng.permutation(384)[:33]] = [0] * 20 + [1] * 13
+    return picks.reshape(64, 6), 2
+
+
+WINDOW_LOADS = {
+    "even": _even,
+    "no_arrival": lambda rng: (np.full((64, 6), 2), 0),
+    "every_pick_held": lambda rng: (rng.integers(0, 2, (64, 6)), 12),
+    "straddle": _straddle,
+    "even_poisoned": _even,
+    "straddle_poisoned": _straddle,
+    "ragged_bound": lambda rng: (rng.integers(0, 2, (64, 6)), 4),
+}
+
+
+@pytest.mark.parametrize("expert", [names.RELU2, names.GATED_SILU])
+@pytest.mark.parametrize("load", list(WINDOW_LOADS))
+def test_windows_give_the_one_buffers_block_and_the_dense_sum(
+        load, expert, monkeypatch):
+    """A block of ``_held_experts_windowed``, forward and all four
+    cotangents (the experts' projections, the tokens, the weights), against
+    the one buffer of the bound and against the dense sum over each token's
+    picks: one window at an even load; no trip, zeros out and zero
+    gradients where nothing arrived; all twelve windows where every pick is
+    held (dropless); a run that lies over a window's edge; the rows behind
+    the last arrival poisoned with NaN in the window's buffer and in its
+    cotangents; a bound that is no multiple of the window (the last window
+    overlaps the one before it)."""
+    t, k, d, width, held = 64, 6, 16, 8, 2
+    window = 100 if load == "ragged_bound" else 32
+    picks, windows = WINDOW_LOADS[load](np.random.default_rng(0))
+    local = jnp.asarray(picks, jnp.int32)
+    arrived = int((picks < held).sum())
+    assert -(-arrived // window) == windows
+    if load.startswith("even"):
+        assert 0 < arrived < window // 2
+    key = jax.random.split(jax.random.PRNGKey(1), 6)
+    expert_fn = moe.EXPERT_FNS[expert]
+    experts = {name: jax.random.normal(
+        key[i], (held, width, d) if name == "down" else (held, d, width))
+        for i, name in enumerate(moe.EXPERT_LEAVES[expert])}
+    x = jax.random.normal(key[3], (t, d))
+    weights = jax.random.uniform(key[4], (t, k))
+    dy = jax.random.normal(key[5], (t, d))
+
+    def dense(experts, x, weights):
+        every = jax.vmap(expert_fn, in_axes=(0, None))(experts, x)
+        rows = every[jnp.minimum(local, held - 1), jnp.arange(t)[:, None]]
+        return jnp.sum(jnp.where((local < held)[..., None],
+                                 weights[..., None] * rows, 0.0), axis=1)
+
+    def one_buffer(*a):
+        return moe._held_experts(*a, local, held, expert_fn)
+
+    def windowed(*a):
+        return moe._held_experts_windowed(*a, local, held, expert_fn, window)
+
+    if load.endswith("poisoned"):
+        _poison_the_rows_behind_the_last_group(monkeypatch)
+    got, pull = jax.vjp(windowed, experts, x, weights)
+    grads = pull(dy)
+    assert got.dtype == jnp.float32 and got.shape == (t, d)
+    for other in (one_buffer, dense):
+        want, pull = jax.vjp(other, experts, x, weights)
+        assert worst(got, want) < 1e-5 if arrived else not want.any()
+        for g, w in zip(jax.tree.leaves(grads), jax.tree.leaves(pull(dy))):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            assert np.isfinite(np.asarray(g)).all()
+            assert rel(g, w) < 1e-5 if arrived else not np.asarray(g).any()
+    if not arrived:
+        assert not np.asarray(got).any()
+
+
+def _primitives(jaxpr):
+    """Every equation's primitive, of a jaxpr and of those nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for param in eqn.params.values():
+            for inner in (param if isinstance(param, (tuple, list))
+                          else [param]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _primitives(inner)
+
+
+@pytest.mark.parametrize("held, windows", [(4, False), (1, True)],
+                         ids=["a_16th_held", "a_64th_held"])
+def test_a_share_of_a_32nd_or_less_takes_windows_and_no_other(held, windows):
+    """512 tokens x top 8 of 64 experts, forward and backward.  4 held (a
+    16th): a window of eight even shares would be half the bound, so the
+    layer keeps ONE buffer of the bound's 4,096 rows and no loop but
+    ``lax.map``'s own over the blocks (a ``scan``), as before there were
+    windows.  1 held (a 64th): windows of 512 rows in a ``while``, and no
+    tensor of 4,096 rows by a row's width or an expert's."""
+    t, k, d, width, n = 512, 8, 16, 24, 64
+    assert moe.share_windows(t, k, held, n) == (
+        (512, 8) if windows else (t * k, 1))
+    key = jax.random.split(jax.random.PRNGKey(0), 5)
+    params = {"router": jax.random.normal(key[0], (d, n)), "experts": {
+        "gate": jax.random.normal(key[1], (held, d, width)),
+        "up": jax.random.normal(key[2], (held, d, width)),
+        "down": jax.random.normal(key[3], (held, width, d))}}
+    x = jax.random.normal(key[4], (t, d))
+    loss = lambda p, x: jnp.sum(jnp.sin(moe.expert_share(
+        p, x, n_experts=n, held=held, first_expert=8, k=k)[0]))
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(params, x).jaxpr
+    loops = {p for p in _primitives(jaxpr) if p in ("while", "scan")}
+    shapes = {a.shape for a in _avals(jaxpr) if hasattr(a, "shape")}
+    if windows:
+        assert loops == {"while", "scan"}
+        assert {(512, d), (512, width)} <= shapes
+        assert not {(t * k, d), (t * k, width), (k, t, d)} & shapes
+    else:
+        assert loops == {"scan"}
+        assert {(t * k, d), (t * k, width), (k, t, d)} <= shapes
 
 
 def test_the_capacity_arm_routes_by_the_same_router():

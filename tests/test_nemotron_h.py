@@ -254,7 +254,7 @@ def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
     assert (z.n_heads, z.n_heads_total, z.n_kv_heads, z.head_dim) == (
         2, 16, 1, 16)
     assert (z.n_experts, z.held, z.first_expert, z.top_k) == (32, 4, 0, 6)
-    assert hybrid.remat_keeps(z) == ()
+    assert hybrid.remat_keeps(z) == (names.EXPERT_OUT,)
 
 
 def test_logits_match_the_reference(f32_pair):
@@ -413,6 +413,53 @@ def test_three_adam_steps_follow_the_reference():
     np.testing.assert_allclose(norms, ref["update_norms"], rtol=2e-3)
     bias = moved["params"]["layer_1"]["experts"]["choice_bias"]
     assert float(jnp.abs(bias).max()) == 0.0   # Adam leaves a buffer alone
+
+
+@pytest.mark.parametrize("rigged, windows", [(False, 1), (True, 8)],
+                         ids=["seeded_weights", "every_pick_held"])
+def test_a_64th_of_the_experts_held_goes_by_windows(rigged, windows):
+    """2 of 128 experts held at top 2, 8 rows x 256 tokens: an expert
+    layer's 4,096 assignments go through windows of 512 rows (eight even
+    shares), at most 8 of them.  At the seeded weights one window holds
+    what arrives; with a choice bias that sends every pick to the two held
+    experts all eight run, and nothing is dropped: loss and every gradient
+    are the reference's either way, under the layers' rematerialisation
+    (which keeps the windowed share's result).  The step built with
+    ``aux=True`` says the windows taken, a row a layer."""
+    import optax
+
+    from tpudist.runtime.mesh import MeshConfig, make_mesh
+    from tpudist.train import init_lm_state, make_lm_train_step
+
+    config = tiny(num_hidden_layers=2, hybrid_override_pattern="ME",
+                  n_routed_experts=2, num_experts_per_tok=2)
+    config["as_run"]["router_experts"] = 128
+    assert moe.share_windows(8 * 256, 2, 2, 128) == (512, 8)
+    weights = arch.init_weights(config, reference.split_seed(11))
+    if rigged:
+        weights["layer_1.choice_bias"] = weights[
+            "layer_1.choice_bias"].at[:2].set(100.0)
+    tokens = jax.random.randint(jax.random.PRNGKey(11), (8, 256), 0,
+                                config["vocab_size"])
+    module = arch.build_module(config, {"remat": "nothing"})
+    params = arch.program_tree(config, weights)
+    loss, grads = jax.value_and_grad(
+        lambda p: lm_loss(module.apply(p, tokens), tokens))(params)
+    ref_loss, ref_grads = arch.loss_and_grads(config, weights, tokens)
+    assert abs(float(loss) - float(ref_loss)) < 2e-6
+    for name, got in zip(arch.leaf_names(config),
+                         arch.named_leaves(config, grads)):
+        assert float(jnp.linalg.norm(ref_grads[name])) > 0, name
+        assert rel(got, ref_grads[name]) < 1e-4, name
+    tx = optax.sgd(0.0)
+    step = make_lm_train_step(
+        module.apply, tx, make_mesh(MeshConfig(data=1),
+                                    devices=jax.devices()[:1]),
+        donate_state=False, aux=True)
+    _, _, aux = step(init_lm_state(params, tx), tokens)
+    assert np.asarray(aux["moe_windows"]).tolist() == [[windows]]
+    arrived = int(np.asarray(aux["moe_expert_tokens"]).sum())
+    assert arrived == 4096 if rigged else 0 < arrived <= 512
 
 
 # ---------------------------------------------------------------------------
@@ -626,7 +673,11 @@ def test_the_layout_events_say_the_arms_and_the_share(tmp_path, f32_pair):
         telemetry.finish(write_report=False)
     (e,) = [r for r in events if r["name"] == names.MIXER_LAYOUT]
     assert e["kinds"] == list(p["module"].layer_types)
-    assert e["one_sublayer"] is True and e["remat_keeps"] == []
+    # (the name is in the graph where a share takes windows; 4 of 32 held
+    # keep one buffer)
+    assert e["one_sublayer"] is True
+    assert e["remat_keeps"] == [names.EXPERT_OUT]
+    assert e["remat_kept_bytes_per_layer"] == 2 * 128 * 32 * 4
     assert (e["ssm_heads"], e["ssm_groups"], e["ssm_head_dim"],
             e["ssm_state"], e["ssm_chunk"]) == ([2, 16], [1, 8], 8, 16, 32)
     assert (e["attention"], e["attn_heads"], e["attn_kv_heads"]) == (
@@ -635,8 +686,10 @@ def test_the_layout_events_say_the_arms_and_the_share(tmp_path, f32_pair):
     assert len(said) == 5
     for r in said:
         assert (r["scoring"], r["scale"], r["width"], r["experts"],
-                r["held"], r["top_k"], r["buffer_rows"]) == (
-                    names.SIGMOID_BIAS, 2.5, 32, 32, 4, 6, 2 * 128 * 6)
+                r["held"], r["top_k"], r["buffer_rows"], r["window_rows"],
+                r["windows_at_most"], r["combine"]) == (
+                    names.SIGMOID_BIAS, 2.5, 32, 32, 4, 6, 2 * 128 * 6,
+                    2 * 128 * 6, 1, names.PICK_MAJOR)
 
 
 def test_the_layers_names_carry_what_the_readers_look_for(f32_pair):

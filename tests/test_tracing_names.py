@@ -98,30 +98,40 @@ def test_a_backward_op_carries_the_transpose_mark_and_its_sublayer(
                 if names.BACKWARD_MARK in n and _under(names.OPTIMIZER, n)]
 
 
-def test_the_combine_scope_nests_in_moe_forward_and_backward():
+@pytest.mark.parametrize("tokens, n_experts, held, k, moved", [
+    (32, 4, 2, 2, ("gather",)), (512, 64, 1, 8, ("gather", "scatter-add"))],
+    ids=["one_buffer", "windows"])
+def test_the_combine_scope_nests_in_moe_forward_and_backward(
+        tokens, n_experts, held, k, moved):
     """Inside the share layer's loop over blocks the lowered names start
     afresh, so the nesting is read off the compiled text, where the
     profiler takes an operation's scope from: ``moe_combine`` under ``moe``
-    (it stays inside what ``moe`` reads), forward and backward."""
+    (it stays inside what ``moe`` reads), forward and backward; where the
+    share takes windows, round the gathers into a window and the
+    scatter-adds out of it."""
     from tpudist.parallel import moe
 
     key = jax.random.split(jax.random.PRNGKey(0), 5)
-    d, width, held = 16, 8, 2
-    params = {"router": jax.random.normal(key[0], (d, 4)), "experts": {
+    d, width = 16, 8
+    assert (moe.share_windows(tokens, k, held, n_experts)[1] > 1) == (
+        "scatter-add" in moved)
+    params = {"router": jax.random.normal(key[0], (d, n_experts)),
+              "experts": {
         "gate": jax.random.normal(key[1], (held, d, width)),
         "up": jax.random.normal(key[2], (held, d, width)),
         "down": jax.random.normal(key[3], (held, width, d))}}
-    x = jax.random.normal(key[4], (32, d))
+    x = jax.random.normal(key[4], (tokens, d))
     loss = lambda p, x: jnp.sum(jnp.sin(moe.expert_share(
-        p, x, n_experts=4, held=held, first_expert=1, k=2)[0]))
+        p, x, n_experts=n_experts, held=held, first_expert=1, k=k)[0]))
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         params, x).compile().as_text()
     # the gathers: a constant the compiler hoists out of the loop loses
     # the loop's names with it
-    gathers = [n for n in re.findall(r'op_name="([^"]+)"', text)
-               if _under(names.MOE_COMBINE, n) and n.endswith("/gather")]
-    assert gathers and all(_under(names.MOE, n) for n in gathers)
-    assert {names.BACKWARD_MARK in n for n in gathers} == {False, True}
+    for op in moved:
+        ops = [n for n in re.findall(r'op_name="([^"]+)"', text)
+               if _under(names.MOE_COMBINE, n) and n.endswith("/" + op)]
+        assert ops and all(_under(names.MOE, n) for n in ops), op
+        assert {names.BACKWARD_MARK in n for n in ops} == {False, True}, op
 
 
 def test_scopes_do_not_change_what_the_step_computes():
@@ -492,27 +502,72 @@ def test_the_pattern_decoder_says_its_layout_once_a_trace(tmp_path):
                  (4, 2, 2, 2, True, names.PICK_MAJOR)}
 
 
+@pytest.mark.parametrize(
+    "tokens, k, held, width, rows, blocks, window_rows, at_most, combine", [
+        (8192, 22, 8, 1024, 180224, 1, 22528, 8, names.SCATTER_ADD),
+        (16384, 10, 32, 2048, 81920, 2, 81920, 1, names.PICK_MAJOR)],
+    ids=["8_of_512_held", "32_of_512_held"])
+def test_moe_layout_says_the_windows_a_share_takes(
+        tmp_path, tokens, k, held, width, rows, blocks, window_rows, at_most,
+        combine):
+    """The two cells' expert layers at their own shapes, traced only: a
+    64th of the experts held takes its arrivals through windows of eight
+    even shares (22,528 rows, at most 8 of them a block), a 16th keeps one
+    buffer of the bound (``window_rows`` says the bound itself)."""
+    from tpudist.parallel import moe
+
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+    params = {"router": jax.ShapeDtypeStruct((width, 512), jnp.float32),
+              "experts": {"up": shape(held, width, 64),
+                          "down": shape(held, 64, width)}}
+    session = telemetry.start(tmp_path / "tele", rank=0, generation=0)
+    try:
+        y, counts, windows = jax.eval_shape(
+            lambda p, x: moe.expert_share(
+                p, x, n_experts=512, held=held, first_expert=0, k=k,
+                expert_fn=moe.relu2_ffn), params, shape(tokens, width))
+        said = [r for r in session.ring if r.get("name") == names.MOE_LAYOUT]
+    finally:
+        telemetry.finish(write_report=False)
+    assert (y.shape, counts.shape, windows.shape) == (
+        (tokens, width), (held,), (blocks,))
+    (e,) = said
+    assert (e["buffer_rows"], e["blocks"], e["window_rows"],
+            e["windows_at_most"], e["combine"], e["dropless"]) == (
+                rows, blocks, window_rows, at_most, combine, True)
+
+
 @pytest.mark.parametrize("feed_forward, remat, keeps, columns", [
     (names.DENSE_FFN, True, [names.MIXER_OUT, names.FFN_GATE, names.FFN_UP,
                              names.FFN_OUT], 32 + 2 * 48 + 32),
     (names.EXPERT_SHARE, True, [names.MIXER_OUT], 32),
-    (names.DENSE_FFN, False, [], 0)],
-    ids=["dense_arm", "expert_share_arm", "no_remat"])
+    (names.DENSE_FFN, False, [], 0),
+    (names.EXPERT_LAYER, True, [names.EXPERT_OUT], 24)],
+    ids=["dense_arm", "expert_share_arm", "no_remat",
+         "one_sublayer_expert_layer"])
 def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
         tmp_path, feed_forward, remat, keeps, columns):
     """``remat_keeps``: the names kept besides a layer's input;
     ``remat_kept_bytes_per_layer``: ``MIXER_OUT``'s ``tokens x d_model x
     itemsize`` and, in the dense arm, ``tokens x (2 x ffn_width + d_model)
-    x itemsize`` more."""
+    x itemsize`` more; in a decoder of one-sublayer layers an expert
+    layer's ``EXPERT_OUT``, ``tokens x latent_width x itemsize``."""
     from tpudist.models.hybrid import HybridLM, HybridSizes
 
+    arms = dict(feed_forward=feed_forward)
+    kinds = (names.LINEAR, names.FULL)
+    if feed_forward == names.EXPERT_LAYER:
+        arms = dict(feed_forward=names.EXPERT_SHARE, one_sublayer=True,
+                    latent_width=24, shared_scored=False)
+        kinds = (names.FULL, names.EXPERT_LAYER)
     hybrid = HybridLM(
-        vocab=64, layer_types=(names.LINEAR, names.FULL), dtype=jnp.bfloat16,
+        vocab=64, layer_types=kinds, dtype=jnp.bfloat16,
         remat=remat, sizes=HybridSizes(
             d_model=32, n_heads=2, n_kv_heads=1, head_dim=16, rotary_dim=4,
             linear_key_heads=1, linear_value_heads=2, linear_key_dim=8,
-            linear_value_dim=8, feed_forward=feed_forward, ffn_width=48,
-            n_experts=4, held=2, top_k=2, expert_width=16, shared_width=16))
+            linear_value_dim=8, ffn_width=48,
+            n_experts=4, held=2, top_k=2, expert_width=16, shared_width=16,
+            **arms))
     tokens = jnp.zeros((2, 64), jnp.int32)
     session = telemetry.start(tmp_path / "tele", rank=0, generation=0)
     try:

@@ -525,7 +525,7 @@ class ExpertShare(nn.Module):
                     names.LATENT_PROJ):
                 inside = _dense(z.latent_width, "latent_down",
                                 self.dtype)(tokens)
-        y, counts = expert_share(
+        y, counts, windows = expert_share(
             params, inside, n_experts=z.n_experts, held=e,
             first_expert=z.first_expert, k=z.top_k, expert_fn=expert_fn,
             router_input=rows, scoring=z.scoring, scale=z.routed_scale)
@@ -540,6 +540,9 @@ class ExpertShare(nn.Module):
         # assignments per held expert: collected by train steps built with
         # ``aux=True`` (make_lm_train_step), one row a layer
         self.sow("intermediates", "moe_expert_tokens", counts)
+        # and the windows each block of tokens took (1 where the share
+        # keeps one buffer), one row a layer
+        self.sow("intermediates", "moe_windows", windows)
         return y.reshape(b, s, d)
 
 
@@ -615,9 +618,16 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
     feed-forward's three products (its input IS ``MIXER_OUT``), so that no
     product of the feed-forward runs twice a step.  The expert-share arm
     recomputes its feed-forward: the buffers of its dispatch are many times
-    a layer's activations.  A layer of one sublayer keeps its input alone."""
+    a layer's activations.  A layer of one sublayer keeps its input and,
+    where it is an expert layer whose share takes its arrivals through
+    windows (:func:`tpudist.parallel.moe.share_windows`; only such a share
+    names it), the share's result ``names.EXPERT_OUT``, ``tokens x
+    (latent_width or d_model) x itemsize`` bytes: what follows the share
+    needs it for its gradient, and the share's loop run again for it is
+    one the compiler cannot merge with the backward pass's own."""
     if sizes.one_sublayer:
-        return ()
+        return ((names.EXPERT_OUT,)
+                if sizes.feed_forward == names.EXPERT_SHARE else ())
     if sizes.feed_forward == names.DENSE_FFN:
         return (names.MIXER_OUT,) + names.DENSE_FFN_KEEPS
     return (names.MIXER_OUT,)
@@ -629,7 +639,8 @@ def kept_bytes(keep: tuple, sizes: HybridSizes, tokens: int, dtype) -> int:
     ``dtype``."""
     columns = {names.MIXER_OUT: sizes.d_model,
                names.FFN_GATE: sizes.ffn_width,
-               names.FFN_UP: sizes.ffn_width, names.FFN_OUT: sizes.d_model}
+               names.FFN_UP: sizes.ffn_width, names.FFN_OUT: sizes.d_model,
+               names.EXPERT_OUT: sizes.latent_width or sizes.d_model}
     return tokens * jnp.dtype(dtype).itemsize * sum(
         columns[name] for name in keep)
 
@@ -648,7 +659,8 @@ class HybridLM(nn.Module):
     # under every policy, a layer keeps what :func:`remat_keeps` names:
     # ``mixer_out`` (``tokens x d_model x itemsize`` bytes a layer) and, with
     # a dense feed-forward, its three products' outputs (``tokens x (2 x
-    # ffn_width + d_model) x itemsize`` more), so its forward runs once
+    # ffn_width + d_model) x itemsize`` more), so its forward runs once; an
+    # expert layer of one sublayer the result of a share that goes by windows
     remat_policy: str = "nothing"
 
     @nn.compact

@@ -8,7 +8,10 @@ computes its part.
 - :func:`expert_share`, the **dropless share**: a device that is told which
   ``held`` of the router's ``n_experts`` experts it holds (``first_expert``
   on) routes every token over all of them and computes the part of the
-  result its own experts give, whatever the load.  What the absent experts
+  result its own experts give, whatever the load: through ONE buffer of
+  all its assignments, or, where it holds a 32nd of the experts or less,
+  through windows over the rows that arrived (:func:`share_windows`).
+  What the absent experts
   would add is left out: the partial sum is what goes on when a chip runs
   alone as one member of an expert-parallel group.  Nothing stands in for
   the absent chips; on one chip the layer runs without its exchange, and
@@ -41,6 +44,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 from tpudist import telemetry
@@ -292,18 +296,44 @@ EXPERT_LEAVES = {names.GATED_SILU: ("gate", "up", "down"),
 #: tokens whose picks :func:`expert_share` takes through its buffers at a
 #: time: the buffers hold ``SHARE_BLOCK_TOKENS * k`` rows
 SHARE_BLOCK_TOKENS = 8192
+#: a window of a share that takes its arrivals through windows
+#: (:func:`share_windows`), in even shares of a block's assignments
+WINDOW_EVEN_SHARES = 8
+#: what a row scattered costs on the chip, in rows gathered (PR 30: 75 ns
+#: against 20): windows, which scatter what they computed, are taken where
+#: this many of them are no more than the one buffer of the bound
+SCATTER_PER_GATHER = 4
 
 
-def _plan(local, held: int):
+def share_windows(block_tokens: int, k: int, held: int,
+                  n_experts: int) -> tuple[int, int]:
+    """``(window_rows, windows_at_most)`` of a block of ``block_tokens``
+    tokens' ``k`` picks at a member that holds ``held`` of ``n_experts``
+    experts: the rows of a window, :data:`WINDOW_EVEN_SHARES` times the
+    block's even share rounded up to a multiple of 512, and how many of
+    them the block's ``block_tokens * k`` assignments fill if every pick is
+    held here.  Where :data:`SCATTER_PER_GATHER` windows would pass the
+    bound (``held / n_experts`` over 1/32) the layer keeps ONE buffer of
+    the bound: ``(block_tokens * k, 1)``."""
+    bound = block_tokens * k
+    rows = -(-WINDOW_EVEN_SHARES * bound * held // (512 * n_experts)) * 512
+    if SCATTER_PER_GATHER * rows > bound:
+        return bound, 1
+    return rows, -(-bound // rows)
+
+
+def _plan(local, held: int, positions: bool = True):
     """For a block's picks ``local [t, k]`` (the held expert's number, or
     ``held`` where the expert is absent): ``order [t * k]``, the
     assignment in each buffer row, sorted by held expert with the absent
     ones behind all held ones, and ``token``, its token; ``pos [t, k]``,
-    each assignment's row; ``counts [held]``, the rows of each expert."""
+    each assignment's row (a second sort: None unless ``positions``);
+    ``counts [held]``, the rows of each expert."""
     k = local.shape[1]
     key = local.reshape(-1)
     order = jnp.argsort(key).astype(jnp.int32)
-    pos = jnp.argsort(order).astype(jnp.int32).reshape(local.shape)
+    pos = (jnp.argsort(order).astype(jnp.int32).reshape(local.shape)
+           if positions else None)
     counts = jnp.sum(key[:, None] == jnp.arange(held)[None], axis=0,
                      dtype=jnp.int32)
     return order // k, order, pos, counts
@@ -348,7 +378,10 @@ def _grouped(expert_fn, counts):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _held_experts(experts, x, weights, local, held, expert_fn):
     """``y [t, d]`` f32: for every token of a block the weighted results of
-    its picks among the ``held`` experts here.
+    its picks among the ``held`` experts here, through ONE buffer of the
+    bound: the path of a member that holds more than a 32nd of the
+    experts (:func:`share_windows`; :func:`_held_experts_windowed` is the
+    other).
 
     All ``t * k`` assignments go through a buffer of as many rows, sorted
     by held expert: the tokens' rows are gathered into it, one grouped
@@ -357,10 +390,12 @@ def _held_experts(experts, x, weights, local, held, expert_fn):
     back and adds up those of held experts, pick-major (:func:`_combine`:
     ``k`` slabs of ``[t, d]``, never a ``[t, k, d]`` tensor, whose ten in
     second-minor place the ``(8, 128)`` tile would pay as sixteen).  So
-    the gathers cost the same whatever the router does, the products go
-    with the rows that arrived, nothing is dropped, and the layer has no
-    buffer to outgrow.  (Gathers, not scatter-adds: on the TPU a row
-    scattered costs four of a row gathered.)
+    on this path the gathers cost the same whatever the router does, the
+    products go with the rows that arrived, nothing is dropped, and the
+    layer has no buffer to outgrow.  (Gathers, not scatter-adds: on the
+    TPU a row scattered costs four of a row gathered, so a path that
+    scatters what arrived wins only while it moves under a quarter of
+    the bound's rows.)
 
     The grouped products define only the rows of their groups: behind the
     last arrival a row of their result, and of their cotangents, is
@@ -403,14 +438,143 @@ def _held_experts_bwd(held, expert_fn, residuals, dy):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+def _windows(local, held: int, expert_fn, window_rows: int):
+    """A windowed block's loop over :func:`_plan`'s order, whose first
+    ``n_live`` rows are the ones that arrived (the held experts' runs
+    lead): ``more(carry)``, whether trip ``carry[0]`` has rows to take, and
+    ``window(i)``, the ``i``-th window of ``window_rows`` rows: its
+    assignments ``at``, their tokens, the experts as a grouped product
+    over the part of each expert's run that lies in the window, and the
+    rows ``live`` that this trip computes."""
+    k = local.shape[1]
+    _, order, _, counts = _plan(local, held, positions=False)
+    ends = jnp.cumsum(counts)
+    n_live = ends[-1]
+    # the last window of a bound that is no multiple of a window starts
+    # early and overlaps the one before it: the rows before ``lo`` are that
+    # trip's
+    last = order.shape[0] - window_rows
+
+    def window(i):
+        lo = i * window_rows
+        start = jnp.minimum(lo, last)
+        at = lax.dynamic_slice(order, (start,), (window_rows,))
+        cuts = jnp.clip(ends, start, start + window_rows)
+        sizes = jnp.diff(cuts, prepend=start)
+        row = start + jnp.arange(window_rows, dtype=jnp.int32)
+        live = ((row >= lo) & (row < n_live))[:, None]
+        return at, at // k, _grouped(expert_fn, sizes), live
+
+    return lambda carry: carry[0] * window_rows < n_live, window
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _held_experts_windowed(experts, x, weights, local, held, expert_fn,
+                           window_rows):
+    """``y [t, d]`` f32 as :func:`_held_experts` gives it, by windows over
+    the sorted assignments: the rows that arrived are the prefix
+    ``[0, n_live)`` of the plan's order, and a ``lax.while_loop`` takes
+    them ``window_rows`` at a time, as many trips as the arrivals fill
+    (none where nothing arrived).  A trip gathers its rows' tokens into a
+    ``[window_rows, d]`` buffer, runs the grouped products over the part of
+    each expert's run that lies in the window, weighs the rows in float32
+    and scatter-adds them into ``y``; a token's picks add up in the order
+    of the sort.  Nothing is dropped: if every pick is held here the loop
+    runs over all ``t * k`` rows.  No tensor of ``t * k`` rows is built
+    but the sort's own.
+
+    The masking rule is :func:`_held_experts`': a row of the window at or
+    behind ``n_live`` is undefined in the products' result and in their
+    cotangents, and the ``where`` over ``live`` leaves it out, forward and
+    backward."""
+    more, window = _windows(local, held, expert_fn, window_rows)
+    flat_weights = weights.reshape(-1)
+
+    def trip(carry):
+        i, y = carry
+        at, token, grouped, live = window(i)
+        with jax.named_scope(names.MOE_COMBINE):
+            rows = _rows(x, token)
+        with jax.named_scope(names.EXPERTS):
+            out = grouped(experts, rows)
+        with jax.named_scope(names.MOE_COMBINE):
+            out = out.astype(jnp.float32) * _rows(flat_weights, at)[:, None]
+            y = y.at[token].add(jnp.where(live, out, 0.0),
+                                mode="promise_in_bounds")
+        return i + 1, y
+
+    return lax.while_loop(
+        more, trip, (jnp.int32(0), jnp.zeros(x.shape, jnp.float32)))[1]
+
+
+def _held_experts_windowed_fwd(experts, x, weights, local, held, expert_fn,
+                               window_rows):
+    y = _held_experts_windowed(experts, x, weights, local, held, expert_fn,
+                               window_rows)
+    return y, (experts, x, weights, local)
+
+
+def _held_experts_windowed_bwd(held, expert_fn, window_rows, residuals, dy):
+    """The same loop (the products are recomputed, nothing of a block is
+    kept but its inputs): a trip gathers its rows' tokens and their
+    cotangents, pulls the rows' cotangent back through the products,
+    scatter-adds the rows' input gradients into ``d_x`` in float32 and
+    each row's weight gradient to its assignment.  The experts' gradients
+    add up over the trips in float32 and are rounded once."""
+    experts, x, weights, local = residuals
+    more, window = _windows(local, held, expert_fn, window_rows)
+    flat_weights = weights.reshape(-1)
+    dy = dy.astype(x.dtype)             # as the products take it
+
+    def trip(carry):
+        i, d_experts, d_x, d_weights = carry
+        at, token, grouped, live = window(i)
+        with jax.named_scope(names.MOE_COMBINE):
+            rows = _rows(x, token)
+            dy_rows = _rows(dy, token).astype(jnp.float32)
+        with jax.named_scope(names.EXPERTS):
+            out, pull = jax.vjp(grouped, experts, rows)
+        d_weight_rows = jnp.sum(dy_rows * out.astype(jnp.float32), axis=-1)
+        d_out = jnp.where(live, dy_rows * _rows(flat_weights, at)[:, None],
+                          0.0)
+        with jax.named_scope(names.EXPERTS):
+            d_trip, d_rows = pull(d_out.astype(out.dtype))
+        d_experts = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
+                                 d_experts, d_trip)
+        with jax.named_scope(names.MOE_COMBINE):
+            d_x = d_x.at[token].add(
+                jnp.where(live, d_rows.astype(jnp.float32), 0.0),
+                mode="promise_in_bounds")
+            # the rows of a window are assignments of their own
+            d_weights = d_weights.at[at].add(
+                jnp.where(live[:, 0], d_weight_rows, 0.0),
+                mode="promise_in_bounds", unique_indices=True)
+        return i + 1, d_experts, d_x, d_weights
+
+    _, d_experts, d_x, d_weights = lax.while_loop(
+        more, trip,
+        (jnp.int32(0),
+         jax.tree.map(lambda w: jnp.zeros(w.shape, jnp.float32), experts),
+         jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros(flat_weights.shape, jnp.float32)))
+    return (jax.tree.map(lambda g, w: g.astype(w.dtype), d_experts, experts),
+            d_x.astype(x.dtype),
+            d_weights.reshape(weights.shape).astype(weights.dtype), None)
+
+
+_held_experts_windowed.defvjp(_held_experts_windowed_fwd,
+                              _held_experts_windowed_bwd)
+
+
 def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
                  first_expert: int, k: int,
                  expert_fn: ExpertFn = gated_ffn,
                  router_input: jax.Array | None = None,
                  block_tokens: int = SHARE_BLOCK_TOKENS,
                  scoring: str = names.SOFTMAX, scale: float = 1.0):
-    """This device's part of one routed-expert layer, dropless:
-    ``(y [tokens, d] in x's dtype, assignments per held expert [held])``.
+    """This device's part of one routed-expert layer, dropless: ``(y
+    [tokens, d] in x's dtype, assignments per held expert [held], windows
+    taken a block [blocks])``.
 
     ``params = {"router": [d_router, n_experts] f32, "experts": a pytree
     with a leading axis over the ``held`` experts held (``first_expert``
@@ -426,24 +590,52 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
     experts compute in ``x``'s dtype.  ``shared``
     is ``sigmoid(x . score) * E_shared(x)``, what every member of the
     group computes alike.  The tokens are taken in equal blocks of at most
-    ``block_tokens``, one after another, so that the buffers hold
-    ``block_tokens * k`` rows and not ``tokens * k``; the result does not
-    depend on it.
+    ``block_tokens``, one after another; the result does not depend on it.
+
+    Which of two paths a block takes is decided at trace time from the
+    layer's shapes alone (:func:`share_windows`).  A member that holds
+    more than a 32nd of the experts keeps ONE buffer of the bound,
+    ``block_tokens * k`` rows (:func:`_held_experts`): its gathers cost
+    the same whatever the router does and it has no buffer to outgrow.
+    One that holds a 32nd or less takes the rows that arrived through
+    windows of eight even shares (:func:`_held_experts_windowed`): one
+    trip of a loop at an even load, as many as the arrivals fill
+    otherwise, dropless alike, and its cost goes with what arrived in
+    steps of a window; its result carries the name ``names.EXPERT_OUT``
+    for a rematerialised caller to keep.  The threshold is the measured
+    cost of a scattered row in gathered rows on the chip
+    (:data:`SCATTER_PER_GATHER`, 4 : 1): a window is scattered into the
+    result where the one buffer is gathered out of, so windows win only
+    under a quarter of the bound.  (A share whose router drifts towards
+    its own experts, as a lone member's trained router does, may fill
+    many windows: what such a member's router sees is its benchmark
+    cell's question, and its answer may move that layer under the
+    threshold later.)
+
+    Returns besides ``y`` and the assignments: ``[blocks]`` int32, the
+    windows each block's arrivals took (1 where the layer keeps one
+    buffer).
 
     Runs under the scope ``names.MOE``, the grouped products under
     ``names.EXPERTS``, the combine (forward, and the tokens' input
-    gradients backward) under ``names.MOE_COMBINE``, the shared expert
-    under ``names.SHARED_EXPERT``.
+    gradients backward; a windowed block's gathers and scatter-adds)
+    under ``names.MOE_COMBINE``, the shared expert under
+    ``names.SHARED_EXPERT``.
     Shard-local: inside a multi-device program call it under ``shard_map``.
     """
     tokens, d = x.shape
     blocks = max(1, -(-tokens // block_tokens))
     while tokens % blocks:
         blocks += 1
+    window_rows, windows_at_most = share_windows(tokens // blocks, k, held,
+                                                 n_experts)
+    windowed = windows_at_most > 1
     telemetry.event(names.MOE_LAYOUT, experts=n_experts, held=held,
                     first=first_expert, top_k=k, dropless=True,
                     buffer_rows=tokens // blocks * k, blocks=blocks,
-                    combine=names.PICK_MAJOR, scoring=scoring, scale=scale,
+                    window_rows=window_rows, windows_at_most=windows_at_most,
+                    combine=names.SCATTER_ADD if windowed
+                    else names.PICK_MAJOR, scoring=scoring, scale=scale,
                     width=d)
     with jax.named_scope(names.MOE):
         scored = x if router_input is None else router_input
@@ -457,8 +649,14 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
                          == jnp.arange(held)[None], axis=0, dtype=jnp.int32)
         experts = jax.tree.map(lambda w: w.astype(x.dtype),
                                params["experts"])
+        if windowed:
+            block_fn = lambda block: _held_experts_windowed(
+                experts, *block, held, expert_fn, window_rows)
+        else:
+            block_fn = lambda block: _held_experts(experts, *block, held,
+                                                   expert_fn)
         y = lax.map(
-            lambda block: _held_experts(experts, *block, held, expert_fn),
+            block_fn,
             jax.tree.map(lambda a: a.reshape(blocks, -1, *a.shape[1:]),
                          (x, routing.weights, routing.local))
         ).reshape(tokens, d)
@@ -470,4 +668,10 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
                     x, shared.pop("score"),
                     preferred_element_type=jnp.float32))
                 y = y + score * expert_fn(shared, x).astype(jnp.float32)
-        return y.astype(x.dtype), counts
+        y = y.astype(x.dtype)
+        if not windowed:
+            return y, counts, jnp.ones((blocks,), jnp.int32)
+        arrived = jnp.sum((routing.local < held).reshape(blocks, -1), axis=1,
+                          dtype=jnp.int32)
+        return (checkpoint_name(y, names.EXPERT_OUT), counts,
+                -(-arrived // window_rows))

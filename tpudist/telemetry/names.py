@@ -97,6 +97,13 @@ FFN_GATE = "ffn_gate"
 FFN_UP = "ffn_up"
 FFN_OUT = "ffn_out"
 DENSE_FFN_KEEPS = (FFN_GATE, FFN_UP, FFN_OUT)
+# tpudist/parallel/moe.py: the result of an expert share that takes its
+# arrivals through windows, ``[tokens, width]`` in the compute dtype (the
+# latent width where the layer has one).  A layer of one sublayer keeps it:
+# the projection behind the share needs it for its weight gradient, and the
+# share's loop, run again for it, could not be merged with the backward
+# pass's own as straight-line code is
+EXPERT_OUT = "expert_out"
 
 # -- spans (tpudist.telemetry.span / record_span) -----------------------------
 STEP = "step"            # one arrival of a step's result to the next
@@ -154,18 +161,25 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # ``feed_forward=`` EXPERT_SHARE / DENSE_FFN; ``norm=`` ZERO_CENTRED / PLAIN
 # and ``norm_after=`` whether it follows its sublayer; ``remat_keeps=`` the
 # names a rematerialised layer keeps besides its input (MIXER_OUT, and
-# DENSE_FFN_KEEPS in the dense arm; ``[]`` without remat) and
+# DENSE_FFN_KEEPS in the dense arm; EXPERT_OUT in an expert layer of one
+# sublayer; ``[]`` without remat) and
 # ``remat_kept_bytes_per_layer=`` what they hold.  And of each expert
 # layer
 # (tpudist/parallel/moe.py): ``experts=`` the router's width, ``held=``,
-# ``first=``, ``top_k=``, ``dropless=``, ``buffer_rows=``, ``blocks=``,
-# ``combine=`` PICK_MAJOR (each token's ``k`` rows are added up as ``k``
-# slabs of [tokens, d]), ``scoring=`` SOFTMAX / SIGMOID_BIAS, ``scale=`` what
+# ``first=``, ``top_k=``, ``dropless=``, ``buffer_rows=`` (the bound: a
+# block's assignments), ``blocks=``, ``window_rows=`` the rows of a window
+# where the share takes its arrivals through windows (the bound itself
+# where it keeps one buffer) and ``windows_at_most=`` how many of them the
+# bound fills (1), ``combine=`` PICK_MAJOR (each token's ``k`` rows are
+# added up as ``k`` slabs of [tokens, d]) or SCATTER_ADD (a window's rows
+# are added into their tokens'), ``scoring=`` SOFTMAX / SIGMOID_BIAS,
+# ``scale=`` what
 # the picks' renormalised weights are multiplied by, ``width=`` the rows'
 # width (an expert layer's latent width where it has one)
 MIXER_LAYOUT = "mixer_layout"
 MOE_LAYOUT = "moe_layout"
 PICK_MAJOR = "pick_major"
+SCATTER_ADD = "scatter_add"
 LINEAR = "linear_attention"
 FULL = "full_attention"
 STATE_SPACE = "state_space"  # a Mamba-2 mixer (tpudist/ops/ssd.py)

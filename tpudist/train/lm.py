@@ -182,7 +182,9 @@ def make_lm_train_step(
     (``moe_dropped_fraction`` scalar, ``moe_expert_load`` ``[n_experts]``,
     ``moe_balance_loss`` scalar; from a dropless share layer
     ``moe_expert_tokens`` ``[layers, held]``, the assignments each held
-    expert got this step) — empty when the model sows nothing.
+    expert got this step, and ``moe_windows`` ``[layers, blocks]``, the
+    windows each block of tokens' arrivals took) — empty when the model
+    sows nothing.
     Requires ``apply_fn`` to accept flax's ``mutable=`` kwarg (i.e. a
     ``Module.apply``).
 
@@ -248,13 +250,14 @@ def make_lm_train_step(
             for name, vals in by_name.items()
         }
         # a dropless share layer (tpudist.models.hybrid) sows the
-        # assignments each held expert got: kept a row a layer, not averaged
-        tokens = [leaf for path, leaf in
-                  jax.tree_util.tree_flatten_with_path(inters)[0]
-                  if any(getattr(e, "key", None) == "moe_expert_tokens"
-                         for e in path)]
-        if tokens:
-            out["moe_expert_tokens"] = jnp.stack(tokens)
+        # assignments each held expert got and the windows each block of
+        # tokens took: kept a row a layer, not averaged
+        for name in ("moe_expert_tokens", "moe_windows"):
+            rows = [leaf for path, leaf in
+                    jax.tree_util.tree_flatten_with_path(inters)[0]
+                    if any(getattr(e, "key", None) == name for e in path)]
+            if rows:
+                out[name] = jnp.stack(rows)
         return out
 
     def grad_of(params, toks):
