@@ -6,8 +6,9 @@ ranks, all restart generations), and produces
 - ``report.json`` — machine-readable: wall-clock, step-time
   p50/p95/max, a goodput breakdown whose components sum to wall-clock
   (step / compile / data / ckpt / comm / init / other / idle /
-  lost_restart), per-rank rows for straggler hunting, the StageTimer
-  phase durations, and the joined fault/watchdog/retry event log;
+  lost_restart), the programs the compile component went to
+  (``compile_programs``), per-rank rows for straggler hunting, the
+  StageTimer phase durations, and the joined fault/watchdog/retry event log;
 - ``report.md`` — the same, human-readable.
 
 Attribution rules (the math the tests pin down):
@@ -41,6 +42,8 @@ from __future__ import annotations
 import json
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
+
+from tpudist.telemetry import names
 
 #: span name → goodput component; unmapped top-level spans land in "other".
 #: ``metric_flush`` (the blocking loss fetch) counts as step time: it waits
@@ -799,6 +802,41 @@ def _serving_summary(records: List[dict]) -> Optional[dict]:
     }
 
 
+#: how many programs the ``compile`` component is itemised by
+_COMPILE_PROGRAMS = 5
+
+
+def _compile_programs(records: List[dict], num_ranks: int) -> List[dict]:
+    """What the ``compile`` component went to, by program: the
+    ``_COMPILE_PROGRAMS`` that cost most in the ``xla_*`` spans' ``fun=``
+    (``tpudist/runtime/compilation_cache.py``), as across-rank means.  A
+    program's trace holds its nested traces, which are also listed under
+    their own names; ``cache`` counts its backend compiles by what they
+    were (hit: a load of ``load_s``; ``cold_s`` is what they cost without
+    the cache)."""
+    rows: Dict[str, dict] = {}
+    for r in records:
+        if r.get("kind") != "span" or "fun" not in r \
+                or r.get("parent") != names.XLA_PARENT:
+            continue
+        row = rows.setdefault(r["fun"], {
+            "fun": r["fun"], "trace_lower_s": 0.0, "compile_or_load_s": 0.0,
+            "load_s": 0.0, "cold_s": 0.0, "cache": {}})
+        dur = float(r.get("dur", 0.0)) / num_ranks
+        if r["name"] == names.XLA_BACKEND_COMPILE:
+            row["compile_or_load_s"] += dur
+            row["load_s"] += float(r.get("load_s", 0.0)) / num_ranks
+            row["cold_s"] += float(r.get("cold_s", 0.0)) / num_ranks
+            cache = r.get("cache", names.UNCACHED)
+            row["cache"][cache] = row["cache"].get(cache, 0) + 1
+        else:
+            row["trace_lower_s"] += dur
+    top = sorted(rows.values(), key=lambda row: -(
+        row["trace_lower_s"] + row["compile_or_load_s"]))[:_COMPILE_PROGRAMS]
+    return [{k: round(v, 6) if isinstance(v, float) else v
+             for k, v in row.items()} for row in top]
+
+
 def aggregate_run(run_dir: "str | Path") -> dict:
     """Merge a run's telemetry into the report dict (see module doc)."""
     records = load_records(run_dir)
@@ -902,6 +940,11 @@ def aggregate_run(run_dir: "str | Path") -> dict:
         "events": events,
         **({"telemetry_dropped": dropped} if have_drops else {}),
     }
+    # Additive like the sections below: absent for streams from before
+    # the ``xla_*`` spans said their program.
+    programs = _compile_programs(records, n)
+    if programs:
+        report["compile_programs"] = programs
     serving = _serving_summary(records)
     if serving is not None:
         report["serving"] = serving
@@ -954,6 +997,18 @@ def render_markdown(report: dict) -> str:
     lines.append(f"| **total** | {report['goodput_sum_s']:.3f} | "
                  f"{report['goodput_sum_s'] / report['wall_clock_s'] * 100:.1f}% |"
                  if report["wall_clock_s"] > 0 else "| **total** | 0 | - |")
+    if report.get("compile_programs"):
+        lines += ["", "### compile: the programs that cost most", "",
+                  "| program | trace + lower s | compile or load s | cache "
+                  "| load s | cold compile s |",
+                  "|---|---:|---:|---|---:|---:|"]
+        for row in report["compile_programs"]:
+            cache = ", ".join(f"{k} x{n}" for k, n in
+                              sorted(row["cache"].items())) or "-"
+            lines.append(
+                f"| {row['fun']} | {row['trace_lower_s']:.3f} | "
+                f"{row['compile_or_load_s']:.3f} | {cache} | "
+                f"{row['load_s']:.3f} | {row['cold_s']:.3f} |")
     sg = report["stragglers"]
     lines += ["", "## Per-rank", "",
               f"straggler: rank {sg['max_step_rank']} spent "

@@ -124,6 +124,29 @@ XLA_DURATION_SPANS = {
 }
 #: the ``parent`` every ``xla_*`` span carries, so that none enters a goodput sum
 XLA_PARENT = "xla"
+# every ``xla_*`` span says which program it was for as ``fun=``: JAX's own
+# ``fun_name`` with ``jit(...)`` stripped, so one program has one name across
+# its trace, its lowering and its compile.  A nested trace carries its own
+# name (``sin``, ``_flash_forward``) and lies inside its caller's duration.
+#: what ``tpudist/train/lm.py`` calls the function it jits: the LM step
+STEP_PROGRAM = "step"
+# ``xla_backend_compile`` (JAX reports a load from the persistent cache and a
+# compile under the one duration event) also says which it was as ``cache=``:
+# CACHE_HIT with ``load_s=`` the retrieval, CACHE_MISS (compiled and written),
+# or UNCACHED (compiled and not written: the cache is off, or the program is
+# under its 0.5 s floor); and ``cold_s=``, what compiling it costs without
+# the cache: the span's own duration where it compiled, the compile time the
+# entry saved + its retrieval where it loaded (JAX keeps an entry's compile
+# time in whole seconds, cut off)
+CACHE_HIT = "hit"
+CACHE_MISS = "miss"
+UNCACHED = "uncached"
+#: ``jax.monitoring`` duration events of a hit, told on the compiling thread
+#: just before the ``backend_compile_duration`` that encloses them: what the
+#: load took, and the entry's own compile time less that
+XLA_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+XLA_CACHE_TIME_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+XLA_CACHE_DURATIONS = (XLA_CACHE_RETRIEVAL, XLA_CACHE_TIME_SAVED)
 #: the ``StepTraceAnnotation`` of the training loops
 STEP_ANNOTATION = "train"
 
@@ -204,6 +227,8 @@ XLA_CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": COMPILE_CACHE_HIT,
     "/jax/compilation_cache/cache_misses": COMPILE_CACHE_MISS,
 }
+#: ... -> the ``cache=`` an ``xla_backend_compile`` span says for it
+XLA_CACHE_TAGS = {COMPILE_CACHE_HIT: CACHE_HIT, COMPILE_CACHE_MISS: CACHE_MISS}
 
 
 def kernel(name: str) -> dict:
