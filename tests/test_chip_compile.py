@@ -571,6 +571,29 @@ def test_olmo_hybrid_cell_step_carries_exactly_the_three_flash_kernels(
     assert "all-reduce" not in text and "all-gather" not in text
 
 
+@pytest.mark.parametrize("fixture, arguments, temporaries, instructions", [
+    ("hybrid_step", 7_508_078_592, 7_438_718_464, 24_035),
+    ("olmo_hybrid_step", 9_195_254_272, 5_077_698_560, 18_651)],
+    ids=["share_cell", "olmo_cell"])
+def test_the_other_pattern_cells_steps_are_what_they_were(
+        request, fixture, arguments, temporaries, instructions):
+    """Both run ``remat_keeps`` and the share cell ``ExpertShare`` and
+    ``expert_share``, where PR 40 names the outputs of an expert layer's
+    dense products for a ONE-sublayer layer to keep: a name no policy
+    names is an identity the compiler drops, so their steps hold the bytes
+    and the instructions to the last one that PR 39's held (their
+    optimized HLO was the parent's text for text once source locations
+    were stripped: PERF.md section 6, PR 40)."""
+    import re
+
+    step, job, m = request.getfixturevalue(fixture)
+    mem = step.memory_analysis()
+    assert mem.argument_size_in_bytes == arguments
+    assert mem.temp_size_in_bytes == temporaries
+    assert len(re.findall(r"^\s+(?:ROOT )?%[\w.\-]+ = ", step.as_text(),
+                          flags=re.M)) == instructions
+
+
 @pytest.fixture(scope="module")
 def nemotron_step(topo):
     """The whole train step of cell ``nemotron3super-train-tp8ep64share-8k``
@@ -589,16 +612,21 @@ def test_nemotron_cell_step_fills_one_chip_and_fits(nemotron_step):
     # 700,862,960 parameters (and 5 x 512 numbers of choice bias) x 12
     # bytes resident
     assert 8.40e9 < mem.argument_size_in_bytes < 8.42e9
-    # 10.59 GiB = 11.37 GB: temporaries 2,962,675,200 bytes, the float32
+    # 11.24 GiB = 12.07 GB: temporaries 3,660,219,904 bytes, the float32
     # gradient (2.80 GB) among them.  An expert layer takes what arrived
     # through windows of 22,528 rows (46 MB at 1,024 wide, 121 MB at 2,688)
-    # and keeps its share's result (16.8 MB a layer) from forward to
-    # backward; PR 37's step, which ran all 180,224 assignments a layer
-    # through buffers of 369 MB and 969 MB, held 11.81 GiB (temporaries
-    # 4,271,943,680).  The issue's ceiling is 15.0 GiB, the compiler allows
-    # 15.75
-    assert 11.2e9 < held < 15.0 * 2 ** 30
-    assert mem.temp_size_in_bytes <= 4_271_943_680 - 1.2e9
+    # and keeps from forward to backward its share's result and, since PR
+    # 40, the outputs of the dense products its backward pass reads (the
+    # router's logits, ``latent_down``'s, the shared expert's ``up``: 138.4
+    # MB a layer, 0.61 GB more in all, of which 0.70 GB show at the
+    # compiler's peak); PR 38's step, which ran those three products twice,
+    # held 10.59 GiB (temporaries 2,962,675,200); PR 37's, which ran all
+    # 180,224 assignments a layer through buffers of 369 MB and 969 MB,
+    # 11.81 GiB (4,271,943,680).  The issue's ceiling is 15.0 GiB, the
+    # compiler allows 15.75
+    assert 11.9e9 < held < 11.5 * 2 ** 30 < 15.0 * 2 ** 30
+    assert 2_962_675_200 + 0.6e9 <= mem.temp_size_in_bytes <= (
+        2_962_675_200 + 0.8e9)
 
 
 def test_nemotron_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
@@ -612,9 +640,14 @@ def test_nemotron_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
     two loops a layer, forward and backward (2 + 1 and 6 + 2 calls): the
     rematerialised forward's loop is dead, because the layer keeps the
     share's result, so the step counts the 59 calls the benchmark's
-    runner holds it to.  The state-space scan is plain XLA.  No
-    collective: one chip's share."""
+    runner holds it to.  The expert layers' dense products whose outputs
+    the layer keeps run once: five layers x (``up`` forward and two
+    backward, ``down`` forward and two backward) products under the shared
+    expert's scope, 30, and not the 35 of a step that runs ``up`` again.
+    The state-space scan is plain XLA.  No collective: one chip's share."""
     import re
+
+    from tpudist.telemetry import names
 
     step, job, m = nemotron_step
     text = step.as_text()
@@ -629,3 +662,7 @@ def test_nemotron_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
     assert "[180224,2688]" not in text and "[180224,1024]" not in text
     assert job["collectives_in_step"] == []
     assert "all-reduce" not in text and "all-gather" not in text
+    shared = [line for line in text.splitlines()
+              if re.search(r" convolution\(", line)
+              and f"/{names.SHARED_EXPERT}/" in line]
+    assert len(shared) == 5 * 6

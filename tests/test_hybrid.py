@@ -613,6 +613,113 @@ def test_a_share_of_a_32nd_or_less_takes_windows_and_no_other(held, windows):
         assert {(t * k, d), (t * k, width), (k, t, d)} <= shapes
 
 
+# ---------------------------------------------------------------------------
+# what a rematerialised expert layer of one sublayer keeps
+
+
+def _sizes(**arms) -> hybrid.HybridSizes:
+    return hybrid.HybridSizes(
+        d_model=32, n_heads=2, n_kv_heads=1, head_dim=16, rotary_dim=4,
+        linear_key_heads=1, linear_value_heads=2, linear_key_dim=8,
+        linear_value_dim=8, ffn_width=48, n_experts=8, held=2, top_k=2,
+        expert_width=16, shared_width=40, **arms)
+
+
+ONE_EXPERT_LAYER = dict(one_sublayer=True, shared_scored=False)
+
+
+@pytest.mark.parametrize("arms, keeps, columns", [
+    (dict(ONE_EXPERT_LAYER, latent_width=24, expert_fn=names.RELU2),
+     (names.EXPERT_OUT, names.ROUTER_LOGITS, names.LATENT_IN,
+      names.SHARED_UP), 24 + 16 + 24 + 40),
+    (dict(ONE_EXPERT_LAYER, expert_fn=names.RELU2),
+     (names.EXPERT_OUT, names.ROUTER_LOGITS, names.SHARED_UP),
+     32 + 16 + 40),
+    (dict(ONE_EXPERT_LAYER, latent_width=24, expert_fn=names.GATED_SILU),
+     (names.EXPERT_OUT, names.ROUTER_LOGITS, names.LATENT_IN,
+      names.SHARED_GATE, names.SHARED_UP), 24 + 16 + 24 + 2 * 40),
+    (dict(ONE_EXPERT_LAYER, expert_fn=names.GATED_SILU),
+     (names.EXPERT_OUT, names.ROUTER_LOGITS, names.SHARED_GATE,
+      names.SHARED_UP), 32 + 16 + 2 * 40),
+    # a scored shared expert is computed inside ``expert_share``, unnamed
+    (dict(one_sublayer=True), (names.EXPERT_OUT, names.ROUTER_LOGITS),
+     32 + 16),
+    # and what was returned before for every other layer
+    (dict(), (MIXER_OUT,), 32),
+    (dict(expert_fn=names.RELU2), (MIXER_OUT,), 32),
+    (dict(feed_forward=names.DENSE_FFN),
+     (MIXER_OUT,) + names.DENSE_FFN_KEEPS, 32 + 2 * 48 + 32),
+    (dict(one_sublayer=True, feed_forward=names.DENSE_FFN), (), 0),
+], ids=["relu2_latent", "relu2", "gated_latent", "gated", "scored_shared",
+        "two_sublayer_share_arm", "two_sublayer_share_arm_relu2",
+        "dense_arm", "one_sublayer_mixers_only"])
+def test_remat_keeps_by_the_layers_shape(arms, keeps, columns):
+    """A one-sublayer expert layer keeps, beside its share's result, the
+    outputs of the dense products its backward pass reads: the router's
+    logits (float32: 8 experts are 16 bf16 columns), ``latent_down``'s
+    where there is a latent space, an unscored shared expert's first
+    products'.  Every other layer keeps what it kept.  ``kept_bytes`` over
+    64 tokens in bf16, by hand."""
+    z = _sizes(**arms)
+    assert hybrid.remat_keeps(z) == keeps
+    assert hybrid.kept_bytes(keeps, z, 64, jnp.bfloat16) == 64 * 2 * columns
+
+
+def _dense_products(jaxpr) -> int:
+    return sum(p == "dot_general" for p in _primitives(jaxpr))
+
+
+@pytest.mark.parametrize("arms", [
+    dict(expert_fn=names.GATED_SILU),
+    dict(expert_fn=names.GATED_SILU, latent_width=24),
+    dict(expert_fn=names.RELU2)], ids=["gated", "gated_latent", "relu2"])
+def test_a_rematerialised_expert_layer_runs_its_dense_products_once(
+        arms, monkeypatch):
+    """Two expert layers of one sublayer behind an attention layer, remat
+    ``nothing``: the gradient holds one ``dot_general`` fewer a layer for
+    each name kept than the program that keeps ``EXPERT_OUT`` alone, and
+    its bits are those of that program and of the program without remat (a
+    kept tensor is the one the rematerialised forward computes).  Bits in
+    float32: in bf16 the CPU's compiler widens the elementwise chains
+    inside a fusion, so there even remat alone, with nothing kept, moves
+    the last bit."""
+    z = _sizes(**ONE_EXPERT_LAYER, **arms)
+    kinds = (names.FULL, names.EXPERT_LAYER, names.EXPERT_LAYER)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 64), 0, 64)
+
+    def program(remat):
+        module = hybrid.HybridLM(vocab=64, layer_types=kinds, sizes=z,
+                                 remat=remat)
+        return lambda p: lm_loss(module.apply(p, tokens), tokens)
+
+    params = hybrid.HybridLM(vocab=64, layer_types=kinds, sizes=z).init(
+        jax.random.PRNGKey(0), tokens)
+    keeps = hybrid.remat_keeps(z)
+    new = [k for k in keeps if k != names.EXPERT_OUT]
+    assert len(new) == (2 if z.expert_fn == names.RELU2 else 3) + bool(
+        z.latent_width)
+    jaxpr = jax.make_jaxpr(jax.grad(program(True)))(params)
+    for name in new:
+        assert str(jaxpr).count(f"name={name}") >= 2
+    kept = jax.jit(jax.grad(program(True)))(params)
+    plain = jax.jit(jax.grad(program(False)))(params)
+    monkeypatch.setattr(hybrid, "remat_keeps", lambda z: (names.EXPERT_OUT,))
+    before = _dense_products(
+        jax.make_jaxpr(jax.grad(program(True)))(params).jaxpr)
+    assert before - _dense_products(jaxpr.jaxpr) == 2 * len(new)
+    recomputed = jax.jit(jax.grad(program(True)))(params)
+    for name in new:
+        # one at a time: each name is one product a layer
+        monkeypatch.setattr(hybrid, "remat_keeps",
+                            lambda z, name=name: (names.EXPERT_OUT, name))
+        one = jax.make_jaxpr(jax.grad(program(True)))(params)
+        assert _dense_products(one.jaxpr) == before - 2
+    for got, again, want in zip(*(jax.tree.leaves(t) for t in (
+            kept, recomputed, plain))):
+        assert got.dtype == want.dtype
+        assert bool(jnp.all(got == again)) and bool(jnp.all(got == want))
+
+
 def test_the_capacity_arm_routes_by_the_same_router():
     logits = jax.random.normal(jax.random.PRNGKey(0), (32, 8))
     routing = moe.route(logits, n_experts=8, k=2, held=2, first_expert=4)

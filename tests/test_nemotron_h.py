@@ -254,7 +254,9 @@ def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
     assert (z.n_heads, z.n_heads_total, z.n_kv_heads, z.head_dim) == (
         2, 16, 1, 16)
     assert (z.n_experts, z.held, z.first_expert, z.top_k) == (32, 4, 0, 6)
-    assert hybrid.remat_keeps(z) == (names.EXPERT_OUT,)
+    assert hybrid.remat_keeps(z) == (
+        names.EXPERT_OUT, names.ROUTER_LOGITS, names.LATENT_IN,
+        names.SHARED_UP)
 
 
 def test_logits_match_the_reference(f32_pair):
@@ -676,8 +678,12 @@ def test_the_layout_events_say_the_arms_and_the_share(tmp_path, f32_pair):
     # (the name is in the graph where a share takes windows; 4 of 32 held
     # keep one buffer)
     assert e["one_sublayer"] is True
-    assert e["remat_keeps"] == [names.EXPERT_OUT]
-    assert e["remat_kept_bytes_per_layer"] == 2 * 128 * 32 * 4
+    # the share's result and latent_down's (32 wide), the router's logits
+    # (32 experts), the shared expert's ``up`` (96 wide), all float32 here
+    assert e["remat_keeps"] == [names.EXPERT_OUT, names.ROUTER_LOGITS,
+                                names.LATENT_IN, names.SHARED_UP]
+    assert e["remat_kept_bytes_per_layer"] == 2 * 128 * (
+        32 + 32 + 32 + 96) * 4
     assert (e["ssm_heads"], e["ssm_groups"], e["ssm_head_dim"],
             e["ssm_state"], e["ssm_chunk"]) == ([2, 16], [1, 8], 8, 16, 32)
     assert (e["attention"], e["attn_heads"], e["attn_kv_heads"]) == (
@@ -690,6 +696,82 @@ def test_the_layout_events_say_the_arms_and_the_share(tmp_path, f32_pair):
                 r["windows_at_most"], r["combine"]) == (
                     names.SIGMOID_BIAS, 2.5, 32, 32, 4, 6, 2 * 128 * 6,
                     2 * 128 * 6, 1, names.PICK_MAJOR)
+
+
+def test_the_real_cells_expert_layers_keep_138_megabytes_a_layer():
+    """The cell's sizes, by hand: 8,192 tokens x (the share's result 1,024
+    + ``latent_down``'s 1,024 + the shared expert's ``up`` 5,376) x 2 bytes
+    + 8,192 x 512 logits x 4 bytes = 16.8 + 16.8 + 88.1 + 16.8 MB."""
+    z = arch.build_module(REAL, {"remat": "nothing"}).sizes
+    keep = hybrid.remat_keeps(z)
+    assert keep == (names.EXPERT_OUT, names.ROUTER_LOGITS, names.LATENT_IN,
+                    names.SHARED_UP)
+    each = [hybrid.kept_bytes((name,), z, 8192, jnp.bfloat16)
+            for name in keep]
+    assert each == [16_777_216, 16_777_216, 16_777_216, 88_080_384]
+    assert hybrid.kept_bytes(keep, z, 8192, jnp.bfloat16) == sum(each) == (
+        138_412_032)
+    assert hybrid.kept_bytes((), z, 8192, jnp.bfloat16) == 0
+
+
+def _dense_products(jaxpr) -> int:
+    found = 0
+    for eqn in jaxpr.eqns:
+        found += eqn.primitive.name == "dot_general"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _dense_products(sub)
+    return found
+
+
+@pytest.mark.parametrize("kept", [
+    (names.SHARED_UP,), (names.ROUTER_LOGITS,), (names.LATENT_IN,),
+    (names.ROUTER_LOGITS, names.LATENT_IN, names.SHARED_UP)],
+    ids=["shared_up", "router_logits", "latent_in", "all_three"])
+def test_a_kept_product_is_one_fewer_a_layer_in_the_gradient(kept,
+                                                             monkeypatch):
+    """The tiny architecture, rematerialised (``nothing``), in bf16: for
+    each name a layer keeps beside ``EXPERT_OUT`` the gradient's jaxpr
+    holds one ``dot_general`` fewer in each of the five expert layers (the
+    shared expert's ``up``, the router's float32 product, ``latent_down``
+    run once, not twice); ``down`` and ``latent_up`` never ran twice."""
+    config = tiny("bfloat16")
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0,
+                                config["vocab_size"])
+    module = arch.build_module(config, {"remat": "nothing"})
+    params = arch.program_tree(config, arch.init_weights(
+        config, reference.split_seed(7)))
+    loss = lambda p: lm_loss(module.apply(p, tokens), tokens)
+
+    def products(keep):
+        monkeypatch.setattr(hybrid, "remat_keeps", lambda z: keep)
+        return _dense_products(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+
+    layers = module.layer_types.count(names.EXPERT_LAYER)
+    assert layers == 5
+    alone = products((names.EXPERT_OUT,))
+    assert products((names.EXPERT_OUT,) + kept) == alone - layers * len(kept)
+    # nothing else of an expert layer's dense products runs twice: naming
+    # what is not in the graph keeps nothing
+    assert products((names.EXPERT_OUT, names.SHARED_GATE)) == alone
+
+
+def test_the_gradient_with_the_keeps_is_the_gradient_without_remat(f32_pair):
+    """Bit for bit, in float32 (in bf16 the CPU's compiler widens the
+    elementwise chains inside a fusion, so there remat alone moves the
+    last bit): a kept tensor is the one the rematerialised forward would
+    compute.  ``f32_pair``'s module is rematerialised with the keeps."""
+    p = f32_pair
+    assert p["module"].remat and len(hybrid.remat_keeps(
+        p["module"].sizes)) == 4
+    plain = arch.build_module(p["config"], {"remat": None})
+    assert not plain.remat
+    loss, grads = jax.value_and_grad(
+        lambda q: lm_loss(plain.apply(q, p["tokens"]), p["tokens"]))(
+            p["params"])
+    assert float(loss) == float(p["loss"])
+    for got, want in zip(jax.tree.leaves(p["grads"]),
+                         jax.tree.leaves(grads)):
+        assert bool(jnp.all(got == want))
 
 
 def test_the_layers_names_carry_what_the_readers_look_for(f32_pair):

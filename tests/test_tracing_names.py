@@ -812,7 +812,9 @@ def test_moe_layout_says_the_windows_a_share_takes(
                              names.FFN_OUT], 32 + 2 * 48 + 32),
     (names.EXPERT_SHARE, True, [names.MIXER_OUT], 32),
     (names.DENSE_FFN, False, [], 0),
-    (names.EXPERT_LAYER, True, [names.EXPERT_OUT], 24)],
+    (names.EXPERT_LAYER, True, [
+        names.EXPERT_OUT, names.ROUTER_LOGITS, names.LATENT_IN,
+        names.SHARED_GATE, names.SHARED_UP], 24 + 8 + 24 + 2 * 16)],
     ids=["dense_arm", "expert_share_arm", "no_remat",
          "one_sublayer_expert_layer"])
 def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
@@ -821,7 +823,9 @@ def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
     ``remat_kept_bytes_per_layer``: ``MIXER_OUT``'s ``tokens x d_model x
     itemsize`` and, in the dense arm, ``tokens x (2 x ffn_width + d_model)
     x itemsize`` more; in a decoder of one-sublayer layers an expert
-    layer's ``EXPERT_OUT``, ``tokens x latent_width x itemsize``."""
+    layer's ``EXPERT_OUT`` and ``LATENT_IN``, ``tokens x latent_width x
+    itemsize`` each, its router's float32 logits (4 experts: 8 bf16
+    columns) and its gated shared expert's two first products'."""
     from tpudist.models.hybrid import HybridLM, HybridSizes
 
     arms = dict(feed_forward=feed_forward)

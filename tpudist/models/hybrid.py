@@ -481,7 +481,12 @@ class ExpertShare(nn.Module):
     experts work in a latent space of ``latent_width`` behind two
     projections (``latent_down`` before them, ``latent_up`` on their
     partial sum; router and shared expert read the tokens at ``d_model``),
-    and whether the shared expert is scored."""
+    and whether the shared expert is scored.  The outputs of its dense
+    products that the backward pass reads carry names for a rematerialised
+    layer to keep (:func:`remat_keeps`): ``latent_down``'s, the unscored
+    shared expert's first products' (named here, by the weight multiplied,
+    and not in the expert function, which the grouped products run too),
+    the router's logits (in ``expert_share``)."""
 
     sizes: HybridSizes
     dtype: jnp.dtype
@@ -523,8 +528,9 @@ class ExpertShare(nn.Module):
         if z.latent_width:
             with jax.named_scope(names.MOE), jax.named_scope(
                     names.LATENT_PROJ):
-                inside = _dense(z.latent_width, "latent_down",
-                                self.dtype)(tokens)
+                inside = checkpoint_name(
+                    _dense(z.latent_width, "latent_down", self.dtype)(tokens),
+                    names.LATENT_IN)
         y, counts, windows = expert_share(
             params, inside, n_experts=z.n_experts, held=e,
             first_expert=z.first_expert, k=z.top_k, expert_fn=expert_fn,
@@ -535,8 +541,17 @@ class ExpertShare(nn.Module):
                     y = _dense(d, "latent_up", self.dtype)(y)
             if not z.shared_scored:
                 with jax.named_scope(names.SHARED_EXPERT):
-                    y = y + expert_fn(jax.tree.map(
-                        lambda w: w.astype(self.dtype), shared), tokens)
+                    shared = jax.tree.map(lambda w: w.astype(self.dtype),
+                                          shared)
+
+                    def product(rows, w):
+                        out = jnp.matmul(rows, w)
+                        for leaf, name in names.SHARED_EXPERT_KEEPS.items():
+                            if shared.get(leaf) is w:
+                                return checkpoint_name(out, name)
+                        return out
+
+                    y = y + expert_fn(shared, tokens, product)
         # assignments per held expert: collected by train steps built with
         # ``aux=True`` (make_lm_train_step), one row a layer
         self.sow("intermediates", "moe_expert_tokens", counts)
@@ -616,18 +631,44 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
     between its mixer and its feed-forward arm, ``tokens x d_model x
     itemsize`` bytes), and in the dense arm the outputs of the
     feed-forward's three products (its input IS ``MIXER_OUT``), so that no
-    product of the feed-forward runs twice a step.  The expert-share arm
-    recomputes its feed-forward: the buffers of its dispatch are many times
-    a layer's activations.  A layer of one sublayer keeps its input and,
-    where it is an expert layer whose share takes its arrivals through
-    windows (:func:`tpudist.parallel.moe.share_windows`; only such a share
-    names it), the share's result ``names.EXPERT_OUT``, ``tokens x
-    (latent_width or d_model) x itemsize`` bytes: what follows the share
-    needs it for its gradient, and the share's loop run again for it is
-    one the compiler cannot merge with the backward pass's own."""
+    product of the feed-forward runs twice a step.  The expert-share arm of
+    a two-sublayer layer recomputes its feed-forward: the buffers of its
+    dispatch are many times a layer's activations.
+
+    A layer of one sublayer keeps its input and, where it is an expert
+    layer, (a) the share's result ``names.EXPERT_OUT`` where the share
+    takes its arrivals through windows
+    (:func:`tpudist.parallel.moe.share_windows`; only such a share names
+    it), ``tokens x (latent_width or d_model) x itemsize`` bytes: what
+    follows the share needs it for its gradient, and the share's loop run
+    again for it is one the compiler cannot merge with the backward
+    pass's own; (b) the outputs of the dense products that its backward
+    pass reads and its rematerialised forward would therefore run again:
+    the router's logits (``tokens x n_experts x 4`` bytes, float32),
+    ``latent_down``'s output where the layer has latent projections
+    (``tokens x latent_width x itemsize``) and an UNSCORED shared expert's
+    first products' (``up``; ``gate`` too where the expert is gated:
+    ``tokens x shared_width x itemsize`` each; the activation between
+    them and ``down`` is recomputed, one elementwise pass).  What no
+    backward pass reads is not kept: ``down``'s and ``latent_up``'s
+    outputs, behind a norm that comes BEFORE the sublayer.  A norm AFTER
+    it (``norm_after``) reads the sublayer's whole output: that is not
+    covered here (such an expert layer runs ``down`` and ``latent_up``
+    twice; no architecture has one).  A scored shared expert is computed
+    inside ``expert_share`` and names nothing.  At 8,192 tokens, 512
+    experts, a latent width of 1,024 and a squared-ReLU shared expert of
+    5,376 in bf16: 16.8 + 16.8 + 16.8 + 88.1 = 138.4 MB a layer."""
     if sizes.one_sublayer:
-        return ((names.EXPERT_OUT,)
-                if sizes.feed_forward == names.EXPERT_SHARE else ())
+        if sizes.feed_forward != names.EXPERT_SHARE:
+            return ()
+        keep = (names.EXPERT_OUT, names.ROUTER_LOGITS)
+        if sizes.latent_width:
+            keep += (names.LATENT_IN,)
+        if not sizes.shared_scored:
+            keep += tuple(names.SHARED_EXPERT_KEEPS[leaf]
+                          for leaf in EXPERT_LEAVES[sizes.expert_fn]
+                          if leaf in names.SHARED_EXPERT_KEEPS)
+        return keep
     if sizes.feed_forward == names.DENSE_FFN:
         return (names.MIXER_OUT,) + names.DENSE_FFN_KEEPS
     return (names.MIXER_OUT,)
@@ -635,14 +676,19 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
 
 def kept_bytes(keep: tuple, sizes: HybridSizes, tokens: int, dtype) -> int:
     """What the activations named ``keep`` hold a layer from its forward to
-    its backward pass, over ``tokens`` positions in compute dtype
-    ``dtype``."""
+    its backward pass, over ``tokens`` positions in compute dtype ``dtype``
+    (the router's logits in float32)."""
     columns = {names.MIXER_OUT: sizes.d_model,
                names.FFN_GATE: sizes.ffn_width,
                names.FFN_UP: sizes.ffn_width, names.FFN_OUT: sizes.d_model,
-               names.EXPERT_OUT: sizes.latent_width or sizes.d_model}
-    return tokens * jnp.dtype(dtype).itemsize * sum(
-        columns[name] for name in keep)
+               names.EXPERT_OUT: sizes.latent_width or sizes.d_model,
+               names.LATENT_IN: sizes.latent_width or 0,
+               names.SHARED_GATE: sizes.shared_width,
+               names.SHARED_UP: sizes.shared_width}
+    itemsize = jnp.dtype(dtype).itemsize
+    return tokens * sum(
+        4 * sizes.n_experts if name == names.ROUTER_LOGITS
+        else itemsize * columns[name] for name in keep)
 
 
 class HybridLM(nn.Module):
@@ -661,6 +707,7 @@ class HybridLM(nn.Module):
     # a dense feed-forward, its three products' outputs (``tokens x (2 x
     # ffn_width + d_model) x itemsize`` more), so its forward runs once; an
     # expert layer of one sublayer the result of a share that goes by windows
+    # and the outputs of its dense products that the backward pass reads
     remat_policy: str = "nothing"
 
     @nn.compact

@@ -585,7 +585,9 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
     ``router_input`` (``x`` before it was rounded to the compute dtype,
     where the caller has it; or the tokens at their own width
     ``d_router`` where ``x`` holds their projection into the experts'
-    latent space), its scores, top ``k``, renormalisation and ``scale``
+    latent space) and carry the name ``names.ROUTER_LOGITS`` for a
+    rematerialised caller to keep; its scores, top ``k``, renormalisation
+    and ``scale``
     are :func:`route`'s by ``scoring``, over all ``n_experts``.  The
     experts compute in ``x``'s dtype.  ``shared``
     is ``sigmoid(x . score) * E_shared(x)``, what every member of the
@@ -639,9 +641,11 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
                     width=d)
     with jax.named_scope(names.MOE):
         scored = x if router_input is None else router_input
-        logits = jnp.matmul(scored.astype(jnp.float32),
-                            params["router"].astype(jnp.float32),
-                            precision=lax.Precision.HIGHEST)
+        logits = checkpoint_name(
+            jnp.matmul(scored.astype(jnp.float32),
+                       params["router"].astype(jnp.float32),
+                       precision=lax.Precision.HIGHEST),
+            names.ROUTER_LOGITS)
         routing = route(logits, n_experts=n_experts, k=k, held=held,
                         first_expert=first_expert, scoring=scoring,
                         choice_bias=params.get("choice_bias"), scale=scale)

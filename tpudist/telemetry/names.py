@@ -104,6 +104,26 @@ DENSE_FFN_KEEPS = (FFN_GATE, FFN_UP, FFN_OUT)
 # share's loop, run again for it, could not be merged with the backward
 # pass's own as straight-line code is
 EXPERT_OUT = "expert_out"
+# tpudist/models/hybrid.py, tpudist/parallel/moe.py: the outputs of an expert
+# layer's dense products that its backward pass reads, which a rematerialised
+# layer of one sublayer keeps beside ``expert_out`` so that each runs once a
+# step, not twice: the UNSCORED shared expert's first product(s), ``[tokens,
+# shared_width]`` in the compute dtype each (``up``; ``gate`` too where the
+# expert is gated; ``down``'s output is read by nothing behind a norm that
+# comes before the sublayer and is not kept), named where ``ExpertShare``
+# calls the expert, not in the expert functions, which the grouped products
+# share; the router's logits, ``[tokens, n_experts]`` in float32 (named in
+# ``expert_share`` for every caller; a caller whose policy does not name
+# them keeps nothing); and ``latent_down``'s output, ``[tokens,
+# latent_width]`` in the compute dtype, where the layer has latent
+# projections (``latent_up``'s is ``expert_out``'s successor and read by
+# nothing).  ``tokens x ((1 or 2) x shared_width + latent_width) x itemsize
+# + tokens x n_experts x 4`` bytes a layer
+SHARED_GATE = "shared_gate"
+SHARED_UP = "shared_up"
+SHARED_EXPERT_KEEPS = {"gate": SHARED_GATE, "up": SHARED_UP}  # by weight
+ROUTER_LOGITS = "router_logits"
+LATENT_IN = "latent_in"
 
 # -- spans (tpudist.telemetry.span / record_span) -----------------------------
 STEP = "step"            # one arrival of a step's result to the next
@@ -184,8 +204,10 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # ``feed_forward=`` EXPERT_SHARE / DENSE_FFN; ``norm=`` ZERO_CENTRED / PLAIN
 # and ``norm_after=`` whether it follows its sublayer; ``remat_keeps=`` the
 # names a rematerialised layer keeps besides its input (MIXER_OUT, and
-# DENSE_FFN_KEEPS in the dense arm; EXPERT_OUT in an expert layer of one
-# sublayer; ``[]`` without remat) and
+# DENSE_FFN_KEEPS in the dense arm; in an expert layer of one sublayer
+# EXPERT_OUT, ROUTER_LOGITS, LATENT_IN where it has latent projections, and
+# SHARED_EXPERT_KEEPS' names of an unscored shared expert's first products;
+# ``[]`` without remat) and
 # ``remat_kept_bytes_per_layer=`` what they hold.  And of each expert
 # layer
 # (tpudist/parallel/moe.py): ``experts=`` the router's width, ``held=``,
