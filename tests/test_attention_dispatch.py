@@ -45,9 +45,10 @@ ROUTES = {
     "v5e-tiles-do-not-divide": ((V5E, 2560, 8, 8, 128, None),
                                 (REFERENCE, names.HEAD_MAJOR, 1024, 1024,
                                  names.WHY_SEQ)),
-    # a window's band-edge tiles are computed whole, and not counted
+    # a window's band-edge tiles are computed whole: the row's tiles, two
+    # key tiles a query tile, most of each dead
     "v5e-windowed": ((V5E, 4096, 8, 2, 128, 512),
-                     (FLASH, names.PACKED, 1024, 1024, None, 0, None)),
+                     (FLASH, names.PACKED, 1024, 1024, None, 0, 3.7328)),
     # unequal tiles: whole
     "default-row-long": (("TPU v6 lite", 8192, 8, 8, 128, None),
                          (FLASH, names.PACKED, 512, 1024, None, 0, 1.1249)),

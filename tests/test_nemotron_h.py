@@ -209,7 +209,8 @@ def test_the_share_cells_layer_is_bit_for_bit_what_it_was(monkeypatch):
 def test_route_names_its_scorings_from_their_table(kw, said):
     with pytest.raises(ValueError, match=said):
         moe.route(jnp.zeros((4, 8)), n_experts=8, k=2, **kw)
-    assert set(moe.SCORINGS) == {names.SOFTMAX, names.SIGMOID_BIAS}
+    assert set(moe.SCORINGS) == {names.SOFTMAX, names.SIGMOID,
+                                 names.SIGMOID_BIAS}
     assert set(moe.EXPERT_FNS) == set(moe.EXPERT_LEAVES) == {
         names.GATED_SILU, names.RELU2}
 
@@ -650,10 +651,11 @@ def test_the_real_configurations_parameters_to_the_parameter():
 def test_an_unknown_layer_kind_is_named_against_the_table():
     sizes = arch.build_module(tiny(), {"remat": None}).sizes
     assert hybrid.layer_kinds(sizes) == (
-        names.LINEAR, names.FULL, names.STATE_SPACE, names.EXPERT_LAYER)
+        names.LINEAR, names.FULL, names.WINDOW, names.STATE_SPACE,
+        names.EXPERT_LAYER)
     paired = dataclasses.replace(sizes, one_sublayer=False)
     assert hybrid.layer_kinds(paired) == tuple(hybrid.MIXERS) == (
-        names.LINEAR, names.FULL, names.STATE_SPACE)
+        names.LINEAR, names.FULL, names.WINDOW, names.STATE_SPACE)
     tokens = jnp.zeros((1, 32), jnp.int32)
     for z, kinds in ((sizes, ("retention",)),
                      (paired, (names.EXPERT_LAYER,))):

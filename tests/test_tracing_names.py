@@ -73,6 +73,23 @@ def step_op_names():
     params = hybrid.init(jax.random.PRNGKey(0), tokens)
     lowered = make_lm_train_step(hybrid.apply, tx, mesh).lower(
         init_lm_state(params, tx), tokens)
+    found |= set(re.findall(r'loc\("([^"]+)"',
+                            lowered.as_text(debug_info=True)))
+    # two softmax kinds behind a gate a head: a window layer's own scope,
+    # and the gate's nested in both
+    from tpudist.models.hybrid import SoftmaxSizes
+
+    hybrid = HybridLM(
+        vocab=64, layer_types=(names.FULL, names.WINDOW),
+        sizes=dataclasses.replace(
+            hybrid.sizes, one_sublayer=False, latent_width=None, ffn_width=32,
+            attention=names.HEAD_GATED_ATTN, softmax_kinds=(
+                (names.FULL, SoftmaxSizes(2, 1, rotary_dim=8)),
+                (names.WINDOW, SoftmaxSizes(4, 1, window=16, rotary_dim=16)))),
+        remat=True, feed_forwards=(names.DENSE_FFN, names.EXPERT_SHARE))
+    params = hybrid.init(jax.random.PRNGKey(0), tokens)
+    lowered = make_lm_train_step(hybrid.apply, tx, mesh).lower(
+        init_lm_state(params, tx), tokens)
     return found | set(re.findall(r'loc\("([^"]+)"',
                                   lowered.as_text(debug_info=True)))
 
@@ -91,7 +108,8 @@ def test_a_backward_op_carries_the_transpose_mark_and_its_sublayer(
     for scope in (names.ATTN, names.MLP, names.EMBED, names.HEAD,
                   names.LOSS, names.LINEAR_ATTN, names.DELTA_RULE,
                   names.MOE, names.EXPERTS, names.SHARED_EXPERT, names.SSM,
-                  names.SSD_SCAN, names.LATENT_PROJ):
+                  names.SSD_SCAN, names.LATENT_PROJ, names.WINDOW_ATTN,
+                  names.HEAD_GATE):
         assert [n for n in step_op_names
                 if names.BACKWARD_MARK in n and _under(scope, n)], scope
     # the optimizer is not differentiated: no transposed op under it

@@ -1,8 +1,10 @@
 """A decoder LM whose layers follow a pattern of mixers (gated delta-rule
-linear attention, Mamba-2 state-space mixers and causal softmax attention)
-and a feed-forward arm: a routed expert layer of which this device holds a
-share, or a dense gated feed-forward.  A layer is a mixer followed by the
-feed-forward arm, or ONE sublayer (a mixer, or the feed-forward arm).
+linear attention, Mamba-2 state-space mixers and softmax attention, causal
+to everything or inside a sliding window) and a feed-forward arm: a routed
+expert layer of which this device holds a share, or a dense gated
+feed-forward, the same for every layer or said a layer.  A layer is a mixer
+followed by the feed-forward arm, or ONE sublayer (a mixer, or the
+feed-forward arm).
 
 What :mod:`tpudist.models.transformer`'s ``Block`` (pre-LN, LayerNorm,
 ungated GELU FFN) cannot say, by mechanism:
@@ -23,6 +25,17 @@ ungated GELU FFN) cannot say, by mechanism:
   a token over every head's dims together, no gate, no rotary positions;
 - **plain grouped-query softmax attention** (:class:`GroupedAttention`): no
   norm, no gate, no rotary positions;
+- **two softmax kinds in one decoder**, ``full_attention`` and
+  ``sliding_attention``, each with sizes of its own
+  (:class:`SoftmaxSizes`: query heads held of how many, the sliding window,
+  the rotary dims, base and YaRN constants), behind **a gate a head**
+  (:class:`HeadGatedAttention`: a sigmoid of the gate's own projection of
+  the layer's input, one number a head a token); a window layer's attention
+  is ``tpudist.ops.attention``'s windowed instance, whose tiles the
+  dispatch's table gives;
+- **leading dense layers before the expert layers**: the feed-forward arm a
+  layer (``HybridLM.feed_forwards``), each layer keeping under remat what
+  its own arm names;
 - **gated delta-rule linear attention** (:class:`GatedDeltaNet`):
   projections fused per key head or separate, a short depthwise causal
   convolution with SiLU over q, k, v, per-head decay, a write strength of
@@ -34,8 +47,9 @@ ungated GELU FFN) cannot say, by mechanism:
   head, a depthwise causal convolution with bias and SiLU, the chunked scan
   of :mod:`tpudist.ops.ssd`, an RMS norm of ``y * silu(z)`` a GROUP;
 - **routed experts as a share** (:class:`ExpertShare`):
-  :func:`tpudist.parallel.moe.expert_share`, dropless; softmax or sigmoid +
-  choice-bias scoring with a scale, gated SiLU or squared-ReLU experts, at
+  :func:`tpudist.parallel.moe.expert_share`, dropless; softmax or sigmoid
+  (with or without a choice bias) scoring with a scale, gated SiLU or
+  squared-ReLU experts, at
   the model's width or in a latent space behind two projections, with a
   scored or plain shared expert; or a **dense gated feed-forward**
   (:class:`GatedMLP`);
@@ -47,7 +61,7 @@ ungated GELU FFN) cannot say, by mechanism:
   member's partial output goes on as it is.
 
 Which arm a layer takes is data on :class:`HybridSizes`, filled in by
-whoever builds the module; nothing here knows a model.  The three
+whoever builds the module; nothing here knows a model.  The four
 architectures that run through it (``cellbench/archs``): ``qwen3_next``
 (norms zero-centred and before the sublayer, :class:`GatedAttention`, fused
 projections with ``nv / nk`` value heads a key head at 128 / 128, write
@@ -59,14 +73,20 @@ held) and ``nemotron_h`` (layers of one sublayer, norms plain and before
 it, :class:`Mamba2Mixer` holding one group of eight,
 :class:`GroupedAttention` at 4 : 1, :class:`ExpertShare` with sigmoid +
 bias scoring, a scale, squared-ReLU experts in a latent space and a plain
-shared expert).
+shared expert) and ``laguna`` (norms plain and before the sublayers,
+:class:`HeadGatedAttention` at two kinds: 24 query heads causal to
+everything with YaRN on half of a head's dims, 36 inside a window of 512
+with plain rotary positions on all of them, on the same 4 key/value heads,
+half of each layer's heads held; a dense feed-forward in layer 0 and
+:class:`ExpertShare` with sigmoid scoring, a scale, gated SiLU experts and a
+plain shared expert behind the others).
 
 The embedding, the head, their names and scopes, the loss the step
 builders take (``lm_loss``) and the remat policy names are
 ``transformer``'s, imported; the causal attention itself is
 ``tpudist.ops.attention``'s length-aware dispatch (the flash kernels where
-they run), the rotary angles ``tpudist.ops.rope``'s.  Training only: no
-decode cache.
+they run; one instance a window), the rotary angles and frequencies
+``tpudist.ops.rope``'s.  Training only: no decode cache.
 """
 
 from __future__ import annotations
@@ -81,9 +101,9 @@ from jax.ad_checkpoint import checkpoint_name
 
 from tpudist import telemetry
 from tpudist.models.transformer import remat_module
-from tpudist.ops.attention import default_attention
+from tpudist.ops.attention import attention_within, default_attention
 from tpudist.ops.gated_delta import chunked_gated_delta_rule
-from tpudist.ops.rope import rope_angles
+from tpudist.ops.rope import rope_angles_at, rope_inv_freq, yarn_inv_freq
 from tpudist.ops.ssd import ssd_scan
 from tpudist.parallel.moe import EXPERT_FNS, EXPERT_LEAVES, expert_share
 from tpudist.telemetry import names
@@ -135,18 +155,62 @@ def _dense(features: int, name: str, dtype):
     return nn.Dense(features, use_bias=False, name=name, dtype=dtype)
 
 
-def rotate_partial(x, rotary_dim: int, base: float):
-    """Rotary positions on the first ``rotary_dim`` dims of each head of
-    ``x [b, s, heads, dh]`` (half-split pairing ``(i, i + rotary_dim / 2)``,
-    angles in f32); the other dims pass through."""
-    half = rotary_dim // 2
-    angles = rope_angles(0, x.shape[1], half, base)[:, None, :]
+def rotate_partial(x, inv_freq, scale: float = 1.0):
+    """Rotary positions on the first ``2 * len(inv_freq)`` dims of each head
+    of ``x [b, s, heads, dh]`` (half-split pairing ``(i, i + half)``, angles
+    ``position * inv_freq`` in f32, cos and sin times ``scale``); the other
+    dims pass through."""
+    half = inv_freq.shape[0]
+    angles = rope_angles_at(0, x.shape[1], inv_freq)[:, None, :]
     sin, cos = jnp.sin(angles), jnp.cos(angles)
+    if scale != 1.0:
+        sin, cos = scale * sin, scale * cos
     x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:rotary_dim].astype(jnp.float32)
+    x2 = x[..., half:2 * half].astype(jnp.float32)
     return jnp.concatenate(
         [(x1 * cos - x2 * sin).astype(x.dtype),
-         (x1 * sin + x2 * cos).astype(x.dtype), x[..., rotary_dim:]], axis=-1)
+         (x1 * sin + x2 * cos).astype(x.dtype), x[..., 2 * half:]], axis=-1)
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN's constants (:func:`tpudist.ops.rope.yarn_inv_freq`) and
+    ``scale``, what multiplies cos and sin with them."""
+
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    scale: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class SoftmaxSizes:
+    """One softmax-attention kind's own sizes (the head width is the
+    decoder's): query heads HELD here of ``n_heads_total`` in all (None:
+    all of them are here) with the key/value heads they read, the sliding
+    window (None: causal to everything), and its rotary positions: the
+    dims rotated, the base, and YaRN's constants where the frequencies are
+    its."""
+
+    n_heads: int
+    n_kv_heads: int
+    n_heads_total: Optional[int] = None
+    window: Optional[int] = None
+    rotary_dim: int = 0
+    rope_theta: float = 1e4
+    yarn: Optional[Yarn] = None
+
+    def rotary(self):
+        """``(inv_freq [rotary_dim / 2] f32, scale)``."""
+        half = self.rotary_dim // 2
+        if self.yarn is None:
+            return rope_inv_freq(half, self.rope_theta), 1.0
+        y = self.yarn
+        return yarn_inv_freq(
+            half, self.rope_theta, factor=y.factor,
+            original_positions=y.original_positions, beta_fast=y.beta_fast,
+            beta_slow=y.beta_slow), y.scale
 
 
 @dataclasses.dataclass(frozen=True)
@@ -155,15 +219,19 @@ class HybridSizes:
     which arm each part of a layer takes (``names.*``)."""
 
     d_model: int
+    head_dim: int
     # softmax attention: heads HELD here, of ``n_heads_total`` query heads
     # in all (None: all of them are here)
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    rotary_dim: int              # the gated attention's
+    n_heads: Optional[int] = None
+    n_kv_heads: Optional[int] = None
+    rotary_dim: int = 0          # the gated attention's
     rope_theta: float = 1e7
     n_heads_total: Optional[int] = None
     attention: str = names.GATED_ATTN        # one of ATTENTIONS
+    # the head-gated arm's sizes INSTEAD of the five above, which it does
+    # not read: ((kind, SoftmaxSizes), ...), a softmax kind (names.FULL,
+    # names.WINDOW) of the decoder's layers each
+    softmax_kinds: tuple = ()
     # gated delta-rule linear attention: heads HELD, of
     # ``linear_value_heads_total`` value heads in all
     linear_key_heads: int = 16
@@ -218,6 +286,15 @@ class HybridSizes:
     shared_scored: bool = True
     eps: float = 1e-6
 
+    def softmax(self, kind: str) -> SoftmaxSizes:
+        """The head-gated arm's sizes of layers of ``kind``."""
+        kinds = dict(self.softmax_kinds)
+        if kind not in kinds:
+            raise ValueError(
+                f"softmax_kinds names {sorted(kinds)}, not {kind!r}: the "
+                f"{names.HEAD_GATED_ATTN} arm takes a kind's sizes from it")
+        return kinds[kind]
+
 
 class GatedAttention(nn.Module):
     """Causal softmax attention with normed, partly rotated q and k and a
@@ -238,8 +315,8 @@ class GatedAttention(nn.Module):
         v = _dense(kv * dh, "v_proj", self.dtype)(x)
         q = ZeroCentredRMSNorm(z.eps, name="q_norm")(q).astype(self.dtype)
         k = ZeroCentredRMSNorm(z.eps, name="k_norm")(k).astype(self.dtype)
-        q = rotate_partial(q, z.rotary_dim, z.rope_theta)
-        k = rotate_partial(k, z.rotary_dim, z.rope_theta)
+        q, k = (rotate_partial(t, rope_inv_freq(z.rotary_dim // 2,
+                                                z.rope_theta)) for t in (q, k))
         # q's heads, k's, v's side by side: the layout the dispatch's packed
         # route (the flash kernels, at head_dim % 128 == 0) indexes itself
         qkv = jnp.concatenate(
@@ -308,9 +385,57 @@ class GroupedAttention(nn.Module):
                              z.heads_axis)
 
 
+class HeadGatedAttention(nn.Module):
+    """Grouped-query softmax attention of layers of ``kind``, by that
+    kind's own sizes (:meth:`HybridSizes.softmax`): causal to everything or
+    inside its sliding window, rotary positions on the first ``rotary_dim``
+    dims of a head at its own frequencies and scale, no q/k norm, and a
+    gate a head: ``sigmoid`` in float32 of a projection of the layer's
+    input (``g_proj``, one column a held head), multiplied into that head's
+    attention output before ``o_proj``, under the scope
+    ``names.HEAD_GATE``.  Holding ``n_heads`` query heads with the
+    ``n_kv_heads`` they read, ``o_proj``'s partial sums are reduced over
+    ``heads_axis`` where there is one; the gate is a head's own, so no
+    statistic crosses the cut."""
+
+    sizes: HybridSizes
+    dtype: jnp.dtype
+    kind: str = names.FULL
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        z, a = self.sizes, self.sizes.softmax(self.kind)
+        h, kv, dh = a.n_heads, a.n_kv_heads, z.head_dim
+        q, k, v = (_dense(heads * dh, f"{name}_proj", self.dtype)(x)
+                   for name, heads in (("q", h), ("k", kv), ("v", kv)))
+        if a.rotary_dim:
+            inv_freq, scale = a.rotary()
+            q = rotate_partial(q.reshape(b, s, h, dh), inv_freq,
+                               scale).reshape(b, s, h * dh)
+            k = rotate_partial(k.reshape(b, s, kv, dh), inv_freq,
+                               scale).reshape(b, s, kv * dh)
+        attn = attention_within(a.window).packed(
+            jnp.concatenate([q, k, v], axis=-1), h, kv)
+        with jax.named_scope(names.HEAD_GATE):
+            gate = jax.nn.sigmoid(
+                _dense(h, "g_proj", self.dtype)(x).astype(jnp.float32))
+            attn = (attn.reshape(b, s, h, dh) * gate[..., None]).astype(
+                self.dtype).reshape(b, s, h * dh)
+        return _over_members(_dense(d, "o_proj", self.dtype)(attn),
+                             z.heads_axis)
+
+
+#: ``HybridSizes.attention`` -> the softmax-attention arm: ``gated`` (per-head
+#: q/k norms, a gate from the query projection, part rotary), ``normed`` (one
+#: q/k norm statistic over all heads, no gate, no rotary), ``grouped`` (plain
+#: grouped-query) and ``head_gated`` (a gate a head from its own projection;
+#: heads, rotary positions and window by the layer's kind, from
+#: ``HybridSizes.softmax_kinds``)
 ATTENTIONS = {names.GATED_ATTN: GatedAttention,
               names.NORMED_ATTN: NormedAttention,
-              names.GROUPED_ATTN: GroupedAttention}
+              names.GROUPED_ATTN: GroupedAttention,
+              names.HEAD_GATED_ATTN: HeadGatedAttention}
 
 
 def causal_depthwise_conv(x, kernel):
@@ -561,17 +686,37 @@ class ExpertShare(nn.Module):
         return y.reshape(b, s, d)
 
 
-def _attention(sizes, dtype, name):
-    return ATTENTIONS[sizes.attention](sizes, dtype, name=name)
+def _softmax(kind: str):
+    """What builds the softmax attention of layers of ``kind``: the decoder's
+    arm of :data:`ATTENTIONS`.  The head-gated arm alone goes by its layer's
+    kind (sizes a kind, a window); the others attend causally to everything
+    at the decoder's one set of sizes."""
+    def build(sizes, dtype, name):
+        if sizes.attention not in ATTENTIONS:
+            raise ValueError(f"attention is {sizes.attention!r}; the softmax "
+                             f"arm is one of {sorted(ATTENTIONS)}")
+        if sizes.attention == names.HEAD_GATED_ATTN:
+            return HeadGatedAttention(sizes, dtype, kind, name=name)
+        if kind != names.FULL:
+            raise ValueError(
+                f"a {kind!r} layer takes the {names.HEAD_GATED_ATTN!r} arm: "
+                f"{sizes.attention!r} has no window")
+        return ATTENTIONS[sizes.attention](sizes, dtype, name=name)
+
+    return build
 
 
 #: layer kind -> (the scope its sublayer runs under, the sublayer's name in
-#: the parameter tree, what builds it): the mixers a layer can hold.  A
-#: ``names.EXPERT_LAYER`` (one-sublayer layers only) holds the feed-forward
-#: arm instead
+#: the parameter tree, what builds it): the mixers a layer can hold: gated
+#: delta-rule linear attention, softmax attention causal to everything
+#: (``full_attention``) or inside a sliding window (``sliding_attention``,
+#: the head-gated arm's: the two may differ in every size of
+#: :class:`SoftmaxSizes`), and a Mamba-2 state-space mixer.  A ``names.EXPERT_LAYER`` (one-sublayer layers only)
+#: holds the feed-forward arm instead
 MIXERS = {
     names.LINEAR: (names.LINEAR_ATTN, "linear_attn", GatedDeltaNet),
-    names.FULL: (names.ATTN, "attn", _attention),
+    names.FULL: (names.ATTN, "attn", _softmax(names.FULL)),
+    names.WINDOW: (names.WINDOW_ATTN, "window_attn", _softmax(names.WINDOW)),
     names.STATE_SPACE: (names.SSM, "ssm", Mamba2Mixer),
 }
 
@@ -709,6 +854,11 @@ class HybridLM(nn.Module):
     # expert layer of one sublayer the result of a share that goes by windows
     # and the outputs of its dense products that the backward pass reads
     remat_policy: str = "nothing"
+    # the feed-forward arm a layer (names.EXPERT_SHARE / names.DENSE_FFN)
+    # where the layers do not share ``sizes.feed_forward`` (leading dense
+    # layers before the expert layers): layer ``i`` is built from ``sizes``
+    # with ``feed_forward=feed_forwards[i]``
+    feed_forwards: Optional[tuple] = None
 
     @nn.compact
     def __call__(self, tokens: jax.Array) -> jax.Array:
@@ -720,12 +870,34 @@ class HybridLM(nn.Module):
                 f"layer_types holds {sorted(unknown)}; a layer "
                 f"(one_sublayer={z.one_sublayer}) is one of "
                 f"{list(layer_kinds(z))}")
-        keep = remat_keeps(z) if self.remat else ()
+        arms = self.feed_forwards or (z.feed_forward,) * len(self.layer_types)
+        if len(arms) != len(self.layer_types) or set(arms) - {
+                names.EXPERT_SHARE, names.DENSE_FFN}:
+            raise ValueError(
+                f"feed_forwards is {arms}: one of {names.EXPERT_SHARE!r} and "
+                f"{names.DENSE_FFN!r} for each of the "
+                f"{len(self.layer_types)} layers")
+        # a layer's sizes: the decoder's, with its own arm
+        of_layer = [dataclasses.replace(z, feed_forward=arm) for arm in arms]
+        keeps = [remat_keeps(zi) if self.remat else () for zi in of_layer]
+        kept = [kept_bytes(keep, z, tokens.size, self.dtype)
+                for keep in keeps]
+        one_arm = len(set(arms)) == 1
+        # the softmax attention's sizes: a kind where the decoder says them
+        # so, else its one set
+        attention_sizes = dict(softmax_kinds={
+            kind: dict(heads=[a.n_heads, a.n_heads_total or a.n_heads],
+                       kv_heads=a.n_kv_heads, window=a.window,
+                       rotary_dim=a.rotary_dim, rope_theta=a.rope_theta,
+                       yarn_factor=a.yarn and a.yarn.factor,
+                       rope_scale=a.yarn.scale if a.yarn else 1.0)
+            for kind, a in z.softmax_kinds}) if z.softmax_kinds else dict(
+            attn_heads=[z.n_heads, z.n_heads_total or z.n_heads],
+            attn_kv_heads=z.n_kv_heads)
         telemetry.event(
             names.MIXER_LAYOUT, kinds=list(self.layer_types),
             one_sublayer=z.one_sublayer, attention=z.attention,
-            attn_heads=[z.n_heads, z.n_heads_total or z.n_heads],
-            attn_kv_heads=z.n_kv_heads, head_dim=z.head_dim,
+            head_dim=z.head_dim, **attention_sizes,
             linear_heads=[z.linear_value_heads, z.linear_value_heads_total
                           or z.linear_value_heads],
             linear_key_heads=z.linear_key_heads,
@@ -737,18 +909,20 @@ class HybridLM(nn.Module):
             ssm_groups=[z.ssm_groups, z.ssm_groups_total or z.ssm_groups],
             ssm_head_dim=z.ssm_head_dim, ssm_state=z.ssm_state,
             ssm_chunk=z.ssm_chunk, heads_axis=z.heads_axis,
-            feed_forward=z.feed_forward, norm=z.norm,
-            norm_after=z.norm_after, remat_keeps=list(keep),
-            remat_kept_bytes_per_layer=kept_bytes(
-                keep, z, tokens.size, self.dtype))
+            feed_forward=z.feed_forward, feed_forwards=list(arms),
+            norm=z.norm, norm_after=z.norm_after,
+            remat_keeps=list(keeps[0]) if one_arm else list(map(list, keeps)),
+            remat_kept_bytes_per_layer=kept[0] if one_arm else kept)
         with jax.named_scope(names.EMBED):
             x = nn.Embed(self.vocab, self.sizes.d_model, name="tok_embed",
                          dtype=self.dtype)(tokens)
-        layer_cls = (remat_module(HybridLayer, self.remat_policy, keep=keep)
-                     if self.remat else HybridLayer)
+        layer_classes = {keep: remat_module(HybridLayer, self.remat_policy,
+                                            keep=keep)
+                         if self.remat else HybridLayer for keep in set(keeps)}
         for i, kind in enumerate(self.layer_types):
-            x = layer_cls(kind, z, self.dtype,
-                          name=f"{names.PATTERN_LAYER}_{i}")(x)
+            x = layer_classes[keeps[i]](
+                kind, of_layer[i], self.dtype,
+                name=f"{names.PATTERN_LAYER}_{i}")(x)
         with jax.named_scope(names.HEAD):
             x = NORMS[z.norm](z.eps, name="final_norm")(x)
             return nn.Dense(self.vocab, use_bias=False, name="head",

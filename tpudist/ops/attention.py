@@ -16,6 +16,7 @@ attention (:mod:`tpudist.parallel.ring_attention`) is the other
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -58,6 +59,17 @@ class Tiles(NamedTuple):
 # strips each wait on their own row statistics; it alone would take 512).
 # The tiles themselves have not been swept on the cells' shapes; where
 # they turn out to depend on the shape the key grows here.
+#
+# A sliding window's band has two edges and no ``sub``: a tile either edge
+# crosses is computed whole and masked, so a call inside a window of 512
+# over 8,192 positions computes 3.87 times its live pairs by the row's 1024
+# x 1024 tiles (:func:`computed_over_live`).  Tiles of the window's own
+# width compute 2.00 times and were slower on the chip at the one cell that
+# has window layers (PR 41, a traced pair at 36 query on 4 key/value heads:
+# the three kernels 50.8 ms a step at 512 x 512 against 42.4 at the row's):
+# the kernels' grids run over every (query, key) tile and skip the dead
+# ones a step at a time, 256 steps a head at 512 against 64.  So a windowed
+# call keeps the row's tiles until the grid itself follows the band.
 TILES = {
     "TPU v5 lite": Tiles(2048, 1024, 1024, 1024, 8192, 256),
 }
@@ -82,7 +94,8 @@ class Route(NamedTuple):
 
 def route(device_kind: str, seq: int, dh: int) -> Route:
     """What runs for ``seq`` positions at head width ``dh`` on a device of
-    this kind.
+    this kind (causal to everything or inside a sliding window: the tiles
+    are the row's either way).
 
     The flash kernels take a length from the row's ``min_seq`` that both
     tiles divide (the kernels' contract), on a TPU: a kind whose name
@@ -104,26 +117,34 @@ def route(device_kind: str, seq: int, dh: int) -> Route:
                  t.sub)
 
 
-def computed_over_live(seq: int, block_q: int, block_k: int,
-                       sub: int = 0) -> float:
-    """Score entries the flash kernels compute over the ``seq·(seq+1)/2``
-    live pairs of plain causal attention: every tile the band touches
-    whole, but a tile on the diagonal, where the kernels work it by
-    ``sub``-wide squares (``sub`` as :func:`diag_sub` gives it, 0 for the
-    whole tile), only the squares on or under the diagonal.  1024 x 1024
-    tiles: 1.50 whole and 1.125 at ``sub`` 256 over 2,048 positions, 1.125
-    and 1.031 over 8,192."""
+def computed_over_live(seq: int, block_q: int, block_k: int, sub: int = 0,
+                       window: Optional[int] = None) -> float:
+    """Score entries the flash kernels compute over the live pairs of
+    causal attention (``seq·(seq+1)/2`` of them, or inside a sliding
+    ``window`` position ``q``'s ``min(q + 1, window)``): every tile the band
+    touches whole, but a tile on the diagonal of the plain causal band,
+    where the kernels work it by ``sub``-wide squares (``sub`` as
+    :func:`diag_sub` gives it, 0 for the whole tile and for every window),
+    only the squares on or under the diagonal.  1024 x 1024 tiles: 1.50
+    whole and 1.125 at ``sub`` 256 over 2,048 positions, 1.125 and 1.031
+    over 8,192; a window of 512 over 8,192 positions 3.87 (2.00 if the tiles
+    were 512 x 512)."""
     n = block_q // sub if sub else 0
     computed = 0
     for i in range(seq // block_q):
         for j in range(seq // block_k):
             if (i + 1) * block_q - 1 < j * block_k:
                 continue                                    # elided
+            if window is not None and (
+                    i * block_q - ((j + 1) * block_k - 1) >= window):
+                continue                    # behind the window: elided too
             if sub and i * block_q < (j + 1) * block_k - 1:
                 computed += n * (n + 1) // 2 * sub * sub    # on the diagonal
             else:
                 computed += block_q * block_k
-    return computed / (seq * (seq + 1) / 2)
+    live = (seq * (seq + 1) / 2 if window is None or window >= seq else
+            window * (window + 1) / 2 + (seq - window) * window)
+    return computed / live
 
 
 def _per_shard(kernel, *operands):
@@ -233,13 +254,14 @@ def make_length_aware_attention(window: Optional[int] = None):
         if r.why_not is not None:
             said["reason"] = r.why_not
         if r.kernel == FLASH:
-            # how a tile on the diagonal is worked (0: whole), and what
-            # that makes the kernels compute; a window's band is not
-            # counted, its tiles are computed whole
+            # how a tile on the diagonal is worked (0: whole, as every tile
+            # a window's edges cross), and what that makes the kernels
+            # compute
             said["diag_sub"] = diag_sub(r.block_q, r.block_k, 0, window, r.sub)
-            if window is None:
-                said["computed_over_live"] = round(computed_over_live(
-                    seq, r.block_q, r.block_k, said["diag_sub"]), 4)
+            said["computed_over_live"] = round(computed_over_live(
+                seq, r.block_q, r.block_k, said["diag_sub"], window), 4)
+            if window is not None:
+                said.update(window=window, tiles=[r.block_q, r.block_k])
         telemetry.event(names.ATTN_LAYOUT, **said)
         if r.why_not is not None:
             return merge_heads(attend(*split_heads(qkv, n_heads, n_kv)))
@@ -262,3 +284,12 @@ def make_length_aware_attention(window: Optional[int] = None):
 
 
 default_attention = make_length_aware_attention()
+
+
+@functools.lru_cache(maxsize=None)
+def attention_within(window: Optional[int]):
+    """The one instance a window: :data:`default_attention` for ``None``,
+    else :func:`make_length_aware_attention` of that window."""
+    if window is None:
+        return default_attention
+    return make_length_aware_attention(window)

@@ -9,19 +9,57 @@ output as it lies), and the fused RoPE+QKV kernel
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 
-def rope_angles(offset, seq: int, half: int, base: float) -> jax.Array:
+def rope_inv_freq(half: int, base: float) -> jax.Array:
+    """The plain inverse frequencies ``base^(-i / half)``, ``i`` in ``[0,
+    half)``, f32."""
+    return base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+
+
+def yarn_inv_freq(half: int, base: float, *, factor: float,
+                  original_positions: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0) -> jax.Array:
+    """YaRN's inverse frequencies (Peng et al. 2023, arXiv:2309.00071) for
+    ``half`` rotated pairs, f32: a pair that turns more than ``beta_fast``
+    times in ``original_positions`` keeps its plain frequency, one that
+    turns fewer than ``beta_slow`` times takes it over ``factor``, and the
+    pairs between them (their numbers cut off to whole ones, outwards) go
+    linearly from one to the other.  A function of constants: the
+    frequencies do not change with the length.  What multiplies cos and
+    sin with them (the attention factor) is the caller's ``scale``."""
+    def pair_that_turns(times):
+        return half * math.log(original_positions / (times * 2 * math.pi)) / (
+            math.log(base))
+
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), 2 * half - 1)
+    if low == high:
+        high += 0.001
+    plain = rope_inv_freq(half, base)
+    keep = 1.0 - jnp.clip(
+        (jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    return plain / factor * (1.0 - keep) + plain * keep
+
+
+def rope_angles_at(offset, seq: int, inv_freq: jax.Array) -> jax.Array:
     """f32 rotary angles ``[(b,) seq, half]`` for positions ``offset +
-    [0, seq)`` — the one place the angle math lives (``rope_rotate`` and
-    the fused RoPE+QKV kernel's tables both call it, so they cannot
-    drift)."""
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    [0, seq)`` at the inverse frequencies ``inv_freq [half]``: the one
+    place the angle math lives."""
     off = jnp.asarray(offset, jnp.float32)
     positions = off[..., None] + jnp.arange(seq, dtype=jnp.float32)
-    return positions[..., None] * freqs
+    return positions[..., None] * inv_freq
+
+
+def rope_angles(offset, seq: int, half: int, base: float) -> jax.Array:
+    """:func:`rope_angles_at` the plain frequencies of ``base``
+    (``rope_rotate`` and the fused RoPE+QKV kernel's tables both call it,
+    so they cannot drift)."""
+    return rope_angles_at(offset, seq, rope_inv_freq(half, base))
 
 
 def rope_rotate(x: jax.Array, base: float = 10000.0, offset=0,

@@ -80,11 +80,12 @@ def _softmax_scores(logits, k, choice_bias, scale):
     return expert_idx, weights, probs
 
 
-def _sigmoid_bias_scores(logits, k, choice_bias, scale):
+def _sigmoid_scores(logits, k, choice_bias, scale):
     """A sigmoid an expert (DeepSeek-V3's router, one group); the picks are
-    the ``k`` largest of ``score + choice_bias``, a buffer that steers the
-    choice alone and takes no gradient; the weights are the picks' UNbiased
-    scores over their sum (+ 1e-20), times ``scale``."""
+    the ``k`` largest scores, or where there is a ``choice_bias`` the ``k``
+    largest of ``score + choice_bias``, a buffer that steers the choice
+    alone and takes no gradient; the weights are the picks' UNbiased scores
+    over their sum (+ 1e-20), times ``scale``."""
     scores = jax.nn.sigmoid(logits)
     chosen = scores if choice_bias is None else scores + lax.stop_gradient(
         choice_bias.astype(jnp.float32))
@@ -95,9 +96,13 @@ def _sigmoid_bias_scores(logits, k, choice_bias, scale):
 
 
 #: scoring -> ``(logits f32, k, choice_bias, scale) -> (picks, weights,
-#: scores)``: the ways :func:`route` scores
+#: scores)``: the ways :func:`route` scores: ``softmax`` (over all experts,
+#: the picks' probabilities renormalised), ``sigmoid`` (a sigmoid an expert,
+#: the picks' scores renormalised times a scale) and ``sigmoid_bias`` (the
+#: same function: whoever builds the layer gives it the bias the picks go by)
 SCORINGS = {names.SOFTMAX: _softmax_scores,
-            names.SIGMOID_BIAS: _sigmoid_bias_scores}
+            names.SIGMOID: _sigmoid_scores,
+            names.SIGMOID_BIAS: _sigmoid_scores}
 
 
 def route(logits: jax.Array, *, n_experts: int, k: int,
@@ -108,8 +113,9 @@ def route(logits: jax.Array, *, n_experts: int, k: int,
     whatever the compute dtype (ties and gate scales are
     precision-sensitive), the top ``k``, and their weights, by ``scoring``
     (:data:`SCORINGS`: softmax with the picks' probabilities renormalised,
-    or sigmoid scores picked by ``score + choice_bias [n_experts]`` and
-    weighted by the picks' own renormalised scores times ``scale``).  The
+    or sigmoid scores, picked as they are or by ``score + choice_bias
+    [n_experts]``, and weighted by the picks' own renormalised scores times
+    ``scale``).  The
     width, the picks and the renormalisation do not depend on which experts
     are held here; ``held`` / ``first_expert`` only say which of the picks
     this device computes (``local``)."""

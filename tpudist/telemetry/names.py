@@ -76,9 +76,15 @@ PATTERN_LAYER = "layer"
 SSM = "ssm"
 SSD_SCAN = "ssd_scan"
 LATENT_PROJ = "latent_proj"
+# ``window_attn`` is a whole softmax mixer whose band is a sliding window
+# (a layer of kind WINDOW), as ``attn`` is one that attends causally to
+# everything; ``head_gate`` (the gate's projection, its sigmoid and the
+# product into the attention's output a head) nests in either
+WINDOW_ATTN = "window_attn"
+HEAD_GATE = "head_gate"
 SCOPES = (EMBED, ATTN, MLP, HEAD, LOSS, OPTIMIZER, GRAD_ACCUM, LINEAR_ATTN,
           DELTA_RULE, MOE, EXPERTS, SHARED_EXPERT, MOE_COMBINE, SSM, SSD_SCAN,
-          LATENT_PROJ)
+          LATENT_PROJ, WINDOW_ATTN, HEAD_GATE)
 #: what JAX itself writes round the scopes of a transposed (backward) op
 BACKWARD_MARK = "transpose("
 
@@ -179,8 +185,9 @@ COMPILE_CACHE_MISS = "compile_cache_miss"
 # [b, s, 3·d] output) or HEAD_MAJOR ([b, h, s, dh] operands, re-laid out
 # round the attention) with the ``reason=`` it was not packed; where the
 # flash kernels run, ``diag_sub=`` the squares a tile on the causal diagonal
-# is worked by (0: whole and masked) and, without a window,
-# ``computed_over_live=`` score entries computed over live pairs
+# is worked by (0: whole and masked), ``computed_over_live=`` score entries
+# computed over live pairs and, of a windowed call, ``window=`` and
+# ``tiles=`` [block_q, block_k]
 ATTN_LAYOUT = "attn_layout"
 PACKED = "packed"
 HEAD_MAJOR = "head_major"
@@ -193,7 +200,10 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # ``one_sublayer=`` whether a layer is one sublayer (a mixer OR the
 # feed-forward arm) and not a mixer and its feed-forward; ``attention=``
 # GATED_ATTN / NORMED_ATTN / GROUPED_ATTN with
-# ``attn_heads=`` [held, in all], ``attn_kv_heads=``, ``head_dim=``; of the
+# ``attn_heads=`` [held, in all], ``attn_kv_heads=``, or HEAD_GATED_ATTN with
+# ``softmax_kinds=`` a softmax kind (FULL, WINDOW) its ``heads`` [held, in
+# all], ``kv_heads``, ``window``, ``rotary_dim``, ``rope_theta``,
+# ``yarn_factor`` and ``rope_scale``; ``head_dim=``; of the
 # state-space mixers ``ssm_heads=`` and ``ssm_groups=`` [held, in all],
 # ``ssm_head_dim=``, ``ssm_state=``, ``ssm_chunk=``; of the
 # delta-rule mixers ``linear_heads=`` [value heads held, in all],
@@ -201,14 +211,16 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # ``linear_projections=`` FUSED / SEPARATE, ``beta_scale=`` (the write
 # strength is that times a sigmoid); ``heads_axis=`` the mapped axis the
 # members that share a layer by heads reduce over, or None;
-# ``feed_forward=`` EXPERT_SHARE / DENSE_FFN; ``norm=`` ZERO_CENTRED / PLAIN
+# ``feed_forward=`` EXPERT_SHARE / DENSE_FFN and ``feed_forwards=`` the arm
+# a layer; ``norm=`` ZERO_CENTRED / PLAIN
 # and ``norm_after=`` whether it follows its sublayer; ``remat_keeps=`` the
 # names a rematerialised layer keeps besides its input (MIXER_OUT, and
 # DENSE_FFN_KEEPS in the dense arm; in an expert layer of one sublayer
 # EXPERT_OUT, ROUTER_LOGITS, LATENT_IN where it has latent projections, and
 # SHARED_EXPERT_KEEPS' names of an unscored shared expert's first products;
 # ``[]`` without remat) and
-# ``remat_kept_bytes_per_layer=`` what they hold.  And of each expert
+# ``remat_kept_bytes_per_layer=`` what they hold (both a list a layer where
+# the layers' feed-forward arms differ).  And of each expert
 # layer
 # (tpudist/parallel/moe.py): ``experts=`` the router's width, ``held=``,
 # ``first=``, ``top_k=``, ``dropless=``, ``buffer_rows=`` (the bound: a
@@ -217,7 +229,8 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # where it keeps one buffer) and ``windows_at_most=`` how many of them the
 # bound fills (1), ``combine=`` PICK_MAJOR (each token's ``k`` rows are
 # added up as ``k`` slabs of [tokens, d]) or SCATTER_ADD (a window's rows
-# are added into their tokens'), ``scoring=`` SOFTMAX / SIGMOID_BIAS,
+# are added into their tokens'), ``scoring=`` SOFTMAX / SIGMOID /
+# SIGMOID_BIAS,
 # ``scale=`` what
 # the picks' renormalised weights are multiplied by, ``width=`` the rows'
 # width (an expert layer's latent width where it has one)
@@ -227,14 +240,17 @@ PICK_MAJOR = "pick_major"
 SCATTER_ADD = "scatter_add"
 LINEAR = "linear_attention"
 FULL = "full_attention"
+WINDOW = "sliding_attention"  # softmax attention inside a sliding window
 STATE_SPACE = "state_space"  # a Mamba-2 mixer (tpudist/ops/ssd.py)
 EXPERT_LAYER = "expert_layer"  # one-sublayer layers only: the feed-forward arm
 GATED_ATTN = "gated"         # per-head q/k norms, an output gate, part rotary
 NORMED_ATTN = "normed"       # one q/k norm statistic over all heads, no gate
 GROUPED_ATTN = "grouped"     # plain grouped-query: no norm, gate or rotary
+HEAD_GATED_ATTN = "head_gated"  # a gate a head from its own projection, rotary
 # tpudist/parallel/moe.py: how a router scores (``route``) and what an expert
 # computes
 SOFTMAX = "softmax"          # softmax over all experts, top k renormalised
+SIGMOID = "sigmoid"          # sigmoid scores, the top k renormalised x a scale
 SIGMOID_BIAS = "sigmoid_bias"  # sigmoid scores, picked by score + a bias
 GATED_SILU = "gated_silu"    # down(silu(gate(x)) * up(x))
 RELU2 = "relu2"              # down(relu(up(x))^2)
