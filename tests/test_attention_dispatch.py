@@ -22,13 +22,18 @@ V5E = "TPU v5 lite"
 
 
 def test_the_table_holds_the_two_rows_the_cells_were_measured_with():
-    assert attention.TILES == {V5E: Tiles(2048, 1024, 1024, 1024, 8192, 256)}
-    assert attention.DEFAULT_TILES == Tiles(1024, 512, 512, 1024, 8192, 256)
+    # the v5e row's last: a window under its tiles runs tiles of the
+    # window's own width, its edge tiles by squares of 256 (PR 42)
+    assert attention.TILES == {
+        V5E: Tiles(2048, 1024, 1024, 1024, 8192, 256, 256)}
+    assert attention.DEFAULT_TILES == Tiles(1024, 512, 512, 1024, 8192, 256,
+                                            0)
 
 
 # id: (device kind, seq, q heads, kv heads, dh, window) ->
 #     (kernel, layout, block_q, block_k, why_not[, what the event says of
-#      the diagonal tiles: diag_sub, computed_over_live])
+#      the band-edge tiles: diag_sub, computed_over_live[, and of a window's
+#      grid: grid_kv, live_steps_share]])
 ROUTES = {
     # cell cgpt590m-train-1chip: 2,048 positions, 12 heads of 128
     "v5e-gpt2-cell": ((V5E, 2048, 12, 12, 128, None),
@@ -45,10 +50,27 @@ ROUTES = {
     "v5e-tiles-do-not-divide": ((V5E, 2560, 8, 8, 128, None),
                                 (REFERENCE, names.HEAD_MAJOR, 1024, 1024,
                                  names.WHY_SEQ)),
-    # a window's band-edge tiles are computed whole: the row's tiles, two
-    # key tiles a query tile, most of each dead
+    # a window under the row's tiles: tiles of its own width, both edge
+    # tiles by squares of 256, two key tiles a query tile (7 of 8 steps a
+    # head live; the laguna cell's sliding layers at 8,192: 31 of 32)
     "v5e-windowed": ((V5E, 4096, 8, 2, 128, 512),
-                     (FLASH, names.PACKED, 1024, 1024, None, 0, 3.7328)),
+                     (FLASH, names.PACKED, 512, 512, None, 256, 1.4998, 2,
+                      0.9375)),
+    "v5e-laguna-cell-sliding": ((V5E, 8192, 36, 4, 128, 512),
+                                (FLASH, names.PACKED, 512, 512, None, 256,
+                                 1.4999, 2, 0.9688)),
+    # a window of whole row tiles keeps them, its edge tiles by the row's
+    # squares; one the row's tiles do not divide keeps them whole
+    "v5e-window-of-two-tiles": ((V5E, 8192, 8, 2, 128, 2048),
+                                (FLASH, names.PACKED, 1024, 1024, None, 256,
+                                 1.1249, 3, 0.875)),
+    "v5e-window-across-a-tile": ((V5E, 8192, 8, 2, 128, 1536),
+                                 (FLASH, names.PACKED, 1024, 1024, None, 0,
+                                  1.9309, 3, 0.875)),
+    # a kind nothing was timed on: a windowed call keeps its row's tiles
+    "default-row-windowed": (("TPU v6 lite", 4096, 8, 2, 128, 256),
+                             (FLASH, names.PACKED, 512, 512, None, 0,
+                              3.8705, 2, 0.9375)),
     # unequal tiles: whole
     "default-row-long": (("TPU v6 lite", 8192, 8, 8, 128, None),
                          (FLASH, names.PACKED, 512, 1024, None, 0, 1.1249)),
@@ -82,9 +104,12 @@ def test_route_and_dispatch(case, monkeypatch, tmp_path):
 
     (kind, seq, h, kv, dh, window), (kernel, layout, bq, bk, why_not,
                                      *diagonal) = ROUTES[case]
-    want = route(kind, seq, dh)
-    # the row's ``sub`` rides with the flash route alone
-    sub = attention.TILES.get(kind, attention.DEFAULT_TILES).sub
+    want = route(kind, seq, dh, window)
+    # the row's ``sub`` (``window_sub`` with a window's own tiles) rides
+    # with the flash route alone
+    row = attention.TILES.get(kind, attention.DEFAULT_TILES)
+    sub = row.window_sub if (bq, bk) != (
+        row.block_q, route(kind, seq, dh).block_k) else row.sub
     assert want == Route(kernel, bq, bk, why_not, sub * (kernel == FLASH))
     assert want.layout == layout
 
@@ -128,7 +153,9 @@ def test_route_and_dispatch(case, monkeypatch, tmp_path):
             lambda qkv: attend.packed(qkv, h, kv),
             jax.ShapeDtypeStruct((2, seq, (h + 2 * kv) * dh), jnp.bfloat16))
         events = [(r["layout"], r.get("reason"), r.get("diag_sub"),
-                   r.get("computed_over_live")) for r in session.ring
+                   r.get("computed_over_live"), r.get("grid_kv"),
+                   r.get("live_steps_share"), r.get("window"),
+                   r.get("tiles")) for r in session.ring
                   if r["name"] == names.ATTN_LAYOUT]
     finally:
         telemetry.finish(write_report=False)
@@ -137,8 +164,15 @@ def test_route_and_dispatch(case, monkeypatch, tmp_path):
     given = {FLASH: (kv, (want.block_q, want.block_k, want.sub)),
              BLOCKWISE: (h, (want.block_k,)), REFERENCE: (h, ())}[want.kernel]
     assert called == [(want.kernel, want.layout, *given, window)]
-    # only where the flash kernels run does the event speak of their tiles
-    assert events == [(want.layout, want.why_not, *(diagonal or (None, None)))]
+    # only where the flash kernels run does the event speak of their tiles,
+    # and only of a windowed call of its window, tiles and grid
+    said = (list(diagonal) + [None] * 4)[:4]
+    if len(diagonal) == 4:
+        said += [window, [bq, bk]]
+    else:
+        assert window is None or kernel != FLASH
+        said += [None, None]
+    assert events == [(want.layout, want.why_not, *said)]
 
 
 # (seq, block_q, block_k, sub) -> computed score entries over live pairs
@@ -183,9 +217,14 @@ def test_computed_over_live(case):
 DIAG_SUB = {
     "plain-causal": ((1024, 1024, 0, None, 256), 256),
     "plain-causal-by-128": ((512, 512, 0, None, 128), 128),
-    "window": ((1024, 1024, 0, 512, 256), 0),
+    "window-across-a-tile": ((1024, 1024, 0, 512, 256), 0),
+    "window-of-one-tile": ((512, 512, 0, 512, 128), 128),
+    "window-of-whole-tiles": ((1024, 1024, 0, 4096, 256), 256),
+    "window-of-one-and-a-half-tiles": ((1024, 1024, 0, 1536, 256), 0),
+    "window-unequal-blocks": ((512, 1024, 0, 1024, 256), 0),
     "ring-hop-shifted-band": ((1024, 1024, None, -1024, 256), 0),
     "band-with-both-edges": ((1024, 1024, 256, 2048, 256), 0),
+    "ring-hop-band-of-whole-tiles": ((1024, 1024, None, 1024, 256), 0),
     "not-causal": ((1024, 1024, None, None, 256), 0),
     "unequal-blocks": ((512, 1024, 0, None, 256), 0),
     "sub-does-not-divide": ((384, 384, 0, None, 256), 0),
@@ -196,6 +235,9 @@ DIAG_SUB = {
 
 @pytest.mark.parametrize("case", sorted(DIAG_SUB))
 def test_only_the_plain_causal_band_over_equal_blocks_is_cut(case):
+    """... and, since PR 42, a window over equal blocks whose width the
+    block divides: its diagonal tile and its far edge tile are the two
+    halves of one staircase."""
     args, want = DIAG_SUB[case]
     assert diag_sub(*args) == want
 

@@ -346,6 +346,29 @@ def _kernels_named(text: str) -> list:
         r'kernel_metadata=\{\s*"kernel"\s*:\s*"([^"]+)"', text)
 
 
+def _kernel_grids(text: str) -> list:
+    """``(name, grid)`` of every Mosaic custom call of a compiled text: the
+    name as :func:`_kernels_named` reads it, the grid out of the call's own
+    serialized body."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    grids = []
+    for name, body in re.findall(
+            r'"kernel"\s*:\s*"([^"]+)"\s*\}\}[^\n]*?"body":"([A-Za-z0-9+/=]+)"',
+            text):
+        ctx = mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm()
+        bounds = re.search(r"iteration_bounds = array<i64: ([\d, ]+)>", asm)
+        grids.append((name, tuple(int(n) for n in bounds.group(1).split(","))))
+    return grids
+
+
 @pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dkv"])
 def test_each_flash_kernel_is_named_once_a_layer(d1024_step, kernel):
@@ -707,8 +730,10 @@ def test_laguna_cell_step_fills_one_chip_and_fits(laguna_step):
 def test_laguna_cell_step_runs_the_band_and_groups_the_experts(laguna_step):
     """Five attention layers, three of them inside a window of 512: the
     three flash kernels by name, the forward twice a layer (every layer is
-    rematerialised), 20 calls, the sliding layers' at the row's 1024 x 1024
-    tiles like the full ones'.  The four expert layers' products are the compiler's grouped
+    rematerialised), 20 calls; the full layers' grids over every 1024 x
+    1024 tile, the sliding layers' over the band's run of 512 x 512 tiles
+    (two key tiles a query tile, two query tiles a key tile and group
+    member).  The four expert layers' products are the compiler's grouped
     matmuls over the 8 experts HELD at the model's width, over windows of
     20,480 rows (eight even shares of the 81,920 assignments a layer): 15 a
     layer, 80 in all, what the benchmark's runner holds the step to.  No
@@ -731,18 +756,34 @@ def test_laguna_cell_step_runs_the_band_and_groups_the_experts(laguna_step):
     # the three sliding layers hold 36 query heads, the two full ones 24
     outs = re.findall(r"%flash_fwd[\w.]* = \(bf16\[1,8192,(\d+)\]", text)
     assert sorted(outs) == ["3072"] * 4 + ["4608"] * 6
+    # 24 heads over 8 x 8 tiles, 6 a key/value head; 36 heads over 16 query
+    # tiles x the band's 2, 9 a key/value head
+    assert sorted(_kernel_grids(text)) == sorted(
+        [("flash_fwd", (24, 8, 8))] * 4 + [("flash_bwd_dq", (24, 8, 8))] * 2
+        + [("flash_bwd_dkv", (4, 8, 8 * 6))] * 2
+        + [("flash_fwd", (36, 16, 2))] * 6
+        + [("flash_bwd_dq", (36, 16, 2))] * 3
+        + [("flash_bwd_dkv", (4, 16, 2 * 9))] * 3)
 
 
 def test_a_512_band_over_8192_keys_computes_what_the_table_gives():
     """The figure the table gives the cell's sliding layers: a windowed
-    call keeps the row's 1024 x 1024 tiles, which compute 15 x 1024^2
-    entries a head for 4,063,488 live pairs, 3.87 times (tiles of the
-    window's own width would compute 31 x 512^2, 2.00 times, and ran 20%
-    longer on the chip: PERF.md section 6, PR 41; ROADMAP S24)."""
+    call runs tiles of the window's own width, 31 x 512^2 entries a head
+    whole for 4,063,488 live pairs, 2.00 times, and by squares of 256 three
+    quarters of that, 1.50 times (the row's 1024 x 1024 tiles, which a
+    call without a window keeps, would compute 15 x 1024^2, 3.87 times:
+    PERF.md section 6, PR 41 and PR 42)."""
     from tpudist.ops.attention import computed_over_live, route
+    from tpudist.ops.flash_attention import diag_sub
 
     r = route("TPU v5 lite", 8192, 128)
-    assert (r.block_q, r.block_k) == (1024, 1024)
-    assert computed_over_live(8192, r.block_q, r.block_k, 0, 512) == (
+    assert (r.block_q, r.block_k, r.sub) == (1024, 1024, V5E_SUB)
+    r = route("TPU v5 lite", 8192, 128, 512)
+    assert (r.block_q, r.block_k, r.sub) == (512, 512, 256)
+    assert diag_sub(r.block_q, r.block_k, 0, 512, r.sub) == 256
+    assert computed_over_live(8192, 1024, 1024, 0, 512) == (
         15 * 1024 ** 2 / 4_063_488)
-    assert round(computed_over_live(8192, 512, 512, 0, 512), 2) == 2.0
+    assert computed_over_live(8192, 512, 512, 0, 512) == (
+        31 * 512 ** 2 / 4_063_488)
+    assert computed_over_live(8192, 512, 512, r.sub, 512) == (
+        31 * 3 * 256 ** 2 / 4_063_488) <= 2.1

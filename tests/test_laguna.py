@@ -35,7 +35,10 @@ from tpudist import telemetry
 from tpudist.models import hybrid
 from tpudist.models.transformer import lm_loss
 from tpudist.ops import attention, rope
-from tpudist.ops.flash_attention import _tile_live
+from tpudist.ops.flash_attention import (_diagonal_strips, _far_edge_strips,
+                                         _first_live_kv, _first_live_q,
+                                         _last_live_kv, _tile_interior,
+                                         _tile_live, band_grid, diag_sub)
 from tpudist.parallel import moe
 from tpudist.telemetry import names
 
@@ -227,45 +230,111 @@ def test_a_window_has_one_attention_instance_and_no_window_the_default():
     assert attention.attention_within(64) is not one
 
 
-# (seq, block_q, block_k, window) -> score entries computed over live pairs
+# (seq, block_q, block_k, window) -> score entries computed over live pairs,
+# every tile whole and, where the window is as wide as whole tiles, its two
+# edge tiles by squares a quarter of the tile wide
 BANDS = {
-    (8192, 1024, 1024, 512): 3.871,     # the cell's sliding layers
-    (8192, 512, 512, 512): 2.0,         # the window's own width
-    (8192, 256, 256, 512): 1.5,
-    (8192, 1024, 512, 512): 2.968,
-    (8192, 1024, 1024, 4096): 1.25,
-    (4096, 1024, 1024, 512): 3.733,
-    (2048, 1024, 1024, 4096): 1.5,      # a window past the end: plain causal
+    (8192, 1024, 1024, 512): (3.871, None),
+    (8192, 512, 512, 512): (2.0, 1.25),   # the cell's sliding layers
+    (8192, 256, 256, 512): (1.5, 1.125),
+    (8192, 1024, 512, 512): (2.968, None),
+    (8192, 1024, 1024, 4096): (1.25, 1.0624),
+    (4096, 1024, 1024, 512): (3.733, None),
+    (2048, 1024, 1024, 4096): (1.5, 1.125),   # a window past the end: causal
 }
 
 
 @pytest.mark.parametrize("seq, bq, bk, window", sorted(BANDS))
 def test_what_a_windows_tiles_compute(seq, bq, bk, window):
     """``computed_over_live`` of a windowed call counts the tiles the
-    kernels' own predicate (``_tile_live``) keeps, whole, over the band's
-    live pairs."""
+    kernels' own predicate (``_tile_live``) keeps over the band's live
+    pairs: whole, or a tile an edge crosses by the squares on its live side
+    where ``diag_sub`` says the kernels take it so."""
+    whole, by_squares = BANDS[seq, bq, bk, window]
     got = attention.computed_over_live(seq, bq, bk, 0, window)
-    assert got == pytest.approx(BANDS[seq, bq, bk, window], rel=1e-3)
-    computed = sum(
-        bq * bk for i in range(seq // bq) for j in range(seq // bk)
-        if _tile_live(i, j, bq, bk, 0, window))
+    assert got == pytest.approx(whole, rel=1e-3)
+    live = [(i, j) for i in range(seq // bq) for j in range(seq // bk)
+            if _tile_live(i, j, bq, bk, 0, window)]
     q = np.arange(seq)
-    assert got == computed / np.minimum(q + 1, window).sum()
+    pairs = np.minimum(q + 1, window).sum()
+    assert got == len(live) * bq * bk / pairs
+    sub = diag_sub(bq, bk, 0, window, bq // 4)
+    assert sub == (0 if by_squares is None else bq // 4)
+    if sub:
+        got = attention.computed_over_live(seq, bq, bk, sub, window)
+        assert got == pytest.approx(by_squares, rel=1e-3)
+        # entry by entry from the strips the kernels unroll: the diagonal
+        # tile's staircase and the far edge tile's, every other tile whole
+        computed = 0
+        for i, j in live:
+            strips = (_diagonal_strips(bq, sub) if i == j else
+                      _far_edge_strips(bq, sub) if i - j == window // bq
+                      else [(slice(0, bq), slice(0, bk))])
+            tile = np.zeros((bq, bk), bool)
+            for rows, cols in strips:
+                assert not tile[rows, cols].any()
+                tile[rows, cols] = True
+            r, c = np.indices((bq, bk))
+            apart = (i * bq + r) - (j * bk + c)
+            assert tile[(apart >= 0) & (apart < window)].all()
+            computed += tile.sum()
+        assert got == computed / pairs
 
 
-def test_a_windowed_call_keeps_the_rows_tiles_and_its_causal_figures():
-    """A windowed call runs the row's own tiles (PR 41's traced pair kept
-    them: tiles of the window's width compute half as much and ran 20%
-    longer, their grid four times as many steps).  At 512 over 8,192 keys
-    that is 15 x 1024^2 entries a head for 4,063,488 live pairs; the causal
-    figures are what they were."""
-    r = attention.route("TPU v5 lite", 8192, 128)
+@pytest.mark.parametrize("seq, bq, bk, window", sorted(BANDS))
+def test_a_sweep_is_the_run_of_a_tiles_live_tiles(seq, bq, bk, window):
+    """For every band of the table: a query tile's first to last live key
+    tile is exactly the set ``_tile_live`` keeps, a key tile's sweep over
+    query tiles starts at its first live one, and the two grid axes are the
+    longest runs (the window's width in tiles plus one, or the sequence)."""
+    nq, nkv = seq // bq, seq // bk
+    kv_steps, q_steps, n_live, n_edge = band_grid(nq, nkv, bq, bk, 0, window)
+    live = np.array([[bool(_tile_live(i, j, bq, bk, 0, window))
+                      for j in range(nkv)] for i in range(nq)])
+    assert n_live == live.sum() and live.any(1).all() and live.any(0).all()
+    assert n_edge == sum(
+        not bool(_tile_interior(i, j, bq, bk, 0, window))
+        for i, j in zip(*np.nonzero(live)))
+    for i in range(nq):
+        run = range(int(_first_live_kv(i, nkv, bq, bk, window)),
+                    int(_last_live_kv(i, nkv, bq, bk, 0)) + 1)
+        assert list(np.flatnonzero(live[i])) == list(run)
+    for j in range(nkv):
+        assert int(_first_live_q(j, nq, bq, bk, 0, window)) == (
+            np.flatnonzero(live[:, j])[0])
+    assert kv_steps == live.sum(1).max() and q_steps == live.sum(0).max()
+    if bq == bk:
+        assert kv_steps == q_steps == min(nkv, -(-window // bk) + 1)
+
+
+def test_a_windowed_call_runs_tiles_of_the_windows_width():
+    """A window narrower than the row's tiles runs square tiles of its own
+    width, its two edge tiles by squares of 256 (PR 42: with the grid cut
+    to the band they beat every other tile of the sweep; before it they ran
+    20% longer than the row's, PR 41; squares of 128 run 5% faster still
+    and cost a tenth of the cell's set-up in trace and load).  At 512 over
+    8,192 keys that is 31 x 3 squares of 256 a head for 4,063,488 live
+    pairs, 32 grid steps a head for 31 live; a window at or over the row's
+    tiles, one the lanes do not
+    divide, or none keeps the row's, and the causal figures are what they
+    were."""
+    r = attention.route("TPU v5 lite", 8192, 128, 512)
     assert (r.kernel, r.block_q, r.block_k, r.sub) == (
-        attention.FLASH, 1024, 1024, 256)
+        attention.FLASH, 512, 512, 256)
+    assert attention.computed_over_live(8192, 512, 512, 256, 512) == (
+        31 * 3 * 256 ** 2 / 4_063_488) <= 2.1
+    # (every live tile of these two windows is one an edge crosses)
+    assert band_grid(16, 16, 512, 512, 0, 512) == (2, 2, 31, 31)
+    assert band_grid(8, 8, 1024, 1024, 0, 512) == (2, 2, 15, 15)
+    assert band_grid(8, 8, 1024, 1024, 0, None) == (8, 8, 36, 8)
+    # (384 is whole lanes wide and does not divide 8,192)
+    for window in (None, 1024, 4096, 100, 384, 384 + 64):
+        r = attention.route("TPU v5 lite", 8192, 128, window)
+        assert (r.kernel, r.block_q, r.block_k, r.sub) == (
+            attention.FLASH, 1024, 1024, 256)
+    assert attention.route("TPU v6 lite", 8192, 128, 256)[1:3] == (512, 1024)
     assert attention.computed_over_live(8192, 1024, 1024, 0, 512) == (
         15 * 1024 ** 2 / 4_063_488)
-    assert attention.computed_over_live(8192, 512, 512, 0, 512) == (
-        31 * 512 ** 2 / 4_063_488) <= 2.1
     assert attention.computed_over_live(
         8192, 1024, 1024, 256) == pytest.approx(1.0311, abs=1e-4)
 

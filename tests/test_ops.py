@@ -289,7 +289,13 @@ WHOLE = {
     "window": (True, 128, 128, 96),
     "window-wider-than-a-tile": (True, 64, 64, 100),
     "ring-hop-shifted-band": (False, 128, 128, (None, 64)),
+    "ring-hop-band-behind-the-shard": (False, 64, 64, (None, -70)),
+    "ring-hop-band-at-a-tile-edge": (False, 64, 64, (None, 128)),
+    "ring-hop-unequal-blocks": (False, 64, 128, (None, 100)),
     "band-with-both-edges": (False, 128, 128, (-32, 160)),
+    "band-with-no-live-tile-in-some-rows": (False, 64, 64, (100, 200)),
+    "window-unequal-blocks-wide-q": (True, 128, 64, 64),
+    "window-unequal-blocks-wide-k": (True, 64, 128, 128),
     "unequal-blocks-wide-k": (True, 64, 128, None),
     "unequal-blocks-wide-q": (True, 128, 64, None),
     "not-causal": (False, 128, 128, None),
@@ -381,9 +387,135 @@ class TestDiagonalTilesBySquares:
             return jax.value_and_grad(both, argnums=(0, 1, 2),
                                       has_aux=True)(q, k, v)
 
+        whole = run(0)
         for got, want in zip(jax.tree.leaves(run(32)),
-                             jax.tree.leaves(run(0))):
+                             jax.tree.leaves(whole)):
             np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # and the grid that follows the band gives what the sweep over
+        # every tile gave (the parent's schedule: every step from tile 0,
+        # the dead ones skipped): the live tiles in the same order
+        seen = []
+        real = kernels.band_grid
+
+        def every_tile(nq, nkv, *band):
+            seen.append((real(nq, nkv, *band)[:2], (nkv, nq)))
+            return nkv, nq, nq * nkv, 0
+
+        monkeypatch.setattr(kernels, "band_grid", every_tile)
+        monkeypatch.setattr(kernels, "_first_live_kv", lambda *a: 0)
+        monkeypatch.setattr(kernels, "_first_live_q", lambda *a: 0)
+        for got, want in zip(jax.tree.leaves(run(0)),
+                             jax.tree.leaves(whole)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        # a band with an upper edge that leaves tiles out has a shorter grid
+        assert all(band <= full for band, full in seen), seen
+        if case in ("ring-hop-band-behind-the-shard",
+                    "band-with-no-live-tile-in-some-rows",
+                    "window-unequal-blocks-wide-q",
+                    "window-wider-than-a-tile"):
+            assert all(band[0] < full[0] for band, full in seen), seen
+
+
+# (block, sub) and the windows of the band's grid: under the block, the
+# block, 1.5 and 2.5 blocks, and past the end of the 256 positions
+BAND_BLOCK, BAND_SUB, BAND_SEQ = 64, 16, 256
+BAND = {
+    f"{name}-{heads}-{layout}": (window, h, kv, layout)
+    for name, window in [("under", 32), ("block", 64), ("1.5-blocks", 96),
+                         ("2-blocks", 128), ("2.5-blocks", 160),
+                         ("past-the-end", 512)]
+    for heads, h, kv in [("mha", 2, 2), ("gqa9", 9, 1)]
+    for layout in ("head-major", "packed")
+}
+
+
+class TestTheGridFollowsTheBand:
+    """A sliding window's three kernels sweep the run of live tiles and no
+    more, a window as wide as whole tiles takes its two edge tiles by
+    squares, and forward, dq, dk and dv are the dense reference's."""
+
+    @pytest.mark.parametrize("case", sorted(BAND))
+    def test_values_and_gradients_match_the_reference(self, case,
+                                                      monkeypatch):
+        from tpudist.ops.attention import merge_heads, split_heads
+
+        window, h, kv, layout = BAND[case]
+        block, sub, seq, d = BAND_BLOCK, BAND_SUB, BAND_SEQ, 32
+        qkv = jax.random.normal(jax.random.PRNGKey(h),
+                                (1, seq, (h + 2 * kv) * d), jnp.float32)
+        w = jax.random.normal(jax.random.PRNGKey(9), (1, seq, h * d))
+        grids, cut = [], []
+        real_call = kernels.pl.pallas_call
+        monkeypatch.setattr(
+            kernels.pl, "pallas_call",
+            lambda *a, **k: grids.append(k["grid"]) or real_call(*a, **k))
+        for strips in ("_diagonal_strips", "_far_edge_strips"):
+            real = getattr(kernels, strips)
+            monkeypatch.setattr(
+                kernels, strips,
+                lambda *a, _name=strips, _real=real: cut.append(_name)
+                or _real(*a))
+
+        def flash(qkv):
+            if layout == "packed":
+                return flash_attention_packed(qkv, h, kv, True, block, block,
+                                              True, window, sub)
+            return merge_heads(flash_attention(
+                *split_heads(qkv, h, kv), True, block, block, True, window,
+                sub))
+
+        def reference(qkv):
+            q, k, v = split_heads(qkv, h, kv)
+            return merge_heads(attention_reference(
+                q, jnp.repeat(k, h // kv, 1), jnp.repeat(v, h // kv, 1),
+                causal=True, window=window))
+
+        def cotangent(fn):
+            return jax.grad(lambda x: jnp.sum(fn(x) * w))(qkv)
+
+        np.testing.assert_allclose(flash(qkv), reference(qkv), atol=2e-5,
+                                   rtol=2e-5)
+        # the gradient holds dq, dk and dv side by side
+        np.testing.assert_allclose(cotangent(flash), cotangent(reference),
+                                   atol=1e-4, rtol=1e-4)
+        # forward, forward again under grad, dq, dk/dv: a query tile's
+        # sweep is the window's width in tiles plus one, and a key tile's
+        # the same a group member
+        n = seq // block
+        steps = min(n, -(-window // block) + 1)
+        assert grids == [(h, n, steps)] * 3 + [(kv, n, steps * (h // kv))]
+        # a window of whole tiles: both staircases in each of the four
+        by_squares = window % block == 0
+        assert cut == ["_diagonal_strips", "_far_edge_strips"] * (
+            4 * by_squares)
+
+    @pytest.mark.parametrize("seq,bq,bk,lo,hi", [
+        (256, 64, 64, 0, 64), (256, 64, 64, 0, 96), (256, 64, 64, 0, 300),
+        (256, 32, 64, 0, 64), (256, 128, 64, 0, 100), (256, 64, 64, None, 64),
+        (256, 64, 64, None, -70), (256, 64, 64, -32, 160),
+        (256, 64, 64, 100, 200), (256, 64, 64, 0, None),
+        (256, 64, 128, 0, None), (256, 64, 64, None, None)])
+    def test_a_sweep_is_the_run_of_live_tiles(self, seq, bq, bk, lo, hi):
+        """First live to last live is exactly what ``_tile_live`` keeps, for
+        a query tile's key tiles and a key tile's query tiles, and the grid
+        axes are the longest runs."""
+        nq, nkv = seq // bq, seq // bk
+        live = np.array([[bool(kernels._tile_live(i, j, bq, bk, lo, hi))
+                          for j in range(nkv)] for i in range(nq)])
+        kv_steps, q_steps, n_live, _ = kernels.band_grid(nq, nkv, bq, bk, lo,
+                                                         hi)
+        assert n_live == live.sum()
+        assert kv_steps == max(live.sum(1).max(), 1)
+        assert q_steps == (nq if hi is None else max(live.sum(0).max(), 1))
+        for i in np.flatnonzero(live.any(1)):
+            first = int(kernels._first_live_kv(i, nkv, bq, bk, hi))
+            last = int(kernels._last_live_kv(i, nkv, bq, bk, lo))
+            assert list(np.flatnonzero(live[i])) == list(
+                range(first, last + 1))
+        if hi is not None:
+            for j in np.flatnonzero(live.any(0)):
+                first = int(kernels._first_live_q(j, nq, bq, bk, lo, hi))
+                assert first == np.flatnonzero(live[:, j])[0]
 
 
 class TestFusedMLP:

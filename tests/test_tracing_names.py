@@ -758,6 +758,45 @@ def test_attn_layout_fires_once_a_call_site_at_trace_time_and_not_a_step(
         telemetry.finish(write_report=False)
 
 
+def test_a_windowed_calls_attn_layout_says_its_grid(tmp_path, monkeypatch):
+    """Of a windowed call where the flash kernels run, the event says the
+    window, the tiles, how the band-edge tiles are worked, what that
+    computes, the key axis of the grid and the share of a head's grid steps
+    that find a live tile (the laguna cell's sliding layers: 32 steps for
+    31 live); the vocabulary's comment names every field."""
+    from pathlib import Path
+    from types import SimpleNamespace
+
+    from tpudist.ops import attention
+
+    monkeypatch.setattr(
+        jax, "devices",
+        lambda *a, **k: [SimpleNamespace(device_kind="TPU v5 lite")])
+    monkeypatch.setattr(attention, "flash_attention_packed",
+                        lambda qkv, h, kv, *a: qkv[..., : h * 128])
+    session = telemetry.start(tmp_path / "tele", rank=0, generation=0)
+    try:
+        for window in (512, None):
+            jax.eval_shape(
+                lambda qkv: attention.attention_within(window).packed(
+                    qkv, 36, 4),
+                jax.ShapeDtypeStruct((1, 8192, 44 * 128), jnp.bfloat16))
+        windowed, plain = [
+            {k: v for k, v in r.items() if k not in ("t", "kind", "name", "dur", "gen", "rank")}
+            for r in session.ring if r["name"] == names.ATTN_LAYOUT]
+    finally:
+        telemetry.finish(write_report=False)
+    assert windowed == dict(
+        layout=names.PACKED, diag_sub=256, computed_over_live=1.4999,
+        window=512, tiles=[512, 512], grid_kv=2, live_steps_share=0.9688)
+    assert plain == dict(layout=names.PACKED, diag_sub=256,
+                         computed_over_live=1.0311)
+    source = Path(names.__file__).read_text()
+    comment = source[:source.index("ATTN_LAYOUT =")].rsplit("\n\n", 1)[1]
+    for field in (*windowed, "reason"):
+        assert f"``{field}=``" in comment, field
+
+
 def test_the_pattern_decoder_says_its_layout_once_a_trace(tmp_path):
     """``mixer_layout`` (the layer kinds) once a trace of the decoder and
     ``moe_layout`` once an expert layer, at trace time and not a step."""

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from tpudist import telemetry
 from tpudist.ops.flash_attention import (
     attention_reference,
+    band_grid,
     blockwise_attention,
     diag_sub,
     flash_attention,
@@ -43,6 +44,11 @@ class Tiles(NamedTuple):
     block_k_long: int   # the KV tile from ``long_seq`` positions, where it
     long_seq: int       # divides the length
     sub: int            # a tile on the diagonal goes by squares this wide
+    # a sliding window narrower than ``block_q`` runs square tiles of its
+    # own width (where that is a multiple of the 128 lanes and divides the
+    # length), its two edge tiles by squares this wide; 0: the row's tiles,
+    # as every other call
+    window_sub: int = 0
 
 
 # The v5e row is what the round-2 autotuner wrote for this kind (1024 x 1024
@@ -60,21 +66,34 @@ class Tiles(NamedTuple):
 # The tiles themselves have not been swept on the cells' shapes; where
 # they turn out to depend on the shape the key grows here.
 #
-# A sliding window's band has two edges and no ``sub``: a tile either edge
-# crosses is computed whole and masked, so a call inside a window of 512
-# over 8,192 positions computes 3.87 times its live pairs by the row's 1024
-# x 1024 tiles (:func:`computed_over_live`).  Tiles of the window's own
-# width compute 2.00 times and were slower on the chip at the one cell that
-# has window layers (PR 41, a traced pair at 36 query on 4 key/value heads:
-# the three kernels 50.8 ms a step at 512 x 512 against 42.4 at the row's):
-# the kernels' grids run over every (query, key) tile and skip the dead
-# ones a step at a time, 256 steps a head at 512 against 64.  So a windowed
-# call keeps the row's tiles until the grid itself follows the band.
+# A sliding window's band has two edges, and the kernels' grids follow it
+# (PR 42): a query tile sweeps the run of its live key tiles and a key tile
+# the run of its live query tiles (``flash_attention.band_grid``), so narrow
+# tiles no longer pay for the tiles they skip (0.4 us a skipped step).
+# Inside a window of 512 over 8,192 positions the row's 1024 x 1024 tiles
+# compute 3.87 times the live pairs; tiles of the window's own width 2.00
+# times whole, and by squares of ``window_sub`` (the tile on the diagonal
+# and the one the far edge crosses are the two halves of one staircase,
+# ``flash_attention.diag_sub``) 1.50 at 256 and 1.25 at 128
+# (:func:`computed_over_live`).  ``window_sub`` 256 is PR 42's, from the one
+# cell that has window layers (36 query on 4 key/value heads of 128), a
+# traced run of it a step of the way: the three kernels of its three sliding
+# layers 42.4 ms a step on the parent, 36.6 with the grid cut alone (the
+# row's tiles), 28.7 by 512 x 512 tiles whole, 28.3 by squares of 256, 26.8
+# of 128; and from the kernels alone timed at the same shape, where 256 x
+# 256 tiles (16.5 ms a layer against 9.8), 1024 x 512 (14.1) and 512 x 1024
+# (11.5) all lost (PERF.md section 6).  Squares of 128 are 1.5 ms a step
+# faster and were not kept for what they cost a start: eight unrolled strips
+# a kernel at twelve call sites put 2.9 s on the step's trace and 1.7 on its
+# load from the compile cache, a tenth of the cell's warm set-up, where
+# squares of 256 cost 0.4 s of trace.  Only a window of 512 has been timed:
+# narrower ones take the same rule untried.
 TILES = {
-    "TPU v5 lite": Tiles(2048, 1024, 1024, 1024, 8192, 256),
+    "TPU v5 lite": Tiles(2048, 1024, 1024, 1024, 8192, 256, 256),
 }
 # Every other kind: the values first chosen on a v5e in round 2, before the
-# autotuner.  The blockwise route off the TPU reads its ``block_k`` here.
+# autotuner.  The blockwise route off the TPU reads its ``block_k`` here.  A
+# windowed call keeps the row's tiles: nothing was timed on another kind.
 DEFAULT_TILES = Tiles(1024, 512, 512, 1024, 8192, 256)
 
 FLASH, REFERENCE, BLOCKWISE = "flash", "reference", "blockwise"
@@ -85,17 +104,19 @@ class Route(NamedTuple):
     block_q: int
     block_k: int
     why_not: Optional[str]   # why not packed flash (names.WHY_*), or None
-    sub: int = 0             # the row's, for the flash kernels' diagonal tiles
+    sub: int = 0             # the row's, for the flash kernels' band-edge tiles
 
     @property
     def layout(self) -> str:
         return names.PACKED if self.why_not is None else names.HEAD_MAJOR
 
 
-def route(device_kind: str, seq: int, dh: int) -> Route:
+def route(device_kind: str, seq: int, dh: int,
+          window: Optional[int] = None) -> Route:
     """What runs for ``seq`` positions at head width ``dh`` on a device of
-    this kind (causal to everything or inside a sliding window: the tiles
-    are the row's either way).
+    this kind, causal to everything or inside a sliding ``window``: the
+    row's tiles, or for a window narrower than them square tiles of the
+    window's width where the row has a ``window_sub``.
 
     The flash kernels take a length from the row's ``min_seq`` that both
     tiles divide (the kernels' contract), on a TPU: a kind whose name
@@ -113,8 +134,11 @@ def route(device_kind: str, seq: int, dh: int) -> Route:
         return Route(REFERENCE, t.block_q, bk, names.WHY_SEQ)
     if not device_kind.startswith("TPU"):
         return Route(BLOCKWISE, t.block_q, bk, names.WHY_PLATFORM)
-    return Route(FLASH, t.block_q, bk, names.WHY_DH if dh % 128 else None,
-                 t.sub)
+    why_not = names.WHY_DH if dh % 128 else None
+    if (window is not None and t.window_sub and window < t.block_q
+            and window % 128 == 0 == seq % window):
+        return Route(FLASH, window, window, why_not, t.window_sub)
+    return Route(FLASH, t.block_q, bk, why_not, t.sub)
 
 
 def computed_over_live(seq: int, block_q: int, block_k: int, sub: int = 0,
@@ -122,26 +146,18 @@ def computed_over_live(seq: int, block_q: int, block_k: int, sub: int = 0,
     """Score entries the flash kernels compute over the live pairs of
     causal attention (``seq·(seq+1)/2`` of them, or inside a sliding
     ``window`` position ``q``'s ``min(q + 1, window)``): every tile the band
-    touches whole, but a tile on the diagonal of the plain causal band,
-    where the kernels work it by ``sub``-wide squares (``sub`` as
-    :func:`diag_sub` gives it, 0 for the whole tile and for every window),
-    only the squares on or under the diagonal.  1024 x 1024 tiles: 1.50
-    whole and 1.125 at ``sub`` 256 over 2,048 positions, 1.125 and 1.031
-    over 8,192; a window of 512 over 8,192 positions 3.87 (2.00 if the tiles
-    were 512 x 512)."""
+    touches whole, but a tile an edge of the band crosses where the kernels
+    work it by ``sub``-wide squares (``sub`` as :func:`diag_sub` gives it, 0
+    for the whole tile), only the squares on its live side of the edge.
+    1024 x 1024 tiles: 1.50 whole and 1.125 at ``sub`` 256 over 2,048
+    positions, 1.125 and 1.031 over 8,192; a window of 512 over 8,192
+    positions 3.87, by 512 x 512 tiles 2.00 whole, 1.50 at ``sub`` 256 and
+    1.25 at 128."""
+    _, _, tiles, edge = band_grid(seq // block_q, seq // block_k, block_q,
+                                  block_k, 0, window)
     n = block_q // sub if sub else 0
-    computed = 0
-    for i in range(seq // block_q):
-        for j in range(seq // block_k):
-            if (i + 1) * block_q - 1 < j * block_k:
-                continue                                    # elided
-            if window is not None and (
-                    i * block_q - ((j + 1) * block_k - 1) >= window):
-                continue                    # behind the window: elided too
-            if sub and i * block_q < (j + 1) * block_k - 1:
-                computed += n * (n + 1) // 2 * sub * sub    # on the diagonal
-            else:
-                computed += block_q * block_k
+    staircase = n * (n + 1) // 2 * sub * sub if sub else block_q * block_k
+    computed = (tiles - edge) * block_q * block_k + edge * staircase
     live = (seq * (seq + 1) / 2 if window is None or window >= seq else
             window * (window + 1) / 2 + (seq - window) * window)
     return computed / live
@@ -223,7 +239,8 @@ def make_length_aware_attention(window: Optional[int] = None):
     layout :func:`route` chose, and why where it is not the packed one.
     """
     def attend(q, k, v):
-        r = route(jax.devices()[0].device_kind, q.shape[2], q.shape[3])
+        r = route(jax.devices()[0].device_kind, q.shape[2], q.shape[3],
+                  window)
         if r.kernel == FLASH:
             return _per_shard(
                 lambda q, k, v: flash_attention(
@@ -249,19 +266,25 @@ def make_length_aware_attention(window: Optional[int] = None):
         them; everywhere else: split, :func:`attend`, merge."""
         dh = qkv.shape[-1] // (n_heads + 2 * n_kv)
         seq = qkv.shape[1]
-        r = route(jax.devices()[0].device_kind, seq, dh)
+        r = route(jax.devices()[0].device_kind, seq, dh, window)
         said = dict(layout=r.layout)
         if r.why_not is not None:
             said["reason"] = r.why_not
         if r.kernel == FLASH:
-            # how a tile on the diagonal is worked (0: whole, as every tile
-            # a window's edges cross), and what that makes the kernels
-            # compute
+            # how a tile an edge of the band crosses is worked (0: whole),
+            # and what that makes the kernels compute
             said["diag_sub"] = diag_sub(r.block_q, r.block_k, 0, window, r.sub)
             said["computed_over_live"] = round(computed_over_live(
                 seq, r.block_q, r.block_k, said["diag_sub"], window), 4)
             if window is not None:
-                said.update(window=window, tiles=[r.block_q, r.block_k])
+                # the key axis of the forward and dq grids, and how many of
+                # a head's grid steps find a live tile
+                nq = seq // r.block_q
+                steps, _, live, _ = band_grid(nq, seq // r.block_k,
+                                              r.block_q, r.block_k, 0, window)
+                said.update(window=window, tiles=[r.block_q, r.block_k],
+                            grid_kv=steps,
+                            live_steps_share=round(live / (nq * steps), 4))
         telemetry.event(names.ATTN_LAYOUT, **said)
         if r.why_not is not None:
             return merge_heads(attend(*split_heads(qkv, n_heads, n_kv)))

@@ -1,7 +1,7 @@
 """Pallas TPU flash attention (blockwise online-softmax) kernel.
 
 The single-chip hot op behind the long-context path: materializes no
-``[seq, seq]`` score matrix — the grid is (batch·heads, q_block, kv_block)
+``[seq, seq]`` score matrix — the grid is (batch·heads, q_block, kv step)
 with KV innermost, the (m, l, acc) online-softmax state lives in VMEM
 scratch across each Q row's KV sweep, and only one [block_k, d] K/V tile
 is VMEM-resident at a time (sequence length is bounded by HBM, not VMEM);
@@ -63,15 +63,35 @@ kernel's math.  Strips of q rows and not of keys, and one rectangle a
 strip and not its square and the rest apart: a strip of keys updates the
 row statistics (or reads ``lse`` and ``delta``) for every q row below it,
 2.5 times a tile's rows, and each further piece is a further round trip
-of ``m``, ``l``, ``acc`` through VMEM (PERF.md section 6, PR 33).  Every
-other band (a window, a ring hop's shifted band, unequal blocks) computes
-its band-edge tiles whole and masked.  ``sub`` is an argument like the
-blocks: ``ops/attention.py`` passes its table's, a caller that names none
-gets ``DIAG_SUB``.
+of ``m``, ``l``, ``acc`` through VMEM (PERF.md section 6, PR 33).  A
+sliding window as wide as a whole number of (equal) tiles has a second
+such edge: the tile ``window / block`` tiles before the diagonal lives
+ABOVE the same staircase, and goes by the complementary strips, each
+against the keys from its own square to the tile's end
+(:func:`_far_edge_strips`); at a window of one tile every live tile is one
+of the two.  Every other band (a window the block does not divide, a ring
+hop's shifted band, unequal blocks) computes its band-edge tiles whole and
+masked.  ``sub`` is an argument like the blocks: ``ops/attention.py``
+passes its table's, a caller that names none gets ``DIAG_SUB``.
+
+What a grid step costs.  A grid pays for the tiles it skips (a quarter to
+four tenths of a microsecond a dead step on a v5e, PR 41 and PR 42), so the
+grids follow the band the call states (``lo <= q − k < hi``).  The live key
+tiles of a query tile are ONE run, :func:`_first_live_kv` to
+:func:`_last_live_kv`, and the forward and dq grids' innermost axis is as
+long as the longest run and no longer (:func:`band_grid`): step ``j`` works
+tile ``first + j``.  dk/dv the transposed way: a key tile's live query tiles
+are a run too, and its innermost axis is the group x the longest such run.
+Steps past a run's end (near the sequence's ends) are skipped with their
+fetch clamped.  Without an upper edge (the plain causal band, a ring hop
+with none) a run starts at tile 0 and the longest is the whole sequence:
+those grids, index maps and bodies are what they were before the band had a
+say.  A window of 512 over 8,192 positions takes 32 steps a head for 31
+live by 512 x 512 tiles, where a grid over every tile took 256.
 
 ``tpudist.ops.attention.make_length_aware_attention`` picks the layout,
 the tiles and ``sub`` from what it can observe (device kind, length,
-``d % 128``).
+``d % 128``, the window).
 
 The module also holds the plain-XLA side of the same math:
 :func:`attention_reference` (the dense ground truth every kernel is tested
@@ -211,11 +231,12 @@ def _band_live_pairs(seq_q: int, seq_k: int, lo, hi) -> int:
     return int(np.clip(k_hi - k_lo + 1, 0, None).sum())
 
 
-def _tile_live(qi, kv, block_q: int, block_k: int, lo, hi):
-    """Whether tile (qi, kv) intersects the band ``lo <= q − k < hi``.
-    The unbounded form keeps a traced always-true predicate so every
-    variant flows through the same ``pl.when``."""
-    live = kv >= 0
+def _tile_live(qi, kv, block_q: int, block_k: int, lo, hi, inside=None):
+    """Whether tile (qi, kv) intersects the band ``lo <= q − k < hi``
+    (and, of a sweep that can run past the sequence's end, is ``inside``
+    it: :func:`_sweep_tile`).  The unbounded form keeps a traced always-true
+    predicate so every variant flows through the same ``pl.when``."""
+    live = kv >= 0 if inside is None else inside
     if lo is not None:
         # max(q − k) over the tile = (qi+1)·bq − 1 − kv·bk
         live &= (qi + 1) * block_q - 1 - kv * block_k >= lo
@@ -246,16 +267,18 @@ _ALL = slice(None)
 
 
 def diag_sub(block_q: int, block_k: int, lo, hi, sub) -> int:
-    """The width of the squares a tile on the diagonal is worked by, or 0
-    where such a tile is computed whole and masked.  Decided by what the
-    call states: the plain causal band (``lo == 0``, no ``hi``) over
-    equal blocks is the one case where every band-edge tile has its corner
-    on the diagonal (``qi·block_q == kv·block_k``), so its live part is
-    the same static staircase of ``sub x sub`` squares in every such tile.
-    A window, a ring hop's shifted band, unequal blocks, or a ``sub`` that
+    """The width of the squares a tile a band's edge crosses is worked by,
+    or 0 where such a tile is computed whole and masked.  Decided by what
+    the call states: a causal band (``lo == 0``) over equal blocks with no
+    upper edge, or one at a multiple of the block (a sliding window as wide
+    as a whole number of tiles), is the case where every band-edge tile has
+    its corner on an edge: a tile on the diagonal (``qi == kv``) lives under
+    the same static staircase of ``sub x sub`` squares, and a tile on the
+    window's far edge (``qi - kv == hi / block``) above it.  Any other
+    window, a ring hop's shifted band, unequal blocks, or a ``sub`` that
     does not cut the block into at least two keep the whole-tile branch."""
-    if (lo == 0 and hi is None and block_q == block_k and sub
-            and block_q % sub == 0 and block_q > sub):
+    if (lo == 0 and block_q == block_k and sub and block_q % sub == 0
+            and block_q > sub and (hi is None or hi % block_q == 0)):
         return sub
     return 0
 
@@ -272,15 +295,28 @@ def _diagonal_strips(block: int, sub: int):
             for i in range(block // sub)]
 
 
-def _mask_diagonal_square(s, keep):
-    """Mask the one square of a strip's scores ``s`` that lies on the
-    diagonal (its last columns, see :func:`_diagonal_strips`) to ``keep``,
-    the square's ``q >= k``; the rest of the strip passes untouched, so the
-    mask chain costs ``sub x sub`` a strip."""
+def _far_edge_strips(block: int, sub: int):
+    """The live part of a tile on a window's far edge (``q - k < hi`` with
+    ``hi`` a multiple of the block: the strict upper triangle, the
+    complement of :func:`_diagonal_strips`' staircase): each strip of
+    ``sub`` q rows against the keys FROM its own square to the tile's end.
+    Only that square (the strip's first ``sub`` columns) is half dead."""
+    return [(slice(i * sub, (i + 1) * sub), slice(i * sub, block))
+            for i in range(block // sub)]
+
+
+def _mask_edge_square(s, keep, far: bool = False):
+    """Mask the one square of a strip's scores ``s`` that an edge crosses to
+    ``keep``: the strip's last columns and the square's ``q >= k`` on the
+    diagonal (:func:`_diagonal_strips`), its first columns and ``q < k`` on
+    a window's far edge (:func:`_far_edge_strips`); the rest of the strip
+    passes untouched, so the mask chain costs ``sub x sub`` a strip."""
     sub = keep.shape[0]
-    square = jnp.where(keep, s[:, -sub:], _MASK_VALUE)
+    square = jnp.where(keep, s[:, :sub] if far else s[:, -sub:], _MASK_VALUE)
     if s.shape[1] == sub:
         return square
+    if far:
+        return jnp.concatenate([square, s[:, sub:]], axis=1)
     return jnp.concatenate([s[:, :-sub], square], axis=1)
 
 
@@ -289,9 +325,10 @@ def _masked_tile_branches(live, qi, kv, block_q: int, block_k: int, lo, hi,
     """Run ``update(rows, cols, mask)`` (one rectangle of the tile, ``mask``
     a function of its scores or ``None``) under the live predicate: an
     interior tile whole and unmasked; a band-edge tile whole and masked to
-    the band, or, where :func:`diag_sub` says it is a tile on the diagonal
-    (``sub``), strip by strip (:func:`_diagonal_strips`).  Bandless kernels
-    keep the single unmasked branch."""
+    the band, or, where :func:`diag_sub` says its corner lies on an edge
+    (``sub``), strip by strip: :func:`_diagonal_strips` on the diagonal,
+    :func:`_far_edge_strips` on a window's far edge.  Bandless kernels keep
+    the single unmasked branch."""
     if lo is None and hi is None:
         @pl.when(live)
         def _():
@@ -303,17 +340,29 @@ def _masked_tile_branches(live, qi, kv, block_q: int, block_k: int, lo, hi,
     def _():
         update(_ALL, _ALL, None)
 
-    @pl.when(live & jnp.logical_not(interior))
-    def _():
-        if not sub:
+    edge = live & jnp.logical_not(interior)
+
+    def by_strips(far: bool):
+        row = lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
+        col = lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+        mask = functools.partial(_mask_edge_square, far=far,
+                                 keep=row < col if far else row >= col)
+        strips = _far_edge_strips if far else _diagonal_strips
+        for rows, cols in strips(block_q, sub):
+            update(rows, cols, mask)
+
+    if not sub:
+        @pl.when(edge)
+        def _():
             update(_ALL, _ALL, lambda s: _tile_band_mask(
                 s, qi, kv, block_q, block_k, lo, hi))
-            return
-        keep = (lax.broadcasted_iota(jnp.int32, (sub, sub), 0)
-                >= lax.broadcasted_iota(jnp.int32, (sub, sub), 1))
-        mask = functools.partial(_mask_diagonal_square, keep=keep)
-        for rows, cols in _diagonal_strips(block_q, sub):
-            update(rows, cols, mask)
+    elif hi is None:
+        pl.when(edge)(lambda: by_strips(False))
+    else:
+        # equal blocks: the tile on the diagonal, or the one the window's
+        # far edge crosses
+        pl.when(edge & (qi == kv))(lambda: by_strips(False))
+        pl.when(edge & (qi != kv))(lambda: by_strips(True))
 
 
 def _tile_band_mask(s, qi, kv, block_q: int, block_k: int, lo, hi):
@@ -336,9 +385,8 @@ def _tile_band_mask(s, qi, kv, block_q: int, block_k: int, lo, hi):
 
 
 def _last_live_kv(qi, nkv, block_q: int, block_k: int, lo):
-    """Index of Q row ``qi``'s last live KV tile (the emission point of the
-    KV-innermost sweeps).  Only the band's lower edge bounds it: k ranges
-    up to q − lo."""
+    """Index of Q row ``qi``'s last live KV tile.  Only the band's lower
+    edge bounds it: k ranges up to q − lo."""
     if lo is None:
         return nkv - 1
     return jnp.clip(
@@ -346,39 +394,104 @@ def _last_live_kv(qi, nkv, block_q: int, block_k: int, lo):
     )
 
 
+def _first_live_kv(qi, nkv, block_q: int, block_k: int, hi):
+    """Index of Q row ``qi``'s first live KV tile.  Only the band's upper
+    edge bounds it (k ranges down to q − hi + 1); without one it is the
+    Python constant 0 and nothing is traced."""
+    if hi is None:
+        return 0
+    return jnp.minimum(jnp.maximum(qi * block_q - hi + 1, 0) // block_k,
+                       nkv - 1)
+
+
+def _first_live_q(kv, nq, block_q: int, block_k: int, lo, hi):
+    """Where KV tile ``kv``'s sweep over Q tiles starts (the transposed
+    schedule of dk/dv).  Only under an upper edge is the longest run of
+    live Q tiles shorter than the sequence, and the sweep then starts at the
+    tile's first live one (q ranges up from k + lo); without one key tile 0
+    sees every Q tile, so the sweep is over all of them from tile 0, the
+    dead ones clamped, as it has always been."""
+    if hi is None or lo is None:
+        return 0
+    return jnp.minimum(jnp.maximum(kv * block_k + lo, 0) // block_q, nq - 1)
+
+
+def band_grid(nq: int, nkv: int, block_q: int, block_k: int, lo,
+              hi) -> tuple[int, int, int, int]:
+    """``(kv_steps, q_steps, live, edge)`` of the band ``lo <= q − k < hi``
+    over ``nq x nkv`` tiles, static, from what the call states: the length
+    of a Q tile's sweep over KV tiles (forward, dq) and of a KV tile's sweep
+    over Q tiles (dk/dv), the number of live tiles, and how many of those an
+    edge of the band crosses.  A tile row's (or column's) live tiles are ONE
+    run, :func:`_first_live_kv` to :func:`_last_live_kv`, and a sweep is as
+    long as the longest: the window's width in tiles plus one under an
+    upper edge, every tile without one (the plain causal band keeps the
+    grid it has always had)."""
+    import numpy as np
+
+    at = np.indices((nq, nkv))
+    live = _tile_live(*at, block_q, block_k, lo, hi)
+    edge = live & ~_tile_interior(*at, block_q, block_k, lo, hi)
+    return (max(int(live.sum(1).max()), 1),
+            nq if hi is None else max(int(live.sum(0).max()), 1),
+            int(live.sum()), int(edge.sum()))
+
+
 def _band_kv_index(block_q: int, block_k: int, lo, hi, nkv: int):
-    """Index map for the KV-innermost sweeps: dead KV tiles (outside the
-    band on either side) re-map to the Q row's nearest live tile — Pallas
-    elides the DMA when consecutive grid steps repeat a block index, so
-    dead tiles cost neither fetch bandwidth nor compute (the kernels'
-    ``_tile_live`` predicate is already false there)."""
+    """Index map for the KV-innermost sweeps: step ``j`` of Q row ``i``
+    reads the row's first live tile + ``j`` (tile ``j`` itself where the
+    band has no upper edge); steps past the row's last live tile re-map to
+    it — Pallas elides the DMA when consecutive grid steps repeat a block
+    index, so dead steps cost neither fetch bandwidth nor compute (the
+    kernels' ``_tile_live`` predicate is already false there)."""
     def kv_index(b, i, j):
+        if hi is not None:
+            j = j + _first_live_kv(i, nkv, block_q, block_k, hi)
         if lo is not None:
             j = jnp.minimum(j, ((i + 1) * block_q - 1 - lo) // block_k)
-        if hi is not None:
-            j = jnp.maximum(
-                j, jnp.maximum(i * block_q - hi + 1, 0) // block_k
-            )
         return (b, jnp.clip(j, 0, nkv - 1), 0)
 
     return kv_index
 
 
+def _sweep_tile(first, j, n_tiles: int):
+    """``(tile, inside)`` of step ``j`` of a sweep that starts at tile
+    ``first``: the step itself where ``first`` is the constant 0 (the sweep
+    is then no longer than the sequence: ``inside`` is ``None``), else
+    ``first + j`` and whether that is still a tile of the sequence."""
+    if isinstance(first, int) and first == 0:
+        return j, None
+    tile = first + j
+    return tile, tile < n_tiles
+
+
+def _emit_step(qi, steps, block_q: int, block_k: int, lo, hi):
+    """The step of Q row ``qi``'s KV sweep that emits.  Without an upper
+    edge the sweep starts at tile 0 and that is the row's last live tile,
+    as it has always been; a band's own sweep emits at its last step (a row
+    with no live tile at all has one too)."""
+    if hi is None:
+        return _last_live_kv(qi, steps, block_q, block_k, lo)
+    return steps - 1
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                   *, block_q: int, block_k: int, lo, hi, sub: int,
-                  scale: float):
-    """One (bh, q_block, kv_block) grid step.
+                  scale: float, nkv: int):
+    """One (bh, q_block, kv step) grid step.
 
     The grid's KV dimension is innermost (TPU grids run sequentially), so
     the (m, l, acc) online-softmax state lives in VMEM scratch across the
     KV sweep of each Q block; only one [block_k, d] K/V tile is resident at
-    a time — sequence length is bounded by HBM, not VMEM.
+    a time — sequence length is bounded by HBM, not VMEM.  The sweep is the
+    run of the Q block's live tiles (:func:`band_grid`): ``nkv`` tiles from
+    tile 0 without an upper edge, else from :func:`_first_live_kv`.
     """
     qi = pl.program_id(1)
-    kv = pl.program_id(2)
-    nkv = pl.num_programs(2)
+    j = pl.program_id(2)
+    steps = pl.num_programs(2)
 
-    @pl.when(kv == 0)
+    @pl.when(j == 0)
     def _():
         m_ref[:] = jnp.full_like(m_ref, _MASK_VALUE)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -407,15 +520,18 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
 
-    _masked_tile_branches(_tile_live(qi, kv, block_q, block_k, lo, hi),
-                          qi, kv, block_q, block_k, lo, hi, update, sub)
+    kv, inside = _sweep_tile(_first_live_kv(qi, nkv, block_q, block_k, hi),
+                             j, nkv)
+    _masked_tile_branches(
+        _tile_live(qi, kv, block_q, block_k, lo, hi, inside),
+        qi, kv, block_q, block_k, lo, hi, update, sub)
 
-    # Last KV block of this Q row: normalize and emit.  A row with no
+    # Last step of this Q row's sweep: normalize and emit.  A row with no
     # live tile at all (possible under a shifted band — e.g. a ring hop
     # whose window edge crosses mid-shard) emits out=0, lse=_MASK_VALUE:
     # exactly the "no contribution" partial for logsumexp merging, and a
     # 0/0 NaN otherwise.
-    @pl.when(kv == _last_live_kv(qi, nkv, block_q, block_k, lo))
+    @pl.when(j == _emit_step(qi, steps, block_q, block_k, lo, hi))
     def _():
         l = l_ref[:, 0]
         # A row is dead when m never left its init — catches both "no live
@@ -595,7 +711,7 @@ def _flash_forward(q, k, v, lay: _Layout, *, causal, block_q, block_k,
 
     kernel = functools.partial(
         _flash_kernel, block_q=bq, block_k=bk, lo=lo, hi=hi,
-        sub=diag_sub(bq, bk, lo, hi, sub), scale=scale,
+        sub=diag_sub(bq, bk, lo, hi, sub), scale=scale, nkv=seq_k // bk,
     )
     q_tile, kv_tile, row_spec = _kv_innermost_specs(lay, bq, bk, lo, hi)
 
@@ -624,7 +740,8 @@ def _flash_forward(q, k, v, lay: _Layout, *, causal, block_q, block_k,
             # size 1 is neither divisible by 8 nor equal to bh.
             jax.ShapeDtypeStruct((bh, 1, seq_q), jnp.float32),
         ],
-        grid=(bh, seq_q // bq, seq_k // bk),
+        grid=(bh, seq_q // bq,
+              band_grid(seq_q // bq, seq_k // bk, bq, bk, lo, hi)[0]),
         in_specs=[q_tile(lay.q), kv_tile(lay.k), kv_tile(lay.v)],
         out_specs=[q_tile(lay.o), row_spec],
         scratch_shapes=[
@@ -774,15 +891,15 @@ def blockwise_attention(
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_acc_ref, *, block_q: int, block_k: int,
-                         lo, hi, sub: int, scale: float):
-    """dq: grid (bh, q_block, kv_block), KV innermost — dq for one Q tile
+                         lo, hi, sub: int, scale: float, nkv: int):
+    """dq: grid (bh, q_block, kv step), KV innermost — dq for one Q tile
     accumulates in VMEM scratch across its KV sweep, mirroring the forward's
-    schedule (and its causal dead-block elision)."""
+    schedule (the run of its live tiles, and its dead-block elision)."""
     qi = pl.program_id(1)
-    kv = pl.program_id(2)
-    nkv = pl.num_programs(2)
+    j = pl.program_id(2)
+    steps = pl.num_programs(2)
 
-    @pl.when(kv == 0)
+    @pl.when(j == 0)
     def _():
         dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
 
@@ -809,10 +926,13 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(k.dtype), k, preferred_element_type=jnp.float32
         )
 
-    _masked_tile_branches(_tile_live(qi, kv, block_q, block_k, lo, hi),
-                          qi, kv, block_q, block_k, lo, hi, update, sub)
+    kv, inside = _sweep_tile(_first_live_kv(qi, nkv, block_q, block_k, hi),
+                             j, nkv)
+    _masked_tile_branches(
+        _tile_live(qi, kv, block_q, block_k, lo, hi, inside),
+        qi, kv, block_q, block_k, lo, hi, update, sub)
 
-    @pl.when(kv == _last_live_kv(qi, nkv, block_q, block_k, lo))
+    @pl.when(j == _emit_step(qi, steps, block_q, block_k, lo, hi))
     def _():
         dq_ref[0] = dq_acc_ref[:].astype(dq_ref.dtype)
 
@@ -820,16 +940,18 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *,
                           block_q: int, block_k: int, lo, hi, sub: int,
-                          scale: float, n_q_tiles: int):
-    """dk/dv: grid (bh_kv, kv_block, group·q_block) with the (group member,
+                          scale: float, q_steps: int, nq: int):
+    """dk/dv: grid (bh_kv, kv_block, group·q step) with the (group member,
     Q tile) sweep innermost — dk/dv for one KV tile accumulate in VMEM
-    scratch across every Q tile of every q head in its GQA group (group=1
-    is plain MHA).  Causal: Q tiles fully above the diagonal are dead
-    (elided); each head's final Q tile is always live, so emission at the
-    last grid step is safe."""
+    scratch across the ``q_steps`` steps of every q head in its GQA group
+    (group=1 is plain MHA): every Q tile of the ``nq``, or under a band
+    with an upper edge the run of the KV tile's live ones
+    (:func:`_first_live_q`).  Causal: Q tiles fully above the diagonal are
+    dead (elided).  Emission at the last grid step needs no live tile."""
     kv = pl.program_id(1)
     gi = pl.program_id(2)
-    qi = gi % n_q_tiles
+    qi, inside = _sweep_tile(
+        _first_live_q(kv, nq, block_q, block_k, lo, hi), gi % q_steps, nq)
 
     @pl.when(gi == 0)
     def _():
@@ -855,8 +977,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype).T, q, preferred_element_type=jnp.float32
         )
 
-    _masked_tile_branches(_tile_live(qi, kv, block_q, block_k, lo, hi),
-                          qi, kv, block_q, block_k, lo, hi, update, sub)
+    _masked_tile_branches(
+        _tile_live(qi, kv, block_q, block_k, lo, hi, inside),
+        qi, kv, block_q, block_k, lo, hi, update, sub)
 
     @pl.when(gi == pl.num_programs(2) - 1)
     def _():
@@ -881,6 +1004,7 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
     do = do.astype(q.dtype)
     nq = seq_q // bq
     nkv = seq_k // bk
+    kv_steps, q_steps = band_grid(nq, nkv, bq, bk, lo, hi)[:2]
 
     work = bh * _band_live_pairs(seq_q, seq_k, lo, hi)
     q_bytes = bh * seq_q * d * q.dtype.itemsize
@@ -891,9 +1015,9 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
-                          lo=lo, hi=hi, sub=sub, scale=scale),
+                          lo=lo, hi=hi, sub=sub, scale=scale, nkv=nkv),
         out_shape=jax.ShapeDtypeStruct(lay.o_shape, q.dtype),
-        grid=(bh, nq, nkv),
+        grid=(bh, nq, kv_steps),
         in_specs=[q_tile(lay.q), kv_tile(lay.k), kv_tile(lay.v),
                   q_tile(lay.o), row_spec, row_spec],
         out_specs=q_tile(lay.o),
@@ -909,24 +1033,29 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
-    # dk/dv sweep (group x Q tiles) innermost per KV head; causal dead Q
+    # dk/dv sweep (group x Q steps) innermost per KV head: every Q tile, or
+    # under an upper edge the run of the KV tile's live ones.  Causal dead Q
     # tiles (fully above the diagonal) re-map to the KV row's first live
-    # tile of the same group head so their DMA is elided, mirroring the
-    # forward trick on the transposed schedule.
+    # tile of the same group head, and steps past a run's end to its last,
+    # so their DMA is elided, mirroring the forward trick on the transposed
+    # schedule.
+
     def q_index(b, j, gi):
         # KV grid row (batch-major over kv heads) + group member -> q row
         if group == 1:
             row, qi = b, gi
         else:
-            g, qi = _split(gi, nq)
+            g, qi = _split(gi, q_steps)
             batch, kv_head = _split(b, kv_heads)
             row = batch * heads + kv_head * group + g
-        if lo is not None:
+        if hi is not None:
+            # band's upper edge: the sweep starts at the first live q tile,
+            # and q tiles past k + hi are dead
+            qi = jnp.minimum(qi + _first_live_q(j, nq, bq, bk, lo, hi),
+                             ((j + 1) * bk - 1 + hi - 1) // bq)
+        elif lo is not None:
             # band's lower edge: q < k + lo tiles are dead
             qi = jnp.maximum(qi, (j * bk + lo) // bq)
-        if hi is not None:
-            # band's upper edge: q tiles past k + hi are dead too
-            qi = jnp.minimum(qi, ((j + 1) * bk - 1 + hi - 1) // bq)
         return row, jnp.clip(qi, 0, nq - 1)
 
     def q_tile_t(at):
@@ -947,12 +1076,13 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=bq, block_k=bk,
-                          lo=lo, hi=hi, sub=sub, scale=scale, n_q_tiles=nq),
+                          lo=lo, hi=hi, sub=sub, scale=scale,
+                          q_steps=q_steps, nq=nq),
         out_shape=[
             jax.ShapeDtypeStruct(lay.dkv_shape, k.dtype),
             jax.ShapeDtypeStruct(lay.dkv_shape, v.dtype),
         ],
-        grid=(bh_kv, nkv, nq * group),
+        grid=(bh_kv, nkv, q_steps * group),
         in_specs=[q_tile_t(lay.q), kv_tile_t(lay.k), kv_tile_t(lay.v),
                   q_tile_t(lay.o), row_spec_t, row_spec_t],
         out_specs=[kv_tile_t(lay.dkv), kv_tile_t(lay.dkv)],

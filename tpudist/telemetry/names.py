@@ -184,10 +184,12 @@ COMPILE_CACHE_MISS = "compile_cache_miss"
 # ``layout=`` PACKED (the flash kernels index the fused projection's own
 # [b, s, 3·d] output) or HEAD_MAJOR ([b, h, s, dh] operands, re-laid out
 # round the attention) with the ``reason=`` it was not packed; where the
-# flash kernels run, ``diag_sub=`` the squares a tile on the causal diagonal
-# is worked by (0: whole and masked), ``computed_over_live=`` score entries
-# computed over live pairs and, of a windowed call, ``window=`` and
-# ``tiles=`` [block_q, block_k]
+# flash kernels run, ``diag_sub=`` the squares a tile an edge of the band
+# crosses is worked by (0: whole and masked), ``computed_over_live=`` score
+# entries computed over live pairs and, of a windowed call, ``window=``,
+# ``tiles=`` [block_q, block_k], ``grid_kv=`` the length of the key axis of
+# the forward and dq grids (the longest run of live key tiles a query tile
+# has) and ``live_steps_share=`` live tiles over a head's grid steps
 ATTN_LAYOUT = "attn_layout"
 PACKED = "packed"
 HEAD_MAJOR = "head_major"
