@@ -626,6 +626,48 @@ def test_the_other_pattern_cells_steps_are_what_they_were(
                           flags=re.M)) == instructions
 
 
+def _step_text(line: int, op: str, body_op: str) -> str:
+    """What ``as_text()`` of a compiled step looks like to
+    ``benchmarks/step_hlo.py::strip``: the location tables, an instruction
+    with its metadata, a Mosaic call whose serialized body names the line of
+    its call site."""
+    import base64
+
+    body = base64.b64encode(
+        f'module {{ "{body_op}"() : () -> () loc("/tree/ops/k.py":{line}:1) }}'
+        .encode()).decode()
+    return (f"HloModule jit_step\n\nFileNames\n1 \"/tree/models/m.py\"\n\n"
+            f"FunctionNames\n1 \"f\"\n\nFileLocations\n1 {{file_name_id=1 "
+            f"line={line}}}\n\nStackFrames\n1 {{file_location_id=1}}\n\n\n"
+            f"ENTRY %main {{\n  %a = f32[8]{{0}} {op}(%x, %y), metadata="
+            f"{{op_name=\"jit(step)/ssm_norm/{op}\" stack_frame_id={line}}}\n"
+            f"  %k = f32[8]{{0}} custom-call(%a), custom_call_target="
+            f"\"tpu_custom_call\", backend_config={{\"custom_call_config\": "
+            f"{{\"body\":\"{body}\"}}}}\n}}\n")
+
+
+@pytest.mark.parametrize("other, same", [
+    ((7, "add", "k.op"), True),        # a line edited above the call sites
+    ((3, "subtract", "k.op"), False),  # another instruction
+    ((3, "add", "k.other"), False)],   # another kernel body
+    ids=["locations", "instruction", "kernel_body"])
+def test_step_hlo_strips_locations_and_nothing_else(other, same):
+    """``benchmarks/step_hlo.py compare`` is what "the accepted cells' steps
+    come out as they are" is judged by (PERF.md section 6, PR 43): source
+    lines, scope names and the Mosaic bodies' locations go, every
+    instruction and every kernel body stays."""
+    sys.path.insert(0, str(Path(__file__).resolve().parent.parent
+                           / "benchmarks"))
+    try:
+        from step_hlo import strip
+    finally:
+        sys.path.pop(0)
+    was = strip(_step_text(3, "add", "k.op"))
+    assert "/tree/" not in was and "ssm_norm" not in was
+    assert "add(%x, %y)" in was and "tpu_custom_call" in was
+    assert (strip(_step_text(*other)) == was) is same
+
+
 @pytest.fixture(scope="module")
 def nemotron_step(topo):
     """The whole train step of cell ``nemotron3super-train-tp8ep64share-8k``
@@ -764,6 +806,71 @@ def test_laguna_cell_step_runs_the_band_and_groups_the_experts(laguna_step):
         + [("flash_fwd", (36, 16, 2))] * 6
         + [("flash_bwd_dq", (36, 16, 2))] * 3
         + [("flash_bwd_dkv", (4, 16, 2 * 9))] * 3)
+
+
+@pytest.fixture(scope="module")
+def granite_step(topo):
+    """The whole train step of cell ``granite4hmicro-train-tp2share-8k`` (one
+    chip's share of a 2-way head-parallel deployment whose state-space group
+    both members hold whole): 1 row x 8,192."""
+    return _cell_step(topo, "granite4hmicro-train-tp2share-8k")
+
+
+def test_granite_cell_step_fills_one_chip_and_fits(granite_step):
+    step, job, m = granite_step
+    assert job["per_chip_batch"] == 1 and job["seq_len"] == 8192
+    assert job["remat"] == "nothing" and job["accum_steps"] == 1
+    mem = step.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    # 730,040,416 parameters x 12 bytes resident: 52% of the chip
+    assert 8.76e9 < mem.argument_size_in_bytes < 8.77e9
+    # 13.74 GiB = 14.76 GB: temporaries 5,994,230,784 bytes, the float32
+    # gradient (2.92 GB) among them, the 8,192 x 50,176 logits and their
+    # gradient, and ten layers' inputs and ``mixer_out`` (33.6 MB each).
+    # The feed-forwards' products are NOT kept (``as_run.ffn_products_kept``
+    # false, ISSUE 43's choice): kept, they are 302 MB a layer, 3.0 GB, and
+    # the step fits at 14.55 GiB only because the compiler then runs the
+    # head's product twice of its own accord (PERF.md section 6, PR 43).
+    # The issue asks over 12 GB and under 15.0 GiB; the compiler allows
+    # 15.75
+    assert 12.0e9 < held < 15.0 * 2 ** 30
+    assert held > 13.5 * 2 ** 30
+
+
+def test_granite_cell_step_runs_head_major_flash_and_mlp_inputs_twice(
+        granite_step):
+    """One attention layer in the ten at 16 query heads on 4 key/value heads
+    of 64: the three flash kernels by name on the dispatch's head-major
+    route (a 64-wide head is half a 128-lane tile: ``[1, 16, 8192, 64]``
+    operands, 1024 x 1024 tiles: 16 heads over 8 x 8 tiles, 4 query heads a
+    key/value head), the forward twice (its layer is
+    rematerialised), and no other custom call: the state-space scan and the
+    feed-forward are plain XLA.  A feed-forward keeps nothing of its three
+    products, so each layer's rematerialised forward runs ``gate`` and
+    ``up`` again (``down``'s output is read by nothing in the backward
+    pass: the norm comes before the sublayer): ten layers x (3 forward + 2
+    again + 6 backward) products with the projections in their names, where
+    a step that kept them would hold 90.  No collective: one chip's
+    share."""
+    import re
+
+    step, job, m = granite_step
+    text = step.as_text()
+    assert sorted(_kernels_named(text)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"]
+    assert text.count("tpu_custom_call") == 4 == (
+        job["custom_calls_per_layer"] * m["layers"])
+    assert sorted(_kernel_grids(text)) == sorted(
+        [("flash_fwd", (16, 8, 8))] * 2 + [("flash_bwd_dq", (16, 8, 8))]
+        + [("flash_bwd_dkv", (4, 8, 8 * 4))])
+    assert "ragged-dot" not in text
+    assert job["collectives_in_step"] == []
+    assert "all-reduce" not in text and "all-gather" not in text
+    products = [line for line in text.splitlines()
+                if re.search(r" convolution\(", line) and re.search(
+                    r"/mlp/(gate|up|down)_proj/dot_general", line)]
+    assert len(products) == 10 * (3 + 2 + 6)
 
 
 def test_a_512_band_over_8192_keys_computes_what_the_table_gives():

@@ -109,7 +109,7 @@ def test_a_backward_op_carries_the_transpose_mark_and_its_sublayer(
                   names.LOSS, names.LINEAR_ATTN, names.DELTA_RULE,
                   names.MOE, names.EXPERTS, names.SHARED_EXPERT, names.SSM,
                   names.SSD_SCAN, names.LATENT_PROJ, names.WINDOW_ATTN,
-                  names.HEAD_GATE):
+                  names.HEAD_GATE, names.SSM_CONV, names.SSM_NORM):
         assert [n for n in step_op_names
                 if names.BACKWARD_MARK in n and _under(scope, n)], scope
     # the optimizer is not differentiated: no transposed op under it

@@ -24,7 +24,8 @@ ungated GELU FFN) cannot say, by mechanism:
   (:class:`NormedAttention`): queries and keys RMS-normed with ONE statistic
   a token over every head's dims together, no gate, no rotary positions;
 - **plain grouped-query softmax attention** (:class:`GroupedAttention`): no
-  norm, no gate, no rotary positions;
+  norm, no gate, no rotary positions, and a softmax scale of its own where
+  the sizes name one (``softmax_scale``; ``head_dim ** -0.5`` otherwise);
 - **two softmax kinds in one decoder**, ``full_attention`` and
   ``sliding_attention``, each with sizes of its own
   (:class:`SoftmaxSizes`: query heads held of how many, the sliding window,
@@ -45,7 +46,9 @@ ungated GELU FFN) cannot say, by mechanism:
 - **a Mamba-2 state-space mixer** (:class:`Mamba2Mixer`): one input
   projection into a gate, heads, groups of ``B`` and ``C`` and a step a
   head, a depthwise causal convolution with bias and SiLU, the chunked scan
-  of :mod:`tpudist.ops.ssd`, an RMS norm of ``y * silu(z)`` a GROUP;
+  of :mod:`tpudist.ops.ssd`, an RMS norm of ``y * silu(z)`` a GROUP, the
+  group a member's own or one that the members of a head share read
+  between them;
 - **routed experts as a share** (:class:`ExpertShare`):
   :func:`tpudist.parallel.moe.expert_share`, dropless; softmax or sigmoid
   (with or without a choice bias) scoring with a scale, gated SiLU or
@@ -58,10 +61,16 @@ ungated GELU FFN) cannot say, by mechanism:
   told its experts.  With ``heads_axis`` (a mapped axis over the members
   that share a layer) the one norm statistic that runs over all heads and
   the output projections' partial sums are reduced over it; without it a
-  member's partial output goes on as it is.
+  member's partial output goes on as it is;
+- **scalar multipliers** on the embeddings (``embedding_scale``), on each
+  sublayer's output before the residual add (``residual_scale``) and under
+  the logits (``logits_divisor``), and **a tied head** (``tied_head``: the
+  logits are the final norm's output against the embedding itself, one
+  tensor with two gradient paths); each 1 / False where an architecture
+  names none, and then no instruction.
 
 Which arm a layer takes is data on :class:`HybridSizes`, filled in by
-whoever builds the module; nothing here knows a model.  The four
+whoever builds the module; nothing here knows a model.  The five
 architectures that run through it (``cellbench/archs``): ``qwen3_next``
 (norms zero-centred and before the sublayer, :class:`GatedAttention`, fused
 projections with ``nv / nk`` value heads a key head at 128 / 128, write
@@ -70,18 +79,24 @@ strength in ``[0, 1]``, :class:`ExpertShare` behind every mixer),
 :class:`NormedAttention`, separate projections at ``dk`` 96 / ``dv`` 192,
 write strength in ``[0, 2]``, :class:`GatedMLP`, half of each mixer's heads
 held) and ``nemotron_h`` (layers of one sublayer, norms plain and before
-it, :class:`Mamba2Mixer` holding one group of eight,
+it, :class:`Mamba2Mixer` holding one WHOLE group of eight,
 :class:`GroupedAttention` at 4 : 1, :class:`ExpertShare` with sigmoid +
 bias scoring, a scale, squared-ReLU experts in a latent space and a plain
-shared expert) and ``laguna`` (norms plain and before the sublayers,
+shared expert), ``laguna`` (norms plain and before the sublayers,
 :class:`HeadGatedAttention` at two kinds: 24 query heads causal to
 everything with YaRN on half of a head's dims, 36 inside a window of 512
 with plain rotary positions on all of them, on the same 4 key/value heads,
 half of each layer's heads held; a dense feed-forward in layer 0 and
 :class:`ExpertShare` with sigmoid scoring, a scale, gated SiLU experts and a
-plain shared expert behind the others).
+plain shared expert behind the others) and ``granitemoehybrid`` (norms
+plain and before the sublayers, :class:`Mamba2Mixer` holding half the heads
+of the ONE group, whose ``B`` and ``C`` both members hold whole,
+:class:`GroupedAttention` at 4 : 1 on 64-wide heads with a softmax scale of
+1 / 64, a :class:`GatedMLP` behind every mixer whose products are NOT kept
+under remat, the four multipliers and a tied head).
 
-The embedding, the head, their names and scopes, the loss the step
+The embedding, the head (untied, or the embedding's own ``attend``), their
+names and scopes, the loss the step
 builders take (``lm_loss``) and the remat policy names are
 ``transformer``'s, imported; the causal attention itself is
 ``tpudist.ops.attention``'s length-aware dispatch (the flash kernels where
@@ -109,9 +124,18 @@ from tpudist.parallel.moe import EXPERT_FNS, EXPERT_LEAVES, expert_share
 from tpudist.telemetry import names
 
 
-def _rms(x, eps):
+def _rms(x, eps, axis=None):
+    """``x * rsqrt(mean(x^2) + eps)`` over the last axis in float32.  With
+    ``axis`` the last axis is this member's equal slice of a vector that the
+    members of that mapped axis hold between them, and the mean square runs
+    over all of it: the sum of squares and the count are both reduced (one
+    ``psum`` of a number a vector)."""
     x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    if axis is None:
+        return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    square = jax.lax.psum(jnp.sum(x * x, axis=-1, keepdims=True), axis)
+    dims = x.shape[-1] * jax.lax.psum(1, axis)
+    return x * jax.lax.rsqrt(square / dims + eps)
 
 
 class ZeroCentredRMSNorm(nn.Module):
@@ -139,13 +163,7 @@ class RMSNorm(nn.Module):
     @nn.compact
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        if self.axis is None:
-            return _rms(x, self.eps) * scale
-        x = x.astype(jnp.float32)
-        square = jax.lax.psum(jnp.sum(x * x, axis=-1, keepdims=True),
-                              self.axis)
-        dims = x.shape[-1] * jax.lax.psum(1, self.axis)
-        return x * jax.lax.rsqrt(square / dims + self.eps) * scale
+        return _rms(x, self.eps, self.axis) * scale
 
 
 NORMS = {names.ZERO_CENTRED: ZeroCentredRMSNorm, names.PLAIN: RMSNorm}
@@ -228,6 +246,9 @@ class HybridSizes:
     rope_theta: float = 1e7
     n_heads_total: Optional[int] = None
     attention: str = names.GATED_ATTN        # one of ATTENTIONS
+    # what multiplies q . k before the softmax where it is not
+    # ``head_dim ** -0.5`` (None); the grouped arm's alone
+    softmax_scale: Optional[float] = None
     # the head-gated arm's sizes INSTEAD of the five above, which it does
     # not read: ((kind, SoftmaxSizes), ...), a softmax kind (names.FULL,
     # names.WINDOW) of the decoder's layers each
@@ -243,7 +264,10 @@ class HybridSizes:
     linear_projections: str = names.FUSED    # or names.SEPARATE
     beta_scale: float = 1.0      # write strength = beta_scale * sigmoid(.)
     # state-space (Mamba-2) mixers: heads and groups HELD, of ``*_total`` in
-    # all; a group's ``B`` and ``C`` serve ``ssm_heads / ssm_groups`` heads
+    # all; a group's ``B`` and ``C`` serve ``ssm_heads / ssm_groups`` of the
+    # heads held.  Where the members that share a layer outnumber the groups
+    # (32 of 64 heads, 1 of 1 group) a group is held by every member that
+    # holds some of its heads (:func:`ssm_group_members` of them)
     ssm_heads: int = 0
     ssm_groups: int = 1
     ssm_head_dim: int = 64
@@ -263,6 +287,12 @@ class HybridSizes:
     # the feed-forward arm
     feed_forward: str = names.EXPERT_SHARE   # or names.DENSE_FFN
     ffn_width: int = 0           # the dense arm's
+    # whether a rematerialised layer keeps the outputs of the dense arm's
+    # three products (:func:`remat_keeps`), so that its forward runs once a
+    # step, or (False) ``mixer_out`` alone and ``gate`` and ``up`` run again: a
+    # choice by memory, ``tokens x (2 x ffn_width + d_model) x itemsize``
+    # bytes a layer
+    ffn_products_kept: bool = True
     # routed experts: ``n_experts`` is the router's width, ``held`` of them
     # (``first_expert`` on) live here
     n_experts: int = 8
@@ -284,6 +314,15 @@ class HybridSizes:
     latent_width: Optional[int] = None
     # the shared expert is ``sigmoid(x . score) * E(x)``, or ``E(x)`` as it is
     shared_scored: bool = True
+    # scalar multipliers, each 1 where an architecture names none (and then
+    # no instruction): on the embeddings, on each sublayer's output before
+    # the residual add, and what the logits are divided by; and whether the
+    # head is the embedding itself (``tok_embed``'s ``attend``: no ``head``
+    # parameter, one tensor with two gradient paths)
+    embedding_scale: float = 1.0
+    residual_scale: float = 1.0
+    logits_divisor: float = 1.0
+    tied_head: bool = False
     eps: float = 1e-6
 
     def softmax(self, kind: str) -> SoftmaxSizes:
@@ -365,8 +404,12 @@ class NormedAttention(nn.Module):
 
 class GroupedAttention(nn.Module):
     """Plain causal grouped-query softmax attention: no norm on queries or
-    keys, no gate, no rotary positions (``rotary_dim`` is not read).
-    Holding ``n_heads`` query heads with the ``n_kv_heads`` they read,
+    keys, no gate, no rotary positions (``rotary_dim`` is not read).  The
+    scores are ``q . k * softmax_scale`` where the sizes name a scale: the
+    attention itself multiplies by ``head_dim ** -0.5``, so the queries are
+    multiplied by ``softmax_scale * sqrt(head_dim)`` first, in the compute
+    dtype (exact where that is a power of two, as 1 / 64 at 64-wide heads
+    is).  Holding ``n_heads`` query heads with the ``n_kv_heads`` they read,
     ``o_proj``'s partial sums are reduced over ``heads_axis`` where there
     is one."""
 
@@ -377,9 +420,11 @@ class GroupedAttention(nn.Module):
     def __call__(self, x):
         z = self.sizes
         h, kv, dh = z.n_heads, z.n_kv_heads, z.head_dim
-        qkv = jnp.concatenate(
-            [_dense(heads * dh, f"{name}_proj", self.dtype)(x)
-             for name, heads in (("q", h), ("k", kv), ("v", kv))], axis=-1)
+        q, k, v = (_dense(heads * dh, f"{name}_proj", self.dtype)(x)
+                   for name, heads in (("q", h), ("k", kv), ("v", kv)))
+        if z.softmax_scale is not None:
+            q = q * (z.softmax_scale * dh ** 0.5)
+        qkv = jnp.concatenate([q, k, v], axis=-1)
         attn = default_attention.packed(qkv, h, kv)
         return _over_members(_dense(x.shape[-1], "o_proj", self.dtype)(attn),
                              z.heads_axis)
@@ -525,17 +570,54 @@ class GatedDeltaNet(nn.Module):
             o.reshape(b, s, nv * dv).astype(self.dtype)), z.heads_axis)
 
 
+def ssm_group_members(sizes: HybridSizes) -> int:
+    """How many of the members that share a layer by heads hold some heads
+    of one state-space group, and so its ``B`` and ``C``: 1 where a member
+    holds whole groups (16 of 128 heads in 1 of 8 groups), the members
+    themselves where they outnumber the groups and every member holds every
+    group (32 of 64 heads, the 1 group of 1: 2).  Anything between (a group
+    over some of the members) is not written down."""
+    if not sizes.ssm_heads:
+        return 1
+    heads = sizes.ssm_heads_total or sizes.ssm_heads
+    groups = sizes.ssm_groups_total or sizes.ssm_groups
+    # a group's heads in all over those of them held here
+    members, rest = divmod(heads * sizes.ssm_groups, groups * sizes.ssm_heads)
+    if rest or (members > 1 and sizes.ssm_groups != groups):
+        raise ValueError(
+            f"{sizes.ssm_heads} of {heads} state-space heads in "
+            f"{sizes.ssm_groups} of {groups} groups: a member holds whole "
+            f"groups, or every member holds every group with an equal part "
+            f"of its heads")
+    return members
+
+
+def ssm_norm_axis(sizes: HybridSizes) -> Optional[str]:
+    """The mapped axis the gated norm's statistic is reduced over: the
+    members' where they read one group between them, None where a member
+    holds whole groups or there is no axis (the heads held, then)."""
+    return sizes.heads_axis if ssm_group_members(sizes) > 1 else None
+
+
 class Mamba2Mixer(nn.Module):
     """The Mamba-2 state-space mixer, holding ``ssm_heads`` heads of
     ``ssm_groups`` groups: ``[z, xBC, dt] = in_proj(u)`` (the held heads'
     and groups' columns: ``z`` and ``x`` a head's ``ssm_head_dim`` each,
     ``B`` and ``C`` a group's ``ssm_state`` each, ``dt`` a number a head), a
-    depthwise causal convolution with bias and SiLU over ``xBC``,
-    ``dt = softplus(dt + dt_bias)``, the chunked scan of
-    :mod:`tpudist.ops.ssd`, an RMS norm of ``y * silu(z)`` whose mean
-    square runs over each GROUP's channels (so a member that holds whole
-    groups computes it alone), and ``out_proj`` (the held heads' rows; its
-    partial sums reduced over ``heads_axis`` where there is one)."""
+    depthwise causal convolution with bias and SiLU over ``xBC`` (scope
+    ``names.SSM_CONV``), ``dt = softplus(dt + dt_bias)``, the chunked scan
+    of :mod:`tpudist.ops.ssd`, an RMS norm of ``y * silu(z)`` whose mean
+    square runs over each GROUP's channels (scope ``names.SSM_NORM``), and
+    ``out_proj`` (the held heads' rows; its partial sums reduced over
+    ``heads_axis`` where there is one).
+
+    A member either holds whole groups, and computes the norm alone, or
+    (:func:`ssm_group_members` over 1) holds part of the heads of every
+    group beside the other members: a group's ``B`` / ``C`` columns of
+    ``in_proj``, conv channels and bias are then every member's, and the
+    norm's mean square runs over the held heads' channels of the group or,
+    given ``heads_axis``, over all the members' (sum of squares and count
+    both reduced)."""
 
     sizes: HybridSizes
     dtype: jnp.dtype
@@ -553,8 +635,9 @@ class Mamba2Mixer(nn.Module):
                             (inner + 2 * bc, z.ssm_conv_width))
         bias = self.param("conv_bias", nn.initializers.zeros,
                           (inner + 2 * bc,))
-        mixed = jax.nn.silu(causal_depthwise_conv(mixed, kernel)
-                            + bias).astype(self.dtype)
+        with jax.named_scope(names.SSM_CONV):
+            mixed = jax.nn.silu(causal_depthwise_conv(mixed, kernel)
+                                + bias).astype(self.dtype)
         u, b_in, c_out = jnp.split(mixed, [inner, inner + bc], axis=-1)
         a_log = self.param("A_log", nn.initializers.zeros, (h,))
         skip = self.param("D", nn.initializers.ones, (h,))
@@ -564,10 +647,11 @@ class Mamba2Mixer(nn.Module):
                      a_log, b_in.reshape(b, s, g, n),
                      c_out.reshape(b, s, g, n), skip, chunk=z.ssm_chunk)
         norm = self.param("norm", nn.initializers.ones, (inner,))
-        y = (y.reshape(b, s, inner).astype(jnp.float32)
-             * jax.nn.silu(gate.astype(jnp.float32)))
-        y = _rms(y.reshape(b, s, g, inner // g), z.eps).reshape(
-            b, s, inner) * norm
+        with jax.named_scope(names.SSM_NORM):
+            y = (y.reshape(b, s, inner).astype(jnp.float32)
+                 * jax.nn.silu(gate.astype(jnp.float32)))
+            y = _rms(y.reshape(b, s, g, inner // g), z.eps,
+                     ssm_norm_axis(z)).reshape(b, s, inner) * norm
         return _over_members(_dense(d, "out_proj", self.dtype)(
             y.astype(self.dtype)), z.heads_axis)
 
@@ -576,9 +660,10 @@ class GatedMLP(nn.Module):
     """The dense feed-forward arm, ``down(silu(gate(x)) * up(x))`` without
     biases, under scope ``names.MLP``.  Its three products' outputs are
     named (``names.DENSE_FFN_KEEPS``): a rematerialised layer keeps them
-    (:func:`remat_keeps`), ``tokens x (2 x ffn_width + d_model) x itemsize``
-    bytes, and its backward pass then recomputes only ``silu(gate) * up``,
-    one elementwise pass, and none of the products."""
+    where the sizes say so (:func:`remat_keeps`), ``tokens x (2 x ffn_width
+    + d_model) x itemsize`` bytes, and its backward pass then recomputes
+    only ``silu(gate) * up``, one elementwise pass, and none of the
+    products; where they are not kept the whole forward runs again."""
 
     sizes: HybridSizes
     dtype: jnp.dtype
@@ -695,6 +780,11 @@ def _softmax(kind: str):
         if sizes.attention not in ATTENTIONS:
             raise ValueError(f"attention is {sizes.attention!r}; the softmax "
                              f"arm is one of {sorted(ATTENTIONS)}")
+        if (sizes.softmax_scale is not None
+                and sizes.attention != names.GROUPED_ATTN):
+            raise ValueError(
+                f"softmax_scale is read by the {names.GROUPED_ATTN!r} arm "
+                f"alone: {sizes.attention!r} attends at head_dim ** -0.5")
         if sizes.attention == names.HEAD_GATED_ATTN:
             return HeadGatedAttention(sizes, dtype, kind, name=name)
         if kind != names.FULL:
@@ -730,9 +820,10 @@ def layer_kinds(sizes: HybridSizes) -> tuple:
 class HybridLayer(nn.Module):
     """``h = x + Mixer(norm(x))``, ``y = h + FFN(norm(h))`` with the norms
     before the sublayers, ``h = x + norm(Mixer(x))``, ``y = h + norm(FFN(h))``
-    with them after.  With ``sizes.one_sublayer`` a layer is the first of
-    the two lines alone, its sublayer a mixer or (``names.EXPERT_LAYER``)
-    the feed-forward arm."""
+    with them after; what is added to ``x`` is multiplied by
+    ``sizes.residual_scale`` first where that is not 1.  With
+    ``sizes.one_sublayer`` a layer is the first of the two lines alone, its
+    sublayer a mixer or (``names.EXPERT_LAYER``) the feed-forward arm."""
 
     kind: str
     sizes: HybridSizes
@@ -742,11 +833,14 @@ class HybridLayer(nn.Module):
     def __call__(self, x):
         z = self.sizes
 
+        def scaled(y):
+            return y if z.residual_scale == 1.0 else z.residual_scale * y
+
         def residual(x, sublayer, norm_name):
             norm = NORMS[z.norm](z.eps, name=norm_name)
             if z.norm_after:
-                return x + norm(sublayer(x)).astype(self.dtype)
-            return x + sublayer(norm(x))
+                return x + scaled(norm(sublayer(x)).astype(self.dtype))
+            return x + scaled(sublayer(norm(x)))
 
         def feed_forward(x):
             # the expert layer names its own scope (``moe``), the dense one
@@ -774,9 +868,13 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
     """The names a rematerialised :class:`HybridLayer` keeps besides its
     input, under every policy: ``names.MIXER_OUT`` (the layer's activation
     between its mixer and its feed-forward arm, ``tokens x d_model x
-    itemsize`` bytes), and in the dense arm the outputs of the
-    feed-forward's three products (its input IS ``MIXER_OUT``), so that no
-    product of the feed-forward runs twice a step.  The expert-share arm of
+    itemsize`` bytes), and in the dense arm, where ``ffn_products_kept``,
+    the outputs of the feed-forward's three products (its input IS
+    ``MIXER_OUT``), so that no product of the feed-forward runs twice a
+    step; where they are not kept (a choice by memory: 3.0 GB over ten
+    layers at 8,192 x 8,192, ROADMAP S26) ``MIXER_OUT`` alone, and each
+    feed-forward's ``gate`` and ``up`` run again in the backward pass
+    (``down``'s output is read by nothing there).  The expert-share arm of
     a two-sublayer layer recomputes its feed-forward: the buffers of its
     dispatch are many times a layer's activations.
 
@@ -814,7 +912,7 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
                           for leaf in EXPERT_LEAVES[sizes.expert_fn]
                           if leaf in names.SHARED_EXPERT_KEEPS)
         return keep
-    if sizes.feed_forward == names.DENSE_FFN:
+    if sizes.feed_forward == names.DENSE_FFN and sizes.ffn_products_kept:
         return (names.MIXER_OUT,) + names.DENSE_FFN_KEEPS
     return (names.MIXER_OUT,)
 
@@ -838,8 +936,13 @@ def kept_bytes(keep: tuple, sizes: HybridSizes, tokens: int, dtype) -> int:
 
 class HybridLM(nn.Module):
     """Causal LM: token embedding, ``len(layer_types)`` pattern layers (each
-    one of :func:`layer_kinds`), a final RMS norm of the layers' kind, an
-    untied head."""
+    one of :func:`layer_kinds`), a final RMS norm of the layers' kind, and
+    a head: untied (a ``head`` parameter), or with ``sizes.tied_head`` the
+    embedding's own ``attend`` (no ``head`` parameter: the one tensor's
+    gradient is the gather's scatter-add plus the head's product).  The
+    embeddings are multiplied by ``sizes.embedding_scale`` in the compute
+    dtype and the logits divided by ``sizes.logits_divisor`` where those are
+    not 1."""
 
     vocab: int
     layer_types: tuple          # one of :func:`layer_kinds` a layer
@@ -849,8 +952,9 @@ class HybridLM(nn.Module):
     # the names ``TransformerLM`` takes, for what the POLICY saves; besides,
     # under every policy, a layer keeps what :func:`remat_keeps` names:
     # ``mixer_out`` (``tokens x d_model x itemsize`` bytes a layer) and, with
-    # a dense feed-forward, its three products' outputs (``tokens x (2 x
-    # ffn_width + d_model) x itemsize`` more), so its forward runs once; an
+    # a dense feed-forward whose sizes say ``ffn_products_kept``, its three
+    # products' outputs (``tokens x (2 x ffn_width + d_model) x itemsize``
+    # more), so its forward runs once; an
     # expert layer of one sublayer the result of a share that goes by windows
     # and the outputs of its dense products that the backward pass reads
     remat_policy: str = "nothing"
@@ -908,14 +1012,24 @@ class HybridLM(nn.Module):
             ssm_heads=[z.ssm_heads, z.ssm_heads_total or z.ssm_heads],
             ssm_groups=[z.ssm_groups, z.ssm_groups_total or z.ssm_groups],
             ssm_head_dim=z.ssm_head_dim, ssm_state=z.ssm_state,
-            ssm_chunk=z.ssm_chunk, heads_axis=z.heads_axis,
+            ssm_chunk=z.ssm_chunk,
+            ssm_group_members=ssm_group_members(z),
+            ssm_norm_over=ssm_norm_axis(z) or names.HELD,
+            heads_axis=z.heads_axis,
+            softmax_scale=z.softmax_scale, residual_scale=z.residual_scale,
+            embedding_scale=z.embedding_scale,
+            logits_divisor=z.logits_divisor, tied_head=z.tied_head,
             feed_forward=z.feed_forward, feed_forwards=list(arms),
             norm=z.norm, norm_after=z.norm_after,
             remat_keeps=list(keeps[0]) if one_arm else list(map(list, keeps)),
-            remat_kept_bytes_per_layer=kept[0] if one_arm else kept)
+            remat_kept_bytes_per_layer=kept[0] if one_arm else kept,
+            dense_products_kept=[names.FFN_UP in keep for keep in keeps])
+        embed = nn.Embed(self.vocab, self.sizes.d_model, name="tok_embed",
+                         dtype=self.dtype)
         with jax.named_scope(names.EMBED):
-            x = nn.Embed(self.vocab, self.sizes.d_model, name="tok_embed",
-                         dtype=self.dtype)(tokens)
+            x = embed(tokens)
+            if z.embedding_scale != 1.0:
+                x = x * z.embedding_scale
         layer_classes = {keep: remat_module(HybridLayer, self.remat_policy,
                                             keep=keep)
                          if self.remat else HybridLayer for keep in set(keeps)}
@@ -925,5 +1039,8 @@ class HybridLM(nn.Module):
                 name=f"{names.PATTERN_LAYER}_{i}")(x)
         with jax.named_scope(names.HEAD):
             x = NORMS[z.norm](z.eps, name="final_norm")(x)
-            return nn.Dense(self.vocab, use_bias=False, name="head",
-                            dtype=self.dtype)(x)
+            logits = embed.attend(x) if z.tied_head else nn.Dense(
+                self.vocab, use_bias=False, name="head", dtype=self.dtype)(x)
+            if z.logits_divisor != 1.0:
+                logits = logits / z.logits_divisor
+            return logits

@@ -76,6 +76,13 @@ PATTERN_LAYER = "layer"
 SSM = "ssm"
 SSD_SCAN = "ssd_scan"
 LATENT_PROJ = "latent_proj"
+# nested in ``ssm`` beside the scan, so that the mixer's time outside its
+# products and its scan can be read by part: ``ssm_conv`` the depthwise
+# convolution over x, B and C with its bias and SiLU; ``ssm_norm`` the
+# gate's product ``y * silu(z)`` and the gated norm, with its reduction
+# where the members of a head share take the statistic together
+SSM_CONV = "ssm_conv"
+SSM_NORM = "ssm_norm"
 # ``window_attn`` is a whole softmax mixer whose band is a sliding window
 # (a layer of kind WINDOW), as ``attn`` is one that attends causally to
 # everything; ``head_gate`` (the gate's projection, its sigmoid and the
@@ -84,7 +91,7 @@ WINDOW_ATTN = "window_attn"
 HEAD_GATE = "head_gate"
 SCOPES = (EMBED, ATTN, MLP, HEAD, LOSS, OPTIMIZER, GRAD_ACCUM, LINEAR_ATTN,
           DELTA_RULE, MOE, EXPERTS, SHARED_EXPERT, MOE_COMBINE, SSM, SSD_SCAN,
-          LATENT_PROJ, WINDOW_ATTN, HEAD_GATE)
+          LATENT_PROJ, WINDOW_ATTN, HEAD_GATE, SSM_CONV, SSM_NORM)
 #: what JAX itself writes round the scopes of a transposed (backward) op
 BACKWARD_MARK = "transpose("
 
@@ -207,7 +214,13 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # all], ``kv_heads``, ``window``, ``rotary_dim``, ``rope_theta``,
 # ``yarn_factor`` and ``rope_scale``; ``head_dim=``; of the
 # state-space mixers ``ssm_heads=`` and ``ssm_groups=`` [held, in all],
-# ``ssm_head_dim=``, ``ssm_state=``, ``ssm_chunk=``; of the
+# ``ssm_head_dim=``, ``ssm_state=``, ``ssm_chunk=``,
+# ``ssm_group_members=`` how many of the members that share a layer read one
+# group (1: a member holds whole groups) and ``ssm_norm_over=`` what the
+# gated norm's mean square runs over: HELD (the heads held here) or the
+# mapped axis; ``softmax_scale=`` (None: ``head_dim ** -0.5``),
+# ``residual_scale=``, ``embedding_scale=``, ``logits_divisor=``,
+# ``tied_head=``; of the
 # delta-rule mixers ``linear_heads=`` [value heads held, in all],
 # ``linear_key_heads=``, ``linear_key_dim=``, ``linear_value_dim=``,
 # ``linear_projections=`` FUSED / SEPARATE, ``beta_scale=`` (the write
@@ -217,7 +230,8 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # a layer; ``norm=`` ZERO_CENTRED / PLAIN
 # and ``norm_after=`` whether it follows its sublayer; ``remat_keeps=`` the
 # names a rematerialised layer keeps besides its input (MIXER_OUT, and
-# DENSE_FFN_KEEPS in the dense arm; in an expert layer of one sublayer
+# DENSE_FFN_KEEPS in a dense arm that keeps its products:
+# ``dense_products_kept=`` says so a layer; in an expert layer of one sublayer
 # EXPERT_OUT, ROUTER_LOGITS, LATENT_IN where it has latent projections, and
 # SHARED_EXPERT_KEEPS' names of an unscored shared expert's first products;
 # ``[]`` without remat) and
@@ -238,6 +252,7 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # width (an expert layer's latent width where it has one)
 MIXER_LAYOUT = "mixer_layout"
 MOE_LAYOUT = "moe_layout"
+HELD = "held"
 PICK_MAJOR = "pick_major"
 SCATTER_ADD = "scatter_add"
 LINEAR = "linear_attention"
