@@ -595,14 +595,24 @@ def test_olmo_hybrid_cell_step_carries_exactly_the_three_flash_kernels(
 
 
 @pytest.mark.parametrize("fixture, arguments, temporaries, instructions", [
-    ("hybrid_step", 7_508_078_592, 7_438_718_464, 24_035),
+    ("hybrid_step", 7_508_078_592, 7_504_172_032, 23_605),
     ("olmo_hybrid_step", 9_195_254_272, 5_077_698_560, 18_651),
-    ("nemotron_step", 8_410_485_760, 3_660_219_904, 23_704),
+    ("nemotron_step", 8_410_485_760, 3_637_826_560, 22_252),
     ("gpt2_cell_step", 8_006_918_656, 7_865_907_712, 20_502)],
     ids=["share_cell", "olmo_cell", "nemotron_cell", "gpt2_cell"])
 def test_the_other_pattern_cells_steps_are_what_they_were(
         request, fixture, arguments, temporaries, instructions):
-    """Since PR 41 all four accepted cells: the pattern decoder's sizes a
+    """Since PR 44 the two cells without experts hold what they held, to
+    the byte and the instruction (their optimized HLO is the parent's text
+    for text, ``benchmarks/step_hlo.py``), and the two expert cells are
+    read again: a rematerialised expert layer keeps its router's picks and
+    their scores, and one of two sublayers its router's logits too, so the
+    nemotron step lost 1,452 instructions (the rematerialised sorts, the
+    gathers of the picks' scores, the backward's scatters) and 22.4 MB of
+    temporaries, and the share cell's 430 instructions and holds 65.5 MB
+    more at the compiler's peak (a layer's logits are 33.6 MB over its
+    16,384 tokens).  Before it, since PR 41 all
+    four accepted cells: the pattern decoder's sizes a
     softmax kind, its feed-forward arm a layer, rotary angles from given
     frequencies and the dispatch's tiles of a windowed call are data none
     of them states, so their steps hold the bytes and the instructions to
@@ -668,6 +678,30 @@ def test_step_hlo_strips_locations_and_nothing_else(other, same):
     assert (strip(_step_text(*other)) == was) is same
 
 
+def _router_sorts(text: str, n_experts: int) -> list:
+    """The operands of each ``sort`` of a compiled step whose first result
+    is ``[8192, n_experts]``: the router's sorts over a row of scores."""
+    import re
+
+    return [shapes.count(f"[8192,{n_experts}]") for shapes in re.findall(
+        rf"= \((f32\[8192,{n_experts}\][^=]*?)\) sort\(", text)]
+
+
+def _picks_gathered(text: str, k: int) -> int:
+    """The instructions of a compiled step that gather ``[8192, k]`` scores
+    at the router's picks under an expert layer's scope: what
+    ``take_along_axis`` behind a ``top_k`` compiles to (the compiler
+    flattens it: a fusion over ``8192 x k`` numbers named for the
+    gather, reshaped to ``[8192, k]``)."""
+    import re
+
+    from tpudist.telemetry import names
+
+    return len(re.findall(
+        rf"= f32\[8192,{k}\]\S* reshape\([^\n]*/{names.MOE}/"
+        rf"jit\(take_along_axis\)/gather", text))
+
+
 @pytest.fixture(scope="module")
 def nemotron_step(topo):
     """The whole train step of cell ``nemotron3super-train-tp8ep64share-8k``
@@ -686,7 +720,9 @@ def test_nemotron_cell_step_fills_one_chip_and_fits(nemotron_step):
     # 700,862,960 parameters (and 5 x 512 numbers of choice bias) x 12
     # bytes resident
     assert 8.40e9 < mem.argument_size_in_bytes < 8.42e9
-    # 11.24 GiB = 12.07 GB: temporaries 3,660,219,904 bytes, the float32
+    # 11.22 GiB = 12.05 GB: temporaries 3,637,826,560 bytes (3,660,219,904
+    # before PR 44 kept the router's picks, 1.4 MB a layer, and so freed
+    # the rematerialised sorts' operands), the float32
     # gradient (2.80 GB) among them.  An expert layer takes what arrived
     # through windows of 22,528 rows (46 MB at 1,024 wide, 121 MB at 2,688)
     # and keeps from forward to backward its share's result and, since PR
@@ -740,6 +776,12 @@ def test_nemotron_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
               if re.search(r" convolution\(", line)
               and f"/{names.SHARED_EXPERT}/" in line]
     assert len(shared) == 5 * 6
+    # the router runs once a step (PR 44): ONE sort over the 512 scores a
+    # layer, with the scores as its third operand, where the forward and
+    # the rematerialised forward each had a top-22 of their own; and no
+    # gather picks the 22 scores out of ``[8192, 512]``
+    assert _router_sorts(text, 512) == [3] * 5
+    assert _picks_gathered(text, 22) == 0
 
 
 @pytest.fixture(scope="module")
@@ -759,10 +801,12 @@ def test_laguna_cell_step_fills_one_chip_and_fits(laguna_step):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     # 672,125,952 parameters x 12 bytes resident
     assert 8.06e9 < mem.argument_size_in_bytes < 8.07e9
-    # 11.47 GiB = 12.32 GB: temporaries 4,254,528,512 bytes, the float32
+    # 11.45 GiB = 12.29 GB: temporaries 4,224,679,936 bytes (4,254,528,512
+    # before PR 44), the float32
     # gradient (2.69 GB) among them; layer 0 keeps its dense feed-forward's
     # three products from forward to backward (503 MB with ``mixer_out``),
-    # the expert layers ``mixer_out`` (50 MB each), and an expert layer
+    # the expert layers ``mixer_out`` (50 MB each) and, since PR 44, their
+    # router's logits and picks (9 MB each), and an expert layer
     # takes what arrived through windows of 20,480 rows (126 MB at 3,072
     # wide).  The issue asks over 12 GB and under 15.0 GiB; the compiler
     # allows 15.75
@@ -795,6 +839,12 @@ def test_laguna_cell_step_runs_the_band_and_groups_the_experts(laguna_step):
     assert "[81920,3072]" not in text and "[81920,1024]" not in text
     assert job["collectives_in_step"] == []
     assert "all-reduce" not in text and "all-gather" not in text
+    # the router runs once a step (PR 44): ONE top-10 over the 256 scores a
+    # layer (scores and columns: no bias, so the picks' scores are the
+    # sort's own values) where forward and rematerialised forward each had
+    # one, and no gather picks the 10 scores out of ``[8192, 256]``
+    assert _router_sorts(text, 256) == [2] * 4
+    assert _picks_gathered(text, 10) == 0
     # the three sliding layers hold 36 query heads, the two full ones 24
     outs = re.findall(r"%flash_fwd[\w.]* = \(bf16\[1,8192,(\d+)\]", text)
     assert sorted(outs) == ["3072"] * 4 + ["4608"] * 6

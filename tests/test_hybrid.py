@@ -402,16 +402,22 @@ def test_any_top_k_adds_up_each_tokens_held_rows(k, block):
     assert_share_follows(program, ref, params, w, x)
 
 
-def _avals(jaxpr):
-    """Every value of a jaxpr and of the jaxprs nested in its equations."""
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in them."""
     for eqn in jaxpr.eqns:
-        yield from (v.aval for v in (*eqn.invars, *eqn.outvars))
+        yield eqn
         for param in eqn.params.values():
             for inner in (param if isinstance(param, (tuple, list))
                           else [param]):
                 inner = getattr(inner, "jaxpr", inner)
                 if hasattr(inner, "eqns"):
-                    yield from _avals(inner)
+                    yield from _equations(inner)
+
+
+def _avals(jaxpr):
+    """Every value of a jaxpr and of the jaxprs nested in its equations."""
+    for eqn in _equations(jaxpr):
+        yield from (v.aval for v in (*eqn.invars, *eqn.outvars))
 
 
 def test_the_combine_builds_no_token_major_tensor():
@@ -571,14 +577,7 @@ def test_windows_give_the_one_buffers_block_and_the_dense_sum(
 
 def _primitives(jaxpr):
     """Every equation's primitive, of a jaxpr and of those nested in it."""
-    for eqn in jaxpr.eqns:
-        yield eqn.primitive.name
-        for param in eqn.params.values():
-            for inner in (param if isinstance(param, (tuple, list))
-                          else [param]):
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    yield from _primitives(inner)
+    return (eqn.primitive.name for eqn in _equations(jaxpr))
 
 
 @pytest.mark.parametrize("held, windows", [(4, False), (1, True)],
@@ -626,27 +625,28 @@ def _sizes(**arms) -> hybrid.HybridSizes:
 
 
 ONE_EXPERT_LAYER = dict(one_sublayer=True, shared_scored=False)
+ROUTERS_KEEPS = (names.ROUTER_LOGITS, names.ROUTER_PICKS)
 
 
 @pytest.mark.parametrize("arms, keeps, columns", [
     (dict(ONE_EXPERT_LAYER, latent_width=24, expert_fn=names.RELU2),
-     (names.EXPERT_OUT, names.ROUTER_LOGITS, names.LATENT_IN,
-      names.SHARED_UP), 24 + 16 + 24 + 40),
+     (names.EXPERT_OUT, *ROUTERS_KEEPS, names.LATENT_IN, names.SHARED_UP),
+     24 + 16 + 8 + 24 + 40),
     (dict(ONE_EXPERT_LAYER, expert_fn=names.RELU2),
-     (names.EXPERT_OUT, names.ROUTER_LOGITS, names.SHARED_UP),
-     32 + 16 + 40),
+     (names.EXPERT_OUT, *ROUTERS_KEEPS, names.SHARED_UP), 32 + 16 + 8 + 40),
     (dict(ONE_EXPERT_LAYER, latent_width=24, expert_fn=names.GATED_SILU),
-     (names.EXPERT_OUT, names.ROUTER_LOGITS, names.LATENT_IN,
-      names.SHARED_GATE, names.SHARED_UP), 24 + 16 + 24 + 2 * 40),
+     (names.EXPERT_OUT, *ROUTERS_KEEPS, names.LATENT_IN, names.SHARED_GATE,
+      names.SHARED_UP), 24 + 16 + 8 + 24 + 2 * 40),
     (dict(ONE_EXPERT_LAYER, expert_fn=names.GATED_SILU),
-     (names.EXPERT_OUT, names.ROUTER_LOGITS, names.SHARED_GATE,
-      names.SHARED_UP), 32 + 16 + 2 * 40),
+     (names.EXPERT_OUT, *ROUTERS_KEEPS, names.SHARED_GATE, names.SHARED_UP),
+     32 + 16 + 8 + 2 * 40),
     # a scored shared expert is computed inside ``expert_share``, unnamed
-    (dict(one_sublayer=True), (names.EXPERT_OUT, names.ROUTER_LOGITS),
-     32 + 16),
+    (dict(one_sublayer=True), (names.EXPERT_OUT, *ROUTERS_KEEPS),
+     32 + 16 + 8),
+    # the expert-share arm of a two-sublayer layer: what its router computed
+    (dict(), (MIXER_OUT, *ROUTERS_KEEPS), 32 + 16 + 8),
+    (dict(expert_fn=names.RELU2), (MIXER_OUT, *ROUTERS_KEEPS), 32 + 16 + 8),
     # and what was returned before for every other layer
-    (dict(), (MIXER_OUT,), 32),
-    (dict(expert_fn=names.RELU2), (MIXER_OUT,), 32),
     (dict(feed_forward=names.DENSE_FFN),
      (MIXER_OUT,) + names.DENSE_FFN_KEEPS, 32 + 2 * 48 + 32),
     (dict(one_sublayer=True, feed_forward=names.DENSE_FFN), (), 0),
@@ -656,10 +656,12 @@ ONE_EXPERT_LAYER = dict(one_sublayer=True, shared_scored=False)
 def test_remat_keeps_by_the_layers_shape(arms, keeps, columns):
     """A one-sublayer expert layer keeps, beside its share's result, the
     outputs of the dense products its backward pass reads: the router's
-    logits (float32: 8 experts are 16 bf16 columns), ``latent_down``'s
+    logits (float32: 8 experts are 16 bf16 columns) and its 2 picks and
+    their scores (int32 and float32: 8 bf16 columns), ``latent_down``'s
     where there is a latent space, an unscored shared expert's first
-    products'.  Every other layer keeps what it kept.  ``kept_bytes`` over
-    64 tokens in bf16, by hand."""
+    products'.  The expert-share arm of a two-sublayer layer keeps the
+    router's two beside ``MIXER_OUT`` (since PR 44); every other layer
+    keeps what it kept.  ``kept_bytes`` over 64 tokens in bf16, by hand."""
     z = _sizes(**arms)
     assert hybrid.remat_keeps(z) == keeps
     assert hybrid.kept_bytes(keeps, z, 64, jnp.bfloat16) == 64 * 2 * columns
@@ -695,7 +697,9 @@ def test_a_rematerialised_expert_layer_runs_its_dense_products_once(
     params = hybrid.HybridLM(vocab=64, layer_types=kinds, sizes=z).init(
         jax.random.PRNGKey(0), tokens)
     keeps = hybrid.remat_keeps(z)
-    new = [k for k in keeps if k != names.EXPERT_OUT]
+    # (the router's picks are no product: the test of the sorts counts them)
+    new = [k for k in keeps
+           if k not in (names.EXPERT_OUT, names.ROUTER_PICKS)]
     assert len(new) == (2 if z.expert_fn == names.RELU2 else 3) + bool(
         z.latent_width)
     jaxpr = jax.make_jaxpr(jax.grad(program(True)))(params)
@@ -718,6 +722,121 @@ def test_a_rematerialised_expert_layer_runs_its_dense_products_once(
             kept, recomputed, plain))):
         assert got.dtype == want.dtype
         assert bool(jnp.all(got == again)) and bool(jnp.all(got == want))
+
+
+def _router_before_pr44(logits, k, choice_bias, scale, scoring):
+    """The yardstick: ``route``'s scorings as they stood before PR 44,
+    ``lax.top_k`` and, for the sigmoids, ``take_along_axis`` behind it."""
+    if scoring == names.SOFTMAX:
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, picks = jax.lax.top_k(probs, k)
+        return picks, weights / jnp.sum(weights, axis=-1,
+                                        keepdims=True), probs
+    scores = jax.nn.sigmoid(logits)
+    chosen = scores if choice_bias is None else (
+        scores + jax.lax.stop_gradient(choice_bias.astype(jnp.float32)))
+    _, picks = jax.lax.top_k(chosen, k)
+    weights = jnp.take_along_axis(scores, picks, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return picks, weights * scale, scores
+
+
+def _bits(a):
+    return np.asarray(a).view(np.uint32)
+
+
+@pytest.mark.parametrize("scoring", [names.SIGMOID, names.SIGMOID_BIAS,
+                                     names.SOFTMAX])
+@pytest.mark.parametrize("tokens, n, k", [(96, 512, 22), (96, 256, 10),
+                                          (16, 8, 3)],
+                         ids=["22_of_512", "10_of_256", "3_of_8"])
+def test_route_is_top_k_and_take_along_axis_bit_for_bit(tokens, n, k,
+                                                        scoring):
+    """The picks (ties to the lower index), their weights and the gradient
+    with respect to the logits are those of ``lax.top_k`` +
+    ``take_along_axis``, to the last bit, whether the scores ride the sort
+    that picks them (a choice bias) or are ``top_k``'s own values (none).
+    Logits and bias are rounded to halves and eighths so that scores tie
+    within a row, biased and unbiased; the gradient comes through weights
+    mixed with both signs, and through the scores themselves."""
+    key = jax.random.split(jax.random.PRNGKey(n + k), 3)
+    logits = jnp.round(2 * jax.random.normal(key[0], (tokens, n))) / 2
+    bias = (jnp.round(4 * jax.random.normal(key[1], (n,))) / 8
+            if scoring == names.SIGMOID_BIAS else None)
+    scale = 1.0 if scoring == names.SOFTMAX else 2.5
+    mix = jax.random.normal(key[2], (tokens, k))
+    ties = jnp.sort(logits if bias is None
+                    else jax.nn.sigmoid(logits) + bias, axis=-1)
+    assert bool(jnp.any(ties[:, 1:] == ties[:, :-1]))
+
+    def now(x):
+        r = moe.route(x, n_experts=n, k=k, scoring=scoring,
+                      choice_bias=bias, scale=scale)
+        return jnp.sum(r.weights * mix) + 1e-3 * jnp.sum(r.probs ** 2), (
+            r.expert_idx, r.weights)
+
+    def before(x):
+        picks, weights, probs = _router_before_pr44(x, k, bias, scale,
+                                                    scoring)
+        return jnp.sum(weights * mix) + 1e-3 * jnp.sum(probs ** 2), (
+            picks, weights)
+
+    (_, (picks, weights)), grad = jax.value_and_grad(now, has_aux=True)(
+        logits)
+    (_, (want_picks, want_weights)), want_grad = jax.value_and_grad(
+        before, has_aux=True)(logits)
+    np.testing.assert_array_equal(picks, want_picks)
+    np.testing.assert_array_equal(_bits(weights), _bits(want_weights))
+    np.testing.assert_array_equal(_bits(grad), _bits(want_grad))
+    assert bool(jnp.any(grad != 0))
+
+
+def _sorts_over(jaxpr, n: int) -> int:
+    """The ``sort`` and ``top_k`` equations of a jaxpr, nested ones too,
+    whose operand is ``n`` wide along the sorted dimension (a ``top_k`` is a
+    sort of the whole row on the chip)."""
+    sorted_axis = {"sort": "dimension", "top_k": "axis"}
+    return sum(
+        eqn.invars[0].aval.shape[eqn.params[sorted_axis[name]]] == n
+        for eqn in _equations(jaxpr)
+        if (name := eqn.primitive.name) in sorted_axis)
+
+
+@pytest.mark.parametrize("scoring", [names.SIGMOID_BIAS, names.SIGMOID,
+                                     names.SOFTMAX])
+@pytest.mark.parametrize("arms, kinds", [
+    (ONE_EXPERT_LAYER, (names.FULL, names.EXPERT_LAYER, names.EXPERT_LAYER)),
+    (dict(), (names.FULL, names.FULL))],
+    ids=["one_sublayer", "two_sublayers"])
+def test_a_rematerialised_expert_layer_sorts_its_scores_once(arms, kinds,
+                                                            scoring,
+                                                            monkeypatch):
+    """Two expert layers behind (or inside) attention layers, remat
+    ``nothing``: the gradient's jaxpr holds ONE sort over the router's 8
+    scores a layer, as the program without remat does, because the layer
+    keeps ``names.ROUTER_PICKS`` and the picks' gradient reads the NAMED
+    picks; with the picks' name taken out of what the layer keeps the sort
+    runs again in the rematerialised forward, twice a layer (counted in the
+    jaxpr, before any chip run: PR 40's lesson (1)).  No gather picks the
+    scores in either: the only tensors of ``[tokens, top_k]`` gathered are
+    the dispatch's."""
+    z = _sizes(scoring=scoring, **arms)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 64), 0, 64)
+
+    def sorts(remat):
+        module = hybrid.HybridLM(vocab=64, layer_types=kinds, sizes=z,
+                                 remat=remat)
+        params = jax.eval_shape(module.init, jax.random.PRNGKey(0), tokens)
+        return _sorts_over(jax.make_jaxpr(jax.grad(
+            lambda p: lm_loss(module.apply(p, tokens), tokens)))(
+                params).jaxpr, z.n_experts)
+
+    assert names.ROUTER_PICKS in hybrid.remat_keeps(z)
+    assert sorts(True) == sorts(False) == 2
+    kept = hybrid.remat_keeps
+    monkeypatch.setattr(hybrid, "remat_keeps", lambda z: tuple(
+        name for name in kept(z) if name != names.ROUTER_PICKS))
+    assert sorts(True) == 4
 
 
 def test_the_capacity_arm_routes_by_the_same_router():

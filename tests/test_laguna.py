@@ -542,13 +542,15 @@ def test_a_router_held_fixed_still_hands_its_gradient_to_the_tokens():
 def test_the_gradient_under_remat_is_the_gradient_without(f32_pair):
     """What a layer keeps changes no number: layer 0 keeps its dense
     feed-forward's three products beside ``mixer_out``, the expert layers
-    ``mixer_out`` alone."""
+    ``mixer_out`` and what their router computed: its logits, its picks and
+    their scores."""
     p = f32_pair
     z = p["module"].sizes
     dense = dataclasses.replace(z, feed_forward=names.DENSE_FFN)
     assert hybrid.remat_keeps(dense) == (
         names.MIXER_OUT,) + names.DENSE_FFN_KEEPS
-    assert hybrid.remat_keeps(z) == (names.MIXER_OUT,)
+    assert hybrid.remat_keeps(z) == (
+        names.MIXER_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS)
     plain = dataclasses.replace(p["module"], remat=False)
     grads = jax.grad(lambda q: lm_loss(plain.apply(q, p["tokens"]),
                                        p["tokens"]))(p["params"])
@@ -559,13 +561,16 @@ def test_the_gradient_under_remat_is_the_gradient_without(f32_pair):
 def test_the_real_cells_layers_keep_their_bytes():
     """Layer 0: ``mixer_out`` and the dense arm's three products over 8,192
     tokens in bf16, 8192 x (3072 + 2 x 12288 + 3072) x 2 = 503.3 MB; the
-    expert layers ``mixer_out`` alone, 50.3 MB."""
+    expert layers ``mixer_out``, 50.3 MB, the router's float32 logits over
+    256 experts, 8.4 MB, and its 10 picks and their scores (int32 and
+    float32), 0.66 MB."""
     z = arch.build_module(REAL, {"remat": "nothing"}).sizes
     dense = dataclasses.replace(z, feed_forward=names.DENSE_FFN)
     assert hybrid.kept_bytes(hybrid.remat_keeps(dense), dense, 8192,
                              jnp.bfloat16) == 503_316_480
     assert hybrid.kept_bytes(hybrid.remat_keeps(z), z, 8192,
-                             jnp.bfloat16) == 50_331_648
+                             jnp.bfloat16) == (
+        50_331_648 + 8_388_608 + 655_360)
 
 
 # ---------------------------------------------------------------------------
@@ -822,10 +827,13 @@ def test_the_layout_events_say_the_kinds_the_arms_and_the_share(tmp_path,
     assert mixer["feed_forwards"] == [names.DENSE_FFN] + [
         names.EXPERT_SHARE] * 4
     assert mixer["remat_keeps"] == [
-        [names.MIXER_OUT, *names.DENSE_FFN_KEEPS]] + [[names.MIXER_OUT]] * 4
+        [names.MIXER_OUT, *names.DENSE_FFN_KEEPS]] + [
+        [names.MIXER_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS]] * 4
     itemsize = 4    # the pair computes in float32
+    # an expert layer: ``mixer_out``, 64 experts' logits, 8 picks and scores
     assert mixer["remat_kept_bytes_per_layer"] == [
-        256 * (64 + 2 * 160 + 64) * itemsize] + [256 * 64 * itemsize] * 4
+        256 * (64 + 2 * 160 + 64) * itemsize] + [
+        256 * (64 * itemsize + 64 * 4 + 8 * 8)] * 4
     layouts = [r for r in ring if r["name"] == names.MOE_LAYOUT]
     assert len(layouts) == 4
     for said in layouts:
@@ -852,8 +860,9 @@ def test_a_decoder_of_one_arm_says_its_keeps_as_before(tmp_path):
                    if r["name"] == names.MIXER_LAYOUT][:1]
     finally:
         telemetry.finish(write_report=False)
-    assert said["remat_keeps"] == [names.MIXER_OUT]
-    assert said["remat_kept_bytes_per_layer"] == 64 * 32 * 4
+    assert said["remat_keeps"] == [names.MIXER_OUT, names.ROUTER_LOGITS,
+                                   names.ROUTER_PICKS]
+    assert said["remat_kept_bytes_per_layer"] == 64 * (32 * 4 + 4 * 4 + 2 * 8)
     assert said["feed_forwards"] == [names.EXPERT_SHARE] * 2
     assert (said["attn_heads"], said["attn_kv_heads"]) == ([2, 2], 1)
     assert "softmax_kinds" not in said

@@ -256,8 +256,8 @@ def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
         2, 16, 1, 16)
     assert (z.n_experts, z.held, z.first_expert, z.top_k) == (32, 4, 0, 6)
     assert hybrid.remat_keeps(z) == (
-        names.EXPERT_OUT, names.ROUTER_LOGITS, names.LATENT_IN,
-        names.SHARED_UP)
+        names.EXPERT_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS,
+        names.LATENT_IN, names.SHARED_UP)
 
 
 def test_logits_match_the_reference(f32_pair):
@@ -681,11 +681,13 @@ def test_the_layout_events_say_the_arms_and_the_share(tmp_path, f32_pair):
     # keep one buffer)
     assert e["one_sublayer"] is True
     # the share's result and latent_down's (32 wide), the router's logits
-    # (32 experts), the shared expert's ``up`` (96 wide), all float32 here
+    # (32 experts), the shared expert's ``up`` (96 wide), all float32 here,
+    # and the 6 picks and their scores (int32 + float32)
     assert e["remat_keeps"] == [names.EXPERT_OUT, names.ROUTER_LOGITS,
-                                names.LATENT_IN, names.SHARED_UP]
+                                names.ROUTER_PICKS, names.LATENT_IN,
+                                names.SHARED_UP]
     assert e["remat_kept_bytes_per_layer"] == 2 * 128 * (
-        32 + 32 + 32 + 96) * 4
+        (32 + 32 + 32 + 96) * 4 + 6 * 8)
     assert (e["ssm_heads"], e["ssm_groups"], e["ssm_head_dim"],
             e["ssm_state"], e["ssm_chunk"]) == ([2, 16], [1, 8], 8, 16, 32)
     assert (e["attention"], e["attn_heads"], e["attn_kv_heads"]) == (
@@ -703,16 +705,19 @@ def test_the_layout_events_say_the_arms_and_the_share(tmp_path, f32_pair):
 def test_the_real_cells_expert_layers_keep_138_megabytes_a_layer():
     """The cell's sizes, by hand: 8,192 tokens x (the share's result 1,024
     + ``latent_down``'s 1,024 + the shared expert's ``up`` 5,376) x 2 bytes
-    + 8,192 x 512 logits x 4 bytes = 16.8 + 16.8 + 88.1 + 16.8 MB."""
+    + 8,192 x 512 logits x 4 bytes = 16.8 + 16.8 + 88.1 + 16.8 MB, and
+    since PR 44 the 22 picks and their scores, 8,192 x 22 x 8 bytes = 1.4
+    MB: 139.9 MB."""
     z = arch.build_module(REAL, {"remat": "nothing"}).sizes
     keep = hybrid.remat_keeps(z)
-    assert keep == (names.EXPERT_OUT, names.ROUTER_LOGITS, names.LATENT_IN,
-                    names.SHARED_UP)
+    assert keep == (names.EXPERT_OUT, names.ROUTER_LOGITS,
+                    names.ROUTER_PICKS, names.LATENT_IN, names.SHARED_UP)
     each = [hybrid.kept_bytes((name,), z, 8192, jnp.bfloat16)
             for name in keep]
-    assert each == [16_777_216, 16_777_216, 16_777_216, 88_080_384]
+    assert each == [16_777_216, 16_777_216, 1_441_792, 16_777_216,
+                    88_080_384]
     assert hybrid.kept_bytes(keep, z, 8192, jnp.bfloat16) == sum(each) == (
-        138_412_032)
+        139_853_824)
     assert hybrid.kept_bytes((), z, 8192, jnp.bfloat16) == 0
 
 
@@ -764,7 +769,7 @@ def test_the_gradient_with_the_keeps_is_the_gradient_without_remat(f32_pair):
     compute.  ``f32_pair``'s module is rematerialised with the keeps."""
     p = f32_pair
     assert p["module"].remat and len(hybrid.remat_keeps(
-        p["module"].sizes)) == 4
+        p["module"].sizes)) == 5
     plain = arch.build_module(p["config"], {"remat": None})
     assert not plain.remat
     loss, grads = jax.value_and_grad(

@@ -279,17 +279,20 @@ def test_a_rematerialised_layer_computes_its_feed_forward_once(policy,
 
 
 def test_the_expert_share_arm_keeps_what_it_kept():
-    """``MIXER_OUT`` alone: the count of products in its gradient is PR
-    34's (352 under ``nothing``, CPU)."""
+    """``MIXER_OUT`` and, since PR 44, what its router computed (the
+    logits, the picks and their scores): the count of products in its
+    gradient is PR 34's (352 under ``nothing``, CPU) less the router's
+    rematerialised product, one a layer."""
     from cellbench.archs import qwen3_next
 
     module, params, loss = bf16_program(
         qwen3_next, json.loads((DATA / "tiny-hybrid.json").read_text()),
         "nothing")
     assert module.sizes.feed_forward == names.EXPERT_SHARE
-    assert hybrid.remat_keeps(module.sizes) == (names.MIXER_OUT,)
+    assert hybrid.remat_keeps(module.sizes) == (
+        names.MIXER_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS)
     jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
-    assert len(products(jaxpr.jaxpr)) == 352
+    assert len(products(jaxpr.jaxpr)) == 352 - len(module.layer_types)
     assert not any(f"name={name}" in str(jaxpr)
                    for name in names.DENSE_FFN_KEEPS)
 

@@ -867,11 +867,13 @@ def test_moe_layout_says_the_windows_a_share_takes(
 @pytest.mark.parametrize("feed_forward, remat, keeps, columns", [
     (names.DENSE_FFN, True, [names.MIXER_OUT, names.FFN_GATE, names.FFN_UP,
                              names.FFN_OUT], 32 + 2 * 48 + 32),
-    (names.EXPERT_SHARE, True, [names.MIXER_OUT], 32),
+    (names.EXPERT_SHARE, True, [names.MIXER_OUT, names.ROUTER_LOGITS,
+                                names.ROUTER_PICKS], 32 + 8 + 8),
     (names.DENSE_FFN, False, [], 0),
     (names.EXPERT_LAYER, True, [
-        names.EXPERT_OUT, names.ROUTER_LOGITS, names.LATENT_IN,
-        names.SHARED_GATE, names.SHARED_UP], 24 + 8 + 24 + 2 * 16)],
+        names.EXPERT_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS,
+        names.LATENT_IN, names.SHARED_GATE, names.SHARED_UP],
+     24 + 8 + 8 + 24 + 2 * 16)],
     ids=["dense_arm", "expert_share_arm", "no_remat",
          "one_sublayer_expert_layer"])
 def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
@@ -882,7 +884,10 @@ def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
     x itemsize`` more; in a decoder of one-sublayer layers an expert
     layer's ``EXPERT_OUT`` and ``LATENT_IN``, ``tokens x latent_width x
     itemsize`` each, its router's float32 logits (4 experts: 8 bf16
-    columns) and its gated shared expert's two first products'."""
+    columns), its 2 picks and their scores (int32 and float32: 8 bf16
+    columns) and its gated shared expert's two first products'; in the
+    expert-share arm of a two-sublayer layer ``MIXER_OUT`` and the router's
+    two."""
     from tpudist.models.hybrid import HybridLM, HybridSizes
 
     arms = dict(feed_forward=feed_forward)

@@ -696,7 +696,8 @@ class ExpertShare(nn.Module):
     layer to keep (:func:`remat_keeps`): ``latent_down``'s, the unscored
     shared expert's first products' (named here, by the weight multiplied,
     and not in the expert function, which the grouped products run too),
-    the router's logits (in ``expert_share``)."""
+    the router's logits (in ``expert_share``), and with them what the
+    router decided, its picks and their scores (in ``route``)."""
 
     sizes: HybridSizes
     dtype: jnp.dtype
@@ -875,8 +876,12 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
     layers at 8,192 x 8,192, ROADMAP S26) ``MIXER_OUT`` alone, and each
     feed-forward's ``gate`` and ``up`` run again in the backward pass
     (``down``'s output is read by nothing there).  The expert-share arm of
-    a two-sublayer layer recomputes its feed-forward: the buffers of its
-    dispatch are many times a layer's activations.
+    a two-sublayer layer recomputes its feed-forward's buffers (those of
+    its dispatch are many times a layer's activations) but not its router:
+    it keeps the router's logits and ``names.ROUTER_PICKS``, the picks and
+    their scores (``tokens x top_k x 8`` bytes), so that the float32
+    product, the sort over ``n_experts`` and the pick of the scores run
+    once a step.
 
     A layer of one sublayer keeps its input and, where it is an expert
     layer, (a) the share's result ``names.EXPERT_OUT`` where the share
@@ -887,7 +892,9 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
     again for it is one the compiler cannot merge with the backward
     pass's own; (b) the outputs of the dense products that its backward
     pass reads and its rematerialised forward would therefore run again:
-    the router's logits (``tokens x n_experts x 4`` bytes, float32),
+    the router's logits (``tokens x n_experts x 4`` bytes, float32) and
+    what it decided by them (``names.ROUTER_PICKS``, ``tokens x top_k x 8``
+    bytes: behind the two, no sort runs again),
     ``latent_down``'s output where the layer has latent projections
     (``tokens x latent_width x itemsize``) and an UNSCORED shared expert's
     first products' (``up``; ``gate`` too where the expert is gated:
@@ -900,11 +907,13 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
     twice; no architecture has one).  A scored shared expert is computed
     inside ``expert_share`` and names nothing.  At 8,192 tokens, 512
     experts, a latent width of 1,024 and a squared-ReLU shared expert of
-    5,376 in bf16: 16.8 + 16.8 + 16.8 + 88.1 = 138.4 MB a layer."""
+    5,376 in bf16 at 22 picks: 16.8 + 16.8 + 1.4 + 16.8 + 88.1 = 139.9 MB
+    a layer."""
+    router = (names.ROUTER_LOGITS, names.ROUTER_PICKS)
     if sizes.one_sublayer:
         if sizes.feed_forward != names.EXPERT_SHARE:
             return ()
-        keep = (names.EXPERT_OUT, names.ROUTER_LOGITS)
+        keep = (names.EXPERT_OUT,) + router
         if sizes.latent_width:
             keep += (names.LATENT_IN,)
         if not sizes.shared_scored:
@@ -914,13 +923,16 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
         return keep
     if sizes.feed_forward == names.DENSE_FFN and sizes.ffn_products_kept:
         return (names.MIXER_OUT,) + names.DENSE_FFN_KEEPS
+    if sizes.feed_forward == names.EXPERT_SHARE:
+        return (names.MIXER_OUT,) + router
     return (names.MIXER_OUT,)
 
 
 def kept_bytes(keep: tuple, sizes: HybridSizes, tokens: int, dtype) -> int:
     """What the activations named ``keep`` hold a layer from its forward to
     its backward pass, over ``tokens`` positions in compute dtype ``dtype``
-    (the router's logits in float32)."""
+    (the router's logits in float32, its picks and their scores in int32
+    and float32)."""
     columns = {names.MIXER_OUT: sizes.d_model,
                names.FFN_GATE: sizes.ffn_width,
                names.FFN_UP: sizes.ffn_width, names.FFN_OUT: sizes.d_model,
@@ -929,9 +941,11 @@ def kept_bytes(keep: tuple, sizes: HybridSizes, tokens: int, dtype) -> int:
                names.SHARED_GATE: sizes.shared_width,
                names.SHARED_UP: sizes.shared_width}
     itemsize = jnp.dtype(dtype).itemsize
+    router = {names.ROUTER_LOGITS: 4 * sizes.n_experts,
+              names.ROUTER_PICKS: 8 * sizes.top_k}
     return tokens * sum(
-        4 * sizes.n_experts if name == names.ROUTER_LOGITS
-        else itemsize * columns[name] for name in keep)
+        router[name] if name in router else itemsize * columns[name]
+        for name in keep)
 
 
 class HybridLM(nn.Module):
@@ -956,7 +970,8 @@ class HybridLM(nn.Module):
     # products' outputs (``tokens x (2 x ffn_width + d_model) x itemsize``
     # more), so its forward runs once; an
     # expert layer of one sublayer the result of a share that goes by windows
-    # and the outputs of its dense products that the backward pass reads
+    # and the outputs of its dense products that the backward pass reads; and
+    # every expert layer its router's logits, picks and the picks' scores
     remat_policy: str = "nothing"
     # the feed-forward arm a layer (names.EXPERT_SHARE / names.DENSE_FFN)
     # where the layers do not share ``sizes.feed_forward`` (leading dense

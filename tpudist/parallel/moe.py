@@ -66,6 +66,56 @@ class Routing(NamedTuple):
     #                        expert is held here, ``held`` where it is absent
 
 
+def _top_scores(scores, bias, k):
+    """``(picks [t, k] int32, the picks' scores [t, k])`` of ``scores [t,
+    n]``: a row's ``k`` largest ``scores + bias [n]`` (``scores`` where
+    ``bias`` is None) in falling order, ties to the lower index as
+    ``lax.top_k`` breaks them, and ``scores`` (never ``scores + bias``) at
+    the picks.  Where they are differentiated both carry the name
+    ``names.ROUTER_PICKS`` for a rematerialised caller to keep.
+
+    Without a bias they are ``lax.top_k``'s own values and indices.  With
+    one, the scores ride the sort that picks them: ONE stable sort of
+    ``(-(scores + bias), column, scores)`` by its first operand, cut to
+    ``k`` columns, gives the picks ``top_k`` gives and moves their scores
+    with them, where ``take_along_axis`` behind a ``top_k`` is a gather of
+    its own (10 ns a number on the chip).  The gradient is given here:
+    JAX's own for a sorted payload gathers the whole row's tangent, and
+    ``top_k``'s and ``take_along_axis``'s is a scatter, which the chip's
+    compiler runs as a sort of all ``t * k`` positions and a scatter
+    behind it (1.7 ms a layer at 8,192 x 22, outside every scope).
+    ``d_scores`` is the same numbers as a one-hot sum, the picks' cotangent
+    where a column is the pick and 0 elsewhere, which fuses into one pass
+    over ``[t, n]``; the choice takes no gradient.  The names are given in
+    the forward rule, to its results and to its residual (a policy does
+    not see into the call itself), so that a caller who keeps them runs no
+    sort again for its backward pass."""
+    @jax.custom_vjp
+    def top(scores, bias):
+        if bias is None:
+            picked, idx = lax.top_k(scores, k)
+            return idx, picked
+        column = lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        _, idx, picked = lax.sort(
+            (-(scores + bias), column, scores), dimension=1, is_stable=True,
+            num_keys=1)
+        return idx[:, :k], picked[:, :k]
+
+    def fwd(scores, bias):
+        idx, picked = checkpoint_name(top(scores, bias), names.ROUTER_PICKS)
+        return (idx, picked), idx
+
+    def bwd(idx, cotangents):
+        is_pick = idx[:, :, None] == lax.broadcasted_iota(
+            jnp.int32, (1, 1, scores.shape[1]), 2)
+        d_scores = jnp.sum(
+            jnp.where(is_pick, cotangents[1][:, :, None], 0.0), axis=1)
+        return d_scores, None if bias is None else jnp.zeros_like(bias)
+
+    top.defvjp(fwd, bwd)
+    return top(scores, bias)
+
+
 def _softmax_scores(logits, k, choice_bias, scale):
     """Softmax over all experts; the raw top probability at ``k=1``
     (Switch), renormalised to sum 1 over the ``k`` at ``k>1``
@@ -74,7 +124,7 @@ def _softmax_scores(logits, k, choice_bias, scale):
         raise ValueError(f"{names.SOFTMAX} scoring takes no choice bias and "
                          f"no scale")
     probs = jax.nn.softmax(logits, axis=-1)
-    weights, expert_idx = lax.top_k(probs, k)
+    expert_idx, weights = _top_scores(probs, None, k)
     if k > 1:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return expert_idx, weights, probs
@@ -85,12 +135,12 @@ def _sigmoid_scores(logits, k, choice_bias, scale):
     the ``k`` largest scores, or where there is a ``choice_bias`` the ``k``
     largest of ``score + choice_bias``, a buffer that steers the choice
     alone and takes no gradient; the weights are the picks' UNbiased scores
-    over their sum (+ 1e-20), times ``scale``."""
+    (:func:`_top_scores`: no gather reads them) over their sum (+ 1e-20),
+    times ``scale``."""
     scores = jax.nn.sigmoid(logits)
-    chosen = scores if choice_bias is None else scores + lax.stop_gradient(
+    bias = None if choice_bias is None else lax.stop_gradient(
         choice_bias.astype(jnp.float32))
-    _, expert_idx = lax.top_k(chosen, k)
-    weights = jnp.take_along_axis(scores, expert_idx, axis=-1)
+    expert_idx, weights = _top_scores(scores, bias, k)
     weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
     return expert_idx, weights * scale, scores
 
@@ -118,7 +168,12 @@ def route(logits: jax.Array, *, n_experts: int, k: int,
     ``scale``).  The
     width, the picks and the renormalisation do not depend on which experts
     are held here; ``held`` / ``first_expert`` only say which of the picks
-    this device computes (``local``)."""
+    this device computes (``local``).  What is decided, the picks and their
+    scores before the renormalisation, carries the name
+    ``names.ROUTER_PICKS`` (:func:`_top_scores`): a rematerialised caller
+    that keeps it beside the logits runs the router once a step, and what
+    runs again behind the two (the scores, the renormalisation, ``local``)
+    is elementwise."""
     if logits.shape[-1] != n_experts:
         raise ValueError(f"the router gives {logits.shape[-1]} scores for "
                          f"{n_experts} experts")
@@ -594,7 +649,8 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
     latent space) and carry the name ``names.ROUTER_LOGITS`` for a
     rematerialised caller to keep; its scores, top ``k``, renormalisation
     and ``scale``
-    are :func:`route`'s by ``scoring``, over all ``n_experts``.  The
+    are :func:`route`'s by ``scoring``, over all ``n_experts``, and the
+    picks and their scores carry ``names.ROUTER_PICKS``.  The
     experts compute in ``x``'s dtype.  ``shared``
     is ``sigmoid(x . score) * E_shared(x)``, what every member of the
     group computes alike.  The tokens are taken in equal blocks of at most

@@ -137,6 +137,14 @@ SHARED_UP = "shared_up"
 SHARED_EXPERT_KEEPS = {"gate": SHARED_GATE, "up": SHARED_UP}  # by weight
 ROUTER_LOGITS = "router_logits"
 LATENT_IN = "latent_in"
+# tpudist/parallel/moe.py: what the router decided, named by ``route`` for
+# every caller: the picks ``[tokens, k]`` int32 and the picks' raw scores
+# ``[tokens, k]`` float32 (before they are renormalised), one name for both.
+# Every rematerialised expert layer keeps them beside the router's logits,
+# a layer of two sublayers too: what is run again behind them is
+# elementwise, with no sort and no pick of a score.  ``tokens x k x 8``
+# bytes a layer
+ROUTER_PICKS = "router_picks"
 
 # -- spans (tpudist.telemetry.span / record_span) -----------------------------
 STEP = "step"            # one arrival of a step's result to the next
@@ -232,9 +240,10 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # names a rematerialised layer keeps besides its input (MIXER_OUT, and
 # DENSE_FFN_KEEPS in a dense arm that keeps its products:
 # ``dense_products_kept=`` says so a layer; in an expert layer of one sublayer
-# EXPERT_OUT, ROUTER_LOGITS, LATENT_IN where it has latent projections, and
-# SHARED_EXPERT_KEEPS' names of an unscored shared expert's first products;
-# ``[]`` without remat) and
+# EXPERT_OUT, ROUTER_LOGITS, ROUTER_PICKS, LATENT_IN where it has latent
+# projections, and SHARED_EXPERT_KEEPS' names of an unscored shared expert's
+# first products; in an expert layer of two sublayers MIXER_OUT,
+# ROUTER_LOGITS, ROUTER_PICKS; ``[]`` without remat) and
 # ``remat_kept_bytes_per_layer=`` what they hold (both a list a layer where
 # the layers' feed-forward arms differ).  And of each expert
 # layer
