@@ -597,12 +597,23 @@ def test_olmo_hybrid_cell_step_carries_exactly_the_three_flash_kernels(
 @pytest.mark.parametrize("fixture, arguments, temporaries, instructions", [
     ("hybrid_step", 7_508_078_592, 7_504_172_032, 23_605),
     ("olmo_hybrid_step", 9_195_254_272, 5_077_698_560, 18_651),
-    ("nemotron_step", 8_410_485_760, 3_637_826_560, 22_252),
-    ("gpt2_cell_step", 8_006_918_656, 7_865_907_712, 20_502)],
-    ids=["share_cell", "olmo_cell", "nemotron_cell", "gpt2_cell"])
+    ("nemotron_step", 8_410_485_760, 3_634_439_680, 23_365),
+    ("gpt2_cell_step", 8_006_918_656, 7_865_907_712, 20_502),
+    ("laguna_step", 8_065_987_072, 4_298_534_400, 17_838)],
+    ids=["share_cell", "olmo_cell", "nemotron_cell", "gpt2_cell",
+         "laguna_cell"])
 def test_the_other_pattern_cells_steps_are_what_they_were(
         request, fixture, arguments, temporaries, instructions):
-    """Since PR 44 the two cells without experts hold what they held, to
+    """Since PR 45 the share cell (one buffer), the two cells without
+    experts here and the granite cell hold what they held (their optimized
+    HLO is the parent's text for text, ``benchmarks/step_hlo.py``), and the
+    two cells whose share goes by windows are read again: a trip scatters
+    its window by strips in a loop of its own, so the nemotron step holds
+    1,113 instructions more (22,252 before) and 3.4 MB of temporaries less
+    (3,637,826,560), and the laguna step 941 more (16,897) and 73.9 MB more
+    (4,224,679,936: the inner loop's tuple carries the window's result and
+    its index vectors beside the sum).  Before it:
+    since PR 44 the two cells without experts hold what they held, to
     the byte and the instruction (their optimized HLO is the parent's text
     for text, ``benchmarks/step_hlo.py``), and the two expert cells are
     read again: a rematerialised expert layer keeps its router's picks and
@@ -687,6 +698,17 @@ def _router_sorts(text: str, n_experts: int) -> list:
         rf"= \((f32\[8192,{n_experts}\][^=]*?)\) sort\(", text)]
 
 
+def _scatter_updates(text: str, result: str) -> list:
+    """The shape of the update of every ``scatter`` instruction of a
+    compiled step whose result is ``result`` (``"f32[8192,1024]"``)."""
+    import re
+
+    return [re.search(rf"%{re.escape(update)} = (\w+\[[\d,]*\])", text)[1]
+            for update in re.findall(
+                rf"= {re.escape(result)}\S* scatter\(%\S+, %\S+, %(\S+?)\)",
+                text)]
+
+
 def _picks_gathered(text: str, k: int) -> int:
     """The instructions of a compiled step that gather ``[8192, k]`` scores
     at the router's picks under an expert layer's scope: what
@@ -720,7 +742,8 @@ def test_nemotron_cell_step_fills_one_chip_and_fits(nemotron_step):
     # 700,862,960 parameters (and 5 x 512 numbers of choice bias) x 12
     # bytes resident
     assert 8.40e9 < mem.argument_size_in_bytes < 8.42e9
-    # 11.22 GiB = 12.05 GB: temporaries 3,637,826,560 bytes (3,660,219,904
+    # 11.22 GiB = 12.05 GB: temporaries 3,634,439,680 bytes (3,637,826,560
+    # before PR 45's strips; 3,660,219,904
     # before PR 44 kept the router's picks, 1.4 MB a layer, and so freed
     # the rematerialised sorts' operands), the float32
     # gradient (2.80 GB) among them.  An expert layer takes what arrived
@@ -770,6 +793,11 @@ def test_nemotron_cell_step_keeps_the_flash_kernels_and_groups_the_experts(
     assert not re.search(r"\[512,1024,2688\]|\[512,2688,1024\]", text)
     assert "[22528,2688]" in text and "[22528,1024]" in text
     assert "[180224,2688]" not in text and "[180224,1024]" not in text
+    # a trip scatters its window by strips of one even share (PR 45): the
+    # ten scatter-adds into the result and into ``d_x`` take 2,816 rows, the
+    # five of the weights' gradient as many numbers, none a window's 22,528
+    assert _scatter_updates(text, "f32[8192,1024]") == ["f32[2816,1024]"] * 10
+    assert _scatter_updates(text, "f32[180224]") == ["f32[2816]"] * 5
     assert job["collectives_in_step"] == []
     assert "all-reduce" not in text and "all-gather" not in text
     shared = [line for line in text.splitlines()
@@ -801,8 +829,9 @@ def test_laguna_cell_step_fills_one_chip_and_fits(laguna_step):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     # 672,125,952 parameters x 12 bytes resident
     assert 8.06e9 < mem.argument_size_in_bytes < 8.07e9
-    # 11.45 GiB = 12.29 GB: temporaries 4,224,679,936 bytes (4,254,528,512
-    # before PR 44), the float32
+    # 11.51 GiB = 12.36 GB: temporaries 4,298,534,400 bytes (4,224,679,936
+    # before PR 45, whose loop over a trip's strips carries the window's
+    # result beside the sum; 4,254,528,512 before PR 44), the float32
     # gradient (2.69 GB) among them; layer 0 keeps its dense feed-forward's
     # three products from forward to backward (503 MB with ``mixer_out``),
     # the expert layers ``mixer_out`` (50 MB each) and, since PR 44, their
@@ -837,6 +866,10 @@ def test_laguna_cell_step_runs_the_band_and_groups_the_experts(laguna_step):
     assert not re.search(r"\[256,3072,1024\]|\[256,1024,3072\]", text)
     assert "[20480,3072]" in text and "[20480,1024]" in text
     assert "[81920,3072]" not in text and "[81920,1024]" not in text
+    # a trip scatters its window by strips of one even share (PR 45): 2,560
+    # rows a scatter-add, none a window's 20,480
+    assert _scatter_updates(text, "f32[8192,3072]") == ["f32[2560,3072]"] * 8
+    assert _scatter_updates(text, "f32[81920]") == ["f32[2560]"] * 4
     assert job["collectives_in_step"] == []
     assert "all-reduce" not in text and "all-gather" not in text
     # the router runs once a step (PR 44): ONE top-10 over the 256 scores a

@@ -328,9 +328,9 @@ def test_the_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
     # a share alone is the reference's share: the absent experts' part is
     # left out in both, and the shared expert is every member's
     alone = arch._experts(x, w, m=m, mode="f32", first=8, held=4)
-    got, counts, _ = moe.expert_share(share_of(m, w, 8, 4, shared=True), x,
-                                      n_experts=16, held=4, first_expert=8,
-                                      k=m["top_k"])
+    got, counts, _, _ = moe.expert_share(
+        share_of(m, w, 8, 4, shared=True), x, n_experts=16, held=4,
+        first_expert=8, k=m["top_k"])
     assert worst(got, alone) < 1e-5
     picks, _ = arch.route(x, w["router"], m=m)
     np.testing.assert_array_equal(
@@ -363,7 +363,7 @@ def test_rigged_imbalance_drops_nothing(rigged, block):
         return arch._experts(x, w, m=m, mode="f32", first=4, held=4,
                              shared=False)
 
-    _, counts, _ = program(params, x)
+    _, counts, _, _ = program(params, x)
     if rigged == "all_held":
         np.testing.assert_array_equal(counts, [1024] * 4)
     else:
@@ -490,31 +490,73 @@ def test_rows_the_grouped_products_leave_undefined_reach_nothing(
 
 
 #: a block of 64 tokens x top 6 = 384 assignments at 2 held experts, taken
-#: through windows of 32 rows (100 in ``ragged_bound``): load -> (the
-#: block's picks from a generator, the windows the arrivals fill)
+#: through windows of 32 rows and strips of 4 (100 and 12 in the ``ragged``
+#: loads: neither the bound's windows nor a window's strips come out even):
+#: load -> (the block's picks from a generator, the windows the arrivals
+#: fill, the strips they touch)
 def _even(rng):
     # a quarter of a window arrives in the mean
-    return np.where(rng.random((64, 6)) < 0.02, rng.integers(0, 2, (64, 6)),
-                    2), 1
+    picks = np.where(rng.random((64, 6)) < 0.02, rng.integers(0, 2, (64, 6)),
+                     2)
+    return picks, 1, -(-int((picks < 2).sum()) // 4)
 
 
-def _straddle(rng):
-    # 33 arrivals, one row past the first window's edge; the second
-    # expert's run of 13 lies over the edge
-    picks = np.full(384, 2)
-    picks[rng.permutation(384)[:33]] = [0] * 20 + [1] * 13
-    return picks.reshape(64, 6), 2
+def _arrivals(n, first=None):
+    """``n`` arrivals at random places, the first expert's ``first`` (half
+    of them) ahead of the second's."""
+    first = n // 2 if first is None else first
+
+    def load(rng):
+        picks = np.full(384, 2)
+        picks[rng.permutation(384)[:n]] = [0] * first + [1] * (n - first)
+        return picks.reshape(64, 6)
+    return load
+
+
+def _every_pick(rng):
+    return rng.integers(0, 2, (64, 6))
 
 
 WINDOW_LOADS = {
     "even": _even,
-    "no_arrival": lambda rng: (np.full((64, 6), 2), 0),
-    "every_pick_held": lambda rng: (rng.integers(0, 2, (64, 6)), 12),
-    "straddle": _straddle,
-    "even_poisoned": _even,
-    "straddle_poisoned": _straddle,
-    "ragged_bound": lambda rng: (rng.integers(0, 2, (64, 6)), 4),
+    "no_arrival": lambda rng: (np.full((64, 6), 2), 0, 0),
+    "every_pick_held": lambda rng: (_every_pick(rng), 12, 96),
+    # 33 arrivals, one row past the first window's edge; the second
+    # expert's run of 13 lies over the edge
+    "straddle": lambda rng: (_arrivals(33, 20)(rng), 2, 9),
+    # three whole windows of nine strips (the ninth starts early, at row
+    # 88 for its rows 96..99), and a last window that starts at row 284
+    # for its rows 300..383: strips 1..8, strip 1 from its fifth row on
+    "ragged_bound": lambda rng: (_every_pick(rng), 4, 35),
+    # the last arrival inside a strip, on a strip's edge, and in the last
+    # strip of a window
+    "ends_inside_a_strip": lambda rng: (_arrivals(6)(rng), 1, 2),
+    "ends_on_a_strips_edge": lambda rng: (_arrivals(8)(rng), 1, 2),
+    "ends_in_the_last_strip": lambda rng: (_arrivals(30)(rng), 1, 8),
+    "second_window_ends_on_an_edge": lambda rng: (_arrivals(44)(rng), 2, 11),
+    # the overlapping last window's rows 300..329: strips 1..3 of it, and
+    # none of the strips before ``lo`` a second time
+    "ragged_last_window_in_part": lambda rng: (_arrivals(330)(rng), 4, 30),
+    # the overlapping last window's first own row alone
+    "ragged_one_row_past_the_third_window":
+        lambda rng: (_arrivals(301)(rng), 4, 28),
 }
+# the same loads with the rows behind the last arrival poisoned
+WINDOW_LOADS.update({f"{load}_poisoned": WINDOW_LOADS[load] for load in (
+    "even", "straddle", "ends_inside_a_strip", "ends_in_the_last_strip",
+    "ragged_last_window_in_part")})
+
+
+def _strips_by_row(arrived, bound, window):
+    """The strips the loops scatter, counted a row at a time: every arrival
+    lies in one window (the last one starts early) and there in one strip
+    of ``window // 8`` rows."""
+    strip = window // 8
+    touched = set()
+    for row in range(arrived):
+        i = row // window
+        touched.add((i, (row - min(i * window, bound - window)) // strip))
+    return len(touched)
 
 
 @pytest.mark.parametrize("expert", [names.RELU2, names.GATED_SILU])
@@ -529,13 +571,21 @@ def test_windows_give_the_one_buffers_block_and_the_dense_sum(
     held (dropless); a run that lies over a window's edge; the rows behind
     the last arrival poisoned with NaN in the window's buffer and in its
     cotangents; a bound that is no multiple of the window (the last window
-    overlaps the one before it)."""
+    overlaps the one before it, and the strips before its own rows are not
+    scattered twice); a window that is no multiple of its strips; a last
+    arrival inside a strip, on a strip's edge and in a window's last strip.
+    The strips the layer counts are the ones the arrivals lie in."""
     t, k, d, width, held = 64, 6, 16, 8, 2
-    window = 100 if load == "ragged_bound" else 32
-    picks, windows = WINDOW_LOADS[load](np.random.default_rng(0))
+    window = 100 if load.startswith("ragged") else 32
+    picks, windows, strips = WINDOW_LOADS[load](np.random.default_rng(0))
     local = jnp.asarray(picks, jnp.int32)
     arrived = int((picks < held).sum())
     assert -(-arrived // window) == windows
+    assert moe.share_strip(window) == window // 8
+    assert _strips_by_row(arrived, t * k, window) == strips
+    first, end = moe._window_run(jnp.arange(-(-t * k // window)), arrived,
+                                 t * k, window)[2:]
+    assert int(jnp.sum(end - first)) == strips
     if load.startswith("even"):
         assert 0 < arrived < window // 2
     key = jax.random.split(jax.random.PRNGKey(1), 6)
@@ -573,6 +623,44 @@ def test_windows_give_the_one_buffers_block_and_the_dense_sum(
             assert rel(g, w) < 1e-5 if arrived else not np.asarray(g).any()
     if not arrived:
         assert not np.asarray(got).any()
+
+
+@pytest.mark.parametrize("expert, products", [(names.RELU2, 2),
+                                              (names.GATED_SILU, 3)])
+def test_a_trip_scatters_strips_and_its_products_take_the_window(
+        expert, products):
+    """The windowed block's jaxpr, forward and with its backward rule: every
+    scatter-add carries a strip's rows (12 of the window's 100) and none the
+    window's, and the grouped products are as many equations as before
+    there were strips, one a projection forward, and behind the forward one
+    again and two transposes a projection: the strips add no call site."""
+    t, k, d, width, held, window = 64, 6, 16, 8, 2, 100
+    experts = {name: jnp.ones(
+        (held, width, d) if name == "down" else (held, d, width))
+        for name in moe.EXPERT_LEAVES[expert]}
+    x, weights = jnp.ones((t, d)), jnp.ones((t, k))
+    local = jnp.zeros((t, k), jnp.int32)
+
+    def windowed(*a):
+        return moe._held_experts_windowed(*a, local, held,
+                                          moe.EXPERT_FNS[expert], window)
+
+    def both(experts, x, weights, dy):
+        y, pull = jax.vjp(windowed, experts, x, weights)
+        return y, pull(dy)
+
+    for program, args, passes in ((windowed, (experts, x, weights), 1),
+                                  (both, (experts, x, weights, x), 4)):
+        eqns = list(_equations(jax.make_jaxpr(program)(*args).jaxpr))
+        updates = [eqn.invars[2].aval.shape for eqn in eqns
+                   if eqn.primitive.name == "scatter-add"]
+        assert sorted(updates) == sorted(
+            [(12, d)] if passes == 1 else [(12,), (12, d), (12, d)])
+        assert sum(eqn.primitive.name.startswith("ragged_dot")
+                   for eqn in eqns) == passes * products
+        # a trip's loop over its strips inside the loop over the windows
+        assert sum(eqn.primitive.name == "while" for eqn in eqns) == (
+            2 if passes == 1 else 4)
 
 
 def _primitives(jaxpr):

@@ -841,7 +841,8 @@ def test_the_layout_events_say_the_kinds_the_arms_and_the_share(tmp_path,
                 said["held"], said["top_k"], said["width"]) == (
             names.SIGMOID, 2.5, 64, 2, 8, 64)
         assert (said["window_rows"], said["windows_at_most"],
-                said["combine"]) == (512, 4, names.SCATTER_ADD)
+                said["strip_rows"], said["combine"]) == (
+            512, 4, 64, names.SCATTER_ADD)
 
 
 def test_a_decoder_of_one_arm_says_its_keeps_as_before(tmp_path):

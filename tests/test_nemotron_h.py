@@ -428,7 +428,9 @@ def test_a_64th_of_the_experts_held_goes_by_windows(rigged, windows):
     experts all eight run, and nothing is dropped: loss and every gradient
     are the reference's either way, under the layers' rematerialisation
     (which keeps the windowed share's result).  The step built with
-    ``aux=True`` says the windows taken, a row a layer."""
+    ``aux=True`` says the windows taken and the strips of 64 rows
+    scattered, a row a layer: the strips the arrivals fill, all 64 where
+    every pick is held."""
     import optax
 
     from tpudist.runtime.mesh import MeshConfig, make_mesh
@@ -463,6 +465,8 @@ def test_a_64th_of_the_experts_held_goes_by_windows(rigged, windows):
     assert np.asarray(aux["moe_windows"]).tolist() == [[windows]]
     arrived = int(np.asarray(aux["moe_expert_tokens"]).sum())
     assert arrived == 4096 if rigged else 0 < arrived <= 512
+    assert moe.share_strip(512) == 64
+    assert np.asarray(aux["moe_strips"]).tolist() == [[-(-arrived // 64)]]
 
 
 # ---------------------------------------------------------------------------
@@ -697,9 +701,9 @@ def test_the_layout_events_say_the_arms_and_the_share(tmp_path, f32_pair):
     for r in said:
         assert (r["scoring"], r["scale"], r["width"], r["experts"],
                 r["held"], r["top_k"], r["buffer_rows"], r["window_rows"],
-                r["windows_at_most"], r["combine"]) == (
+                r["windows_at_most"], r["strip_rows"], r["combine"]) == (
                     names.SIGMOID_BIAS, 2.5, 32, 32, 4, 6, 2 * 128 * 6,
-                    2 * 128 * 6, 1, names.PICK_MAJOR)
+                    2 * 128 * 6, 1, 2 * 128 * 6, names.PICK_MAJOR)
 
 
 def test_the_real_cells_expert_layers_keep_138_megabytes_a_layer():

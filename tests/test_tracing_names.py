@@ -830,17 +830,19 @@ def test_the_pattern_decoder_says_its_layout_once_a_trace(tmp_path):
 
 
 @pytest.mark.parametrize(
-    "tokens, k, held, width, rows, blocks, window_rows, at_most, combine", [
-        (8192, 22, 8, 1024, 180224, 1, 22528, 8, names.SCATTER_ADD),
-        (16384, 10, 32, 2048, 81920, 2, 81920, 1, names.PICK_MAJOR)],
+    "tokens, k, held, width, rows, blocks, window_rows, at_most, strip_rows,"
+    " combine", [
+        (8192, 22, 8, 1024, 180224, 1, 22528, 8, 2816, names.SCATTER_ADD),
+        (16384, 10, 32, 2048, 81920, 2, 81920, 1, 81920, names.PICK_MAJOR)],
     ids=["8_of_512_held", "32_of_512_held"])
 def test_moe_layout_says_the_windows_a_share_takes(
         tmp_path, tokens, k, held, width, rows, blocks, window_rows, at_most,
-        combine):
+        strip_rows, combine):
     """The two cells' expert layers at their own shapes, traced only: a
     64th of the experts held takes its arrivals through windows of eight
-    even shares (22,528 rows, at most 8 of them a block), a 16th keeps one
-    buffer of the bound (``window_rows`` says the bound itself)."""
+    even shares (22,528 rows, at most 8 of them a block) and scatters them
+    by strips of one (2,816 rows), a 16th keeps one buffer of the bound
+    (``window_rows`` and ``strip_rows`` say the bound itself)."""
     from tpudist.parallel import moe
 
     shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)
@@ -849,19 +851,20 @@ def test_moe_layout_says_the_windows_a_share_takes(
                           "down": shape(held, 64, width)}}
     session = telemetry.start(tmp_path / "tele", rank=0, generation=0)
     try:
-        y, counts, windows = jax.eval_shape(
+        y, counts, windows, strips = jax.eval_shape(
             lambda p, x: moe.expert_share(
                 p, x, n_experts=512, held=held, first_expert=0, k=k,
                 expert_fn=moe.relu2_ffn), params, shape(tokens, width))
         said = [r for r in session.ring if r.get("name") == names.MOE_LAYOUT]
     finally:
         telemetry.finish(write_report=False)
-    assert (y.shape, counts.shape, windows.shape) == (
-        (tokens, width), (held,), (blocks,))
+    assert (y.shape, counts.shape, windows.shape, strips.shape) == (
+        (tokens, width), (held,), (blocks,), (blocks,))
     (e,) = said
     assert (e["buffer_rows"], e["blocks"], e["window_rows"],
-            e["windows_at_most"], e["combine"], e["dropless"]) == (
-                rows, blocks, window_rows, at_most, combine, True)
+            e["windows_at_most"], e["strip_rows"], e["combine"],
+            e["dropless"]) == (
+                rows, blocks, window_rows, at_most, strip_rows, combine, True)
 
 
 @pytest.mark.parametrize("feed_forward, remat, keeps, columns", [

@@ -742,7 +742,7 @@ class ExpertShare(nn.Module):
                 inside = checkpoint_name(
                     _dense(z.latent_width, "latent_down", self.dtype)(tokens),
                     names.LATENT_IN)
-        y, counts, windows = expert_share(
+        y, counts, windows, strips = expert_share(
             params, inside, n_experts=z.n_experts, held=e,
             first_expert=z.first_expert, k=z.top_k, expert_fn=expert_fn,
             router_input=rows, scoring=z.scoring, scale=z.routed_scale)
@@ -766,9 +766,11 @@ class ExpertShare(nn.Module):
         # assignments per held expert: collected by train steps built with
         # ``aux=True`` (make_lm_train_step), one row a layer
         self.sow("intermediates", "moe_expert_tokens", counts)
-        # and the windows each block of tokens took (1 where the share
-        # keeps one buffer), one row a layer
+        # and the windows each block of tokens took and the strips it
+        # scattered (1 and 1 where the share keeps one buffer), one row a
+        # layer each
         self.sow("intermediates", "moe_windows", windows)
+        self.sow("intermediates", "moe_strips", strips)
         return y.reshape(b, s, d)
 
 

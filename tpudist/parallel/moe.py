@@ -361,8 +361,11 @@ SHARE_BLOCK_TOKENS = 8192
 #: (:func:`share_windows`), in even shares of a block's assignments
 WINDOW_EVEN_SHARES = 8
 #: what a row scattered costs on the chip, in rows gathered (PR 30: 75 ns
-#: against 20): windows, which scatter what they computed, are taken where
-#: this many of them are no more than the one buffer of the bound
+#: against 20): windows are taken where this many of them are no more than
+#: the one buffer of the bound.  The threshold was measured on trips that
+#: scattered a whole window; a trip scatters the strips its arrivals touch
+#: (:func:`share_strip`), and what it still pays a window is its gathers,
+#: its selects and the experts' float32 gradient sums
 SCATTER_PER_GATHER = 4
 
 
@@ -373,14 +376,51 @@ def share_windows(block_tokens: int, k: int, held: int,
     experts: the rows of a window, :data:`WINDOW_EVEN_SHARES` times the
     block's even share rounded up to a multiple of 512, and how many of
     them the block's ``block_tokens * k`` assignments fill if every pick is
-    held here.  Where :data:`SCATTER_PER_GATHER` windows would pass the
-    bound (``held / n_experts`` over 1/32) the layer keeps ONE buffer of
-    the bound: ``(block_tokens * k, 1)``."""
+    held here.  A window is what a trip gathers and runs the grouped
+    products over; what it scatters goes by strips of one even share
+    (:func:`share_strip`), as many as its arrivals touch.  Where
+    :data:`SCATTER_PER_GATHER` windows would pass the bound (``held /
+    n_experts`` over 1/32) the layer keeps ONE buffer of the bound:
+    ``(block_tokens * k, 1)``."""
     bound = block_tokens * k
     rows = -(-WINDOW_EVEN_SHARES * bound * held // (512 * n_experts)) * 512
     if SCATTER_PER_GATHER * rows > bound:
         return bound, 1
     return rows, -(-bound // rows)
+
+
+def share_strip(window_rows: int) -> int:
+    """The rows of a strip of a window of ``window_rows``: one of its
+    :data:`WINDOW_EVEN_SHARES` even shares (rounded down where they do not
+    divide it: the window's last strip then starts early, as a bound's last
+    window does).  A trip scatter-adds its window a strip at a time, the
+    strips its arrivals touch and no other.  One even share and not two:
+    on the chip a scatter-add costs a pass over its whole operand besides
+    its rows, which favours fewer and longer strips, but a strip of two
+    even shares of 3,072-wide float32 rows takes the compiler's fast
+    memory from the tokens the window's gathers read, and they cost more
+    than the calls saved (PERF.md section 6, PR 45: both cells measured
+    faster by 0.6% at one)."""
+    return window_rows // WINDOW_EVEN_SHARES
+
+
+def _window_run(i, n_live, bound: int, window_rows: int):
+    """``(lo, start, first, end)`` of window ``i`` of ``bound`` sorted
+    assignments whose first ``n_live`` arrived: ``lo``, the first row that
+    is this window's own; ``start``, its first row (the last window of a
+    bound that is no multiple of a window starts early and overlaps the one
+    before it: the rows before ``lo`` are that trip's); and the strips
+    ``[first, end)`` of :func:`share_strip` rows that its live rows
+    ``[lo, n_live)`` touch, none where it has none.  Elementwise over
+    arrays of ``i`` and ``n_live``: the loop's trips and the layer's count
+    of strips read the same arithmetic."""
+    strip = share_strip(window_rows)
+    lo = i * window_rows
+    start = jnp.minimum(lo, bound - window_rows)
+    first = (lo - start) // strip
+    live_end = jnp.clip(n_live - start, 0, window_rows)
+    return lo, start, first, jnp.where(n_live > lo, -(-live_end // strip),
+                                       first)
 
 
 def _plan(local, held: int, positions: bool = True):
@@ -499,32 +539,58 @@ def _held_experts_bwd(held, expert_fn, residuals, dy):
 _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
+class _Window(NamedTuple):
+    """What a trip of a windowed block's loop takes (:func:`_windows`)."""
+
+    at: jax.Array       # [window_rows] int32: the rows' assignments
+    token: jax.Array    # [window_rows] int32: their tokens
+    grouped: Callable   # the experts, as grouped products over the part of
+    #                     each expert's run that lies in the window
+    live: jax.Array     # [window_rows, 1] bool: the rows this trip computes
+    first: jax.Array    # the strips ``[first, end)`` that hold them
+    end: jax.Array
+    strip: Callable     # ``(j, *arrays [window_rows, ...]) -> (live
+    #                     [strip_rows] bool, *the arrays' rows of strip j)``
+
+
 def _windows(local, held: int, expert_fn, window_rows: int):
     """A windowed block's loop over :func:`_plan`'s order, whose first
     ``n_live`` rows are the ones that arrived (the held experts' runs
     lead): ``more(carry)``, whether trip ``carry[0]`` has rows to take, and
-    ``window(i)``, the ``i``-th window of ``window_rows`` rows: its
-    assignments ``at``, their tokens, the experts as a grouped product
-    over the part of each expert's run that lies in the window, and the
-    rows ``live`` that this trip computes."""
+    ``window(i)``, the ``i``-th :class:`_Window` of ``window_rows`` rows.
+
+    A window's live rows are one run, ``[lo, n_live)`` clipped to the
+    window, and what a trip scatters it scatters by the strips of
+    :func:`share_strip` rows that the run touches (:func:`_window_run`):
+    ``strip(j, ...)`` slices strip ``j``'s rows out of the window's arrays
+    and says which of them are live, those of the run that no strip before
+    it took (a window's last strip starts early where the strips do not
+    divide the window)."""
     k = local.shape[1]
     _, order, _, counts = _plan(local, held, positions=False)
     ends = jnp.cumsum(counts)
     n_live = ends[-1]
-    # the last window of a bound that is no multiple of a window starts
-    # early and overlaps the one before it: the rows before ``lo`` are that
-    # trip's
-    last = order.shape[0] - window_rows
+    strip_rows = share_strip(window_rows)
 
     def window(i):
-        lo = i * window_rows
-        start = jnp.minimum(lo, last)
+        lo, start, first, end = _window_run(i, n_live, order.shape[0],
+                                            window_rows)
         at = lax.dynamic_slice(order, (start,), (window_rows,))
         cuts = jnp.clip(ends, start, start + window_rows)
         sizes = jnp.diff(cuts, prepend=start)
         row = start + jnp.arange(window_rows, dtype=jnp.int32)
         live = ((row >= lo) & (row < n_live))[:, None]
-        return at, at // k, _grouped(expert_fn, sizes), live
+
+        def strip(j, *arrays):
+            own = j * strip_rows
+            at_row = jnp.minimum(own, window_rows - strip_rows)
+            row = start + at_row + jnp.arange(strip_rows, dtype=jnp.int32)
+            live = (row >= jnp.maximum(lo, start + own)) & (row < n_live)
+            return live, *(lax.dynamic_slice_in_dim(a, at_row, strip_rows)
+                           for a in arrays)
+
+        return _Window(at, at // k, _grouped(expert_fn, sizes), live, first,
+                       end, strip)
 
     return lambda carry: carry[0] * window_rows < n_live, window
 
@@ -537,31 +603,39 @@ def _held_experts_windowed(experts, x, weights, local, held, expert_fn,
     ``[0, n_live)`` of the plan's order, and a ``lax.while_loop`` takes
     them ``window_rows`` at a time, as many trips as the arrivals fill
     (none where nothing arrived).  A trip gathers its rows' tokens into a
-    ``[window_rows, d]`` buffer, runs the grouped products over the part of
-    each expert's run that lies in the window, weighs the rows in float32
-    and scatter-adds them into ``y``; a token's picks add up in the order
-    of the sort.  Nothing is dropped: if every pick is held here the loop
-    runs over all ``t * k`` rows.  No tensor of ``t * k`` rows is built
-    but the sort's own.
+    ``[window_rows, d]`` buffer and runs the grouped products over the part
+    of each expert's run that lies in the window; an inner loop then takes
+    the result by strips of one even share (:func:`share_strip`), as many
+    as the window's arrivals touch: a strip weighs its rows in float32 and
+    scatter-adds them into ``y``, so the scatter goes with what arrived
+    where the buffer and the products' shapes go with the window.  A
+    token's picks add up in the order of the sort.  Nothing is dropped: if
+    every pick is held here the loops run over all ``t * k`` rows.  No
+    tensor of ``t * k`` rows is built but the sort's own.
 
     The masking rule is :func:`_held_experts`': a row of the window at or
     behind ``n_live`` is undefined in the products' result and in their
-    cotangents, and the ``where`` over ``live`` leaves it out, forward and
-    backward."""
+    cotangents, and the ``where`` over a strip's live rows leaves it out,
+    forward and backward."""
     more, window = _windows(local, held, expert_fn, window_rows)
     flat_weights = weights.reshape(-1)
 
     def trip(carry):
         i, y = carry
-        at, token, grouped, live = window(i)
+        w = window(i)
         with jax.named_scope(names.MOE_COMBINE):
-            rows = _rows(x, token)
+            rows = _rows(x, w.token)
         with jax.named_scope(names.EXPERTS):
-            out = grouped(experts, rows)
+            out = w.grouped(experts, rows)
+
+        def scatter(j, y):
+            live, rows, token, at = w.strip(j, out, w.token, w.at)
+            rows = rows.astype(jnp.float32) * _rows(flat_weights, at)[:, None]
+            return y.at[token].add(jnp.where(live[:, None], rows, 0.0),
+                                   mode="promise_in_bounds")
+
         with jax.named_scope(names.MOE_COMBINE):
-            out = out.astype(jnp.float32) * _rows(flat_weights, at)[:, None]
-            y = y.at[token].add(jnp.where(live, out, 0.0),
-                                mode="promise_in_bounds")
+            y = lax.fori_loop(w.first, w.end, scatter, y)
         return i + 1, y
 
     return lax.while_loop(
@@ -576,12 +650,13 @@ def _held_experts_windowed_fwd(experts, x, weights, local, held, expert_fn,
 
 
 def _held_experts_windowed_bwd(held, expert_fn, window_rows, residuals, dy):
-    """The same loop (the products are recomputed, nothing of a block is
+    """The same loops (the products are recomputed, nothing of a block is
     kept but its inputs): a trip gathers its rows' tokens and their
-    cotangents, pulls the rows' cotangent back through the products,
-    scatter-adds the rows' input gradients into ``d_x`` in float32 and
-    each row's weight gradient to its assignment.  The experts' gradients
-    add up over the trips in float32 and are rounded once."""
+    cotangents and pulls the window's cotangent back through the products;
+    the strips its arrivals touch scatter-add the rows' input gradients
+    into ``d_x`` in float32 and each row's weight gradient to its
+    assignment.  The experts' gradients add up over the trips in float32
+    and are rounded once."""
     experts, x, weights, local = residuals
     more, window = _windows(local, held, expert_fn, window_rows)
     flat_weights = weights.reshape(-1)
@@ -589,27 +664,38 @@ def _held_experts_windowed_bwd(held, expert_fn, window_rows, residuals, dy):
 
     def trip(carry):
         i, d_experts, d_x, d_weights = carry
-        at, token, grouped, live = window(i)
+        w = window(i)
         with jax.named_scope(names.MOE_COMBINE):
-            rows = _rows(x, token)
-            dy_rows = _rows(dy, token).astype(jnp.float32)
+            rows = _rows(x, w.token)
+            dy_rows = _rows(dy, w.token).astype(jnp.float32)
         with jax.named_scope(names.EXPERTS):
-            out, pull = jax.vjp(grouped, experts, rows)
+            out, pull = jax.vjp(w.grouped, experts, rows)
         d_weight_rows = jnp.sum(dy_rows * out.astype(jnp.float32), axis=-1)
-        d_out = jnp.where(live, dy_rows * _rows(flat_weights, at)[:, None],
-                          0.0)
+        # whole: the products take a window, and its rows before ``lo`` are
+        # in their groups
+        d_out = jnp.where(w.live,
+                          dy_rows * _rows(flat_weights, w.at)[:, None], 0.0)
         with jax.named_scope(names.EXPERTS):
             d_trip, d_rows = pull(d_out.astype(out.dtype))
         d_experts = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
                                  d_experts, d_trip)
-        with jax.named_scope(names.MOE_COMBINE):
+
+        def scatter(j, carry):
+            d_x, d_weights = carry
+            live, rows, token, at, d_weight = w.strip(
+                j, d_rows, w.token, w.at, d_weight_rows)
             d_x = d_x.at[token].add(
-                jnp.where(live, d_rows.astype(jnp.float32), 0.0),
+                jnp.where(live[:, None], rows.astype(jnp.float32), 0.0),
                 mode="promise_in_bounds")
             # the rows of a window are assignments of their own
             d_weights = d_weights.at[at].add(
-                jnp.where(live[:, 0], d_weight_rows, 0.0),
+                jnp.where(live, d_weight, 0.0),
                 mode="promise_in_bounds", unique_indices=True)
+            return d_x, d_weights
+
+        with jax.named_scope(names.MOE_COMBINE):
+            d_x, d_weights = lax.fori_loop(w.first, w.end, scatter,
+                                           (d_x, d_weights))
         return i + 1, d_experts, d_x, d_weights
 
     _, d_experts, d_x, d_weights = lax.while_loop(
@@ -635,7 +721,7 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
                  scoring: str = names.SOFTMAX, scale: float = 1.0):
     """This device's part of one routed-expert layer, dropless: ``(y
     [tokens, d] in x's dtype, assignments per held expert [held], windows
-    taken a block [blocks])``.
+    taken a block [blocks], strips scattered a block [blocks])``.
 
     ``params = {"router": [d_router, n_experts] f32, "experts": a pytree
     with a leading axis over the ``held`` experts held (``first_expert``
@@ -664,21 +750,25 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
     One that holds a 32nd or less takes the rows that arrived through
     windows of eight even shares (:func:`_held_experts_windowed`): one
     trip of a loop at an even load, as many as the arrivals fill
-    otherwise, dropless alike, and its cost goes with what arrived in
-    steps of a window; its result carries the name ``names.EXPERT_OUT``
-    for a rematerialised caller to keep.  The threshold is the measured
-    cost of a scattered row in gathered rows on the chip
-    (:data:`SCATTER_PER_GATHER`, 4 : 1): a window is scattered into the
-    result where the one buffer is gathered out of, so windows win only
-    under a quarter of the bound.  (A share whose router drifts towards
-    its own experts, as a lone member's trained router does, may fill
-    many windows: what such a member's router sees is its benchmark
-    cell's question, and its answer may move that layer under the
-    threshold later.)
+    otherwise, dropless alike.  A trip's gathers and products go with the
+    window; what it scatter-adds into the result goes with the arrivals,
+    by strips of one even share (:func:`share_strip`: one or two strips a
+    trip at an even load, eight where the window is full).  Its result
+    carries the name ``names.EXPERT_OUT`` for a rematerialised caller to
+    keep.  The threshold is the measured cost of a scattered row in
+    gathered rows on the chip (:data:`SCATTER_PER_GATHER`, 4 : 1), from
+    when a trip scattered its whole window where the one buffer is
+    gathered out of.  (A share whose router drifts towards its own
+    experts, as a lone member's trained router does, may fill many
+    windows: what such a member's router sees is its benchmark cell's
+    question, and its answer may move that layer under the threshold
+    later.)
 
-    Returns besides ``y`` and the assignments: ``[blocks]`` int32, the
-    windows each block's arrivals took (1 where the layer keeps one
-    buffer).
+    Returns besides ``y`` and the assignments, each ``[blocks]`` int32:
+    the windows each block's arrivals took and the strips it scattered (1
+    and 1 where the layer keeps one buffer, whose one strip is the bound).
+    ``strips * strip_rows`` over the arrivals is how many rows the layer
+    moved into its result for each that arrived.
 
     Runs under the scope ``names.MOE``, the grouped products under
     ``names.EXPERTS``, the combine (forward, and the tokens' input
@@ -691,13 +781,16 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
     blocks = max(1, -(-tokens // block_tokens))
     while tokens % blocks:
         blocks += 1
+    bound = tokens // blocks * k
     window_rows, windows_at_most = share_windows(tokens // blocks, k, held,
                                                  n_experts)
     windowed = windows_at_most > 1
     telemetry.event(names.MOE_LAYOUT, experts=n_experts, held=held,
                     first=first_expert, top_k=k, dropless=True,
-                    buffer_rows=tokens // blocks * k, blocks=blocks,
+                    buffer_rows=bound, blocks=blocks,
                     window_rows=window_rows, windows_at_most=windows_at_most,
+                    strip_rows=share_strip(window_rows) if windowed
+                    else window_rows,
                     combine=names.SCATTER_ADD if windowed
                     else names.PICK_MAJOR, scoring=scoring, scale=scale,
                     width=d)
@@ -736,8 +829,11 @@ def expert_share(params: dict, x: jax.Array, *, n_experts: int, held: int,
                 y = y + score * expert_fn(shared, x).astype(jnp.float32)
         y = y.astype(x.dtype)
         if not windowed:
-            return y, counts, jnp.ones((blocks,), jnp.int32)
+            one = jnp.ones((blocks,), jnp.int32)
+            return y, counts, one, one
         arrived = jnp.sum((routing.local < held).reshape(blocks, -1), axis=1,
                           dtype=jnp.int32)
+        _, _, first, end = _window_run(
+            jnp.arange(windows_at_most), arrived[:, None], bound, window_rows)
         return (checkpoint_name(y, names.EXPERT_OUT), counts,
-                -(-arrived // window_rows))
+                -(-arrived // window_rows), jnp.sum(end - first, axis=1))

@@ -252,9 +252,12 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # block's assignments), ``blocks=``, ``window_rows=`` the rows of a window
 # where the share takes its arrivals through windows (the bound itself
 # where it keeps one buffer) and ``windows_at_most=`` how many of them the
-# bound fills (1), ``combine=`` PICK_MAJOR (each token's ``k`` rows are
+# bound fills (1), ``strip_rows=`` the rows of one scatter-add, an even
+# share of a window's eight (the bound itself where it keeps one buffer),
+# ``combine=`` PICK_MAJOR (each token's ``k`` rows are
 # added up as ``k`` slabs of [tokens, d]) or SCATTER_ADD (a window's rows
-# are added into their tokens'), ``scoring=`` SOFTMAX / SIGMOID /
+# are added into their tokens', the strips that hold arrivals and no
+# other), ``scoring=`` SOFTMAX / SIGMOID /
 # SIGMOID_BIAS,
 # ``scale=`` what
 # the picks' renormalised weights are multiplied by, ``width=`` the rows'

@@ -182,9 +182,13 @@ def make_lm_train_step(
     (``moe_dropped_fraction`` scalar, ``moe_expert_load`` ``[n_experts]``,
     ``moe_balance_loss`` scalar; from a dropless share layer
     ``moe_expert_tokens`` ``[layers, held]``, the assignments each held
-    expert got this step, and ``moe_windows`` ``[layers, blocks]``, the
-    windows each block of tokens' arrivals took) — empty when the model
-    sows nothing.
+    expert got this step, ``moe_windows`` ``[layers, blocks]``, the
+    windows each block of tokens' arrivals took, and ``moe_strips``
+    ``[layers, blocks]``, the strips of a window (``strip_rows`` of the
+    layer's ``moe_layout`` event) each block scatter-added into its
+    result: a windowed share scatters ``moe_strips * strip_rows`` rows for
+    the ``moe_expert_tokens`` that arrived) — empty when the model sows
+    nothing.
     Requires ``apply_fn`` to accept flax's ``mutable=`` kwarg (i.e. a
     ``Module.apply``).
 
@@ -250,9 +254,10 @@ def make_lm_train_step(
             for name, vals in by_name.items()
         }
         # a dropless share layer (tpudist.models.hybrid) sows the
-        # assignments each held expert got and the windows each block of
-        # tokens took: kept a row a layer, not averaged
-        for name in ("moe_expert_tokens", "moe_windows"):
+        # assignments each held expert got, the windows each block of
+        # tokens took and the strips it scattered: kept a row a layer, not
+        # averaged
+        for name in ("moe_expert_tokens", "moe_windows", "moe_strips"):
             rows = [leaf for path, leaf in
                     jax.tree_util.tree_flatten_with_path(inters)[0]
                     if any(getattr(e, "key", None) == name for e in path)]
