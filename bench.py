@@ -109,81 +109,6 @@ def bench_toy() -> dict:
     }
 
 
-def bench_fused_mlp(batch: int = 4096) -> dict:
-    """A/B the explicit-VMEM Pallas toy-MLP kernel against XLA's own
-    fusion of the same forward (``tpudist/ops/fused_mlp.py``).
-
-    The interesting outcome is recorded either way (VERDICT r3 weak #3):
-    on a 371-parameter MLP the expectation is that XLA's fusion already
-    saturates — the kernel exists to show the explicit-VMEM formulation
-    and to measure what hand-fusing buys (or costs) at this scale.
-    Forward-only (the kernel defines no VJP); BOTH paths are asserted
-    against a float64 numpy forward before timing — the kernel at its
-    Precision.HIGHEST budget (1e-4), the XLA path at the TPU
-    default-precision bf16-pass budget (5e-2)."""
-    import jax.numpy as jnp
-
-    from tpudist.models import create_toy_model
-    from tpudist.ops.fused_mlp import (NEGATIVE_SLOPE, fused_mlp,
-                                       mlp_reference, pad_params)
-
-    _, params = create_toy_model(jax.random.PRNGKey(0))
-    p = params["params"]
-    weights = [(p[f"dense_{i}"]["kernel"], p[f"dense_{i}"]["bias"])
-               for i in range(len(p))]
-    padded, _, d_out = pad_params(weights)
-    x = jnp.asarray(
-        np.random.default_rng(0).standard_normal((batch, 2)), jnp.float32)
-
-    f_fused = jax.jit(lambda x: fused_mlp(x, padded, d_out))
-    f_xla = jax.jit(lambda x: mlp_reference(x, weights))
-
-    # Ground truth is float64 numpy, NOT the XLA path: on TPU the default
-    # matmul precision is a single bf16 pass (~1e-2 rel), while the kernel
-    # runs Precision.HIGHEST — comparing them directly flags the XLA side's
-    # own rounding as a "kernel mismatch" (observed on-chip r4: rel=0.013).
-    h = np.asarray(x, np.float64)
-    for i, (w, b) in enumerate(weights):
-        h = h @ np.asarray(w, np.float64) + np.asarray(b, np.float64)
-        if i + 1 < len(weights):
-            h = np.where(h >= 0, h, NEGATIVE_SLOPE * h)
-    scale = max(np.abs(h).max(), 1e-6)
-    rel = float(np.abs(np.asarray(f_fused(x)) - h).max() / scale)
-    rel_xla = float(np.abs(np.asarray(f_xla(x)) - h).max() / scale)
-    if not np.isfinite(rel) or rel > 1e-4:
-        raise AssertionError(f"fused_mlp numerics mismatch: rel={rel}")
-    if not np.isfinite(rel_xla) or rel_xla > 5e-2:  # bf16-pass budget
-        raise AssertionError(f"xla reference numerics mismatch: rel={rel_xla}")
-
-    rates = {}
-    for tag, fn in (("pallas_fused", f_fused), ("xla_fused", f_xla)):
-        _sync(fn(x))  # warmup/compile
-        best = 0.0
-        for _ in range(3):
-            n = 0
-            t0 = time.perf_counter()
-            while True:
-                for _ in range(20):
-                    out = fn(x)
-                _sync(out)
-                n += 20
-                dt = time.perf_counter() - t0
-                if dt >= 0.3:
-                    break
-            best = max(best, batch * n / dt)
-        rates[tag] = round(best, 1)
-    return {
-        "metric": "toy_mlp_fused_forward_samples_per_sec",
-        "unit": "samples/sec (forward only)",
-        "config": {"batch": batch},
-        "max_rel_err_vs_f64": round(rel, 8),
-        "xla_rel_err_vs_f64": round(rel_xla, 8),
-        **rates,
-        "pallas_over_xla": round(rates["pallas_fused"] / rates["xla_fused"],
-                                 3),
-    }
-
-
 def bench_lm(*, name: str, batch: int, seq_len: int, d_model: int,
              n_layers: int, n_heads: int, d_ff: int, vocab: int = 256,
              steps: int = 5, precision: str = "fp32",
@@ -622,14 +547,14 @@ def main() -> None:
 
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--sections", default="all",
-                    help="comma list of toy,fused,dense,mfu,mfu_scanned,"
+                    help="comma list of toy,dense,mfu,mfu_scanned,"
                          "decode,long,dh128 "
                          "(default: all).  Targeted reruns merge "
                          "into the existing BENCH_EXTENDED.json instead of "
                          "clobbering other sections' evidence.")
     cli = ap.parse_args()
     want = {s.strip() for s in cli.sections.split(",") if s.strip()}
-    known = {"all", "toy", "fused", "dense", "mfu", "mfu_scanned",
+    known = {"all", "toy", "dense", "mfu", "mfu_scanned",
              "decode", "long", "dh128"}
     if not want or want - known:
         # A typo'd section must not produce a success-looking empty run.
@@ -654,8 +579,8 @@ def main() -> None:
     # through them needs it (dense/MFU at seq 2048 included).  It runs
     # BEFORE any timing and a mismatch raises: a bad kernel never
     # records a number.
-    if on_tpu and any(sec(s) for s in ("fused", "dense", "mfu",
-                                       "mfu_scanned", "long", "dh128")):
+    if on_tpu and any(sec(s) for s in ("dense", "mfu", "mfu_scanned",
+                                       "long", "dh128")):
         results["numerics_gate"] = numerics_gate()
 
     toy = None
@@ -678,10 +603,6 @@ def main() -> None:
     def pair(key, fp32_key, bf16_key, **kw):
         same_window_pair(results, measured_now, key, fp32_key, bf16_key,
                          **kw)
-
-    if on_tpu and sec("fused"):
-        # Kernel-vs-XLA A/B on the toy forward.
-        run_section("toy_fused_mlp", bench_fused_mlp)
 
     # MXU-dense LM config: matmul-dominated, the MFU yardstick — timed at
     # both precisions (bf16 = the MXU's native throughput, the number that

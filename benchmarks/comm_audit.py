@@ -1083,7 +1083,7 @@ def main(argv=None) -> int:
             # A regime that cannot BUILD on this box (e.g. a jax API the
             # installed version lacks) is a failed row, not a crashed
             # artifact: later regimes still audit and the file still
-            # lands (the scaling_multiproc error-row convention).
+            # lands (an error row, the harnesses' convention).
             results[name] = {"error": repr(e), "ok": False}
             n_fail += 1
             print(f"[comm-audit] {name}: ERROR {e!r}", flush=True)

@@ -22,7 +22,7 @@ planner's predictions come with the measured size of their own error.
 
 Rung geometries (two per workload, so a ranking that only works at one
 scale is caught): training on 4- and 8-device virtual CPU meshes
-(subprocess-pinned, the round_snapshot trick); serving on two engine
+(subprocess-pinned); serving on two engine
 geometries (slots x max_len).  Virtual-CPU rungs validate the planner's
 MECHANICS — the match verdict and error band are real measurements of
 the cost model on this host, not hardware truth.
@@ -55,7 +55,7 @@ _STUB = """
 import os
 # BOTH pins are required: jax.config for this process's first backend
 # resolution, and the env var for every code path that re-resolves from
-# the environment (the round_snapshot virtual-mesh trick).
+# the environment.
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:

@@ -2,8 +2,8 @@
 """Analytic roofline for the MFU-row configs — the no-hardware half of
 "drive MFU ≥40% or prove the ceiling" (VERDICT r3 #2).
 
-For each lever-matrix rung of ``mfu_hunt.py`` this computes, from the
-model geometry alone:
+For each rung of the d1024 lever matrix (``RUNGS``) this computes, from
+the model geometry alone:
 
 - model FLOPs per step (``tpudist.utils.flops`` accounting);
 - HBM bytes per step: parameter traffic (bf16 weights read in fwd AND

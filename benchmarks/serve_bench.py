@@ -47,8 +47,7 @@ the paper-facing serving questions need:
   tpurun-launched workers, each a disaggregated server SPMD over its
   own ``--devices-per-proc``-emulated mesh with SERIALIZED KV handoff
   (the cross-process transfer), merged per-pool serving report
-  embedded.  ``round_snapshot.py`` freezes this rung into the round's
-  ``BENCH_SERVE`` artifact;
+  embedded;
 - **the speculative-decode sweep** (``--spec`` [+ ``--draft-layers``
   ``--draft-k`` ``--spec-distill``]) — the decode roofline said only
   fewer-passes-per-token remained: rungs sweep draft size × drafted-K
@@ -67,8 +66,7 @@ the paper-facing serving questions need:
 
 One warmup request absorbs XLA compilation before any timed rung, so
 rows measure the steady engine, not the first dispatch.  Artifact:
-``BENCH_SERVE_r{NN}.json`` (round-frozen like every other harness — and
-snapshotted into the round scoreboard by ``round_snapshot.py``), with
+``BENCH_SERVE_r{NN}.json`` (round-frozen like every other harness), with
 the run's merged telemetry serving section embedded for cross-checking.
 ``--smoke`` shrinks everything to a CPU-CI scale (seconds, asserted by
 ``tests/test_benchmarks.py``).

@@ -308,7 +308,6 @@ def kernel_cases(w: Widths) -> list:
     from tpudist.ops.fused_linear import (fused_rope_qkv,
                                           fused_rope_qkv_reference,
                                           lora_delta, lora_delta_reference)
-    from tpudist.ops.fused_mlp import fused_mlp, mlp_reference, pad_params
     from tpudist.ops.fused_sample import (fused_residual_prep,
                                           fused_residual_reference,
                                           fused_sample_prep,
@@ -442,20 +441,6 @@ def kernel_cases(w: Widths) -> list:
                     jnp.asarray(r.integers(0, B + 1, S), jnp.int32))
         return make
 
-    def highest(ref):
-        """The fused MLP's dots are Precision.HIGHEST by design (full-f32
-        MXU passes); its XLA twin must not take the TPU's bf16 default."""
-        def run(*args):
-            with jax.default_matmul_precision("highest"):
-                return ref(*args)
-        return run
-
-    def make_mlp(r):
-        sizes = [2, 64, 64, 1]
-        ws = [(normal(r, (a, b), f32, a ** -0.5), normal(r, (b,), f32, 0.1))
-              for a, b in zip(sizes[:-1], sizes[1:])]
-        return normal(r, (256, 2), f32), ws
-
     heads = dict(n_heads=nh, n_kv=n_kv, dh=dh)
     cases = []
     for quant in (False, True):
@@ -504,11 +489,6 @@ def kernel_cases(w: Widths) -> list:
                 *a, layer=layer, interpret=interpret),
             lambda *a: lora_delta_reference(*a, layer=layer),
             make_lora(T), BF16_TOL))
-    cases.append(KernelCase(
-        "fused_mlp",
-        lambda x, ws, interpret: fused_mlp(
-            x, pad_params(ws)[0], ws[-1][0].shape[1], interpret=interpret),
-        highest(mlp_reference), make_mlp, dict(atol=1e-5, rtol=1e-5)))
     return cases
 
 

@@ -103,7 +103,7 @@ class TestPhasesTiny:
         assert families >= {
             "numerics_gate", "paged_attention", "paged_prefill",
             "fused_sample", "fused_residual", "fused_rope_qkv",
-            "lora_delta", "fused_mlp"}
+            "lora_delta"}
         assert all(np.isfinite(v) for k, v in report.items()
                    if k != "numerics_gate")
 

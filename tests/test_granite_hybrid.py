@@ -18,7 +18,6 @@ entry (3e-7 read), 1e-4 of a gradient's norm (under 1e-5 read).  Against
 
 import dataclasses
 import json
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -27,12 +26,13 @@ import pytest
 
 from cellbench import reference
 from cellbench.archs import granitemoehybrid as arch
+from tests.decoder_reference import (DATA, highest, logits, reference_logits,
+                                     reference_pair, rel, seeded, tiny, worst)
 from tpudist import telemetry
 from tpudist.models import hybrid
 from tpudist.models.transformer import lm_loss
 from tpudist.telemetry import names
 
-DATA = Path(__file__).resolve().parent.parent / "cellbench" / "tests" / "data"
 TINY = json.loads((DATA / "tiny-granite-hybrid.json").read_text())
 REAL = json.loads((DATA.parents[1] / "configs"
                    / "granite-4.0-h-micro.json").read_text())
@@ -40,39 +40,16 @@ MEMBERS = 2
 SHARED = ("mamba_n_heads", "num_attention_heads", "num_key_value_heads")
 
 
-def tiny(dtype="float32", **keys) -> dict:
-    config = json.loads(json.dumps(TINY))
-    config.update(keys)
-    config["as_run"]["compute_dtype"] = dtype
-    return config
-
-
 def whole(**keys) -> dict:
     """The uncut tiny model: every published head (layers and vocabulary as
     the share's)."""
-    return tiny(**{k: TINY["published"][k] for k in SHARED}, **keys)
+    return tiny(TINY, **{k: TINY["published"][k] for k in SHARED}, **keys)
 
 
 @pytest.fixture(autouse=True)
 def highest_precision():
-    # the CPU multiplies float32 exactly; stated for the reader
-    with jax.default_matmul_precision("highest"):
+    with highest():
         yield
-
-
-def seeded(config: dict, seed: int) -> dict:
-    return jax.jit(lambda words: arch.init_weights(config, words))(
-        reference.split_seed(seed))
-
-
-def rel(got, want) -> float:
-    got, want = (np.asarray(x, np.float64) for x in (got, want))
-    return float(np.linalg.norm(got - want) / (np.linalg.norm(want) + 1e-30))
-
-
-def worst(got, want) -> float:
-    got, want = (np.asarray(x, np.float64) for x in (got, want))
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
 
 
 # ---------------------------------------------------------------------------
@@ -95,7 +72,7 @@ def test_the_decoder_is_the_published_modules_on_shared_weights():
             "name", "source", "model_type", "reduced", "published",
             "deployment", "as_run", "departures", "assumed")},
         attn_implementation="eager")).eval()
-    weights = seeded(config, 5)
+    weights = seeded(arch, config, 5)
     t = lambda a: torch.tensor(np.asarray(a))
     state = {"model.embed_tokens.weight": t(weights["embed"]),
              "lm_head.weight": t(weights["embed"]),
@@ -139,20 +116,7 @@ def test_the_decoder_is_the_published_modules_on_shared_weights():
 
 @pytest.fixture(scope="module")
 def f32_pair():
-    with jax.default_matmul_precision("highest"):
-        config = tiny()
-        weights = seeded(config, 7)
-        tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0,
-                                    config["vocab_size"])
-        module = arch.build_module(config, {"remat": "nothing"})
-        params = arch.program_tree(config, weights)
-        loss, grads = jax.jit(jax.value_and_grad(
-            lambda p: lm_loss(module.apply(p, tokens), tokens)))(params)
-        ref_loss, ref_grads = jax.jit(
-            lambda w: arch.loss_and_grads(config, w, tokens))(weights)
-        return dict(config=config, weights=weights, tokens=tokens,
-                    module=module, params=params, loss=loss, grads=grads,
-                    ref_loss=ref_loss, ref_grads=ref_grads)
+    return reference_pair(arch, tiny(TINY))
 
 
 def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
@@ -179,11 +143,7 @@ def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
 
 
 def test_logits_match_the_reference(f32_pair):
-    p = f32_pair
-    got = jax.jit(p["module"].apply)(p["params"], p["tokens"])
-    want = jax.jit(lambda w: arch.forward(p["config"], w, p["tokens"]))(
-        p["weights"])
-    assert worst(got, want) < 3e-5
+    assert worst(logits(f32_pair), reference_logits(f32_pair)) < 3e-5
 
 
 def test_loss_matches_the_reference(f32_pair):
@@ -291,8 +251,8 @@ def test_an_adam_step_over_the_stacked_tree_is_optaxs(f32_pair):
 
 
 def test_the_references_own_zeroed_state_differs_from_the_carried_one():
-    config = tiny()
-    weights = seeded(config, 3)
+    config = tiny(TINY)
+    weights = seeded(arch, config, 3)
     tokens = jnp.asarray(np.random.default_rng(0).integers(
         0, 256, (1, 128), dtype=np.int32))
     carried, zeroed = jax.jit(lambda w: tuple(
@@ -310,8 +270,9 @@ def test_an_attention_layer_runs_where_the_pattern_has_it(kinds):
     attention layer at the step of the Mamba layer that follows it, and
     behind the loop where none does: the program's unrolled layers agree
     wherever the pattern puts them."""
-    config = tiny(layer_types=list(kinds), num_hidden_layers=len(kinds))
-    weights = seeded(config, 2)
+    config = tiny(TINY, layer_types=list(kinds),
+                  num_hidden_layers=len(kinds))
+    weights = seeded(arch, config, 2)
     tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 64), 0, 256)
     module = arch.build_module(config, {"remat": None})
     got, want = jax.jit(lambda w: (
@@ -387,10 +348,10 @@ def reference_mixer(w, x, kind, m):
 def shares():
     """The uncut layer of each kind, its two shares and what the uncut
     reference gives."""
-    with jax.default_matmul_precision("highest"):
-        full, held = whole(), tiny()
+    with highest():
+        full, held = whole(), tiny(TINY)
         m_full, m_held = arch.dims(full), arch.dims(held)
-        weights = seeded(full, 11)
+        weights = seeded(arch, full, 11)
         x = jax.random.normal(jax.random.PRNGKey(3), (2, 128, 64),
                               jnp.float32)
         sizes = arch.build_module(held, {"remat": None}).sizes

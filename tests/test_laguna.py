@@ -22,15 +22,15 @@ import dataclasses
 import json
 import math
 import types
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cellbench import reference
 from cellbench.archs import laguna as arch
+from tests.decoder_reference import (DATA, highest, logits, reference_logits,
+                                     reference_pair, rel, seeded, tiny, worst)
 from tpudist import telemetry
 from tpudist.models import hybrid
 from tpudist.models.transformer import lm_loss
@@ -42,23 +42,15 @@ from tpudist.ops.flash_attention import (_diagonal_strips, _far_edge_strips,
 from tpudist.parallel import moe
 from tpudist.telemetry import names
 
-DATA = Path(__file__).resolve().parent.parent / "cellbench" / "tests" / "data"
 TINY = json.loads((DATA / "tiny-laguna.json").read_text())
 REAL = json.loads((DATA.parents[1] / "configs"
                    / "laguna-s-2.1.json").read_text())
 HEAD_MEMBERS, EXPERT_MEMBERS = 2, 32
 
 
-def tiny(dtype="float32", **keys) -> dict:
-    config = json.loads(json.dumps(TINY))
-    config.update(keys)
-    config["as_run"]["compute_dtype"] = dtype
-    return config
-
-
 def whole() -> dict:
     """The tiny configuration uncut: every head and every expert here."""
-    config = tiny()
+    config = tiny(TINY)
     for key in ("num_attention_heads", "num_attention_heads_per_layer",
                 "num_key_value_heads", "num_experts"):
         config[key] = config["published"][key]
@@ -67,19 +59,8 @@ def whole() -> dict:
 
 @pytest.fixture(autouse=True)
 def highest_precision():
-    # the CPU multiplies float32 exactly; stated for the reader
-    with jax.default_matmul_precision("highest"):
+    with highest():
         yield
-
-
-def worst(got, want) -> float:
-    got, want = (np.asarray(x, np.float64) for x in (got, want))
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
-
-
-def rel(got, want) -> float:
-    got, want = (np.asarray(x, np.float64) for x in (got, want))
-    return float(np.linalg.norm(got - want) / (np.linalg.norm(want) + 1e-30))
 
 
 def softmax_sizes(config: dict, kind: str) -> hybrid.SoftmaxSizes:
@@ -143,7 +124,8 @@ def test_rotary_by_kind_is_the_position_at_a_time_form(kind):
     assert (a.rotary_dim, scale != 1.0) == {
         names.FULL: (dh // 2, True), names.WINDOW: (dh, False)}[kind]
     x = np.asarray(jax.random.normal(jax.random.PRNGKey(0), (1, 40, 3, dh)))
-    got = np.asarray(hybrid.rotate_partial(jnp.asarray(x), inv_freq, scale))
+    got = np.asarray(jax.jit(lambda x: hybrid.rotate_partial(
+        x, inv_freq, scale))(jnp.asarray(x)))
     freq = np.asarray(inv_freq, np.float64)
     want = x.astype(np.float64).copy()
     for p in range(x.shape[1]):
@@ -155,11 +137,10 @@ def test_rotary_by_kind_is_the_position_at_a_time_form(kind):
     assert worst(got, want) < 2e-6
     assert np.array_equal(got[..., 2 * half:], x[..., 2 * half:])
     # and the reference's own rotation says the same
-    assert worst(arch.rotate(jnp.asarray(x[0]),
-                             arch.dims(TINY)["rope"][
-                                 {names.FULL: arch.FULL,
-                                  names.WINDOW: arch.SLIDING}[kind]]),
-                 want[0]) < 2e-6
+    rope_of_kind = arch.dims(TINY)["rope"][
+        {names.FULL: arch.FULL, names.WINDOW: arch.SLIDING}[kind]]
+    assert worst(jax.jit(lambda x: arch.rotate(x, rope_of_kind))(
+        jnp.asarray(x[0])), want[0]) < 2e-6
 
 
 # ---------------------------------------------------------------------------
@@ -198,29 +179,26 @@ def dense_masked_attention(x, p, a, dh, window, gate=True):
 
 @pytest.mark.parametrize("kind", [names.FULL, names.WINDOW])
 def test_the_head_gated_arm_is_a_dense_masked_softmax(kind):
-    sizes = arch.build_module(tiny(), {"remat": None}).sizes
+    sizes = arch.build_module(tiny(TINY), {"remat": None}).sizes
     a, dh = sizes.softmax(kind), sizes.head_dim
     assert a.window == {names.FULL: None, names.WINDOW: 32}[kind]
     module = hybrid.HeadGatedAttention(sizes, jnp.float32, kind)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 128, 64), jnp.float32)
     params = jax.tree.map(
         lambda w: 0.2 * jax.random.normal(jax.random.PRNGKey(w.size), w.shape),
-        module.init(jax.random.PRNGKey(2), x)["params"])
+        jax.jit(module.init)(jax.random.PRNGKey(2), x)["params"])
     assert params["g_proj"]["kernel"].shape == (64, a.n_heads)
     assert "q_norm" not in params
-    got = module.apply({"params": params}, x)
-    want = jnp.stack([dense_masked_attention(row, params, a, dh, a.window)
-                      for row in x])
-    assert worst(got, want) < 1e-5
+    got = jax.jit(module.apply)({"params": params}, x)
+    dense = lambda window, **gate: jax.jit(lambda x, params: jnp.stack([
+        dense_masked_attention(row, params, a, dh, window, **gate)
+        for row in x]))(x, params)
+    assert worst(got, dense(a.window)) < 1e-5
     # a dropped gate, and on a sliding layer a dropped window, are another
     # result
-    no_gate = jnp.stack([dense_masked_attention(
-        row, params, a, dh, a.window, gate=False) for row in x])
-    assert worst(got, no_gate) > 0.1
+    assert worst(got, dense(a.window, gate=False)) > 0.1
     if kind == names.WINDOW:
-        no_window = jnp.stack([dense_masked_attention(
-            row, params, a, dh, None) for row in x])
-        assert worst(got, no_window) > 0.1
+        assert worst(got, dense(None)) > 0.1
 
 
 def test_a_window_has_one_attention_instance_and_no_window_the_default():
@@ -345,19 +323,7 @@ def test_a_windowed_call_runs_tiles_of_the_windows_width():
 
 @pytest.fixture(scope="module")
 def f32_pair():
-    with jax.default_matmul_precision("highest"):
-        config = tiny()
-        weights = arch.init_weights(config, reference.split_seed(7))
-        tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0,
-                                    config["vocab_size"])
-        module = arch.build_module(config, {"remat": "nothing"})
-        params = arch.program_tree(config, weights)
-        loss, grads = jax.value_and_grad(
-            lambda p: lm_loss(module.apply(p, tokens), tokens))(params)
-        ref_loss, ref_grads = arch.loss_and_grads(config, weights, tokens)
-        return dict(config=config, weights=weights, tokens=tokens,
-                    module=module, params=params, loss=loss, grads=grads,
-                    ref_loss=ref_loss, ref_grads=ref_grads)
+    return reference_pair(arch, tiny(TINY))
 
 
 def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
@@ -392,10 +358,7 @@ def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
 
 
 def test_logits_match_the_reference(f32_pair):
-    p = f32_pair
-    got = p["module"].apply(p["params"], p["tokens"])
-    want = arch.forward(p["config"], p["weights"], p["tokens"])
-    assert worst(got, want) < 3e-5
+    assert worst(logits(f32_pair), reference_logits(f32_pair)) < 3e-5
 
 
 def test_loss_matches_the_reference(f32_pair):
@@ -450,9 +413,7 @@ def test_another_arm_is_not_this_architecture(f32_pair, wrong):
         for i in range(1, 5):
             params["params"][f"layer_{i}"]["experts"]["choice_bias"] = (
                 jnp.linspace(-0.3, 0.3, 64))
-    got = module.apply(params, p["tokens"])
-    want = arch.forward(p["config"], p["weights"], p["tokens"])
-    assert worst(got, want) > 1e-3
+    assert worst(logits(p, module, params), reference_logits(p)) > 1e-3
 
 
 def test_the_gate_is_in_the_result(f32_pair):
@@ -461,18 +422,14 @@ def test_the_gate_is_in_the_result(f32_pair):
     p = f32_pair
     weights = {k: jnp.zeros_like(v) if k.endswith("g_proj") else v
                for k, v in p["weights"].items()}
-    got = p["module"].apply(arch.program_tree(p["config"], weights),
-                            p["tokens"])
-    want = arch.forward(p["config"], weights, p["tokens"])
-    assert worst(got, want) < 3e-5
-    assert worst(got, arch.forward(p["config"], p["weights"],
-                                   p["tokens"])) > 1e-2
+    got = logits(p, params=arch.program_tree(p["config"], weights))
+    assert worst(got, reference_logits(p, weights)) < 3e-5
+    assert worst(got, reference_logits(p)) > 1e-2
     m = arch.dims(p["config"])
     x = jax.random.normal(jax.random.PRNGKey(5), (128, 64))
-    w = arch.of_layer(weights, 1)
-    halved = arch.attention(x, w, kind=arch.SLIDING, m=m, mode="f32")
-    ungated = arch.attention(x, w, kind=arch.SLIDING, m=m, mode="f32",
-                             gate=False)
+    halved, ungated = jax.jit(lambda x, w: tuple(
+        arch.attention(x, w, kind=arch.SLIDING, m=m, mode="f32", **gate)
+        for gate in ({}, dict(gate=False))))(x, arch.of_layer(weights, 1))
     assert worst(halved, 0.5 * ungated) < 1e-6
 
 
@@ -482,8 +439,8 @@ def test_the_reference_takes_a_lone_row_without_the_loop_over_rows(f32_pair):
     less); the mean of the rows' own losses and gradients is the block's."""
     p = f32_pair
     assert p["tokens"].shape[0] == 2
-    alone = [arch.loss_and_grads(p["config"], p["weights"], p["tokens"][i:i + 1])
-             for i in range(2)]
+    a_row = jax.jit(lambda w, row: arch.loss_and_grads(p["config"], w, row))
+    alone = [a_row(p["weights"], p["tokens"][i:i + 1]) for i in range(2)]
     assert (alone[0][0] + alone[1][0]) / 2 == pytest.approx(
         float(p["ref_loss"]), rel=1e-6)
     for name, want in p["ref_grads"].items():
@@ -510,7 +467,8 @@ def test_the_references_query_blocks_are_the_whole_rows_attention(
     def out_and_grads():
         f = lambda x, w: jnp.sum(jnp.sin(arch.attention(
             x, w, kind=kind, m=m, mode="f32")))
-        return jax.value_and_grad(f, argnums=(0, 1))(x, w)
+        # (a new program a call: ``QUERY_BLOCK`` is read while tracing)
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1)))(x, w)
 
     whole = out_and_grads()
     monkeypatch.setattr(arch, "QUERY_BLOCK", 64)
@@ -520,17 +478,13 @@ def test_the_references_query_blocks_are_the_whole_rows_attention(
 
 
 def test_a_router_held_fixed_still_hands_its_gradient_to_the_tokens():
-    config = tiny()
+    config = tiny(TINY)
     config["as_run"]["router_trained"] = False
-    weights = arch.init_weights(config, reference.split_seed(7))
-    tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0, 256)
-    module = arch.build_module(config, {"remat": None})
-    assert not module.sizes.router_trained
-    grads = jax.grad(lambda p: lm_loss(module.apply(p, tokens), tokens))(
-        arch.program_tree(config, weights))
-    _, want = arch.loss_and_grads(config, weights, tokens)
+    p = reference_pair(arch, config, options={"remat": None})
+    assert not p["module"].sizes.router_trained
+    want = p["ref_grads"]
     got = dict(zip(arch.leaf_names(config),
-                   arch.named_leaves(config, grads)))
+                   arch.named_leaves(config, p["grads"])))
     for name, g in got.items():
         if name.endswith(".router"):
             assert not np.any(np.asarray(g)) and not np.any(
@@ -552,8 +506,8 @@ def test_the_gradient_under_remat_is_the_gradient_without(f32_pair):
     assert hybrid.remat_keeps(z) == (
         names.MIXER_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS)
     plain = dataclasses.replace(p["module"], remat=False)
-    grads = jax.grad(lambda q: lm_loss(plain.apply(q, p["tokens"]),
-                                       p["tokens"]))(p["params"])
+    grads = jax.jit(jax.grad(lambda q: lm_loss(
+        plain.apply(q, p["tokens"]), p["tokens"])))(p["params"])
     for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(p["grads"])):
         assert bool(jnp.all(a == b))
 
@@ -605,23 +559,23 @@ def test_the_two_head_shares_add_up_to_the_uncut_layer(kind, layer):
     gate is a head's own: no statistic crosses the cut).  Told
     ``heads_axis``, under a ``vmap`` with that axis name, ``o_proj``'s
     partial sums are added up and each member's output IS the uncut one."""
-    full, held = whole(), tiny()
+    full, held = whole(), tiny(TINY)
     m_full, m_held = arch.dims(full), arch.dims(held)
     assert m_full["heads"][layer] == 2 * m_held["heads"][layer]
-    w = arch.of_layer(arch.init_weights(full, reference.split_seed(11)),
-                      layer)
+    w = arch.of_layer(seeded(arch, full, 11), layer)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 128, 64), jnp.float32)
-    want = jnp.stack([arch.attention(row, w, kind=kind, m=m_full, mode="f32")
-                      for row in x])
+    reference_of = lambda m: jax.jit(lambda x, w: jnp.stack([arch.attention(
+        row, w, kind=kind, m=m, mode="f32") for row in x]))
+    want = reference_of(m_full)(x, w)
     shares = [head_share(m_full, w, layer, i) for i in range(HEAD_MEMBERS)]
     sizes = arch.build_module(held, {"remat": None}).sizes
     program_kind = {arch.FULL: names.FULL, arch.SLIDING: names.WINDOW}[kind]
-    alone = hybrid.HeadGatedAttention(sizes, jnp.float32, program_kind)
+    alone, given_a_share = jax.jit(hybrid.HeadGatedAttention(
+        sizes, jnp.float32, program_kind).apply), reference_of(m_held)
     parts = []
     for share in shares:
-        got = alone.apply({"params": attention_params(share)}, x)
-        given = jnp.stack([arch.attention(row, share, kind=kind, m=m_held,
-                                          mode="f32") for row in x])
+        got = alone({"params": attention_params(share)}, x)
+        given = given_a_share(x, share)
         assert worst(got, given) < 1e-5
         parts.append(got)
     assert worst(sum(parts), want) < 1e-5
@@ -631,8 +585,8 @@ def test_the_two_head_shares_add_up_to_the_uncut_layer(kind, layer):
         program_kind)
     stacked = jax.tree.map(lambda *a: jnp.stack(a),
                            *map(attention_params, shares))
-    every = jax.vmap(lambda p: shared.apply({"params": p}, x),
-                     axis_name="heads")(stacked)
+    every = jax.jit(jax.vmap(lambda p: shared.apply({"params": p}, x),
+                             axis_name="heads"))(stacked)
     for member in range(HEAD_MEMBERS):
         assert worst(every[member], want) < 1e-5
 
@@ -653,14 +607,16 @@ def test_the_32_expert_shares_add_up_to_the_uncut_layer():
     shared expert.  The 32 results, with the shared expert (what every
     member computes alike) counted once, add up to what the uncut reference
     gives for the whole layer."""
-    full, held = whole(), tiny()
+    full, held = whole(), tiny(TINY)
     m_full = arch.dims(full)
-    w = arch.of_layer(arch.init_weights(full, reference.split_seed(11)), 1)
+    w = arch.of_layer(seeded(arch, full, 11), 1)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 128, 64), jnp.float32)
-    want = jnp.stack([arch.experts(row, w, m=m_full, mode="f32")
-                      for row in x])
-    shared_alone = jnp.stack([arch.experts(row, w, m=m_full, mode="f32",
-                                           held=0) for row in x])
+    # (which experts are held is a Python number to reference and program
+    # alike: a program a member)
+    reference_of = lambda **held: jax.jit(lambda x, w: jnp.stack([
+        arch.experts(row, w, m=m_full, mode="f32", **held) for row in x]))(
+            x, w)
+    want, shared_alone = reference_of(), reference_of(held=0)
     sizes = arch.build_module(held, {"remat": None}).sizes
     per = sizes.held
     assert per * EXPERT_MEMBERS == sizes.n_experts == 64
@@ -668,13 +624,11 @@ def test_the_32_expert_shares_add_up_to_the_uncut_layer():
     for member in range(EXPERT_MEMBERS):
         module = hybrid.ExpertShare(dataclasses.replace(
             sizes, first_expert=member * per), jnp.float32)
-        got, state = module.apply(
-            {"params": expert_params(m_full, w, member * per, per)}, x,
-            mutable=["intermediates"])
+        got, state = jax.jit(lambda p: module.apply(
+            p, x, mutable=["intermediates"]))(
+                {"params": expert_params(m_full, w, member * per, per)})
         if member in (0, 17):
-            given = jnp.stack([arch.experts(
-                row, w, m=m_full, mode="f32", first=member * per, held=per)
-                for row in x])
+            given = reference_of(first=member * per, held=per)
             assert worst(got, given) < 1e-5
         total = total + got
         loads.append(np.asarray(
@@ -700,11 +654,17 @@ def test_the_tiny_share_goes_by_windows_as_the_real_one_does():
 
 def test_routes_sigmoid_arm_is_plain_sigmoid_top_k_renormalised():
     logits = 1.5 * jax.random.normal(jax.random.PRNGKey(0), (96, 64))
-    got = moe.route(logits, n_experts=64, k=8, held=2, first_expert=6,
-                    scoring=names.SIGMOID, scale=2.5)
-    scores = jax.nn.sigmoid(logits)
-    order = jnp.argsort(-scores, axis=-1)[:, :8]
-    picked = jnp.take_along_axis(scores, order, axis=-1)
+    got = jax.jit(lambda x: moe.route(
+        x, n_experts=64, k=8, held=2, first_expert=6, scoring=names.SIGMOID,
+        scale=2.5))(logits)
+
+    @jax.jit
+    def plainly(logits):
+        scores = jax.nn.sigmoid(logits)
+        order = jnp.argsort(-scores, axis=-1)[:, :8]
+        return scores, order, jnp.take_along_axis(scores, order, axis=-1)
+
+    scores, order, picked = plainly(logits)
     assert np.array_equal(got.expert_idx, order)
     assert worst(got.weights,
                  2.5 * picked / picked.sum(-1, keepdims=True)) < 1e-6
@@ -714,8 +674,9 @@ def test_routes_sigmoid_arm_is_plain_sigmoid_top_k_renormalised():
     assert np.array_equal(got.local, np.where((local >= 0) & (local < 2),
                                               local, 2))
     # the same picks and weights as the biased arm with a bias of zero
-    biased = moe.route(logits, n_experts=64, k=8, scoring=names.SIGMOID_BIAS,
-                       choice_bias=jnp.zeros(64), scale=2.5)
+    biased = jax.jit(lambda x: moe.route(
+        x, n_experts=64, k=8, scoring=names.SIGMOID_BIAS,
+        choice_bias=jnp.zeros(64), scale=2.5))(logits)
     assert np.array_equal(biased.expert_idx, got.expert_idx)
     assert np.array_equal(biased.weights, got.weights)
 
@@ -736,18 +697,25 @@ def test_the_other_scorings_are_bit_for_bit_what_they_were(scoring):
     logits = 2.0 * jax.random.normal(jax.random.PRNGKey(1), (64, 32))
     bias = 0.05 * jax.random.normal(jax.random.PRNGKey(2), (32,))
     if scoring == names.SOFTMAX:
-        got = moe.route(logits, n_experts=32, k=4)
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        weights, picks = jax.lax.top_k(probs, 4)
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        now = lambda x: moe.route(x, n_experts=32, k=4)
+
+        def before(logits):
+            probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+            weights, picks = jax.lax.top_k(probs, 4)
+            return probs, picks, weights / jnp.sum(weights, axis=-1,
+                                                   keepdims=True)
     else:
-        got = moe.route(logits, n_experts=32, k=4, scoring=scoring,
-                        choice_bias=bias, scale=5.0)
-        probs = jax.nn.sigmoid(logits.astype(jnp.float32))
-        _, picks = jax.lax.top_k(probs + bias, 4)
-        weights = jnp.take_along_axis(probs, picks, axis=-1)
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                             + 1e-20) * 5.0
+        now = lambda x: moe.route(x, n_experts=32, k=4, scoring=scoring,
+                                  choice_bias=bias, scale=5.0)
+
+        def before(logits):
+            probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+            _, picks = jax.lax.top_k(probs + bias, 4)
+            weights = jnp.take_along_axis(probs, picks, axis=-1)
+            return probs, picks, weights / (jnp.sum(
+                weights, axis=-1, keepdims=True) + 1e-20) * 5.0
+    got = jax.jit(now)(logits)
+    probs, picks, weights = jax.jit(before)(logits)
     assert np.array_equal(got.expert_idx, picks)
     assert np.array_equal(got.weights, weights)
     assert np.array_equal(got.probs, probs)

@@ -20,7 +20,6 @@ sums in another order).
 import dataclasses
 import json
 import types
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -29,41 +28,25 @@ import pytest
 
 from cellbench import reference
 from cellbench.archs import nemotron_h as arch
+from tests.decoder_reference import (DATA, highest, logits, reference_logits,
+                                     reference_pair, rel, run_steps, seeded,
+                                     tiny, worst)
 from tpudist import telemetry
 from tpudist.models import hybrid
 from tpudist.models.transformer import lm_loss
 from tpudist.parallel import moe
 from tpudist.telemetry import names
 
-DATA = Path(__file__).resolve().parent.parent / "cellbench" / "tests" / "data"
 TINY = json.loads((DATA / "tiny-nemotron-h.json").read_text())
 REAL = json.loads((DATA.parents[1] / "configs"
                    / "nemotron-3-super-120b-a12b.json").read_text())
 MEMBERS = 8
 
 
-def tiny(dtype="float32", **keys) -> dict:
-    config = json.loads(json.dumps(TINY))
-    config.update(keys)
-    config["as_run"]["compute_dtype"] = dtype
-    return config
-
-
 @pytest.fixture(autouse=True)
 def highest_precision():
-    # the CPU multiplies float32 exactly; stated for the reader
-    with jax.default_matmul_precision("highest"):
+    with highest():
         yield
-
-
-def rel(got, want) -> float:
-    got, want = (np.asarray(x, np.float64) for x in (got, want))
-    return float(np.linalg.norm(got - want) / (np.linalg.norm(want) + 1e-30))
-
-
-def worst(got, want) -> float:
-    got, want = (np.asarray(x, np.float64) for x in (got, want))
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
 
 
 # ---------------------------------------------------------------------------
@@ -89,7 +72,7 @@ def test_the_mamba2_mixer_is_bambas_torch_forward():
         ssm_chunk=chunk, eps=1e-5)
     ours = hybrid.Mamba2Mixer(sizes, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 4 * chunk, d))
-    params = ours.init(jax.random.PRNGKey(1), x)["params"]
+    params = jax.jit(ours.init)(jax.random.PRNGKey(1), x)["params"]
     ks = iter(jax.random.split(jax.random.PRNGKey(2), 8))
     draw = lambda a, scale: scale * jax.random.normal(next(ks), a.shape)
     params = {
@@ -111,7 +94,7 @@ def test_the_mamba2_mixer_is_bambas_torch_forward():
             getattr(their, name).copy_(t(params[name]))
         their.norm.weight.copy_(t(params["norm"]))
         want = their.torch_forward(t(x)).numpy()
-    assert worst(ours.apply({"params": params}, x), want) < 2e-5
+    assert worst(jax.jit(ours.apply)({"params": params}, x), want) < 2e-5
 
 
 def test_routes_sigmoid_arm_is_deepseek_v3s_router():
@@ -132,9 +115,10 @@ def test_routes_sigmoid_arm_is_deepseek_v3s_router():
         their.weight.copy_(torch.tensor(np.asarray(w.T)))
         their.e_score_correction_bias.copy_(torch.tensor(np.asarray(bias)))
         picks, weights = their(torch.tensor(np.asarray(x)))
-    routing = moe.route(x @ w, n_experts=experts, k=k,
-                        scoring=names.SIGMOID_BIAS, choice_bias=bias,
-                        scale=scale)
+    route = jax.jit(lambda logits, bias: moe.route(
+        logits, n_experts=experts, k=k, scoring=names.SIGMOID_BIAS,
+        choice_bias=bias, scale=scale))
+    routing = route(x @ w, bias)
     # the picks in any order: each token's weights by expert
     dense = lambda p, v: np.asarray(jnp.zeros((96, experts)).at[
         jnp.arange(96)[:, None], jnp.asarray(p)].set(jnp.asarray(v)))
@@ -144,8 +128,7 @@ def test_routes_sigmoid_arm_is_deepseek_v3s_router():
     assert worst(got, want) < 2e-6
     assert np.allclose(got.sum(axis=1), scale, rtol=1e-5)
     # the bias steers the choice and is no part of a weight
-    plain = moe.route(x @ w, n_experts=experts, k=k,
-                      scoring=names.SIGMOID_BIAS, scale=scale)
+    plain = route(x @ w, None)
     assert (np.asarray(plain.expert_idx) != np.asarray(
         routing.expert_idx)).any()
     scores = np.asarray(jax.nn.sigmoid(x @ w))
@@ -172,9 +155,9 @@ def softmax_route_as_before(logits, *, n_experts, k, held=None,
 @pytest.mark.parametrize("k", [1, 2, 10])
 def test_routes_softmax_arm_is_bit_for_bit_what_it_was(k):
     logits = 3.0 * jax.random.normal(jax.random.PRNGKey(k), (64, 32))
-    got = moe.route(logits, n_experts=32, k=k, held=4, first_expert=8)
-    want = softmax_route_as_before(logits, n_experts=32, k=k, held=4,
-                                   first_expert=8)
+    got, want = (jax.jit(lambda x, fn=fn: fn(
+        x, n_experts=32, k=k, held=4, first_expert=8))(logits)
+        for fn in (moe.route, softmax_route_as_before))
     for a, b in zip(got, want):
         assert a.dtype == b.dtype and bool(jnp.all(a == b))
 
@@ -192,7 +175,7 @@ def test_the_share_cells_layer_is_bit_for_bit_what_it_was(monkeypatch):
                 names.SOFTMAX, names.GATED_SILU, True, None, False, 1.0)
     layer = hybrid.ExpertShare(sizes, jnp.bfloat16)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, sizes.d_model))
-    params = layer.init(jax.random.PRNGKey(1), x)
+    params = jax.jit(layer.init)(jax.random.PRNGKey(1), x)
     run = jax.jit(jax.value_and_grad(
         lambda p: jnp.sum(jnp.sin(layer.apply(p, x).astype(jnp.float32)))))
     now = run(params)
@@ -221,19 +204,7 @@ def test_route_names_its_scorings_from_their_table(kw, said):
 
 @pytest.fixture(scope="module")
 def f32_pair():
-    with jax.default_matmul_precision("highest"):
-        config = tiny()
-        weights = arch.init_weights(config, reference.split_seed(7))
-        tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0,
-                                    config["vocab_size"])
-        module = arch.build_module(config, {"remat": "nothing"})
-        params = arch.program_tree(config, weights)
-        loss, grads = jax.value_and_grad(
-            lambda p: lm_loss(module.apply(p, tokens), tokens))(params)
-        ref_loss, ref_grads = arch.loss_and_grads(config, weights, tokens)
-        return dict(config=config, weights=weights, tokens=tokens,
-                    module=module, params=params, loss=loss, grads=grads,
-                    ref_loss=ref_loss, ref_grads=ref_grads)
+    return reference_pair(arch, tiny(TINY))
 
 
 def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
@@ -261,10 +232,7 @@ def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
 
 
 def test_logits_match_the_reference(f32_pair):
-    p = f32_pair
-    got = p["module"].apply(p["params"], p["tokens"])
-    want = arch.forward(p["config"], p["weights"], p["tokens"])
-    assert worst(got, want) < 3e-5
+    assert worst(logits(f32_pair), reference_logits(f32_pair)) < 3e-5
 
 
 def test_loss_matches_the_reference(f32_pair):
@@ -307,9 +275,8 @@ def test_another_arm_is_not_this_architecture(f32_pair, wrong):
     flat = dict(jax.tree_util.tree_flatten_with_path(p["params"])[0])
     params = jax.tree_util.tree_map_with_path(
         lambda path, a: flat.get(path, a),
-        other.init(jax.random.PRNGKey(0), p["tokens"]))
-    want = arch.forward(p["config"], p["weights"], p["tokens"])
-    assert worst(other.apply(params, p["tokens"]), want) > 1e-2
+        jax.jit(other.init)(jax.random.PRNGKey(0), p["tokens"]))
+    assert worst(logits(p, other, params), reference_logits(p)) > 1e-2
 
 
 @pytest.mark.parametrize("wrong", [
@@ -326,12 +293,16 @@ def test_another_arm_is_not_this_expert_layer(f32_pair, wrong):
     m = arch.dims(p["config"])
     w = arch.of_layer(p["weights"], 1)
     x = jax.random.normal(jax.random.PRNGKey(5), (1, 128, 64), jnp.float32)
-    routed = lambda y: y - arch.experts(x[0], w, m=m, mode="f32", held=0)
-    want = routed(arch.experts(x[0], w, m=m, mode="f32"))
+    shared_alone, whole_layer = jax.jit(lambda x, w: tuple(
+        arch.experts(x, w, m=m, mode="f32", **held)
+        for held in (dict(held=0), {})))(x[0], w)
+    routed = lambda y: y - shared_alone
+    want = routed(whole_layer)
     sizes = p["module"].sizes
     params = dict(p["params"]["params"]["layer_1"]["experts"])
-    assert worst(routed(hybrid.ExpertShare(sizes, jnp.float32).apply(
-        {"params": params}, x)[0]), want) < 1e-5
+    share = lambda sizes, params: jax.jit(hybrid.ExpertShare(
+        sizes, jnp.float32).apply)({"params": params}, x)
+    assert worst(routed(share(sizes, params)[0]), want) < 1e-5
     if wrong == "choice_bias_dropped":
         params["choice_bias"] = jnp.zeros_like(params["choice_bias"])
     else:
@@ -339,8 +310,7 @@ def test_another_arm_is_not_this_expert_layer(f32_pair, wrong):
         if "expert_fn" in wrong:
             params["gate"], params["shared_gate"] = (params["up"],
                                                      params["shared_up"])
-    got = hybrid.ExpertShare(sizes, jnp.float32).apply({"params": params}, x)
-    assert worst(routed(got[0]), want) > 5e-2
+    assert worst(routed(share(sizes, params)[0]), want) > 5e-2
 
 
 def test_a_scored_shared_expert_reads_the_rows_the_experts_read(f32_pair):
@@ -354,17 +324,13 @@ def test_a_router_held_fixed_still_hands_its_gradient_to_the_tokens():
     """``router_trained: false`` (the cell's stated fallback): the router's
     own gradient is zero in program and reference alike, every other
     tensor's gradient is still the reference's."""
-    config = tiny()
+    config = tiny(TINY)
     config["as_run"]["router_trained"] = False
-    weights = arch.init_weights(config, reference.split_seed(7))
-    tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0, 256)
-    module = arch.build_module(config, {"remat": None})
-    assert module.sizes.router_trained is False
-    grads = jax.grad(lambda p: lm_loss(module.apply(p, tokens), tokens))(
-        arch.program_tree(config, weights))
-    _, ref = arch.loss_and_grads(config, weights, tokens)
+    p = reference_pair(arch, config, options={"remat": None})
+    assert p["module"].sizes.router_trained is False
+    ref = p["ref_grads"]
     for name, got in zip(arch.leaf_names(config),
-                         arch.named_leaves(config, grads)):
+                         arch.named_leaves(config, p["grads"])):
         if name.endswith(".router"):
             assert float(jnp.abs(got).max()) == 0.0 == float(
                 jnp.abs(ref[name]).max())
@@ -377,26 +343,14 @@ def test_three_adam_steps_follow_the_reference():
     reference's own Adam: losses to 1e-5, every tensor's change after three
     steps to 2e-3 of its norm; the step built with ``aux=True`` says the
     assignments of each expert layer's held experts."""
-    import optax
-
-    from tpudist.runtime.mesh import MeshConfig, make_mesh
-    from tpudist.train import init_lm_state, make_lm_train_step
-
-    config = tiny(num_hidden_layers=4, hybrid_override_pattern="ME*E")
-    weights = arch.init_weights(config, reference.split_seed(7))
+    config = tiny(TINY, num_hidden_layers=4, hybrid_override_pattern="ME*E")
+    weights = seeded(arch, config, 7)
     module = arch.build_module(config, {"remat": "nothing"})
     rng = np.random.default_rng(0)
     batches = [rng.integers(0, 256, (2, 128), dtype=np.int32)
                for _ in range(3)]
-    tx = optax.adam(2e-3)
-    mesh = make_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
-    step = make_lm_train_step(module.apply, tx, mesh, donate_state=False,
-                              aux=True)
-    state = init_lm_state(arch.program_tree(config, weights), tx)
-    losses = []
-    for batch in batches:
-        state, loss, aux = step(state, jnp.asarray(batch))
-        losses.append(float(loss))
+    state, losses, aux = run_steps(arch, config, module, weights, batches,
+                                   2e-3, aux=True)
     counts = np.asarray(aux["moe_expert_tokens"])
     assert counts.shape == (2, 4)        # [expert layers, held]
     assert 0 < counts.sum() < 2 * 2 * 128 * 6
@@ -431,16 +385,11 @@ def test_a_64th_of_the_experts_held_goes_by_windows(rigged, windows):
     ``aux=True`` says the windows taken and the strips of 64 rows
     scattered, a row a layer: the strips the arrivals fill, all 64 where
     every pick is held."""
-    import optax
-
-    from tpudist.runtime.mesh import MeshConfig, make_mesh
-    from tpudist.train import init_lm_state, make_lm_train_step
-
-    config = tiny(num_hidden_layers=2, hybrid_override_pattern="ME",
+    config = tiny(TINY, num_hidden_layers=2, hybrid_override_pattern="ME",
                   n_routed_experts=2, num_experts_per_tok=2)
     config["as_run"]["router_experts"] = 128
     assert moe.share_windows(8 * 256, 2, 2, 128) == (512, 8)
-    weights = arch.init_weights(config, reference.split_seed(11))
+    weights = seeded(arch, config, 11)
     if rigged:
         weights["layer_1.choice_bias"] = weights[
             "layer_1.choice_bias"].at[:2].set(100.0)
@@ -448,20 +397,18 @@ def test_a_64th_of_the_experts_held_goes_by_windows(rigged, windows):
                                 config["vocab_size"])
     module = arch.build_module(config, {"remat": "nothing"})
     params = arch.program_tree(config, weights)
-    loss, grads = jax.value_and_grad(
-        lambda p: lm_loss(module.apply(p, tokens), tokens))(params)
-    ref_loss, ref_grads = arch.loss_and_grads(config, weights, tokens)
+    # (``reference_pair``'s two programs, on weights rigged behind the seed)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: lm_loss(module.apply(p, tokens), tokens)))(params)
+    ref_loss, ref_grads = jax.jit(
+        lambda w: arch.loss_and_grads(config, w, tokens))(weights)
     assert abs(float(loss) - float(ref_loss)) < 2e-6
     for name, got in zip(arch.leaf_names(config),
                          arch.named_leaves(config, grads)):
         assert float(jnp.linalg.norm(ref_grads[name])) > 0, name
         assert rel(got, ref_grads[name]) < 1e-4, name
-    tx = optax.sgd(0.0)
-    step = make_lm_train_step(
-        module.apply, tx, make_mesh(MeshConfig(data=1),
-                                    devices=jax.devices()[:1]),
-        donate_state=False, aux=True)
-    _, _, aux = step(init_lm_state(params, tx), tokens)
+    _, _, aux = run_steps(arch, config, module, weights, [tokens], 0.0,
+                          aux=True)
     assert np.asarray(aux["moe_windows"]).tolist() == [[windows]]
     arrived = int(np.asarray(aux["moe_expert_tokens"]).sum())
     assert arrived == 4096 if rigged else 0 < arrived <= 512
@@ -475,7 +422,7 @@ def test_a_64th_of_the_experts_held_goes_by_windows(rigged, windows):
 
 def whole() -> dict:
     """The uncut tiny model: every published head, group and expert."""
-    return tiny(**{k: v for k, v in TINY["published"].items()
+    return tiny(TINY, **{k: v for k, v in TINY["published"].items()
                    if k in ("mamba_num_heads", "n_groups",
                             "num_attention_heads", "num_key_value_heads",
                             "n_routed_experts")})
@@ -539,23 +486,23 @@ def test_the_eight_head_shares_add_up_to_the_uncut_layer(kind, layer):
     group's).  Told ``heads_axis``, under a ``vmap`` with that axis name,
     the output projections' partial sums are added up and each member's
     output IS the uncut one."""
-    full, held = whole(), tiny()
+    full, held = whole(), tiny(TINY)
     m_full, m_held = arch.dims(full), arch.dims(held)
-    w = arch.of_layer(arch.init_weights(full, reference.split_seed(11)),
-                      layer)
+    w = arch.of_layer(seeded(arch, full, 11), layer)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 128, 64), jnp.float32)
-    want = jnp.stack([arch.sublayer(row, w, kind=kind, m=m_full, mode="f32")
-                      for row in x])
+    reference_of = lambda m: jax.jit(lambda x, w: jnp.stack([arch.sublayer(
+        row, w, kind=kind, m=m, mode="f32") for row in x]))
+    want = reference_of(m_full)(x, w)
     shares = [share_of(m_full, w, kind, i) for i in range(MEMBERS)]
     sizes = arch.build_module(held, {"remat": None}).sizes
     cls = hybrid.Mamba2Mixer if kind == arch.MAMBA else (
         hybrid.GroupedAttention)
-    alone = cls(sizes, jnp.float32)
+    alone, given_a_share = jax.jit(cls(sizes, jnp.float32).apply), (
+        reference_of(m_held))
     parts = []
     for share in shares:
-        got = alone.apply({"params": sublayer_params(held, layer, share)}, x)
-        given = jnp.stack([arch.sublayer(row, share, kind=kind, m=m_held,
-                                         mode="f32") for row in x])
+        got = alone({"params": sublayer_params(held, layer, share)}, x)
+        given = given_a_share(x, share)
         assert worst(got, given) < 1e-5
         parts.append(got)
     assert worst(sum(parts), want) < 1e-5
@@ -563,8 +510,8 @@ def test_the_eight_head_shares_add_up_to_the_uncut_layer(kind, layer):
     shared = cls(dataclasses.replace(sizes, heads_axis="heads"), jnp.float32)
     stacked = jax.tree.map(lambda *a: jnp.stack(a), *(
         sublayer_params(held, layer, share) for share in shares))
-    every = jax.vmap(lambda p: shared.apply({"params": p}, x),
-                     axis_name="heads")(stacked)
+    every = jax.jit(jax.vmap(lambda p: shared.apply({"params": p}, x),
+                             axis_name="heads"))(stacked)
     for member in range(MEMBERS):
         assert worst(every[member], want) < 1e-5
 
@@ -574,16 +521,17 @@ def test_the_eight_expert_shares_add_up_to_the_uncut_layer():
     latent projections and shared expert.  The eight results, with the
     shared expert (what every member computes alike) counted once, add up
     to what the uncut reference gives for the whole layer."""
-    full, held = whole(), tiny()
+    full, held = whole(), tiny(TINY)
     m_full = arch.dims(full)
     layer = 1
-    w = arch.of_layer(arch.init_weights(full, reference.split_seed(11)),
-                      layer)
+    w = arch.of_layer(seeded(arch, full, 11), layer)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 128, 64), jnp.float32)
-    want = jnp.stack([arch.experts(row, w, m=m_full, mode="f32")
-                      for row in x])
-    shared_alone = jnp.stack([arch.experts(row, w, m=m_full, mode="f32",
-                                           held=0) for row in x])
+    # (which experts are held is a Python number to reference and program
+    # alike: a program a member)
+    reference_of = lambda **held: jax.jit(lambda x, w: jnp.stack([
+        arch.experts(row, w, m=m_full, mode="f32", **held) for row in x]))(
+            x, w)
+    want, shared_alone = reference_of(), reference_of(held=0)
     sizes = arch.build_module(held, {"remat": None}).sizes
     per = sizes.held
     total, loads = 0.0, []
@@ -592,11 +540,9 @@ def test_the_eight_expert_shares_add_up_to_the_uncut_layer():
             sizes, first_expert=member * per), jnp.float32)
         params = sublayer_params(held, layer,
                                  share_of(m_full, w, arch.EXPERTS, member))
-        got, state = module.apply({"params": params}, x,
-                                  mutable=["intermediates"])
-        given = jnp.stack([arch.experts(
-            row, w, m=m_full, mode="f32", first=member * per, held=per)
-            for row in x])
+        got, state = jax.jit(lambda p: module.apply(
+            p, x, mutable=["intermediates"]))({"params": params})
+        given = reference_of(first=member * per, held=per)
         assert worst(got, given) < 1e-5
         total = total + got
         loads.append(np.asarray(jax.tree.leaves(state)[0]))
@@ -609,7 +555,7 @@ def test_the_eight_expert_shares_add_up_to_the_uncut_layer():
 def test_the_whole_layers_parameters_are_the_eight_shares():
     count = lambda c: {k: int(np.prod(s))
                        for k, s in arch.weight_shapes(c).items()}
-    n_whole, n_held = count(whole()), count(tiny())
+    n_whole, n_held = count(whole()), count(tiny(TINY))
     # what a head owns apart from its group's B and C and the key/value
     # head four members read alike; held whole: router, latent projections,
     # shared expert, the norms over d_model, embedding and head
@@ -653,7 +599,7 @@ def test_the_real_configurations_parameters_to_the_parameter():
 
 
 def test_an_unknown_layer_kind_is_named_against_the_table():
-    sizes = arch.build_module(tiny(), {"remat": None}).sizes
+    sizes = arch.build_module(tiny(TINY), {"remat": None}).sizes
     assert hybrid.layer_kinds(sizes) == (
         names.LINEAR, names.FULL, names.WINDOW, names.STATE_SPACE,
         names.EXPERT_LAYER)
@@ -745,12 +691,11 @@ def test_a_kept_product_is_one_fewer_a_layer_in_the_gradient(kept,
     holds one ``dot_general`` fewer in each of the five expert layers (the
     shared expert's ``up``, the router's float32 product, ``latent_down``
     run once, not twice); ``down`` and ``latent_up`` never ran twice."""
-    config = tiny("bfloat16")
+    config = tiny(TINY, "bfloat16")
     tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0,
                                 config["vocab_size"])
     module = arch.build_module(config, {"remat": "nothing"})
-    params = arch.program_tree(config, arch.init_weights(
-        config, reference.split_seed(7)))
+    params = arch.program_tree(config, seeded(arch, config, 7))
     loss = lambda p: lm_loss(module.apply(p, tokens), tokens)
 
     def products(keep):
@@ -776,8 +721,8 @@ def test_the_gradient_with_the_keeps_is_the_gradient_without_remat(f32_pair):
         p["module"].sizes)) == 5
     plain = arch.build_module(p["config"], {"remat": None})
     assert not plain.remat
-    loss, grads = jax.value_and_grad(
-        lambda q: lm_loss(plain.apply(q, p["tokens"]), p["tokens"]))(
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda q: lm_loss(plain.apply(q, p["tokens"]), p["tokens"])))(
             p["params"])
     assert float(loss) == float(p["loss"])
     for got, want in zip(jax.tree.leaves(p["grads"]),

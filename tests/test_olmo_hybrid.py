@@ -17,7 +17,6 @@ alone: ``SCAN_BOUNDS`` below, each with its reading.
 
 import dataclasses
 import json
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +25,9 @@ import pytest
 
 from cellbench import reference
 from cellbench.archs import olmo_hybrid as arch
+from tests.decoder_reference import (DATA, highest, logits, reference_logits,
+                                     reference_pair, rel, run_steps, seeded,
+                                     tiny, worst)
 from tpudist import telemetry
 from tpudist.models import hybrid
 from tpudist.models.transformer import lm_loss
@@ -33,32 +35,13 @@ from tpudist.ops.gated_delta import (chunked_gated_delta_rule,
                                      gated_delta_rule_reference)
 from tpudist.telemetry import names
 
-DATA = Path(__file__).resolve().parent.parent / "cellbench" / "tests" / "data"
 TINY = json.loads((DATA / "tiny-olmo-hybrid.json").read_text())
-
-
-def tiny(dtype="float32", **keys) -> dict:
-    config = json.loads(json.dumps(TINY))
-    config.update(keys)
-    config["as_run"]["compute_dtype"] = dtype
-    return config
 
 
 @pytest.fixture(autouse=True)
 def highest_precision():
-    # the CPU multiplies float32 exactly; stated for the reader
-    with jax.default_matmul_precision("highest"):
+    with highest():
         yield
-
-
-def rel(got, want) -> float:
-    got, want = (np.asarray(x, np.float64) for x in (got, want))
-    return float(np.linalg.norm(got - want) / (np.linalg.norm(want) + 1e-30))
-
-
-def worst(got, want) -> float:
-    got, want = (np.asarray(x, np.float64) for x in (got, want))
-    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
 
 
 # ---------------------------------------------------------------------------
@@ -106,10 +89,10 @@ def wide(*args, **kw):
 @pytest.mark.parametrize("dtype, alike", CASES)
 def test_chunked_scan_gives_the_recurrences_values(dtype, alike):
     args = scan_inputs(dtype, 1.9 if alike else None)
-    got = wide(*args)
+    got = jax.jit(wide)(*args)
     assert got.dtype == dtype and got.shape == args[2].shape
-    assert worst(got.astype(jnp.float32), gated_delta_rule_reference(
-        *args)) < SCAN_BOUNDS[dtype, alike]
+    assert worst(got.astype(jnp.float32), jax.jit(
+        gated_delta_rule_reference)(*args)) < SCAN_BOUNDS[dtype, alike]
 
 
 @pytest.mark.parametrize("dtype, alike", CASES)
@@ -117,8 +100,8 @@ def test_chunked_scan_gives_the_recurrences_gradients(dtype, alike):
     args = scan_inputs(dtype, 1.9 if alike else None, seed=1)
 
     def through(fn):
-        return jax.grad(lambda *a: jnp.sum(jnp.sin(
-            fn(*a).astype(jnp.float32))), argnums=(0, 1, 2, 3, 4))(*args)
+        return jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(
+            fn(*a).astype(jnp.float32))), argnums=(0, 1, 2, 3, 4)))(*args)
 
     for name, got, want in zip("q k v g beta".split(), through(wide),
                                through(gated_delta_rule_reference)):
@@ -132,13 +115,14 @@ def test_the_product_over_16_rows_loses_identical_keys_beyond_beta_1():
     1e6 on a chunk of identical keys at ``beta`` 1.9 and cancel badly even in
     float32 at the highest precision; told the truth, it holds 5e-5."""
     args = scan_inputs(jnp.float32, 1.9)
-    want = gated_delta_rule_reference(*args)
-    assert worst(chunked_gated_delta_rule(*args), want) > 1e-2
-    assert worst(wide(*args), want) < SCAN_BOUNDS[jnp.float32, True]
+    narrow = jax.jit(chunked_gated_delta_rule)
+    want = jax.jit(gated_delta_rule_reference)(*args)
+    assert worst(narrow(*args), want) > 1e-2
+    assert worst(jax.jit(wide)(*args), want) < SCAN_BOUNDS[jnp.float32, True]
     # within [0, 1] the two agree to float32's rounding
     q, k, v, g, beta = scan_inputs(jnp.float32)
-    assert worst(wide(q, k, v, g, beta / 2),
-                 chunked_gated_delta_rule(q, k, v, g, beta / 2)) < 1e-5
+    assert worst(jax.jit(wide)(q, k, v, g, beta / 2),
+                 narrow(q, k, v, g, beta / 2)) < 1e-5
 
 
 @pytest.mark.parametrize("beta_max", [0.0, 2.5])
@@ -154,19 +138,7 @@ def test_scan_refuses_a_write_strength_it_does_not_hold_for(beta_max):
 
 @pytest.fixture(scope="module")
 def f32_pair():
-    with jax.default_matmul_precision("highest"):
-        config = tiny()
-        weights = arch.init_weights(config, reference.split_seed(7))
-        tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0,
-                                    config["vocab_size"])
-        module = arch.build_module(config, {"remat": "nothing"})
-        params = arch.program_tree(config, weights)
-        loss, grads = jax.value_and_grad(
-            lambda p: lm_loss(module.apply(p, tokens), tokens))(params)
-        ref_loss, ref_grads = arch.loss_and_grads(config, weights, tokens)
-        return dict(config=config, weights=weights, tokens=tokens,
-                    module=module, params=params, loss=loss, grads=grads,
-                    ref_loss=ref_loss, ref_grads=ref_grads)
+    return reference_pair(arch, tiny(TINY))
 
 
 def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
@@ -181,10 +153,7 @@ def test_the_module_takes_the_arms_the_architecture_names(f32_pair):
 
 
 def test_logits_match_the_reference(f32_pair):
-    p = f32_pair
-    got = p["module"].apply(p["params"], p["tokens"])
-    want = arch.forward(p["config"], p["weights"], p["tokens"])
-    assert worst(got, want) < 3e-5
+    assert worst(logits(f32_pair), reference_logits(f32_pair)) < 3e-5
 
 
 def test_loss_matches_the_reference(f32_pair):
@@ -225,13 +194,11 @@ def products(jaxpr) -> list:
 def bf16_program(arch_, config, policy):
     """``(module, params, loss)`` of an architecture's tiny configuration
     in bf16, rematerialised by ``policy``."""
-    config = json.loads(json.dumps(config))
-    config["as_run"]["compute_dtype"] = "bfloat16"
+    config = tiny(config, "bfloat16")
     tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0,
                                 config["vocab_size"])
     module = arch_.build_module(config, {"remat": policy})
-    params = arch_.program_tree(config, arch_.init_weights(
-        config, reference.split_seed(7)))
+    params = arch_.program_tree(config, seeded(arch_, config, 7))
     return module, params, lambda p: lm_loss(module.apply(p, tokens), tokens)
 
 
@@ -307,33 +274,20 @@ def test_another_arm_is_not_this_architecture(f32_pair, wrong):
     p = f32_pair
     other = p["module"].clone(sizes=dataclasses.replace(
         p["module"].sizes, **wrong))
-    want = arch.forward(p["config"], p["weights"], p["tokens"])
-    assert worst(other.apply(p["params"], p["tokens"]), want) > 1e-2
+    assert worst(logits(p, other), reference_logits(p)) > 1e-2
 
 
 def test_three_adam_steps_follow_the_reference():
     """``make_lm_train_step`` over the float32 program against the
     reference's own Adam: losses to 1e-5, every tensor's change after three
     steps to 2e-3 of its norm."""
-    import optax
-
-    from tpudist.runtime.mesh import MeshConfig, make_mesh
-    from tpudist.train import init_lm_state, make_lm_train_step
-
-    config = tiny()
-    weights = arch.init_weights(config, reference.split_seed(7))
+    config = tiny(TINY)
+    weights = seeded(arch, config, 7)
     module = arch.build_module(config, {"remat": "nothing"})
     rng = np.random.default_rng(0)
     batches = [rng.integers(0, 256, (2, 128), dtype=np.int32)
                for _ in range(3)]
-    tx = optax.adam(2e-3)
-    mesh = make_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
-    step = make_lm_train_step(module.apply, tx, mesh, donate_state=False)
-    state = init_lm_state(arch.program_tree(config, weights), tx)
-    losses = []
-    for batch in batches:
-        state, loss = step(state, jnp.asarray(batch))
-        losses.append(float(loss))
+    state, losses, _ = run_steps(arch, config, module, weights, batches, 2e-3)
     ref = reference.train_readings(arch, config, 7, batches, lr=2e-3,
                                    rows_per_block=2)
     np.testing.assert_allclose(losses, ref["losses"], rtol=1e-5)
@@ -392,13 +346,13 @@ def test_the_two_halves_of_the_heads_add_up_to_the_uncut_layer(kind, layer):
     heads' dims and the output projections' partial sums are added up, so
     each member's mixer output IS the uncut one.  A member alone (no axis:
     the cell) gives what the reference gives when handed that half."""
-    whole, held = tiny(**WHOLE), tiny()
+    whole, held = tiny(TINY, **WHOLE), tiny(TINY)
     m_whole, m_held = arch.dims(whole), arch.dims(held)
-    w = arch.of_layer(arch.init_weights(whole, reference.split_seed(11)),
-                      layer)
+    w = arch.of_layer(seeded(arch, whole, 11), layer)
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 128, 64), jnp.float32)
-    want = jnp.stack([arch.mixer(row, w, kind=kind, m=m_whole, mode="f32")
-                      for row in x])
+    reference_of = lambda m: jax.jit(lambda x, w: jnp.stack([arch.mixer(
+        row, w, kind=kind, m=m, mode="f32") for row in x]))
+    want = reference_of(m_whole)(x, w)
     halves = [half_of(m_whole, w, kind, member) for member in (0, 1)]
 
     def program_params(half):
@@ -414,16 +368,16 @@ def test_the_two_halves_of_the_heads_add_up_to_the_uncut_layer(kind, layer):
                  jnp.float32)
     stacked = jax.tree.map(lambda a, b: jnp.stack([a, b]),
                            *map(program_params, halves))
-    both = jax.vmap(lambda p: shared.apply({"params": p}, x),
-                    axis_name="heads")(stacked)
+    both = jax.jit(jax.vmap(lambda p: shared.apply({"params": p}, x),
+                            axis_name="heads"))(stacked)
     for member in (0, 1):
         assert worst(both[member], want) < 1e-5
-    alone = cls(sizes, jnp.float32)
+    alone, given_a_half = jax.jit(cls(sizes, jnp.float32).apply), (
+        reference_of(m_held))
     parts = []
     for half in halves:
-        got = alone.apply({"params": program_params(half)}, x)
-        given = jnp.stack([arch.mixer(row, half, kind=kind, m=m_held,
-                                      mode="f32") for row in x])
+        got = alone({"params": program_params(half)}, x)
+        given = given_a_half(x, half)
         assert worst(got, given) < 1e-5
         parts.append(got)
     if kind == arch.LINEAR:
@@ -436,7 +390,7 @@ def test_the_two_halves_of_the_heads_add_up_to_the_uncut_layer(kind, layer):
 
 
 def test_the_whole_layers_parameters_are_the_two_halves(f32_pair):
-    whole, held = tiny(**WHOLE), f32_pair["config"]
+    whole, held = tiny(TINY, **WHOLE), f32_pair["config"]
     count = lambda c: {k: int(np.prod(s))
                        for k, s in arch.weight_shapes(c).items()}
     n_whole, n_held = count(whole), count(held)

@@ -15,9 +15,6 @@ from tpudist.ops import (
     flash_attention,
     flash_attention_packed,
     flash_attention_with_lse,
-    fused_mlp,
-    mlp_reference,
-    pad_params,
 )
 
 # the module: ``tpudist.ops.flash_attention`` names the function it exports
@@ -516,42 +513,6 @@ class TestTheGridFollowsTheBand:
             for j in np.flatnonzero(live.any(0)):
                 first = int(kernels._first_live_q(j, nq, bq, bk, lo, hi))
                 assert first == np.flatnonzero(live[:, j])[0]
-
-
-class TestFusedMLP:
-    def _toy_weights(self, seed=0):
-        """The reference MLP shape: 2→10→10→10→10→1 (toy_model_and_data.py)."""
-        dims = [2, 10, 10, 10, 10, 1]
-        ks = jax.random.split(jax.random.PRNGKey(seed), len(dims) - 1)
-        return [
-            (jax.random.normal(k, (i, o)) / np.sqrt(i), jnp.zeros((o,)))
-            for k, i, o in zip(ks, dims[:-1], dims[1:])
-        ]
-
-    def test_matches_reference(self):
-        weights = self._toy_weights()
-        x = jax.random.normal(jax.random.PRNGKey(1), (512, 2))
-        padded, _, d_out = pad_params(weights)
-        out = fused_mlp(x, padded, d_out, interpret=True)
-        ref = mlp_reference(x, weights)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5, rtol=1e-5)
-
-    def test_batch_tiling(self):
-        weights = self._toy_weights()
-        x = jax.random.normal(jax.random.PRNGKey(1), (1024, 2))
-        padded, _, d_out = pad_params(weights)
-        out = fused_mlp(x, padded, d_out, block_batch=256, interpret=True)
-        np.testing.assert_allclose(np.asarray(out),
-                                   np.asarray(mlp_reference(x, weights)),
-                                   atol=1e-5, rtol=1e-5)
-
-    def test_indivisible_batch_raises(self):
-        weights = self._toy_weights()
-        padded, _, d_out = pad_params(weights)
-        x = jnp.zeros((300, 2))
-        with pytest.raises(ValueError, match="divide"):
-            fused_mlp(x, padded, d_out, block_batch=256, interpret=True)
 
 
 class TestBlockwiseAttention:

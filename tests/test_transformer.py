@@ -1034,7 +1034,7 @@ class TestRemat:
         toks = _tokens(batch=2, seq=16, vocab=64)
         np.testing.assert_allclose(
             np.asarray(decode_logits(module, params, toks)),
-            np.asarray(module.apply(params, toks)),
+            np.asarray(jax.jit(module.apply)(params, toks)),
             atol=1e-4, rtol=1e-4)
 
 
@@ -1051,8 +1051,8 @@ class TestRematPolicies:
                                           **cfg)
 
         def grad_of(mod):
-            return jax.grad(
-                lambda p: float(0) + lm_loss(mod.apply(p, toks), toks))(params)
+            return jax.jit(jax.grad(
+                lambda p: float(0) + lm_loss(mod.apply(p, toks), toks)))(params)
 
         base = grad_of(mod0)
         for policy in ("nothing", "dots", "dots_no_batch"):
@@ -1155,8 +1155,8 @@ class TestPackedAttentionRoute:
             attention_fn=attention_fn and _untagged(default_attention),
             **cfg)
         tokens = _tokens(batch=2, seq=seq)
-        return jax.value_and_grad(
-            lambda p: lm_loss(module.apply(p, tokens), tokens))(params)
+        return jax.jit(jax.value_and_grad(
+            lambda p: lm_loss(module.apply(p, tokens), tokens)))(params)
 
     @pytest.mark.parametrize("kv_heads", [None, 1], ids=["mha", "gqa"])
     def test_packed_route_equals_head_major_at_dh_128(self, as_tpu, layouts,
@@ -1203,5 +1203,6 @@ class TestPackedAttentionRoute:
         head_mod, _ = create_transformer(
             jax.random.PRNGKey(0), seq_len=64,
             attention_fn=_untagged(default_attention), **cfg)
-        np.testing.assert_array_equal(packed_mod.apply(params, tokens),
-                                      head_mod.apply(params, tokens))
+        np.testing.assert_array_equal(
+            jax.jit(packed_mod.apply)(params, tokens),
+            jax.jit(head_mod.apply)(params, tokens))

@@ -10,8 +10,6 @@ as fallbacks and in correctness tests (interpret mode on CPU).
   shards between chips, flash blocks within a chip).
 - :mod:`rope` — rotary angles and rotation, shared by the models and the
   fused RoPE+QKV kernel.
-- :mod:`fused_mlp` — the toy workload's 5-layer MLP in one kernel, weights
-  zero-padded to lane-aligned tiles, activations pinned in VMEM.
 - :mod:`paged_attention` — serving-decode attention that walks the paged
   KV cache's block table INSIDE the kernel (vLLM-PagedAttention style):
   live blocks only, int8 dequant in-registers, the decode-window mask
@@ -47,11 +45,6 @@ from tpudist.ops.rope import (  # noqa: F401
 from tpudist.ops.paged_attention import (  # noqa: F401
     paged_attention,
     paged_attention_reference,
-)
-from tpudist.ops.fused_mlp import (  # noqa: F401
-    fused_mlp,
-    mlp_reference,
-    pad_params,
 )
 from tpudist.ops.paged_prefill import (  # noqa: F401
     paged_prefill_attention,

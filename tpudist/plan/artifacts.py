@@ -44,7 +44,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 from tpudist.utils.envutil import env_flag, env_int
 
 #: Header schema version this loader understands (satellite of ISSUE 20:
-#: round_snapshot stamps this into every future artifact write).
+#: ``benchmarks/plan_bench.py`` writes it into its artifact's header).
 ARTIFACT_SCHEMA = 1
 
 #: Families the planner consumes.  Other frozen files (PARITY, BANDS,
@@ -153,8 +153,8 @@ class ArtifactSet:
                 if r.path.name.startswith(family + "_r")) or "no file found"
             raise PlanArtifactError(
                 f"required artifact family {family!r} unavailable under "
-                f"{self.root} ({why}) — run the benchmarks "
-                f"(benchmarks/round_snapshot.py) or unset "
+                f"{self.root} ({why}) — run the family's harness "
+                f"under benchmarks/ or unset "
                 f"TPUDIST_PLAN_STRICT to degrade to the analytic model")
         return a
 

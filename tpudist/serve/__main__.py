@@ -10,8 +10,8 @@ real measurement harness is ``benchmarks/serve_bench.py``.
 ``--replicas N`` (N >= 2) runs the same burst through the fleet router
 instead: N replica servers behind :class:`tpudist.serve.FleetRouter`,
 with the routing/failover stats in the summary — the multi-replica
-quick-start (``benchmarks/router_bench.py`` is the measurement
-harness).
+quick-start (``tests/test_router.py`` holds the routing and failover
+drives).
 """
 
 from __future__ import annotations

@@ -35,7 +35,6 @@ FUSED_ROPE_QKV = "fused_rope_qkv"
 LORA_DELTA = "lora_delta"
 FUSED_SAMPLE = "fused_sample"
 FUSED_RESIDUAL = "fused_residual"
-FUSED_MLP = "fused_mlp"
 #: the key of ``pallas_call``'s ``metadata`` dict that holds the name
 KERNEL_KEY = "kernel"
 

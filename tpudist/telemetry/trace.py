@@ -28,8 +28,7 @@ the ``prefill``/``decode_block`` spans already account); old streams
 without them aggregate byte-identically.
 
 ``TPUDIST_TRACE=0`` disarms lifeline emission (trace_ids still mint —
-a 16-hex id per request is noise-level); the observability bench
-measures the armed cost (``BENCH_OBS``).
+a 16-hex id per request is noise-level).
 
 :func:`export_chrome_trace` renders the joined records as Chrome
 trace-event JSON (Perfetto/chrome://tracing loadable): one process row

@@ -204,8 +204,8 @@ ENV_VARS = {
         "env spelling of ServeConfig.auto: plan unpinned serving knobs "
         "against the frozen measurement artifacts (default off)",
     "TPUDIST_PLAN_DIR":
-        "planner artifact directory (default: the repo root, where "
-        "round_snapshot freezes *_rNN.json)",
+        "planner artifact directory (default: the repo root, where the "
+        "harnesses' *_rNN.json records lie)",
     "TPUDIST_PLAN_TOPN":
         "rows the plan report prints per workload (default 0 = all)",
     "TPUDIST_PLAN_STALE_ROUNDS":
