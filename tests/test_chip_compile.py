@@ -199,6 +199,60 @@ def test_chunked_delta_rule_compiles_at_unequal_widths_beyond_beta_1(
     assert mem.temp_size_in_bytes < 1e9
 
 
+def test_chunked_delta_rule_compiles_at_a_decay_a_channel(one_chip):
+    """Cell ``kimilinear-train-ep32share-8k``'s scan: 32 heads of 128 x 128
+    over one row of 8,192 positions with ``g [b, s, h, dk]``, forward and
+    backward: the sub-blocks' scaled keys are ``[128, 1, 32, 4, 64, 128]``
+    (268 MB in bf16) and nothing of the chunk's ``[16, 16, dk]`` pairs is
+    ever laid out."""
+    from tpudist.ops.gated_delta import chunked_gated_delta_rule
+
+    b, s, h, d = 1, 8192, 32, 128
+
+    def loss(q, k, v, g, beta):
+        return chunked_gated_delta_rule(q, k, v, g, beta).astype(
+            jnp.float32).sum()
+
+    wide = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+    channel = jax.ShapeDtypeStruct((b, s, h, d), jnp.float32)
+    head = jax.ShapeDtypeStruct((b, s, h), jnp.float32)
+    mem = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                   (wide, wide, wide, channel, head),
+                   one_chip).memory_analysis()
+    assert mem.temp_size_in_bytes < 4e9
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
+def test_flash_attention_compiles_at_values_narrower_than_keys(one_chip,
+                                                               grad):
+    """The latent layer's call: 32 heads whose queries and keys are 192 wide
+    (one and a half lane tiles: every block's last dimension is the array's
+    own, whole) on values of 128, head-major, the row's 1024 x 1024 tiles by
+    squares of 256; the three kernels under their names, the outputs at the
+    values' width and ``dq``, ``dk`` at the scores'."""
+    from tpudist.ops import flash_attention
+
+    b, h, s, d, d_v = 1, 32, 8192, 192, 128
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, 1024, 1024, False, None,
+                               V5E_SUB).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if grad else loss
+    wide = jax.ShapeDtypeStruct((b, h, s, d), jnp.bfloat16)
+    narrow = jax.ShapeDtypeStruct((b, h, s, d_v), jnp.bfloat16)
+    with _counting_cut_tiles() as cut:
+        text = _compile(fn, (wide, wide, narrow), one_chip).as_text()
+    assert sorted(_kernels_named(text)) == (
+        ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"] if grad
+        else ["flash_fwd"])
+    assert cut == [(1024, V5E_SUB)] * (3 if grad else 1)
+    # the output at the values' width, ``dq`` and ``dk`` at the scores'
+    if grad:
+        assert [x.shape[-1] for x in jax.eval_shape(
+            fn, wide, wide, narrow)] == [d, d, d_v]
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd+bwd"])
 def test_packed_flash_attention_compiles_at_the_cells_shape(one_chip, grad):
     """The packed entry's blocks — ``(1, 1024, 128)`` column blocks of a
@@ -962,6 +1016,69 @@ def test_granite_cell_step_runs_head_major_flash_and_mlp_inputs_twice(
                 if re.search(r" convolution\(", line) and re.search(
                     r"/mlp/(gate|up|down)_proj/dot_general", line)]
     assert len(products) == 10 * (3 + 2 + 6)
+
+
+@pytest.fixture(scope="module")
+def kimi_step(topo):
+    """The whole train step of cell ``kimilinear-train-ep32share-8k`` (one
+    chip's share of a deployment in which 32 chips share each layer, experts
+    32 ways): 1 row x 8,192."""
+    return _cell_step(topo, "kimilinear-train-ep32share-8k")
+
+
+def test_kimi_cell_step_fills_one_chip_and_fits(kimi_step):
+    step, job, m = kimi_step
+    assert job["per_chip_batch"] == 1 and job["seq_len"] == 8192
+    assert job["remat"] == "nothing" and job["accum_steps"] == 1
+    mem = step.memory_analysis()
+    held = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    # 602,434,432 parameters and buffer entries x 12 bytes resident
+    assert 7.22e9 < mem.argument_size_in_bytes < 7.24e9
+    # 11.38 GiB = 12.21 GB: temporaries 4,985,193,984 bytes, the float32
+    # gradient (2.41 GB) among them; layer 0 keeps its dense feed-forward's
+    # three products from forward to backward (377 MB with ``mixer_out``),
+    # the expert layers ``mixer_out`` (38 MB each) and their router's logits
+    # and picks (9 MB each); a KDA layer's rematerialised forward holds the
+    # sub-blocks' scaled keys (268 MB in bf16) and a chunk's pairs, and an
+    # expert layer takes what arrived through windows of 16,384 rows.  No
+    # lighter policy fits: ``dots_no_batch`` holds 16.41 GiB and no remat is
+    # refused by the compiler.  The issue asks over 12 GB and under 15.0
+    # GiB; the compiler allows 15.75
+    assert 12.0e9 < held < 11.8 * 2 ** 30 < 15.0 * 2 ** 30
+
+
+def test_kimi_cell_step_runs_the_flash_kernels_at_192_on_128(kimi_step):
+    """One latent layer in the five: the three flash kernels by name on the
+    dispatch's head-major route at 192-wide queries and keys on 128-wide
+    values (nothing padded: the forward's output is ``[32, 8192, 128]``),
+    the forward twice (its layer is rematerialised), 32 heads over 8 x 8
+    tiles.  The four expert layers' products are the compiler's grouped
+    matmuls over the 8 experts HELD, over windows of 16,384 rows (four even
+    shares of the 65,536 assignments a layer): 15 a layer, 64 custom calls
+    in all, what the benchmark's runner holds the step to.  The KDA scans
+    are plain XLA.  No collective: one chip's share."""
+    import re
+
+    step, job, m = kimi_step
+    text = step.as_text()
+    assert sorted(_kernels_named(text)) == [
+        "flash_bwd_dkv", "flash_bwd_dq", "flash_fwd", "flash_fwd"]
+    assert text.count("tpu_custom_call") == 64 == (
+        job["custom_calls_per_layer"] * m["layers"])
+    assert sorted(_kernel_grids(text)) == sorted(
+        [("flash_fwd", (32, 8, 8))] * 2 + [("flash_bwd_dq", (32, 8, 8))]
+        + [("flash_bwd_dkv", (32, 8, 8))])
+    outs = re.findall(r"%flash_fwd[\w.]* = \(bf16\[(\d+),8192,(\d+)\]", text)
+    assert outs == [("32", "128")] * 2
+    assert re.search(r"ragged-dot", text)
+    assert re.search(r"\[8,2304,1024\]", text)
+    assert not re.search(r"\[256,2304,1024\]|\[256,1024,2304\]", text)
+    assert "[16384,2304]" in text and "[65536,2304]" not in text
+    # ONE sort a layer over score + bias, the scores and the columns
+    assert _router_sorts(text, 256) == [3] * 4
+    assert job["collectives_in_step"] == []
+    assert "all-reduce" not in text and "all-gather" not in text
 
 
 def test_a_512_band_over_8192_keys_computes_what_the_table_gives():

@@ -602,10 +602,11 @@ def test_an_unknown_layer_kind_is_named_against_the_table():
     sizes = arch.build_module(tiny(TINY), {"remat": None}).sizes
     assert hybrid.layer_kinds(sizes) == (
         names.LINEAR, names.FULL, names.WINDOW, names.STATE_SPACE,
-        names.EXPERT_LAYER)
+        names.CHANNEL_LINEAR, names.LATENT, names.EXPERT_LAYER)
     paired = dataclasses.replace(sizes, one_sublayer=False)
     assert hybrid.layer_kinds(paired) == tuple(hybrid.MIXERS) == (
-        names.LINEAR, names.FULL, names.WINDOW, names.STATE_SPACE)
+        names.LINEAR, names.FULL, names.WINDOW, names.STATE_SPACE,
+        names.CHANNEL_LINEAR, names.LATENT)
     tokens = jnp.zeros((1, 32), jnp.int32)
     for z, kinds in ((sizes, ("retention",)),
                      (paired, (names.EXPERT_LAYER,))):

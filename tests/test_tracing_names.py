@@ -90,6 +90,19 @@ def step_op_names():
     params = hybrid.init(jax.random.PRNGKey(0), tokens)
     lowered = make_lm_train_step(hybrid.apply, tx, mesh).lower(
         init_lm_state(params, tx), tokens)
+    found |= set(re.findall(r'loc\("([^"]+)"',
+                            lowered.as_text(debug_info=True)))
+    # a delta rule whose decay is a number a channel behind low-rank gates,
+    # and latent attention: the two mixers' scopes and what nests in them
+    hybrid = HybridLM(
+        vocab=64, layer_types=(names.CHANNEL_LINEAR, names.LATENT),
+        sizes=dataclasses.replace(
+            hybrid.sizes, n_heads=2, linear_key_heads=2, linear_gate_rank=4,
+            latent_rank=12, latent_key_dims=(24, 8), latent_value_dim=16),
+        remat=True)
+    params = hybrid.init(jax.random.PRNGKey(0), tokens)
+    lowered = make_lm_train_step(hybrid.apply, tx, mesh).lower(
+        init_lm_state(params, tx), tokens)
     return found | set(re.findall(r'loc\("([^"]+)"',
                                   lowered.as_text(debug_info=True)))
 
@@ -109,7 +122,8 @@ def test_a_backward_op_carries_the_transpose_mark_and_its_sublayer(
                   names.LOSS, names.LINEAR_ATTN, names.DELTA_RULE,
                   names.MOE, names.EXPERTS, names.SHARED_EXPERT, names.SSM,
                   names.SSD_SCAN, names.LATENT_PROJ, names.WINDOW_ATTN,
-                  names.HEAD_GATE, names.SSM_CONV, names.SSM_NORM):
+                  names.HEAD_GATE, names.SSM_CONV, names.SSM_NORM, names.KDA,
+                  names.KDA_GATE, names.LATENT_ATTN, names.LATENT_KV):
         assert [n for n in step_op_names
                 if names.BACKWARD_MARK in n and _under(scope, n)], scope
     # the optimizer is not differentiated: no transposed op under it

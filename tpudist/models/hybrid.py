@@ -43,6 +43,19 @@ ungated GELU FFN) cannot say, by mechanism:
   ``beta_scale * sigmoid``, L2-normed queries and keys, the chunked scan of
   :mod:`tpudist.ops.gated_delta` (key and value widths may differ), an RMS
   norm gated by ``silu(z)``;
+- **a delta rule whose decay is a number a CHANNEL**
+  (:class:`KimiDeltaAttention`): a projection each for q, k and v, the same
+  convolution and L2 norms, a forget gate ``-exp(A_log) * softplus(.)`` a
+  channel of a head behind a two-step projection of low rank, the chunked
+  scan at ``g [b, s, h, dk]`` (sub-blocks of 16, the gate held above -5 a
+  position: :mod:`tpudist.ops.gated_delta`), an RMS norm a head gated by a
+  SIGMOID behind another low-rank projection;
+- **latent attention without positions** (:class:`LatentAttention`): keys
+  and values projected out of one normed latent a token, a head's key its
+  own part beside a part ALL heads share, nothing turned, the scores taken
+  over both parts and the values narrower than the keys (192 on 128: the
+  dispatch's head-major route, the flash kernels at a value width of their
+  own); the keys are expanded to every head (training: no absorbed form);
 - **a Mamba-2 state-space mixer** (:class:`Mamba2Mixer`): one input
   projection into a gate, heads, groups of ``B`` and ``C`` and a step a
   head, a depthwise causal convolution with bias and SiLU, the chunked scan
@@ -70,7 +83,7 @@ ungated GELU FFN) cannot say, by mechanism:
   names none, and then no instruction.
 
 Which arm a layer takes is data on :class:`HybridSizes`, filled in by
-whoever builds the module; nothing here knows a model.  The five
+whoever builds the module; nothing here knows a model.  The six
 architectures that run through it (``cellbench/archs``): ``qwen3_next``
 (norms zero-centred and before the sublayer, :class:`GatedAttention`, fused
 projections with ``nv / nk`` value heads a key head at 128 / 128, write
@@ -93,7 +106,13 @@ plain and before the sublayers, :class:`Mamba2Mixer` holding half the heads
 of the ONE group, whose ``B`` and ``C`` both members hold whole,
 :class:`GroupedAttention` at 4 : 1 on 64-wide heads with a softmax scale of
 1 / 64, a :class:`GatedMLP` behind every mixer whose products are NOT kept
-under remat, the four multipliers and a tied head).
+under remat, the four multipliers and a tied head) and ``kimi_linear``
+(norms plain and before the sublayers, :class:`KimiDeltaAttention` at 32
+heads of 128 / 128 with gates of rank 128 three layers in four,
+:class:`LatentAttention` at 32 heads of 128 + 64 on 128 out of a latent of
+512 in the fourth, all heads held; a dense feed-forward in layer 0 and
+:class:`ExpertShare` with sigmoid + bias scoring, a scale, gated SiLU
+experts and a plain shared expert behind the others).
 
 The embedding, the head (untied, or the embedding's own ``attend``), their
 names and scopes, the loss the step
@@ -116,8 +135,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from tpudist import telemetry
 from tpudist.models.transformer import remat_module
-from tpudist.ops.attention import attention_within, default_attention
-from tpudist.ops.gated_delta import chunked_gated_delta_rule
+from tpudist.ops.attention import (attention_within, default_attention,
+                                   merge_heads, route)
+from tpudist.ops.gated_delta import (GATE_FLOOR, SUB_BLOCK,
+                                     chunked_gated_delta_rule)
 from tpudist.ops.rope import rope_angles_at, rope_inv_freq, yarn_inv_freq
 from tpudist.ops.ssd import ssd_scan
 from tpudist.parallel.moe import EXPERT_FNS, EXPERT_LEAVES, expert_share
@@ -263,6 +284,19 @@ class HybridSizes:
     linear_value_heads_total: Optional[int] = None
     linear_projections: str = names.FUSED    # or names.SEPARATE
     beta_scale: float = 1.0      # write strength = beta_scale * sigmoid(.)
+    # the channel-gated delta-rule mixer reads ``linear_value_heads`` (its
+    # heads: a key head a value head), the two widths, the convolution's
+    # and this: the rank of its forget gate's and its output gate's
+    # two-step projections
+    linear_gate_rank: int = 0
+    # latent attention (``n_heads`` of ``n_heads_total`` heads): the rank of
+    # the latent that keys and values are projected out of, the two parts
+    # of a head's query and key (its own, out of the latent; and the part
+    # ALL heads share, projected beside the latent) and the values' width;
+    # the scores are taken over both parts, ``sum(latent_key_dims)`` wide
+    latent_rank: int = 0
+    latent_key_dims: tuple = (0, 0)
+    latent_value_dim: int = 0
     # state-space (Mamba-2) mixers: heads and groups HELD, of ``*_total`` in
     # all; a group's ``B`` and ``C`` serve ``ssm_heads / ssm_groups`` of the
     # heads held.  Where the members that share a layer outnumber the groups
@@ -496,6 +530,15 @@ def causal_depthwise_conv(x, kernel):
                for j in range(width))
 
 
+def _unit_heads(t, heads: int, width: int, dtype, scale: float = 1.0):
+    """``t [b, s, heads * width]`` as ``[b, s, heads, width]``, L2-normed a
+    head in float32 (times ``scale``), in ``dtype``."""
+    t = t.reshape(*t.shape[:2], heads, width).astype(jnp.float32)
+    t = t * (scale * jax.lax.rsqrt(
+        jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6))
+    return t.astype(dtype)
+
+
 class GatedDeltaNet(nn.Module):
     """The gated delta-rule linear-attention mixer, holding
     ``linear_key_heads`` / ``linear_value_heads`` heads (each of its
@@ -555,10 +598,8 @@ class GatedDeltaNet(nn.Module):
 
         def unit(t, scale=1.0):
             """L2-normed per head, each key head serving r value heads."""
-            t = t.reshape(b, s, nk, dk).astype(jnp.float32)
-            t = t * (scale * jax.lax.rsqrt(
-                jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6))
-            return jnp.repeat(t.astype(self.dtype), r, axis=2)
+            return jnp.repeat(_unit_heads(t, nk, dk, self.dtype, scale), r,
+                              axis=2)
 
         o = chunked_gated_delta_rule(
             unit(q, dk ** -0.5), unit(k), v.reshape(b, s, nv, dv), g, beta,
@@ -568,6 +609,126 @@ class GatedDeltaNet(nn.Module):
             gate.reshape(b, s, nv, dv).astype(jnp.float32))
         return _over_members(_dense(d, "out_proj", self.dtype)(
             o.reshape(b, s, nv * dv).astype(self.dtype)), z.heads_axis)
+
+
+class KimiDeltaAttention(nn.Module):
+    """The delta-rule mixer whose decay is a number a CHANNEL (Kimi Delta
+    Attention), holding ``linear_value_heads`` heads of ``linear_key_dim``
+    / ``linear_value_dim`` (each projection the held heads' columns,
+    ``o_proj`` their rows; its partial sums reduced over ``heads_axis``
+    where there is one): ``q, k, v`` a projection each, a depthwise causal
+    convolution with SiLU over the three, queries and keys L2-normed a head
+    (queries times ``dk ** -0.5``); the forget gate ``g = -exp(A_log[head])
+    * softplus(f_b(f_a(x)) + dt_bias)`` in float32, a number a channel
+    behind a projection of rank ``linear_gate_rank``; the write strength
+    ``sigmoid(b_proj(x))`` a head; :func:`chunked_gated_delta_rule` at
+    ``g [b, s, h, dk]``; and ``o_proj(norm * rms(o) * sigmoid(g_b(g_a(x))))``,
+    the RMS over a head's ``dv`` and the gate a SIGMOID behind a projection
+    of the same rank.  The gates' projections, softplus, ``exp(A_log)`` and
+    the gate's product run under ``names.KDA_GATE``: what this mixer adds
+    to :class:`GatedDeltaNet`."""
+
+    sizes: HybridSizes
+    dtype: jnp.dtype
+    chunk: int = 64
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        z = self.sizes
+        h, dk, dv = z.linear_value_heads, z.linear_key_dim, z.linear_value_dim
+        rank = z.linear_gate_rank
+        telemetry.event(
+            names.MIXER_LAYOUT, decay=names.CHANNEL,
+            heads=[h, z.linear_value_heads_total or h], dk=dk, dv=dv,
+            chunk=self.chunk, sub_block=SUB_BLOCK, gate_floor=GATE_FLOOR,
+            gate_rank=rank)
+        mixed = jnp.concatenate(
+            [_dense(h * width, f"{name}_proj", self.dtype)(x)
+             for name, width in (("q", dk), ("k", dk), ("v", dv))], axis=-1)
+        kernel = self.param("conv", nn.initializers.lecun_normal(),
+                            (mixed.shape[-1], z.linear_conv_width))
+        mixed = jax.nn.silu(causal_depthwise_conv(mixed, kernel)).astype(
+            self.dtype)
+        q, k, v = jnp.split(mixed, [h * dk, 2 * h * dk], axis=-1)
+        a_log = self.param("A_log", nn.initializers.zeros, (h,))
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (h * dk,))
+
+        def low_rank(name, width):
+            return _dense(width, f"{name}_b_proj", self.dtype)(
+                _dense(rank, f"{name}_a_proj", self.dtype)(x))
+
+        with jax.named_scope(names.KDA_GATE):
+            f = low_rank("f", h * dk).astype(jnp.float32) + dt_bias
+            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+                f.reshape(b, s, h, dk))
+        beta = jax.nn.sigmoid(
+            _dense(h, "b_proj", self.dtype)(x).astype(jnp.float32))
+
+        o = chunked_gated_delta_rule(
+            _unit_heads(q, h, dk, self.dtype, dk ** -0.5),
+            _unit_heads(k, h, dk, self.dtype), v.reshape(b, s, h, dv), g,
+            beta, chunk=self.chunk)
+        norm = self.param("norm", nn.initializers.ones, (dv,))
+        with jax.named_scope(names.KDA_GATE):
+            gate = jax.nn.sigmoid(low_rank("g", h * dv).astype(jnp.float32))
+            o = norm * _rms(o, z.eps) * gate.reshape(b, s, h, dv)
+        return _over_members(_dense(d, "o_proj", self.dtype)(
+            o.reshape(b, s, h * dv).astype(self.dtype)), z.heads_axis)
+
+
+class LatentAttention(nn.Module):
+    """Causal softmax attention whose keys and values come out of a latent
+    (multi-head latent attention), without positions, holding ``n_heads``
+    heads: ``q = q_proj(x)``, a head's ``own + shared`` dims
+    (``latent_key_dims``); ``kv_a_proj(x)`` gives the latent
+    (``latent_rank``) and ONE key part that all heads share; ``kv_b_proj``
+    of the RMS-normed latent gives each held head its own key part and its
+    values (``latent_value_dim``); head ``h``'s key is ``[own_h | shared]``,
+    the shared part as it is (nothing is turned: no rotary positions); the
+    scores are ``q . k / sqrt(own + shared)`` and the values narrower than
+    the keys, which the dispatch's head-major route and the flash kernels
+    take as they are (``ops/flash_attention.py``: values of a width of
+    their own); ``o_proj`` reads the heads' values side by side, its
+    partial sums reduced over ``heads_axis`` where there is one.  The keys
+    are expanded to every head for training; the form that absorbs
+    ``kv_b_proj`` into the queries is a decoder's and is not here.  What
+    stands round the kernels besides ``q_proj`` and ``o_proj`` runs under
+    ``names.LATENT_KV``."""
+
+    sizes: HybridSizes
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, d = x.shape
+        z = self.sizes
+        h, dv = z.n_heads, z.latent_value_dim
+        own, shared = z.latent_key_dims
+        r = route(jax.devices()[0].device_kind, s, own + shared)
+        telemetry.event(
+            names.ATTN_LAYOUT, layout=names.HEAD_MAJOR,
+            reason=r.why_not or names.WHY_WIDTHS, kernel=r.kernel,
+            qk_dim=own + shared, v_dim=dv, latent_rank=z.latent_rank)
+        q = _dense(h * (own + shared), "q_proj", self.dtype)(x)
+        with jax.named_scope(names.LATENT_KV):
+            latent, k_shared = jnp.split(
+                _dense(z.latent_rank + shared, "kv_a_proj", self.dtype)(x),
+                [z.latent_rank], axis=-1)
+            latent = RMSNorm(z.eps, name="kv_norm")(latent).astype(self.dtype)
+            kv = _dense(h * (own + dv), "kv_b_proj", self.dtype)(
+                latent).reshape(b, s, h, own + dv)
+            k = jnp.concatenate(
+                [kv[..., :own], jnp.broadcast_to(
+                    k_shared[:, :, None], (b, s, h, shared))], axis=-1)
+            v = kv[..., own:]
+        # head-major: a head's 192 dims are not whole lane tiles
+        heads_first = lambda t: t.transpose(0, 2, 1, 3)
+        attn = default_attention(
+            heads_first(q.reshape(b, s, h, own + shared)), heads_first(k),
+            heads_first(v))
+        return _over_members(_dense(d, "o_proj", self.dtype)(
+            merge_heads(attn)), z.heads_axis)
 
 
 def ssm_group_members(sizes: HybridSizes) -> int:
@@ -804,13 +965,17 @@ def _softmax(kind: str):
 #: delta-rule linear attention, softmax attention causal to everything
 #: (``full_attention``) or inside a sliding window (``sliding_attention``,
 #: the head-gated arm's: the two may differ in every size of
-#: :class:`SoftmaxSizes`), and a Mamba-2 state-space mixer.  A ``names.EXPERT_LAYER`` (one-sublayer layers only)
-#: holds the feed-forward arm instead
+#: :class:`SoftmaxSizes`), a Mamba-2 state-space mixer, a delta rule whose
+#: decay is a number a channel and latent attention.  A
+#: ``names.EXPERT_LAYER`` (one-sublayer layers only) holds the feed-forward
+#: arm instead
 MIXERS = {
     names.LINEAR: (names.LINEAR_ATTN, "linear_attn", GatedDeltaNet),
     names.FULL: (names.ATTN, "attn", _softmax(names.FULL)),
     names.WINDOW: (names.WINDOW_ATTN, "window_attn", _softmax(names.WINDOW)),
     names.STATE_SPACE: (names.SSM, "ssm", Mamba2Mixer),
+    names.CHANNEL_LINEAR: (names.KDA, "kda", KimiDeltaAttention),
+    names.LATENT: (names.LATENT_ATTN, "latent_attn", LatentAttention),
 }
 
 
@@ -883,7 +1048,12 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
     it keeps the router's logits and ``names.ROUTER_PICKS``, the picks and
     their scores (``tokens x top_k x 8`` bytes), so that the float32
     product, the sort over ``n_experts`` and the pick of the scores run
-    once a step.
+    once a step.  No mixer names anything of its own: a delta-rule
+    mixer's (a decay a head or a channel), a latent attention's and every
+    other mixer's forward runs again whole in the backward pass, and what
+    they hold meanwhile (the channel-gated scan's scaled keys, ``tokens x
+    heads x 4 x dk x itemsize`` bytes, 268 MB at 8,192 x 32 x 128 in bf16)
+    is the compiler's to place.
 
     A layer of one sublayer keeps its input and, where it is an expert
     layer, (a) the share's result ``names.EXPERT_OUT`` where the share
@@ -1025,7 +1195,10 @@ class HybridLM(nn.Module):
             linear_key_dim=z.linear_key_dim,
             linear_value_dim=z.linear_value_dim,
             linear_projections=z.linear_projections,
-            beta_scale=z.beta_scale,
+            beta_scale=z.beta_scale, linear_gate_rank=z.linear_gate_rank,
+            latent_rank=z.latent_rank,
+            latent_key_dims=list(z.latent_key_dims),
+            latent_value_dim=z.latent_value_dim,
             ssm_heads=[z.ssm_heads, z.ssm_heads_total or z.ssm_heads],
             ssm_groups=[z.ssm_groups, z.ssm_groups_total or z.ssm_groups],
             ssm_head_dim=z.ssm_head_dim, ssm_state=z.ssm_state,

@@ -27,6 +27,11 @@ each array; the kernels only ever see that block.
 
 - *head-major* — :func:`flash_attention`, :func:`flash_attention_with_lse`:
   ``q [b, h, s, d]``, ``k, v [b, h_kv, s, d]``, seen as ``[b·h, s, d]``.
+  The values may have a width of their own, ``v [b, h_kv, s, d_v]`` (latent
+  attention scores at 192 and reads values of 128): ``o``, ``do`` and ``dv``
+  are then ``d_v`` wide, the accumulators with them, and the softmax scale
+  is the queries' ``d ** -0.5``; every tile's last dimension is the
+  array's own, whole.
   K/V are arrays of their own, which is what ring attention rotates
   between chips; the pipeline schedules and any injected
   ``attention_fn`` use it too.
@@ -579,8 +584,10 @@ def _gqa_shape_check(q, k, v) -> int:
     natively, no K/V repeat)."""
     batch, heads, _, d = q.shape
     kv_heads = k.shape[1]
-    if k.shape != v.shape or k.shape[0] != batch or k.shape[3] != d:
-        raise ValueError(f"k/v shape {k.shape} incompatible with q {q.shape}")
+    if (k.shape[:3] != v.shape[:3] or k.shape[0] != batch
+            or k.shape[3] != d):
+        raise ValueError(f"k {k.shape} / v {v.shape} incompatible with q "
+                         f"{q.shape}: v alone may have a width of its own")
     if heads % kv_heads:
         raise ValueError(
             f"q heads {heads} must be a multiple of kv heads {kv_heads}"
@@ -607,7 +614,10 @@ class _Layout(NamedTuple):
     and where a head's tile lives in each array the three calls read and
     write (see :func:`_head_tile`).  ``o`` also places ``do`` and ``dq``,
     ``dkv`` places ``dk`` and ``dv``; the stats (``lse``, ``delta``) are
-    ``[batch·heads, 1, seq_q]`` in both."""
+    ``[batch·heads, 1, seq_q]`` in both.  ``d`` is the width of a head's
+    queries and keys (the scores', and ``dq``'s and ``dk``'s), ``d_v`` that
+    of its values (and of ``o``, ``do`` and ``dv``): the head-major entry
+    takes values of a width of their own, the packed one has ``d_v == d``."""
 
     batch: int
     heads: int
@@ -615,23 +625,29 @@ class _Layout(NamedTuple):
     seq_q: int
     seq_k: int
     d: int
+    d_v: int
     q: Callable
     k: Callable
     v: Callable
     o: Callable
     dkv: Callable
     o_shape: tuple
-    dkv_shape: tuple
+    dq_shape: tuple
+    dk_shape: tuple
+    dv_shape: tuple
 
 
 def _head_major_layout(q, k, v) -> _Layout:
-    """``[b, h, s, d]`` operands, each seen as ``[b·h, s, d]``."""
+    """``[b, h, s, d]`` operands, each seen as ``[b·h, s, d]``; ``v`` (and
+    with it ``o``) may be ``d_v`` wide where ``q`` and ``k`` are ``d``."""
     batch, heads, seq_q, d = q.shape
     kv_heads = _gqa_shape_check(q, k, v)
-    seq_k = k.shape[2]
+    seq_k, d_v = k.shape[2], v.shape[3]
     at = _head_tile(1)
-    return _Layout(batch, heads, kv_heads, seq_q, seq_k, d, at, at, at, at,
-                   at, (batch * heads, seq_q, d), (batch * kv_heads, seq_k, d))
+    return _Layout(batch, heads, kv_heads, seq_q, seq_k, d, d_v, at, at, at,
+                   at, at, (batch * heads, seq_q, d_v),
+                   (batch * heads, seq_q, d), (batch * kv_heads, seq_k, d),
+                   (batch * kv_heads, seq_k, d_v))
 
 
 def _packed_layout(qkv, heads: int, kv_heads: int) -> _Layout:
@@ -645,12 +661,12 @@ def _packed_layout(qkv, heads: int, kv_heads: int) -> _Layout:
             f"packed qkv {qkv.shape} does not hold {heads} q heads and "
             f"2 x {kv_heads} kv heads (kv heads must divide q heads)")
     d = cols // (heads + 2 * kv_heads)
+    wide, narrow = (batch, seq, heads * d), (batch, seq, kv_heads * d)
     return _Layout(
-        batch, heads, kv_heads, seq, seq, d,
+        batch, heads, kv_heads, seq, seq, d, d,
         _head_tile(heads), _head_tile(kv_heads, heads),
         _head_tile(kv_heads, heads + kv_heads), _head_tile(heads),
-        _head_tile(kv_heads),
-        (batch, seq, heads * d), (batch, seq, kv_heads * d))
+        _head_tile(kv_heads), wide, wide, narrow, narrow)
 
 
 def _blocks(lay: _Layout, block_q: int, block_k: int, interpret: bool):
@@ -675,20 +691,21 @@ def _blocks(lay: _Layout, block_q: int, block_k: int, interpret: bool):
 
 def _kv_innermost_specs(lay: _Layout, bq: int, bk: int, lo, hi):
     """``(q_tile, kv_tile, row_spec)`` for the ``(batch·heads, q tile, kv
-    tile)`` grids of the forward and dq calls: ``q_tile(at)`` /
-    ``kv_tile(at)`` make the ``(1, tile, d)`` spec of an array whose head
-    tiles lie where ``at`` says (dead KV tiles re-mapped, see
-    :func:`_band_kv_index`); ``row_spec`` is the stats' ``(1, 1, bq)``."""
+    tile)`` grids of the forward and dq calls: ``q_tile(at, width)`` /
+    ``kv_tile(at, width)`` make the ``(1, tile, width)`` spec of an array
+    whose head tiles lie where ``at`` says, ``lay.d`` wide unless told
+    (dead KV tiles re-mapped, see :func:`_band_kv_index`); ``row_spec`` is
+    the stats' ``(1, 1, bq)``."""
     kv_row = _kv_row_map(lay.heads, lay.kv_heads)
     band_j = _band_kv_index(bq, bk, lo, hi, lay.seq_k // bk)
 
-    def q_tile(at):
-        return pl.BlockSpec((1, bq, lay.d), lambda b, i, j: at(b, i),
+    def q_tile(at, width=lay.d):
+        return pl.BlockSpec((1, bq, width), lambda b, i, j: at(b, i),
                             memory_space=pltpu.VMEM)
 
-    def kv_tile(at):
+    def kv_tile(at, width=lay.d):
         return pl.BlockSpec(
-            (1, bk, lay.d),
+            (1, bk, width),
             lambda b, i, j: at(kv_row(b), band_j(b, i, j)[1]),
             memory_space=pltpu.VMEM)
 
@@ -703,7 +720,7 @@ def _flash_forward(q, k, v, lay: _Layout, *, causal, block_q, block_k,
     entry passes one array three times): ``(o, lse)`` with ``o`` of
     ``lay.o_shape`` and ``lse`` ``[batch·heads, 1, seq_q]``."""
     lo, hi = _normalize_band(causal, window)
-    seq_q, seq_k, d = lay.seq_q, lay.seq_k, lay.d
+    seq_q, seq_k, d, d_v = lay.seq_q, lay.seq_k, lay.d, lay.d_v
     bq, bk = _blocks(lay, block_q, block_k, interpret)
     scale = d ** -0.5
     bh = lay.batch * lay.heads
@@ -719,9 +736,9 @@ def _flash_forward(q, k, v, lay: _Layout, *, causal, block_q, block_k,
     # exp per score entry; causal does half the score work).
     work = bh * _band_live_pairs(seq_q, seq_k, lo, hi)
     cost = pl.CostEstimate(
-        flops=int(4 * work * d),
+        flops=int(2 * work * (d + d_v)),
         transcendentals=int(work),
-        bytes_accessed=int(2 * bh * seq_q * d + 2 * bh_kv * seq_k * d)
+        bytes_accessed=int((bh * seq_q + bh_kv * seq_k) * (d + d_v))
         * q.dtype.itemsize,
     )
     return pl.pallas_call(
@@ -742,12 +759,12 @@ def _flash_forward(q, k, v, lay: _Layout, *, causal, block_q, block_k,
         ],
         grid=(bh, seq_q // bq,
               band_grid(seq_q // bq, seq_k // bk, bq, bk, lo, hi)[0]),
-        in_specs=[q_tile(lay.q), kv_tile(lay.k), kv_tile(lay.v)],
-        out_specs=[q_tile(lay.o), row_spec],
+        in_specs=[q_tile(lay.q), kv_tile(lay.k), kv_tile(lay.v, d_v)],
+        out_specs=[q_tile(lay.o, d_v), row_spec],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),   # m (running row max)
             pltpu.VMEM((bq, 1), jnp.float32),   # l (running normalizer)
-            pltpu.VMEM((bq, d), jnp.float32),   # acc (unnormalized out)
+            pltpu.VMEM((bq, d_v), jnp.float32),  # acc (unnormalized out)
         ],
         compiler_params=pltpu.CompilerParams(
             # bh and q rows are independent; only the KV sweep accumulates.
@@ -762,13 +779,14 @@ def _flash_forward(q, k, v, lay: _Layout, *, causal, block_q, block_k,
 def _head_major_forward(q, k, v, *, causal, block_q, block_k, interpret,
                         out_f32, window, sub):
     lay = _head_major_layout(q, k, v)
-    batch, heads, seq_q, d = q.shape
+    batch, heads, seq_q, _ = q.shape
     out, lse = _flash_forward(
-        q.reshape(lay.o_shape), k.reshape(lay.dkv_shape),
-        v.reshape(lay.dkv_shape), lay, causal=causal, block_q=block_q,
+        q.reshape(lay.dq_shape), k.reshape(lay.dk_shape),
+        v.reshape(lay.dv_shape), lay, causal=causal, block_q=block_q,
         block_k=block_k, interpret=interpret, out_f32=out_f32, window=window,
         sub=sub)
-    return out.reshape(batch, heads, seq_q, d), lse.reshape(batch, heads, seq_q)
+    return (out.reshape(batch, heads, seq_q, lay.d_v),
+            lse.reshape(batch, heads, seq_q))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
@@ -882,7 +900,7 @@ def blockwise_attention(
 
     m0 = jnp.full(q.shape[:-1], _MASK_VALUE, jnp.float32)
     l0 = jnp.zeros(q.shape[:-1], jnp.float32)
-    o0 = jnp.zeros(q.shape, jnp.float32)
+    o0 = jnp.zeros(q.shape[:-1] + v.shape[-1:], jnp.float32)
     (m, l, o), _ = lax.scan(
         body, (m0, l0, o0), (jnp.arange(num_kv), kb, vb)
     )
@@ -990,11 +1008,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
                     block_q, block_k, interpret, window=None, sub=0):
     """The two backward calls over operands laid out as ``lay`` says:
-    ``(dq, dk, dv)`` with ``dq`` of ``lay.o_shape``, ``dk`` / ``dv`` of
-    ``lay.dkv_shape``; ``lse`` / ``delta`` are ``[batch·heads, 1, seq_q]``."""
+    ``(dq, dk, dv)`` of ``lay.dq_shape``, ``lay.dk_shape`` and
+    ``lay.dv_shape``; ``lse`` / ``delta`` are ``[batch·heads, 1, seq_q]``."""
     lo, hi = _normalize_band(causal, window)
     heads, kv_heads = lay.heads, lay.kv_heads
-    seq_q, seq_k, d = lay.seq_q, lay.seq_k, lay.d
+    seq_q, seq_k, d, d_v = lay.seq_q, lay.seq_k, lay.d, lay.d_v
     group = heads // kv_heads
     bq, bk = _blocks(lay, block_q, block_k, interpret)
     sub = diag_sub(bq, bk, lo, hi, sub)
@@ -1007,26 +1025,29 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
     kv_steps, q_steps = band_grid(nq, nkv, bq, bk, lo, hi)[:2]
 
     work = bh * _band_live_pairs(seq_q, seq_k, lo, hi)
-    q_bytes = bh * seq_q * d * q.dtype.itemsize
-    kv_bytes = bh_kv * seq_k * d * q.dtype.itemsize
-    in_bytes = int(2 * q_bytes + 2 * kv_bytes + 2 * bh * seq_q * 4)
+    # q (dq), o (do); k (dk), v (dv)
+    q_bytes, o_bytes = (bh * seq_q * w * q.dtype.itemsize for w in (d, d_v))
+    k_bytes, v_bytes = (bh_kv * seq_k * w * q.dtype.itemsize
+                        for w in (d, d_v))
+    in_bytes = int(q_bytes + o_bytes + k_bytes + v_bytes
+                   + 2 * bh * seq_q * 4)
 
     q_tile, kv_tile, row_spec = _kv_innermost_specs(lay, bq, bk, lo, hi)
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
                           lo=lo, hi=hi, sub=sub, scale=scale, nkv=nkv),
-        out_shape=jax.ShapeDtypeStruct(lay.o_shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(lay.dq_shape, q.dtype),
         grid=(bh, nq, kv_steps),
-        in_specs=[q_tile(lay.q), kv_tile(lay.k), kv_tile(lay.v),
-                  q_tile(lay.o), row_spec, row_spec],
+        in_specs=[q_tile(lay.q), kv_tile(lay.k), kv_tile(lay.v, d_v),
+                  q_tile(lay.o, d_v), row_spec, row_spec],
         out_specs=q_tile(lay.o),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=int(6 * work * d), transcendentals=int(work),
+            flops=int(2 * work * (2 * d + d_v)), transcendentals=int(work),
             bytes_accessed=in_bytes + int(q_bytes),
         ),
         **names.kernel(names.FLASH_BWD_DQ),
@@ -1058,8 +1079,8 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
             qi = jnp.maximum(qi, (j * bk + lo) // bq)
         return row, jnp.clip(qi, 0, nq - 1)
 
-    def q_tile_t(at):
-        return pl.BlockSpec((1, bq, d),
+    def q_tile_t(at, width=d):
+        return pl.BlockSpec((1, bq, width),
                             lambda b, j, gi: at(*q_index(b, j, gi)),
                             memory_space=pltpu.VMEM)
 
@@ -1070,8 +1091,8 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
     row_spec_t = pl.BlockSpec((1, 1, bq), row_index_t,
                               memory_space=pltpu.VMEM)
 
-    def kv_tile_t(at):
-        return pl.BlockSpec((1, bk, d), lambda b, j, gi: at(b, j),
+    def kv_tile_t(at, width=d):
+        return pl.BlockSpec((1, bk, width), lambda b, j, gi: at(b, j),
                             memory_space=pltpu.VMEM)
 
     dk, dv = pl.pallas_call(
@@ -1079,23 +1100,23 @@ def _flash_backward(q, k, v, do, lse, delta, lay: _Layout, *, causal,
                           lo=lo, hi=hi, sub=sub, scale=scale,
                           q_steps=q_steps, nq=nq),
         out_shape=[
-            jax.ShapeDtypeStruct(lay.dkv_shape, k.dtype),
-            jax.ShapeDtypeStruct(lay.dkv_shape, v.dtype),
+            jax.ShapeDtypeStruct(lay.dk_shape, k.dtype),
+            jax.ShapeDtypeStruct(lay.dv_shape, v.dtype),
         ],
         grid=(bh_kv, nkv, q_steps * group),
-        in_specs=[q_tile_t(lay.q), kv_tile_t(lay.k), kv_tile_t(lay.v),
-                  q_tile_t(lay.o), row_spec_t, row_spec_t],
-        out_specs=[kv_tile_t(lay.dkv), kv_tile_t(lay.dkv)],
+        in_specs=[q_tile_t(lay.q), kv_tile_t(lay.k), kv_tile_t(lay.v, d_v),
+                  q_tile_t(lay.o, d_v), row_spec_t, row_spec_t],
+        out_specs=[kv_tile_t(lay.dkv), kv_tile_t(lay.dkv, d_v)],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         cost_estimate=pl.CostEstimate(
-            flops=int(8 * work * d), transcendentals=int(work),
-            bytes_accessed=in_bytes + int(2 * kv_bytes),
+            flops=int(4 * work * (d + d_v)), transcendentals=int(work),
+            bytes_accessed=in_bytes + int(k_bytes + v_bytes),
         ),
         **names.kernel(names.FLASH_BWD_DKV),
         interpret=interpret,
@@ -1125,8 +1146,8 @@ def _bwd(causal, block_q, block_k, interpret, out_f32, window, sub, residuals,
     lay = _head_major_layout(q, k, v)
     stats = (lay.batch * lay.heads, 1, lay.seq_q)
     dq, dk, dv = _flash_backward(
-        q.reshape(lay.o_shape), k.reshape(lay.dkv_shape),
-        v.reshape(lay.dkv_shape), g_out.reshape(lay.o_shape),
+        q.reshape(lay.dq_shape), k.reshape(lay.dk_shape),
+        v.reshape(lay.dv_shape), g_out.reshape(lay.o_shape),
         lse.reshape(stats), delta.reshape(stats), lay, causal=causal,
         block_q=block_q, block_k=block_k, interpret=interpret, window=window,
         sub=sub,

@@ -1,11 +1,18 @@
 """Gated delta-rule linear attention, computed in chunks.
 
-The recurrence (Yang et al. 2024, "Gated Delta Networks"), per head, with a
-state ``S [dk, dv]`` that starts at zero::
+The recurrence, per head, with a state ``S [dk, dv]`` that starts at zero,
+in two forms that differ in what forgets::
 
-    S <- exp(g_t) * S
+    S <- exp(g_t) * S          a decay a head: g_t one number
+                               (Yang et al. 2024, "Gated Delta Networks")
+    S <- Diag(exp(g_t)) S      a decay a CHANNEL: g_t [dk], a number a row
+                               of S (Kimi Team 2025, "Kimi Linear")
     S <- S + k_t (x) (beta_t * (v_t - S^T k_t))
     o_t = S^T q_t
+
+``g [b, s, h]`` asks for the first and ``g [b, s, h, dk]`` for the second;
+a decay a channel that is the same over a head's channels IS the first
+(held by a test to float32 rounding).
 
 :func:`gated_delta_rule_reference` is that loop as written, a position at a
 time.  :func:`chunked_gated_delta_rule` gives the same result from matrix
@@ -25,9 +32,38 @@ products over chunks of ``chunk`` positions:
   ``v' = u - w S``, ``o = (q exp(G)) S + (q k^T . decay) v'``,
   ``S <- exp(G_last) S + (k exp(G_last - G))^T v'``.
 
-Every decay is ``exp`` of a difference of cumulative sums that is taken
-before the ``exp`` and is never positive, so nothing overflows however
-strongly a head forgets.
+With a decay a head every decay is ``exp`` of a difference of cumulative
+sums that is taken before the ``exp`` and is never positive, so nothing
+overflows however strongly a head forgets.
+
+**A decay a channel** does not factor out of the contraction over ``dk``:
+``A[i, j] = beta_i sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])`` (``G [c, dk]``
+now), and ``q k^T . decay`` likewise.  The products are taken on operands
+scaled by ``exp(G - G_r)`` and ``exp(G_r - G)`` against a reference
+position ``r``, and a single ``r`` a chunk would put ``exp`` of a chunk's
+whole decay, with the sign that overflows, on one of them.  So a chunk's
+rows go by sub-blocks of ``SUB_BLOCK`` = 16 (:func:`_pairs_by_channel`),
+each with its first row as ITS reference: a row ``i`` of the sub-block is
+scaled by ``exp(G_i - G_r)``, never positive; the keys of EARLIER
+sub-blocks by ``exp(G_r - G_j)``, never positive either; only the keys of
+the sub-block itself (the diagonal sub-blocks, where both signs meet) take
+a positive exponent, at most the sub-block's own span, ``15`` positions'
+decay; keys of later sub-blocks are masked before the ``exp``.  One batched
+product ``[4, 16, dk] x [4, 64, dk]`` a chunk gives the chunk's ``[64, 64]``
+(the multiply-adds of the per-head form's one product), for ``A`` and for
+``q k^T`` on the same scaled keys; ``w``, the scan's ``q exp(G)``, ``k
+exp(G_last - G)`` and the state's ``exp(G_last)`` (a number a ROW of ``S``)
+are elementwise and never positive.  Float32 (and bf16) hold ``e^88``: with
+sub-blocks of 16 no exponent passes that for a decay down to ``e^-5.8`` a
+position a channel.  Beyond it the gate is CLAMPED: the per-channel form
+computes the recurrence of ``max(g, -GATE_FLOOR)``, ``GATE_FLOOR`` = 5 (the
+largest exponent is then 75), so a channel that would forget to under
+``e^-5`` = 0.7% a position forgets to 0.7% instead (after two positions
+4.5e-5 is left where less should be; the gradient through a clamped gate is
+zero).  That is a departure from the recurrence as written, stated where a
+model's gate can reach it (``-exp(A_log) * softplus(.)`` passes 5 only for
+``A_log`` over 1.6 at a softplus of 1); narrower sub-blocks would move the
+bound and were not needed.  The per-head path traces to the program it was.
 
 Differentiable by JAX's own rules: the backward pass is autodiff through
 the chunked form, with the scan's body under ``jax.checkpoint`` so that
@@ -65,7 +101,12 @@ is still enough.
 
 Tested at equal key and value widths (16 / 16 here, 128 / 128 compiled for
 the chip) and at unequal ones (12 / 24 here, 96 / 192 compiled), ``beta``
-drawn from ``(0, 1)`` and from ``(0, 2)``.
+drawn from ``(0, 1)`` and from ``(0, 2)``; a decay a channel at 16 / 16 and
+8 / 16 here (128 / 128 compiled), against the per-position loop forward and
+in every gradient, at mild decays, AT the floor (``g`` in ``[-5, -4.5]``: a
+sub-block's span of ``e^-75``), over one chunk and over several, and
+constant over channels against the per-head path
+(``tests/test_kimi_linear.py``).
 """
 
 from __future__ import annotations
@@ -77,6 +118,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from tpudist.telemetry import names
+
+
+#: a chunk's rows go by sub-blocks this long where the decay is a number a
+#: channel, and the gate is held above ``-GATE_FLOOR`` a position there: the
+#: largest exponent taken is a sub-block's span, ``(SUB_BLOCK - 1) *
+#: GATE_FLOOR`` = 75, and float32 (bf16 too) holds ``e^88``
+SUB_BLOCK = 16
+GATE_FLOOR = 5.0
 
 
 def _precision(dtype):
@@ -144,11 +193,48 @@ def _unit_lower_inverse_bwd(precision, base, inv, d_inv):
 _unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
+def _pairs_by_channel(k, cum, sub: int, mm, dtype):
+    """What stands in for ``mm(x, k) * decay`` where the decay is a number a
+    channel: ``pairs(x)[i, j] = sum_d x_i[d] k_j[d] exp(G_i[d] - G_j[d])``
+    over the pairs ``j <= i`` of a chunk (entries above the diagonal hold
+    what the products leave there: finite, and masked by the caller), for
+    ``k [n, b, h, c, dk]`` and ``cum`` its ``G``, as matrix products: the
+    chunk goes by sub-blocks of ``sub`` rows, each against ONE reference
+    position of its own, its first row ``r``: ``x_i exp(G_i - G_r)`` (the
+    exponent is never positive: ``i >= r``) against ``k_j exp(G_r - G_j)``,
+    whose exponent is never positive for a ``j`` of an earlier sub-block,
+    at most the sub-block's own span for one of the same sub-block, and
+    ``-inf`` (masked before the ``exp``) for one of a later sub-block,
+    whose pairs are all dead."""
+    n, b, h, c, dk = k.shape
+    m = c // sub
+    blocks = cum.reshape(n, b, h, m, sub, dk)
+    ref = blocks[:, :, :, :, 0]                          # G_r, [n, b, h, m, dk]
+    ahead = jnp.exp(blocks - ref[:, :, :, :, None]).reshape(n, b, h, c, dk)
+    # row block I reads the keys up to its own last row
+    reach = jnp.arange(c)[None, :] < (jnp.arange(m)[:, None] + 1) * sub
+    behind = jnp.exp(jnp.where(
+        reach[:, :, None],
+        ref[:, :, :, :, None] - cum[:, :, :, None], -jnp.inf))
+    k_behind = (k.astype(jnp.float32)[:, :, :, None] * behind).astype(dtype)
+
+    def pairs(x):
+        x = (x.astype(jnp.float32) * ahead).astype(dtype)
+        return mm("nbhmid,nbhmjd->nbhmij",
+                  x.reshape(n, b, h, m, sub, dk), k_behind).reshape(
+                      n, b, h, c, c)
+
+    return pairs
+
+
 def chunked_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
                              beta_max: float = 1.0):
     """``q, k [b, s, h, dk]``, ``v [b, s, h, dv]`` (``dk`` and ``dv`` need
-    not be equal), ``g, beta [b, s, h]`` -> ``o [b, s, h, dv]`` in ``v``'s
-    dtype.  ``g <= 0`` is the log of the per-position decay, ``beta`` in
+    not be equal), ``beta [b, s, h]`` and ``g [b, s, h]`` (a decay a head)
+    or ``g [b, s, h, dk]`` (a decay a channel: told apart by the array's
+    rank) -> ``o [b, s, h, dv]`` in ``v``'s dtype.  ``g <= 0`` is the log of
+    the per-position decay (a decay a channel is computed for
+    ``max(g, -GATE_FLOOR)``: the module's docstring), ``beta`` in
     ``[0, beta_max]`` the write strength, ``beta_max`` at most 2 (the
     module's docstring says what it decides); ``q`` and ``k`` come
     normalised and scaled as the caller wants them.  ``s`` must be a whole
@@ -161,6 +247,10 @@ def chunked_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
     if s % chunk:
         raise ValueError(f"{s} positions are not a whole number of chunks "
                          f"of {chunk}")
+    by_channel = g.ndim == 4
+    if by_channel and chunk % SUB_BLOCK:
+        raise ValueError(f"a chunk of {chunk} is not a whole number of "
+                         f"sub-blocks of {SUB_BLOCK}")
     n = s // chunk
     dtype = v.dtype
     precision = _precision(dtype)
@@ -184,16 +274,25 @@ def chunked_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
         q, k, v = chunks(q), chunks(k), chunks(v)
         g = chunks(g.astype(jnp.float32))
         beta = chunks(beta.astype(jnp.float32))
-        cum = jnp.cumsum(g, axis=-1)                     # G, [n, b, h, c]
-        last = cum[..., -1:]
+        if by_channel:
+            g = jnp.maximum(g, -GATE_FLOOR)
+        cum = jnp.cumsum(g, axis=3)            # G, [n, b, h, c] or [.., dk]
+        last = cum[:, :, :, -1:]
         lower = jnp.tril(jnp.ones((chunk, chunk), bool))
-        # exp(G_i - G_j) for j <= i and 0 above the diagonal: the difference
-        # is masked before the exp, so no positive number is exponentiated
-        decay = jnp.exp(jnp.where(
-            lower, cum[..., :, None] - cum[..., None, :], -jnp.inf))
-        grow = jnp.exp(cum)[..., None]
+        if by_channel:
+            pairs = _pairs_by_channel(k, cum, SUB_BLOCK, mm, dtype)
+            rows = lambda factor: factor         # [n, b, h, c, dk] as it is
+        else:
+            # exp(G_i - G_j) for j <= i and 0 above the diagonal: the
+            # difference is masked before the exp, so no positive number is
+            # exponentiated
+            decay = jnp.exp(jnp.where(
+                lower, cum[..., :, None] - cum[..., None, :], -jnp.inf))
+            pairs = lambda x: mm("nbhid,nbhjd->nbhij", x, k) * decay
+            rows = lambda factor: factor[..., None]
+        grow = rows(jnp.exp(cum))
         k_beta = (k.astype(jnp.float32) * beta[..., None]).astype(dtype)
-        a = mm("nbhid,nbhjd->nbhij", k_beta, k) * decay
+        a = pairs(k_beta)
         a = jnp.where(jnp.tril(lower, -1), a, 0.0)
         t = _unit_lower_inverse(a, inverse_precision,
                                 _inverse_base(beta_max)).astype(dtype)
@@ -202,11 +301,17 @@ def chunked_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
                * beta[..., None]).astype(dtype)
         w = mm("nbhij,nbhjd->nbhid", t,
                k_beta.astype(jnp.float32) * grow).astype(dtype)
-        intra = (mm("nbhid,nbhjd->nbhij", q, k) * decay).astype(dtype)
+        intra = pairs(q)
+        if by_channel:   # the products' own entries above the diagonal
+            intra = jnp.where(lower, intra, 0.0)
+        intra = intra.astype(dtype)
         q_in = (q.astype(jnp.float32) * grow).astype(dtype)
         k_out = (k.astype(jnp.float32)
-                 * jnp.exp(last - cum)[..., None]).astype(dtype)
-        keep = jnp.exp(last)[..., None]                  # [n, b, h, 1, 1]
+                 * rows(jnp.exp(last - cum))).astype(dtype)
+        # what is left of the carried state behind the chunk: a number a
+        # head [n, b, h, 1, 1], or one a row of the state [n, b, h, dk, 1]
+        keep = (jnp.swapaxes(jnp.exp(last), -1, -2) if by_channel
+                else jnp.exp(last)[..., None])
 
         @jax.checkpoint
         def body(state, xs):
@@ -225,15 +330,19 @@ def chunked_gated_delta_rule(q, k, v, g, beta, *, chunk: int = 64,
 
 def gated_delta_rule_reference(q, k, v, g, beta):
     """The recurrence as written, one position at a time, in float32 at the
-    highest matmul precision: what the chunked form is tested against."""
+    highest matmul precision: what the chunked form is tested against.
+    ``g [b, s, h]`` (a decay a head) or ``g [b, s, h, dk]`` (a decay a
+    channel, as it is: nothing is clamped here)."""
     f32 = lambda x: x.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
     b, s, h, dk = q.shape
     hi = lax.Precision.HIGHEST
+    if g.ndim == 3:
+        g = g[..., None]
 
     def step(state, xs):
         q_t, k_t, v_t, g_t, beta_t = xs                  # [b, h, ...]
-        state = state * jnp.exp(g_t)[..., None, None]
+        state = state * jnp.exp(g_t)[..., None]
         read = jnp.einsum("bhde,bhd->bhe", state, k_t, precision=hi)
         write = beta_t[..., None] * (v_t - read)
         state = state + k_t[..., :, None] * write[..., None, :]

@@ -88,9 +88,25 @@ SSM_NORM = "ssm_norm"
 # product into the attention's output a head) nests in either
 WINDOW_ATTN = "window_attn"
 HEAD_GATE = "head_gate"
+# ``kda`` is a whole delta-rule mixer whose decay is a number a CHANNEL (a
+# layer of kind CHANNEL_LINEAR: norm, projections, convolution, gates, the
+# scan, output projection), as ``linear_attn`` is one whose decay is a number
+# a head; ``delta_rule`` nests in it as there, and ``kda_gate`` beside it:
+# what such a mixer adds to a delta-rule mixer, the forget gate's low-rank
+# projections with their softplus and ``exp(A_log)``, and the output gate's
+# low-rank projections with their sigmoid and its product into the normed
+# output.  ``latent_attn`` is a whole latent-attention mixer (a layer of kind
+# LATENT); ``latent_kv`` nests in it: what latent attention adds round the
+# flash kernels, the projection into the latent and the shared key, the
+# latent's norm, the projection out of it and the assembly of the heads' keys
+KDA = "kda"
+KDA_GATE = "kda_gate"
+LATENT_ATTN = "latent_attn"
+LATENT_KV = "latent_kv"
 SCOPES = (EMBED, ATTN, MLP, HEAD, LOSS, OPTIMIZER, GRAD_ACCUM, LINEAR_ATTN,
           DELTA_RULE, MOE, EXPERTS, SHARED_EXPERT, MOE_COMBINE, SSM, SSD_SCAN,
-          LATENT_PROJ, WINDOW_ATTN, HEAD_GATE, SSM_CONV, SSM_NORM)
+          LATENT_PROJ, WINDOW_ATTN, HEAD_GATE, SSM_CONV, SSM_NORM, KDA,
+          KDA_GATE, LATENT_ATTN, LATENT_KV)
 #: what JAX itself writes round the scopes of a transposed (backward) op
 BACKWARD_MARK = "transpose("
 
@@ -203,7 +219,13 @@ COMPILE_CACHE_MISS = "compile_cache_miss"
 # entries computed over live pairs and, of a windowed call, ``window=``,
 # ``tiles=`` [block_q, block_k], ``grid_kv=`` the length of the key axis of
 # the forward and dq grids (the longest run of live key tiles a query tile
-# has) and ``live_steps_share=`` live tiles over a head's grid steps
+# has) and ``live_steps_share=`` live tiles over a head's grid steps.
+# tpudist/models/hybrid.py, once a trace of each latent-attention call site:
+# ``layout=`` HEAD_MAJOR with its ``reason=`` (a head's 192 dims are not
+# whole lane tiles: WHY_DH), ``kernel=`` what the dispatch's table routes the
+# call to, ``qk_dim=`` the width the scores are taken at (a head's own key
+# part + the part all heads share), ``v_dim=`` the values' own width (the
+# flash kernels take it as it is: nothing is padded) and ``latent_rank=``
 ATTN_LAYOUT = "attn_layout"
 PACKED = "packed"
 HEAD_MAJOR = "head_major"
@@ -211,6 +233,7 @@ WHY_DH = "dh"                # head_dim is not a multiple of 128 lanes
 WHY_SEQ = "seq"              # too short for the flash kernels, or no tile fits
 WHY_PLATFORM = "platform"    # not a TPU
 WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
+WHY_WIDTHS = "widths"        # values narrower than keys: the packed layout has one width
 # tpudist/models/hybrid.py, once a trace of the decoder: ``kinds=`` the layer
 # kinds in order (the keys of models/hybrid.py::MIXERS, or EXPERT_LAYER) and
 # ``one_sublayer=`` whether a layer is one sublayer (a mixer OR the
@@ -231,7 +254,10 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # delta-rule mixers ``linear_heads=`` [value heads held, in all],
 # ``linear_key_heads=``, ``linear_key_dim=``, ``linear_value_dim=``,
 # ``linear_projections=`` FUSED / SEPARATE, ``beta_scale=`` (the write
-# strength is that times a sigmoid); ``heads_axis=`` the mapped axis the
+# strength is that times a sigmoid), ``linear_gate_rank=`` the rank of a
+# CHANNEL_LINEAR mixer's two gates; of the LATENT mixers ``latent_rank=``,
+# ``latent_key_dims=`` [a head's own, the part all heads share] and
+# ``latent_value_dim=`` (its heads are ``attn_heads=``); ``heads_axis=`` the mapped axis the
 # members that share a layer by heads reduce over, or None;
 # ``feed_forward=`` EXPERT_SHARE / DENSE_FFN and ``feed_forwards=`` the arm
 # a layer; ``norm=`` ZERO_CENTRED / PLAIN
@@ -260,7 +286,11 @@ WHY_CUSTOM_FN = "custom_fn"  # an injected attention_fn without a packed route
 # SIGMOID_BIAS,
 # ``scale=`` what
 # the picks' renormalised weights are multiplied by, ``width=`` the rows'
-# width (an expert layer's latent width where it has one)
+# width (an expert layer's latent width where it has one).  ``mixer_layout``
+# is also said once a trace of each CHANNEL_LINEAR call site: ``decay=``
+# CHANNEL, ``heads=`` [held, in all], ``dk=``, ``dv=``, ``chunk=``,
+# ``sub_block=`` the rows of the chunk's sub-blocks and ``gate_floor=`` what
+# the gate is held above (tpudist/ops/gated_delta.py), ``gate_rank=``
 MIXER_LAYOUT = "mixer_layout"
 MOE_LAYOUT = "moe_layout"
 HELD = "held"
@@ -270,6 +300,9 @@ LINEAR = "linear_attention"
 FULL = "full_attention"
 WINDOW = "sliding_attention"  # softmax attention inside a sliding window
 STATE_SPACE = "state_space"  # a Mamba-2 mixer (tpudist/ops/ssd.py)
+CHANNEL_LINEAR = "channel_gated_linear_attention"  # a delta rule, a decay a channel
+LATENT = "latent_attention"  # softmax over keys and values out of a latent
+CHANNEL = "channel"
 EXPERT_LAYER = "expert_layer"  # one-sublayer layers only: the feed-forward arm
 GATED_ATTN = "gated"         # per-head q/k norms, an output gate, part rotary
 NORMED_ATTN = "normed"       # one q/k norm statistic over all heads, no gate
