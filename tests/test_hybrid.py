@@ -721,6 +721,111 @@ def _dense_products(jaxpr) -> int:
     return sum(p == "dot_general" for p in _primitives(jaxpr))
 
 
+@pytest.mark.parametrize("kind", [*hybrid.MIXERS, None], ids=str)
+def test_a_delta_rule_layer_keeps_its_chunks_inverse_and_no_other(kind):
+    """Since PR 48 a layer whose mixer scans by the delta rule (a decay a
+    head or a channel) keeps ``DELTA_INVERSE`` behind whatever its
+    feed-forward arm keeps: ``tokens x heads x 64 x 4`` bytes, float32
+    whatever the compute dtype (2 heads: 256 bf16 columns).  Every other
+    kind of layer, and a call that names no kind, keeps what it kept."""
+    delta = kind in (names.LINEAR, names.CHANNEL_LINEAR)
+    assert delta == (kind in hybrid.DELTA_RULE_KINDS)
+    for arms in (dict(), dict(feed_forward=names.DENSE_FFN),
+                 dict(feed_forward=names.DENSE_FFN, ffn_products_kept=False),
+                 dict(one_sublayer=True),
+                 dict(one_sublayer=True, feed_forward=names.DENSE_FFN)):
+        z = _sizes(**arms)
+        keeps, arm = hybrid.remat_keeps(z, kind), hybrid.remat_keeps(z)
+        assert keeps == arm + ((names.DELTA_INVERSE,) if delta else ())
+        for dtype in (jnp.bfloat16, jnp.float32):
+            assert hybrid.kept_bytes(keeps, z, 64, dtype) == hybrid.kept_bytes(
+                arm, z, 64, dtype) + (64 * 2 * 64 * 4 if delta else 0)
+
+
+def _delta_rule_call(decay: str, beta_max: float, dk: int, dv: int):
+    """``(args, module class)``: float32 operands over two chunks of two
+    heads, and a module that is the scan alone, for ``remat_module``."""
+    import flax.linen as nn
+
+    q, k, v, g, beta = scan_inputs(2, jnp.float32, heads=2, dk=dk, dv=dv)
+    if decay == names.CHANNEL:
+        g = g[..., None] * jax.random.uniform(
+            jax.random.PRNGKey(5), (dk,), minval=0.5, maxval=1.0)
+
+    class Scan(nn.Module):
+        @nn.compact
+        def __call__(self, *args):
+            return chunked_gated_delta_rule(*args, beta_max=beta_max)
+
+    return [q, k, v, g, beta_max * beta], Scan
+
+
+@pytest.mark.parametrize("kind, decay, beta_max, dk, dv, solved", [
+    (names.LINEAR, "head", 1.0, 16, 16, 10),
+    (names.LINEAR, "head", 2.0, 12, 24, 10),
+    (names.CHANNEL_LINEAR, names.CHANNEL, 1.0, 16, 16, 11)],
+    ids=["a_head_beta_1", "a_head_beta_2_unequal_widths", "a_channel"])
+def test_a_rematerialised_delta_rule_solves_for_its_inverse_once(
+        kind, decay, beta_max, dk, dv, solved):
+    """The scan under ``remat_module(..., "nothing", remat_keeps(...))``: with
+    ``DELTA_INVERSE`` among the names the gradient holds the inverse's ten
+    products fewer (by halves from a base of 16 rows or of 4: two a level
+    and two a doubling of the base's span, ten either way) than under the
+    same policy without the name: the rematerialised forward no longer
+    solves.  A decay a channel loses an eleventh, the product that makes
+    ``A``: there ``A`` is that product masked and nothing else reads it,
+    where a decay a head multiplies it by the decays, whose gradient wants
+    it again.  The gradients are the same bits either way: the kept ``T`` is
+    the float32 one the rematerialised forward computed."""
+    from tpudist.models.transformer import remat_module
+
+    args, scan = _delta_rule_call(decay, beta_max, dk, dv)
+    keeps = hybrid.remat_keeps(_sizes(), kind)
+    assert keeps[-1] == names.DELTA_INVERSE
+
+    def gradient(keep):
+        module = remat_module(scan, "nothing", keep)()
+        return jax.grad(lambda *a: jnp.sum(jnp.sin(module.apply({}, *a))),
+                        argnums=(0, 1, 2, 3, 4))
+
+    kept, solved_again = gradient(keeps), gradient(keeps[:-1])
+    count = lambda fn: _dense_products(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert count(solved_again) - count(kept) == solved
+    assert str(jax.make_jaxpr(kept)(*args)).count(
+        f"name={names.DELTA_INVERSE}") >= 1
+    for name, got, want in zip("q k v g beta".split(), jax.jit(kept)(*args),
+                               jax.jit(solved_again)(*args)):
+        assert bool(jnp.any(want != 0)) and bool(jnp.all(got == want)), name
+
+
+@pytest.mark.parametrize("decay, beta_max", [
+    ("head", 1.0), ("head", 2.0), (names.CHANNEL, 1.0)],
+    ids=["a_head_beta_1", "a_head_beta_2", "a_channel"])
+def test_outside_a_policy_the_inverses_name_is_an_identity(decay, beta_max,
+                                                           monkeypatch):
+    """No policy names it: the call lowers to the text it lowers to with the
+    name taken out (a name lowers to nothing), forward and gradient, and
+    under a rematerialisation that names nothing the inverse is solved for
+    again as it was."""
+    from tpudist.ops import gated_delta
+
+    args, _ = _delta_rule_call(decay, beta_max, 16, 16)
+
+    def read():
+        # (new functions a reading: nothing traced before is found again)
+        call = lambda *a: chunked_gated_delta_rule(*a, beta_max=beta_max)
+        loss = lambda fn: jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
+                                   argnums=(0, 1, 2, 3, 4))
+        again = loss(jax.checkpoint(call))
+        return ([jax.jit(fn).lower(*args).as_text()
+                 for fn in (call, loss(call), again)],
+                _dense_products(jax.make_jaxpr(again)(*args).jaxpr))
+
+    named = read()
+    monkeypatch.setattr(gated_delta, "checkpoint_name", lambda x, name: x)
+    assert named == read()
+
+
 @pytest.mark.parametrize("arms", [
     dict(expert_fn=names.GATED_SILU),
     dict(expert_fn=names.GATED_SILU, latent_width=24),
@@ -758,15 +863,17 @@ def test_a_rematerialised_expert_layer_runs_its_dense_products_once(
         assert str(jaxpr).count(f"name={name}") >= 2
     kept = jax.jit(jax.grad(program(True)))(params)
     plain = jax.jit(jax.grad(program(False)))(params)
-    monkeypatch.setattr(hybrid, "remat_keeps", lambda z: (names.EXPERT_OUT,))
+    monkeypatch.setattr(hybrid, "remat_keeps",
+                        lambda z, kind: (names.EXPERT_OUT,))
     before = _dense_products(
         jax.make_jaxpr(jax.grad(program(True)))(params).jaxpr)
     assert before - _dense_products(jaxpr.jaxpr) == 2 * len(new)
     recomputed = jax.jit(jax.grad(program(True)))(params)
     for name in new:
         # one at a time: each name is one product a layer
-        monkeypatch.setattr(hybrid, "remat_keeps",
-                            lambda z, name=name: (names.EXPERT_OUT, name))
+        monkeypatch.setattr(
+            hybrid, "remat_keeps",
+            lambda z, kind, name=name: (names.EXPERT_OUT, name))
         one = jax.make_jaxpr(jax.grad(program(True)))(params)
         assert _dense_products(one.jaxpr) == before - 2
     for got, again, want in zip(*(jax.tree.leaves(t) for t in (
@@ -888,8 +995,8 @@ def test_a_rematerialised_expert_layer_sorts_its_scores_once(arms, kinds,
     assert names.ROUTER_PICKS in hybrid.remat_keeps(z)
     assert sorts(True) == sorts(False) == 2
     kept = hybrid.remat_keeps
-    monkeypatch.setattr(hybrid, "remat_keeps", lambda z: tuple(
-        name for name in kept(z) if name != names.ROUTER_PICKS))
+    monkeypatch.setattr(hybrid, "remat_keeps", lambda z, kind: tuple(
+        name for name in kept(z, kind) if name != names.ROUTER_PICKS))
     assert sorts(True) == 4
 
 

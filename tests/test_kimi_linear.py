@@ -570,15 +570,23 @@ def test_a_router_held_fixed_still_hands_its_gradient_to_the_tokens():
 def test_the_gradient_under_remat_is_the_gradient_without(f32_pair):
     """What a layer keeps changes no number: layer 0 keeps its dense
     feed-forward's three products beside ``mixer_out``, the expert layers
-    ``mixer_out`` and what their router computed; the two mixers name
-    nothing of their own and run again whole."""
+    ``mixer_out`` and what their router computed; a KDA layer its chunks'
+    inverse besides (since PR 48: the rest of its mixer runs again), the
+    latent layer nothing of its mixer's, which runs again whole."""
     p = f32_pair
     z = p["module"].sizes
     dense = dataclasses.replace(z, feed_forward=names.DENSE_FFN)
     assert hybrid.remat_keeps(dense) == (
         names.MIXER_OUT,) + names.DENSE_FFN_KEEPS
-    assert hybrid.remat_keeps(z) == (
+    assert hybrid.remat_keeps(z, names.LATENT) == hybrid.remat_keeps(z) == (
         names.MIXER_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS)
+    assert hybrid.remat_keeps(z, names.CHANNEL_LINEAR) == (
+        names.MIXER_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS,
+        names.DELTA_INVERSE)
+    text = str(jax.make_jaxpr(jax.grad(lambda q: lm_loss(
+        p["module"].apply(q, p["tokens"]), p["tokens"])))(p["params"]))
+    assert text.count(f"name={names.DELTA_INVERSE}") >= p[
+        "module"].layer_types.count(names.CHANNEL_LINEAR) > 0
     plain = dataclasses.replace(p["module"], remat=False)
     grads = jax.jit(jax.grad(lambda q: lm_loss(
         plain.apply(q, p["tokens"]), p["tokens"])))(p["params"])
@@ -591,7 +599,9 @@ def test_the_real_cells_layers_keep_their_bytes():
     """Layer 0: ``mixer_out`` and the dense arm's three products over 8,192
     tokens in bf16, 8192 x (2304 + 2 x 9216 + 2304) x 2 = 377.5 MB; the
     expert layers ``mixer_out``, 37.7 MB, the router's float32 logits over
-    256 experts, 8.4 MB, and its 8 picks and their scores, 0.5 MB."""
+    256 experts, 8.4 MB, and its 8 picks and their scores, 0.5 MB; a KDA
+    layer its 128 chunks' inverse besides, 8,192 x 32 heads x 64 x 4 bytes
+    = 67.1 MB in float32."""
     z = arch.build_module(REAL, {"remat": "nothing"}).sizes
     dense = dataclasses.replace(z, feed_forward=names.DENSE_FFN)
     assert hybrid.kept_bytes(hybrid.remat_keeps(dense), dense, 8192,
@@ -599,6 +609,11 @@ def test_the_real_cells_layers_keep_their_bytes():
     assert hybrid.kept_bytes(hybrid.remat_keeps(z), z, 8192,
                              jnp.bfloat16) == (
         37_748_736 + 8_388_608 + 524_288)
+    for kind, inverse in ((names.CHANNEL_LINEAR, 67_108_864),
+                          (names.LATENT, 0)):
+        assert hybrid.kept_bytes(
+            hybrid.remat_keeps(z, kind), z, 8192, jnp.bfloat16) == (
+                37_748_736 + 8_388_608 + 524_288 + inverse)
 
 
 # ---------------------------------------------------------------------------

@@ -700,7 +700,7 @@ def test_a_kept_product_is_one_fewer_a_layer_in_the_gradient(kept,
     loss = lambda p: lm_loss(module.apply(p, tokens), tokens)
 
     def products(keep):
-        monkeypatch.setattr(hybrid, "remat_keeps", lambda z: keep)
+        monkeypatch.setattr(hybrid, "remat_keeps", lambda z, kind: keep)
         return _dense_products(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
 
     layers = module.layer_types.count(names.EXPERT_LAYER)

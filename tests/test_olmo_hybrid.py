@@ -234,7 +234,8 @@ def test_a_rematerialised_layer_computes_its_feed_forward_once(policy,
         layers * [z.d_model, z.ffn_width, z.ffn_width])
     grads = jax.jit(jax.grad(loss))(params)
 
-    monkeypatch.setattr(hybrid, "remat_keeps", lambda z: (names.MIXER_OUT,))
+    monkeypatch.setattr(hybrid, "remat_keeps",
+                        lambda z, kind: (names.MIXER_OUT,))
     assert ffn_residuals() == []
     # the policy that saves every product without a batch dimension saves
     # these three already; ``nothing`` runs them again
@@ -249,7 +250,9 @@ def test_the_expert_share_arm_keeps_what_it_kept():
     """``MIXER_OUT`` and, since PR 44, what its router computed (the
     logits, the picks and their scores): the count of products in its
     gradient is PR 34's (352 under ``nothing``, CPU) less the router's
-    rematerialised product, one a layer."""
+    rematerialised product, one a layer, and, since PR 48, less the ten
+    products of a chunk's inverse in each of its three delta-rule layers,
+    which keep ``DELTA_INVERSE``."""
     from cellbench.archs import qwen3_next
 
     module, params, loss = bf16_program(
@@ -259,7 +262,11 @@ def test_the_expert_share_arm_keeps_what_it_kept():
     assert hybrid.remat_keeps(module.sizes) == (
         names.MIXER_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS)
     jaxpr = jax.make_jaxpr(jax.grad(loss))(params)
-    assert len(products(jaxpr.jaxpr)) == 352 - len(module.layer_types)
+    linear = module.layer_types.count(names.LINEAR)
+    assert linear == 3 and hybrid.remat_keeps(
+        module.sizes, names.LINEAR)[-1] == names.DELTA_INVERSE
+    assert len(products(jaxpr.jaxpr)) == (
+        352 - len(module.layer_types) - 10 * linear)
     assert not any(f"name={name}" in str(jaxpr)
                    for name in names.DENSE_FFN_KEEPS)
 
@@ -428,6 +435,14 @@ def test_the_layout_event_says_the_arms_and_the_share(tmp_path, f32_pair):
             e["beta_scale"]) == ([2, 4], 2, 12, 24, names.SEPARATE, 2.0)
     assert (e["feed_forward"], e["norm"], e["norm_after"],
             e["heads_axis"]) == (names.DENSE_FFN, names.PLAIN, True, None)
+    # what a rematerialised layer keeps, a layer: the three delta-rule
+    # layers their chunks' inverse besides (2 held heads x 64 float32 a
+    # position), the attention layer nothing of its mixer's
+    dense = [names.MIXER_OUT, *names.DENSE_FFN_KEEPS]
+    assert e["remat_keeps"] == [dense + [names.DELTA_INVERSE]] * 3 + [dense]
+    held = e["remat_kept_bytes_per_layer"]
+    assert held[0] == held[1] == held[2] == held[3] + p[
+        "tokens"].size * 2 * 64 * 4
 
 
 def test_the_layers_names_carry_what_the_readers_look_for(f32_pair):

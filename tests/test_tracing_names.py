@@ -904,7 +904,8 @@ def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
     columns), its 2 picks and their scores (int32 and float32: 8 bf16
     columns) and its gated shared expert's two first products'; in the
     expert-share arm of a two-sublayer layer ``MIXER_OUT`` and the router's
-    two."""
+    two; and of the two kinds of layer here the delta-rule one alone
+    ``DELTA_INVERSE``."""
     from tpudist.models.hybrid import HybridLM, HybridSizes
 
     arms = dict(feed_forward=feed_forward)
@@ -929,5 +930,13 @@ def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
     finally:
         telemetry.finish(write_report=False)
     assert len(said) == 1
+    held = 2 * 64 * columns * 2
+    if remat and names.LINEAR in kinds:
+        # the layer whose mixer scans by the delta rule keeps its chunks'
+        # inverse besides, float32 whatever the compute dtype: 2 heads x a
+        # chunk of 64 numbers a position; the attention layer nothing more,
+        # so the event says both a layer
+        keeps = [keeps + [names.DELTA_INVERSE], keeps]
+        held = [held + 2 * 64 * 2 * 64 * 4, held]
     assert said[0]["remat_keeps"] == keeps
-    assert said[0]["remat_kept_bytes_per_layer"] == 2 * 64 * columns * 2
+    assert said[0]["remat_kept_bytes_per_layer"] == held

@@ -539,6 +539,12 @@ def _unit_heads(t, heads: int, width: int, dtype, scale: float = 1.0):
     return t.astype(dtype)
 
 
+#: positions a chunk of the delta rule's scan, and the kinds of layer whose
+#: mixer runs it (and keeps its chunks' inverse under remat)
+DELTA_CHUNK = 64
+DELTA_RULE_KINDS = (names.LINEAR, names.CHANNEL_LINEAR)
+
+
 class GatedDeltaNet(nn.Module):
     """The gated delta-rule linear-attention mixer, holding
     ``linear_key_heads`` / ``linear_value_heads`` heads (each of its
@@ -546,7 +552,7 @@ class GatedDeltaNet(nn.Module):
 
     sizes: HybridSizes
     dtype: jnp.dtype
-    chunk: int = 64
+    chunk: int = DELTA_CHUNK
 
     @nn.compact
     def __call__(self, x):
@@ -630,7 +636,7 @@ class KimiDeltaAttention(nn.Module):
 
     sizes: HybridSizes
     dtype: jnp.dtype
-    chunk: int = 64
+    chunk: int = DELTA_CHUNK
 
     @nn.compact
     def __call__(self, x):
@@ -1032,7 +1038,7 @@ class HybridLayer(nn.Module):
         return feed_forward(checkpoint_name(x, names.MIXER_OUT))
 
 
-def remat_keeps(sizes: HybridSizes) -> tuple:
+def remat_keeps(sizes: HybridSizes, kind: Optional[str] = None) -> tuple:
     """The names a rematerialised :class:`HybridLayer` keeps besides its
     input, under every policy: ``names.MIXER_OUT`` (the layer's activation
     between its mixer and its feed-forward arm, ``tokens x d_model x
@@ -1048,10 +1054,16 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
     it keeps the router's logits and ``names.ROUTER_PICKS``, the picks and
     their scores (``tokens x top_k x 8`` bytes), so that the float32
     product, the sort over ``n_experts`` and the pick of the scores run
-    once a step.  No mixer names anything of its own: a delta-rule
-    mixer's (a decay a head or a channel), a latent attention's and every
-    other mixer's forward runs again whole in the backward pass, and what
-    they hold meanwhile (the channel-gated scan's scaled keys, ``tokens x
+    once a step.  A layer of ``kind`` ``names.LINEAR`` or
+    ``names.CHANNEL_LINEAR`` (a mixer that scans by the delta rule, a decay
+    a head or a channel) keeps ``names.DELTA_INVERSE`` besides, the float32
+    inverse ``T`` of each chunk (``tokens x heads x chunk x 4`` bytes: 134
+    MB at 2 x 8,192 x 32 heads), which is all the inverse's own backward
+    pass reads: the rest of such a mixer's forward runs again in the
+    backward pass, but solves for nothing.  No other mixer names anything
+    of its own (a latent attention's and a state-space mixer's forward runs
+    again whole), and no ``kind`` given says no mixer; what a delta-rule
+    mixer holds meanwhile (the channel-gated scan's scaled keys, ``tokens x
     heads x 4 x dk x itemsize`` bytes, 268 MB at 8,192 x 32 x 128 in bf16)
     is the compiler's to place.
 
@@ -1082,9 +1094,10 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
     5,376 in bf16 at 22 picks: 16.8 + 16.8 + 1.4 + 16.8 + 88.1 = 139.9 MB
     a layer."""
     router = (names.ROUTER_LOGITS, names.ROUTER_PICKS)
+    mixer = (names.DELTA_INVERSE,) if kind in DELTA_RULE_KINDS else ()
     if sizes.one_sublayer:
         if sizes.feed_forward != names.EXPERT_SHARE:
-            return ()
+            return mixer
         keep = (names.EXPERT_OUT,) + router
         if sizes.latent_width:
             keep += (names.LATENT_IN,)
@@ -1092,19 +1105,20 @@ def remat_keeps(sizes: HybridSizes) -> tuple:
             keep += tuple(names.SHARED_EXPERT_KEEPS[leaf]
                           for leaf in EXPERT_LEAVES[sizes.expert_fn]
                           if leaf in names.SHARED_EXPERT_KEEPS)
-        return keep
+        return keep + mixer
     if sizes.feed_forward == names.DENSE_FFN and sizes.ffn_products_kept:
-        return (names.MIXER_OUT,) + names.DENSE_FFN_KEEPS
+        return (names.MIXER_OUT,) + names.DENSE_FFN_KEEPS + mixer
     if sizes.feed_forward == names.EXPERT_SHARE:
-        return (names.MIXER_OUT,) + router
-    return (names.MIXER_OUT,)
+        return (names.MIXER_OUT,) + router + mixer
+    return (names.MIXER_OUT,) + mixer
 
 
 def kept_bytes(keep: tuple, sizes: HybridSizes, tokens: int, dtype) -> int:
     """What the activations named ``keep`` hold a layer from its forward to
     its backward pass, over ``tokens`` positions in compute dtype ``dtype``
     (the router's logits in float32, its picks and their scores in int32
-    and float32)."""
+    and float32, a delta rule's inverse in float32: ``DELTA_CHUNK`` numbers
+    a position a held head)."""
     columns = {names.MIXER_OUT: sizes.d_model,
                names.FFN_GATE: sizes.ffn_width,
                names.FFN_UP: sizes.ffn_width, names.FFN_OUT: sizes.d_model,
@@ -1113,10 +1127,11 @@ def kept_bytes(keep: tuple, sizes: HybridSizes, tokens: int, dtype) -> int:
                names.SHARED_GATE: sizes.shared_width,
                names.SHARED_UP: sizes.shared_width}
     itemsize = jnp.dtype(dtype).itemsize
-    router = {names.ROUTER_LOGITS: 4 * sizes.n_experts,
-              names.ROUTER_PICKS: 8 * sizes.top_k}
+    fixed = {names.ROUTER_LOGITS: 4 * sizes.n_experts,
+             names.ROUTER_PICKS: 8 * sizes.top_k,
+             names.DELTA_INVERSE: 4 * sizes.linear_value_heads * DELTA_CHUNK}
     return tokens * sum(
-        router[name] if name in router else itemsize * columns[name]
+        fixed[name] if name in fixed else itemsize * columns[name]
         for name in keep)
 
 
@@ -1142,8 +1157,9 @@ class HybridLM(nn.Module):
     # products' outputs (``tokens x (2 x ffn_width + d_model) x itemsize``
     # more), so its forward runs once; an
     # expert layer of one sublayer the result of a share that goes by windows
-    # and the outputs of its dense products that the backward pass reads; and
-    # every expert layer its router's logits, picks and the picks' scores
+    # and the outputs of its dense products that the backward pass reads;
+    # every expert layer its router's logits, picks and the picks' scores;
+    # and a layer whose mixer scans by the delta rule its chunks' inverse
     remat_policy: str = "nothing"
     # the feed-forward arm a layer (names.EXPERT_SHARE / names.DENSE_FFN)
     # where the layers do not share ``sizes.feed_forward`` (leading dense
@@ -1170,10 +1186,11 @@ class HybridLM(nn.Module):
                 f"{len(self.layer_types)} layers")
         # a layer's sizes: the decoder's, with its own arm
         of_layer = [dataclasses.replace(z, feed_forward=arm) for arm in arms]
-        keeps = [remat_keeps(zi) if self.remat else () for zi in of_layer]
+        keeps = [remat_keeps(zi, kind) if self.remat else ()
+                 for zi, kind in zip(of_layer, self.layer_types)]
         kept = [kept_bytes(keep, z, tokens.size, self.dtype)
                 for keep in keeps]
-        one_arm = len(set(arms)) == 1
+        alike = len(set(keeps)) == 1
         # the softmax attention's sizes: a kind where the decoder says them
         # so, else its one set
         attention_sizes = dict(softmax_kinds={
@@ -1211,8 +1228,8 @@ class HybridLM(nn.Module):
             logits_divisor=z.logits_divisor, tied_head=z.tied_head,
             feed_forward=z.feed_forward, feed_forwards=list(arms),
             norm=z.norm, norm_after=z.norm_after,
-            remat_keeps=list(keeps[0]) if one_arm else list(map(list, keeps)),
-            remat_kept_bytes_per_layer=kept[0] if one_arm else kept,
+            remat_keeps=list(keeps[0]) if alike else list(map(list, keeps)),
+            remat_kept_bytes_per_layer=kept[0] if alike else kept,
             dense_products_kept=[names.FFN_UP in keep for keep in keeps])
         embed = nn.Embed(self.vocab, self.sizes.d_model, name="tok_embed",
                          dtype=self.dtype)

@@ -68,9 +68,18 @@ bound and were not needed.  The per-head path traces to the program it was.
 Differentiable by JAX's own rules: the backward pass is autodiff through
 the chunked form, with the scan's body under ``jax.checkpoint`` so that
 what is kept per chunk is the carried state alone (``[b, h, dk, dv]``
-float32 a chunk) and the body's products are recomputed.  Plain XLA, no
-Pallas kernel; the whole of it runs under the scope
-``names.DELTA_RULE``.
+float32 a chunk) and the body's products are recomputed.  The inverse
+alone has a backward pass of its own, ``dA = -T^T dT T^T``, whose one
+residual is ``T``; differentiated, ``T`` carries the name
+``names.DELTA_INVERSE`` (float32, its two minor dimensions as one), so
+that a caller that rematerialises the call can keep it
+(``tpudist.models.hybrid.remat_keeps``: ``tokens x heads x chunk x 4``
+bytes) and its rematerialised forward then solves for nothing: the
+inverse's ten products, and with a decay a channel the product that makes
+``A``, run once a step.  Everything else of the forward (the decays, ``u``,
+``w``, the scan) runs again.  Under no policy, or one that does not name
+it, the name is an identity.  Plain XLA, no Pallas kernel; the whole of it
+runs under the scope ``names.DELTA_RULE``.
 
 Operands in a low-precision compute dtype (bf16) are multiplied as they
 are with float32 accumulation; float32 operands at the highest matmul
@@ -116,6 +125,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from tpudist.telemetry import names
 
@@ -180,7 +190,14 @@ def _unit_lower_inverse(a: jax.Array, precision, base: int) -> jax.Array:
 
 
 def _unit_lower_inverse_fwd(a, precision, base):
-    inv = _unit_lower_inverse(a, precision, base)
+    # output and residual are the ONE named value, so that a rematerialised
+    # caller whose policy keeps the name has nothing left to solve for (a name
+    # on the output alone leaves the residual to be computed again); named
+    # with its two minor dimensions as one: as it lies its 64 columns take 128
+    # lanes, and a ``T`` kept so would hold twice its bytes
+    inv = checkpoint_name(
+        _unit_lower_inverse(a, precision, base).reshape(*a.shape[:-2], -1),
+        names.DELTA_INVERSE).reshape(a.shape)
     return inv, inv
 
 

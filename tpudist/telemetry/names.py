@@ -160,6 +160,15 @@ LATENT_IN = "latent_in"
 # elementwise, with no sort and no pick of a score.  ``tokens x k x 8``
 # bytes a layer
 ROUTER_PICKS = "router_picks"
+# tpudist/ops/gated_delta.py: the inverse of a chunk of the delta rule,
+# ``T = (I + A)^-1``, ``[chunks, batch, heads, chunk x chunk]`` in float32
+# whatever the compute dtype (named with its two minor dimensions as one, so
+# that it lies lane-dense).  A rematerialised layer whose mixer scans by the
+# delta rule (a decay a head or a channel) keeps it: ``T`` is the one
+# residual of the inverse's own backward pass, and with it kept the
+# rematerialised forward does not solve for it again.  ``tokens x heads x
+# chunk x 4`` bytes a layer
+DELTA_INVERSE = "delta_inverse"
 
 # -- spans (tpudist.telemetry.span / record_span) -----------------------------
 STEP = "step"            # one arrival of a step's result to the next
@@ -268,9 +277,11 @@ WHY_WIDTHS = "widths"        # values narrower than keys: the packed layout has 
 # EXPERT_OUT, ROUTER_LOGITS, ROUTER_PICKS, LATENT_IN where it has latent
 # projections, and SHARED_EXPERT_KEEPS' names of an unscored shared expert's
 # first products; in an expert layer of two sublayers MIXER_OUT,
-# ROUTER_LOGITS, ROUTER_PICKS; ``[]`` without remat) and
+# ROUTER_LOGITS, ROUTER_PICKS; DELTA_INVERSE besides in a layer of kind LINEAR
+# or CHANNEL_LINEAR; ``[]`` without remat) and
 # ``remat_kept_bytes_per_layer=`` what they hold (both a list a layer where
-# the layers' feed-forward arms differ).  And of each expert
+# the layers do not all keep the same: their feed-forward arms differ, or
+# only some mixers scan by the delta rule).  And of each expert
 # layer
 # (tpudist/parallel/moe.py): ``experts=`` the router's width, ``held=``,
 # ``first=``, ``top_k=``, ``dropless=``, ``buffer_rows=`` (the bound: a
