@@ -2,7 +2,8 @@
 plain float32 reference (``cellbench/archs/<arch>.py``): the two error
 measures, a tiny configuration from ``cellbench/tests/data``, the precision
 context, the pair of program and reference on the same weights and tokens,
-their logits, and three Adam steps through ``make_lm_train_step``.  One
+their logits, three Adam steps through ``make_lm_train_step``, and the
+count of dense products in a gradient's jaxpr.  One
 decision, written down once; ``tests/test_hybrid.py``, ``test_olmo_hybrid``,
 ``test_nemotron_h``, ``test_laguna`` and ``test_granite_hybrid`` import it
 (``from tests.decoder_reference import ...``, as ``tests/check_failures.py``
@@ -41,6 +42,18 @@ def worst(got, want) -> float:
     value's measure."""
     got, want = (np.asarray(x, np.float64) for x in (got, want))
     return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+def dense_products(jaxpr) -> int:
+    """``dot_general`` equations in ``jaxpr`` and every jaxpr nested in it
+    (what a rematerialised layer runs again shows as more of them in the
+    gradient's)."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        found += eqn.primitive.name == "dot_general"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += dense_products(sub)
+    return found
 
 
 def tiny(config: dict, dtype="float32", **keys) -> dict:

@@ -726,20 +726,26 @@ def test_a_delta_rule_layer_keeps_its_chunks_inverse_and_no_other(kind):
     """Since PR 48 a layer whose mixer scans by the delta rule (a decay a
     head or a channel) keeps ``DELTA_INVERSE`` behind whatever its
     feed-forward arm keeps: ``tokens x heads x 64 x 4`` bytes, float32
-    whatever the compute dtype (2 heads: 256 bf16 columns).  Every other
-    kind of layer, and a call that names no kind, keeps what it kept."""
+    whatever the compute dtype (2 heads: 256 bf16 columns).  Since PR 49 a
+    decay a channel keeps ``names.KDA_KEEPS`` before it, what its mixer
+    computes on the way to the scan: ``heads x 8`` columns each here, in
+    the compute dtype.  Every other kind of layer, and a call that names
+    no kind, keeps what it kept: a decay a head the inverse alone."""
     delta = kind in (names.LINEAR, names.CHANNEL_LINEAR)
     assert delta == (kind in hybrid.DELTA_RULE_KINDS)
+    channel = names.KDA_KEEPS if kind == names.CHANNEL_LINEAR else ()
     for arms in (dict(), dict(feed_forward=names.DENSE_FFN),
                  dict(feed_forward=names.DENSE_FFN, ffn_products_kept=False),
                  dict(one_sublayer=True),
                  dict(one_sublayer=True, feed_forward=names.DENSE_FFN)):
         z = _sizes(**arms)
         keeps, arm = hybrid.remat_keeps(z, kind), hybrid.remat_keeps(z)
-        assert keeps == arm + ((names.DELTA_INVERSE,) if delta else ())
+        assert keeps == arm + channel + (
+            (names.DELTA_INVERSE,) if delta else ())
         for dtype in (jnp.bfloat16, jnp.float32):
             assert hybrid.kept_bytes(keeps, z, 64, dtype) == hybrid.kept_bytes(
-                arm, z, 64, dtype) + (64 * 2 * 64 * 4 if delta else 0)
+                arm, z, 64, dtype) + (64 * 2 * 64 * 4 if delta else 0) + (
+                    len(channel) * 64 * 2 * 8 * jnp.dtype(dtype).itemsize)
 
 
 def _delta_rule_call(decay: str, beta_max: float, dk: int, dv: int):

@@ -37,9 +37,9 @@ import pytest
 
 from cellbench import reference
 from cellbench.archs import kimi_linear as arch
-from tests.decoder_reference import (DATA, highest, logits, reference_logits,
-                                     reference_pair, rel, run_steps, seeded,
-                                     tiny, worst)
+from tests.decoder_reference import (DATA, dense_products, highest, logits,
+                                     reference_logits, reference_pair, rel,
+                                     run_steps, seeded, tiny, worst)
 from tpudist import telemetry
 from tpudist.models import hybrid
 from tpudist.models.transformer import lm_loss
@@ -570,9 +570,11 @@ def test_a_router_held_fixed_still_hands_its_gradient_to_the_tokens():
 def test_the_gradient_under_remat_is_the_gradient_without(f32_pair):
     """What a layer keeps changes no number: layer 0 keeps its dense
     feed-forward's three products beside ``mixer_out``, the expert layers
-    ``mixer_out`` and what their router computed; a KDA layer its chunks'
-    inverse besides (since PR 48: the rest of its mixer runs again), the
-    latent layer nothing of its mixer's, which runs again whole."""
+    ``mixer_out`` and what their router computed; a KDA layer besides what
+    its mixer computes on the way to the scan (since PR 49:
+    ``names.KDA_KEEPS``) and its chunks' inverse (since PR 48), the latent
+    layer nothing of its mixer's, which runs again whole; a delta rule at a
+    decay a head the inverse alone, as before PR 49."""
     p = f32_pair
     z = p["module"].sizes
     dense = dataclasses.replace(z, feed_forward=names.DENSE_FFN)
@@ -582,11 +584,16 @@ def test_the_gradient_under_remat_is_the_gradient_without(f32_pair):
         names.MIXER_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS)
     assert hybrid.remat_keeps(z, names.CHANNEL_LINEAR) == (
         names.MIXER_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS,
+        *names.KDA_KEEPS, names.DELTA_INVERSE)
+    assert names.KDA_KEEPS == (names.KDA_Q, names.KDA_K, names.KDA_V)
+    assert hybrid.remat_keeps(z, names.LINEAR) == (
+        names.MIXER_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS,
         names.DELTA_INVERSE)
     text = str(jax.make_jaxpr(jax.grad(lambda q: lm_loss(
         p["module"].apply(q, p["tokens"]), p["tokens"])))(p["params"]))
-    assert text.count(f"name={names.DELTA_INVERSE}") >= p[
-        "module"].layer_types.count(names.CHANNEL_LINEAR) > 0
+    for name in (names.DELTA_INVERSE,) + names.KDA_KEEPS:
+        assert text.count(f"name={name}") >= p[
+            "module"].layer_types.count(names.CHANNEL_LINEAR) > 0, name
     plain = dataclasses.replace(p["module"], remat=False)
     grads = jax.jit(jax.grad(lambda q: lm_loss(
         plain.apply(q, p["tokens"]), p["tokens"])))(p["params"])
@@ -595,13 +602,44 @@ def test_the_gradient_under_remat_is_the_gradient_without(f32_pair):
         assert not np.any(np.asarray(b)) or worst(a, b) < 1e-4
 
 
+@pytest.mark.parametrize("kept", [
+    *((name,) for name in names.KDA_KEEPS), names.KDA_KEEPS],
+    ids=[*names.KDA_KEEPS, "all_three"])
+def test_a_kept_projection_is_one_product_fewer_a_kda_layer(kept,
+                                                            monkeypatch):
+    """The tiny architecture, rematerialised (``nothing``), in bf16: for
+    each of ``q_proj``, ``k_proj`` and ``v_proj`` whose output a KDA layer
+    keeps, the gradient's jaxpr holds one ``dot_general`` fewer in each of
+    the four KDA layers than under the parent's policy (what a layer kept
+    before PR 49: the name sits on the product's own output, not on a
+    copy), and with all three kept three fewer."""
+    config = tiny(TINY, "bfloat16")
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 128), 0,
+                                config["vocab_size"])
+    module = arch.build_module(config, {"remat": "nothing"})
+    params = arch.program_tree(config, seeded(arch, config, 7))
+    loss = lambda p: lm_loss(module.apply(p, tokens), tokens)
+    since_49 = hybrid.remat_keeps
+
+    def products(keep):
+        monkeypatch.setattr(hybrid, "remat_keeps", lambda z, kind: tuple(
+            name for name in since_49(z, kind)
+            if name not in names.KDA_KEEPS or name in keep))
+        return dense_products(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+
+    layers = module.layer_types.count(names.CHANNEL_LINEAR)
+    assert layers == 4
+    assert products(()) - products(kept) == layers * len(kept)
+
+
 def test_the_real_cells_layers_keep_their_bytes():
     """Layer 0: ``mixer_out`` and the dense arm's three products over 8,192
     tokens in bf16, 8192 x (2304 + 2 x 9216 + 2304) x 2 = 377.5 MB; the
     expert layers ``mixer_out``, 37.7 MB, the router's float32 logits over
     256 experts, 8.4 MB, and its 8 picks and their scores, 0.5 MB; a KDA
     layer its 128 chunks' inverse besides, 8,192 x 32 heads x 64 x 4 bytes
-    = 67.1 MB in float32."""
+    = 67.1 MB in float32, and each of ``names.KDA_KEEPS``, 8,192 x 4,096
+    columns (32 heads of 128) x 2 bytes = 67.1 MB in bf16."""
     z = arch.build_module(REAL, {"remat": "nothing"}).sizes
     dense = dataclasses.replace(z, feed_forward=names.DENSE_FFN)
     assert hybrid.kept_bytes(hybrid.remat_keeps(dense), dense, 8192,
@@ -609,11 +647,15 @@ def test_the_real_cells_layers_keep_their_bytes():
     assert hybrid.kept_bytes(hybrid.remat_keeps(z), z, 8192,
                              jnp.bfloat16) == (
         37_748_736 + 8_388_608 + 524_288)
-    for kind, inverse in ((names.CHANNEL_LINEAR, 67_108_864),
-                          (names.LATENT, 0)):
+    for name in names.KDA_KEEPS:
+        assert hybrid.kept_bytes((name,), z, 8192, jnp.bfloat16) == (
+            8192 * 4096 * 2) == 67_108_864, name
+    for kind, mixer in ((names.CHANNEL_LINEAR,
+                         (1 + len(names.KDA_KEEPS)) * 67_108_864),
+                        (names.LINEAR, 67_108_864), (names.LATENT, 0)):
         assert hybrid.kept_bytes(
             hybrid.remat_keeps(z, kind), z, 8192, jnp.bfloat16) == (
-                37_748_736 + 8_388_608 + 524_288 + inverse)
+                37_748_736 + 8_388_608 + 524_288 + mixer)
 
 
 # ---------------------------------------------------------------------------

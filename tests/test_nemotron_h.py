@@ -28,9 +28,9 @@ import pytest
 
 from cellbench import reference
 from cellbench.archs import nemotron_h as arch
-from tests.decoder_reference import (DATA, highest, logits, reference_logits,
-                                     reference_pair, rel, run_steps, seeded,
-                                     tiny, worst)
+from tests.decoder_reference import (DATA, dense_products, highest, logits,
+                                     reference_logits, reference_pair, rel,
+                                     run_steps, seeded, tiny, worst)
 from tpudist import telemetry
 from tpudist.models import hybrid
 from tpudist.models.transformer import lm_loss
@@ -672,15 +672,6 @@ def test_the_real_cells_expert_layers_keep_138_megabytes_a_layer():
     assert hybrid.kept_bytes((), z, 8192, jnp.bfloat16) == 0
 
 
-def _dense_products(jaxpr) -> int:
-    found = 0
-    for eqn in jaxpr.eqns:
-        found += eqn.primitive.name == "dot_general"
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _dense_products(sub)
-    return found
-
-
 @pytest.mark.parametrize("kept", [
     (names.SHARED_UP,), (names.ROUTER_LOGITS,), (names.LATENT_IN,),
     (names.ROUTER_LOGITS, names.LATENT_IN, names.SHARED_UP)],
@@ -701,7 +692,7 @@ def test_a_kept_product_is_one_fewer_a_layer_in_the_gradient(kept,
 
     def products(keep):
         monkeypatch.setattr(hybrid, "remat_keeps", lambda z, kind: keep)
-        return _dense_products(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+        return dense_products(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
 
     layers = module.layer_types.count(names.EXPERT_LAYER)
     assert layers == 5

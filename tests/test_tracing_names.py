@@ -890,9 +890,11 @@ def test_moe_layout_says_the_windows_a_share_takes(
     (names.EXPERT_LAYER, True, [
         names.EXPERT_OUT, names.ROUTER_LOGITS, names.ROUTER_PICKS,
         names.LATENT_IN, names.SHARED_GATE, names.SHARED_UP],
-     24 + 8 + 8 + 24 + 2 * 16)],
+     24 + 8 + 8 + 24 + 2 * 16),
+    (names.CHANNEL_LINEAR, True, [names.MIXER_OUT, names.ROUTER_LOGITS,
+                                  names.ROUTER_PICKS], 32 + 8 + 8)],
     ids=["dense_arm", "expert_share_arm", "no_remat",
-         "one_sublayer_expert_layer"])
+         "one_sublayer_expert_layer", "a_decay_a_channel"])
 def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
         tmp_path, feed_forward, remat, keeps, columns):
     """``remat_keeps``: the names kept besides a layer's input;
@@ -905,7 +907,9 @@ def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
     columns) and its gated shared expert's two first products'; in the
     expert-share arm of a two-sublayer layer ``MIXER_OUT`` and the router's
     two; and of the two kinds of layer here the delta-rule one alone
-    ``DELTA_INVERSE``."""
+    ``DELTA_INVERSE``, behind ``names.KDA_KEEPS`` (what the mixer computes
+    on the way to its scan: ``tokens x heads x 8 x itemsize`` bytes each
+    here) where its decay is a number a channel."""
     from tpudist.models.hybrid import HybridLM, HybridSizes
 
     arms = dict(feed_forward=feed_forward)
@@ -914,6 +918,9 @@ def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
         arms = dict(feed_forward=names.EXPERT_SHARE, one_sublayer=True,
                     latent_width=24, shared_scored=False)
         kinds = (names.FULL, names.EXPERT_LAYER)
+    if feed_forward == names.CHANNEL_LINEAR:
+        arms = dict(feed_forward=names.EXPERT_SHARE, linear_gate_rank=4)
+        kinds = (names.CHANNEL_LINEAR, names.FULL)
     hybrid = HybridLM(
         vocab=64, layer_types=kinds, dtype=jnp.bfloat16,
         remat=remat, sizes=HybridSizes(
@@ -926,17 +933,23 @@ def test_mixer_layout_says_what_a_rematerialised_layer_keeps(
     session = telemetry.start(tmp_path / "tele", rank=0, generation=0)
     try:
         jax.eval_shape(hybrid.init, jax.random.PRNGKey(0), tokens)
-        said = [r for r in session.ring if r.get("name") == names.MIXER_LAYOUT]
+        # (the decoder's own event: a KDA call site says one of its own)
+        said = [r for r in session.ring
+                if r.get("name") == names.MIXER_LAYOUT and "kinds" in r]
     finally:
         telemetry.finish(write_report=False)
     assert len(said) == 1
     held = 2 * 64 * columns * 2
-    if remat and names.LINEAR in kinds:
+    if remat and kinds[0] in (names.LINEAR, names.CHANNEL_LINEAR):
         # the layer whose mixer scans by the delta rule keeps its chunks'
         # inverse besides, float32 whatever the compute dtype: 2 heads x a
-        # chunk of 64 numbers a position; the attention layer nothing more,
-        # so the event says both a layer
-        keeps = [keeps + [names.DELTA_INVERSE], keeps]
-        held = [held + 2 * 64 * 2 * 64 * 4, held]
+        # chunk of 64 numbers a position (a decay a channel before it what
+        # its mixer hands the scan, 2 heads x 8 bf16 columns each); the
+        # attention layer nothing more, so the event says both a layer
+        channel = (list(names.KDA_KEEPS)
+                   if kinds[0] == names.CHANNEL_LINEAR else [])
+        keeps = [keeps + channel + [names.DELTA_INVERSE], keeps]
+        held = [held + 2 * 64 * 2 * 64 * 4
+                + len(channel) * 2 * 64 * 2 * 8 * 2, held]
     assert said[0]["remat_keeps"] == keeps
     assert said[0]["remat_kept_bytes_per_layer"] == held

@@ -632,7 +632,10 @@ class KimiDeltaAttention(nn.Module):
     the RMS over a head's ``dv`` and the gate a SIGMOID behind a projection
     of the same rank.  The gates' projections, softplus, ``exp(A_log)`` and
     the gate's product run under ``names.KDA_GATE``: what this mixer adds
-    to :class:`GatedDeltaNet`."""
+    to :class:`GatedDeltaNet`.  The three projections' outputs are named
+    (``names.KDA_KEEPS``) for a rematerialised layer to keep
+    (:func:`remat_keeps`): ``tokens x heads x (2 x dk + dv) x itemsize``
+    bytes a layer."""
 
     sizes: HybridSizes
     dtype: jnp.dtype
@@ -650,8 +653,11 @@ class KimiDeltaAttention(nn.Module):
             chunk=self.chunk, sub_block=SUB_BLOCK, gate_floor=GATE_FLOOR,
             gate_rank=rank)
         mixed = jnp.concatenate(
-            [_dense(h * width, f"{name}_proj", self.dtype)(x)
-             for name, width in (("q", dk), ("k", dk), ("v", dv))], axis=-1)
+            [checkpoint_name(_dense(h * width, f"{name}_proj", self.dtype)(x),
+                             kept)
+             for name, width, kept in (("q", dk, names.KDA_Q),
+                                       ("k", dk, names.KDA_K),
+                                       ("v", dv, names.KDA_V))], axis=-1)
         kernel = self.param("conv", nn.initializers.lecun_normal(),
                             (mixed.shape[-1], z.linear_conv_width))
         mixed = jax.nn.silu(causal_depthwise_conv(mixed, kernel)).astype(
@@ -1059,8 +1065,18 @@ def remat_keeps(sizes: HybridSizes, kind: Optional[str] = None) -> tuple:
     a head or a channel) keeps ``names.DELTA_INVERSE`` besides, the float32
     inverse ``T`` of each chunk (``tokens x heads x chunk x 4`` bytes: 134
     MB at 2 x 8,192 x 32 heads), which is all the inverse's own backward
-    pass reads: the rest of such a mixer's forward runs again in the
-    backward pass, but solves for nothing.  No other mixer names anything
+    pass reads, so the rematerialised scan solves for nothing.  At a decay
+    a HEAD (``names.LINEAR``) that is all: the rest of the mixer's forward
+    (projections, convolution, norms, gates, the scan's products) runs
+    again in the backward pass (its projections' outputs were measured and
+    left out where they are narrow, and do not fit where they are fused:
+    ROADMAP S18).  At a decay a CHANNEL (``names.CHANNEL_LINEAR``) the
+    layer keeps ``names.KDA_KEEPS`` before the inverse, the outputs of
+    ``q_proj``, ``k_proj`` and ``v_proj`` (``tokens x heads x (2 x dk +
+    dv) x itemsize`` bytes: 201 MB at 8,192 x 32 x 128 in bf16, lane-dense
+    columns), so those three products run once a step; the convolution,
+    SiLU, L2 norms, the gates' low-rank products and the scan's own
+    products run again from them.  No other mixer names anything
     of its own (a latent attention's and a state-space mixer's forward runs
     again whole), and no ``kind`` given says no mixer; what a delta-rule
     mixer holds meanwhile (the channel-gated scan's scaled keys, ``tokens x
@@ -1095,6 +1111,8 @@ def remat_keeps(sizes: HybridSizes, kind: Optional[str] = None) -> tuple:
     a layer."""
     router = (names.ROUTER_LOGITS, names.ROUTER_PICKS)
     mixer = (names.DELTA_INVERSE,) if kind in DELTA_RULE_KINDS else ()
+    if kind == names.CHANNEL_LINEAR:
+        mixer = names.KDA_KEEPS + mixer
     if sizes.one_sublayer:
         if sizes.feed_forward != names.EXPERT_SHARE:
             return mixer
@@ -1118,14 +1136,18 @@ def kept_bytes(keep: tuple, sizes: HybridSizes, tokens: int, dtype) -> int:
     its backward pass, over ``tokens`` positions in compute dtype ``dtype``
     (the router's logits in float32, its picks and their scores in int32
     and float32, a delta rule's inverse in float32: ``DELTA_CHUNK`` numbers
-    a position a held head)."""
+    a position a held head; a channel-gated mixer's projections the held
+    heads' columns)."""
     columns = {names.MIXER_OUT: sizes.d_model,
                names.FFN_GATE: sizes.ffn_width,
                names.FFN_UP: sizes.ffn_width, names.FFN_OUT: sizes.d_model,
                names.EXPERT_OUT: sizes.latent_width or sizes.d_model,
                names.LATENT_IN: sizes.latent_width or 0,
                names.SHARED_GATE: sizes.shared_width,
-               names.SHARED_UP: sizes.shared_width}
+               names.SHARED_UP: sizes.shared_width,
+               names.KDA_Q: sizes.linear_value_heads * sizes.linear_key_dim,
+               names.KDA_K: sizes.linear_value_heads * sizes.linear_key_dim,
+               names.KDA_V: sizes.linear_value_heads * sizes.linear_value_dim}
     itemsize = jnp.dtype(dtype).itemsize
     fixed = {names.ROUTER_LOGITS: 4 * sizes.n_experts,
              names.ROUTER_PICKS: 8 * sizes.top_k,
@@ -1160,6 +1182,7 @@ class HybridLM(nn.Module):
     # and the outputs of its dense products that the backward pass reads;
     # every expert layer its router's logits, picks and the picks' scores;
     # and a layer whose mixer scans by the delta rule its chunks' inverse
+    # (at a decay a channel its q / k / v projections' outputs too)
     remat_policy: str = "nothing"
     # the feed-forward arm a layer (names.EXPERT_SHARE / names.DENSE_FFN)
     # where the layers do not share ``sizes.feed_forward`` (leading dense

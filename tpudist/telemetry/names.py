@@ -169,6 +169,21 @@ ROUTER_PICKS = "router_picks"
 # rematerialised forward does not solve for it again.  ``tokens x heads x
 # chunk x 4`` bytes a layer
 DELTA_INVERSE = "delta_inverse"
+# tpudist/models/hybrid.py (``KimiDeltaAttention``): what a delta-rule mixer
+# whose decay is a number a channel computes on the way to its scan, each
+# ``[tokens, heads x 128]`` in the compute dtype, lane-dense: the outputs of
+# ``q_proj``, ``k_proj`` and ``v_proj``, named where each product comes out,
+# before the concatenate.  A rematerialised layer of kind CHANNEL_LINEAR keeps
+# them (KDA_KEEPS) beside ``delta_inverse``: the three products then run once
+# a step, not twice (the convolution, SiLU and the norms that follow them are
+# elementwise and run again from the kept tensors).  ``tokens x heads x
+# (2 x dk + dv) x itemsize`` bytes a layer.  A mixer whose decay is a number
+# a head (``GatedDeltaNet``) names nothing of the kind: its projections are
+# narrower (PR 36) or its cell has no memory left for them (ROADMAP S18)
+KDA_Q = "kda_q_proj"
+KDA_K = "kda_k_proj"
+KDA_V = "kda_v_proj"
+KDA_KEEPS = (KDA_Q, KDA_K, KDA_V)
 
 # -- spans (tpudist.telemetry.span / record_span) -----------------------------
 STEP = "step"            # one arrival of a step's result to the next
@@ -278,7 +293,7 @@ WHY_WIDTHS = "widths"        # values narrower than keys: the packed layout has 
 # projections, and SHARED_EXPERT_KEEPS' names of an unscored shared expert's
 # first products; in an expert layer of two sublayers MIXER_OUT,
 # ROUTER_LOGITS, ROUTER_PICKS; DELTA_INVERSE besides in a layer of kind LINEAR
-# or CHANNEL_LINEAR; ``[]`` without remat) and
+# or CHANNEL_LINEAR, behind KDA_KEEPS in the latter; ``[]`` without remat) and
 # ``remat_kept_bytes_per_layer=`` what they hold (both a list a layer where
 # the layers do not all keep the same: their feed-forward arms differ, or
 # only some mixers scan by the delta rule).  And of each expert
